@@ -44,10 +44,9 @@ MEMBOUND = {"Pooling", "LRN", "Softmax", "SoftmaxWithLoss", "Concat",
             "Slice", "Flatten", "Reshape", "BatchNorm", "Accuracy"}
 
 # bf16 peak TFLOP/s per chip by device_kind substring (public spec
-# sheets); MFU is reported against the RUNNING chip's peak, not a
-# hard-coded generation, so committed evidence is self-describing.
-# One copy: bench.py and scripts/bench_attention.py both resolve
-# through here.
+# sheets); MFU is reported against the RUNNING chip's peak.  One copy:
+# bench.py and scripts/bench_attention.py both resolve through here.
+# A device_kind that matches no row is an error, not a default.
 PEAK_BF16_TFLOPS = (
     ("v6e", 918.0), ("trillium", 918.0),
     ("v5p", 459.0),
@@ -57,20 +56,17 @@ PEAK_BF16_TFLOPS = (
     ("v2", 45.0),
 )
 
-# the explicitly-labeled reference chip callers fall back to when the
-# device_kind matches no known chip (v5e)
-FALLBACK_PEAK_TFLOPS = 197.0
-
 
 def peak_tflops_for_kind(device_kind: str) -> tuple:
-    """(peak_bf16_tflops, source) for a device_kind string, or
-    (None, 'unknown') when it matches no known chip — callers then
-    fall back to an explicitly-labeled v5e reference."""
+    """(peak_bf16_tflops, source) for a device_kind string; raises
+    ValueError when it matches no known chip."""
     kind = str(device_kind or "").lower()
     for sub, peak in PEAK_BF16_TFLOPS:
         if sub in kind:
             return peak, f"device_kind:{kind}"
-    return None, "unknown"
+    raise ValueError(
+        f"no bf16 peak known for device_kind {device_kind!r}: add it to "
+        "analysis/roofline.PEAK_BF16_TFLOPS with its source")
 
 
 def peak_tflops(device) -> tuple:
@@ -147,12 +143,14 @@ def analyze_net(net, *, act_bytes: int, param_bytes: int,
     return rows
 
 
-def classify(rows: List[dict], *, peak_tflops: float = None,
+def classify(rows: List[dict], *, peak_tflops: float = 197.0,
              hbm_gbs: float = 819.0) -> List[dict]:
     """Adds t_flop_us / t_mem_us / bound / t_us to each row (in place)
     and returns the rows sorted DESCENDING by roofline time — the
-    autotuner's pruning order.  Defaults model the v5e reference."""
-    peak = (peak_tflops or FALLBACK_PEAK_TFLOPS) * 1e12
+    autotuner's pruning order.  The defaults are the model's chip, a
+    TPU v5e (197 bf16 TFLOP/s, 819 GB/s), not a guess about the
+    running device."""
+    peak = peak_tflops * 1e12
     bw = hbm_gbs * 1e9
     for r in rows:
         r["t_flop_us"] = r["flops"] / peak * 1e6

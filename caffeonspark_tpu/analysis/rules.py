@@ -72,8 +72,9 @@ class DevicePutAliasing(Rule):
     afterwards.
 
     On the CPU backend `device_put` ALIASES aligned host numpy buffers
-    (zero-copy), so mutating the source buffer after staging corrupts
-    the staged batch — the PR 3 ingest bug (see queue_runner.py's
+    (zero-copy), and on a TPU it returns before the buffer has been
+    read, so mutating the source buffer after staging corrupts the
+    staged batch — the PR 3 ingest bug (see queue_runner.py's
     `_resolve_host_copy`).  Flagged: a `device_put(buf, ...)` (or
     `make_array_from_process_local_data(..., buf)`) whose buffer is a
     plain name/attribute that the same scope later mutates in place

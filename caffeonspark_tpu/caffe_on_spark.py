@@ -531,6 +531,8 @@ def serve_main(conf: Config) -> int:
 def main(argv: Optional[List[str]] = None) -> int:
     conf = Config(argv if argv is not None else sys.argv[1:])
     conf.validate()
+    from .utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if getattr(conf, "serve", False):
         return serve_main(conf)
     if getattr(conf, "deploy", False):

@@ -665,10 +665,16 @@ def chunked_feed(batches: Iterator[Dict[str, np.ndarray]], *,
 
 def _resolve_host_copy(host_copy: Optional[bool]) -> bool:
     """Copy numpy buffers before device_put?  On the CPU backend
-    jax.device_put ALIASES aligned host buffers (zero-copy), so a
-    pooled/reused pack buffer mutated after staging would corrupt the
-    staged batch; accelerator backends copy H2D anyway.  Default: copy
-    on CPU only; COS_STAGE_COPY=0/1 overrides."""
+    jax.device_put ALIASES aligned host buffers (zero-copy) for good,
+    so a pooled/reused pack buffer mutated after staging would corrupt
+    the staged batch.  On a TPU device_put returns BEFORE the host
+    buffer has been read (measured, PR 21: a 40 MB buffer overwritten
+    right after the call corrupted the device value 10 times of 10),
+    so there the buffer must stay untouched until the transfer is done
+    — which every in-repo producer guarantees by allocating per batch
+    (pack_batch, np.stack); a caller that reuses buffers sets
+    COS_STAGE_COPY=1.  Default: copy on CPU only; COS_STAGE_COPY=0/1
+    overrides."""
     if host_copy is not None:
         return bool(host_copy)
     env = os.environ.get("COS_STAGE_COPY")

@@ -47,6 +47,7 @@ from ..metrics import PipelineMetrics
 from ..serving.fleet import Fleet
 from ..tools import chaos
 from ..tools.supervisor import pick_snapshot
+from ..utils.chips import local_tpu_chips
 from ..utils.envutils import env_int, env_num
 from .canary import ACCEPT, CanaryGate, EvalRecord
 from .finetune import FineTuner
@@ -76,6 +77,15 @@ class DeployController:
         if not conf.outputPath:
             raise ValueError("-deploy needs -output (snapshot + "
                              "lineage directory)")
+        if local_tpu_chips():
+            # checked first: building the fine-tuner below already
+            # initialises the JAX backend, which claims the chips
+            raise RuntimeError(
+                "-deploy fine-tunes in this process, which holds every "
+                "TPU chip of the host, and then starts serving replicas "
+                "and a canary as child processes that each need one: a "
+                "chip belongs to one process at a time, so they would "
+                "fail or hang in warm-up (ROADMAP queue 3 item 4)")
         self.conf = conf
         self.outdir = conf.outputPath
         self.metrics = metrics or PipelineMetrics()

@@ -85,20 +85,12 @@ def build_argparser() -> argparse.ArgumentParser:
 
 class MiniCluster:
     def __init__(self, args):
-        import jax
         from .parallel import ParallelSolver, build_mesh, distributed_init
         from .proto import read_net, read_solver
         from .solver import Solver
+        from .utils.compile_cache import enable_compile_cache
 
-        # persistent XLA compile cache across runs (first TPU compile of
-        # a big net is 20-40s; resumes/retrains hit the cache)
-        cache = os.environ.get("JAX_CACHE_DIR", "/tmp/cos_jax_cache")
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 2)
-        except Exception:
-            pass
+        enable_compile_cache()   # resumes/retrains skip the compile
 
         # sync-mode policy (COS_SYNC_MODE, parallel/syncmode.py):
         # lockstep joins the global jax.distributed mesh as always;
@@ -386,13 +378,20 @@ class MiniCluster:
             import ml_dtypes
             import numpy as np
             np_dtype = ml_dtypes.bfloat16
+            # only pixel/feature tops narrow: label and id tops keep
+            # f32 — bf16's 8 significant bits would round class ids
+            # above 256 to other ids (LayerOp.index_bottoms)
+            narrow = {n for n, _, kind in solver.train_net.input_specs
+                      if kind.startswith("data")}
 
             def _cast(bs):
                 # uint8 pixels / int32 aux of the device-transform split
                 # keep their wire dtype; the device stage emits bf16
                 for b in bs:
-                    yield {k: v if v.dtype in (np.uint8, np.int32)
-                           else v.astype(np_dtype) for k, v in b.items()}
+                    yield {k: v.astype(np_dtype)
+                           if k in narrow and v.dtype not in (np.uint8,
+                                                              np.int32)
+                           else v for k, v in b.items()}
 
             batches_it = _cast(batches_it)
         # fused multi-step loop (COS_STEPS_PER_LOOP=K>1): stack K

@@ -15,6 +15,7 @@ executor hosts and independence from cv2.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -25,6 +26,7 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libcos_native.so")
 _SRC = os.path.join(_DIR, "cos_native.cpp")
+_LOG = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
@@ -45,16 +47,21 @@ def build(force: bool = False) -> bool:
         return os.path.exists(_SO)
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
            _SRC, "-o", _SO, "-ljpeg"]
+    # callers fall back to cv2 with the same semantics, but say so: a
+    # silent None would hide which decoder a measurement ran on
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=120)
-        if r.returncode != 0:
-            _build_failed = True
-            return False
-        return True
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _LOG.warning("native decoder not built (%s); using cv2", e)
         _build_failed = True
         return False
+    if r.returncode != 0:
+        _LOG.warning("native decoder not built (g++ rc %d: %s); using "
+                     "cv2", r.returncode, r.stderr.strip()[-400:])
+        _build_failed = True
+        return False
+    return True
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -742,10 +742,13 @@ class Net:
                 # precision: E[x²]−E[x]² cancels catastrophically in
                 # bf16 for unnormalized activations — their target is
                 # self.dtype above
+                # — and id-carrying bottoms (labels, token ids) are
+                # never narrowed: see LayerOp.index_bottoms
                 bottoms = [b.astype(target)
                            if jnp.issubdtype(b.dtype, jnp.floating)
-                           and b.dtype != target else b
-                           for b in bottoms]
+                           and b.dtype != target
+                           and i not in op.index_bottoms else b
+                           for i, b in enumerate(bottoms)]
             if self.remat and train and lparams \
                     and not op.f32_stats:
                 # only parameterized layers are checkpointed — wrapping

@@ -29,7 +29,7 @@ import math
 import os
 import zlib
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -106,14 +106,20 @@ class LayerOp:
     # layer updates running statistics in the forward pass and must run
     # in f32 (exempt from compute-dtype casts and rematerialization)
     f32_stats: bool = False
+    # positions of bottoms that carry class / row ids as floats (Caffe's
+    # convention): exempt from compute-dtype casts — bf16 keeps 8
+    # significant bits, so an id above 256 would be rounded to another
+    # id (and 999 to 1000, out of range: a NaN loss on the chip, PR 21)
+    index_bottoms: Tuple[int, ...] = ()
 
 
 def register(name: str, *, params=None, is_loss=False, is_data=False,
-             f32_stats=False):
+             f32_stats=False, index_bottoms=()):
     def deco(fn):
         _REGISTRY[name] = LayerOp(name, fn, params or (lambda lp, s: []),
                                   is_loss=is_loss, is_data=is_data,
-                                  f32_stats=f32_stats)
+                                  f32_stats=f32_stats,
+                                  index_bottoms=tuple(index_bottoms))
         return fn
     return deco
 
@@ -454,7 +460,7 @@ def _embed_params(lp, shapes):
     return specs
 
 
-@register("Embed", params=_embed_params)
+@register("Embed", params=_embed_params, index_bottoms=(0,))
 def _embed(ctx, lp, params, bottoms):
     ep = lp.embed_param
     idx = bottoms[0].astype(jnp.int32)
@@ -684,25 +690,30 @@ def _lrn(ctx, lp, params, bottoms):
     # relu in-kernel (pallas) or inline (XLA fallback) — identical
     # semantics on every backend
     fuse_relu = lp.name in ctx.fused_relu_lrn
+    from .pallas_kernels import pallas_enabled
+    interpret = _pallas_interpret()
+    use_kernel = (pallas_enabled() or interpret) and x.ndim == 4
     if lp.name in ctx.bias_lrn:
         # generalized stem epilogue (net.py bias peephole): the
         # producing conv's bias arrives as params[0] and bias-add +
         # relu + LRN run in one fused pass (pallas on TPU, the
         # identical-semantics XLA chain elsewhere)
         from .pallas_kernels import (bias_relu_lrn_across_channels,
-                                     pallas_enabled, xla_bias_relu_lrn)
+                                     xla_bias_relu_lrn)
         bias = params[0]
-        if pallas_enabled() and x.ndim == 4:
-            return [bias_relu_lrn_across_channels(x, bias, n, alpha,
-                                                  beta, k)]
+        if use_kernel:
+            return [_on_batch_shards(
+                lambda a, b: bias_relu_lrn_across_channels(
+                    a, b, n, alpha, beta, k, interpret), x, bias)]
         return [xla_bias_relu_lrn(x, bias, n, alpha, beta, k)]
     if p.norm_region == NormRegion.ACROSS_CHANNELS:
-        from .pallas_kernels import lrn_across_channels, pallas_enabled
-        if pallas_enabled() and x.ndim == 4:
+        from .pallas_kernels import lrn_across_channels
+        if use_kernel:
             # fused VMEM-resident kernel on TPU, with a matching fused
             # VJP kernel so the training path stays on Pallas
-            return [lrn_across_channels(x, n, alpha, beta, k, False,
-                                        fuse_relu)]
+            return [_on_batch_shards(
+                lambda a: lrn_across_channels(
+                    a, n, alpha, beta, k, interpret, fuse_relu), x)]
         if fuse_relu:
             x = jnp.maximum(x, 0)
         # one shared XLA fallback chain (pallas_kernels owns it so the
@@ -848,7 +859,7 @@ def _parameter(ctx, lp, params, bottoms):
     return [params[0]]
 
 
-@register("BatchReindex")
+@register("BatchReindex", index_bottoms=(1,))
 def _batch_reindex(ctx, lp, params, bottoms):
     """batch_reindex_layer.cpp: top = bottom[0][bottom[1]] along axis 0
     (gather; gradients scatter-add back through the first bottom)."""
@@ -1066,7 +1077,7 @@ def _loss_normalizer(norm_mode, valid_count, batch, full):
     return jnp.maximum(valid_count, 1.0)  # VALID
 
 
-@register("SoftmaxWithLoss", is_loss=True)
+@register("SoftmaxWithLoss", is_loss=True, index_bottoms=(1,))
 def _softmax_loss(ctx, lp, params, bottoms):
     axis = lp.softmax_param.axis if lp.has("softmax_param") else 1
     scores, labels = bottoms[0], bottoms[1]
@@ -1141,7 +1152,7 @@ def _contrastive_loss(ctx, lp, params, bottoms):
     return [jnp.sum(y * dist_sq + (1.0 - y) * mismatch) / (2.0 * n)]
 
 
-@register("HingeLoss", is_loss=True)
+@register("HingeLoss", is_loss=True, index_bottoms=(1,))
 def _hinge_loss(ctx, lp, params, bottoms):
     x, y = bottoms[0], bottoms[1]
     n = x.shape[0]
@@ -1153,7 +1164,8 @@ def _hinge_loss(ctx, lp, params, bottoms):
     return [jnp.sum(margin) / n]
 
 
-@register("MultinomialLogisticLoss", is_loss=True)
+@register("MultinomialLogisticLoss", is_loss=True,
+          index_bottoms=(1,))
 def _mll_loss(ctx, lp, params, bottoms):
     """-log(p[label]) on an already-softmaxed bottom (legacy pairing of
     Softmax + MultinomialLogisticLoss)."""
@@ -1164,7 +1176,7 @@ def _mll_loss(ctx, lp, params, bottoms):
     return [-jnp.sum(jnp.log(jnp.maximum(p, 1e-20))) / n]
 
 
-@register("InfogainLoss", is_loss=True)
+@register("InfogainLoss", is_loss=True, index_bottoms=(1,))
 def _infogain_loss(ctx, lp, params, bottoms):
     """Infogain-weighted multinomial loss: -(1/N) Σ_n Σ_k H[label_n, k]
     · log(p_nk).  The infogain matrix H arrives as bottom[2] (or, in
@@ -1191,7 +1203,7 @@ def _infogain_loss(ctx, lp, params, bottoms):
     return [-jnp.sum(rows * logp) / n]
 
 
-@register("Accuracy")
+@register("Accuracy", index_bottoms=(1,))
 def _accuracy(ctx, lp, params, bottoms):
     p = lp.accuracy_param
     axis = p.axis
@@ -1266,11 +1278,35 @@ def flash_mesh(mesh, batch_axes=("dp",), head_axes=("tp",),
         _FLASH_MESH.pop()
 
 
-def _flash_interpret() -> bool:
-    """COS_FLASH_INTERPRET=1 forces the Pallas kernels in interpret
-    mode on any backend — how the CPU suite exercises the shard_map
-    flash route on virtual meshes."""
+def _pallas_interpret() -> bool:
+    """COS_FLASH_INTERPRET=1 forces the Pallas kernels (flash and LRN)
+    in interpret mode on any backend — how the CPU suite exercises the
+    shard_map kernel routes on virtual meshes."""
     return os.environ.get("COS_FLASH_INTERPRET") == "1"
+
+
+def _on_batch_shards(kernel, x, *whole):
+    """Run a batch-major Pallas kernel on each device's batch shard.
+
+    A bare pallas_call cannot be partitioned: inside a dp-sharded
+    program JAX refuses to lower it ("Mosaic kernels cannot be
+    automatically partitioned", the seed's four-chip train step,
+    PR 21).  While a mesh is installed (flash_mesh, the route
+    attention already takes) the call goes through shard_map over the
+    batch axes instead; `whole` operands (a bias) reach every shard
+    unsplit.  Without a mesh, or with batch axes of extent 1, the
+    kernel is called directly."""
+    if _FLASH_MESH:
+        mesh, b_axes, _, _ = _FLASH_MESH[-1]
+        b_axes = tuple(a for a in b_axes if mesh.shape.get(a, 1) > 1)
+        if b_axes:
+            from jax.sharding import PartitionSpec as P
+            from ..parallel.sp import shard_map_nocheck
+            spec = P(b_axes, *([None] * (x.ndim - 1)))
+            return shard_map_nocheck(
+                kernel, mesh, (spec,) + (P(),) * len(whole),
+                spec)(x, *whole)
+    return kernel(x, *whole)
 
 
 def _attention_dispatch(q, k, v, *, causal: bool):
@@ -1280,7 +1316,7 @@ def _attention_dispatch(q, k, v, *, causal: bool):
     the same math (tests/test_pallas.py flash parity)."""
     from .pallas_kernels import flash_attention, pallas_enabled
     t = q.shape[2]
-    interpret = _flash_interpret()
+    interpret = _pallas_interpret()
     # only 128-aligned sequence lengths take the kernel: Mosaic block
     # shapes must tile (8, 128), and at small T the O(T²) XLA path is
     # cheap anyway

@@ -89,11 +89,8 @@ def distributed_init(coordinator: Optional[str] = None,
     # "multiprocess computations"): the multihost failure drills and
     # the lockstep leg of scripts/bench_syncmode.py run 2-4 CPU ranks
     # through here.  Must be set BEFORE the backend initializes; inert
-    # on accelerator backends, best-effort across jax versions.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:           # noqa: BLE001 — flag name drifts
-        pass
+    # on accelerator backends.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -323,10 +320,11 @@ class MeshLayout:
 
     def install_flash(self, fn):
         """A bare pallas_call cannot be GSPMD-partitioned, but attention
-        is embarrassingly parallel over batch x heads — on meshes the
-        dispatch is routed through shard_map (ops.layers.flash_mesh)
-        and each device runs the kernel on its local block.  Single-
-        device meshes call the kernel directly."""
+        is embarrassingly parallel over batch x heads and LRN over
+        batch — on meshes the Pallas dispatches are routed through
+        shard_map (ops.layers.flash_mesh) and each device runs the
+        kernel on its local block.  Single-device meshes call the
+        kernel directly."""
         if self.mesh.devices.size <= 1:
             return fn
 

@@ -23,27 +23,19 @@ import math
 from functools import partial
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
 
 def shard_map_nocheck(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication checker disabled: a pallas_call's
-    outputs carry no varying-mesh-axes metadata, which the default
-    checker rejects.  Tolerates the check_rep -> check_vma rename
-    across jax versions — the ONE place that knows the kwarg (used by
-    ring attention's flash mode and ops.layers' multi-device flash)."""
-    import inspect
-    sig = inspect.signature(shard_map).parameters
-    kw = {k: False for k in ("check_rep", "check_vma") if k in sig}
+    """shard_map with the varying-mesh-axes checker disabled: a
+    pallas_call's outputs carry no such metadata, which the default
+    checker rejects (used by ring attention's flash mode and
+    ops.layers' per-device kernel routes)."""
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
+                     out_specs=out_specs, check_vma=False)
 
 
 def attention(q: Array, k: Array, v: Array, *, causal: bool = False,

@@ -666,18 +666,41 @@ class CaffeProcessor:
         With an explicit -mesh the extract forward runs under the SAME
         MeshLayout the training step uses (mesh-parallel forward: tp/ep
         params stay sharded, batch over dp); the implicit all-dp
-        default keeps the single-program path so extract output stays
+        default keeps the single-program path — one program on ONE
+        device (_extract_params) — so extract output stays
         byte-identical to the pre-mesh behavior."""
         from .serving.forward import BlobForward
         net = self.solver.test_net or self.solver.train_net
-        layout = (self.psolver.layout
-                  if (getattr(self.conf, "mesh", "")
-                      and self.psolver.mesh.devices.size > 1)
-                  else None)
+        layout = self._extract_layout()
         fwd = getattr(self, "_blob_forward", None)
         if fwd is None or fwd.net is not net or fwd.layout is not layout:
             fwd = self._blob_forward = BlobForward(net, layout=layout)
         return fwd(blob_names)
+
+    def _extract_layout(self):
+        return (self.psolver.layout
+                if (getattr(self.conf, "mesh", "")
+                    and self.psolver.mesh.devices.size > 1)
+                else None)
+
+    def _extract_params(self):
+        """Params for the extract forward.  Without an explicit -mesh
+        the forward is one un-partitioned program, but the trainer
+        leaves the params replicated over every device of the default
+        all-dp mesh — and a program spanning several devices would have
+        to partition the Pallas LRN kernels, which Mosaic refuses
+        outside shard_map (the four-chip -features run, PR 21).  So the
+        single-program path reads the params from the mesh's first
+        local device and runs there, as serving without -serveMesh does."""
+        if self._extract_layout() is not None \
+                or self.psolver.mesh.devices.size == 1:
+            return self.params
+        held = getattr(self, "_extract_held", None)
+        if held is None or held[0] is not self.params:
+            import jax
+            held = self._extract_held = (self.params, jax.device_put(
+                self.params, self.psolver.mesh.local_devices[0]))
+        return held[1]
 
     def extract_rows(self, records, blob_names: Sequence[str],
                      source: Optional[DataSource] = None
@@ -689,9 +712,10 @@ class CaffeProcessor:
         source = source or self.feature_source()
         assert source is not None, "no data layer to decode records with"
         fwd = self._feature_fwd(tuple(blob_names))
+        params = self._extract_params()
         feat_shardings = None
         if getattr(source, "_device_transform", False) \
-                and self.psolver is not None:
+                and params is self.params:
             feat_shardings = self.psolver.input_shardings(
                 self.solver.test_net or self.solver.train_net)
         rows: List[Dict[str, Any]] = []
@@ -710,7 +734,7 @@ class CaffeProcessor:
             # processor) emits uint8+aux: finish the transform here,
             # placed on the mesh so mesh-sharded params and the input
             # agree on devices
-            out = fwd(self.params,
+            out = fwd(params,
                       source.apply_device_stage(source.next_batch(buf),
                                                 feat_shardings))
             rows.extend(fetch_rows(out, blob_names, ids, real, bs))

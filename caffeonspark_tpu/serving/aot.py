@@ -4,12 +4,11 @@ Elastic scale-up is only real if a fresh replica is serving in
 seconds, and the dominant cost of a cold replica is XLA compiling the
 bucket programs (`InferenceService.warmup` compiles
 log2(max_batch)+1 of them; a big net on TPU pays tens of seconds
-each).  The fix is the same persistent compilation cache
-`mini_cluster` and `bench.py` already use for training: point
-`jax_compilation_cache_dir` at shared storage BEFORE the first trace,
-and a replica whose (program, compile options) were compiled by ANY
-earlier replica warms up on deserialized executables — cache hits,
-zero fresh compiles (`RecompileGuard`-verifiable).
+each).  The fix is the persistent compilation cache every entry
+point already enables (`utils/compile_cache.py`): on shared storage, a
+replica whose (program, compile options) were compiled by ANY earlier
+replica warms up on deserialized executables — cache hits, zero fresh
+compiles (`RecompileGuard`-verifiable).
 
 Cache layout: one subdirectory per serving identity, named by a
 digest of (net topology, bucket set, served blobs) —
@@ -22,8 +21,10 @@ params-agnostic (`BlobForward`), so every version of one net shares
 one program set — that sharing is what makes rolling hot-swap free
 and it would be thrown away by a version-keyed cache.
 
-Knob: COS_AOT_CACHE_DIR (unset = no persistent cache; serving then
-compiles per process exactly as before).
+Knob: COS_AOT_CACHE_DIR (unset = the process-wide cache of
+`utils/compile_cache.py`, no per-model namespace).  Where
+`JAX_COMPILATION_CACHE_DIR` is set the environment has placed the
+cache: the namespace is not used, nothing is re-pointed or reset.
 """
 
 from __future__ import annotations
@@ -85,32 +86,30 @@ def resolve_cache_dir(net_param, buckets: Sequence[int],
 
 
 def enable_aot_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at `cache_dir`.  Must
+    """Point JAX's persistent compilation cache at `cache_dir`; False
+    (and nothing touched) when the environment placed the cache.  Must
     run before the first trace of the programs it should capture (the
     serving path calls it before warmup).  min_compile_time 0 /
     min_entry_size -1 persist even the fast CPU compiles — the CI box
     is where the warm-start tests prove the mechanism the TPU path
     relies on."""
-    import jax
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-        # the cache binds its directory lazily at the FIRST compile
-        # and then never re-reads the config — and model/param loading
-        # already compiled small host programs by the time serving
-        # configures the dir, so without a reset the warmup programs
-        # silently skip the cache (observed: zero entries written)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception as e:      # noqa: BLE001 — jax config moved
-        _LOG.warning("AOT cache unavailable (%s); serving will "
-                     "compile per process", e)
+    from ..utils.compile_cache import CACHE_ENV, placed_by_env
+    if placed_by_env():
+        _LOG.info("%s is set: serving compiles into it, the AOT "
+                  "namespace %s is not used", CACHE_ENV, cache_dir)
         return False
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the cache binds its directory lazily at the FIRST compile and
+    # then never re-reads the config — and model/param loading already
+    # compiled small host programs by the time serving configures the
+    # dir, so without a reset the warmup programs silently skip the
+    # cache (observed: zero entries written)
+    compilation_cache.reset_cache()
     _LOG.info("AOT compilation cache at %s", cache_dir)
     return True
 

@@ -50,6 +50,7 @@ from ..obs.recorder import record as record_event
 from ..tools.nodeagent import (AGENT_ERRORS, AgentProc, agent_call,
                                agent_env_overlay, agent_urls_from_env)
 from ..tools.supervisor import terminate_processes
+from ..utils.chips import chip_env, local_chip_ids, require_chips
 from .batcher import _env_int
 from .retry import RetryPolicy
 from .router import (DOWN, OK, STARTING, TRANSPORT_ERRORS, Router,
@@ -365,14 +366,25 @@ class Fleet:
         expensive part of a cold start is each process's own warmup
         compile — with the AOT cache, replica 0 fills it and the rest
         mostly hit it)."""
+        # a chip belongs to one process at a time: on a TPU host each
+        # local replica of a fleet of several gets its OWN chip through
+        # its environment (a fleet of one keeps every chip, for
+        # -serveMesh), and a fleet larger than the host is refused
+        # here — before anything is spawned, and without this process
+        # touching a JAX backend (which would claim the chips itself)
+        chips = [] if self.agents else require_chips(
+            self.n, f"a fleet of {self.n} replicas")
         try:
             for i in range(self.n):
                 name = f"replica{i}"
                 # the fleet-assigned index rides into the subprocess
                 # so per-replica chaos (COS_FAULT_REPLICA_SLOW) can
                 # target one replica; respawns reuse this env dict,
-                # keeping the index stable across restarts
+                # keeping the index (and the chip) stable across
+                # restarts
                 renv = dict(self.env, COS_REPLICA_INDEX=str(i))
+                if chips and self.n > 1:
+                    renv.update(chip_env(chips[i]))
                 if self.agents:
                     rep: ReplicaProcess = AgentReplicaProcess(
                         name, self.serve_args, env=renv,
@@ -731,6 +743,20 @@ class Fleet:
             self._next_index += 1
             name = f"replica{i}"
             renv = dict(self.env, COS_REPLICA_INDEX=str(i))
+            chips = [] if self.agents else local_chip_ids()
+            if chips:
+                # a replica started without a chip of its own (a fleet
+                # of one) holds them all
+                held = [(r.env or {}).get("TPU_VISIBLE_CHIPS")
+                        for r in self.replicas.values() if not r.retired]
+                free = [c for c in chips if c not in held]
+                if None in held or not free:
+                    raise RuntimeError(
+                        f"fleet: cannot add {name}: every TPU chip of "
+                        f"this host ({len(chips)}) is held by a running "
+                        "replica, and a chip belongs to one process at "
+                        "a time (ROADMAP queue 3 item 4)")
+                renv.update(chip_env(free[0]))
             args = self.serve_args
             if self._default_model is not None:
                 args = _args_with_model(self.serve_args,

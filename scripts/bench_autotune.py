@@ -21,7 +21,7 @@ their raw compute time and typically stay inert on CPU.  The artifact
 carries a floor=0 control A/B so the raw ratio without the model is
 committed next to the modeled one.
 
-ALWAYS exits 0 with ONE JSON document on stdout (bench.py contract);
+ALWAYS exits 0 with ONE JSON document on stdout;
 --out also writes the full artifact (bench_evidence/bench_autotune.json
 via `make bench-autotune`).
 

@@ -22,7 +22,7 @@ Gates: `gate_accepts` (clean rounds accepted), `regression_rejected`,
 incumbent), `accepted_improves` (final incumbent beats the bootstrap
 on the held-out eval), `zero_failed_client_requests`.
 
-ALWAYS exits 0 with ONE JSON document on stdout (bench.py contract);
+ALWAYS exits 0 with ONE JSON document on stdout;
 the full artifact lands in bench_evidence/bench_deploy.json.
 
 Usage:

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Observability overhead benchmark (bench.py contract: ALWAYS exits
+"""Observability overhead benchmark (ALWAYS exits
 0 with one JSON document on stdout; --out writes the same document).
 
 The obs layer's promise is that it is cheap enough to leave on: with

@@ -28,7 +28,7 @@ Two legs:
 `--quick` runs scenarios/prodday_smoke.json only (no deploy faults,
 no a/b cells) and stays tier-1-safe (<60s).
 
-ALWAYS exits 0 with ONE JSON document on stdout (bench.py contract);
+ALWAYS exits 0 with ONE JSON document on stdout;
 the full artifact lands in bench_evidence/bench_prodday.json.
 
 Usage:

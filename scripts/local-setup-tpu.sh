@@ -17,10 +17,10 @@
 
 REPO="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 
-# 1. persistent XLA compilation cache (first CaffeNet compile is ~30s;
-#    cached recompiles are instant across runs)
-export JAX_CACHE_DIR="${JAX_CACHE_DIR:-$HOME/.cache/cos_tpu_xla}"
-mkdir -p "$JAX_CACHE_DIR"
+# 1. persistent XLA compilation cache: JAX reads this variable itself
+#    (unset, the package keeps its cache in <repo>/.jax_cache)
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/cos_tpu_xla}"
+mkdir -p "$JAX_COMPILATION_CACHE_DIR"
 
 # 2. native decode/transform library (threaded libjpeg pipeline)
 if [ ! -f "$REPO/caffeonspark_tpu/native/libcos_native.so" ]; then
@@ -37,5 +37,5 @@ if [ -n "$1" ]; then
 fi
 
 export PYTHONPATH="$REPO:${PYTHONPATH}"
-echo "caffeonspark_tpu env ready (repo: $REPO, cache: $JAX_CACHE_DIR)"
+echo "caffeonspark_tpu env ready (repo: $REPO, cache: $JAX_COMPILATION_CACHE_DIR)"
 echo "try: python -m caffeonspark_tpu.mini_cluster -conf <solver.prototxt>"

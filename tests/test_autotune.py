@@ -533,8 +533,8 @@ def test_roofline_variant_costing(monkeypatch):
 def test_peak_table(monkeypatch):
     peak, src = rl.peak_tflops_for_kind("TPU v5e")
     assert peak == 197.0 and src.startswith("device_kind:")
-    peak, src = rl.peak_tflops_for_kind("weird chip")
-    assert peak is None and src == "unknown"
+    with pytest.raises(ValueError, match="weird chip"):
+        rl.peak_tflops_for_kind("weird chip")
     assert rl.SCHEMA == "cos-roofline" and rl.MODEL_VERSION >= 2
 
 

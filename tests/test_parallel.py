@@ -481,6 +481,87 @@ def test_flash_shard_map_dp_tp_training_matches(monkeypatch):
     np.testing.assert_allclose(w_fl, w_ref, rtol=2e-3, atol=2e-5)
 
 
+def test_lrn_kernel_runs_on_batch_shards_under_dp(monkeypatch):
+    """XLA cannot partition a bare pallas_call, so inside a dp-sharded
+    step the LRN kernels go through shard_map over the batch axis
+    (ops.layers._on_batch_shards): each device's kernel sees its OWN
+    batch shard, and the training trajectory — bias gradient through
+    the fused bias+ReLU+LRN epilogue included — matches the
+    single-device XLA chain.  Interpret mode stands in for Mosaic on
+    the virtual mesh."""
+    import jax
+    import caffeonspark_tpu.ops.pallas_kernels as pk
+    from caffeonspark_tpu.parallel import ParallelSolver
+
+    npm = NetParameter.from_text("""
+layer { name: "data" type: "Input" top: "data" top: "label"
+  input_param { shape { dim: 8 dim: 3 dim: 12 dim: 12 }
+                shape { dim: 8 } } }
+layer { name: "conv1" type: "Convolution" bottom: "data" top: "conv1"
+  convolution_param { num_output: 8 kernel_size: 3
+    weight_filler { type: "xavier" }
+    bias_filler { type: "constant" value: 0.1 } } }
+layer { name: "relu1" type: "ReLU" bottom: "conv1" top: "conv1" }
+layer { name: "norm1" type: "LRN" bottom: "conv1" top: "norm1"
+  lrn_param { local_size: 5 alpha: 0.0001 beta: 0.75 } }
+layer { name: "conv2" type: "Convolution" bottom: "norm1" top: "conv2"
+  convolution_param { num_output: 8 kernel_size: 3
+    weight_filler { type: "xavier" } } }
+layer { name: "norm2" type: "LRN" bottom: "conv2" top: "norm2"
+  lrn_param { local_size: 5 alpha: 0.0001 beta: 0.75 } }
+layer { name: "ip" type: "InnerProduct" bottom: "norm2" top: "ip"
+  inner_product_param { num_output: 4
+    weight_filler { type: "xavier" } } }
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip"
+  bottom: "label" top: "loss" }""")
+    sp_txt = ("base_lr: 0.05 momentum: 0.9 lr_policy: 'fixed' "
+              "random_seed: 3")
+    rng = np.random.RandomState(0)
+    batch = {"data": rng.randn(8, 3, 12, 12).astype(np.float32),
+             "label": rng.randint(0, 4, 8).astype(np.float32)}
+
+    seen = []          # batch extent each kernel trace was handed
+    for name in ("lrn_across_channels", "bias_relu_lrn_across_channels"):
+        real = getattr(pk, name)
+
+        def spy(x, *a, _real=real, _name=name):
+            seen.append((_name, x.shape[0]))
+            return _real(x, *a)
+        monkeypatch.setattr(pk, name, spy)
+
+    def run(mesh, kernels):
+        monkeypatch.setenv("COS_FUSE_BIAS_RELU_LRN", "1")
+        if kernels:
+            monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("COS_FLASH_INTERPRET", raising=False)
+        s = Solver(SolverParameter.from_text(sp_txt), npm)
+        ps = ParallelSolver(s, mesh)
+        p, st = ps.init()
+        step = ps.train_step()
+        seen.clear()       # drop Net construction's shape-inference traces
+        losses = []
+        for i in range(2):
+            p, st, out = step(p, st, ps.shard_batch(batch),
+                              s.step_rng(i))
+            losses.append(float(out["loss"]))
+        return (losses,
+                np.asarray(jax.device_get(p["conv1"]["bias"])),
+                list(seen))
+
+    l_ref, b_ref, seen_ref = run(build_mesh(dp=1, devices=jax.devices()[:1]),
+                                 kernels=False)
+    l_dp, b_dp, seen_dp = run(build_mesh(dp=4, devices=jax.devices()[:4]),
+                              kernels=True)
+    assert seen_ref == [], "the reference run must stay on the XLA chain"
+    assert {n for n, _ in seen_dp} == {"lrn_across_channels",
+                                       "bias_relu_lrn_across_channels"}
+    assert {b for _, b in seen_dp} == {2}, \
+        f"kernels must see the per-device shard 8/4, got {seen_dp}"
+    np.testing.assert_allclose(l_dp, l_ref, rtol=1e-5)
+    np.testing.assert_allclose(b_dp, b_ref, rtol=1e-4, atol=1e-6)
+
+
 def test_lockstep_steps():
     # 1000 records, 10 ranks, batch 32 → 100/rank → 3 steps each
     assert lockstep_steps(1000, 32, 10) == 3
