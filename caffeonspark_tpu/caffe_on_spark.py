@@ -29,6 +29,7 @@ import numpy as np
 
 from .config import Config
 from .data.source import DataSource, get_source
+from .metrics import timed_records
 from .processor import CaffeProcessor
 from .utils import fsutils
 
@@ -211,7 +212,9 @@ class CaffeOnSpark:
             train_bs = source_train.batch_size
             val_bs = source_validation.batch_size
             persistent = bool(getattr(conf, "isPersistent", False))
-            train_gen = _record_loop(source_train, persistent=persistent)
+            train_gen = timed_records(
+                _record_loop(source_train, persistent=persistent),
+                proc.metrics, train_bs)
             val_gen = _record_loop(source_validation,
                                    persistent=persistent)
             max_iter = sp.max_iter
@@ -282,9 +285,10 @@ class CaffeOnSpark:
     # ------------------------------------------------------------------
     def _feed_until_done(self, proc: CaffeProcessor,
                          source: DataSource) -> None:
-        gen = _record_loop(source,
-                           persistent=bool(getattr(proc.conf,
-                                                   "isPersistent", False)))
+        gen = timed_records(
+            _record_loop(source, persistent=bool(
+                getattr(proc.conf, "isPersistent", False))),
+            proc.metrics, source.batch_size)
         while proc._thread is not None and proc._thread.is_alive():
             if not proc.feed_queue(0, next(gen)):
                 break

@@ -18,6 +18,7 @@ Reference semantics preserved (SURVEY §2.2):
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import os
 import queue
@@ -28,6 +29,7 @@ from typing import Callable, Dict, Iterator, Optional
 import jax
 import numpy as np
 
+from ..metrics import span_of, timed_records
 from .source import STOP_MARK
 
 _LOG = logging.getLogger(__name__)
@@ -107,11 +109,17 @@ def tune_decode_threads(src, pool_width: int):
 
 
 class FeedQueue:
-    """Bounded record queue with STOP_MARK epoch protocol."""
+    """Bounded record queue with STOP_MARK epoch protocol.  Handed the
+    job's PipelineMetrics (`metrics`, with `batch_size` for the span's
+    ordinal), a feeder that really has to wait in a blocking offer()
+    reports the episode as `read_blocked`."""
 
     def __init__(self, capacity: int = SOURCE_QUEUE_CAPACITY):
         self._q: queue.Queue = queue.Queue(maxsize=capacity)
         self._stopped = False
+        self.metrics = None
+        self.batch_size = 1
+        self._offered = 0
 
     def offer(self, item, timeout: Optional[float] = None) -> bool:
         """Put with backpressure; returns False if stopped or the
@@ -121,8 +129,20 @@ class FeedQueue:
         single non-blocking attempt."""
         if self._stopped:
             return False
-        deadline = (None if timeout is None
-                    else time.monotonic() + timeout)
+        if timeout is not None:
+            ok = self._offer_until(item, time.monotonic() + timeout)
+        else:
+            try:
+                self._q.put_nowait(item)
+                ok = True
+            except queue.Full:
+                with span_of(self.metrics, "read_blocked",
+                             n=self._offered // self.batch_size):
+                    ok = self._offer_until(item, None)
+        self._offered += ok
+        return ok
+
+    def _offer_until(self, item, deadline: Optional[float]) -> bool:
         while True:
             if self._stopped:
                 return False
@@ -237,6 +257,7 @@ class TransformerPool:
         self._results: Dict[int, object] = {}
         self._next_emit = 0
         self._in_seq: Optional[int] = None   # total batches dispatched
+        self._dispatched = 0    # so far (dispatcher writes, spans read)
         self._error: Optional[BaseException] = None
         self._consecutive = 0
         self.drops = 0
@@ -251,8 +272,8 @@ class TransformerPool:
                              name="cos-xform-dispatch")
         self._threads.append(d)
         for i in range(self.num_threads):
-            t = threading.Thread(target=self._worker, daemon=True,
-                                 name=f"cos-xform-{i}")
+            t = threading.Thread(target=self._worker, args=(i,),
+                                 daemon=True, name=f"cos-xform-{i}")
             self._threads.append(t)
         for t in self._threads:
             t.start()
@@ -293,14 +314,9 @@ class TransformerPool:
         seq = 0
         try:
             while not self._should_stop():
-                try:
-                    item = self.feed.take(timeout=0.2)
-                except queue.Empty:
-                    if self.feed.stopped:
-                        break
-                    continue
-                if item is None:
-                    break               # terminal sentinel
+                item = self._next_record()
+                if item is None or item is _END:
+                    break               # terminal sentinel / winding down
                 if item is STOP_MARK:
                     # epoch boundary: drop the ragged tail
                     if buf and self.metrics is not None:
@@ -317,6 +333,7 @@ class TransformerPool:
                     if not self._put_work((seq, buf, draw)):
                         return
                     seq += 1
+                    self._dispatched = seq
                     buf = []
         except BaseException as e:      # noqa: BLE001 — surfaced on take()
             self._fail(e)
@@ -327,19 +344,42 @@ class TransformerPool:
             for _ in range(self.num_threads):
                 self._put_work(_END, force=True)
 
+    def _next_record(self):
+        """One item off the feed; _END when the pool winds down.  The
+        wait for the feeder, when there is one, is `group_starved`."""
+        try:
+            return self.feed.take(timeout=0)
+        except queue.Empty:
+            pass
+        with span_of(self.metrics, "group_starved", n=self._dispatched):
+            while not self._should_stop():
+                try:
+                    return self.feed.take(timeout=0.2)
+                except queue.Empty:
+                    if self.feed.stopped:
+                        break
+        return _END
+
     def _put_work(self, item, force: bool = False) -> bool:
-        while True:
-            if not force and self._should_stop():
-                return False
-            try:
-                self._work.put(item, timeout=0.2)
-                return True
-            except queue.Full:
-                if force and self._should_stop():
-                    # workers are exiting on their own stop checks;
-                    # don't spin on a full queue forever
+        if not force and self._should_stop():
+            return False
+        try:
+            self._work.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with span_of(self.metrics, "group_blocked", n=self._dispatched):
+            while True:
+                if not force and self._should_stop():
                     return False
-                continue
+                try:
+                    self._work.put(item, timeout=0.2)
+                    return True
+                except queue.Full:
+                    if force and self._should_stop():
+                        # workers are exiting on their own stop checks;
+                        # don't spin on a full queue forever
+                        return False
 
     # -- workers: pack + thread-safe drop accounting --------------------
     def _record_ok(self):
@@ -368,20 +408,34 @@ class TransformerPool:
                 f"{n} consecutive batch failures — systematic "
                 f"data/config error; last: {exc}") from exc
 
-    def _worker(self):
+    def _next_work(self, w: int):
+        """The next (seq, buf, draw), or _END.  With the work queue
+        empty every dispatched batch is taken, so the one this worker
+        waits for (`pack_starved`) is ordinal `_dispatched`."""
+        try:
+            return self._work.get_nowait()
+        except queue.Empty:
+            pass
+        with span_of(self.metrics, "pack_starved", n=self._dispatched,
+                     w=w):
+            while True:
+                try:
+                    return self._work.get(timeout=0.2)
+                except queue.Empty:
+                    if self._should_stop():
+                        return _END
+
+    def _worker(self, w: int):
+        m = self.metrics
         while True:
-            try:
-                item = self._work.get(timeout=0.2)
-            except queue.Empty:
-                if self._should_stop():
-                    return
-                continue
+            item = self._next_work(w)
             if item is _END:
                 return
             seq, buf, draw = item
-            t0 = time.perf_counter()
+            cpu0 = time.thread_time()
             try:
-                batch = self.pack(buf, draw)
+                with span_of(m, "pack", n=seq, w=w):
+                    batch = self.pack(buf, draw)
             except Exception as e:      # pack failure → DROPPED slot
                 batch = DROPPED
                 try:
@@ -389,19 +443,24 @@ class TransformerPool:
                 except BaseException as abort:  # noqa: BLE001
                     self._fail(abort)
             else:
-                if self.metrics is not None:
-                    self.metrics.add("pack", time.perf_counter() - t0)
+                if m is not None:
+                    m.add("pack_cpu", time.thread_time() - cpu0)
                 try:
                     self._record_ok()
                 except BaseException as abort:  # noqa: BLE001
                     self._fail(abort)
-            self._deposit(seq, batch)
+            self._deposit(seq, batch, w)
 
-    def _deposit(self, seq: int, batch):
+    def _window_full(self, seq: int) -> bool:
+        return (self._error is None and not self._should_stop()
+                and seq - self._next_emit >= self._window)
+
+    def _deposit(self, seq: int, batch, w: int):
         with self._cond:
-            while (self._error is None and not self._should_stop()
-                   and seq - self._next_emit >= self._window):
-                self._cond.wait(0.2)
+            if self._window_full(seq):
+                with span_of(self.metrics, "pack_blocked", n=seq, w=w):
+                    while self._window_full(seq):
+                        self._cond.wait(0.2)
             self._results[seq] = batch
             self._cond.notify_all()
 
@@ -463,6 +522,7 @@ class PipelinedFeed:
         self._closed = False
         ext = should_stop or (lambda: False)
         self.feed = FeedQueue(capacity)
+        self.feed.metrics, self.feed.batch_size = metrics, src.batch_size
         self._reader_error: dict = {}
         do_shuffle = src.phase_train if shuffle is None else shuffle
         tune_decode_threads(src, num_threads)
@@ -480,7 +540,8 @@ class PipelinedFeed:
                     got_any = False
                     records = (src.shuffled_records(epoch) if do_shuffle
                                else src.records())
-                    for rec in records:
+                    for rec in timed_records(records, metrics,
+                                             src.batch_size):
                         got_any = True
                         if not self.feed.offer(rec):
                             return
@@ -636,11 +697,9 @@ def stack_chunks(batches: Iterator[Dict[str, np.ndarray]],
             except StopIteration:
                 break
         if len(buf) == n:
-            t0 = time.perf_counter()
-            block = {key: np.stack([b[key] for b in buf])
-                     for key in buf[0]}
-            if metrics is not None:
-                metrics.add("stack", time.perf_counter() - t0)
+            with span_of(metrics, "stack"):
+                block = {key: np.stack([b[key] for b in buf])
+                         for key in buf[0]}
             yield n, block
         else:
             for b in buf:
@@ -732,9 +791,7 @@ def device_prefetch(batches: Iterator[Dict[str, np.ndarray]], *,
                if chunked else {})
     copy_host = _resolve_host_copy(host_copy)
 
-    def put_one(v, sh, copy):
-        if copy and isinstance(v, np.ndarray):
-            v = np.array(v, copy=True)
+    def put_one(v, sh):
         if sh is None:
             return jax.device_put(v)
         if multiproc:
@@ -750,18 +807,24 @@ def device_prefetch(batches: Iterator[Dict[str, np.ndarray]], *,
                 return sh.get(k[:-len(DEVICE_AUX_SUFFIX)])
             return sh[k]  # unknown top = config error: fail fast
 
-        staged = {k: put_one(v, sh_for(k), copy) for k, v in b.items()}
-        if not fns:
-            return staged
-        out = {}
-        for k, v in staged.items():
-            if k.endswith(DEVICE_AUX_SUFFIX):
-                continue
-            aux = staged.get(k + DEVICE_AUX_SUFFIX)
-            fn = fns.get(k)
-            out[k] = fn(v, aux) if (fn is not None
-                                    and aux is not None) else v
-        return out
+        if copy:
+            with span_of(metrics, "stage_copy"):
+                b = {k: np.array(v, copy=True)
+                     if isinstance(v, np.ndarray) else v
+                     for k, v in b.items()}
+        with span_of(metrics, "stage_put"):
+            staged = {k: put_one(v, sh_for(k)) for k, v in b.items()}
+            if not fns:
+                return staged
+            out = {}
+            for k, v in staged.items():
+                if k.endswith(DEVICE_AUX_SUFFIX):
+                    continue
+                aux = staged.get(k + DEVICE_AUX_SUFFIX)
+                fn = fns.get(k)
+                out[k] = fn(v, aux) if (fn is not None
+                                        and aux is not None) else v
+            return out
 
     def put(item):
         if not chunked:
@@ -771,56 +834,67 @@ def device_prefetch(batches: Iterator[Dict[str, np.ndarray]], *,
             return 1, stage_dict(b, sharding, jitted, copy_host)
         return n, stage_dict(b, chunk_sharding, vjitted, False)
 
-    def timed_put(b):
-        t0 = time.perf_counter()
-        staged = put(b)
-        if metrics is not None:
-            metrics.add("stage", time.perf_counter() - t0)
-        return staged
+    ordinal = itertools.count()
+
+    def staged_batches():
+        """batches -> staged, on whichever thread stages.  Upstream is a
+        generator, so `stage_starved` brackets every next() on it, wait
+        or not (as `queue_wait` does on the solver thread)."""
+        it = iter(batches)
+        for n in ordinal:
+            try:
+                with span_of(metrics, "stage_starved", n=n):
+                    b = next(it)
+            except StopIteration:
+                return
+            with span_of(metrics, "stage", n=n):
+                staged = put(b)
+            yield n, staged
 
     if background:
-        return _background_stage(batches, timed_put, depth, metrics)
-    return _foreground_stage(batches, timed_put, depth)
+        return _background_stage(staged_batches(), depth, metrics)
+    return _foreground_stage(staged_batches(), depth)
 
 
-def _foreground_stage(batches, timed_put, depth):
+def _foreground_stage(staged_batches, depth):
     buf = collections.deque()
-    for b in batches:
-        buf.append(timed_put(b))
+    for _, staged in staged_batches:
+        buf.append(staged)
         if len(buf) > depth:
             yield buf.popleft()
     while buf:
         yield buf.popleft()
 
 
-def _background_stage(batches, timed_put, depth, metrics):
+def _background_stage(staged_batches, depth, metrics):
     outq: queue.Queue = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
     state: dict = {}
 
+    def hand_over(staged):
+        while not stop.is_set():
+            try:
+                outq.put(staged, timeout=0.2)
+                return
+            except queue.Full:
+                continue
+
     def run():
         try:
-            for b in batches:
-                staged = timed_put(b)
+            for n, staged in staged_batches:
                 if metrics is not None:
                     metrics.gauge("stage_depth", outq.qsize())
-                while not stop.is_set():
-                    try:
-                        outq.put(staged, timeout=0.2)
-                        break
-                    except queue.Full:
-                        continue
+                try:
+                    outq.put_nowait(staged)
+                except queue.Full:
+                    with span_of(metrics, "stage_blocked", n=n):
+                        hand_over(staged)
                 if stop.is_set():
                     return
         except BaseException as e:      # noqa: BLE001 — re-raised below
             state["err"] = e
         finally:
-            while not stop.is_set():
-                try:
-                    outq.put(_END, timeout=0.2)
-                    break
-                except queue.Full:
-                    continue
+            hand_over(_END)
 
     def gen():
         # lazy start: the thread exists only once the consumer actually

@@ -21,6 +21,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..metrics import span_of
 from ..proto.caffe import Datum, LayerParameter
 from .lmdb_io import LmdbReader
 from .sequencefile import SequenceFileReader
@@ -93,6 +94,9 @@ class DataSource:
             mean_dir=os.path.dirname(self.source_uri()) or None)
         self._device_transform = False
         self._device_fns = None
+        # the job's PipelineMetrics, handed over by a trainer: next_batch
+        # then reports pack_decode / pack_transform (None: no report)
+        self.metrics = None
 
     # -- config ------------------------------------------------------------
     def _batch_size(self) -> int:
@@ -131,30 +135,9 @@ class DataSource:
         `draw` replays a pre-drawn augmentation (TransformerPool's
         ordered-draw protocol) instead of consuming the RNG here."""
         c, h, w = self.image_dims()
-        n = len(records)
         labels = np.asarray([r[1] for r in records], np.float32)
-        if all(r[5] for r in records):
-            data = self._decode_encoded_batch(records, c, h, w)
-        else:
-            data = np.zeros((n, c, h, w), np.float32)
-            for i, (rid, label, rc, rh, rw, encoded, payload) in \
-                    enumerate(records):
-                if encoded:
-                    data[i] = decode_image(
-                        payload, channels=c,
-                        resize_hw=(h, w) if (self.resize
-                                             or (rh, rw) != (h, w))
-                        else None)
-                else:
-                    if (rh, rw) != (h, w):
-                        raise ValueError(
-                            f"record {rid}: {rh}x{rw} != layer {h}x{w} "
-                            "(set -resize for encoded sources)")
-                    if isinstance(payload, np.ndarray):
-                        data[i] = payload.reshape(rc, rh, rw)
-                    else:
-                        data[i] = np.frombuffer(payload, np.uint8).astype(
-                            np.float32).reshape(rc, rh, rw)
+        with span_of(self.metrics, "pack_decode"):
+            data = self._records_to_data(records, c, h, w)
         out_names = list(self.layer.top)
         # device-transform split: ships uint8 + per-sample crop/flip aux.
         # Requires pixel payloads (encoded image or uint8 buffer) — a
@@ -171,11 +154,13 @@ class DataSource:
                     f"payloads, but record {bad[0]!r} carries "
                     f"{bad[6].dtype} data — unset COS_DEVICE_TRANSFORM "
                     "for float-valued sources")
-            u8, aux = self.transformer.host_stage(data, draw=draw)
+            with span_of(self.metrics, "pack_transform"):
+                u8, aux = self.transformer.host_stage(data, draw=draw)
             batch = {out_names[0]: u8,
                      out_names[0] + DEVICE_AUX_SUFFIX: aux}
         else:
-            batch = {out_names[0]: self.transformer(data, draw=draw)}
+            with span_of(self.metrics, "pack_transform"):
+                batch = {out_names[0]: self.transformer(data, draw=draw)}
         if len(out_names) > 1:
             batch[out_names[1]] = labels
         return batch
@@ -271,6 +256,32 @@ class DataSource:
                     aux = jax.device_put(aux, sh)
             out[k] = f(v, aux)
         return out
+
+    def _records_to_data(self, records, c, h, w) -> np.ndarray:
+        """The records' pixels as one (n, c, h, w) array, undecoded
+        payloads decoded: the `pack_decode` half of a pack."""
+        if all(r[5] for r in records):
+            return self._decode_encoded_batch(records, c, h, w)
+        data = np.zeros((len(records), c, h, w), np.float32)
+        for i, (rid, label, rc, rh, rw, encoded, payload) in \
+                enumerate(records):
+            if encoded:
+                data[i] = decode_image(
+                    payload, channels=c,
+                    resize_hw=(h, w) if (self.resize
+                                         or (rh, rw) != (h, w))
+                    else None)
+            else:
+                if (rh, rw) != (h, w):
+                    raise ValueError(
+                        f"record {rid}: {rh}x{rw} != layer {h}x{w} "
+                        "(set -resize for encoded sources)")
+                if isinstance(payload, np.ndarray):
+                    data[i] = payload.reshape(rc, rh, rw)
+                else:
+                    data[i] = np.frombuffer(payload, np.uint8).astype(
+                        np.float32).reshape(rc, rh, rw)
+        return data
 
     def _decode_encoded_batch(self, records, c, h, w) -> np.ndarray:
         from .. import native

@@ -20,20 +20,91 @@ per-replica
 state/outstanding/requests table under `replicas` in the router
 summary.
 
-Stage names used by the training runtime:
-  queue_wait  solver thread blocked in next(gen) waiting for a batch
-  pack        transformer-pool decode/augment/pack of one batch
-  stack       np.stack of K packed batches into one (K, batch…) block
-              (fused multi-step path, COS_STEPS_PER_LOOP > 1)
-  stage       device_put / make_array + device-transform dispatch (H2D)
-  step        jitted train-step call (on accelerators this is dispatch
-              wall-time — the async runtime returns before compute
-              finishes; per-step throughput comes from mark_step());
-              for a fused chunk this is the recovered chunk_time/K
-  scan_step   one fused K-step dispatch (whole-chunk wall time)
-  comm        injected gradient-exchange floor sleeps (bench drills:
-              COS_FAULT_COMM_NS_PER_BYTE models the exposed wire time
-              of the COS_GRAD_SYNC plan)
+The train path's vocabulary — THE one place it is written down.  Every
+thread of the feed -> pack -> stage -> step chain reports what it does
+through `PipelineMetrics.span(stage, **attrs)`: on exit the span adds
+its wall time to the series `stage` (what `add()` does) and it brackets
+the same interval in a `jax.profiler.TraceAnnotation("cos.<stage>")`.
+With no profiler session the annotation is inert (about half a
+microsecond); with one (`POST /v1/profile`, `mini_cluster -profile`,
+`perfbench --trace 1`) the span lands on the host plane of the same
+`.xplane.pb` as the device ops, on the same clock.  "Tracing on" means
+"a profiler session is running": there is no other switch.
+
+*busy* spans are recorded once per unit of work.  *starved* (waiting for
+upstream) and *blocked* (waiting for downstream) spans are recorded only
+for an actual wait: the non-blocking get/put is tried first and the span
+opens around the polling loop only when that fails, so one sample is one
+real episode.  Every span carries `n=` the ordinal of the batch at that
+stage (the pool emits in feed order, so ordinal k at `pack` is ordinal k
+at `stage` and step k while iter_size is 1 and nothing was dropped: a
+dropped batch keeps its `n` at `pack` and never reaches `stage`); worker
+spans also carry `w=` the worker index.  A span opened with no attrs
+inside another span on the same thread inherits the outer one's.
+
+  thread      series / span   kind     covers
+  feeder      read            busy     source iteration (LMDB cursor,
+                                       Datum parse, shuffle buffer):
+                                       timed per record, summed, ONE
+                                       sample per batch_size records
+                                       (`timed_records`); series only
+              read_blocked    blocked  the feed queue is full
+                                       (FeedQueue.offer: every feeder,
+                                       Spark's through feed_queue)
+  dispatcher  group_starved   starved  no record to take
+              group_blocked   blocked  the pool's work queue is full
+  worker      pack            busy     whole pack of one batch; ONE
+                                       sample per packed batch
+              pack_decode     busy     child of pack: filling `data`
+                                       from the records (JPEG decode or
+                                       the raw per-record loop)
+              pack_transform  busy     child of pack: crop/mirror/mean
+                                       (Transformer / host_stage)
+              pack_cpu        series   time.thread_time() delta over the
+                                       same interval as pack: seconds the
+                                       worker was ON a CPU (the rest is
+                                       GIL or scheduler wait)
+              pack_starved    starved  no work queued
+              pack_blocked    blocked  results window full (_deposit)
+  (any)       stack           busy     np.stack of K packed batches into
+                                       one (K, batch…) block (fused
+                                       multi-step path only)
+  stager      stage_starved   starved  waiting for a packed batch
+              stage           busy     whole staging of one batch
+              stage_copy      busy     child of stage: host copy
+                                       (copy-on-CPU aliasing defense)
+              stage_put       busy     child of stage: device_put /
+                                       make_array_from_process_local_data
+                                       + device-transform dispatch
+              stage_blocked   blocked  hand-off queue full
+  solver      queue_wait      starved  next(gen): waiting for a staged
+                                       batch
+              step            busy     ENQUEUE of one dispatch, attrs
+                                       it= first iteration, k= steps in
+                                       it.  On an accelerator the async
+                                       runtime returns before compute
+                                       finishes: this is dispatch wall
+                                       time, not device time (throughput
+                                       comes from mark_step()); for a
+                                       fused chunk the series holds
+                                       chunk_time/K per step
+              scan_step       busy     one fused K-step dispatch (whole
+                                       chunk; the profiler span of a
+                                       fused chunk is cos.step, k=K)
+              validation      busy     the train loop is held by a
+                                       validation round
+              snapshot        busy     ... by a snapshot
+              init_params     busy     parameter + optimizer-state init
+  (compiler)  compile         series   programs the backend compiled
+              cache_load      series   programs fetched from the
+                                       persistent cache (`CompileWatch`,
+                                       from jax.monitoring; counters
+                                       cache_hits / cache_misses)
+  solver      comm            series   injected gradient-exchange floor
+                                       sleeps (bench drills:
+                                       COS_FAULT_COMM_NS_PER_BYTE)
+              sync_exchange   busy     relaxed sync modes: host-side
+                                       round-average / global merge
 
 Static run facts ride in the same JSON via `set_info`: the trainer
 publishes the gradient-exchange plan as `info.comm` (per-step wire
@@ -69,6 +140,7 @@ SIGKILLed run keeps telemetry no older than one interval.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -145,6 +217,81 @@ class _Gauge:
         }
 
 
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, resolved once
+
+
+class _NullAnnotation:
+    """Stands in where jax cannot be imported: a span is then add()."""
+
+    def __init__(self, name, **attrs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = _NullAnnotation
+    return _ANNOTATION
+
+
+class _Span:
+    """One interval of one thread: series sample + profiler annotation
+    (PipelineMetrics.span).  Nothing is added to the series when the
+    body raises — a pack that failed is a drop, not a pack."""
+
+    __slots__ = ("_m", "_stage", "_attrs", "_outer", "_ann", "_t0",
+                 "seconds")
+
+    def __init__(self, m: "PipelineMetrics", stage: str, attrs: dict):
+        self._m, self._stage, self._attrs = m, stage, attrs
+
+    def __enter__(self):
+        tls = self._m._tls
+        self._outer = getattr(tls, "attrs", None)
+        if not self._attrs and self._outer:
+            self._attrs = self._outer
+        tls.attrs = self._attrs
+        self._ann = _annotation()("cos." + self._stage, **self._attrs)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(et, ev, tb)
+        self._m._tls.attrs = self._outer
+        if et is None:
+            self._record(self.seconds)
+        return False
+
+    def _record(self, seconds: float):
+        self._m.add(self._stage, seconds)
+
+
+class _StepSpan(_Span):
+    """cos.step: one dispatch of k solver steps (PipelineMetrics.step_span)."""
+
+    __slots__ = ()
+
+    def _record(self, seconds: float):
+        k = self._attrs["k"]
+        if k == 1:
+            self._m.add("step", seconds)
+            self._m.mark_step()
+        else:
+            self._m.add_chunk(k, seconds)
+
+
 class PipelineMetrics:
     """Thread-safe per-stage timeline: durations, counters, gauges, and
     step timestamps for steady-state throughput."""
@@ -159,6 +306,7 @@ class PipelineMetrics:
         self._cap = capacity
         self._step_i = 0
         self._created = time.monotonic()
+        self._tls = threading.local()   # span attrs, per thread
 
     # -- recording (hot path: one lock, O(1)) ---------------------------
     def add(self, stage: str, seconds: float):
@@ -167,6 +315,19 @@ class PipelineMetrics:
             if s is None:
                 s = self._series[stage] = _Series(self._cap)
             s.add(seconds)
+
+    def span(self, stage: str, **attrs) -> _Span:
+        """`with m.span(stage, n=k):` — on exit what add(stage, seconds)
+        does, and the same interval as a `cos.<stage>` annotation on
+        the profiler's clock (inert unless a profiler session runs).
+        add() stays for back-dated intervals."""
+        return _Span(self, stage, attrs)
+
+    def step_span(self, it: int, k: int) -> _Span:
+        """The solver thread's dispatch of `k` steps from iteration
+        `it`: `step` + mark_step() for k == 1, add_chunk() for a fused
+        chunk; either way one `cos.step` annotation."""
+        return _StepSpan(self, "step", {"it": it, "k": k})
 
     def incr(self, name: str, n: int = 1):
         with self._lock:
@@ -284,6 +445,106 @@ class PipelineMetrics:
 
         atomic_write_local(path, _write)
         return path
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span_of(metrics: Optional[PipelineMetrics], stage: str, **attrs):
+    """`metrics.span(...)`, or nothing where a stage was handed no
+    PipelineMetrics (-features, serving, bare library use)."""
+    return _NO_SPAN if metrics is None else metrics.span(stage, **attrs)
+
+
+def timed_records(records, metrics: Optional[PipelineMetrics],
+                  batch_size: int):
+    """Yield from `records`, timing the source's own iteration (LMDB
+    cursor, Datum parse, shuffle buffer) per record and recording the
+    sum as ONE `read` sample per `batch_size` records — the feeder's
+    busy time.  Series only: a profiler span per record would cost more
+    than the read; on the timeline the feeder's busy time is the
+    complement of `read_blocked`."""
+    if metrics is None:
+        yield from records
+        return
+    it = iter(records)
+    acc, k = 0.0, 0
+    while True:
+        t0 = time.perf_counter()
+        try:
+            rec = next(it)
+        except StopIteration:
+            return
+        acc += time.perf_counter() - t0
+        k += 1
+        if k == batch_size:
+            metrics.add("read", acc)
+            acc, k = 0.0, 0
+        yield rec
+
+
+class CompileWatch:
+    """jax.monitoring -> the `compile` and `cache_load` series (and the
+    `cache_hits` / `cache_misses` counters) of one trainer.
+
+    On JAX 0.9.0 `/jax/core/compile/backend_compile_duration` fires for
+    EVERY program, fetched or compiled: it wraps compile_or_get_cached.
+    A fetch from the persistent cache first fires
+    `/jax/compilation_cache/cache_retrieval_time_sec` (lookup +
+    deserialize + load) on the same thread, so that one is the
+    `cache_load` sample and the backend-compile event right behind it on
+    that thread is skipped.  `iteration()` is the trainer's current
+    iteration, None before its first step: a compile after that also
+    goes to the flight recorder — "which step recompiled".  stop()
+    unregisters; jax's other listeners are never touched."""
+
+    BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+    COUNTERS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                "/jax/compilation_cache/cache_misses": "cache_misses"}
+
+    def __init__(self, metrics: PipelineMetrics, iteration=lambda: None):
+        self.metrics = metrics
+        self.iteration = iteration
+        self._fetched = threading.local()
+        self._on = False
+
+    def _on_duration(self, event: str, seconds: float, **kw):
+        if event == self.CACHE_RETRIEVAL:
+            self._fetched.flag = True
+            self.metrics.add("cache_load", seconds)
+        elif event == self.BACKEND_COMPILE:
+            if getattr(self._fetched, "flag", False):
+                self._fetched.flag = False
+                return
+            self.metrics.add("compile", seconds)
+            it = self.iteration()
+            if it is not None:
+                from .obs.recorder import record
+                record("trainer", "compile", it=it,
+                       seconds=round(seconds, 4))
+
+    def _on_event(self, event: str, **kw):
+        name = self.COUNTERS.get(event)
+        if name is not None:
+            self.metrics.incr(name)
+
+    def start(self) -> "CompileWatch":
+        if not self._on:
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+            monitoring.register_event_listener(self._on_event)
+            self._on = True
+        return self
+
+    def stop(self) -> None:
+        if self._on:
+            from jax import monitoring
+            monitoring.unregister_event_duration_listener(
+                self._on_duration)
+            monitoring.unregister_event_listener(self._on_event)
+            self._on = False
 
 
 def metrics_flush_s() -> float:

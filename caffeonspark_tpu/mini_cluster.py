@@ -22,6 +22,7 @@ continue).
 from __future__ import annotations
 
 import argparse
+import itertools
 import os
 import signal
 import sys
@@ -329,7 +330,7 @@ class MiniCluster:
                                         stage_background, stage_depth,
                                         steps_per_loop,
                                         transform_threads)
-        from .metrics import PipelineMetrics
+        from .metrics import CompileWatch, PipelineMetrics, span_of
         tmajor = frozenset(
             n for n, _, kind in solver.train_net.input_specs
             if kind.endswith(":T"))
@@ -338,6 +339,12 @@ class MiniCluster:
         # the step loop; COS_TRANSFORM_THREADS=0 restores the inline
         # generator path
         pmetrics = PipelineMetrics()
+        src.metrics = pmetrics      # next_batch reports its halves
+        # a compile after this run's first step is a recompile: it goes
+        # to the flight recorder with the iteration (None until then)
+        first_it = it
+        compile_watch = CompileWatch(
+            pmetrics, lambda: None if it == first_it else it)
         # observability (caffeonspark_tpu/obs): COS_METRICS_FLUSH_S
         # background-flushes the summary to <output>/metrics.json via
         # the atomic-write path (a SIGKILLed run keeps telemetry no
@@ -362,13 +369,12 @@ class MiniCluster:
                 # inline path: record read + decode + transform all
                 # happen right here, serial with the step loop
                 it_ = src.batches(loop=True)
-                while True:
-                    t0 = time.perf_counter()
+                for nb in itertools.count():
                     try:
-                        b = next(it_)
+                        with pmetrics.span("pack", n=nb):
+                            b = next(it_)
                     except StopIteration:
                         return
-                    pmetrics.add("pack", time.perf_counter() - t0)
                     yield b
 
             raw_batches = _timed_batches()
@@ -472,31 +478,27 @@ class MiniCluster:
         # multiples of the sync boundary k, so `it` and `sched_it`
         # stay congruent mod k and exchange boundaries keep firing.
         sched_it = it
+        compile_watch.start()
         try:
             with profile_trace(self.args.profile):
-                while it < max_iter and not self._stop:
+                for nd in itertools.count():    # dispatches of this run
+                    if it >= max_iter or self._stop:
+                        break
                     inj.step_delay()
                     inj.maybe_die(it)
-                    t_wait = time.perf_counter()
-                    n, batch = next(gen)
-                    pmetrics.add("queue_wait",
-                                 time.perf_counter() - t_wait)
-                    t_step = time.perf_counter()
-                    if n == 1:
-                        params, st, out = step(params, st, batch,
-                                               solver.step_rng(it))
-                        it += 1
-                        pmetrics.add("step",
-                                     time.perf_counter() - t_step)
-                        pmetrics.mark_step()
-                    else:
-                        params, st, out = fused_step(params, st, batch)
-                        it += n
-                        pmetrics.add_chunk(
-                            n, time.perf_counter() - t_step)
+                    with pmetrics.span("queue_wait", n=nd):
+                        n, batch = next(gen)
+                    with pmetrics.step_span(it, n) as dispatch:
+                        if n == 1:
+                            params, st, out = step(params, st, batch,
+                                                   solver.step_rng(it))
+                        else:
+                            params, st, out = fused_step(params, st,
+                                                         batch)
+                    it += n
                     sched_it += n
                     # straggler injector: this rank runs factor× slower
-                    inj.slow_sleep(time.perf_counter() - t_step)
+                    inj.slow_sleep(dispatch.seconds)
                     if comm_sleep:
                         # one exchange per solver step, fused or not;
                         # n per-step samples so the series stays
@@ -505,14 +507,15 @@ class MiniCluster:
                         for _ in range(n):
                             pmetrics.add("comm", comm_sleep)
                     if sync is not None:
-                        t_x = time.perf_counter()
-                        new_it = sync.maybe_exchange(it, _sync_get,
-                                                     _sync_put)
-                        if sync_boundary and (new_it != it
-                                              or it % sync_boundary
-                                              == 0):
-                            pmetrics.add("sync_exchange",
-                                         time.perf_counter() - t_x)
+                        # a sample only at an exchange boundary (a
+                        # re-admission jump happens on one too): the
+                        # heartbeat-only calls in between are not one
+                        at_exchange = (sync_boundary
+                                       and it % sync_boundary == 0)
+                        with span_of(pmetrics if at_exchange else None,
+                                     "sync_exchange", it=it):
+                            new_it = sync.maybe_exchange(it, _sync_get,
+                                                         _sync_put)
                         if new_it != it:
                             # re-admission: the exchange fast-forwarded
                             # us to the pack's clock — the LR schedule
@@ -557,18 +560,21 @@ class MiniCluster:
                                          timer.records_per_sec, 1),
                                      "ts": time.time()}) + "\n")
                     if interleave and sched_it % test_interval == 0:
-                        for _ in range(test_iter):
-                            vb = val_src.apply_device_stage(
-                                _stage_val(next(val_gen)),
-                                None if val_multiproc else vsh)
-                            vout = eval_step(params, vb)
-                            # pre-reduce each output to a REPLICATED scalar
-                            # (jnp.mean all-reduces a dp-sharded blob): a
-                            # per-example top spanning other hosts' devices
-                            # cannot be device_get directly
-                            val_report.add_batch(
-                                {n: jnp.mean(vout[n]) for n in val_names})
-                        val_report.finish_round()
+                        with pmetrics.span("validation", it=it):
+                            for _ in range(test_iter):
+                                vb = val_src.apply_device_stage(
+                                    _stage_val(next(val_gen)),
+                                    None if val_multiproc else vsh)
+                                vout = eval_step(params, vb)
+                                # pre-reduce each output to a REPLICATED
+                                # scalar (jnp.mean all-reduces a dp-sharded
+                                # blob): a per-example top spanning other
+                                # hosts' devices cannot be device_get
+                                # directly
+                                val_report.add_batch(
+                                    {n: jnp.mean(vout[n])
+                                     for n in val_names})
+                            val_report.finish_round()
                         if self._is_rank0:
                             row = val_report.rounds[-1]
                             print("validation iter %d: %s" % (
@@ -617,11 +623,13 @@ class MiniCluster:
                         export_p = checkpoint.gather_params_if_sharded(
                             params)
                         if self._is_rank0 or sharded:
-                            m, s = checkpoint.snapshot(
-                                solver.train_net, export_p, st, self.prefix,
-                                fmt=self.sp.snapshot_format,
-                                solver_type=solver.solver_type,
-                                write_main=self._is_rank0)
+                            with pmetrics.span("snapshot", it=it):
+                                m, s = checkpoint.snapshot(
+                                    solver.train_net, export_p, st,
+                                    self.prefix,
+                                    fmt=self.sp.snapshot_format,
+                                    solver_type=solver.solver_type,
+                                    write_main=self._is_rank0)
                             if self._is_rank0:
                                 print(f"snapshot → {m}")
                                 from .obs.recorder import record
@@ -640,6 +648,7 @@ class MiniCluster:
             # must not leak a reader/pool/stager still decoding at full
             # speed), then land the step-timeline artifact — partial
             # runs are exactly when it matters
+            compile_watch.stop()
             try:
                 gen.close()
             except Exception:           # noqa: BLE001

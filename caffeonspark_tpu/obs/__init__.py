@@ -17,7 +17,10 @@ The one package every subsystem reports through:
                  (`/metrics?format=prom` on replica, router, and the
                  training metrics port), plus the round-trip validator.
   * `profiler` — on-demand bounded `jax.profiler` capture
-                 (`POST /v1/profile`) on a live process.
+                 (`POST /v1/profile`) on a live process; of a live
+                 trainer it shows the train path's own `cos.*` stages
+                 (`PipelineMetrics.span`, vocabulary in `metrics.py`)
+                 beside the device ops, on one clock.
   * `http`     — the training-side metrics port (COS_METRICS_PORT).
 
 Everything here is HOST-side plumbing: nothing imports jax at module

@@ -16,12 +16,12 @@ barrier.
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import os
 import queue
 import threading
-import time
 from typing import (Any, Dict, Iterator, List, Optional,
                     Sequence, Tuple)
 
@@ -37,7 +37,7 @@ from .data.queue_runner import (DROP_LIMIT_DEFAULT, DROPPED, FeedQueue,
                                 stage_depth, steps_per_loop,
                                 transform_threads, tune_decode_threads)
 from .data.source import STOP_MARK, DataSource
-from .metrics import PipelineMetrics
+from .metrics import CompileWatch, PipelineMetrics
 from .parallel import ParallelSolver, build_mesh, parse_mesh_spec
 from .solver import Solver
 
@@ -131,6 +131,14 @@ class CaffeProcessor:
         # the solver thread: all drop accounting shares one lock
         self._drop_lock = threading.Lock()
         self.metrics = PipelineMetrics()  # step-timeline (stop() dumps)
+        # called on the solver thread after each dispatch with
+        # (it, n, batch, params, opt_state, outputs): `it` the first
+        # iteration of the dispatch, `n` its steps.  None = one test
+        # per step; the loop computes nothing for it.
+        self.step_observer = None
+        self._it_now: Optional[int] = None   # None until the first step
+        self._compile_watch = CompileWatch(self.metrics,
+                                           lambda: self._it_now)
         self._flusher = None          # COS_METRICS_FLUSH_S (start())
         self._obs_server = None       # COS_METRICS_PORT (start())
         self._train_pool: Optional[TransformerPool] = None
@@ -152,6 +160,14 @@ class CaffeProcessor:
         self.val_source: Optional[DataSource] = (
             get_source(vl, phase_train=False, **self._source_kw)
             if vl is not None else None)
+        # the stages with no handle on the job's metrics get this one:
+        # next_batch reports its halves, a full feed queue the feeder's
+        # wait (FeedQueue.offer: Spark feeders come through feed_queue)
+        for src, q in zip((self.train_source, self.val_source),
+                          self.queues):
+            q.metrics = self.metrics
+            if src is not None:
+                src.metrics, q.batch_size = self.metrics, src.batch_size
 
     # -- queue API (feedQueue backpressure, :192-198) --------------------
     def feed_queue(self, idx: int, sample) -> bool:
@@ -166,6 +182,8 @@ class CaffeProcessor:
 
     # -- lifecycle -------------------------------------------------------
     def start(self):
+        self._it_now = None
+        self._compile_watch.start()
         self._init_params()
         for q in self.queues:       # re-arm after a previous run stopped
             q.reset()
@@ -189,20 +207,21 @@ class CaffeProcessor:
     def _init_params(self):
         if self.params is not None:
             return
-        params, st = self.psolver.init()
-        conf = self.conf
-        if conf.snapshotStateFile:
-            params, st = checkpoint.restore(
-                self.solver.train_net, params, st,
-                conf.snapshotStateFile,
-                weights_path=conf.snapshotModelFile or None)
-            params = self.psolver.shard_params(params)
-            st = self.psolver.shard_opt_state(st)
-        elif conf.snapshotModelFile:
-            params = checkpoint.copy_layers(
-                self.solver.train_net, params, conf.snapshotModelFile)
-            params = self.psolver.shard_params(params)
-        self.params, self.opt_state = params, st
+        with self.metrics.span("init_params"):
+            params, st = self.psolver.init()
+            conf = self.conf
+            if conf.snapshotStateFile:
+                params, st = checkpoint.restore(
+                    self.solver.train_net, params, st,
+                    conf.snapshotStateFile,
+                    weights_path=conf.snapshotModelFile or None)
+                params = self.psolver.shard_params(params)
+                st = self.psolver.shard_opt_state(st)
+            elif conf.snapshotModelFile:
+                params = checkpoint.copy_layers(
+                    self.solver.train_net, params, conf.snapshotModelFile)
+                params = self.psolver.shard_params(params)
+            self.params, self.opt_state = params, st
 
     def stop(self):
         self._stopped = True
@@ -217,6 +236,7 @@ class CaffeProcessor:
                 self._snapshotter.wait(timeout=600)
             except BaseException as e:      # noqa: BLE001
                 snap_err = e                # must not mask train error
+        self._compile_watch.stop()
         if self._obs_server is not None:
             self._obs_server.stop()
             self._obs_server = None
@@ -319,13 +339,12 @@ class CaffeProcessor:
     def _pack_or_drop(self, src, buf, *, val: bool = False):
         """Inline pack with the drop policy (validation rounds and the
         COS_TRANSFORM_THREADS=0 legacy train path)."""
-        t0 = time.perf_counter()
         try:
-            batch = src.next_batch(buf)
+            with self.metrics.span("pack"):
+                batch = src.next_batch(buf)
         except Exception as e:
             self._note_pack_drop(e, val=val)   # raises at the limit
             return None
-        self.metrics.add("pack", time.perf_counter() - t0)
         self._note_pack_ok(val=val)
         return batch
 
@@ -456,33 +475,33 @@ class CaffeProcessor:
                 metrics=self.metrics)
             params, st = self.params, self.opt_state
             m = self.metrics
-            while True:
+            for nd in itertools.count():        # dispatches of this run
                 inj.step_delay()
                 inj.maybe_die(it)
-                t_wait = time.perf_counter()
                 try:
-                    n, batch = next(gen)
+                    with m.span("queue_wait", n=nd):
+                        n, batch = next(gen)
                 except StopIteration:
                     break
-                m.add("queue_wait", time.perf_counter() - t_wait)
                 m.gauge("feed_depth", len(self.queues[0]))
-                t_step = time.perf_counter()
-                if n == 1:
-                    params, st, out = step(params, st, batch,
-                                           solver.step_rng(it))
-                    it += 1
-                    m.add("step", time.perf_counter() - t_step)
-                    m.mark_step()
-                else:
-                    params, st, out = fused_step(params, st, batch)
-                    it += n
-                    m.add_chunk(n, time.perf_counter() - t_step)
-                inj.slow_sleep(time.perf_counter() - t_step)
+                with m.step_span(it, n) as dispatch:
+                    if n == 1:
+                        params, st, out = step(params, st, batch,
+                                               solver.step_rng(it))
+                    else:
+                        params, st, out = fused_step(params, st, batch)
+                if self.step_observer is not None:
+                    self.step_observer(it, n, batch, params, st, out)
+                it += n
+                self._it_now = it
+                inj.slow_sleep(dispatch.seconds)
                 # interleaved validation: rank-0 records, all ranks step
                 if self.interleave_validation and test_interval \
                         and it % test_interval == 0 \
                         and eval_step is not None and test_iter:
-                    self._run_validation(eval_step, params, test_iter)
+                    with m.span("validation", it=it):
+                        self._run_validation(eval_step, params,
+                                             test_iter)
                 if snap and it % snap == 0:
                     # the multi-host tp/ep param gather is a COLLECTIVE
                     # — every rank runs it at this lockstep boundary
@@ -493,7 +512,8 @@ class CaffeProcessor:
                     if self.rank == 0 \
                             or checkpoint.state_is_sharded(st):
                         self.params, self.opt_state = params, st
-                        self._snapshot(export_params=export_p)
+                        with m.span("snapshot", it=it):
+                            self._snapshot(export_params=export_p)
                 if it >= max_iter:
                     break
             self.params, self.opt_state = params, st
@@ -501,7 +521,8 @@ class CaffeProcessor:
                 export_p = checkpoint.gather_params_if_sharded(params)
                 if self.rank == 0 \
                         or checkpoint.state_is_sharded(st):
-                    self._snapshot(final=True, export_params=export_p)
+                    with m.span("snapshot", it=it):
+                        self._snapshot(final=True, export_params=export_p)
         except BaseException as e:     # surfaced on stop()/join()
             from .obs.recorder import maybe_dump, record
             record("trainer", "fatal",

@@ -474,6 +474,323 @@ layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "ip"
     proc.stop()
 
 
+# -- the host timeline: spans, series, observer ------------------------
+
+SERIES_ALWAYS = ("read", "pack", "pack_decode", "pack_transform",
+                 "pack_cpu", "stage_starved", "stage", "stage_copy",
+                 "stage_put", "queue_wait", "step", "init_params")
+# a feeder that runs ahead of a solver held to 20 ms a step fills every
+# queue on the way (blocked); an interleaved job's feeder hands over one
+# round at a time, and the pool runs dry between rounds (starved)
+SERIES_BLOCKED = ("read_blocked", "group_blocked", "pack_blocked",
+                  "stage_blocked")
+SERIES_STARVED = ("group_starved", "pack_starved")
+
+
+def _timeline_job(tmp_path, monkeypatch, *, max_iter, extra_solver="",
+                  step_delay_ms=20):
+    """A tiny two-phase job: raw 1x28x28 records, batch 16, the full
+    pipelined runtime (2 pool workers + background stager) on the CPU."""
+    from caffeonspark_tpu.config import Config
+    from caffeonspark_tpu.data import LmdbWriter
+    from caffeonspark_tpu.data.synthetic import make_images
+    from caffeonspark_tpu.proto.caffe import Datum
+
+    monkeypatch.setenv("COS_TRANSFORM_THREADS", "2")
+    monkeypatch.setenv("COS_STAGE_BG", "1")
+    monkeypatch.setenv("COS_FAULT_STEP_DELAY_MS", str(step_delay_ms))
+    imgs, labels = make_images(64, seed=6)
+    recs = [(b"%06d" % i,
+             Datum(channels=1, height=28, width=28,
+                   data=(imgs[i, 0] * 255).astype(np.uint8).tobytes(),
+                   label=int(labels[i])).to_binary())
+            for i in range(64)]
+    LmdbWriter(str(tmp_path / "lmdb")).write(recs)
+    data = "\n".join(f"""
+layer {{ name: "data" type: "MemoryData" top: "data" top: "label"
+  include {{ phase: {phase} }} source_class: "LMDB"
+  memory_data_param {{ source: "{tmp_path}/lmdb" batch_size: 16
+    channels: 1 height: 28 width: 28 }}
+  transform_param {{ crop_size: 24 mirror: {mirror} }} }}"""
+                     for phase, mirror in (("TRAIN", "true"),
+                                           ("TEST", "false")))
+    net = tmp_path / "net.prototxt"
+    net.write_text(data + """
+layer { name: "ip" type: "InnerProduct" bottom: "data" top: "ip"
+  inner_product_param { num_output: 10
+    weight_filler { type: "xavier" } } }
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip"
+  bottom: "label" top: "loss" }""")
+    solver = tmp_path / "solver.prototxt"
+    solver.write_text(f'net: "{net}"\nbase_lr: 0.01\n'
+                      f'lr_policy: "fixed"\nmax_iter: {max_iter}\n'
+                      'snapshot_prefix: "x"\nrandom_seed: 2\n'
+                      'snapshot_after_train: false\n' + extra_solver)
+    return Config(["-conf", str(solver), "-train",
+                   "-output", str(tmp_path)])
+
+
+def test_span_adds_what_add_would():
+    """span() == add() of the same interval, plus an annotation that is
+    inert with no profiler session; a body that raises adds nothing; an
+    inner span with no attrs carries the outer one's."""
+    m = PipelineMetrics()
+    t0 = time.perf_counter()
+    with m.span("pack", n=3, w=1) as outer:
+        time.sleep(0.02)
+        with m.span("pack_decode") as inner:
+            assert inner._attrs == {"n": 3, "w": 1}
+    took = time.perf_counter() - t0
+    s = m.summary()["stages"]
+    assert s["pack"]["count"] == 1 and s["pack_decode"]["count"] == 1
+    assert 0.02 <= outer.seconds <= took
+    assert s["pack"]["total_s"] == pytest.approx(outer.seconds, abs=1e-6)
+    with pytest.raises(KeyError):
+        with m.span("pack", n=4):
+            raise KeyError("a failed pack is a drop, not a pack")
+    assert m.summary()["stages"]["pack"]["count"] == 1
+    assert getattr(m._tls, "attrs", None) is None
+    # the solver thread's dispatch: one step, then a fused chunk of 4
+    with m.step_span(0, 1):
+        pass
+    with m.step_span(1, 4):
+        pass
+    s = m.summary()
+    assert s["stages"]["step"]["count"] == 5 and s["steps"] == 5
+    assert s["stages"]["scan_step"]["count"] == 1
+
+
+def test_train_job_reports_every_series(tmp_path, monkeypatch):
+    """One -train job with validation and snapshots: every series of the
+    vocabulary (metrics.py) has samples, and `pack` still counts packed
+    batches, one sample each."""
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.data import get_source
+    from caffeonspark_tpu.processor import CaffeProcessor
+
+    # a round of 20 batches is more than pool, stager and feed queue hold
+    conf = _timeline_job(tmp_path, monkeypatch, max_iter=40,
+                         extra_solver="test_interval: 20\ntest_iter: 2\n"
+                                      "snapshot: 20\n")
+    proc = CaffeProcessor.instance(conf)
+    for q in proc.queues:
+        q._q.maxsize = 24
+    packed = []
+    for src in (proc.train_source, proc.val_source):
+        real = src.pack_batch
+        src.pack_batch = (lambda recs, draw=None, real=real:
+                          (packed.append(1), real(recs, draw))[1])
+    train_src = get_source(conf.train_data_layer(), phase_train=True)
+    val_src = get_source(conf.test_data_layer(), phase_train=False)
+    df = CaffeOnSpark().trainWithValidation(train_src, val_src, conf)
+    assert len(df) == 2
+    for pool in (proc._train_pool, proc._val_pool):
+        pool.join(timeout=5)
+    s = proc.metrics.summary()
+    stages = s["stages"]
+    for name in SERIES_ALWAYS + SERIES_BLOCKED + SERIES_STARVED + (
+            "validation", "snapshot"):
+        assert stages.get(name, {}).get("count", 0) > 0, name
+    assert stages["compile"]["count"] + stages.get(
+        "cache_load", {"count": 0})["count"] > 0
+    assert stages["pack"]["count"] == len(packed)
+    assert stages["pack_cpu"]["count"] == len(packed)
+    assert stages["step"]["count"] == 40
+    assert stages["validation"]["count"] == 2
+    assert stages["snapshot"]["count"] == 2
+    halves = (stages["pack_decode"]["total_s"]
+              + stages["pack_transform"]["total_s"])
+    assert halves <= stages["pack"]["total_s"]
+    proc.stop()
+
+
+def test_pool_workers_account_for_their_lifetime():
+    """pack + pack_starved + pack_blocked is what a worker does from
+    start to exit: no more than threads x pool lifetime, and (loosely,
+    for a busy CI host) at least 0.7 of it."""
+    m = PipelineMetrics()
+    feed = FeedQueue()
+
+    def pack(buf, draw):
+        time.sleep(0.01)
+        return {"ids": np.asarray(buf)}
+
+    t0 = time.perf_counter()
+    pool = TransformerPool(feed, 4, pack, num_threads=2,
+                           metrics=m).start()
+    time.sleep(0.1)                       # starved: nothing fed yet
+    for i in range(4 * 24):
+        feed.offer(i)
+    time.sleep(0.15)                      # blocked: nobody takes
+    got = [b for b in iter(lambda: pool.take(timeout=5), None)
+           if feed.offer(None) or True][:24]
+    assert len(got) == 24
+    pool.join(timeout=5)
+    lifetime = 2 * (time.perf_counter() - t0)
+    stages = m.summary()["stages"]
+    assert stages["pack"]["count"] == 24
+    for name in ("pack_starved", "pack_blocked", "group_starved",
+                 "group_blocked"):
+        assert stages[name]["count"] > 0, name
+    accounted = sum(stages[k]["total_s"]
+                    for k in ("pack", "pack_starved", "pack_blocked"))
+    assert 0.7 * lifetime <= accounted <= lifetime, (accounted, lifetime)
+
+
+def test_profile_of_a_train_job_holds_the_host_timeline(tmp_path,
+                                                         monkeypatch):
+    """A profiler capture of a live -train job, read through the
+    benchmark's own span loader: the chain's stages are there on one
+    clock, a pack's halves lie inside it, and batch n is packed before it
+    is staged before its step is dispatched."""
+    import jax
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.data import get_source
+    from caffeonspark_tpu.processor import CaffeProcessor
+    from perfbench.harness import spans as S
+    from perfbench.harness.trace import find_xplane
+
+    conf = _timeline_job(tmp_path, monkeypatch, max_iter=40)
+    proc = CaffeProcessor.instance(conf)
+    trace_dir = str(tmp_path / "trace")
+    seen = []
+
+    def observer(it, n, batch, params, st, out):
+        seen.append((it, n))
+        if it == 1:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        elif it == 38:          # the pool packs a dozen batches ahead
+            jax.profiler.stop_trace()
+
+    proc.step_observer = observer
+    CaffeOnSpark().train(get_source(conf.train_data_layer(),
+                                    phase_train=True), conf)
+    stages = proc.metrics.summary()["stages"]
+    proc.stop()
+    assert seen == [(i, 1) for i in range(40)]
+    for name in SERIES_ALWAYS + SERIES_BLOCKED:
+        assert stages.get(name, {}).get("count", 0) > 0, name
+    spans = S.load(find_xplane(trace_dir))
+    by = {}
+    for name, line, s, e, stats in spans:
+        by.setdefault(name, []).append((line, s, e, stats))
+    for name in ("pack", "pack_decode", "pack_transform", "stage",
+                 "stage_put", "queue_wait", "step"):
+        assert by.get(name), name
+    packs = {p[3]["n"]: p for p in by["pack"]}
+    for half in by["pack_decode"] + by["pack_transform"]:
+        line, s, e, stats = half
+        outer = packs.get(stats["n"])
+        if outer is None:
+            continue                    # its pack began before the trace
+        assert outer[0] == line and outer[3]["w"] == stats["w"]
+        assert outer[1] <= s and e <= outer[2]
+    stages = {p[3]["n"]: p for p in by["stage"]}
+    steps = {p[3]["it"]: p for p in by["step"]}
+    chained = [n for n in sorted(packs) if n in stages and n in steps]
+    assert len(chained) >= 3, (sorted(packs), sorted(stages),
+                               sorted(steps))
+    for n in chained:
+        assert packs[n][2] <= stages[n][1] <= stages[n][2] \
+            <= steps[n][1], n
+    # five kinds of thread minus the feeder, whose busy time is no span
+    assert len({p[0] for name in ("pack", "stage", "step")
+                for p in by[name]}) >= 3
+
+
+def test_step_observer_error_surfaces_on_stop(tmp_path, monkeypatch):
+    """The hook computes nothing itself, and an observer that raises ends
+    the job like any train error: on stop()."""
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.data import get_source
+    from caffeonspark_tpu.processor import CaffeProcessor
+
+    conf = _timeline_job(tmp_path, monkeypatch, max_iter=10,
+                         step_delay_ms=0)
+    proc = CaffeProcessor.instance(conf)
+    calls = []
+
+    def observer(it, n, batch, params, st, out):
+        calls.append(it)
+        assert set(batch) == {"data", "label"} and "loss" in out
+        if it == 3:
+            raise RuntimeError("observer gave up")
+
+    proc.step_observer = observer
+    with pytest.raises(RuntimeError, match="observer gave up"):
+        CaffeOnSpark().train(get_source(conf.train_data_layer(),
+                                        phase_train=True), conf)
+    assert calls == [0, 1, 2, 3]
+    assert proc.metrics.summary()["stages"]["step"]["count"] == 4
+
+
+def test_compile_events_reach_series_and_recorder(tmp_path,
+                                                  monkeypatch):
+    """With a processor live, jax's backend-compile event lands in the
+    `compile` series and, once a step is done, in the flight recorder
+    with the iteration; a fetch from the persistent cache is a
+    `cache_load` and no compile; after stop() nothing is written."""
+    import jax
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.data import get_source
+    from caffeonspark_tpu.metrics import CompileWatch
+    from caffeonspark_tpu.obs.recorder import get_recorder
+    from caffeonspark_tpu.processor import CaffeProcessor
+
+    conf = _timeline_job(tmp_path, monkeypatch, max_iter=6,
+                         step_delay_ms=0)
+    proc = CaffeProcessor.instance(conf)
+
+    def observer(it, n, batch, params, st, out):
+        if it == 3:
+            jax.monitoring.record_event_duration_secs(
+                CompileWatch.BACKEND_COMPILE, 1.25)
+            jax.monitoring.record_event_duration_secs(
+                CompileWatch.CACHE_RETRIEVAL, 0.75)
+            jax.monitoring.record_event_duration_secs(
+                CompileWatch.BACKEND_COMPILE, 0.76)    # the fetch's own
+            jax.monitoring.record_event(
+                "/jax/compilation_cache/cache_hits")
+
+    proc.step_observer = observer
+    hits0 = proc.metrics.get_counter("cache_hits")
+    CaffeOnSpark().train(get_source(conf.train_data_layer(),
+                                    phase_train=True), conf)
+    stages = proc.metrics.summary()["stages"]
+    assert stages["compile"]["max_ms"] == 1250.0
+    assert stages["cache_load"]["max_ms"] == 750.0
+    assert proc.metrics.get_counter("cache_hits") >= hits0 + 1
+    mine = [e for e in get_recorder().events()
+            if e["source"] == "trainer" and e["event"] == "compile"
+            and e["seconds"] == 1.25]
+    assert [e["it"] for e in mine] == [3]
+    proc.stop()
+    before = proc.metrics.summary()["stages"]["compile"]["count"]
+    jax.monitoring.record_event_duration_secs(
+        CompileWatch.BACKEND_COMPILE, 2.5)
+    assert proc.metrics.summary()["stages"]["compile"]["count"] == before
+    assert not [e for e in get_recorder().events()
+                if e["event"] == "compile" and e.get("seconds") == 2.5]
+
+
+def test_timed_records_one_read_sample_per_batch():
+    from caffeonspark_tpu.metrics import timed_records
+    m = PipelineMetrics()
+
+    def slow():
+        for i in range(10):
+            time.sleep(0.002)
+            yield i
+
+    assert list(timed_records(slow(), m, 4)) == list(range(10))
+    read = m.summary()["stages"]["read"]
+    assert read["count"] == 2                    # the ragged tail: none
+    assert 0.016 <= read["total_s"] < 0.1
+    assert list(timed_records(iter(range(3)), None, 4)) == [0, 1, 2]
+
+
 @pytest.mark.slow
 @pytest.mark.bench
 def test_bench_ingest_smoke(tmp_path):

@@ -755,3 +755,43 @@ layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "ip"
     assert "step" in doc["stages"]
     assert doc["info"]["faults"]["active"] is True
     assert not [p for p in os.listdir(out) if ".tmp." in p]
+
+
+def test_profile_of_live_trainer_shows_host_stages(tmp_path, monkeypatch):
+    """POST /v1/profile on a live trainer's metrics port: the capture
+    holds the job's own `cos.*` stages (metrics.py's vocabulary) from
+    every kind of thread of the feed -> pack -> stage -> step chain."""
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.data import get_source
+    from caffeonspark_tpu.processor import CaffeProcessor
+    from perfbench.harness import spans as S
+    from perfbench.harness.trace import find_xplane
+    from tests.test_ingest_pipeline import _timeline_job
+
+    monkeypatch.setenv("COS_METRICS_PORT", "0")
+    conf = _timeline_job(tmp_path, monkeypatch, max_iter=100000,
+                         step_delay_ms=10)
+    proc = CaffeProcessor.instance(conf)
+    for q in proc.queues:
+        q._q.maxsize = 24                # the feeder fills them
+    started = threading.Event()
+    proc.step_observer = lambda it, *rest: it == 3 and started.set()
+    job = threading.Thread(
+        target=lambda: CaffeOnSpark().train(get_source(
+            conf.train_data_layer(), phase_train=True), conf),
+        daemon=True)
+    job.start()
+    try:
+        assert started.wait(60)
+        out = _post_json(
+            f"http://127.0.0.1:{proc._obs_server.port}/v1/profile",
+            {"duration_ms": 500})
+    finally:
+        proc.stop()
+        job.join(timeout=30)
+    names = {s[0] for s in S.load(find_xplane(out["trace_dir"]))}
+    for thread, stage in (("feeder", "read_blocked"),
+                          ("dispatcher", "group_blocked"),
+                          ("worker", "pack"), ("stager", "stage"),
+                          ("solver", "queue_wait"), ("solver", "step")):
+        assert stage in names, (thread, stage, sorted(names))
