@@ -130,21 +130,39 @@ class DataSource:
                    ) -> Dict[str, np.ndarray]:
         """Pack + transform records into the data layer's blobs
         (ImageDataSource.nextBatch analog, `ImageDataSource.scala:99-163`).
-        All-encoded batches take the native threaded JPEG path
-        (libcos_native, the jcaffe Mat/decode analog) when built.
         `draw` replays a pre-drawn augmentation (TransformerPool's
-        ordered-draw protocol) instead of consuming the RNG here."""
+        ordered-draw protocol) instead of consuming the RNG here.
+
+        Which way a batch is packed follows from what its records
+        hold.  Every record carrying uint8 pixels (all encoded, or all
+        raw `bytes`/uint8 payloads at the layer's geometry): the pixels
+        stay uint8 (`pack_decode`) until one native pass writes the
+        float32 batch (`pack_transform`; counter `pack_fused`).  Float
+        payloads, mixed batches, a corrupt image, a mean the kernel
+        does not do, no native library: float32 `data`, then
+        `Transformer.__call__` (counter `pack_general`), which defines
+        the values of both.  The counters count batches by the way they
+        took, a batch that then fails included."""
         c, h, w = self.image_dims()
         labels = np.asarray([r[1] for r in records], np.float32)
-        with span_of(self.metrics, "pack_decode"):
-            data = self._records_to_data(records, c, h, w)
+        m = self.metrics
+        with span_of(m, "pack_decode"):
+            pixels = self._records_to_pixels(records, c, h, w)
+            if m is not None and not self._device_transform:
+                m.incr("pack_general" if pixels is None else "pack_fused")
+            data = (self._records_to_data(records, c, h, w)
+                    if pixels is None else None)
         out_names = list(self.layer.top)
+        if pixels is not None:
+            with span_of(m, "pack_transform"):
+                batch = {out_names[0]: self.transformer.fused(
+                    pixels, (c, h, w), draw, self.num_threads)}
         # device-transform split: ships uint8 + per-sample crop/flip aux.
         # Requires pixel payloads (encoded image or uint8 buffer) — a
         # float payload can't be losslessly narrowed, and a silent
         # per-batch fallback would emit inconsistent key sets that
         # combine_batches/iter_size would mis-merge, so fail fast.
-        if self._device_transform:
+        elif self._device_transform:
             bad = next((r for r in records
                         if not r[5] and isinstance(r[6], np.ndarray)
                         and r[6].dtype != np.uint8), None)
@@ -154,12 +172,12 @@ class DataSource:
                     f"payloads, but record {bad[0]!r} carries "
                     f"{bad[6].dtype} data — unset COS_DEVICE_TRANSFORM "
                     "for float-valued sources")
-            with span_of(self.metrics, "pack_transform"):
+            with span_of(m, "pack_transform"):
                 u8, aux = self.transformer.host_stage(data, draw=draw)
             batch = {out_names[0]: u8,
                      out_names[0] + DEVICE_AUX_SUFFIX: aux}
         else:
-            with span_of(self.metrics, "pack_transform"):
+            with span_of(m, "pack_transform"):
                 batch = {out_names[0]: self.transformer(data, draw=draw)}
         if len(out_names) > 1:
             batch[out_names[1]] = labels
@@ -257,9 +275,44 @@ class DataSource:
             out[k] = f(v, aux)
         return out
 
+    def _records_to_pixels(self, records, c, h, w):
+        """The records' pixels for `Transformer.fused`, or None where
+        the batch takes `_records_to_data` + `Transformer.__call__`
+        (`next_batch` has the rule).  Encoded records: one uint8
+        (n, c, h, w) array — float32 if an image is of another size and
+        was resampled, whose fractions a uint8 store would drop.  Raw
+        records: the payloads themselves, which the kernel reads in
+        place."""
+        if (self._device_transform or not records
+                or not self.transformer.fusable(c, h, w)):
+            return None
+        if all(r[5] for r in records):
+            from .. import native
+            jpegs = [r[6] for r in records]
+            kw = dict(channels=c, out_h=h, out_w=w,
+                      num_threads=self.num_threads)
+            try:
+                px = native.decode_batch(jpegs, out_dtype=np.uint8,
+                                         exact=True, **kw)
+                return px if px is not None else \
+                    native.decode_batch(jpegs, **kw)
+            except ValueError:
+                return None  # corrupt image: per-image path reports it
+        size = c * h * w
+
+        def raw_u8(r):
+            p = r[6]
+            return not r[5] and tuple(r[2:5]) == (c, h, w) and (
+                len(p) == size if isinstance(p, bytes) else
+                isinstance(p, np.ndarray) and p.dtype == np.uint8
+                and p.size == size)
+        return ([r[6] for r in records] if all(map(raw_u8, records))
+                else None)
+
     def _records_to_data(self, records, c, h, w) -> np.ndarray:
-        """The records' pixels as one (n, c, h, w) array, undecoded
-        payloads decoded: the `pack_decode` half of a pack."""
+        """The records' pixels as one float32 (n, c, h, w) array (uint8
+        under the device-transform split), undecoded payloads decoded:
+        the general path's `pack_decode`."""
         if all(r[5] for r in records):
             return self._decode_encoded_batch(records, c, h, w)
         data = np.zeros((len(records), c, h, w), np.float32)

@@ -190,6 +190,54 @@ class Transformer:
         crop = int(self.tp.crop_size)
         return (crop, crop) if crop else (h, w)
 
+    # -- the one-pass pack ------------------------------------------------
+    # __call__ above is the definition (and the tests' oracle); it writes
+    # the batch as float32 three or four times.  Where the records carry
+    # uint8 pixels, `fused` gets the same values, bit for bit, in one
+    # native pass from those pixels to the float32 batch.
+
+    def fusable(self, c: int, h: int, w: int) -> bool:
+        """Whether `fused` serves this configuration on (c, h, w)
+        inputs.  It leaves to __call__ what the kernel does not do —
+        a mean plane that is neither full-size nor large enough to
+        centre-crop — and what __call__ refuses (a mean_value count or
+        a crop that does not fit), so that the refusal stays its own."""
+        from .. import native
+        if not native.available():
+            return False
+        if len(self.tp.mean_value) not in (0, 1, c):
+            return False
+        oh, ow = self.output_hw(h, w)
+        if oh > h or ow > w:
+            return False
+        m = self.mean
+        return m is None or (
+            m.ndim == 3 and m.shape[0] in (1, c)
+            and (m.shape[1:] == (h, w)
+                 or (m.shape[1] >= oh and m.shape[2] >= ow)))
+
+    def fused(self, pixels, chw: Tuple[int, int, int],
+              draw: Optional[AugDraw] = None,
+              num_threads: int = 0) -> np.ndarray:
+        """`self(pixels as float32, draw)` in one pass
+        (`native.transform_batch`): `pixels` is an (N, C, H, W) uint8 or
+        float32 array, or N raw uint8 images of `chw`.  Only where
+        `fusable(*chw)`.  The output is a fresh array (a staged batch
+        is read by the device after `device_put` returns)."""
+        from .. import native
+        c, h, w = chw
+        if draw is None:
+            draw = self.draw(len(pixels), h, w)
+        hs, ws = draw.offs if draw.offs is not None else (None, None)
+        tp = self.tp
+        mean = (np.asarray(list(tp.mean_value), np.float32)
+                if tp.mean_value else self.mean)
+        return native.transform_batch(
+            pixels, chw=chw,
+            crop=int(tp.crop_size) if draw.offs is not None else 0,
+            h_off=hs, w_off=ws, mirror=draw.flip, mean=mean,
+            scale=float(tp.scale), num_threads=num_threads)
+
     # -- device-side transform (COS_DEVICE_TRANSFORM) ----------------------
     # TPU-first split of the Caffe transform: the host keeps only the
     # RNG-bearing byte moves (crop + mirror, on uint8), and the float

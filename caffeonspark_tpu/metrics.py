@@ -55,11 +55,17 @@ inside another span on the same thread inherits the outer one's.
               group_blocked   blocked  the pool's work queue is full
   worker      pack            busy     whole pack of one batch; ONE
                                        sample per packed batch
-              pack_decode     busy     child of pack: filling `data`
-                                       from the records (JPEG decode or
-                                       the raw per-record loop)
-              pack_transform  busy     child of pack: crop/mirror/mean
-                                       (Transformer / host_stage)
+              pack_decode     busy     child of pack: records -> pixels
+                                       (JPEG decode into uint8 planes;
+                                       for raw records, gathering the
+                                       payloads; on the general path
+                                       the float32 `data` array)
+              pack_transform  busy     child of pack: crop/mirror/mean/
+                                       scale into the batch (one native
+                                       pass from the uint8 pixels;
+                                       Transformer.__call__ on the
+                                       general path; host_stage under
+                                       COS_DEVICE_TRANSFORM)
               pack_cpu        series   time.thread_time() delta over the
                                        same interval as pack: seconds the
                                        worker was ON a CPU (the rest is
@@ -128,7 +134,11 @@ pipelined configuration (pool + background stager), where queue_wait
 measures pure starvation.
 
 Counters (dropped batches, ragged-tail records) and gauges (queue
-depths, sampled each step) ride along in the same summary.
+depths, sampled each step) ride along in the same summary.  `pack_fused`
+and `pack_general` count batches by the way their pack took
+(`DataSource.next_batch` has the rule): the one-pass kernel over uint8
+pixels, or float32 `data` through `Transformer.__call__`; the
+device-transform split's batches count as neither.
 
 The observability layer (caffeonspark_tpu/obs) builds on this format
 without a second bookkeeping path: `obs/prom.py` renders the same

@@ -6,10 +6,15 @@ as `libcos_native.so` (libjpeg decode + threaded NCHW transform).  The
 library builds on demand with g++ (Makefile equivalent: `make -C
 caffeonspark_tpu/native`); when the toolchain or libjpeg is missing,
 callers fall back to the cv2/numpy path in `data.transformer` /
-`data.source` — same semantics.  Measured (tools/simulator.py): on a
-single core the cv2 fallback is competitive (its SIMD decode beats
-plain libjpeg); the native path's win is its thread pool on multi-core
-executor hosts and independence from cv2.
+`data.source` — same semantics.  It links the system's libjpeg, which
+here is libjpeg-turbo (`libjpeg.so.62`), the SIMD decoder cv2 bundles
+too: the two decode a JPEG to the same pixels at about the same speed.
+What the native path adds is what happens around the decode: pixels
+stay uint8 in BGR planes, and one pass (`transform_batch`) crops,
+mirrors, subtracts the mean, scales and writes the float32 batch.
+Every call takes `num_threads`: 0 spreads a batch over the host's
+cores, and a transformer pool pins 1 (`tune_decode_threads`), which
+runs on the calling thread.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libcos_native.so")
 _SRC = os.path.join(_DIR, "cos_native.cpp")
 _LOG = logging.getLogger(__name__)
+_ABI_VERSION = 2        # cos_native_version() of the source beside this
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
@@ -45,8 +51,12 @@ def build(force: bool = False) -> bool:
             return True       # can't stat: trust the shipped .so
     if not os.path.exists(_SRC):
         return os.path.exists(_SO)
+    # to a name of this process's own, then renamed: several processes
+    # (test workers, executors on one host) may build at once, and none
+    # may load a half-written file
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO, "-ljpeg"]
+           _SRC, "-o", tmp, "-ljpeg"]
     # callers fall back to cv2 with the same semantics, but say so: a
     # silent None would hide which decoder a measurement ran on
     try:
@@ -61,6 +71,7 @@ def build(force: bool = False) -> bool:
                      "cv2", r.returncode, r.stderr.strip()[-400:])
         _build_failed = True
         return False
+    os.replace(tmp, _SO)
     return True
 
 
@@ -113,14 +124,16 @@ def _bind(lib) -> None:
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_long),
         ctypes.POINTER(ctypes.c_long), ctypes.c_int, ctypes.c_int,
         ctypes.c_int, ctypes.c_int,
-        ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int]
+        ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int, ctypes.c_int]
     lib.cos_transform_batch.restype = None
     lib.cos_transform_batch.argtypes = [
-        ctypes.POINTER(ctypes.c_float), ctypes.c_int, ctypes.c_int,
-        ctypes.c_int, ctypes.c_int, ctypes.c_int,
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p), ctypes.c_int,
+        ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+        ctypes.c_int,
         ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
         ctypes.POINTER(ctypes.c_ubyte),
-        ctypes.POINTER(ctypes.c_float), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_float), ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int,
         ctypes.c_float, ctypes.POINTER(ctypes.c_float), ctypes.c_int]
     lib.cos_crop_mirror_u8.restype = None
     lib.cos_crop_mirror_u8.argtypes = [
@@ -130,12 +143,16 @@ def _bind(lib) -> None:
         ctypes.POINTER(ctypes.c_ubyte),
         ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int]
     lib.cos_native_version.restype = ctypes.c_int
+    if lib.cos_native_version() != _ABI_VERSION:
+        # same symbols, other signatures: as stale as a missing symbol
+        raise AttributeError(
+            f"libcos_native.so is version {lib.cos_native_version()}, "
+            f"not {_ABI_VERSION}")
 
 
 def available() -> bool:
-    """COS_NATIVE=0 forces the cv2/numpy fallback — on few-core hosts
-    cv2's SIMD decode beats libjpeg (see module docstring), and an
-    ingest pool supplies its own inter-batch parallelism."""
+    """COS_NATIVE=0 forces the cv2/numpy fallback (same semantics; the
+    way to A/B the two on one host)."""
     if os.environ.get("COS_NATIVE", "").lower() in ("0", "false", "no"):
         return False
     return get_lib() is not None
@@ -143,13 +160,17 @@ def available() -> bool:
 
 def decode_batch(images: Sequence[bytes], *, channels: int, out_h: int,
                  out_w: int, num_threads: int = 0,
-                 out_dtype=np.float32) -> np.ndarray:
+                 out_dtype=np.float32,
+                 exact: bool = False) -> Optional[np.ndarray]:
     """JPEG bytes → (N, C, out_h, out_w) BGR planes.
 
-    out_dtype float32 (default) or uint8 — the uint8 path decodes
-    straight into byte planes for the device-transform split
-    (COS_DEVICE_TRANSFORM): no float buffer, no host cast pass, and
-    its truncating store equals `float_output.astype(uint8)` exactly."""
+    An image already out_h x out_w is only deinterleaved; any other is
+    resized bilinearly.  out_dtype float32 (default) or uint8.  The
+    uint8 store of a resized image truncates (it equals
+    `float_output.astype(uint8)` exactly, which is what the
+    device-transform split ships); a caller that wants the decoded
+    pixels themselves passes `exact=True` with uint8 and gets None as
+    soon as one image is of another size."""
     lib = get_lib()
     if lib is None:
         raise RuntimeError("native library unavailable")
@@ -158,64 +179,117 @@ def decode_batch(images: Sequence[bytes], *, channels: int, out_h: int,
     offsets = np.zeros(n, np.int64)
     sizes = np.asarray([len(b) for b in images], np.int64)
     np.cumsum(sizes[:-1], out=offsets[1:]) if n > 1 else None
+    args = [blob, offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
+            sizes.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
+            n, channels, out_h, out_w]
     if np.dtype(out_dtype) == np.uint8:
         out = np.empty((n, channels, out_h, out_w), np.uint8)
-        fn, ptr = lib.cos_decode_batch_u8, ctypes.c_ubyte
+        ok = lib.cos_decode_batch_u8(
+            *args, out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
+            num_threads, int(exact))
+        if ok < 0:
+            return None
     else:
         out = np.empty((n, channels, out_h, out_w), np.float32)
-        fn, ptr = lib.cos_decode_batch, ctypes.c_float
-    ok = fn(
-        blob, offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
-        sizes.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
-        n, channels, out_h, out_w,
-        out.ctypes.data_as(ctypes.POINTER(ptr)), num_threads)
+        ok = lib.cos_decode_batch(
+            *args, out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+            num_threads)
     if ok != n:
         raise ValueError(f"{n - ok}/{n} images failed to decode")
     return out
 
 
-def transform_batch(batch: np.ndarray, *, crop: int = 0,
+def _int32_within(name: str, v, n: int, hi: int) -> np.ndarray:
+    """Per-image crop origins as the kernel reads them: n int32 values
+    in [0, hi]."""
+    a = np.ascontiguousarray(v if v is not None else np.zeros(n), np.int32)
+    if a.shape != (n,) or (n and (a.min() < 0 or a.max() > hi)):
+        raise ValueError(f"{name}: need {n} offsets in [0, {hi}]")
+    return a
+
+
+def transform_batch(batch, *, chw: Optional[Tuple[int, int, int]] = None,
+                    crop: int = 0,
                     h_off: Optional[np.ndarray] = None,
                     w_off: Optional[np.ndarray] = None,
                     mirror: Optional[np.ndarray] = None,
                     mean: Optional[np.ndarray] = None,
                     scale: float = 1.0,
                     num_threads: int = 0) -> np.ndarray:
-    """Caffe transform on an (N, C, H, W) float32 batch (native)."""
+    """Caffe transform in one pass: crop + mirror + mean + scale from
+    the source pixels to a fresh (N, C, oh, ow) float32 batch, each
+    output pixel written once.
+
+    `batch` is an (N, C, H, W) array, uint8 or float32 (anything else
+    is cast to float32), or a sequence of N raw uint8 images of `chw` =
+    (C, H, W) each (`bytes`, or uint8 arrays: Datum payloads), which
+    the kernel reads in place.  `mean`: 1-D values (one, or one a
+    channel) or a (1 or C, mh, mw) plane, subtracted at the SOURCE
+    pixel as Caffe does: a full-size plane at each image's own crop
+    window, any other (mh >= oh, mw >= ow) at its centre window.  The
+    float operations are those of `Transformer.__call__` in the same
+    order, so the two agree bit for bit."""
     lib = get_lib()
     if lib is None:
         raise RuntimeError("native library unavailable")
-    batch = np.ascontiguousarray(batch, np.float32)
-    n, c, h, w = batch.shape
+    keep = []          # buffers the pointer array points into
+    if isinstance(batch, np.ndarray):
+        dt = np.uint8 if batch.dtype == np.uint8 else np.float32
+        batch = np.ascontiguousarray(batch, dt)
+        n, c, h, w = batch.shape
+        in_ptr, ptrs = batch.ctypes.data, None
+    else:
+        dt = np.uint8
+        c, h, w = chw
+        n = len(batch)
+        in_ptr, ptrs = None, (ctypes.c_void_p * n)()
+        for i, p in enumerate(batch):
+            if isinstance(p, np.ndarray):
+                if p.dtype != np.uint8:
+                    raise ValueError(f"image {i}: {p.dtype}, not uint8")
+                p = np.ascontiguousarray(p)
+                keep.append(p)
+                size, ptrs[i] = p.size, p.ctypes.data
+            else:
+                size = len(p)
+                ptrs[i] = ctypes.cast(ctypes.c_char_p(p), ctypes.c_void_p)
+            if size != c * h * w:
+                raise ValueError(
+                    f"image {i}: {size} bytes, not {c}x{h}x{w}")
+    crop = int(crop)
+    if crop < 0 or crop > h or crop > w:
+        raise ValueError(f"crop {crop} outside input {h}x{w}")
     oh = crop or h
     ow = crop or w
-    out = np.empty((n, c, oh, ow), np.float32)
-    zeros = np.zeros(n, np.int32)
-    h_off = np.ascontiguousarray(h_off if h_off is not None else zeros,
-                                 np.int32)
-    w_off = np.ascontiguousarray(w_off if w_off is not None else zeros,
-                                 np.int32)
+    h_off = _int32_within("h_off", h_off, n, h - oh)
+    w_off = _int32_within("w_off", w_off, n, w - ow)
     mir = np.ascontiguousarray(
         mirror if mirror is not None else np.zeros(n, np.uint8), np.uint8)
-    if mean is None:
-        mean_ptr, mode = None, 0
-    elif mean.ndim == 1:
+    if mir.shape != (n,):
+        raise ValueError(f"mirror: need {n} flags")
+    mode, mc, mh, mw = 0, 0, 0, 0
+    if mean is not None:
         mean = np.ascontiguousarray(mean, np.float32)
-        mean_ptr, mode = mean.ctypes.data_as(
-            ctypes.POINTER(ctypes.c_float)), 1
-    else:
-        mean = np.ascontiguousarray(mean, np.float32)
-        assert mean.shape == (c, oh, ow), (mean.shape, (c, oh, ow))
-        mean_ptr, mode = mean.ctypes.data_as(
-            ctypes.POINTER(ctypes.c_float)), 2
+        if mean.ndim == 1:
+            mode, mc = 1, mean.shape[0]
+        elif mean.ndim == 3:
+            mode, (mc, mh, mw) = 2, mean.shape
+            if (mh, mw) != (h, w) and (mh < oh or mw < ow):
+                raise ValueError(f"mean {mh}x{mw} under output {oh}x{ow}")
+        else:
+            raise ValueError(f"mean of {mean.ndim} dimensions")
+        if mc not in (1, c):
+            raise ValueError(f"{mc} mean channels for {c} channels")
+    out = np.empty((n, c, oh, ow), np.float32)
+    float_p = ctypes.POINTER(ctypes.c_float)
     lib.cos_transform_batch(
-        batch.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        n, c, h, w, crop,
+        in_ptr, ptrs, int(dt == np.uint8), n, c, h, w, crop,
         h_off.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
         w_off.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
         mir.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
-        mean_ptr, mode, ctypes.c_float(scale),
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)), num_threads)
+        None if mean is None else mean.ctypes.data_as(float_p),
+        mode, mc, mh, mw, ctypes.c_float(scale),
+        out.ctypes.data_as(float_p), num_threads)
     return out
 
 

@@ -164,10 +164,11 @@ def test_transformer_pool_drop_skip_and_abort():
         p.stop(join_timeout=5)
 
 
-def test_transformer_pool_ordered_draw_parity(tmp_path):
+@pytest.mark.parametrize("kind", ["encoded", "raw"])
+def test_transformer_pool_ordered_draw_parity(tmp_path, kind):
     """num_threads > 1 packing reproduces the inline path's
     augmentation stream exactly (crop offsets + mirror flips pre-drawn
-    in feed order by the dispatcher)."""
+    in feed order by the dispatcher), for JPEG and for raw records."""
     import cv2
     from caffeonspark_tpu.data import LmdbWriter, get_source
     from caffeonspark_tpu.data.synthetic import make_images
@@ -176,10 +177,14 @@ def test_transformer_pool_ordered_draw_parity(tmp_path):
     imgs, labels = make_images(48, seed=4)
     recs = []
     for i in range(48):
-        ok, buf = cv2.imencode(".jpg", (imgs[i, 0] * 255).astype(np.uint8))
-        recs.append((b"%06d" % i,
-                     Datum(encoded=True, data=bytes(buf),
-                           label=int(labels[i])).to_binary()))
+        u8 = (imgs[i, 0] * 255).astype(np.uint8)
+        if kind == "encoded":
+            ok, buf = cv2.imencode(".jpg", u8)
+            d = Datum(encoded=True, data=bytes(buf), label=int(labels[i]))
+        else:
+            d = Datum(channels=1, height=28, width=28, data=u8.tobytes(),
+                      label=int(labels[i]))
+        recs.append((b"%06d" % i, d.to_binary()))
     LmdbWriter(str(tmp_path / "lmdb")).write(recs)
     lp = LayerParameter.from_text(f'''
         name: "data" type: "MemoryData" top: "data" top: "label"
@@ -197,6 +202,13 @@ def test_transformer_pool_ordered_draw_parity(tmp_path):
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(a["data"], b["data"])
         np.testing.assert_array_equal(a["label"], b["label"])
+    # and both are what Transformer.__call__ makes of the same records
+    # on the same stream of draws
+    oracle = get_source(lp, phase_train=True, seed=9, resize=True)
+    records = list(oracle.records())
+    for k, b in enumerate(got):
+        data = oracle._records_to_data(records[8 * k:8 * k + 8], 1, 28, 28)
+        np.testing.assert_array_equal(b["data"], oracle.transformer(data))
 
 
 def test_pipelined_feed_small_shard_carries_tail(tmp_path):
@@ -602,6 +614,38 @@ def test_train_job_reports_every_series(tmp_path, monkeypatch):
               + stages["pack_transform"]["total_s"])
     assert halves <= stages["pack"]["total_s"]
     proc.stop()
+
+
+def test_train_job_packs_every_batch_in_one_pass(tmp_path, monkeypatch):
+    """An LMDB -train run through TransformerPool: every batch the pool
+    packs, train and validation, takes the one-pass kernel
+    (`pack_fused` = `pack` samples, `pack_general` absent), and the
+    counters reach the -pipeline_metrics dump."""
+    import json
+    from caffeonspark_tpu import native
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.data import get_source
+    from caffeonspark_tpu.processor import CaffeProcessor
+    if not native.available():
+        pytest.skip("native toolchain/libjpeg unavailable")
+
+    conf = _timeline_job(tmp_path, monkeypatch, max_iter=8,
+                         extra_solver="test_interval: 4\ntest_iter: 2\n",
+                         step_delay_ms=0)
+    dump = tmp_path / "pipeline_metrics.json"
+    monkeypatch.setenv("COS_PIPELINE_METRICS", str(dump))
+    proc = CaffeProcessor.instance(conf)
+    train_src = get_source(conf.train_data_layer(), phase_train=True)
+    val_src = get_source(conf.test_data_layer(), phase_train=False)
+    CaffeOnSpark().trainWithValidation(train_src, val_src, conf)
+    for pool in (proc._train_pool, proc._val_pool):
+        pool.join(timeout=5)
+    s = proc.metrics.summary()
+    assert s["stages"]["step"]["count"] == 8
+    assert s["counters"]["pack_fused"] == s["stages"]["pack"]["count"] >= 12
+    assert "pack_general" not in s["counters"]
+    proc.stop()
+    assert json.load(open(dump))["counters"]["pack_fused"] >= 12
 
 
 def test_pool_workers_account_for_their_lifetime():

@@ -30,7 +30,7 @@ def _jpegs(n=6, h=32, w=32):
 
 
 def test_version(lib):
-    assert lib.cos_native_version() == 1
+    assert lib.cos_native_version() == 2
 
 
 def test_decode_batch_matches_cv2(lib):
