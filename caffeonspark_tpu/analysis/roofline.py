@@ -39,9 +39,10 @@ MODEL_VERSION = 2          # v1: scripts/roofline.py inline model;
 
 ELEMENTWISE = {"ReLU", "Dropout", "Eltwise", "Scale", "Bias", "PReLU",
                "Sigmoid", "TanH", "ELU", "AbsVal", "Power", "Exp",
-               "Log", "BNLL"}
+               "Log", "BNLL", "SiLU"}
 MEMBOUND = {"Pooling", "LRN", "Softmax", "SoftmaxWithLoss", "Concat",
-            "Slice", "Flatten", "Reshape", "BatchNorm", "Accuracy"}
+            "Slice", "Flatten", "Reshape", "BatchNorm", "Accuracy",
+            "RMSNorm"}
 
 # bf16 peak TFLOP/s per chip by device_kind substring (public spec
 # sheets); MFU is reported against the RUNNING chip's peak.  One copy:

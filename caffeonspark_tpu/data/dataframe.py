@@ -104,6 +104,10 @@ class DataFrameSource(DataSource):
 
     def next_batch(self, rows: Sequence[Dict]) -> Dict[str, np.ndarray]:
         out: Dict[str, np.ndarray] = {}
+        if self.metrics is not None:
+            # typed tops have no one-pass kernel: every batch is packed
+            # the general way (PipelineMetrics counter, as images)
+            self.metrics.incr("pack_general")
         for top in self.tops:
             col = top.name
             vals = [r.get(col) for r in rows]

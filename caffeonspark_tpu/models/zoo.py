@@ -365,6 +365,104 @@ layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
     return parse_net_prototxt(t)
 
 
+def kanana2(vocab: int = 16032, hidden: int = 2048, heads: int = 32,
+            qk_nope: int = 128, qk_rope: int = 64, v_head: int = 128,
+            kv_lora_rank: int = 512, dense_width: int = 6144,
+            expert_width: int = 768, experts: int = 128, top_k: int = 6,
+            shared_experts: int = 2, experts_held: int = 16,
+            first_expert: int = 0, routed_scaling_factor: float = 2.448,
+            expert_layers: int = 5, seq: int = 4096, batch: int = 2,
+            rope_theta: float = 1e6, eps: float = 1e-6,
+            init_std: float = 0.02, recompute: bool = True
+            ) -> NetParameter:
+    """kakaocorp/kanana-2-30b-a3b (`model_type: deepseek_v3`) as one
+    chip's share of an expert-parallel deployment: pre-norm residual
+    blocks of latent attention (no q compression, rotary positions on
+    64 of the 192 q/k dims) and a SiLU-gated feed-forward that is dense
+    in the leading block and, in the `expert_layers` that follow,
+    `experts` sigmoid-routed experts of which this net holds
+    `experts_held` from `first_expert` on, plus `shared_experts` shared
+    ones as one gated FFN.  The defaults are the published widths with
+    the cut of `perfbench/configs/kanana2_30b_a3b.json` (16 of 128
+    experts, an eighth of the vocabulary, 1 + 5 of 48 layers);
+    `experts_held=experts`, `vocab=128256`, `expert_layers=47` is the
+    whole model.  Time-major (T, B) int tops `input_ids` /
+    `target_ids`; every block is one `recompute_block`; each expert
+    layer's `moe_stats` / `moe_rows` tops are net outputs."""
+    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+
+    def ip(name, bottom, top, n, tag):
+        return f"""
+layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
+  {tag} inner_product_param {{ num_output: {n} axis: 2 bias_term: false
+    {gauss} }} }}"""
+
+    t = f"""
+name: "Kanana2"
+layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
+  cos_data_param {{ batch_size: {batch}
+    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }}
+    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }} }} }}
+layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
+  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
+    {gauss} }} }}
+"""
+    h = "h0"
+    for i in range(expert_layers + 1):
+        p = f"L{i}"
+        tag = f'recompute_block: "{p}"' if recompute else ""
+        t += f"""
+layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
+  {tag} rms_norm_param {{ eps: {eps} }} }}
+layer {{ name: "{p}.attn" type: "LatentAttention" bottom: "{p}.n1"
+  top: "{p}.a" {tag}
+  attention_param {{ num_heads: {heads} causal: true
+    qk_nope_head_dim: {qk_nope} qk_rope_head_dim: {qk_rope}
+    v_head_dim: {v_head} kv_lora_rank: {kv_lora_rank}
+    rope_theta: {rope_theta} rms_norm_eps: {eps} {gauss} }} }}
+layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
+  top: "{p}.h1" {tag} }}
+layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
+  {tag} rms_norm_param {{ eps: {eps} }} }}"""
+        if i == 0:
+            t += ip(f"{p}.gate", f"{p}.n2", f"{p}.g", dense_width, tag)
+            t += ip(f"{p}.up", f"{p}.n2", f"{p}.u", dense_width, tag)
+            t += f"""
+layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g" {tag} }}
+layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
+  top: "{p}.gu" {tag} eltwise_param {{ operation: PROD }} }}"""
+            t += ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, tag)
+        else:
+            # blobs: router, bias (moves only the choice: frozen),
+            # W_gate, W_up, W_down, S_gate, S_up, S_down
+            t += f"""
+layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
+  top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
+  param {{ lr_mult: 1 }} param {{ lr_mult: 0 decay_mult: 0 }}
+  moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
+    top_k: {top_k} dispatch: "dropless" scoring: "sigmoid"
+    selection_bias: true routed_scaling_factor: {routed_scaling_factor}
+    gated: true shared_hidden_dim: {shared_experts * expert_width}
+    experts_held: {experts_held} first_expert: {first_expert}
+    {gauss} }} }}"""
+        t += f"""
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
+  top: "{p}.out" {tag} }}
+"""
+        h = f"{p}.out"
+    t += f"""
+layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
+  rms_norm_param {{ eps: {eps} }} }}"""
+    t += ip("head.logits", "head.n", "logits", vocab, "")
+    t += """
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
+  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
+"""
+    return parse_net_prototxt(t)
+
+
 def lstm_lm(vocab: int = 8801, d_model: int = 1000, seq: int = 20,
             batch_size: int = 32) -> NetParameter:
     """LRCN-shaped recurrent language model: Embed -> cont-gated LSTM

@@ -476,6 +476,57 @@ class Net:
                     w = 0.0
                 if w:
                     self.loss_weights[t] = w
+        self.recompute_blocks = self._recompute_blocks()
+
+    def _recompute_blocks(self) -> Dict[str, dict]:
+        """`recompute_block: "<name>"` on consecutive layers makes them
+        one block: in a TRAIN pass `apply` runs it under one
+        jax.checkpoint, so only the blobs that enter it are kept for
+        the backward pass and everything inside is computed again
+        there.  -> {first layer's name: {layers, inputs, exports}}."""
+        blocks: Dict[str, dict] = {}
+        seen: set = set()
+        run: List[LayerParameter] = []
+
+        def close():
+            if not run:
+                return
+            tag = run[0].recompute_block
+            names = {lp.name for lp in run}
+            made: set = set()
+            inputs: List[str] = []
+            for lp in run:
+                if L.get_op(lp.type).f32_stats:
+                    raise ValueError(
+                        f"recompute_block {tag!r}: layer {lp.name!r} "
+                        f"({lp.type}) updates running statistics in its "
+                        "forward pass and cannot be recomputed")
+                for b in lp.bottom:
+                    if b not in made and b not in inputs:
+                        inputs.append(b)
+                made.update(lp.top)
+            outside = {b for lp in self.compute_layers
+                       if lp.name not in names for b in lp.bottom}
+            keep = outside | set(self.output_blobs) | set(self.loss_weights)
+            blocks[run[0].name] = {
+                "layers": list(run), "inputs": inputs,
+                "exports": [t for t in dict.fromkeys(
+                    t for lp in run for t in lp.top) if t in keep]}
+            run.clear()
+
+        for lp in self.compute_layers:
+            tag = lp.recompute_block
+            if run and tag != run[0].recompute_block:
+                close()
+            if tag:
+                if not run and tag in seen:
+                    raise ValueError(
+                        f"recompute_block {tag!r} is not contiguous "
+                        f"(again at layer {lp.name!r})")
+                seen.add(tag)
+                run.append(lp)
+        close()
+        return blocks
 
     # ------------------------------------------------------------------
     def _fuse_relu_lrn(self, layers: List[LayerParameter], fused: set,
@@ -701,7 +752,7 @@ class Net:
         compute = (self.compute_layers if subset is None else
                    [lp for lp in self.compute_layers
                     if lp.name in subset])
-        for lp in compute:
+        def run_layer(lp, blobs, params):
             op = L.get_op(lp.type)
             ctx.layer_name = lp.name
             ctx.variant = self.layer_variants.get(lp.name)
@@ -762,9 +813,35 @@ class Net:
                     op.apply(ctx, lp, p, b), **kw)
                 tops = fn(lparams, bottoms)
             else:
-                tops = op.apply(ctx, lp, lparams, bottoms)
+                # the layer's name on every op it emits: device time can
+                # be summed by prototxt layer from a profiler trace
+                with jax.named_scope(lp.name):
+                    tops = op.apply(ctx, lp, lparams, bottoms)
             for name, val in zip(lp.top, tops):
                 blobs[name] = val
+
+        blocks = (self.recompute_blocks
+                  if train and subset is None and not self.remat else {})
+        skip: set = set()
+        for lp in compute:
+            if lp.name in skip:
+                continue
+            blk = blocks.get(lp.name)
+            if blk is None:
+                run_layer(lp, blobs, params)
+                continue
+
+            def block_fn(bparams, ins, blk=blk):
+                local, merged = dict(ins), {**params, **bparams}
+                for blp in blk["layers"]:
+                    run_layer(blp, local, merged)
+                return {n: local[n] for n in blk["exports"]}
+
+            own = {blp.name: params[blp.name] for blp in blk["layers"]
+                   if blp.name in self.param_layout}
+            blobs.update(jax.checkpoint(block_fn)(
+                own, {n: blobs[n] for n in blk["inputs"]}))
+            skip.update(blp.name for blp in blk["layers"])
         return blobs, ctx.state_out
 
     def loss(self, params: Params, inputs: Dict[str, Array], *,

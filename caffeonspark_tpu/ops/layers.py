@@ -1309,11 +1309,14 @@ def _on_batch_shards(kernel, x, *whole):
     return kernel(x, *whole)
 
 
-def _attention_dispatch(q, k, v, *, causal: bool):
+def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
     """Flash (Pallas, O(block·T) VMEM) on TPU when the shape tiles;
     under a multi-device mesh the kernel runs per-device via shard_map
     over (batch, heads); XLA einsum attention otherwise — numerically
-    the same math (tests/test_pallas.py flash parity)."""
+    the same math (tests/test_pallas.py flash parity).  q/k (B, H, T,
+    D), v (B, H, T, Dv): Dv need not equal D.  `mxu_dtype` is the
+    operand type of the kernel's products (None = float32 operands,
+    the kernel's exact mode); the einsum path takes XLA's precision."""
     from .pallas_kernels import flash_attention, pallas_enabled
     t = q.shape[2]
     interpret = _pallas_interpret()
@@ -1354,20 +1357,24 @@ def _attention_dispatch(q, k, v, *, causal: bool):
             fl = shard_map_nocheck(
                 functools.partial(flash_attention, causal=causal,
                                   block_q=128, block_k=128,
-                                  interpret=interpret),
+                                  interpret=interpret,
+                                  mxu_dtype=mxu_dtype),
                 mesh, (spec, spec, spec), spec)
             return fl(q, k, v)
         # shapes don't tile the mesh: einsum path below
     elif enabled and not _FLASH_MESH and t % 128 == 0:
-        return flash_attention(q, k, v, causal, 128, 128,
-                               interpret=interpret)
+        return flash_attention(q, k, v, causal, 128, 128, interpret,
+                               mxu_dtype)
     from ..parallel.sp import attention as _plain_attention
     return _plain_attention(q, k, v, causal=causal)
 
 
 @register("MultiHeadAttention", params=_mha_params)
 def _mha(ctx, lp, params, bottoms):
-    """Multi-head self-attention on time-major (T, B, D) input —
+    """Multi-head self-attention on time-major (T, B, D) input: one
+    fused `W_qkv` of equal heads, no positions, no norm (the latent
+    variant with rotary positions is `LatentAttention` below; both end
+    in `_attention_dispatch`) —
     extension beyond the reference (SURVEY §5.7: it has no attention at
     all).  Under jit on a mesh, GSPMD partitions the attention einsums
     along whatever axes the activations carry; for explicit
@@ -1397,11 +1404,162 @@ def _mha(ctx, lp, params, bottoms):
     return [jnp.einsum("tbe,de->tbd", o, params[1])]
 
 
+def rms_norm(x, scale, eps):
+    """x / sqrt(mean(x², last axis) + eps) · scale, statistics in
+    float32 whatever the compute dtype."""
+    x32 = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * inv).astype(x.dtype) * scale
+
+
+def _rms_norm_params(lp, shapes):
+    rp = lp.rms_norm_param
+    f = (rp.scale_filler if rp.has("scale_filler")
+         else FillerParameter(type="constant", value=1.0))
+    return [("scale", (int(shapes[0][-1]),), f)]
+
+
+@register("RMSNorm", params=_rms_norm_params)
+def _rms_norm(ctx, lp, params, bottoms):
+    return [rms_norm(bottoms[0], params[0], float(lp.rms_norm_param.eps))]
+
+
+@register("SiLU")
+def _silu(ctx, lp, params, bottoms):
+    return [jax.nn.silu(bottoms[0])]
+
+
+def rope_adjacent(x, theta: float):
+    """Rotary positions on adjacent pairs of the last axis; x is
+    (T, ...) with the position on axis 0.  Pair i of width-w x turns
+    by the angle t · theta^(-2i/w)."""
+    w = x.shape[-1]
+    t = x.shape[0]
+    inv = 1.0 / (theta ** (jnp.arange(0, w, 2, dtype=jnp.float32) / w))
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    shape = (t,) + (1,) * (x.ndim - 2) + (w // 2,)
+    cos = jnp.cos(ang).reshape(shape).astype(x.dtype)
+    sin = jnp.sin(ang).reshape(shape).astype(x.dtype)
+    xe, xo = x[..., 0::2], x[..., 1::2]
+    out = jnp.stack([xe * cos - xo * sin, xo * cos + xe * sin], axis=-1)
+    return out.reshape(x.shape)
+
+
+def _mla_params(lp, shapes):
+    ap = lp.attention_param
+    d = math.prod(shapes[0][2:]) if len(shapes[0]) > 2 else 1
+    h = int(ap.num_heads)
+    nope, rope = int(ap.qk_nope_head_dim), int(ap.qk_rope_head_dim)
+    vd, r = int(ap.v_head_dim), int(ap.kv_lora_rank)
+    if not (h and nope and rope and vd and r) or rope % 2:
+        raise ValueError(
+            f"LatentAttention {lp.name!r} needs num_heads, "
+            "qk_nope_head_dim, an even qk_rope_head_dim, v_head_dim "
+            "and kv_lora_rank")
+    wf = _filler(ap.weight_filler if ap.has("weight_filler") else None,
+                 "xavier")
+    one = FillerParameter(type="constant", value=1.0)
+    return [("W_q", (h * (nope + rope), d), wf),
+            ("W_kva", (r + rope, d), wf),
+            ("kv_norm", (r,), one),
+            ("W_kvb", (h * (nope + vd), r), wf),
+            ("W_o", (d, h * vd), wf)]
+
+
+@register("LatentAttention", params=_mla_params)
+def _mla(ctx, lp, params, bottoms):
+    """Multi-head latent attention without q compression (deepseek_v3
+    with `q_lora_rank: null`) on time-major (T, B, D) input:
+
+        q = x W_q                 -> H x (nope + rope)
+        [c_kv, k_rope] = x W_kva  -> kv_lora_rank + rope (k_rope shared
+                                     by all heads)
+        RMSNorm(c_kv) W_kvb       -> H x (k_nope + v)
+        k = [k_nope, RoPE(k_rope)],  q = [q_nope, RoPE(q_rope)]
+        o = softmax(q kᵀ / sqrt(nope + rope), causal) v  -> H x v
+        y = o W_o
+
+    RoPE turns adjacent pairs by position t (axis 0) with base
+    `rope_theta`.  q/k are nope + rope wide and v is v_head_dim wide;
+    the attention itself is `_attention_dispatch`, the one MultiHead-
+    Attention takes.  On the TPU its kernel multiplies in one bfloat16
+    pass with float32 accumulation — XLA's default precision for the
+    projections around it — unless the layer is pinned to float32 by
+    an autotune variant."""
+    ap = lp.attention_param
+    w_q, w_kva, kv_norm, w_kvb, w_o = params
+    x = bottoms[0]
+    t, b = x.shape[0], x.shape[1]
+    h = int(ap.num_heads)
+    nope, rope = int(ap.qk_nope_head_dim), int(ap.qk_rope_head_dim)
+    vd, r = int(ap.v_head_dim), int(ap.kv_lora_rank)
+    theta = float(ap.rope_theta)
+    prec = ctx.precision()
+    xf = x.reshape(t, b, -1)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("tbd,ed->tbe", xf, w_q,
+                       precision=prec).reshape(t, b, h, nope + rope)
+        kva = jnp.einsum("tbd,ed->tbe", xf, w_kva, precision=prec)
+        c_kv = rms_norm(kva[..., :r], kv_norm, float(ap.rms_norm_eps))
+        k_rope = rope_adjacent(kva[..., r:], theta)         # (T, B, rope)
+        kvb = jnp.einsum("tbr,er->tbe", c_kv, w_kvb,
+                         precision=prec).reshape(t, b, h, nope + vd)
+        q = jnp.concatenate(
+            [q[..., :nope], rope_adjacent(q[..., nope:], theta)], axis=-1)
+        k = jnp.concatenate(
+            [kvb[..., :nope],
+             jnp.broadcast_to(k_rope[:, :, None, :], (t, b, h, rope))],
+            axis=-1)
+        v = kvb[..., nope:]
+        # (T, B, H, ·) -> (B, H, T, ·)
+        q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
+        mxu = (jnp.bfloat16 if prec is None and q.dtype == jnp.float32
+               and jax.default_backend() == "tpu" else None)
+        o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
+                                mxu_dtype=mxu)
+        o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * vd)
+        return [jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)]
+
+
+def _moe_held(mp):
+    """(first expert, experts held) of a layer: all of them unless the
+    layer is told its share."""
+    e = int(mp.num_experts)
+    held = int(mp.experts_held) or e
+    first = int(mp.first_expert)
+    if first + held > e:
+        raise ValueError(
+            f"moe_param: experts [{first}, {first + held}) of {e}")
+    return first, held
+
+
 def _moe_params(lp, shapes):
     mp = lp.moe_param
     d = int(shapes[0][-1])
     e = int(mp.num_experts)
     h = int(mp.hidden_dim)
+    if mp.dispatch == "dropless":
+        wf = _filler(mp.weight_filler if mp.has("weight_filler")
+                     else None, "xavier")
+        _, held = _moe_held(mp)
+        specs = [("router", (d, e), wf)]
+        if mp.selection_bias:
+            specs.append(("bias", (e,),
+                          FillerParameter(type="constant", value=0.0)))
+        if mp.gated:
+            specs += [("W_gate", (held, d, h), wf),
+                      ("W_up", (held, d, h), wf),
+                      ("W_down", (held, h, d), wf)]
+        else:
+            specs += [("W1", (held, d, h), wf), ("W2", (held, h, d), wf)]
+        hs = int(mp.shared_hidden_dim)
+        if hs:
+            specs += [("S_gate", (d, hs), wf), ("S_up", (d, hs), wf),
+                      ("S_down", (hs, d), wf)]
+        return specs
+    if mp.dispatch != "capacity":
+        raise ValueError(f"moe_param.dispatch {mp.dispatch!r}: "
+                         "expected capacity or dropless")
     if mp.has("weight_filler"):
         wf = _filler(mp.weight_filler)
         return [("router", (d, e), wf), ("W1", (e, d, h), wf),
@@ -1420,8 +1578,12 @@ def _moe_params(lp, shapes):
 @register("MixtureOfExperts", params=_moe_params)
 def _moe(ctx, lp, params, bottoms):
     """Top-k routed expert FFN on (..., D) input — extension beyond the
-    reference, built the way TPU MoE stacks are (Switch/GShard-style
-    fixed expert capacity):
+    reference.  `moe_param.dispatch: "dropless"` is `_moe_dropless`
+    below (sorted assignments, grouped products over the experts held,
+    sigmoid or softmax scoring, gated and shared experts).  The default,
+    `"capacity"`, is what this docstring describes from here on, built
+    the way TPU MoE stacks were (Switch/GShard-style fixed expert
+    capacity):
 
     * each token's top-k experts get it IF the expert still has room;
       capacity C = ceil(k·N/E · capacity_factor) is a static shape, so
@@ -1437,6 +1599,8 @@ def _moe(ctx, lp, params, bottoms):
       layer's second `loss_weight`.
     """
     mp = lp.moe_param
+    if mp.dispatch == "dropless":
+        return _moe_dropless(ctx, lp, params, bottoms)
     router, w1, w2 = params
     x = bottoms[0]
     lead = x.shape[:-1]
@@ -1482,6 +1646,147 @@ def _moe(ctx, lp, params, bottoms):
         frac = onehot.mean(axis=0)              # (E,)
         mean_p = probs.mean(axis=0)
         tops.append((e * jnp.sum(frac * mean_p)).astype(jnp.float32))
+    return tops
+
+
+_MOE_ROW_TILE = 512     # the grouped product's row tile on the TPU
+
+
+def _moe_chunk_rows(n: int, k: int, held: int, e: int) -> int:
+    """Rows of sorted assignments one pass of the grouped products
+    takes: 4/3 of the k·N·held/E an even router sends to the held
+    experts, in whole tiles of 512, at most all k·N.  Not a capacity:
+    a step whose held assignments outnumber it takes further passes."""
+    want = -(-4 * k * n * held // (3 * e))
+    return min(k * n, -(-want // _MOE_ROW_TILE) * _MOE_ROW_TILE)
+
+
+def _moe_dropless(ctx, lp, params, bottoms):
+    """Routed experts without a capacity, for a layer that may hold
+    only some of the experts.
+
+    Router: s = sigmoid(x W_g) (or softmax) over ALL `num_experts`, in
+    float32 at HIGHEST precision (a routing choice must not turn on a
+    bfloat16 rounding); the k experts with the largest s + bias are
+    chosen; weights s_i / sum(s chosen) x routed_scaling_factor.
+
+    Dispatch: the k·N assignments are sorted by expert, those of
+    experts held elsewhere last.  The sorted rows are taken in passes
+    of `_moe_chunk_rows` rows: gather the tokens, three grouped
+    products (`lax.ragged_dot`: on the TPU a Mosaic grouped matmul
+    that visits only the tiles its groups cover), weight, scatter-add.
+    A pass that holds no held assignment is skipped (`lax.cond`); an
+    even router needs one, every token on one held expert needs them
+    all, and nothing is ever dropped.  Each pass is recomputed in the
+    backward pass, so the layer keeps no k·N-row activation.
+
+    What the absent experts would add is left out: the result is this
+    share's part of the routed sum plus the shared experts."""
+    mp = lp.moe_param
+    names = [n for n, _, _ in _moe_params(lp, [bottoms[0].shape])]
+    pd = dict(zip(names, params))
+    x = bottoms[0]
+    lead, d = x.shape[:-1], x.shape[-1]
+    e, k = int(mp.num_experts), max(1, int(mp.top_k))
+    first, held = _moe_held(mp)
+    gated = bool(mp.gated)
+    xf = x.reshape(-1, d)
+    n = xf.shape[0]
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.matmul(xf.astype(jnp.float32),
+                            pd["router"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        if mp.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        elif mp.scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            raise ValueError(f"moe_param.scoring {mp.scoring!r}")
+        sel = scores
+        if "bias" in pd:
+            sel = scores + lax.stop_gradient(
+                pd["bias"].astype(jnp.float32))[None, :]
+        _, topi = lax.top_k(sel, k)                          # (N, k)
+        topv = jnp.take_along_axis(scores, topi, axis=1)
+        if k > 1:
+            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+        gates = (topv * float(mp.routed_scaling_factor)).reshape(-1)
+        # token-major flattening: assignment a belongs to token a // k
+        local = topi.reshape(-1) - first
+        on_held = (local >= 0) & (local < held)
+        group = jnp.where(on_held, local, held)              # absent last
+        order = jnp.argsort(group, stable=True)
+        counts = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        ends = jnp.cumsum(counts)
+        starts = ends - counts
+        total = ends[-1]
+
+    rows = _moe_chunk_rows(n, k, held, e)
+    n_pass = -(-(k * n) // rows)
+    order = jnp.pad(order, (0, n_pass * rows - k * n))
+    prec = ctx.precision()
+    w_in = (pd["W_gate"], pd["W_up"]) if gated else (pd["W1"],)
+    w_out = pd["W_down"] if gated else pd["W2"]
+
+    def one_pass(acc, lo, xf, gates, w_in, w_out):
+        def run(acc):
+            idx = lax.dynamic_slice(order, (lo,), (rows,))
+            valid = (lo + jnp.arange(rows)) < total
+            idx = jnp.where(valid, idx, 0)
+            tok = idx // k
+            sizes = (jnp.clip(ends - lo, 0, rows)
+                     - jnp.clip(starts - lo, 0, rows))
+            xs = jnp.where(valid[:, None], xf[tok], 0)
+            hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
+                                 precision=prec)
+            if gated:
+                hid = jax.nn.silu(hid) * lax.ragged_dot(
+                    xs, w_in[1].astype(xs.dtype), sizes, precision=prec)
+            else:
+                hid = jax.nn.relu(hid)
+            ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
+                                precision=prec)
+            # rows past the last group hold whatever the kernel left
+            # (NaN bit patterns included): they are cut out BEFORE the
+            # product, so that neither the sum nor the gates' gradient
+            # (d/dg = ys) ever sees them
+            ys = jnp.where(valid[:, None], ys, 0) \
+                * gates[idx][:, None].astype(ys.dtype)
+            return acc.at[tok].add(ys)
+
+        return lax.cond(lo < total, run, lambda a: a, acc)
+
+    with jax.named_scope("moe.experts"):
+        # one scanned body: the backward pass sums the experts' weight
+        # gradients in the scan's carry (one buffer a blob, not one a
+        # pass) and keeps of each pass only the running sum it started
+        # from
+        step = jax.checkpoint(one_pass)
+        gates = gates.astype(xf.dtype)
+        routed, _ = lax.scan(
+            lambda acc, lo: (step(acc, lo, xf, gates, w_in, w_out), None),
+            jnp.zeros_like(xf), jnp.arange(n_pass, dtype=jnp.int32) * rows)
+
+    out = routed
+    if "S_gate" in pd:
+        with jax.named_scope("moe.shared"):
+            hs = jax.nn.silu(jnp.matmul(xf, pd["S_gate"], precision=prec)) \
+                * jnp.matmul(xf, pd["S_up"], precision=prec)
+            out = out + jnp.matmul(hs, pd["S_down"], precision=prec)
+    tops = [out.reshape(lead + (d,))]
+    if len(lp.top) > 1:
+        cf = counts.astype(jnp.float32)
+        # dropped = held assignments less the rows the passes cover;
+        # the passes cover all k·N sorted rows, so this reads 0 unless
+        # a later change to the pass arithmetic breaks that
+        covered = jnp.minimum(total, n_pass * rows)
+        tops.append(jnp.stack([
+            jnp.max(cf) / jnp.maximum(jnp.mean(cf), 1.0),
+            total.astype(jnp.float32) / (k * n),
+            (total - covered).astype(jnp.float32)]))
+    if len(lp.top) > 2:
+        tops.append(counts.astype(jnp.float32))
     return tops
 
 

@@ -432,7 +432,7 @@ _NEG_INF = -1e30          # finite mask value: -inf NaNs the m-corr path
 
 
 def _online_softmax_step(q, kb, vb, m, l, acc, *, sm_scale: float,
-                         causal: bool, q_pos, k_pos):
+                         causal: bool, q_pos, k_pos, mxu_dtype=None):
     """One online-softmax accumulation (the flash/ring shared algebra):
     scores for (q, kb) fold into the (m, l, acc) carry.  The m_safe
     guard makes fully-masked-so-far rows accumulate exact zeros (a
@@ -448,14 +448,22 @@ def _online_softmax_step(q, kb, vb, m, l, acc, *, sm_scale: float,
     p = jnp.exp(s - m_safe)
     corr = jnp.exp(m - m_safe)
     l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+    if mxu_dtype is not None:
+        p = p.astype(mxu_dtype)
     acc_new = acc * corr + jnp.dot(
         p, vb, preferred_element_type=jnp.float32)
     return m_new, l_new, acc_new
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      sm_scale: float, causal: bool, block_k: int):
-    q = q_ref[0].astype(jnp.float32)            # (block_q, D)
+                      sm_scale: float, causal: bool, block_k: int,
+                      mxu_dtype=None):
+    # operands of every product: float32 (exact, several MXU passes)
+    # unless the caller asks for one bfloat16 pass with float32
+    # accumulation, which is what XLA's default precision gives the
+    # einsum path on the TPU
+    cd = mxu_dtype or jnp.float32
+    q = q_ref[0].astype(cd)                     # (block_q, D)
     t = k_ref.shape[1]
     block_q = q.shape[0]
     qi = pl.program_id(1)
@@ -464,13 +472,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     def body(i, carry):
         m, l, acc = carry
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
+        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
         k_pos = i * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         return _online_softmax_step(q, kb, vb, m, l, acc,
                                     sm_scale=sm_scale, causal=causal,
-                                    q_pos=q_pos, k_pos=k_pos)
+                                    q_pos=q_pos, k_pos=k_pos,
+                                    mxu_dtype=mxu_dtype)
 
     if causal:
         # K/V blocks starting past this q block's last row are fully
@@ -480,7 +489,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         n_k = t // block_k
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    a0 = jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
+    a0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_k, body, (m0, l0, a0))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)
@@ -488,9 +497,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, *, sm_scale: float,
-                          causal: bool, block_q: int):
-    kb = k_ref[0].astype(jnp.float32)           # (block_k, D)
-    vb = v_ref[0].astype(jnp.float32)
+                          causal: bool, block_q: int, mxu_dtype=None):
+    cd = mxu_dtype or jnp.float32
+    kb = k_ref[0].astype(cd)                    # (block_k, D)
+    vb = v_ref[0].astype(cd)
     t = q_ref.shape[1]
     block_k = kb.shape[0]
     ki = pl.program_id(1)
@@ -499,9 +509,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     def body(i, carry):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        dob = do_ref[0, pl.ds(i * block_q, block_q), :].astype(
-            jnp.float32)
+        qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(cd)
+        dob = do_ref[0, pl.ds(i * block_q, block_q), :].astype(cd)
         lse = lse_ref[0, pl.ds(i * block_q, block_q)]   # (block_q, 1)
         dlt = delta_ref[0, pl.ds(i * block_q, block_q)]
         s = jnp.dot(qb, kb.T,
@@ -511,28 +520,30 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         p = jnp.exp(s - lse)                     # exact probabilities
-        dv_new = dv + jnp.dot(p.T, dob,
+        dv_new = dv + jnp.dot(p.astype(cd).T, dob,
                               preferred_element_type=jnp.float32)
         dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
         ds = p * (dp - dlt) * sm_scale
-        dk_new = dk + jnp.dot(ds.T, qb,
+        dk_new = dk + jnp.dot(ds.astype(cd).T, qb,
                               preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
-    z = jnp.zeros((block_k, kb.shape[-1]), jnp.float32)
+    zk = jnp.zeros((block_k, kb.shape[-1]), jnp.float32)
+    zv = jnp.zeros((block_k, vb.shape[-1]), jnp.float32)
     # causal: q blocks ending before this k block's first row see only
     # masked scores — start at the diagonal
     i0 = (ki * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(i0, t // block_q, body, (z, z))
+    dk, dv = jax.lax.fori_loop(i0, t // block_q, body, (zk, zv))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, *, sm_scale: float,
-                         causal: bool, block_k: int):
-    qb = q_ref[0].astype(jnp.float32)            # (block_q, D)
-    dob = do_ref[0].astype(jnp.float32)
+                         causal: bool, block_k: int, mxu_dtype=None):
+    cd = mxu_dtype or jnp.float32
+    qb = q_ref[0].astype(cd)                     # (block_q, D)
+    dob = do_ref[0].astype(cd)
     lse = lse_ref[0]                             # (block_q, 1)
     dlt = delta_ref[0]
     t = k_ref.shape[1]
@@ -542,8 +553,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         jnp.int32, (block_q, block_k), 0)
 
     def body(i, dq):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
+        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
         s = jnp.dot(qb, kb.T,
                     preferred_element_type=jnp.float32) * sm_scale
         if causal:
@@ -553,7 +564,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         p = jnp.exp(s - lse)
         dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
         ds = p * (dp - dlt) * sm_scale
-        return dq + jnp.dot(ds, kb, preferred_element_type=jnp.float32)
+        return dq + jnp.dot(ds.astype(cd), kb,
+                            preferred_element_type=jnp.float32)
 
     if causal:
         n_k = ((qi + 1) * block_q + block_k - 1) // block_k
@@ -579,9 +591,22 @@ def _flash_specs(block, d, t):
     return qspec, kvspec, vec, vec_full
 
 
+def _flash_compiler_params(block_bytes: int, interpret: bool):
+    """Mosaic's default scoped VMEM (16 MiB on the v5e) holds the
+    double-buffered blocks of a call up to T ~ 2k at 128-wide heads;
+    beyond that the call asks for what its blocks need (the chip has
+    128 MiB).  {} below the default, so small calls compile as before."""
+    need = 2 * block_bytes + (4 << 20)
+    if interpret or need <= (16 << 20):
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(need, 100 << 20))}
+
+
 def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
-                    interpret):
+                    interpret, mxu_dtype=None):
     bh, t, d = q.shape
+    dv = v.shape[-1]
     if t % block_q or t % block_k:
         # a truncated grid would leave the output/lse tail rows
         # uninitialized garbage — fail loudly (mirrors
@@ -591,103 +616,137 @@ def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
             f"flash_attention needs T divisible by the blocks: "
             f"t={t} % block_q={block_q}, t={t} % block_k={block_k}")
     kern = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
-                             causal=causal, block_k=block_k)
-    qspec, kvspec, vec, _ = _flash_specs(block_q, d, t)
+                             causal=causal, block_k=block_k,
+                             mxu_dtype=mxu_dtype)
+    qspec, kspec, vec, _ = _flash_specs(block_q, d, t)
+    ospec, vspec, _, _ = _flash_specs(block_q, dv, t)
+    isz = q.dtype.itemsize
     out, lse = pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)),
         grid=(bh, t // block_q),
-        in_specs=[qspec, kvspec, kvspec],
-        out_specs=(qspec, vec),
+        in_specs=[qspec, kspec, vspec],
+        out_specs=(ospec, vec),
         interpret=interpret,
+        name="cos_flash_fwd",
+        **_flash_compiler_params(
+            t * (d + dv) * isz + block_q * (d + dv + 128) * 4, interpret),
     )(q, k, v)
     return out, lse[:, :, 0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: int = BLOCK_Q,
                     block_k: int = BLOCK_K,
-                    interpret: bool = False) -> jax.Array:
-    """Fused blockwise attention, (B, H, T, D) → (B, H, T, D).
+                    interpret: bool = False,
+                    mxu_dtype=None) -> jax.Array:
+    """Fused blockwise attention, q/k (B, H, T, D), v (B, H, T, Dv) →
+    (B, H, T, Dv); Dv may differ from D (latent attention: 192-wide
+    q/k, 128-wide v).
 
     Same math as parallel.sp.attention (softmax(QKᵀ/√D)V, optional
     causal mask); O(block·T) VMEM instead of an O(T²) HBM score
     matrix, exact (not approximate) via online softmax.  Requires T
     divisible by the block sizes — callers fall back to the XLA path
-    otherwise (ops.layers._mha)."""
+    otherwise (ops.layers._mha).  `mxu_dtype` (None = float32
+    operands) is the operand type of the products; the accumulators,
+    the softmax statistics and the outputs stay float32/input dtype."""
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
-    qf, kf, vf = (x.reshape(b * h, t, d) for x in (q, k, v))
+    qf, kf = (x.reshape(b * h, t, d) for x in (q, k))
+    vf = v.reshape(b * h, t, v.shape[-1])
     out, _ = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
-                             block_k, interpret)
-    return out.reshape(b, h, t, d)
+                             block_k, interpret, mxu_dtype)
+    return out.reshape(b, h, t, v.shape[-1])
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
+                   mxu_dtype):
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
-    qf, kf, vf = (x.reshape(b * h, t, d) for x in (q, k, v))
+    qf, kf = (x.reshape(b * h, t, d) for x in (q, k))
+    vf = v.reshape(b * h, t, v.shape[-1])
     out, lse = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
-                               block_k, interpret)
-    return out.reshape(b, h, t, d), (qf, kf, vf, out, lse)
+                               block_k, interpret, mxu_dtype)
+    return out.reshape(b, h, t, v.shape[-1]), (qf, kf, vf, out, lse)
 
 
 def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
                     block_q: int, block_k: int, interpret: bool,
-                    out_dtype=None):
+                    out_dtype=None, mxu_dtype=None):
     """dq, dk, dv for one (q-group, kv-block) attention pair from the
     saved stats — the flash backward building block.  All operands
-    flattened (B·H, T, D) / (B·H, T); `causal` masks with LOCAL
+    flattened (B·H, T, D) / (B·H, T), v and dO (B·H, T, Dv); `causal`
+    masks with LOCAL
     positions, so callers composing cross-shard pairs (ring backward,
     parallel/sp.py) pass causal=True only for the diagonal pair and
     causal=False for fully-visible ones.  `out_dtype` overrides the
     gradient dtype — accumulating callers pass float32 so bf16 inputs
     don't round each per-hop partial before the sum."""
     bh, t, d = qf.shape
+    dv_w = vf.shape[-1]
     sm_scale = 1.0 / math.sqrt(d)
     lse = lse[:, :, None]          # (bh, t, 1): see _flash_specs
     delta = delta[:, :, None]
-    qspec, kvspec, vec, vec_full = _flash_specs(block_q, d, t)
-    kspec_b, _, _, _ = _flash_specs(block_k, d, t)
+    qspec, kfull, vec, vec_full = _flash_specs(block_q, d, t)
+    dospec, vfull, _, _ = _flash_specs(block_q, dv_w, t)
+    kspec_b, qfull, _, _ = _flash_specs(block_k, d, t)
+    vspec_b, dofull, _, _ = _flash_specs(block_k, dv_w, t)
+    isz = qf.dtype.itemsize
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, block_k=block_k),
+                          causal=causal, block_k=block_k,
+                          mxu_dtype=mxu_dtype),
         out_shape=jax.ShapeDtypeStruct((bh, t, d),
                                        out_dtype or qf.dtype),
         grid=(bh, t // block_q),
-        in_specs=[qspec, kvspec, kvspec, qspec, vec, vec],
+        in_specs=[qspec, kfull, vfull, dospec, vec, vec],
         out_specs=qspec,
         interpret=interpret,
+        name="cos_flash_bwd_dq",
+        **_flash_compiler_params(
+            t * (d + dv_w) * isz + block_q * (2 * d + dv_w + 256) * 4,
+            interpret),
     )(qf, kf, vf, dof, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q),
+                          causal=causal, block_q=block_q,
+                          mxu_dtype=mxu_dtype),
         out_shape=(jax.ShapeDtypeStruct((bh, t, d),
                                         out_dtype or kf.dtype),
-                   jax.ShapeDtypeStruct((bh, t, d),
+                   jax.ShapeDtypeStruct((bh, t, dv_w),
                                         out_dtype or vf.dtype)),
         grid=(bh, t // block_k),
-        in_specs=[kvspec, kspec_b, kspec_b, kvspec, vec_full, vec_full],
-        out_specs=(kspec_b, kspec_b),
+        in_specs=[qfull, kspec_b, vspec_b, dofull, vec_full, vec_full],
+        out_specs=(kspec_b, vspec_b),
         interpret=interpret,
+        name="cos_flash_bwd_dkv",
+        # the two per-row statistics travel as (T, 1) columns, which
+        # VMEM pads to 128 lanes
+        **_flash_compiler_params(
+            t * (d + dv_w) * isz + 2 * t * 128 * 4
+            + block_k * 2 * (d + dv_w) * 4, interpret),
     )(qf, kf, vf, dof, lse, delta)
     return dq, dk, dv
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, mxu_dtype, res,
+                   do):
     qf, kf, vf, out, lse = res
     bh, t, d = qf.shape
-    dof = do.reshape(bh, t, d)
+    dof = do.reshape(bh, t, vf.shape[-1])
     # delta = rowsum(dO ∘ O): cheap elementwise+reduce, XLA fuses it
     delta = jnp.sum(dof.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     dq, dk, dv = flash_bwd_block(qf, kf, vf, dof, lse, delta,
                                  causal=causal, block_q=block_q,
-                                 block_k=block_k, interpret=interpret)
-    shape = do.shape
-    return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape))
+                                 block_k=block_k, interpret=interpret,
+                                 mxu_dtype=mxu_dtype)
+    lead = do.shape[:3]
+    return (dq.reshape(lead + (d,)), dk.reshape(lead + (d,)),
+            dv.reshape(do.shape))
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

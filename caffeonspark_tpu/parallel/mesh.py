@@ -199,9 +199,12 @@ def tp_param_specs(net, *, min_features: int = TP_MIN_FEATURES
                 rp = lp.recurrent_param
                 if int(rp.num_output) * 4 >= min_features:
                     spec = P("tp", None)     # (4N, D) gate split
-            elif lp.type == "MixtureOfExperts" and bname in ("W1",
-                                                             "W2"):
-                spec = P("ep", None, None)   # expert-dim split
+            elif lp.type == "MixtureOfExperts" and bname in (
+                    "W1", "W2", "W_gate", "W_up", "W_down"):
+                # expert-dim split: the capacity dispatch's W1/W2 and
+                # the dropless dispatch's gated experts alike (router,
+                # selection bias and shared experts stay replicated)
+                spec = P("ep", None, None)
             specs[lname][bname] = spec
     return specs
 

@@ -545,30 +545,84 @@ class CoSDataParameter(Message):
 
 class MoEParameter(Message):
     """Extension (no reference equivalent): top-k routed
-    mixture-of-experts FFN with fixed expert capacity; the expert
-    dimension shards over the ep mesh axis.  A second top, when
-    declared, emits the load-balancing auxiliary loss (weight it via
-    the layer's second loss_weight)."""
+    mixture-of-experts FFN; the expert dimension shards over the ep
+    mesh axis.  Two dispatches share the message:
+
+    * `dispatch: "capacity"` (default; what an old prototxt gets):
+      softmax router, ReLU experts `W1`/`W2`, fixed expert capacity
+      C = ceil(k N / E * capacity_factor), overflow dropped.  A second
+      top, when declared, emits the load-balancing auxiliary loss
+      (weight it via the layer's second loss_weight).
+    * `dispatch: "dropless"`: assignments are sorted by expert and run
+      through grouped matrix products over the experts this layer
+      HOLDS; nothing is dropped whatever the imbalance.  `scoring`
+      picks the router (`softmax` | `sigmoid`), `selection_bias` adds
+      a bias blob that moves only the top-k choice (give it lr_mult 0),
+      the chosen scores are normalised over the k and multiplied by
+      `routed_scaling_factor`; `gated` experts are SiLU-gated
+      (`W_gate`/`W_up`/`W_down`), `shared_hidden_dim` > 0 adds shared
+      experts as one gated FFN every token passes.  `experts_held` /
+      `first_expert` tell the layer which experts live here (0 = all):
+      it routes over all `num_experts` and computes the part of the
+      sum that experts [first_expert, first_expert + experts_held)
+      give, plus the shared experts.  Tops after the first: a (3,)
+      vector [rows per held expert max/mean, share of the k N
+      assignments on held experts, dropped assignments], then the
+      rows per held expert."""
     FIELDS = [
         Field(1, "num_experts", UINT32, default=4),
         Field(2, "hidden_dim", UINT32, default=256),
         Field(3, "weight_filler", MESSAGE, message=FillerParameter),
         Field(4, "top_k", UINT32, default=1),
         Field(5, "capacity_factor", FLOAT, default=1.25),
+        Field(6, "dispatch", STRING, default="capacity"),
+        Field(7, "scoring", STRING, default="softmax"),
+        Field(8, "selection_bias", BOOL, default=False),
+        Field(9, "routed_scaling_factor", FLOAT, default=1.0),
+        Field(10, "gated", BOOL, default=False),
+        Field(11, "shared_hidden_dim", UINT32, default=0),
+        Field(12, "experts_held", UINT32, default=0),
+        Field(13, "first_expert", UINT32, default=0),
     ]
 
 
 class AttentionParameter(Message):
-    """Extension (no reference equivalent): multi-head self-attention for
-    long-context models.  The layer computes fused O(T²) attention that
-    GSPMD partitions over whatever mesh axes the activations carry; for
-    explicit O(T/S)-memory ring execution over the sp axis use
-    `parallel.sp.ring_attention` directly."""
+    """Extension (no reference equivalent): self-attention on
+    time-major (T, B, D) input.
+
+    `MultiHeadAttention`: one fused `W_qkv` of `num_heads` equal heads
+    of `head_dim`, no positions.  `LatentAttention` (deepseek_v3's
+    multi-head latent attention without q compression): `W_q` gives
+    `num_heads` x (`qk_nope_head_dim` + `qk_rope_head_dim`); `W_kva`
+    gives a `kv_lora_rank`-wide latent and ONE `qk_rope_head_dim`-wide
+    rotary key shared by all heads; RMSNorm(latent) `W_kvb` gives
+    `num_heads` x (`qk_nope_head_dim` keys + `v_head_dim` values);
+    rotary positions (adjacent pairs, `rope_theta`) turn the rope
+    parts; q/k are nope + rope wide, v is `v_head_dim` wide.  Both
+    types share one attention dispatch (flash kernel on the TPU when
+    the shape tiles, XLA einsums otherwise); GSPMD partitions the
+    einsums over whatever mesh axes the activations carry."""
     FIELDS = [
         Field(1, "num_heads", UINT32, default=1),
         Field(2, "head_dim", UINT32, default=64),
         Field(3, "causal", BOOL, default=False),
         Field(4, "weight_filler", MESSAGE, message=FillerParameter),
+        Field(5, "kv_lora_rank", UINT32, default=0),
+        Field(6, "qk_nope_head_dim", UINT32, default=0),
+        Field(7, "qk_rope_head_dim", UINT32, default=0),
+        Field(8, "v_head_dim", UINT32, default=0),
+        Field(9, "rope_theta", FLOAT, default=10000.0),
+        Field(10, "rms_norm_eps", FLOAT, default=1e-6),
+    ]
+
+
+class RMSNormParameter(Message):
+    """Extension: y = x / sqrt(mean(x^2 over the last axis) + eps) *
+    scale, one `scale` blob the width of the last axis (constant 1
+    unless `scale_filler` says otherwise)."""
+    FIELDS = [
+        Field(1, "eps", FLOAT, default=1e-6),
+        Field(2, "scale_filler", MESSAGE, message=FillerParameter),
     ]
 
 
@@ -594,6 +648,12 @@ class LayerParameter(Message):
         Field(148, "cos_data_param", MESSAGE, message=CoSDataParameter),
         Field(149, "attention_param", MESSAGE, message=AttentionParameter),
         Field(150, "moe_param", MESSAGE, message=MoEParameter),
+        Field(151, "rms_norm_param", MESSAGE, message=RMSNormParameter),
+        # consecutive layers that give the same non-empty name form one
+        # block whose activations are recomputed in the backward pass
+        # (Net.apply: one jax.checkpoint around the block); COS_REMAT
+        # stays the per-layer override it is
+        Field(152, "recompute_block", STRING),
         # layer-specific params (upstream numbers)
         Field(100, "transform_param", MESSAGE,
               message=TransformationParameter),

@@ -411,7 +411,9 @@ class Solver:
                                   net.param_layout[ln]]
                              for ln in stat_layers}
             lr = learning_rate(self.param, state.iter)
-            params2, state2 = self._apply_update(params, grads, state, lr)
+            with jax.named_scope("update"):
+                params2, state2 = self._apply_update(params, grads, state,
+                                                     lr)
             # BatchNorm running stats updated by the forward pass(es)
             params2 = net.merge_forward_state(params2, fwd_state)
             outputs["lr"] = lr

@@ -1,7 +1,8 @@
 """Analytic FLOP estimates for a constructed Net.
 
 Counts the multiply-accumulate work of the parametrised layers
-(Convolution / Deconvolution / InnerProduct / LSTM-style weights) from
+(Convolution / Deconvolution / InnerProduct / LSTM-style weights,
+MultiHeadAttention / LatentAttention, MixtureOfExperts) from
 the weight blob shapes and inferred top shapes — the >99% of CaffeNet's
 arithmetic that lands on the MXU.  Elementwise layers (ReLU, LRN,
 Pooling, Softmax) are ignored; they are HBM-bound, not FLOP-bound.
@@ -59,6 +60,24 @@ def layer_forward_flops(net) -> dict:
                 * int(ap.head_dim)
             out[lp.name] = total
             continue
+        if lp.type == "LatentAttention":
+            # the five projections per (t, b) position, plus causal
+            # attention with nope + rope wide q/k and v_head_dim wide
+            # v: the masked half of QK^T and PV is not work
+            t_s, b_s = first_top[0], first_top[1]
+            ap = lp.attention_param
+            total = 2 * t_s * b_s * sum(
+                prod(ps) for (_, ps, _) in specs if len(ps) == 2)
+            total += (2 * b_s * int(ap.num_heads) * t_s * t_s // 2
+                      * (int(ap.qk_nope_head_dim)
+                         + int(ap.qk_rope_head_dim)
+                         + int(ap.v_head_dim)))
+            out[lp.name] = total
+            continue
+        if lp.type == "MixtureOfExperts":
+            out[lp.name] = _moe_forward_flops(lp, dict(
+                (n, ps) for (n, ps, _) in specs), prod(first_top[:-1]))
+            continue
         for (pname, pshape, _) in specs:
             if len(pshape) < 2 or "bias" in pname:
                 continue
@@ -79,6 +98,29 @@ def layer_forward_flops(net) -> dict:
                 total += 2 * prod(first_top) * prod(pshape[1:])
         out[lp.name] = total
     return out
+
+
+def _moe_forward_flops(lp, shapes: dict, n: int) -> int:
+    """Router over all experts for every token, plus the expert
+    products a token's k assignments touch.  `capacity` dispatch runs
+    every expert on its full (C, D) buffer; `dropless` runs, for an
+    even router, the k x held / experts of the assignments that fall on
+    the experts this layer holds, plus the shared experts on every
+    token."""
+    from math import ceil
+    mp = lp.moe_param
+    e, k = int(mp.num_experts), max(1, int(mp.top_k))
+    total = 2 * n * prod(shapes["router"])
+    if mp.dispatch == "dropless":
+        held = int(mp.experts_held) or e
+        per_expert = sum(prod(ps[1:]) for nm, ps in shapes.items()
+                         if nm.startswith("W"))
+        total += int(2 * n * k * held / e * per_expert)
+        total += 2 * n * sum(prod(ps) for nm, ps in shapes.items()
+                             if nm.startswith("S_"))
+        return total
+    cap = max(1, int(ceil(k * n / e * float(mp.capacity_factor))))
+    return total + 2 * cap * (prod(shapes["W1"]) + prod(shapes["W2"]))
 
 
 def train_step_flops(net) -> int:

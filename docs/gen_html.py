@@ -15,6 +15,7 @@ PAGES = {
     "parity.html": "../PARITY.md",
     "survey.html": "../SURVEY.md",
     "architecture.html": "architecture.md",
+    "llm_layers.html": "llm_layers.md",
     "benchmarks.html": "benchmarks.md",
     "migration.html": "migration.md",
     "tuning.html": "tuning.md",
