@@ -97,15 +97,57 @@ def stage_background(default: Optional[bool] = None) -> bool:
     return jax.default_backend() != "cpu"
 
 
-def tune_decode_threads(src, pool_width: int):
-    """Under a multi-worker transformer pool, inter-batch parallelism
-    replaces the native decoder's intra-batch thread pool: N workers
-    each spawning the decoder's default ncores threads oversubscribes
-    the host (measured 2.6x slower packs on a 2-core box).  Pin
-    per-call decode to one thread unless the caller set num_threads
-    explicitly."""
-    if pool_width > 1 and getattr(src, "num_threads", None) == 0:
-        src.num_threads = 1
+# cores a pool leaves alone: the solver thread's and the stager's
+RESERVED_CORES = 2
+_CGROUP_CPU_MAX = "/sys/fs/cgroup/cpu.max"
+
+
+def usable_cores() -> int:
+    """CPUs this process may run on, as far as it can observe: its
+    affinity mask (`os.cpu_count()` where the platform has none), cut
+    by a cgroup-v2 CPU quota (`cpu.max`: "quota period", whole CPUs of
+    it) where one is set."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except (AttributeError, OSError):
+        cores = os.cpu_count() or 1
+    try:
+        with open(_CGROUP_CPU_MAX) as f:
+            quota, period = f.read().split()[:2]
+        if quota != "max":
+            cores = min(cores, int(quota) // int(period))
+    except (OSError, ValueError, ZeroDivisionError):
+        pass
+    return max(1, cores)
+
+
+def pack_thread_share(cores: int, pool_width: int,
+                      local_procs: int = 1) -> int:
+    """Threads one pool worker's native calls run on: the process's
+    part of `cores` (`local_procs` processes of the job share the
+    host), less RESERVED_CORES, split over the pool's workers; never
+    under 1."""
+    mine = cores // local_procs
+    return max(1, (mine - RESERVED_CORES) // pool_width)
+
+
+def tune_decode_threads(src, pool_width: int, local_procs: int = 1):
+    """Give a pooled source its share of the host's cores.  A pool
+    parallelises across batches (`pool_width` workers, a whole batch
+    each) and every native call parallelises inside one (`num_threads`
+    over the batch's images).  At `num_threads` 0, "nobody chose", each
+    call would take every core, and N workers doing that oversubscribe
+    the host (measured 2.6x slower packs on a 2-core box).  So each
+    worker gets `pack_thread_share` of what `usable_cores` sees: 5 of a
+    13-core host's under a pool of 2, and still 1 on the 2-core box,
+    which runs on the calling thread.  A caller that set `num_threads`
+    keeps it (Spark's source spec passes its own).  Every pooled source
+    comes through here; a validation pool's gets one train worker's
+    share.  The thread count cannot change a value: images are
+    independent and the draw is made before the pack."""
+    if getattr(src, "num_threads", None) == 0:
+        src.num_threads = pack_thread_share(usable_cores(), pool_width,
+                                            local_procs)
 
 
 class FeedQueue:
@@ -512,20 +554,23 @@ class PipelinedFeed:
     callers (mini_cluster): a reader thread streams `src` records into
     a bounded feed queue (one mark_epoch_end per epoch, shuffled at
     TRAIN like DataSource.batches), the pool packs them off-thread.
-    Iterate for ordered batches; close() tears the threads down."""
+    Iterate for ordered batches; close() tears the threads down.
+    `local_procs`: processes of this job on this host, which share its
+    cores (`tune_decode_threads`)."""
 
     def __init__(self, src, *, loop: bool = True,
                  shuffle: Optional[bool] = None, num_threads: int = 2,
                  metrics=None,
                  should_stop: Optional[Callable[[], bool]] = None,
-                 capacity: int = SOURCE_QUEUE_CAPACITY):
+                 capacity: int = SOURCE_QUEUE_CAPACITY,
+                 local_procs: int = 1):
         self._closed = False
         ext = should_stop or (lambda: False)
         self.feed = FeedQueue(capacity)
         self.feed.metrics, self.feed.batch_size = metrics, src.batch_size
         self._reader_error: dict = {}
         do_shuffle = src.phase_train if shuffle is None else shuffle
-        tune_decode_threads(src, num_threads)
+        tune_decode_threads(src, num_threads, local_procs)
 
         def read():
             # NOTE: mirrors DataSource.batches()'s record loop (shuffle

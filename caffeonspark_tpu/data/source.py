@@ -86,7 +86,9 @@ class DataSource:
         self.num_ranks = num_ranks
         self.seed = seed
         self.resize = resize
-        self.num_threads = num_threads  # 0 = native decoder's default
+        # threads a native call spreads a batch over; 0 = every core,
+        # until a pool gives its workers their share (tune_decode_threads)
+        self.num_threads = num_threads
         self.batch_size = self._batch_size()
         self.transformer = Transformer(
             layer.transform_param if layer.has("transform_param") else None,
@@ -146,6 +148,8 @@ class DataSource:
         c, h, w = self.image_dims()
         labels = np.asarray([r[1] for r in records], np.float32)
         m = self.metrics
+        if m is not None:
+            m.gauge("pack_threads", self.num_threads)
         with span_of(m, "pack_decode"):
             pixels = self._records_to_pixels(records, c, h, w)
             if m is not None and not self._device_transform:

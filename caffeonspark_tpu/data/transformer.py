@@ -222,8 +222,9 @@ class Transformer:
         """`self(pixels as float32, draw)` in one pass
         (`native.transform_batch`): `pixels` is an (N, C, H, W) uint8 or
         float32 array, or N raw uint8 images of `chw`.  Only where
-        `fusable(*chw)`.  The output is a fresh array (a staged batch
-        is read by the device after `device_put` returns)."""
+        `fusable(*chw)`.  The output is an array nobody else refers to
+        (a staged batch is read by the device after `device_put`
+        returns)."""
         from .. import native
         c, h, w = chw
         if draw is None:

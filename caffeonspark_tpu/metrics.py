@@ -68,8 +68,19 @@ inside another span on the same thread inherits the outer one's.
                                        COS_DEVICE_TRANSFORM)
               pack_cpu        series   time.thread_time() delta over the
                                        same interval as pack: seconds the
-                                       worker was ON a CPU (the rest is
-                                       GIL or scheduler wait)
+                                       WORKER's own thread was on a CPU
+                                       (the rest is GIL or scheduler
+                                       wait).  A native call runs one
+                                       share of its work on the worker
+                                       and the rest on helper threads
+                                       this does not count: CPUs a pack
+                                       kept busy ~ pack_cpu x
+                                       pack_threads
+              pack_threads    gauge    one sample per pack: threads its
+                                       native calls were given (0 =
+                                       nobody chose, every core; a pool
+                                       gives each worker its share of
+                                       the cores, tune_decode_threads)
               pack_starved    starved  no work queued
               pack_blocked    blocked  results window full (_deposit)
   (any)       stack           busy     np.stack of K packed batches into

@@ -84,6 +84,17 @@ def build_argparser() -> argparse.ArgumentParser:
     return p
 
 
+def local_ranks(args) -> int:
+    """How many of the job's ranks run on this host, as far as a rank
+    can tell from its own arguments: all `-cluster` of them when the
+    rendezvous is on the loopback (the supervisor's single-host mode)
+    or there is none (elastic ranks on a shared output), else 1 —
+    a pod's `-local_ranks` never reaches the rank."""
+    n = args.cluster or 1
+    host = (args.server or "").rsplit("/", 1)[-1].rsplit(":", 1)[0]
+    return n if host in ("", "localhost", "127.0.0.1", "[::1]") else 1
+
+
 class MiniCluster:
     def __init__(self, args):
         from .parallel import ParallelSolver, build_mesh, distributed_init
@@ -362,7 +373,8 @@ class MiniCluster:
         if nthreads > 0:
             feed = PipelinedFeed(src, loop=True, num_threads=nthreads,
                                  metrics=pmetrics,
-                                 should_stop=lambda: self._stop)
+                                 should_stop=lambda: self._stop,
+                                 local_procs=local_ranks(self.args))
             raw_batches = iter(feed)
         else:
             def _timed_batches():

@@ -12,9 +12,14 @@ too: the two decode a JPEG to the same pixels at about the same speed.
 What the native path adds is what happens around the decode: pixels
 stay uint8 in BGR planes, and one pass (`transform_batch`) crops,
 mirrors, subtracts the mean, scales and writes the float32 batch.
-Every call takes `num_threads`: 0 spreads a batch over the host's
-cores, and a transformer pool pins 1 (`tune_decode_threads`), which
-runs on the calling thread.
+Every call takes `num_threads` and spreads the batch's images over
+that many threads, the calling thread one of them (so 1 spawns
+nothing): 0 means one per hardware thread, and a transformer pool gives
+each of its workers a share of the cores the process may use
+(`data/queue_runner.py:tune_decode_threads`; the share is never under
+1, which is what a 2-core box gets: two workers that each took both
+cores packed 2.6x slower there).  Images are independent and each
+output pixel is written once, so the thread count changes no value.
 """
 
 from __future__ import annotations
@@ -23,8 +28,10 @@ import ctypes
 import logging
 import os
 import subprocess
+import sys
 import threading
-from typing import Optional, Sequence, Tuple
+import weakref
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -158,11 +165,93 @@ def available() -> bool:
     return get_lib() is not None
 
 
+# a pack's batch-sized arrays (`BufferPool`): a block under POOL_MIN_BYTES
+# is malloc's own business (it is not mapped and unmapped on every use),
+# and at most POOL_KEEP free blocks a size wait for the next pack
+POOL_KEEP = 4
+POOL_MIN_BYTES = 32 << 20
+
+
+class BufferPool:
+    """Batch-sized arrays whose memory comes back instead of going to
+    the OS.  `take(shape, dtype)` is `np.empty` for the caller; when the
+    array it returned is garbage — when today's allocator would free it
+    — its memory goes on a free list and the next `take` of that size
+    writes into pages already touched.  A fresh 475 MB batch costs 4 us a
+    page in first touches (PERF.md section 5), and a feed that maps and
+    unmaps 2.5 GB/s of them outran the chip host's reclaim until the
+    machine's 40 GiB were gone (PR 27).
+
+    As safe as the free it replaces: whoever still reads the array (the
+    TPU runtime holds a reference until its transfer is complete) keeps
+    it alive, and memory still reachable through another view of it is
+    recognised by its reference count and left to the allocator.  That
+    rests on CPython's counting (an array dies when its last reference
+    goes, `sys.getrefcount` of a block only the pool holds is 2), so a
+    new pool tries it once, and on an interpreter that counts another
+    way every `take` is `np.empty`."""
+
+    def __init__(self):
+        self._free: Dict[int, List[np.ndarray]] = {}
+        self._reuses = self._counts_as_expected()
+        if not self._reuses:
+            _LOG.warning("cos_native: reference counts are not CPython's; "
+                        "pack buffers are not reused")
+
+    def take(self, shape, dtype) -> np.ndarray:
+        dtype = np.dtype(dtype)
+        nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+        if nbytes < POOL_MIN_BYTES or not self._reuses:
+            return np.empty(shape, dtype)
+        return self._lend(nbytes, shape, dtype)
+
+    def _lend(self, nbytes: int, shape, dtype) -> np.ndarray:
+        free = self._free.setdefault(nbytes, [])
+        while True:
+            try:                # list.pop/append are atomic: no lock
+                base = free.pop()       # for a finalizer to deadlock on
+            except IndexError:
+                base = np.empty(nbytes, np.uint8)
+                break
+            # ours alone? (`base` here + getrefcount's argument); a view
+            # someone still holds counts one more: that memory is theirs
+            if sys.getrefcount(base) == 2:
+                break
+        out = base.view(dtype).reshape(shape)
+        weakref.finalize(out, self._give_back, base).atexit = False
+        return out
+
+    def _give_back(self, base: np.ndarray) -> None:
+        free = self._free.get(base.nbytes)
+        if free is not None and len(free) < POOL_KEEP:
+            free.append(base)
+
+    def _counts_as_expected(self) -> bool:
+        """Take, drop, take: the same memory.  Drop it while a slice
+        lives, take: other memory."""
+        a = self._lend(64, (64,), np.uint8)
+        addr = a.ctypes.data
+        del a
+        b = self._lend(64, (64,), np.uint8)
+        came_back = b.ctypes.data == addr
+        part = b[:1]
+        del b
+        c = self._lend(64, (64,), np.uint8)
+        left_alone = c.ctypes.data != addr
+        del part, c
+        self._free.clear()
+        return came_back and left_alone
+
+
+_buffers = BufferPool()
+
+
 def decode_batch(images: Sequence[bytes], *, channels: int, out_h: int,
                  out_w: int, num_threads: int = 0,
                  out_dtype=np.float32,
                  exact: bool = False) -> Optional[np.ndarray]:
-    """JPEG bytes → (N, C, out_h, out_w) BGR planes.
+    """JPEG bytes → (N, C, out_h, out_w) BGR planes, in an array of
+    its own (batch-sized ones come from the `BufferPool`).
 
     An image already out_h x out_w is only deinterleaved; any other is
     resized bilinearly.  out_dtype float32 (default) or uint8.  The
@@ -183,14 +272,14 @@ def decode_batch(images: Sequence[bytes], *, channels: int, out_h: int,
             sizes.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
             n, channels, out_h, out_w]
     if np.dtype(out_dtype) == np.uint8:
-        out = np.empty((n, channels, out_h, out_w), np.uint8)
+        out = _buffers.take((n, channels, out_h, out_w), np.uint8)
         ok = lib.cos_decode_batch_u8(
             *args, out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
             num_threads, int(exact))
         if ok < 0:
             return None
     else:
-        out = np.empty((n, channels, out_h, out_w), np.float32)
+        out = _buffers.take((n, channels, out_h, out_w), np.float32)
         ok = lib.cos_decode_batch(
             *args, out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
             num_threads)
@@ -217,7 +306,8 @@ def transform_batch(batch, *, chw: Optional[Tuple[int, int, int]] = None,
                     scale: float = 1.0,
                     num_threads: int = 0) -> np.ndarray:
     """Caffe transform in one pass: crop + mirror + mean + scale from
-    the source pixels to a fresh (N, C, oh, ow) float32 batch, each
+    the source pixels to an (N, C, oh, ow) float32 batch that nobody
+    else refers to (a batch-sized one from the `BufferPool`), each
     output pixel written once.
 
     `batch` is an (N, C, H, W) array, uint8 or float32 (anything else
@@ -280,7 +370,7 @@ def transform_batch(batch, *, chw: Optional[Tuple[int, int, int]] = None,
             raise ValueError(f"mean of {mean.ndim} dimensions")
         if mc not in (1, c):
             raise ValueError(f"{mc} mean channels for {c} channels")
-    out = np.empty((n, c, oh, ow), np.float32)
+    out = _buffers.take((n, c, oh, ow), np.float32)
     float_p = ctypes.POINTER(ctypes.c_float)
     lib.cos_transform_batch(
         in_ptr, ptrs, int(dt == np.uint8), n, c, h, w, crop,

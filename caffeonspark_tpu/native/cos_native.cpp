@@ -5,9 +5,10 @@
 // and caffe::DataTransformer via FloatDataTransformer
 // (jni/JniFloatDataTransformer.cpp) — feeding preallocated NCHW float
 // buffers.  Exposed as a plain C ABI for ctypes (no pybind11 in this
-// image).  Threading: one worker per hardware thread across the batch
-// (the transformer-thread-pool analog of CaffeProcessor.scala:54-55);
-// with one thread the work runs on the caller's own.
+// image).  Threading: every call spreads the batch's images over
+// `num_threads` threads, the caller's own among them (0 = one per
+// hardware thread; a transformer pool hands each of its workers a share
+// of the cores, data/queue_runner.py:tune_decode_threads).
 //
 // Layout notes: decode emits BGR channel order (OpenCV convention, which
 // Caffe models expect) as planar CHW, uint8 or float32.  An image already
@@ -31,21 +32,20 @@
 namespace {
 
 // `worker` drains a shared counter of n items: run it on num_threads
-// threads (0 = one per hardware thread, never more than n).  One thread
-// means the calling thread, so a pool that pins 1 spawns nothing.
+// threads (0 = one per hardware thread, never more than n).  The
+// calling thread is one of them and the other num_threads - 1 are
+// spawned: one thread spawns nothing, and the caller's own CPU clock
+// (a pool worker's `pack_cpu`) sees its share of the work.
 template <typename F>
 void run_workers(int num_threads, int n, F worker) {
   int nthreads = num_threads > 0
                      ? num_threads
                      : static_cast<int>(std::thread::hardware_concurrency());
   nthreads = std::max(1, std::min(nthreads, n));
-  if (nthreads == 1) {
-    worker();
-    return;
-  }
-  std::vector<std::thread> pool;
-  for (int t = 0; t < nthreads; ++t) pool.emplace_back(worker);
-  for (auto& t : pool) t.join();
+  std::vector<std::thread> helpers;
+  for (int t = 1; t < nthreads; ++t) helpers.emplace_back(worker);
+  worker();
+  for (auto& t : helpers) t.join();
 }
 
 struct JpegErr {

@@ -19,6 +19,7 @@ train_state/test_state stages.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -262,6 +263,13 @@ def prefuse_conv_bias_eligible(layers: Sequence[LayerParameter],
               if j > ci and conv.top[0] in l2.bottom
               and l2 is not relu_lp]
     return others in ([], [lrn_lp])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _constant_blobs(spec, dtype):
+    """{key: blob of `shape` filled with `value`} for `spec`'s (key,
+    (shape, value)) pairs, as one program (`Net.init`)."""
+    return {k: jnp.full(shape, value, dtype) for k, (shape, value) in spec}
 
 
 class Net:
@@ -683,17 +691,33 @@ class Net:
 
     # ------------------------------------------------------------------
     def init(self, key: Array) -> Params:
-        """Initialize all learnable blobs (filler semantics)."""
+        """Initialize all learnable blobs (filler semantics).  Filled
+        blob by blob from Python, every distinct fill is a program of
+        its own to compile or to fetch from the persistent cache (74
+        for ResNet-50, 3.7 s of every warm start).  The constant
+        fillers, most of the blobs, have no arithmetic a compiler could
+        order another way and are filled by one program; a random
+        filler inside a larger program rounds differently in the last
+        bit, so those stay one program a shape."""
         from .ops.fillers import fill
         from .ops.layers import stable_hash
         params: Params = {}
+        constants = {}
         for lname, specs in self.param_layout.items():
             lkey = jax.random.fold_in(key, stable_hash(lname))
             blobs = {}
             for i, (bname, shape, filler) in enumerate(specs):
-                blobs[bname] = fill(jax.random.fold_in(lkey, i), filler,
-                                    shape, self.dtype)
+                if (filler.type or "constant") == "constant":
+                    blobs[bname] = None         # keeps its place
+                    constants[lname, bname] = (
+                        tuple(int(d) for d in shape), float(filler.value))
+                else:
+                    blobs[bname] = fill(jax.random.fold_in(lkey, i),
+                                        filler, shape, self.dtype)
             params[lname] = blobs
+        filled = _constant_blobs(tuple(constants.items()), self.dtype)
+        for (lname, bname), blob in filled.items():
+            params[lname][bname] = blob
         return params
 
     def input_names(self) -> List[str]:

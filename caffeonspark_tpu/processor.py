@@ -432,7 +432,9 @@ class CaffeProcessor:
                 vsrc = self.val_source
                 # one pack worker: validation packs ahead between
                 # rounds and is off the latency-critical path — extra
-                # threads would only pressure the train pool
+                # workers would only pressure the train pool, and its
+                # native calls get one train worker's share of the cores
+                tune_decode_threads(vsrc, nthreads)
                 self._val_pool = TransformerPool(
                     self.queues[1], vsrc.batch_size,
                     pack=vsrc.pack_batch, draw_fn=vsrc.make_draw_fn(),

@@ -30,6 +30,7 @@ for a mesh.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, NamedTuple, Optional, Tuple
 
@@ -49,7 +50,9 @@ class OptState(NamedTuple):
     history2: Params            # second moment (Adam) / delta accum (AdaDelta)
 
 
+@functools.partial(jax.jit, static_argnames="dtype")
 def _zeros_like_params(params: Params, dtype=None) -> Params:
+    # one program for the whole tree, like Net.init
     return jax.tree_util.tree_map(
         lambda p: jnp.zeros_like(p, dtype=dtype), params)
 

@@ -89,14 +89,15 @@ def _mean_file(tmp_path, shape, name):
 MEANS = ("none", "value1", "value3", "file_full", "file_out")
 GRID = list(itertools.product(
     ("TRAIN", "TEST"), ("crop", "nocrop"), MEANS, (1.0, 1 / 256),
-    (1, 3), ("encoded", "raw")))
+    (1, 3), ("encoded", "raw"), (1, 3)))
 
 
-@pytest.mark.parametrize("phase,crop,mean,scale,c,kind", GRID)
+@pytest.mark.parametrize("phase,crop,mean,scale,c,kind,num_threads", GRID)
 def test_fused_pack_equals_transformer_call(tmp_path, phase, crop, mean,
-                                            scale, c, kind):
+                                            scale, c, kind, num_threads):
     """Max abs gap 0.0 against Transformer.__call__ on the same AugDraw,
-    over the whole grid of what a transform_param can ask for."""
+    over the whole grid of what a transform_param can ask for, with the
+    native calls on the caller's thread alone and on three."""
     oh, ow = (CROP, CROP) if crop == "crop" else (H, W)
     parts = [f"scale: {scale!r}", "mirror: true"]
     if crop == "crop":
@@ -111,7 +112,8 @@ def test_fused_pack_equals_transformer_call(tmp_path, phase, crop, mean,
     elif mean == "file_out":
         parts.append('mean_file: "%s"' % _mean_file(
             tmp_path, (c, oh, ow), "out.binaryproto"))
-    src = _source(tmp_path, c, " ".join(parts), train=phase == "TRAIN")
+    src = _source(tmp_path, c, " ".join(parts), train=phase == "TRAIN",
+                  num_threads=num_threads)
     records = _records(kind, c)
     draw = src.transformer.draw(N, H, W)
     if mean == "value3" and c == 1:
@@ -146,6 +148,33 @@ def test_fused_pack_draws_like_the_general_path(tmp_path):
         want = b.transformer(b._records_to_data(records, 3, H, W))
         assert np.abs(got - want).max() == 0.0
     assert _counters(a) == {"pack_fused": 3}
+
+
+@pytest.mark.parametrize("kind", ["encoded", "raw"])
+def test_fused_pack_writes_into_memory_that_came_back(tmp_path, kind,
+                                                      monkeypatch):
+    """A batch nobody refers to any more gives its memory to the next
+    pack; one somebody still holds (the stager, the device runtime
+    reading it) keeps its values whatever is packed after it."""
+    src = _source(tmp_path, 3, f"crop_size: {CROP} mirror: true")
+    from caffeonspark_tpu import native
+    monkeypatch.setattr(native, "POOL_MIN_BYTES", 1024)
+    records, other = _records(kind, 3), _records(kind, 3, seed=8)
+    draw = src.transformer.draw(N, H, W)
+    want = _general(src, records, draw, 3)
+    held = src.next_batch(records, draw=draw)["data"]
+    addr = held.ctypes.data
+    for _ in range(3):                  # `held` is alive: never its memory
+        nxt = src.next_batch(other, draw=draw)["data"]
+        assert nxt.ctypes.data != addr
+    assert np.abs(held - want).max() == 0.0
+    seen = {nxt.ctypes.data}
+    del held, nxt
+    for _ in range(3):                  # nobody holds any: memory returns
+        seen.add(src.next_batch(records, draw=draw)["data"].ctypes.data)
+    assert len(seen) <= 3
+    assert np.abs(src.next_batch(records, draw=draw)["data"]
+                  - want).max() == 0.0
 
 
 def test_fused_pack_resampled_images_stay_float(tmp_path):

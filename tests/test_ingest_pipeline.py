@@ -164,11 +164,78 @@ def test_transformer_pool_drop_skip_and_abort():
         p.stop(join_timeout=5)
 
 
+@pytest.mark.parametrize(
+    "cores,quota,pool_width,explicit,local_procs,want", [
+        (13, None, 2, 0, 1, 5),         # the one-chip host: 10 in all
+        (13, "max 100000", 2, 0, 1, 5),  # a cgroup with no quota
+        (13, "800000 100000", 2, 0, 1, 3),
+        (13, "400000 100000", 2, 0, 1, 1),
+        (13, "50000 100000", 2, 0, 1, 1),   # half a CPU
+        (13, "garbage", 2, 0, 1, 5),
+        (2, None, 2, 0, 1, 1),          # the 2-core box keeps its 1
+        (1, None, 2, 0, 1, 1),
+        (13, None, 1, 0, 1, 11),        # a validation-style pool of one
+        (13, None, 4, 0, 1, 2),
+        (112, None, 2, 0, 1, 55),
+        (13, None, 2, 4, 1, 4),         # the caller's choice stands
+        (13, None, 2, 1, 1, 1),
+        (2, None, 2, 7, 1, 7),
+        (13, None, 2, 0, 2, 2),         # two ranks of a job on the host
+        (112, None, 2, 0, 4, 13),
+        (8, None, 2, 0, 8, 1),
+        (None, None, 2, 0, 1, 2),       # no affinity call: cpu_count()
+    ])
+def test_pooled_pack_thread_share(tmp_path, monkeypatch, cores, quota,
+                                  pool_width, explicit, local_procs,
+                                  want):
+    """tune_decode_threads: a pooled source nobody chose threads for
+    gets (cores the process may use - 2) // pool width, cores cut by a
+    cgroup quota and by the job's other processes on the host, never
+    under 1; a caller's own num_threads stands."""
+    import os
+    from types import SimpleNamespace
+    from caffeonspark_tpu.data import queue_runner as qr
+
+    if cores is None:
+        def no_affinity(pid):
+            raise AttributeError("sched_getaffinity")
+        monkeypatch.setattr(os, "sched_getaffinity", no_affinity,
+                            raising=False)
+        monkeypatch.setattr(os, "cpu_count", lambda: 6)
+    else:
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid: set(range(cores)), raising=False)
+        monkeypatch.setattr(os, "cpu_count", lambda: 999)
+    cpu_max = tmp_path / "cpu.max"
+    if quota is not None:
+        cpu_max.write_text(quota + "\n")
+    monkeypatch.setattr(qr, "_CGROUP_CPU_MAX", str(cpu_max))
+    src = SimpleNamespace(num_threads=explicit)
+    qr.tune_decode_threads(src, pool_width, local_procs)
+    assert src.num_threads == want
+
+
+def test_mini_cluster_counts_its_local_ranks():
+    """A rank divides the cores by the job's ranks on its host when its
+    own arguments say they are all there."""
+    from types import SimpleNamespace as A
+    from caffeonspark_tpu.mini_cluster import local_ranks
+    assert local_ranks(A(cluster=None, server=None)) == 1
+    assert local_ranks(A(cluster=4, server="127.0.0.1:47788")) == 4
+    assert local_ranks(A(cluster=4, server="localhost:1")) == 4
+    assert local_ranks(A(cluster=2, server="agent://127.0.0.1:9")) == 2
+    assert local_ranks(A(cluster=2, server=None)) == 2     # elastic
+    assert local_ranks(A(cluster=16, server="10.0.0.5:47788")) == 1
+
+
+@pytest.mark.parametrize("num_threads", [1, 3])
 @pytest.mark.parametrize("kind", ["encoded", "raw"])
-def test_transformer_pool_ordered_draw_parity(tmp_path, kind):
-    """num_threads > 1 packing reproduces the inline path's
-    augmentation stream exactly (crop offsets + mirror flips pre-drawn
-    in feed order by the dispatcher), for JPEG and for raw records."""
+def test_transformer_pool_ordered_draw_parity(tmp_path, kind,
+                                              num_threads):
+    """A pool of several workers, each pack's native calls on
+    `num_threads` threads, reproduces the inline path's augmentation
+    stream exactly (crop offsets + mirror flips pre-drawn in feed order
+    by the dispatcher), for JPEG and for raw records."""
     import cv2
     from caffeonspark_tpu.data import LmdbWriter, get_source
     from caffeonspark_tpu.data.synthetic import make_images
@@ -194,11 +261,17 @@ def test_transformer_pool_ordered_draw_parity(tmp_path, kind):
           channels: 1 height: 28 width: 28 }}''')
     ref_src = get_source(lp, phase_train=True, seed=9, resize=True)
     ref = list(ref_src.batches(loop=False, shuffle=False))
-    src = get_source(lp, phase_train=True, seed=9, resize=True)
+    src = get_source(lp, phase_train=True, seed=9, resize=True,
+                     num_threads=num_threads)
+    src.metrics = PipelineMetrics()
     feed = PipelinedFeed(src, loop=False, shuffle=False, num_threads=3)
     got = list(feed)
     feed.close()
     assert len(got) == len(ref) == 6
+    assert src.num_threads == num_threads       # the caller chose
+    gauge = src.metrics.summary()["queue_depths"]["pack_threads"]
+    assert gauge == {"samples": 6, "mean": num_threads,
+                     "max": num_threads}
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(a["data"], b["data"])
         np.testing.assert_array_equal(a["label"], b["label"])
@@ -634,6 +707,11 @@ def test_train_job_packs_every_batch_in_one_pass(tmp_path, monkeypatch):
                          step_delay_ms=0)
     dump = tmp_path / "pipeline_metrics.json"
     monkeypatch.setenv("COS_PIPELINE_METRICS", str(dump))
+    monkeypatch.setenv("COS_METRICS_FLUSH_S", "0.2")
+    # a host of 9 cores: (9 - 2) // 2 pool workers = 3 threads a pack,
+    # for the train pool's workers and the validation pool's alike
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(9)), raising=False)
     proc = CaffeProcessor.instance(conf)
     train_src = get_source(conf.train_data_layer(), phase_train=True)
     val_src = get_source(conf.test_data_layer(), phase_train=False)
@@ -644,8 +722,16 @@ def test_train_job_packs_every_batch_in_one_pass(tmp_path, monkeypatch):
     assert s["stages"]["step"]["count"] == 8
     assert s["counters"]["pack_fused"] == s["stages"]["pack"]["count"] >= 12
     assert "pack_general" not in s["counters"]
+    assert (proc.train_source.num_threads, proc.val_source.num_threads
+            ) == (3, 3)
     proc.stop()
     assert json.load(open(dump))["counters"]["pack_fused"] >= 12
+    # the flusher's metrics.json: one pack_threads sample a packed batch
+    flushed = json.load(open(tmp_path / "metrics.json"))
+    packs = flushed["stages"]["pack"]["count"]
+    assert flushed["counters"]["pack_fused"] == packs >= 12
+    assert flushed["queue_depths"]["pack_threads"] == {
+        "samples": packs, "mean": 3.0, "max": 3}
 
 
 def test_pool_workers_account_for_their_lifetime():

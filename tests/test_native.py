@@ -59,6 +59,49 @@ def test_decode_corrupt_raises(lib):
                             out_w=8)
 
 
+@pytest.mark.parametrize("n,num_threads", [(1, 1), (1, 4), (3, 8),
+                                           (6, 3), (6, 0), (24, 32)])
+def test_threaded_calls_agree_with_one_thread(lib, n, num_threads):
+    """run_workers: the caller's thread takes a share and spawns
+    num_threads - 1 helpers, never more than n in all; one image, fewer
+    images than threads, more threads than cores, and 0 = a thread a
+    hardware thread all give the values one thread gives, for each of
+    the three kernels."""
+    jpegs = _jpegs(n)
+    one = native.decode_batch(jpegs, channels=3, out_h=32, out_w=32,
+                              out_dtype=np.uint8, exact=True,
+                              num_threads=1)
+    got = native.decode_batch(jpegs, channels=3, out_h=32, out_w=32,
+                              out_dtype=np.uint8, exact=True,
+                              num_threads=num_threads)
+    np.testing.assert_array_equal(got, one)
+    rng = np.random.RandomState(n)
+    hs, ws = rng.randint(0, 9, n), rng.randint(0, 9, n)
+    flip = rng.randint(0, 2, n).astype(np.uint8)
+    kw = dict(crop=24, h_off=hs, w_off=ws, mirror=flip,
+              mean=np.asarray([104.0, 117.0, 123.0], np.float32))
+    np.testing.assert_array_equal(
+        native.transform_batch(one, num_threads=num_threads, **kw),
+        native.transform_batch(one, num_threads=1, **kw))
+    np.testing.assert_array_equal(
+        native.crop_mirror_u8(one, hs, ws, flip, crop=24,
+                              num_threads=num_threads),
+        native.crop_mirror_u8(one, hs, ws, flip, crop=24, num_threads=1))
+
+
+@pytest.mark.parametrize("num_threads", [1, 3, 0])
+def test_corrupt_jpeg_inside_a_threaded_decode_raises(lib, num_threads):
+    """One corrupt image among good ones, wherever a thread meets it:
+    the call still raises, and says how many failed."""
+    jpegs = _jpegs(6)
+    jpegs[4] = jpegs[4][:40]
+    for exact, dt in ((False, np.float32), (True, np.uint8)):
+        with pytest.raises(ValueError, match="1/6 images failed"):
+            native.decode_batch(jpegs, channels=3, out_h=32, out_w=32,
+                                out_dtype=dt, exact=exact,
+                                num_threads=num_threads)
+
+
 def test_transform_matches_numpy(lib):
     rng = np.random.RandomState(0)
     batch = rng.rand(4, 3, 12, 12).astype(np.float32) * 255
@@ -167,3 +210,109 @@ def test_host_stage_native_equals_numpy(lib, monkeypatch):
     b_u8, b_aux = Transformer(tp, phase_train=True, seed=3).host_stage(x)
     np.testing.assert_array_equal(a_u8, b_u8)
     np.testing.assert_array_equal(a_aux, b_aux)
+
+
+# -- BufferPool: batch-sized arrays that come back -----------------------
+
+@pytest.fixture
+def small_pool(monkeypatch):
+    """A pool that keeps blocks from 1 KiB, two a size."""
+    monkeypatch.setattr(native, "POOL_MIN_BYTES", 1024)
+    monkeypatch.setattr(native, "POOL_KEEP", 2)
+    return native.BufferPool()
+
+
+def test_buffer_pool_reuses_memory_nobody_refers_to(small_pool):
+    pool = small_pool
+    a = pool.take((4, 256), np.float32)
+    assert a.shape == (4, 256) and a.dtype == np.float32
+    assert a.flags["C_CONTIGUOUS"] and a.flags["WRITEABLE"]
+    addr = a.ctypes.data
+    del a
+    b = pool.take((256, 4), np.float32)     # same bytes, another shape
+    assert b.ctypes.data == addr
+    u = pool.take((4096,), np.uint8)        # b is alive: not its memory
+    assert u.ctypes.data != addr
+    # under min_bytes: plain np.empty, nothing kept
+    small = pool.take((8,), np.float32)
+    assert small.base is None
+    del small
+    assert list(pool._free) == [4096]
+
+
+def test_buffer_pool_leaves_memory_a_view_still_reaches(small_pool):
+    """The array handed out dies but a slice of it lives: that memory is
+    the slice's, as with the allocator's own free, and is never handed
+    out again."""
+    pool = small_pool
+    a = pool.take((4, 256), np.float32)
+    a[:] = 7.0
+    addr, row = a.ctypes.data, a[2]
+    del a
+    b = pool.take((4, 256), np.float32)
+    assert b.ctypes.data != addr
+    b[:] = 9.0
+    assert (row == 7.0).all()
+    del row, b
+    assert pool.take((4, 256), np.float32) is not None
+
+
+@pytest.mark.parametrize("shift", [1, -1])
+def test_buffer_pool_is_np_empty_where_counts_differ(small_pool,
+                                                     monkeypatch, shift):
+    """Reuse rests on CPython's reference counts.  On an interpreter
+    that reports one more (nothing would ever come back) or one fewer
+    (memory a view still reaches would be handed out), a new pool finds
+    out at once and every take is np.empty's."""
+    import sys
+    real = sys.getrefcount
+    monkeypatch.setattr(native.sys, "getrefcount",
+                        lambda o: real(o) - 1 + shift)
+    pool = native.BufferPool()
+    a = pool.take((4, 256), np.float32)
+    assert a.base is None and not pool._free
+    assert small_pool.take((4, 256), np.float32).base is not None
+
+
+def test_buffer_pool_keeps_at_most_keep_a_size(small_pool):
+    pool = small_pool
+    arrays = [pool.take((2048,), np.uint8) for _ in range(5)]
+    del arrays
+    assert [len(v) for v in pool._free.values()] == [2]
+
+
+def test_buffer_pool_under_threads(small_pool):
+    """More takers than cores, each filling its array with its own value
+    and reading it back after a pause: an array handed to two holders at
+    once would show the other's value."""
+    import sys
+    import threading
+    import time
+    pool = small_pool
+    bad, addrs, takes = [], set(), []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+
+    def taker(k):
+        deadline = time.monotonic() + 1.0
+        while time.monotonic() < deadline:
+            a = pool.take((64, 64), np.float32)
+            addrs.add(a.ctypes.data)
+            takes.append(k)
+            a[:] = k
+            time.sleep(0)
+            if not (a == k).all():
+                bad.append(k)
+            del a
+
+    try:
+        threads = [threading.Thread(target=taker, args=(k,))
+                   for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert not bad and len(addrs) < len(takes) / 4

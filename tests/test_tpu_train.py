@@ -307,3 +307,26 @@ def test_fused_loop_matches_single_steps_on_tpu():
             np.testing.assert_allclose(
                 np.asarray(p8[ln][bn]), np.asarray(p1[ln][bn]),
                 rtol=1e-4, atol=1e-6, err_msg=f"{ln}/{bn}")
+
+
+def test_buffer_pool_memory_outlives_its_transfer_on_tpu():
+    """`device_put` returns before the host buffer is read.  A pooled
+    buffer comes back only when nothing refers to it, and the runtime
+    refers to it until its transfer is complete: thirty 64 MB batches,
+    each staged and dropped at once while the next is written into
+    whatever memory has come back, all arrive as they were written."""
+    import jax
+    from caffeonspark_tpu.native import BufferPool
+    pool = BufferPool()
+    shape = (16, 1024, 1024)
+    staged, addrs = [], set()
+    for k in range(30):
+        a = pool.take(shape, np.float32)
+        addrs.add(a.ctypes.data)
+        a[:] = k
+        staged.append(jax.device_put(a))
+        del a
+    for k, d in enumerate(staged):
+        got = np.asarray(d[:, ::257, ::129])
+        assert (got == k).all(), (k, np.unique(got))
+    assert len(addrs) < 30
