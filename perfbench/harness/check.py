@@ -160,7 +160,8 @@ def compare(prog: dict, ref: dict, lr_mults: dict, lr: float) -> dict:
 
 
 def verdict(nums: dict, limits: dict) -> bool:
-    """Every limited number at or under its limit; prints each beside it."""
+    """Every limited number at or under its limit; prints each beside it,
+    then the numbers that were read and are held to no limit in this cell."""
     ok = True
     for name, limit in limits.items():
         value = nums.get(name)
@@ -171,4 +172,7 @@ def verdict(nums: dict, limits: dict) -> bool:
               + (f" (worst leaf {leaf})" if leaf else "")
               + ("" if good else "  <-- FAILS"), flush=True)
         ok = ok and good
+    for name, value in nums.items():
+        if name not in limits and isinstance(value, float):
+            print(f"[perfbench] recorded {name} = {value!r}", flush=True)
     return ok

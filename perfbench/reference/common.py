@@ -193,16 +193,21 @@ def sgd_update(params, hist, grads, layers, *, lr, momentum, weight_decay):
     return new_p, new_h
 
 
-def train_steps(model, cfg, seed, batches):
+def train_steps(model, cfg, seed, batches, devices=None):
     """Follow the first len(batches) solver iterations from the seed.
 
     model: a reference module (layers, loss_sum, ROW_BLOCK); cfg: the
     configuration's JSON; batches: [(data, labels)] as the data layer
     delivers them (float32 NCHW after crop/mirror/mean, float labels).
-    Returns each step's loss and, on the host in float32 under
+    devices: the chips of the cell.  Given more than one, the rows of a
+    block lie over them and the parameters on each: the same equations
+    over the whole batch (BatchNorm's statistics are over all its rows),
+    in a quarter of the memory a chip; a batch of four chips does not
+    fit one.  Returns each step's loss and, on the host in float32 under
     "layer/i", the parameters at the start, after step 1 and after the
     last step, and the momentum after step 1."""
     import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
     sv = cfg["solver"]
     lr, mom, wd = sv["base_lr"], sv["momentum"], sv["weight_decay"]
     crop = batches[0][0].shape[-1]
@@ -219,7 +224,14 @@ def train_steps(model, cfg, seed, batches):
             model.loss_sum, has_aux=True)(p, data, labels, masks)
         return total, stats, g
 
-    params = init_params(layers, seed)
+    if devices is not None and len(devices) > 1:
+        mesh = Mesh(np.asarray(devices), ("rows",))
+        by_rows = NamedSharding(mesh, PartitionSpec("rows"))
+        on_each = NamedSharding(mesh, PartitionSpec())
+    else:
+        by_rows = on_each = (devices or jax.local_devices())[0]
+
+    params = jax.device_put(init_params(layers, seed), on_each)
     out = {"p0": host(params), "losses": []}
     hist = jax.tree.map(jnp.zeros_like, params)
     for it, (data, labels) in enumerate(batches):
@@ -228,9 +240,10 @@ def train_steps(model, cfg, seed, batches):
         masks_all = model.masks(cfg, seed, it, n)
         total, gsum, stats = 0.0, None, {}
         for lo in range(0, n, step):
-            m = {k: v[lo:lo + step] for k, v in masks_all.items()}
-            t, stats, g = block_grads(params, jnp.asarray(data[lo:lo + step]),
-                                      jnp.asarray(labels[lo:lo + step]), m)
+            m, d, lab = jax.device_put(
+                ({k: v[lo:lo + step] for k, v in masks_all.items()},
+                 data[lo:lo + step], labels[lo:lo + step]), by_rows)
+            t, stats, g = block_grads(params, d, lab, m)
             total += float(t)
             gsum = g if gsum is None else jax.tree.map(jnp.add, gsum, g)
         grads = jax.tree.map(lambda a: a / n, gsum)

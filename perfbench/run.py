@@ -13,7 +13,9 @@ per-layer metric is a file found by its name in BENCHMARK.json:
 
 The run fails, printing no result line, without a TPU of a kind that
 peaks.json knows, or with fewer chips than the cell asks for.  The last
-line of a passing run is the result object.
+line of a run's standard output is the result object; its last key,
+`checks`, holds every number `correct` compared beside its limit, and the
+same are the last lines of standard error.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ import argparse                                     # noqa: E402
 import importlib                                    # noqa: E402
 import importlib.util                               # noqa: E402
 import json                                         # noqa: E402
+import math                                         # noqa: E402
 import os                                           # noqa: E402
 import sys                                          # noqa: E402
 
@@ -103,9 +106,13 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             metrics[name] = {"value": value, "unit": units[name]}
     result = {"correct": bool(run["correct"]), "attempted": run["attempted"],
               "failed": run["failed"], "metrics": metrics}
+    # every number compared, beside its limit; the key comes last
+    checks = {name: {"value": plain(run["nums"].get(name)), "limit": limit}
+              for name, limit in res["cell"]["limits"].items()}
     if device is None:
         result["metrics"] = {k: None for k in metrics}   # a CPU rehearsal
         result["rehearsal"] = True
+        result["checks"] = checks
         return result
     dev = dict(device, memory_peak_bytes=run["memory_peak_bytes"])
     if trace:
@@ -124,7 +131,15 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             sys.exit(f"perfbench: {name} = {m['value']} is over 100% of the "
                      "peak: the operations are counted too high or the "
                      "time leaves out work")
+    result["checks"] = checks
     return result
+
+
+def plain(value):
+    """A compared number as JSON can hold it."""
+    if isinstance(value, float) and not math.isfinite(value):
+        return repr(value)
+    return value
 
 
 def main(argv=None) -> int:
@@ -151,6 +166,10 @@ def main(argv=None) -> int:
     result = run_cell(ROOT, args.workload, args.seed, args.seconds,
                       bool(args.trace), device=device)
     sys.stdout.flush()
+    for name, c in result["checks"].items():
+        print(f"perfbench: {name} = {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -73,6 +73,9 @@ def test_every_name_resolves_to_its_files():
         assert set(e.get("workloads", [])) <= cells
     for e in m["per_layer"]:
         assert e["moves"] in e2e
+    # a retired cell leaves no orphan: the files under cells/ are the cells
+    assert {fn[:-len(".json")] for fn in os.listdir(
+        os.path.join(PB, "cells"))} == cells
     for path, _, files in os.walk(PB):
         if "__pycache__" in path:
             continue

@@ -137,12 +137,22 @@ def test_lower_precision_control_reads_worse_than_the_sound_program():
 
 
 def test_manifest_resolves_both_new_cells():
-    dp4 = R.resolve(ROOT, "caffenet.train_raw_dp4")
+    dp4 = R.resolve(ROOT, "resnet50.train_raw_dp4")
     assert dp4["chips"] == 4 and dp4["traffic"]["encoding"] == "raw"
-    assert dp4["config"]["per_device_batch"] == 768
+    assert dp4["config"]["per_device_batch"] == 64
     assert dp4["cell"]["limits"]["ingest_pixel_gap"] == 0
-    assert "dp.collective_exposed_ms.train" in R.metric_names(
-        dp4["manifest"], "per_layer", "caffenet.train_raw_dp4")
+    # the same 1,024 records as the one-chip cell of the configuration
+    one = R.resolve(ROOT, "resnet50.train_raw")
+    assert (dp4["cell"]["records_per_global_batch"] * 4
+            == one["cell"]["records_per_global_batch"])
+    assert {k: v for k, v in dp4["traffic"].items() if k != "who"} \
+        == {k: v for k, v in one["traffic"].items() if k != "who"}
+    names = R.metric_names(dp4["manifest"], "per_layer",
+                           "resnet50.train_raw_dp4")
+    for name in ("dp.collective_ms.train", "dp.collective_exposed_ms.train",
+                 "ingest.read_ms_per_img.train", "step.dispatch_ms.train",
+                 "step.mfu_pct.train", "device.idle_pct.train"):
+        assert name in names
     tok = R.resolve(ROOT, "kanana2.train_packed4k")
     assert tok["chips"] == 1 and tok["traffic"]["kind"] == "train_tokens"
     cfg = tok["config"]

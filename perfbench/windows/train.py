@@ -9,11 +9,15 @@ is wrapped by a pass-through that
   * on the job's first CHECK_STEPS steps copies out what the comparison
     needs (state before step 1, momentum after it, parameters after the
     last, each loss, the first pixel row of every packed image);
-  * after the warm-up steps, and once no stock of packed batches is
-    left, blocks on the step's loss (window edge a, a value fetched from
-    the device), counts steps, and
-    at the first step that ends `seconds` later, a whole number of pool
-    rounds after edge a, blocks again (edge b);
+  * at the last warm-up step (the job's step CHECK_STEPS + warmup_steps)
+    blocks on the step's loss and stamps the clock: set-up ends there,
+    at a fixed point of the job;
+  * from that step on, once no stock of packed batches is left, blocks
+    on the step's loss (window edge a, a value fetched from the device),
+    counts steps, and at the first step that ends `seconds` later, a
+    whole number of pool rounds after edge a, blocks again (edge b).
+    The steps between the stamp and edge a (the drain of the stock) are
+    the benchmark's own and belong to neither `setup_s` nor the window;
   * with tracing on, starts and stops the profiler at the edges and marks
     `dispatch` / `wait_for_batch` spans on the profiler's clock.
 
@@ -52,6 +56,7 @@ class Observer:
         self.cap = {"losses": [], "strips": [], "labels": [], "rows": [],
                     "row_idx": []}
         self.window_losses = []
+        self.t_setup = None               # the last warm-up step is done
         self.t_a = self.t_b = None
         self.n_a = self.n_b = None
         self.metrics_a = self.metrics_b = None
@@ -79,6 +84,14 @@ class Observer:
     def _sample_memory(self):
         from ..harness import devices
         self.memory_peak = max(self.memory_peak, devices.memory_now())
+
+    def setup_and_drain(self):
+        """-> (setup_s, drain seconds, drain steps): process start to the
+        stamp of the last warm-up step, less the comparison's own copying;
+        then what the benchmark's own stock rule added before edge a."""
+        return ((self.t_setup - self.t0) - self.overhead,
+                self.t_a - self.t_setup,
+                self.n_a - (CHECK_STEPS + self.warmup_steps))
 
     def _on_compile(self, event, duration, **kw):
         if self.in_window and "compile" in event:
@@ -137,11 +150,18 @@ class Observer:
             else:
                 result = real(params, st, batch, rng)
             out = result[2]
+            if n + 1 == CHECK_STEPS + self.warmup_steps:
+                # set-up ends at a fixed point of the job: how long the
+                # stock then takes to drain follows the step time and the
+                # feed, and a PR that makes either faster must not read
+                # as a slower or a faster set-up
+                jax.block_until_ready(out["loss"])
+                self.t_setup = self.stamps["setup_end"] = time.perf_counter()
             if self.t_a is None:
-                # warm-up ends once its steps are done AND the stock of
-                # packed batches that set-up built is gone: batches packed
-                # so far less steps taken is under one pool round, as it
-                # is whenever the loop lives from hand to mouth.  A run
+                # the window opens once the warm-up steps are done AND the
+                # stock of packed batches that set-up built is gone: packed
+                # so far less steps taken is under one pool round, as it is
+                # whenever the loop lives from hand to mouth.  A run
                 # that compiles leaves the pool and the stager half a
                 # minute to pack ahead, up to 11 batches; run down inside
                 # the window that read as up to 25% more img/s, and a
@@ -293,7 +313,7 @@ def run(ctx: dict) -> dict:
                            f"{'closed' if obs.t_b else 'never closed'}")
     window_s = obs.t_b - obs.t_a
     steps = obs.n_b - obs.n_a
-    setup_s = (obs.t_a - obs.t0) - obs.overhead
+    setup_s, drain_s, drain_steps = obs.setup_and_drain()
     peak = obs.memory_peak
     obs.stamps["window_start"] = obs.t_a
     print("[perfbench] set-up, seconds from process start: "
@@ -310,6 +330,7 @@ def run(ctx: dict) -> dict:
           f"images (pool rounds of {obs.round}); peak HBM {peak} bytes; "
           f"set-up {setup_s:.2f} s "
           f"(+{obs.overhead:.2f} s copying for the comparison); "
+          f"warm-up drain {drain_s:.2f} s, {drain_steps} steps; "
           f"compiles in window {obs.compiles_in_window}", flush=True)
     gc.collect()
 
@@ -336,8 +357,8 @@ def run(ctx: dict) -> dict:
     t_match = time.perf_counter() - t_ref
     from ..reference import common
     lr_mults = check.lr_mults_of(model.layers(cfg, crop))
-    with jax.default_device(jax.local_devices()[0]):
-        ref = common.train_steps(model, cfg, ctx["seed"], batches)
+    ref = common.train_steps(model, cfg, ctx["seed"], batches,
+                             jax.local_devices()[:ctx["chips"]])
     prog = {k: check.by_index(obs.cap[k])
             for k in ("p0", "p1", "v1", "p_last")}
     prog["losses"] = obs.cap["losses"]
@@ -351,7 +372,8 @@ def run(ctx: dict) -> dict:
         "correct": correct, "nums": nums,
         "attempted": steps, "failed": nums["nonfinite_window_losses"],
         "window_s": window_s, "steps": steps, "images": steps * batch,
-        "setup_s": setup_s, "memory_peak_bytes": peak,
+        "setup_s": setup_s, "warmup_drain_s": drain_s,
+        "warmup_drain_steps": drain_steps, "memory_peak_bytes": peak,
         "pipeline": (obs.metrics_a, obs.metrics_b), "batch": batch,
         "trace_dir": trace_dir, "facts": facts, "losses": losses,
         "flops_per_step": 3 * common.forward_flops(model, cfg, crop, batch),
