@@ -463,6 +463,130 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
     return parse_net_prototxt(t)
 
 
+# lfm2_moe's published operator schedule (LFM2-24B-A2B, 40 layers):
+# conv, conv, attention, then three convs and an attention repeating,
+# a conv last
+LFM2_LAYER_TYPES = tuple(
+    "full_attention" if i >= 2 and (i - 2) % 4 == 0 else "conv"
+    for i in range(40))
+
+
+def lfm2(vocab: int = 8192, hidden: int = 2048, heads: int = 32,
+         kv_heads: int = 8, head_dim: int = 64, dense_width: int = 11776,
+         expert_width: int = 1536, experts: int = 64, top_k: int = 4,
+         experts_held: int = 8, first_expert: int = 0,
+         routed_scaling_factor: float = 1.0, norm_epsilon: float = 1e-6,
+         layer_types=LFM2_LAYER_TYPES, num_dense_layers: int = 2,
+         first_layer: int = 1, layers: int = 7, conv_taps: int = 3,
+         conv_bias: bool = False, seq: int = 8192, batch: int = 1,
+         rope_theta: float = 1e6, eps: float = 1e-5,
+         init_std: float = 0.02, recompute: bool = True) -> NetParameter:
+    """LiquidAI/LFM2-24B-A2B (`model_type: lfm2_moe`) as one chip's
+    share of an expert-parallel deployment: pre-norm residual blocks
+    whose operator follows `layer_types` — the gated short convolution
+    (`conv`) or grouped-query attention with q/k norms and rotary
+    positions (`full_attention`) — and whose feed-forward is a dense
+    SiLU-gated one in the published layers below `num_dense_layers` and
+    `experts` sigmoid-routed experts (no shared one) after, of which
+    this net holds `experts_held` from `first_expert` on.  The net is
+    the published layers [`first_layer`, `first_layer` + `layers`),
+    named L0, L1, ... in the order they run.  The defaults are the
+    published widths with the cut of `perfbench/configs/
+    lfm2_24b_a2b.json` (8 of 64 experts, an eighth of the vocabulary,
+    published layers 1-7: one dense conv layer, then attention, three
+    convs, attention, conv over experts); `experts_held=64,
+    vocab=65536, first_layer=0, layers=40` is the whole model.
+    Time-major (T, B) int tops `input_ids` / `target_ids` (one row of
+    8,192 by default); every block is one `recompute_block`; each
+    expert layer's `moe_stats` / `moe_rows` tops are net outputs."""
+    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    if first_layer + layers > len(layer_types):
+        raise ValueError(f"lfm2: layers [{first_layer}, "
+                         f"{first_layer + layers}) of {len(layer_types)}")
+
+    def ip(name, bottom, top, n, tag):
+        return f"""
+layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
+  {tag} inner_product_param {{ num_output: {n} axis: 2 bias_term: false
+    {gauss} }} }}"""
+
+    t = f"""
+name: "LFM2"
+layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
+  cos_data_param {{ batch_size: {batch}
+    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }}
+    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }} }} }}
+layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
+  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
+    {gauss} }} }}
+"""
+    h = "h0"
+    for i in range(layers):
+        p = f"L{i}"
+        published = first_layer + i
+        tag = f'recompute_block: "{p}"' if recompute else ""
+        t += f"""
+layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
+  {tag} rms_norm_param {{ eps: {eps} }} }}"""
+        kind = layer_types[published]
+        if kind == "conv":
+            t += f"""
+layer {{ name: "{p}.conv" type: "ShortConv" bottom: "{p}.n1" top: "{p}.a"
+  {tag} short_conv_param {{ taps: {conv_taps}
+    bias_term: {"true" if conv_bias else "false"} {gauss} }} }}"""
+        elif kind == "full_attention":
+            t += f"""
+layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
+  top: "{p}.a" {tag}
+  attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
+    head_dim: {head_dim} causal: true qk_norm: true rotary: true
+    rope_theta: {rope_theta} rms_norm_eps: {eps} {gauss} }} }}"""
+        else:
+            raise ValueError(f"lfm2: layer type {kind!r}")
+        t += f"""
+layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
+  top: "{p}.h1" {tag} }}
+layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
+  {tag} rms_norm_param {{ eps: {eps} }} }}"""
+        if published < num_dense_layers:
+            t += ip(f"{p}.gate", f"{p}.n2", f"{p}.g", dense_width, tag)
+            t += ip(f"{p}.up", f"{p}.n2", f"{p}.u", dense_width, tag)
+            t += f"""
+layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g" {tag} }}
+layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
+  top: "{p}.gu" {tag} eltwise_param {{ operation: PROD }} }}"""
+            t += ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, tag)
+        else:
+            # blobs: router, bias (moves only the choice: frozen),
+            # W_gate, W_up, W_down
+            t += f"""
+layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
+  top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
+  param {{ lr_mult: 1 }} param {{ lr_mult: 0 decay_mult: 0 }}
+  moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
+    top_k: {top_k} dispatch: "dropless" scoring: "sigmoid"
+    selection_bias: true routed_scaling_factor: {routed_scaling_factor}
+    norm_epsilon: {norm_epsilon} gated: true
+    experts_held: {experts_held} first_expert: {first_expert}
+    {gauss} }} }}"""
+        t += f"""
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
+  top: "{p}.out" {tag} }}
+"""
+        h = f"{p}.out"
+    t += f"""
+layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
+  rms_norm_param {{ eps: {eps} }} }}"""
+    t += ip("head.logits", "head.n", "logits", vocab, "")
+    t += """
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
+  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
+"""
+    return parse_net_prototxt(t)
+
+
 def lstm_lm(vocab: int = 8801, d_model: int = 1000, seq: int = 20,
             batch_size: int = 32) -> NetParameter:
     """LRCN-shaped recurrent language model: Embed -> cont-gated LSTM

@@ -1313,10 +1313,16 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
     """Flash (Pallas, O(block·T) VMEM) on TPU when the shape tiles;
     under a multi-device mesh the kernel runs per-device via shard_map
     over (batch, heads); XLA einsum attention otherwise — numerically
-    the same math (tests/test_pallas.py flash parity).  q/k (B, H, T,
-    D), v (B, H, T, Dv): Dv need not equal D.  `mxu_dtype` is the
-    operand type of the kernel's products (None = float32 operands,
-    the kernel's exact mode); the einsum path takes XLA's precision."""
+    the same math (tests/test_pallas.py flash parity).  q (B, H, T, D),
+    k (B, H/g, T, D), v (B, H/g, T, Dv): Dv need not equal D, and with
+    g > 1 query head h reads key/value head h // g (the kernels' block
+    index maps and the einsum path's reshape: k and v are never
+    repeated, except before the time-sharded ring, which assumes equal
+    heads).  `mxu_dtype` is the operand type of the kernel's products
+    (None = float32 operands, the kernel's exact mode); the einsum path
+    takes XLA's precision.  Everything here runs under the scope
+    `attn.core`: the kernels' (or the einsums') device time apart from
+    the products, norms and rotary turns of the layer around them."""
     from .pallas_kernels import flash_attention, pallas_enabled
     t = q.shape[2]
     interpret = _pallas_interpret()
@@ -1325,56 +1331,72 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
     # cheap anyway
     enabled = ((pallas_enabled() or interpret) and not _FLASH_SUPPRESS
                and not os.environ.get("COS_DISABLE_FLASH"))
-    if enabled and _FLASH_MESH:
-        import functools
-        from jax.sharding import PartitionSpec as P
-        from ..parallel.sp import shard_map_nocheck
-        mesh, b_axes, h_axes, t_axes = _FLASH_MESH[-1]
-        shape = dict(mesh.shape)
-        b_axes = tuple(a for a in b_axes if shape.get(a, 1) > 1)
-        h_axes = tuple(a for a in h_axes if shape.get(a, 1) > 1)
-        t_axes = tuple(a for a in t_axes if shape.get(a, 1) > 1)
-        nb = math.prod(shape[a] for a in b_axes) if b_axes else 1
-        nh = math.prod(shape[a] for a in h_axes) if h_axes else 1
-        tiles = q.shape[0] % nb == 0 and q.shape[1] % nh == 0
-        if t_axes and len(t_axes) == 1 and tiles and t % shape[t_axes[0]] == 0:
-            # TIME sharded: differentiable fused ring per (b, h) block
-            nt = shape[t_axes[0]]
-            from ..parallel.sp import flash_block_size
-            if flash_block_size(t // nt) is not None:
-                from ..parallel.sp import _ring_attention_local
-                spec = P(b_axes or None, h_axes or None, t_axes, None)
+    with jax.named_scope("attn.core"):
+        if enabled and _FLASH_MESH:
+            import functools
+            from jax.sharding import PartitionSpec as P
+            from ..parallel.sp import shard_map_nocheck
+            mesh, b_axes, h_axes, t_axes = _FLASH_MESH[-1]
+            shape = dict(mesh.shape)
+            b_axes = tuple(a for a in b_axes if shape.get(a, 1) > 1)
+            h_axes = tuple(a for a in h_axes if shape.get(a, 1) > 1)
+            t_axes = tuple(a for a in t_axes if shape.get(a, 1) > 1)
+            nb = math.prod(shape[a] for a in b_axes) if b_axes else 1
+            nh = math.prod(shape[a] for a in h_axes) if h_axes else 1
+            tiles = (q.shape[0] % nb == 0 and q.shape[1] % nh == 0
+                     and k.shape[1] % nh == 0)
+            if (t_axes and len(t_axes) == 1 and tiles
+                    and t % shape[t_axes[0]] == 0):
+                # TIME sharded: differentiable fused ring per (b, h) block
+                nt = shape[t_axes[0]]
+                from ..parallel.sp import flash_block_size
+                if flash_block_size(t // nt) is not None:
+                    from ..parallel.sp import _ring_attention_local
+                    g = q.shape[1] // k.shape[1]
+                    if g > 1:       # the ring rotates equal heads
+                        k, v = (jnp.repeat(a, g, axis=1) for a in (k, v))
+                    spec = P(b_axes or None, h_axes or None, t_axes, None)
+                    fl = shard_map_nocheck(
+                        functools.partial(
+                            _ring_attention_local, axis_name=t_axes[0],
+                            causal=causal,
+                            flash="interpret" if interpret else True),
+                        mesh, (spec, spec, spec), spec)
+                    return fl(q, k, v)
+                # local T unsuited to the kernel: einsum path below
+            elif not t_axes and tiles and t % 128 == 0:
+                spec = P(b_axes or None, h_axes or None, None, None)
                 fl = shard_map_nocheck(
-                    functools.partial(
-                        _ring_attention_local, axis_name=t_axes[0],
-                        causal=causal,
-                        flash="interpret" if interpret else True),
+                    functools.partial(flash_attention, causal=causal,
+                                      block_q=128, block_k=128,
+                                      interpret=interpret,
+                                      mxu_dtype=mxu_dtype),
                     mesh, (spec, spec, spec), spec)
                 return fl(q, k, v)
-            # local T unsuited to the kernel: einsum path below
-        elif not t_axes and tiles and t % 128 == 0:
-            spec = P(b_axes or None, h_axes or None, None, None)
-            fl = shard_map_nocheck(
-                functools.partial(flash_attention, causal=causal,
-                                  block_q=128, block_k=128,
-                                  interpret=interpret,
-                                  mxu_dtype=mxu_dtype),
-                mesh, (spec, spec, spec), spec)
-            return fl(q, k, v)
-        # shapes don't tile the mesh: einsum path below
-    elif enabled and not _FLASH_MESH and t % 128 == 0:
-        return flash_attention(q, k, v, causal, 128, 128, interpret,
-                               mxu_dtype)
-    from ..parallel.sp import attention as _plain_attention
-    return _plain_attention(q, k, v, causal=causal)
+            # shapes don't tile the mesh: einsum path below
+        elif enabled and not _FLASH_MESH and t % 128 == 0:
+            return flash_attention(q, k, v, causal, 128, 128, interpret,
+                                   mxu_dtype)
+        from ..parallel.sp import attention as _plain_attention
+        return _plain_attention(q, k, v, causal=causal)
+
+
+def _kernel_operand_dtype(prec, q):
+    """Operand type of the flash kernels' products for a layer at
+    precision `prec`: one bfloat16 pass with float32 accumulation where
+    XLA's default gives the projections around them the same (float32
+    blobs on the TPU, no precision pinned), else None = float32."""
+    return (jnp.bfloat16 if prec is None and q.dtype == jnp.float32
+            and jax.default_backend() == "tpu" else None)
 
 
 @register("MultiHeadAttention", params=_mha_params)
 def _mha(ctx, lp, params, bottoms):
     """Multi-head self-attention on time-major (T, B, D) input: one
     fused `W_qkv` of equal heads, no positions, no norm (the latent
-    variant with rotary positions is `LatentAttention` below; both end
-    in `_attention_dispatch`) —
+    variant with rotary positions is `LatentAttention` below, the one
+    with fewer key/value heads and q/k norms `GroupedQueryAttention`;
+    all end in `_attention_dispatch`) —
     extension beyond the reference (SURVEY §5.7: it has no attention at
     all).  Under jit on a mesh, GSPMD partitions the attention einsums
     along whatever axes the activations carry; for explicit
@@ -1513,12 +1535,134 @@ def _mla(ctx, lp, params, bottoms):
         v = kvb[..., nope:]
         # (T, B, H, ·) -> (B, H, T, ·)
         q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
-        mxu = (jnp.bfloat16 if prec is None and q.dtype == jnp.float32
-               and jax.default_backend() == "tpu" else None)
         o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
-                                mxu_dtype=mxu)
+                                mxu_dtype=_kernel_operand_dtype(prec, q))
         o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * vd)
         return [jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)]
+
+
+def _gqa_heads(ap):
+    h, hd = int(ap.num_heads), int(ap.head_dim)
+    hkv = int(ap.num_kv_heads) or h
+    if not (h and hd) or h % hkv or (ap.rotary and hd % 2):
+        raise ValueError(
+            f"GroupedQueryAttention: {h} heads of {hd} over {hkv} "
+            "key/value heads (num_heads must be a multiple of "
+            "num_kv_heads, a rotary head_dim even)")
+    return h, hkv, hd
+
+
+def _gqa_params(lp, shapes):
+    ap = lp.attention_param
+    d = math.prod(shapes[0][2:]) if len(shapes[0]) > 2 else 1
+    h, hkv, hd = _gqa_heads(ap)
+    wf = _filler(ap.weight_filler if ap.has("weight_filler") else None,
+                 "xavier")
+    one = FillerParameter(type="constant", value=1.0)
+    specs = [("W_q", (h * hd, d), wf), ("W_k", (hkv * hd, d), wf),
+             ("W_v", (hkv * hd, d), wf), ("W_o", (d, h * hd), wf)]
+    if ap.qk_norm:
+        specs += [("q_norm", (hd,), one), ("k_norm", (hd,), one)]
+    return specs
+
+
+@register("GroupedQueryAttention", params=_gqa_params)
+def _gqa(ctx, lp, params, bottoms):
+    """Self-attention with fewer key/value heads than query heads
+    (lfm2, and most dense decoders since) on time-major (T, B, D) input:
+
+        q = x W_q -> H x head_dim;  k = x W_k, v = x W_v -> H/g x head_dim
+        qk_norm: q <- RMSNorm(q), k <- RMSNorm(k) over each head, one
+                 head_dim-wide scale each, shared by the heads
+        rotary:  adjacent pairs of the WHOLE head turn by position t
+                 with base rope_theta, after the norms
+        o = softmax(q k^T / sqrt(head_dim), causal) v, query head h
+            reading key/value head h // g;  y = o W_o
+
+    The attention itself is `_attention_dispatch`, the one the other
+    two attention types take; products are as `LatentAttention`'s (one
+    bfloat16 pass on the TPU at the default precision)."""
+    ap = lp.attention_param
+    w_q, w_k, w_v, w_o = params[:4]
+    x = bottoms[0]
+    t, b = x.shape[0], x.shape[1]
+    h, hkv, hd = _gqa_heads(ap)
+    prec = ctx.precision()
+    xf = x.reshape(t, b, -1)
+    with jax.named_scope("attn"):
+        q, k, v = (jnp.einsum("tbd,ed->tbe", xf, w, precision=prec
+                              ).reshape(t, b, n, hd)
+                   for w, n in ((w_q, h), (w_k, hkv), (w_v, hkv)))
+        if ap.qk_norm:
+            eps = float(ap.rms_norm_eps)
+            q, k = rms_norm(q, params[4], eps), rms_norm(k, params[5], eps)
+        if ap.rotary:
+            theta = float(ap.rope_theta)
+            q, k = rope_adjacent(q, theta), rope_adjacent(k, theta)
+        # (T, B, heads, hd) -> (B, heads, T, hd)
+        q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
+        o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
+                                mxu_dtype=_kernel_operand_dtype(prec, q))
+        o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * hd)
+        return [jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)]
+
+
+def _short_conv_params(lp, shapes):
+    cp = lp.short_conv_param
+    d = int(shapes[0][-1])
+    taps = int(cp.taps)
+    if taps < 1:
+        raise ValueError(f"ShortConv {lp.name!r}: taps {taps}")
+    wf = _filler(cp.weight_filler if cp.has("weight_filler") else None,
+                 "xavier")
+    specs = [("W_in", (3 * d, d), wf), ("taps", (d, taps), wf),
+             ("W_out", (d, d), wf)]
+    if cp.bias_term:
+        specs.append(("bias", (d,),
+                      FillerParameter(type="constant", value=0.0)))
+    return specs
+
+
+def short_conv_mix(b, c, u, taps, bias=None):
+    """c * conv(b * u): the gates and the depthwise causal convolution
+    over time (axis 0) of the gated short convolution.  taps (D, L);
+    tap j multiplies the input at t - (L - 1) + j, zero before t = 0.
+    Shifted slices of one padded array, so XLA fuses gate, shifts and
+    gate into one pass."""
+    z = b * u
+    n_taps, t = taps.shape[1], z.shape[0]
+    zp = jnp.pad(z, ((n_taps - 1, 0),) + ((0, 0),) * (z.ndim - 1))
+    conv = sum(zp[j:j + t] * taps[:, j].astype(z.dtype)
+               for j in range(n_taps))
+    if bias is not None:
+        conv = conv + bias.astype(z.dtype)
+    return c * conv
+
+
+@register("ShortConv", params=_short_conv_params)
+def _short_conv(ctx, lp, params, bottoms):
+    """The gated short convolution (lfm2's `conv` operator) on
+    time-major (T, B, D) input:
+
+        [b, c, u] = split3(x W_in)      three D-wide parts, in this order
+        z = b * u
+        v[t] = sum_j taps[:, j] * z[t - (L - 1) + j]   per channel, causal
+        y = (c * v) W_out
+
+    No state crosses a batch column, and nothing marks a document's
+    start inside a packed row (L - 1 tokens of the document before
+    reach over a boundary)."""
+    w_in, taps, w_out = params[:3]
+    x = bottoms[0]
+    d = x.shape[-1]
+    prec = ctx.precision()
+    with jax.named_scope("sconv"):
+        bcu = jnp.einsum("...d,ed->...e", x, w_in, precision=prec)
+        with jax.named_scope("sconv.mix"):
+            y = short_conv_mix(bcu[..., :d], bcu[..., d:2 * d],
+                               bcu[..., 2 * d:], taps,
+                               params[3] if len(params) > 3 else None)
+        return [jnp.einsum("...d,ed->...e", y, w_out, precision=prec)]
 
 
 def _moe_held(mp):
@@ -1668,7 +1812,8 @@ def _moe_dropless(ctx, lp, params, bottoms):
     Router: s = sigmoid(x W_g) (or softmax) over ALL `num_experts`, in
     float32 at HIGHEST precision (a routing choice must not turn on a
     bfloat16 rounding); the k experts with the largest s + bias are
-    chosen; weights s_i / sum(s chosen) x routed_scaling_factor.
+    chosen; weights s_i / (sum(s chosen) + norm_epsilon) x
+    routed_scaling_factor.
 
     Dispatch: the k·N assignments are sorted by expert, those of
     experts held elsewhere last.  The sorted rows are taken in passes
@@ -1710,7 +1855,10 @@ def _moe_dropless(ctx, lp, params, bottoms):
         _, topi = lax.top_k(sel, k)                          # (N, k)
         topv = jnp.take_along_axis(scores, topi, axis=1)
         if k > 1:
-            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+            total_s = jnp.sum(topv, axis=-1, keepdims=True)
+            if float(mp.norm_epsilon):
+                total_s = total_s + float(mp.norm_epsilon)
+            topv = topv / total_s
         gates = (topv * float(mp.routed_scaling_factor)).reshape(-1)
         # token-major flattening: assignment a belongs to token a // k
         local = topi.reshape(-1) - first

@@ -423,8 +423,10 @@ def pallas_enabled() -> bool:
 #
 # Layout: q,k,v (B, H, T, D) flattened to (B·H, T, D); grid =
 # (B·H, T/block).  K/V block specs expose the full (T, D) per head —
-# VMEM-bounded at T·D·4 bytes ≈ 4 MB at T=8k, D=128 f32 (longer
-# sequences belong to ring attention's shards anyway).
+# VMEM-bounded at T·D·4 bytes ≈ 4 MB at T=8k, D=128 f32; rows beyond
+# 4,096 are cut into chunks whose calls fit the default VMEM window and
+# run pair by pair (`_flash_chunk`: the same composition the ring makes
+# across devices).
 
 BLOCK_Q = 128
 BLOCK_K = 128
@@ -591,16 +593,114 @@ def _flash_specs(block, d, t):
     return qspec, kvspec, vec, vec_full
 
 
+def _flash_kv_specs(block, d, t, g):
+    """(one block of `block` rows, all t rows) of a k / v array that
+    holds one head for every `g` heads of the grid's first axis: program
+    b*H + h reads head b*(H/g) + h // g = (b*H + h) // g, so grouped
+    queries read their shared keys and values in place.  g = 1 gives
+    `_flash_specs`' own maps."""
+    if g == 1:
+        blk, full, _, _ = _flash_specs(block, d, t)
+        return blk, full
+    return (pl.BlockSpec((1, block, d), lambda b, i, *_: (b // g, i, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i, *_: (b // g, 0, 0)))
+
+
+def _lanes(width: int) -> int:
+    """What a block's last dimension takes in VMEM: a head narrower
+    than the 128 lanes of a tile is padded to them (64-wide heads cost
+    what 128-wide ones do).  Widths from 128 up count as they are, as
+    they always have here, so the calls of the shapes the tree already
+    ran ask for what they asked."""
+    return max(width, 128)
+
+
+# What XLA's memory-space assignment leaves every op on the v5e, a
+# Mosaic call included, whatever window the call itself asks for: its
+# own VMEM buffers that live across the call lie from here up (PR 33).
+_SCOPED_VMEM = 16 << 20
+# Rows up to which a call may still ask for a larger window: the calls
+# the tree already ran on the chip (kanana2's at 4,096) keep their
+# lowering.
+_ASK_UP_TO_T = 4096
+
+
+def _flash_window(block_bytes: int) -> int:
+    """VMEM a call takes: its blocks double-buffered, and room for
+    Mosaic's own scratch."""
+    return 2 * block_bytes + (4 << 20)
+
+
 def _flash_compiler_params(block_bytes: int, interpret: bool):
     """Mosaic's default scoped VMEM (16 MiB on the v5e) holds the
     double-buffered blocks of a call up to T ~ 2k at 128-wide heads;
     beyond that the call asks for what its blocks need (the chip has
-    128 MiB).  {} below the default, so small calls compile as before."""
-    need = 2 * block_bytes + (4 << 20)
-    if interpret or need <= (16 << 20):
+    128 MiB).  {} below the default, so small calls compile as before.
+    Rows beyond `_ASK_UP_TO_T` never come here over the default:
+    `_flash_chunk` cuts them first."""
+    need = _flash_window(block_bytes)
+    if interpret or need <= _SCOPED_VMEM:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(need, 100 << 20))}
+
+
+def _flash_chunk(t: int, block: int, *block_bytes) -> int:
+    """Rows of q and of k / v a single call takes.  A window above the
+    default is not safe: XLA places the VMEM buffers it keeps across a
+    Mosaic call as if the call took the default 16 MiB, so a call that
+    uses more writes over them (the sorted token ids of the embedding's
+    scatter-add among them: a step of lfm2 at one row of 8,192 tokens
+    never ended, PR 33).  So rows beyond `_ASK_UP_TO_T` are halved
+    until every one of `block_bytes` (functions of the rows: one for
+    each kernel of the call) fits the default window; the caller runs
+    the pairs of chunks and adds them up.  t itself where it fits or
+    is short enough to ask; a length no halving brings inside is an
+    error, not a call that may never end."""
+    def fits(n):
+        return all(_flash_window(f(n)) <= _SCOPED_VMEM
+                   for f in block_bytes)
+    if t <= _ASK_UP_TO_T or fits(t):
+        return t
+    c = t
+    while not fits(c):
+        if c % 2 or (c // 2) % block:
+            raise ValueError(
+                f"flash_attention: {t} rows cannot be halved into "
+                f"chunks of whole {block}-row blocks that fit "
+                f"{_SCOPED_VMEM >> 20} MiB of VMEM (stopped at {c})")
+        c //= 2
+    return c
+
+
+def _chunk_pairs(n: int, causal: bool):
+    """(q chunk, k / v chunk, causal) of every pair that holds a score:
+    under the causal mask the pairs below the diagonal are whole, the
+    diagonal ones masked by their local positions (the chunks are
+    equally long), the ones above it empty."""
+    return [(i, j, causal and i == j) for i in range(n)
+            for j in range(i + 1 if causal else n)]
+
+
+def _rows(x, i, c):
+    return jax.lax.slice_in_dim(x, i * c, (i + 1) * c, axis=1)
+
+
+def _fwd_block_bytes(d, dv, isz, block_q):
+    return lambda t: (t * (_lanes(d) + _lanes(dv)) * isz
+                      + block_q * (_lanes(d) + _lanes(dv) + 128) * 4)
+
+
+def _dq_block_bytes(d, dv, isz, block_q):
+    return lambda t: (t * (_lanes(d) + _lanes(dv)) * isz
+                      + block_q * (2 * _lanes(d) + _lanes(dv) + 256) * 4)
+
+
+def _dkv_block_bytes(d, dv, isz, block_k):
+    # the two per-row statistics travel as (T, 1) columns, which VMEM
+    # pads to 128 lanes
+    return lambda t: (t * (_lanes(d) + _lanes(dv)) * isz + 2 * t * 128 * 4
+                      + block_k * 2 * (_lanes(d) + _lanes(dv)) * 4)
 
 
 def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
@@ -615,12 +715,35 @@ def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
         raise ValueError(
             f"flash_attention needs T divisible by the blocks: "
             f"t={t} % block_q={block_q}, t={t} % block_k={block_k}")
+    isz = q.dtype.itemsize
+    block_bytes = _fwd_block_bytes(d, dv, isz, block_q)
+    c = _flash_chunk(t, max(block_q, block_k), block_bytes)
+    if c < t:
+        # each chunk of q against the chunks of k / v it sees, one call
+        # a pair; a row's parts are weighted by their share of its
+        # softmax sum
+        outs, lses = [], []
+        for i in range(t // c):
+            parts = [_flash_fwd_call(_rows(q, i, c), _rows(k, j, c),
+                                     _rows(v, j, c), sm_scale, cz,
+                                     block_q, block_k, interpret,
+                                     mxu_dtype)
+                     for qi, j, cz in _chunk_pairs(t // c, causal)
+                     if qi == i]
+            lse = functools.reduce(jnp.logaddexp, [p[1] for p in parts])
+            outs.append(sum(o * jnp.exp(l - lse)[:, :, None].astype(
+                o.dtype) for o, l in parts))
+            lses.append(lse)
+        return (jnp.concatenate(outs, axis=1),
+                jnp.concatenate(lses, axis=1))
     kern = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                              causal=causal, block_k=block_k,
                              mxu_dtype=mxu_dtype)
-    qspec, kspec, vec, _ = _flash_specs(block_q, d, t)
-    ospec, vspec, _, _ = _flash_specs(block_q, dv, t)
-    isz = q.dtype.itemsize
+    g = bh // k.shape[0]            # query heads a key/value head
+    qspec, _, vec, _ = _flash_specs(block_q, d, t)
+    ospec, _, _, _ = _flash_specs(block_q, dv, t)
+    _, kspec = _flash_kv_specs(block_q, d, t, g)
+    _, vspec = _flash_kv_specs(block_q, dv, t, g)
     out, lse = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
@@ -630,10 +753,18 @@ def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
         out_specs=(ospec, vec),
         interpret=interpret,
         name="cos_flash_fwd",
-        **_flash_compiler_params(
-            t * (d + dv) * isz + block_q * (d + dv + 128) * 4, interpret),
+        **_flash_compiler_params(block_bytes(t), interpret),
     )(q, k, v)
     return out, lse[:, :, 0]
+
+
+def _flash_flatten(q, k, v):
+    """(B, heads, T, ·) -> (B·heads, T, ·), each with its own heads."""
+    if q.shape[1] % k.shape[1] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"flash_attention: {q.shape[1]} query heads over "
+            f"{k.shape[1]} key / {v.shape[1]} value heads")
+    return tuple(x.reshape((-1,) + x.shape[2:]) for x in (q, k, v))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -642,9 +773,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: int = BLOCK_K,
                     interpret: bool = False,
                     mxu_dtype=None) -> jax.Array:
-    """Fused blockwise attention, q/k (B, H, T, D), v (B, H, T, Dv) →
-    (B, H, T, Dv); Dv may differ from D (latent attention: 192-wide
-    q/k, 128-wide v).
+    """Fused blockwise attention, q (B, H, T, D), k (B, H/g, T, D),
+    v (B, H/g, T, Dv) → (B, H, T, Dv); Dv may differ from D (latent
+    attention: 192-wide q/k, 128-wide v), and with g > 1 query head h
+    reads key/value head h // g through the kernels' block index maps
+    (no repeated copy of k or v; dK/dV come out a query head and are
+    summed over each group).
 
     Same math as parallel.sp.attention (softmax(QKᵀ/√D)V, optional
     causal mask); O(block·T) VMEM instead of an O(T²) HBM score
@@ -655,8 +789,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     the softmax statistics and the outputs stay float32/input dtype."""
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
-    qf, kf = (x.reshape(b * h, t, d) for x in (q, k))
-    vf = v.reshape(b * h, t, v.shape[-1])
+    qf, kf, vf = _flash_flatten(q, k, v)
     out, _ = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
                              block_k, interpret, mxu_dtype)
     return out.reshape(b, h, t, v.shape[-1])
@@ -666,8 +799,7 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
                    mxu_dtype):
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
-    qf, kf = (x.reshape(b * h, t, d) for x in (q, k))
-    vf = v.reshape(b * h, t, v.shape[-1])
+    qf, kf, vf = _flash_flatten(q, k, v)
     out, lse = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
                                block_k, interpret, mxu_dtype)
     return out.reshape(b, h, t, v.shape[-1]), (qf, kf, vf, out, lse)
@@ -678,7 +810,9 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
                     out_dtype=None, mxu_dtype=None):
     """dq, dk, dv for one (q-group, kv-block) attention pair from the
     saved stats — the flash backward building block.  All operands
-    flattened (B·H, T, D) / (B·H, T), v and dO (B·H, T, Dv); `causal`
+    flattened (B·H, T, D) / (B·H, T), v and dO (B·H, T, Dv); k and v
+    may hold B·H/g heads (grouped queries), and dk, dv then come back
+    summed over each group, in k's and v's shapes; `causal`
     masks with LOCAL
     positions, so callers composing cross-shard pairs (ring backward,
     parallel/sp.py) pass causal=True only for the diagonal pair and
@@ -687,14 +821,36 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
     don't round each per-hop partial before the sum."""
     bh, t, d = qf.shape
     dv_w = vf.shape[-1]
+    isz = qf.dtype.itemsize
+    dq_bytes = _dq_block_bytes(d, dv_w, isz, block_q)
+    dkv_bytes = _dkv_block_bytes(d, dv_w, isz, block_k)
+    c = _flash_chunk(t, max(block_q, block_k), dq_bytes, dkv_bytes)
+    if c < t:
+        # lse and delta are whole rows' statistics, so the pairs of
+        # chunks add up: dq over a q chunk's pairs, dk and dv over a
+        # k / v chunk's
+        n = t // c
+        dqs, dks, dvs = [None] * n, [None] * n, [None] * n
+        for i, j, cz in _chunk_pairs(n, causal):
+            part = flash_bwd_block(
+                _rows(qf, i, c), _rows(kf, j, c), _rows(vf, j, c),
+                _rows(dof, i, c), _rows(lse, i, c), _rows(delta, i, c),
+                causal=cz, block_q=block_q, block_k=block_k,
+                interpret=interpret, out_dtype=out_dtype,
+                mxu_dtype=mxu_dtype)
+            for acc, at, x in zip((dqs, dks, dvs), (i, j, j), part):
+                acc[at] = x if acc[at] is None else acc[at] + x
+        return tuple(jnp.concatenate(a, axis=1) for a in (dqs, dks, dvs))
     sm_scale = 1.0 / math.sqrt(d)
     lse = lse[:, :, None]          # (bh, t, 1): see _flash_specs
     delta = delta[:, :, None]
-    qspec, kfull, vec, vec_full = _flash_specs(block_q, d, t)
-    dospec, vfull, _, _ = _flash_specs(block_q, dv_w, t)
-    kspec_b, qfull, _, _ = _flash_specs(block_k, d, t)
-    vspec_b, dofull, _, _ = _flash_specs(block_k, dv_w, t)
-    isz = qf.dtype.itemsize
+    g = bh // kf.shape[0]           # query heads a key/value head
+    qspec, qfull, vec, vec_full = _flash_specs(block_q, d, t)
+    dospec, dofull, _, _ = _flash_specs(block_q, dv_w, t)
+    dkspec, _, _, _ = _flash_specs(block_k, d, t)
+    dvspec, _, _, _ = _flash_specs(block_k, dv_w, t)
+    kspec_b, kfull = _flash_kv_specs(block_k, d, t, g)
+    vspec_b, vfull = _flash_kv_specs(block_k, dv_w, t, g)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, block_k=block_k,
@@ -706,9 +862,7 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
         out_specs=qspec,
         interpret=interpret,
         name="cos_flash_bwd_dq",
-        **_flash_compiler_params(
-            t * (d + dv_w) * isz + block_q * (2 * d + dv_w + 256) * 4,
-            interpret),
+        **_flash_compiler_params(dq_bytes(t), interpret),
     )(qf, kf, vf, dof, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
@@ -720,15 +874,16 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
                                         out_dtype or vf.dtype)),
         grid=(bh, t // block_k),
         in_specs=[qfull, kspec_b, vspec_b, dofull, vec_full, vec_full],
-        out_specs=(kspec_b, vspec_b),
+        out_specs=(dkspec, dvspec),
         interpret=interpret,
         name="cos_flash_bwd_dkv",
-        # the two per-row statistics travel as (T, 1) columns, which
-        # VMEM pads to 128 lanes
-        **_flash_compiler_params(
-            t * (d + dv_w) * isz + 2 * t * 128 * 4
-            + block_k * 2 * (d + dv_w) * 4, interpret),
+        **_flash_compiler_params(dkv_bytes(t), interpret),
     )(qf, kf, vf, dof, lse, delta)
+    if g > 1:
+        # one dk, dv a query head: the group's sum is its key/value
+        # head's gradient
+        dk = dk.reshape(bh // g, g, t, d).sum(axis=1)
+        dv = dv.reshape(bh // g, g, t, dv_w).sum(axis=1)
     return dq, dk, dv
 
 
@@ -745,8 +900,9 @@ def _flash_vjp_bwd(causal, block_q, block_k, interpret, mxu_dtype, res,
                                  block_k=block_k, interpret=interpret,
                                  mxu_dtype=mxu_dtype)
     lead = do.shape[:3]
-    return (dq.reshape(lead + (d,)), dk.reshape(lead + (d,)),
-            dv.reshape(do.shape))
+    kv_lead = (lead[0], kf.shape[0] // lead[0], t)
+    return (dq.reshape(lead + (d,)), dk.reshape(kv_lead + (d,)),
+            dv.reshape(kv_lead + (vf.shape[-1],)))
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -205,6 +205,8 @@ def tp_param_specs(net, *, min_features: int = TP_MIN_FEATURES
                 # the dropless dispatch's gated experts alike (router,
                 # selection bias and shared experts stay replicated)
                 spec = P("ep", None, None)
+            # the three attention types and ShortConv stay replicated:
+            # their head / channel splits are not written
             specs[lname][bname] = spec
     return specs
 

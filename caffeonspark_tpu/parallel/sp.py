@@ -40,15 +40,23 @@ def shard_map_nocheck(fn, mesh, in_specs, out_specs):
 
 def attention(q: Array, k: Array, v: Array, *, causal: bool = False,
               q_offset: int = 0, k_offset: int = 0) -> Array:
-    """Reference softmax attention. q,k,v: (B, H, T, D)."""
+    """Reference softmax attention. q,k,v: (B, H, T, D); k and v may
+    hold H / g heads, query head h then reads key/value head h // g."""
     scale = 1.0 / math.sqrt(q.shape[-1])
+    b, h, tq, _ = q.shape
+    g = h // k.shape[1]
+    if g > 1:       # the group as rows of q: no repeated k or v
+        q = q.reshape(b, h // g, g * tq, q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
-        qpos = q_offset + jnp.arange(q.shape[2])[:, None]
+        qpos = q_offset + jnp.arange(tq)[:, None]
         kpos = k_offset + jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(qpos >= kpos, s, -jnp.inf)
+        mask = qpos >= kpos
+        s = jnp.where(jnp.tile(mask, (g, 1)) if g > 1 else mask, s,
+                      -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return o.reshape(b, h, tq, -1) if g > 1 else o
 
 
 def flash_block_size(t: int):

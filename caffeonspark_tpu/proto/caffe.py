@@ -583,6 +583,9 @@ class MoEParameter(Message):
         Field(11, "shared_hidden_dim", UINT32, default=0),
         Field(12, "experts_held", UINT32, default=0),
         Field(13, "first_expert", UINT32, default=0),
+        # added to the sum of the k chosen scores before the division
+        # (lfm2_moe: 1e-6); 0 = the bare sum
+        Field(14, "norm_epsilon", FLOAT, default=0.0),
     ]
 
 
@@ -598,7 +601,14 @@ class AttentionParameter(Message):
     rotary key shared by all heads; RMSNorm(latent) `W_kvb` gives
     `num_heads` x (`qk_nope_head_dim` keys + `v_head_dim` values);
     rotary positions (adjacent pairs, `rope_theta`) turn the rope
-    parts; q/k are nope + rope wide, v is `v_head_dim` wide.  Both
+    parts; q/k are nope + rope wide, v is `v_head_dim` wide.
+    `GroupedQueryAttention`: separate `W_q` (`num_heads` x `head_dim`),
+    `W_k` / `W_v` (`num_kv_heads` x `head_dim`; 0 = `num_heads`) and
+    `W_o`; query head h reads key/value head h // (num_heads /
+    num_kv_heads); `qk_norm` puts an RMSNorm (`rms_norm_eps`, one
+    `head_dim`-wide scale each, shared by the heads) on every q and k
+    head; `rotary` turns adjacent pairs of the whole head by
+    `rope_theta` after the norms.  All
     types share one attention dispatch (flash kernel on the TPU when
     the shape tiles, XLA einsums otherwise); GSPMD partitions the
     einsums over whatever mesh axes the activations carry."""
@@ -613,6 +623,23 @@ class AttentionParameter(Message):
         Field(8, "v_head_dim", UINT32, default=0),
         Field(9, "rope_theta", FLOAT, default=10000.0),
         Field(10, "rms_norm_eps", FLOAT, default=1e-6),
+        Field(11, "num_kv_heads", UINT32, default=0),
+        Field(12, "qk_norm", BOOL, default=False),
+        Field(13, "rotary", BOOL, default=False),
+    ]
+
+
+class ShortConvParameter(Message):
+    """Extension: the gated short convolution (`ShortConv`, lfm2) on
+    time-major (T, B, D) input: `[b, c, u] = split3(x W_in)`, a
+    depthwise causal convolution of `taps` taps over time on `b * u`
+    (zero before t = 0), `y = (c * conv) W_out`.  Blobs `W_in` (3D, D),
+    `taps` (D, taps; tap j multiplies the input at t - (taps - 1) + j),
+    `W_out` (D, D), then with `bias_term` the convolution's (D,) bias."""
+    FIELDS = [
+        Field(1, "taps", UINT32, default=3),
+        Field(2, "bias_term", BOOL, default=False),
+        Field(3, "weight_filler", MESSAGE, message=FillerParameter),
     ]
 
 
@@ -649,6 +676,7 @@ class LayerParameter(Message):
         Field(149, "attention_param", MESSAGE, message=AttentionParameter),
         Field(150, "moe_param", MESSAGE, message=MoEParameter),
         Field(151, "rms_norm_param", MESSAGE, message=RMSNormParameter),
+        Field(153, "short_conv_param", MESSAGE, message=ShortConvParameter),
         # consecutive layers that give the same non-empty name form one
         # block whose activations are recomputed in the backward pass
         # (Net.apply: one jax.checkpoint around the block); COS_REMAT

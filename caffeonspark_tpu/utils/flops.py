@@ -2,7 +2,7 @@
 
 Counts the multiply-accumulate work of the parametrised layers
 (Convolution / Deconvolution / InnerProduct / LSTM-style weights,
-MultiHeadAttention / LatentAttention, MixtureOfExperts) from
+the three attention types, ShortConv, MixtureOfExperts) from
 the weight blob shapes and inferred top shapes — the >99% of CaffeNet's
 arithmetic that lands on the MXU.  Elementwise layers (ReLU, LRN,
 Pooling, Softmax) are ignored; they are HBM-bound, not FLOP-bound.
@@ -73,6 +73,24 @@ def layer_forward_flops(net) -> dict:
                          + int(ap.qk_rope_head_dim)
                          + int(ap.v_head_dim)))
             out[lp.name] = total
+            continue
+        if lp.type == "GroupedQueryAttention":
+            # the four projections per (t, b) position (W_k and W_v at
+            # their own fewer heads), plus causal attention over
+            # head_dim wide q/k and v for every QUERY head
+            t_s, b_s = first_top[0], first_top[1]
+            ap = lp.attention_param
+            total = 2 * t_s * b_s * sum(
+                prod(ps) for (_, ps, _) in specs if len(ps) == 2)
+            total += (2 * b_s * int(ap.num_heads) * t_s * t_s // 2
+                      * 2 * int(ap.head_dim))
+            out[lp.name] = total
+            continue
+        if lp.type == "ShortConv":
+            # W_in and W_out per position; the taps and the two gates
+            # are elementwise passes (HBM-bound), not counted
+            out[lp.name] = 2 * prod(first_top[:-1]) * sum(
+                prod(ps) for (n, ps, _) in specs if n in ("W_in", "W_out"))
             continue
         if lp.type == "MixtureOfExperts":
             out[lp.name] = _moe_forward_flops(lp, dict(

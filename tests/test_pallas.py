@@ -195,45 +195,126 @@ def test_lrn_pallas_bf16_io_f32_normalizer():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("t,block", [(256, 128), (64, 64), (384, 128)])
-def test_flash_attention_matches_reference(causal, t, block):
+@pytest.mark.parametrize("t,block,h,g,d", [
+    (256, 128, 3, 1, 32), (64, 64, 3, 1, 32), (384, 128, 3, 1, 32),
+    # grouped queries: h heads over h / g key/value heads of 64
+    (256, 128, 8, 4, 64), (1024, 128, 8, 4, 64)])
+def test_flash_attention_matches_reference(causal, t, block, h, g, d):
     """Flash fwd parity vs the einsum reference (interpret mode)."""
     from caffeonspark_tpu.ops.pallas_kernels import flash_attention
     from caffeonspark_tpu.parallel.sp import attention
     rng = np.random.RandomState(0)
-    b, h, d = 2, 3, 32
+    b = 2
     q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
     ref = attention(q, k, v, causal=causal)
+    if g > 1:       # the einsum path itself against repeated heads
+        rep = attention(q, jnp.repeat(k, g, axis=1),
+                        jnp.repeat(v, g, axis=1), causal=causal)
+        np.testing.assert_allclose(np.asarray(ref), np.asarray(rep),
+                                   rtol=2e-5, atol=2e-5)
     out = flash_attention(q, k, v, causal, block, block, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads_match_reference(causal):
-    """Flash bwd kernels (dq/dk/dv) vs jax.grad of the reference."""
+@pytest.mark.parametrize("t,h,g,d", [
+    (256, 2, 1, 16), (256, 8, 4, 64), (1024, 8, 4, 64)])
+def test_flash_attention_grads_match_reference(causal, t, h, g, d):
+    """Flash bwd kernels (dq/dk/dv) vs jax.grad of the reference; with
+    g > 1 dk and dv are the sums over each group of query heads."""
     from caffeonspark_tpu.ops.pallas_kernels import flash_attention
     from caffeonspark_tpu.parallel.sp import attention
     rng = np.random.RandomState(1)
-    b, h, t, d = 2, 2, 256, 16
+    b = 2
     q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
 
     def scal(fn):
         return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
 
-    gr = jax.grad(scal(lambda q, k, v: attention(q, k, v,
-                                                 causal=causal)),
-                  argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(scal(lambda q, k, v: attention(
+        q, jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1),
+        causal=causal)), argnums=(0, 1, 2))(q, k, v)
     gf = jax.grad(scal(lambda q, k, v: flash_attention(
         q, k, v, causal, 128, 128, True)), argnums=(0, 1, 2))(q, k, v)
     for name, a, b_ in zip("qkv", gr, gf):
+        assert a.shape == b_.shape
         np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
-                                   rtol=2e-4, atol=1e-5,
+                                   rtol=2e-4, atol=1e-5 * g,
                                    err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("h,g,d,dv,room", [
+    (8, 4, 64, 64, 1_100_000), (4, 1, 192, 128, 1_500_000)])
+def test_flash_attention_in_chunks_matches_reference(monkeypatch, causal,
+                                                     h, g, d, dv, room):
+    """Rows too long for the default VMEM window go as pairs of chunks
+    (`_flash_chunk`): the forward parts weighted by their share of the
+    softmax sum, dq / dk / dv added over the pairs.  The window is made
+    small here (`room` bytes over Mosaic's own scratch) so that 512
+    rows are cut, forward in 2 chunks of 256 and backward in 4 of 128,
+    as 8,192 are on the chip."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    from caffeonspark_tpu.parallel.sp import attention
+    monkeypatch.setattr(pk, "_ASK_UP_TO_T", 0)
+    monkeypatch.setattr(pk, "_SCOPED_VMEM", (4 << 20) + room)
+    t, b = 512, 2
+    fwd = pk._flash_chunk(t, 128, pk._fwd_block_bytes(d, dv, 4, 128))
+    bwd = pk._flash_chunk(t, 128, pk._dq_block_bytes(d, dv, 4, 128),
+                          pk._dkv_block_bytes(d, dv, 4, 128))
+    assert (fwd, bwd) == (256, 128)
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, h // g, t, dv), jnp.float32)
+
+    def scal(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+    ref = lambda q, k, v: attention(q, k, v, causal=causal)  # noqa: E731
+    fl = lambda q, k, v: pk.flash_attention(                  # noqa: E731
+        q, k, v, causal, 128, 128, True)
+    np.testing.assert_allclose(np.asarray(fl(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    gr = jax.grad(scal(ref), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.grad(scal(fl), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("qkv", gr, gf):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
+                                   rtol=2e-4, atol=1e-5 * g,
+                                   err_msg=f"d{name}")
+
+
+def test_flash_chunk_keeps_long_rows_inside_the_default_window():
+    """8,192 rows of 64-wide heads: 4,096 a forward call, 2,048 a
+    backward one; the shapes the tree already ran on the chip (up to
+    4,096 rows) stay one call."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+
+    def chunks(t, d, dv):
+        return (pk._flash_chunk(t, 128, pk._fwd_block_bytes(d, dv, 4, 128)),
+                pk._flash_chunk(t, 128, pk._dq_block_bytes(d, dv, 4, 128),
+                                pk._dkv_block_bytes(d, dv, 4, 128)))
+
+    assert chunks(8192, 64, 64) == (4096, 2048)
+    assert chunks(4096, 64, 64) == (4096, 4096)
+    assert chunks(4096, 192, 128) == (4096, 4096)      # kanana2's
+    assert chunks(8192, 192, 128) == (4096, 2048)
+    assert chunks(1024, 64, 64) == (1024, 1024)
+    # a length no halving brings inside is refused, not sent to a
+    # window XLA does not keep free
+    with pytest.raises(ValueError, match="cannot be halved"):
+        pk._flash_chunk(8192 + 128, 128,
+                        pk._fwd_block_bytes(64, 64, 4, 128))
+    assert pk._chunk_pairs(2, True) == [(0, 0, True), (1, 0, False),
+                                        (1, 1, True)]
+    assert len(pk._chunk_pairs(2, False)) == 4
 
 
 def test_flash_attention_rejects_indivisible_t():
