@@ -130,7 +130,11 @@ fault-injection plan as `info.faults` (tools/chaos.py — {"active":
 false} on clean runs, the exact injectors otherwise, so every drill
 and bench artifact is self-describing), and the sync-mode policy +
 final exchange counts as `info.sync` (COS_SYNC_MODE, K/staleness,
-exchanges / skipped / adopted / timeouts / max_gap).  The relaxed
+exchanges / skipped / adopted / timeouts / max_gap), and after the
+first step what its flash attention calls were lowered to as
+`info.flash` (per call shape and kernel: tiles, calls an attention,
+share of score tiles under the masked body;
+`ops.pallas_kernels.flash_plans`).  The relaxed
 sync modes also record a `sync_exchange` stage series (host-side
 round-average / global-merge wall time).  The continuous-deployment
 controller publishes `info.deploy` the same way (incumbent, verdict

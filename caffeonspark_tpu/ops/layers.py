@@ -1367,16 +1367,17 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
             elif not t_axes and tiles and t % 128 == 0:
                 spec = P(b_axes or None, h_axes or None, None, None)
                 fl = shard_map_nocheck(
+                    # the kernels size their own tiles from the
+                    # shard's shape (`pallas_kernels._flash_tiles`)
                     functools.partial(flash_attention, causal=causal,
-                                      block_q=128, block_k=128,
                                       interpret=interpret,
                                       mxu_dtype=mxu_dtype),
                     mesh, (spec, spec, spec), spec)
                 return fl(q, k, v)
             # shapes don't tile the mesh: einsum path below
         elif enabled and not _FLASH_MESH and t % 128 == 0:
-            return flash_attention(q, k, v, causal, 128, 128, interpret,
-                                   mxu_dtype)
+            return flash_attention(q, k, v, causal, interpret=interpret,
+                                   mxu_dtype=mxu_dtype)
         from ..parallel.sp import attention as _plain_attention
         return _plain_attention(q, k, v, causal=causal)
 

@@ -422,175 +422,211 @@ def pallas_enabled() -> bool:
 # device shards, flash within a shard.
 #
 # Layout: q,k,v (B, H, T, D) flattened to (B·H, T, D); grid =
-# (B·H, T/block).  K/V block specs expose the full (T, D) per head —
-# VMEM-bounded at T·D·4 bytes ≈ 4 MB at T=8k, D=128 f32; rows beyond
-# 4,096 are cut into chunks whose calls fit the default VMEM window and
-# run pair by pair (`_flash_chunk`: the same composition the ring makes
-# across devices).
+# (B·H, T/block).  K/V block specs expose the full (T, D) per head, in
+# the operand type of the products (cast once, before the call: 3 MB a
+# head at T=4k, 192/128-wide bf16); rows whose blocks and tiles do not
+# fit the default VMEM window are cut into chunks that do and run pair
+# by pair (`_flash_chunk`: the same composition the ring makes across
+# devices).  A score tile does only what it needs: the tiles wholly
+# under the causal diagonal run a body with no mask at all, the tiles
+# on it the masked one, and the tile sizes follow from the shape
+# (`_flash_tiles`).
 
-BLOCK_Q = 128
+BLOCK_Q = 128             # the floor of a tile, and the public default
 BLOCK_K = 128
 _NEG_INF = -1e30          # finite mask value: -inf NaNs the m-corr path
 
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T, contracted in place
+
+
+def _dot_nt(a, b):
+    """a @ b.T in float32, the transpose folded into the product (no
+    XLU pass over b)."""
+    return jax.lax.dot_general(a, b, _NT,
+                               preferred_element_type=jnp.float32)
+
 
 def _online_softmax_step(q, kb, vb, m, l, acc, *, sm_scale: float,
-                         causal: bool, q_pos, k_pos, mxu_dtype=None):
+                         visible=None):
     """One online-softmax accumulation (the flash/ring shared algebra):
-    scores for (q, kb) fold into the (m, l, acc) carry.  The m_safe
+    scores for (q, kb) fold into the (m, l, acc) carry; `visible`
+    (None = every score) is the causal mask of a tile that holds
+    masked scores.  The m_safe
     guard makes fully-masked-so-far rows accumulate exact zeros (a
     no-op for rows that have seen the causal diagonal).  m and l are
     (block_q, 1) column vectors — Mosaic's block-shape rule wants the
     per-row stats rank-2, and the column form broadcasts against the
-    (block_q, block_k) score strip with no reshapes."""
-    s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * sm_scale
-    if causal:
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    (block_q, block_k) score strip with no reshapes.  The products
+    take their operands as they come (the caller casts them once, to
+    the type the MXU is to see); p is cast to v's."""
+    s = _dot_nt(q, kb) * sm_scale
+    if visible is not None:
+        s = jnp.where(visible, s, _NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     m_safe = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, m_new)
     p = jnp.exp(s - m_safe)
     corr = jnp.exp(m - m_safe)
     l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-    if mxu_dtype is not None:
-        p = p.astype(mxu_dtype)
     acc_new = acc * corr + jnp.dot(
-        p, vb, preferred_element_type=jnp.float32)
+        p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
     return m_new, l_new, acc_new
 
 
+def _rows_at(i, block):
+    return pl.ds(pl.multiple_of(i * block, block), block)
+
+
+def _row_minus_col(rows: int, cols: int):
+    """(rows, cols) int32 of row index - column index: one tile's
+    causal mask is this against a scalar."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _diagonal_span(i, block, other):
+    """[lo, hi): the tiles of `other` rows along one axis that tile i of
+    `block` rows along the other axis meets on the causal diagonal (the
+    axes are equally long).  Before lo and from hi on a tile is whole or
+    empty: a q tile sees its k tiles before lo whole and none from hi
+    on; a k tile is seen by no q tile before lo and whole from hi on."""
+    return (i * block) // other, ((i + 1) * block + other - 1) // other
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      sm_scale: float, causal: bool, block_k: int,
-                      mxu_dtype=None):
-    # operands of every product: float32 (exact, several MXU passes)
-    # unless the caller asks for one bfloat16 pass with float32
-    # accumulation, which is what XLA's default precision gives the
-    # einsum path on the TPU
-    cd = mxu_dtype or jnp.float32
-    q = q_ref[0].astype(cd)                     # (block_q, D)
-    t = k_ref.shape[1]
+                      sm_scale: float, causal: bool, block_k: int):
+    # operands of every product arrive in the type the MXU is to see
+    # (float32: exact, several passes; bfloat16: one pass, float32
+    # accumulation, what XLA's default precision gives the einsum path
+    # on the TPU); no tile is cast here but p, which is made here
+    q = q_ref[0]                                # (block_q, D)
     block_q = q.shape[0]
     qi = pl.program_id(1)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
 
-    def body(i, carry):
-        m, l, acc = carry
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
-        k_pos = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        return _online_softmax_step(q, kb, vb, m, l, acc,
-                                    sm_scale=sm_scale, causal=causal,
-                                    q_pos=q_pos, k_pos=k_pos,
-                                    mxu_dtype=mxu_dtype)
+    def tile(i, carry, visible=None):
+        at = _rows_at(i, block_k)
+        return _online_softmax_step(q, k_ref[0, at, :], v_ref[0, at, :],
+                                    *carry, sm_scale=sm_scale,
+                                    visible=visible)
 
+    carry = (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32))
     if causal:
-        # K/V blocks starting past this q block's last row are fully
-        # masked — skipping them halves the causal pass's work
-        n_k = ((qi + 1) * block_q + block_k - 1) // block_k
+        # K/V tiles starting past this q tile's last row are fully
+        # masked and skipped, the ones ending at or before its first row
+        # hold no masked score and take the body without a mask
+        n_whole, n_k = _diagonal_span(qi, block_q, block_k)
+        carry = jax.lax.fori_loop(0, n_whole, tile, carry)
+        ahead = _row_minus_col(block_q, block_k)
+        # row qi*block_q + r sees column i*block_k + c
+        carry = jax.lax.fori_loop(
+            n_whole, n_k,
+            lambda i, carry: tile(
+                i, carry, ahead >= i * block_k - qi * block_q), carry)
     else:
-        n_k = t // block_k
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    a0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_k, body, (m0, l0, a0))
+        carry = jax.lax.fori_loop(0, k_ref.shape[1] // block_k, tile,
+                                  carry)
+    m, l, acc = carry
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, *, sm_scale: float,
-                          causal: bool, block_q: int, mxu_dtype=None):
-    cd = mxu_dtype or jnp.float32
-    kb = k_ref[0].astype(cd)                    # (block_k, D)
-    vb = v_ref[0].astype(cd)
-    t = q_ref.shape[1]
+                          causal: bool, block_q: int):
+    # the scores of this kernel are transposed, (block_k, block_q): both
+    # accumulations are then plain products of them (pT dO, dsT q), and
+    # the two per-row statistics broadcast as (1, block_q) rows
+    kb = k_ref[0]                               # (block_k, D)
+    vb = v_ref[0]
     block_k = kb.shape[0]
     ki = pl.program_id(1)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    n_q = q_ref.shape[1] // block_q
 
-    def body(i, carry):
+    def tile(i, carry, visible=None):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(cd)
-        dob = do_ref[0, pl.ds(i * block_q, block_q), :].astype(cd)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)]   # (block_q, 1)
-        dlt = delta_ref[0, pl.ds(i * block_q, block_q)]
-        s = jnp.dot(qb, kb.T,
-                    preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                     # exact probabilities
-        dv_new = dv + jnp.dot(p.astype(cd).T, dob,
-                              preferred_element_type=jnp.float32)
-        dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dlt) * sm_scale
-        dk_new = dk + jnp.dot(ds.astype(cd).T, qb,
-                              preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+        at = _rows_at(i, block_q)
+        qb = q_ref[0, at, :]
+        dob = do_ref[0, at, :]
+        st = _dot_nt(kb, qb) * sm_scale
+        if visible is not None:
+            st = jnp.where(visible, st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, :, at])    # exact probabilities
+        dv = dv + jnp.dot(pt.astype(dob.dtype), dob,
+                          preferred_element_type=jnp.float32)
+        dst = pt * (_dot_nt(vb, dob) - delta_ref[0, :, at])
+        dk = dk + jnp.dot(dst.astype(qb.dtype), qb,
+                          preferred_element_type=jnp.float32)
+        return dk, dv
 
-    zk = jnp.zeros((block_k, kb.shape[-1]), jnp.float32)
-    zv = jnp.zeros((block_k, vb.shape[-1]), jnp.float32)
-    # causal: q blocks ending before this k block's first row see only
-    # masked scores — start at the diagonal
-    i0 = (ki * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(i0, t // block_q, body, (zk, zv))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    carry = (jnp.zeros((block_k, kb.shape[-1]), jnp.float32),
+             jnp.zeros((block_k, vb.shape[-1]), jnp.float32))
+    # causal: q tiles ending before this k tile's first row see only
+    # masked scores and are skipped; the ones up to its last row lie on
+    # the diagonal; the rest hold no masked score
+    i_whole = 0
+    if causal:
+        i0, i_whole = _diagonal_span(ki, block_k, block_q)
+        behind = -_row_minus_col(block_k, block_q)
+        # row i*block_q + r sees column ki*block_k + c
+        carry = jax.lax.fori_loop(
+            i0, i_whole,
+            lambda i, carry: tile(
+                i, carry, behind >= ki * block_k - i * block_q), carry)
+    dk, dv = jax.lax.fori_loop(i_whole, n_q, tile, carry)
+    # d(scores) = ds * sm_scale: the scale once, on the accumulator
+    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, *, sm_scale: float,
-                         causal: bool, block_k: int, mxu_dtype=None):
-    cd = mxu_dtype or jnp.float32
-    qb = q_ref[0].astype(cd)                     # (block_q, D)
-    dob = do_ref[0].astype(cd)
+                         causal: bool, block_k: int):
+    qb = q_ref[0]                                # (block_q, D)
+    dob = do_ref[0]
     lse = lse_ref[0]                             # (block_q, 1)
     dlt = delta_ref[0]
-    t = k_ref.shape[1]
     block_q = qb.shape[0]
     qi = pl.program_id(1)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
 
-    def body(i, dq):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(cd)
-        s = jnp.dot(qb, kb.T,
-                    preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dlt) * sm_scale
-        return dq + jnp.dot(ds.astype(cd), kb,
+    def tile(i, dq, visible=None):
+        at = _rows_at(i, block_k)
+        kb = k_ref[0, at, :]
+        s = _dot_nt(qb, kb) * sm_scale
+        if visible is not None:
+            s = jnp.where(visible, s, _NEG_INF)
+        ds = jnp.exp(s - lse) * (_dot_nt(dob, v_ref[0, at, :]) - dlt)
+        return dq + jnp.dot(ds.astype(kb.dtype), kb,
                             preferred_element_type=jnp.float32)
 
+    dq = jnp.zeros((block_q, qb.shape[-1]), jnp.float32)
     if causal:
-        n_k = ((qi + 1) * block_q + block_k - 1) // block_k
+        n_whole, n_k = _diagonal_span(qi, block_q, block_k)
+        dq = jax.lax.fori_loop(0, n_whole, tile, dq)
+        ahead = _row_minus_col(block_q, block_k)
+        dq = jax.lax.fori_loop(
+            n_whole, n_k,
+            lambda i, dq: tile(
+                i, dq, ahead >= i * block_k - qi * block_q), dq)
     else:
-        n_k = t // block_k
-    dq = jax.lax.fori_loop(0, n_k, body,
-                           jnp.zeros((block_q, qb.shape[-1]),
-                                     jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+        dq = jax.lax.fori_loop(0, k_ref.shape[1] // block_k, tile, dq)
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_specs(block, d, t):
     # `*_` absorbs the scalar-prefetch refs appended to index-map args
     # when these specs are used under a PrefetchScalarGridSpec.
-    # Per-row stats (m/l/lse/delta) travel as (bh, t, 1) column vectors:
-    # Mosaic requires the last two block dims divisible by (8, 128) OR
-    # equal to the array dims — (block, 1) satisfies that ((1, block)
-    # from a rank-2 (bh, t) layout does not, and fails to lower).
+    # Per-row stats of a block of rows (m/l/lse/delta) travel as
+    # (bh, t, 1) column vectors: Mosaic requires the last two block
+    # dims divisible by (8, 128) OR equal to the array dims — (block, 1)
+    # satisfies that ((1, block) from a rank-2 (bh, t) layout does not,
+    # and fails to lower).  All t rows' stats travel as one (1, t) row
+    # of a (bh, 1, t) array: lane-dense, t x 4 bytes a head.
     qspec = pl.BlockSpec((1, block, d), lambda b, i, *_: (b, i, 0))
     kvspec = pl.BlockSpec((1, t, d), lambda b, i, *_: (b, 0, 0))
     vec = pl.BlockSpec((1, block, 1), lambda b, i, *_: (b, i, 0))
-    vec_full = pl.BlockSpec((1, t, 1), lambda b, i, *_: (b, 0, 0))
-    return qspec, kvspec, vec, vec_full
+    row_full = pl.BlockSpec((1, 1, t), lambda b, i, *_: (b, 0, 0))
+    return qspec, kvspec, vec, row_full
 
 
 def _flash_kv_specs(block, d, t, g):
@@ -607,42 +643,100 @@ def _flash_kv_specs(block, d, t, g):
 
 
 def _lanes(width: int) -> int:
-    """What a block's last dimension takes in VMEM: a head narrower
-    than the 128 lanes of a tile is padded to them (64-wide heads cost
-    what 128-wide ones do).  Widths from 128 up count as they are, as
-    they always have here, so the calls of the shapes the tree already
-    ran ask for what they asked."""
-    return max(width, 128)
+    """What a block's last dimension takes in VMEM: whole tiles of 128
+    lanes (64-wide heads cost what 128-wide ones do, 192-wide what
+    256-wide ones do)."""
+    return -(-width // 128) * 128
 
 
 # What XLA's memory-space assignment leaves every op on the v5e, a
 # Mosaic call included, whatever window the call itself asks for: its
 # own VMEM buffers that live across the call lie from here up (PR 33).
+# No flash call asks for more: a shape whose blocks and tiles do not
+# fit is cut into chunks that do (`_flash_chunk`).
 _SCOPED_VMEM = 16 << 20
-# Rows up to which a call may still ask for a larger window: the calls
-# the tree already ran on the chip (kanana2's at 4,096) keep their
-# lowering.
-_ASK_UP_TO_T = 4096
+_MOSAIC_ROOM = 1 << 19    # Mosaic's own scratch beside what is counted
 
 
-def _flash_window(block_bytes: int) -> int:
-    """VMEM a call takes: its blocks double-buffered, and room for
-    Mosaic's own scratch."""
-    return 2 * block_bytes + (4 << 20)
+def _flash_window(call_bytes: int) -> int:
+    """VMEM a call takes: what `_fwd/_dq/_dkv_block_bytes` count, and
+    room for Mosaic's own scratch."""
+    return call_bytes + _MOSAIC_ROOM
 
 
-def _flash_compiler_params(block_bytes: int, interpret: bool):
-    """Mosaic's default scoped VMEM (16 MiB on the v5e) holds the
-    double-buffered blocks of a call up to T ~ 2k at 128-wide heads;
-    beyond that the call asks for what its blocks need (the chip has
-    128 MiB).  {} below the default, so small calls compile as before.
-    Rows beyond `_ASK_UP_TO_T` never come here over the default:
-    `_flash_chunk` cuts them first."""
-    need = _flash_window(block_bytes)
-    if interpret or need <= _SCOPED_VMEM:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=min(need, 100 << 20))}
+def _fwd_block_bytes(d, dv, isz, block_q, block_k=BLOCK_K):
+    """VMEM bytes of a forward call over t rows at these tiles: the
+    blocks the pipeline double-buffers (q, all of k and v in operands of
+    `isz` bytes; the float32 output and the lse column, which VMEM pads
+    to 128 lanes) and the float32 tiles live in a step (s, p and the
+    mask; p again as an operand; two accumulators)."""
+    ld, ldv = _lanes(d), _lanes(dv)
+    return lambda t: (
+        2 * (t * (ld + ldv) * isz
+             + block_q * (ld * isz + (ldv + 128) * 4))
+        + block_q * (block_k * (12 + isz) + 2 * ldv * 4))
+
+
+def _dq_block_bytes(d, dv, isz, block_q, block_k=BLOCK_K):
+    """The dq call's: blocks of q and dO, all of k and v, the float32
+    dq block and two statistic columns; live s, dp, ds and the mask, ds
+    again as an operand, two accumulators."""
+    ld, ldv = _lanes(d), _lanes(dv)
+    return lambda t: (
+        2 * (t * (ld + ldv) * isz
+             + block_q * ((ld + ldv) * isz + (ld + 256) * 4))
+        + block_q * (block_k * (16 + isz) + 2 * ld * 4))
+
+
+def _dkv_block_bytes(d, dv, isz, block_q, block_k=BLOCK_K):
+    """The dk/dv call's: blocks of k and v, all of q and dO, the two
+    float32 output blocks, and the two statistics as (1, t) rows (16
+    bytes a row's value as Mosaic tiles them); live sT, dpT and the
+    mask, pT and dsT as operands, two pairs of accumulators."""
+    ld, ldv = _lanes(d), _lanes(dv)
+    return lambda t: (
+        2 * (t * (ld + ldv) * isz + 2 * 16 * t
+             + block_k * (ld + ldv) * (isz + 4))
+        + block_k * (block_q * (12 + 2 * isz) + 2 * (ld + ldv) * 4))
+
+
+# The three counts are upper estimates (Mosaic reuses the room of tiles
+# that are dead), held against the compiler for a described v5e over a
+# grid of tiles at both language models' shapes (PR 34): every pair of
+# tiles it refused counts over the window here, and every pair counted
+# inside the window compiled.
+_BLOCK_BYTES = {"fwd": _fwd_block_bytes, "dq": _dq_block_bytes,
+                "dkv": _dkv_block_bytes}
+# Past 512 rows or columns a tile buys nothing on the v5e (my chip run,
+# PR 34, call 1: each kernel alone over a grid of tiles at both language
+# models' shapes): the loop's fixed costs are spread thin by then, and
+# the half of every diagonal tile that lies above the diagonal grows
+# with the tile.
+_MAX_TILE = 512
+
+
+def _flash_tiles(kernel: str, t: int, d: int, dv: int, isz: int,
+                 floor=(BLOCK_Q, BLOCK_K)):
+    """(block_q, block_k) of one flash kernel ("fwd", "dq", "dkv") over
+    t rows of d / dv-wide heads in operands of `isz` bytes: the largest
+    tiles, multiples of the floor that divide t, whose call fits the
+    default VMEM window.  A wider tile of the streamed side (k for the
+    forward and dq, q for dk/dv) amortises the accumulator's rescale,
+    the statistics and the loop's fixed cost; a taller tile of the
+    resident side feeds the MXU more rows a weight load.  None where
+    even the floor does not fit (the caller cuts the rows first)."""
+    need = _BLOCK_BYTES[kernel]
+
+    def sizes(lo):
+        return [n for n in range(lo, min(t, max(lo, _MAX_TILE)) + 1, lo)
+                if t % n == 0]
+
+    fit = [(bq, bk) for bq in sizes(floor[0]) for bk in sizes(floor[1])
+           if _flash_window(need(d, dv, isz, bq, bk)(t)) <= _SCOPED_VMEM]
+    # the most scores a tile, then the wider streamed side
+    streamed = 0 if kernel == "dkv" else 1
+    return max(fit, key=lambda s: (s[0] * s[1], s[streamed]),
+               default=None)
 
 
 def _flash_chunk(t: int, block: int, *block_bytes) -> int:
@@ -651,17 +745,15 @@ def _flash_chunk(t: int, block: int, *block_bytes) -> int:
     Mosaic call as if the call took the default 16 MiB, so a call that
     uses more writes over them (the sorted token ids of the embedding's
     scatter-add among them: a step of lfm2 at one row of 8,192 tokens
-    never ended, PR 33).  So rows beyond `_ASK_UP_TO_T` are halved
-    until every one of `block_bytes` (functions of the rows: one for
-    each kernel of the call) fits the default window; the caller runs
-    the pairs of chunks and adds them up.  t itself where it fits or
-    is short enough to ask; a length no halving brings inside is an
-    error, not a call that may never end."""
+    never ended, PR 33).  So the rows are halved until every one of
+    `block_bytes` (functions of the rows: one for each kernel of the
+    call, at its smallest tiles) fits the default window; the caller
+    runs the pairs of chunks and adds them up.  t itself where it fits;
+    a length no halving brings inside is an error, not a call that may
+    never end."""
     def fits(n):
         return all(_flash_window(f(n)) <= _SCOPED_VMEM
                    for f in block_bytes)
-    if t <= _ASK_UP_TO_T or fits(t):
-        return t
     c = t
     while not fits(c):
         if c % 2 or (c // 2) % block:
@@ -686,27 +778,59 @@ def _rows(x, i, c):
     return jax.lax.slice_in_dim(x, i * c, (i + 1) * c, axis=1)
 
 
-def _fwd_block_bytes(d, dv, isz, block_q):
-    return lambda t: (t * (_lanes(d) + _lanes(dv)) * isz
-                      + block_q * (_lanes(d) + _lanes(dv) + 128) * 4)
+def _masked_tiles(kernel: str, t: int, block_q: int, block_k: int,
+                  causal: bool):
+    """(score tiles that run the masked body, score tiles visited) of
+    one call: what the kernels' loop bounds come to, summed over the
+    grid's second axis."""
+    n_q, n_k = t // block_q, t // block_k
+    if not causal:
+        return 0, n_q * n_k
+    if kernel == "dkv":     # a k tile's program: q tiles from lo on
+        spans = [_diagonal_span(i, block_k, block_q) for i in range(n_k)]
+        visited = sum(n_q - lo for lo, _ in spans)
+    else:                   # a q tile's program: k tiles before hi
+        spans = [_diagonal_span(i, block_q, block_k) for i in range(n_q)]
+        visited = sum(hi for _, hi in spans)
+    return sum(hi - lo for lo, hi in spans), visited
 
 
-def _dq_block_bytes(d, dv, isz, block_q):
-    return lambda t: (t * (_lanes(d) + _lanes(dv)) * isz
-                      + block_q * (2 * _lanes(d) + _lanes(dv) + 256) * 4)
+# What was lowered, by call shape: the tiles chosen, the calls an
+# attention takes and the share of score tiles under the masked body.
+# Static, written while a program is traced; `flash_plans()` is what
+# the -train job puts into its metrics after the first step.
+_FLASH_PLANS: dict = {}
 
 
-def _dkv_block_bytes(d, dv, isz, block_k):
-    # the two per-row statistics travel as (T, 1) columns, which VMEM
-    # pads to 128 lanes
-    return lambda t: (t * (_lanes(d) + _lanes(dv)) * isz + 2 * t * 128 * 4
-                      + block_k * 2 * (_lanes(d) + _lanes(dv)) * 4)
+def _note_plan(kernel, shape, causal, chunk, tiles):
+    bh, t, d, dv, dtype, g = shape
+    pairs = _chunk_pairs(t // chunk, causal)
+    counts = [_masked_tiles(kernel, chunk, *tiles, cz)
+              for _, _, cz in pairs]
+    masked, visited = (sum(c[i] for c in counts) for i in (0, 1))
+    key = (f"{bh}x{t}x{d}/{dv} {jnp.dtype(dtype).name} g{g}"
+           f"{' causal' if causal else ''}")
+    _FLASH_PLANS.setdefault(key, {})[kernel] = {
+        "block_q": tiles[0], "block_k": tiles[1], "calls": len(pairs),
+        "masked_tile_share": round(masked / visited, 4)}
 
 
-def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
-                    interpret, mxu_dtype=None):
-    bh, t, d = q.shape
-    dv = v.shape[-1]
+def flash_plans() -> dict:
+    """{call shape: {kernel: tiles, calls an attention, masked share}}
+    of every flash attention lowered by this process."""
+    return {k: dict(v) for k, v in _FLASH_PLANS.items()}
+
+
+def _operands(mxu_dtype, *xs):
+    """The arrays in the type the MXU is to see, cast once, here: HBM,
+    the DMAs and VMEM then carry that type, and no kernel casts a block
+    it is handed.  None = as they come."""
+    if mxu_dtype is None:
+        return xs
+    return tuple(x.astype(mxu_dtype) for x in xs)
+
+
+def _check_blocks(t, block_q, block_k):
     if t % block_q or t % block_k:
         # a truncated grid would leave the output/lse tail rows
         # uninitialized garbage — fail loudly (mirrors
@@ -715,47 +839,62 @@ def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
         raise ValueError(
             f"flash_attention needs T divisible by the blocks: "
             f"t={t} % block_q={block_q}, t={t} % block_k={block_k}")
-    isz = q.dtype.itemsize
-    block_bytes = _fwd_block_bytes(d, dv, isz, block_q)
-    c = _flash_chunk(t, max(block_q, block_k), block_bytes)
-    if c < t:
-        # each chunk of q against the chunks of k / v it sees, one call
-        # a pair; a row's parts are weighted by their share of its
-        # softmax sum
-        outs, lses = [], []
-        for i in range(t // c):
-            parts = [_flash_fwd_call(_rows(q, i, c), _rows(k, j, c),
-                                     _rows(v, j, c), sm_scale, cz,
-                                     block_q, block_k, interpret,
-                                     mxu_dtype)
-                     for qi, j, cz in _chunk_pairs(t // c, causal)
-                     if qi == i]
-            lse = functools.reduce(jnp.logaddexp, [p[1] for p in parts])
-            outs.append(sum(o * jnp.exp(l - lse)[:, :, None].astype(
-                o.dtype) for o, l in parts))
-            lses.append(lse)
-        return (jnp.concatenate(outs, axis=1),
-                jnp.concatenate(lses, axis=1))
-    kern = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
-                             causal=causal, block_k=block_k,
-                             mxu_dtype=mxu_dtype)
+
+
+def _flash_fwd_one(q, k, v, causal, *, sm_scale, tiles, interpret,
+                   out_dtype):
+    """One forward call: (out, lse) of q over all of k, v."""
+    bh, t, d = q.shape
+    dv = v.shape[-1]
+    block_q, block_k = tiles
     g = bh // k.shape[0]            # query heads a key/value head
     qspec, _, vec, _ = _flash_specs(block_q, d, t)
     ospec, _, _, _ = _flash_specs(block_q, dv, t)
     _, kspec = _flash_kv_specs(block_q, d, t, g)
     _, vspec = _flash_kv_specs(block_q, dv, t, g)
     out, lse = pl.pallas_call(
-        kern,
-        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
+        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
+                          causal=causal, block_k=block_k),
+        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), out_dtype),
                    jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)),
         grid=(bh, t // block_q),
         in_specs=[qspec, kspec, vspec],
         out_specs=(ospec, vec),
         interpret=interpret,
         name="cos_flash_fwd",
-        **_flash_compiler_params(block_bytes(t), interpret),
     )(q, k, v)
     return out, lse[:, :, 0]
+
+
+def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
+                    interpret, mxu_dtype=None):
+    bh, t, d = q.shape
+    dv = v.shape[-1]
+    _check_blocks(t, block_q, block_k)
+    out_dtype = q.dtype
+    q, k, v = _operands(mxu_dtype, q, k, v)
+    isz = q.dtype.itemsize
+    floor = (block_q, block_k)
+    c = _flash_chunk(t, max(floor), _fwd_block_bytes(d, dv, isz, *floor))
+    tiles = _flash_tiles("fwd", c, d, dv, isz, floor)
+    _note_plan("fwd", (bh, t, d, dv, q.dtype, bh // k.shape[0]), causal,
+               c, tiles)
+    one = functools.partial(_flash_fwd_one, sm_scale=sm_scale,
+                            tiles=tiles, interpret=interpret,
+                            out_dtype=out_dtype)
+    if c == t:
+        return one(q, k, v, causal)
+    # each chunk of q against the chunks of k / v it sees, one call a
+    # pair; a row's parts are weighted by their share of its softmax sum
+    outs, lses = [], []
+    for i in range(t // c):
+        parts = [one(_rows(q, i, c), _rows(k, j, c), _rows(v, j, c), cz)
+                 for qi, j, cz in _chunk_pairs(t // c, causal) if qi == i]
+        lse = functools.reduce(jnp.logaddexp, [p[1] for p in parts])
+        outs.append(sum(o * jnp.exp(l - lse)[:, :, None].astype(
+            o.dtype) for o, l in parts))
+        lses.append(lse)
+    return jnp.concatenate(outs, axis=1), jnp.concatenate(lses, axis=1)
 
 
 def _flash_flatten(q, k, v):
@@ -783,10 +922,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Same math as parallel.sp.attention (softmax(QKᵀ/√D)V, optional
     causal mask); O(block·T) VMEM instead of an O(T²) HBM score
     matrix, exact (not approximate) via online softmax.  Requires T
-    divisible by the block sizes — callers fall back to the XLA path
-    otherwise (ops.layers._mha).  `mxu_dtype` (None = float32
-    operands) is the operand type of the products; the accumulators,
-    the softmax statistics and the outputs stay float32/input dtype."""
+    divisible by `block_q` and `block_k` — callers fall back to the XLA
+    path otherwise (ops.layers._mha); they are the smallest tiles a
+    kernel may take, the tiles it does take follow from the shape
+    (`_flash_tiles`).  `mxu_dtype` (None = the inputs' own type) is
+    the operand type of the products: q, k, v and dO are cast to it
+    once, before the kernels; the scores, the softmax statistics and
+    the accumulators stay float32, the outputs the inputs' type."""
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
     qf, kf, vf = _flash_flatten(q, k, v)
@@ -805,6 +947,64 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
     return out.reshape(b, h, t, v.shape[-1]), (qf, kf, vf, out, lse)
 
 
+def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
+                  interpret, out_dtype):
+    """One pair's dq: a program a block of q, all of k and v past it;
+    the statistics as (block_q, 1) columns beside the scores' rows."""
+    bh, t, d = qf.shape
+    dv_w = vf.shape[-1]
+    block_q, block_k = tiles
+    g = bh // kf.shape[0]           # query heads a key/value head
+    qspec, _, vec, _ = _flash_specs(block_q, d, t)
+    dospec, _, _, _ = _flash_specs(block_q, dv_w, t)
+    _, kfull = _flash_kv_specs(block_q, d, t, g)
+    _, vfull = _flash_kv_specs(block_q, dv_w, t, g)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel,
+                          sm_scale=1.0 / math.sqrt(d), causal=causal,
+                          block_k=block_k),
+        out_shape=jax.ShapeDtypeStruct((bh, t, d), out_dtype),
+        grid=(bh, t // block_q),
+        in_specs=[qspec, kfull, vfull, dospec, vec, vec],
+        out_specs=qspec,
+        interpret=interpret,
+        name="cos_flash_bwd_dq",
+    )(qf, kf, vf, dof, lse[:, :, None], delta[:, :, None])
+
+
+def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
+                   interpret, out_dtypes):
+    """One pair's dk, dv: a program a block of k and v, all of q and dO
+    past it; the statistics as (1, t) rows beside the transposed
+    scores' columns."""
+    bh, t, d = qf.shape
+    dv_w = vf.shape[-1]
+    block_q, block_k = tiles
+    g = bh // kf.shape[0]
+    dkspec, qfull, _, row_full = _flash_specs(block_k, d, t)
+    dvspec, dofull, _, _ = _flash_specs(block_k, dv_w, t)
+    kspec, _ = _flash_kv_specs(block_k, d, t, g)
+    vspec, _ = _flash_kv_specs(block_k, dv_w, t, g)
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel,
+                          sm_scale=1.0 / math.sqrt(d), causal=causal,
+                          block_q=block_q),
+        out_shape=(jax.ShapeDtypeStruct((bh, t, d), out_dtypes[0]),
+                   jax.ShapeDtypeStruct((bh, t, dv_w), out_dtypes[1])),
+        grid=(bh, t // block_k),
+        in_specs=[qfull, kspec, vspec, dofull, row_full, row_full],
+        out_specs=(dkspec, dvspec),
+        interpret=interpret,
+        name="cos_flash_bwd_dkv",
+    )(qf, kf, vf, dof, lse[:, None, :], delta[:, None, :])
+    if g > 1:
+        # one dk, dv a query head: the group's sum is its key/value
+        # head's gradient
+        dk = dk.reshape(bh // g, g, t, d).sum(axis=1)
+        dv = dv.reshape(bh // g, g, t, dv_w).sum(axis=1)
+    return dk, dv
+
+
 def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
                     block_q: int, block_k: int, interpret: bool,
                     out_dtype=None, mxu_dtype=None):
@@ -818,73 +1018,44 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
     parallel/sp.py) pass causal=True only for the diagonal pair and
     causal=False for fully-visible ones.  `out_dtype` overrides the
     gradient dtype — accumulating callers pass float32 so bf16 inputs
-    don't round each per-hop partial before the sum."""
+    don't round each per-hop partial before the sum.  `block_q` /
+    `block_k` are the smallest tiles, `mxu_dtype` the operand type, as
+    in `flash_attention`."""
     bh, t, d = qf.shape
     dv_w = vf.shape[-1]
+    _check_blocks(t, block_q, block_k)
+    out_dtypes = tuple(out_dtype or x.dtype for x in (qf, kf, vf))
+    qf, kf, vf, dof = _operands(mxu_dtype, qf, kf, vf, dof)
     isz = qf.dtype.itemsize
-    dq_bytes = _dq_block_bytes(d, dv_w, isz, block_q)
-    dkv_bytes = _dkv_block_bytes(d, dv_w, isz, block_k)
-    c = _flash_chunk(t, max(block_q, block_k), dq_bytes, dkv_bytes)
-    if c < t:
-        # lse and delta are whole rows' statistics, so the pairs of
-        # chunks add up: dq over a q chunk's pairs, dk and dv over a
-        # k / v chunk's
-        n = t // c
-        dqs, dks, dvs = [None] * n, [None] * n, [None] * n
-        for i, j, cz in _chunk_pairs(n, causal):
-            part = flash_bwd_block(
-                _rows(qf, i, c), _rows(kf, j, c), _rows(vf, j, c),
-                _rows(dof, i, c), _rows(lse, i, c), _rows(delta, i, c),
-                causal=cz, block_q=block_q, block_k=block_k,
-                interpret=interpret, out_dtype=out_dtype,
-                mxu_dtype=mxu_dtype)
-            for acc, at, x in zip((dqs, dks, dvs), (i, j, j), part):
-                acc[at] = x if acc[at] is None else acc[at] + x
-        return tuple(jnp.concatenate(a, axis=1) for a in (dqs, dks, dvs))
-    sm_scale = 1.0 / math.sqrt(d)
-    lse = lse[:, :, None]          # (bh, t, 1): see _flash_specs
-    delta = delta[:, :, None]
-    g = bh // kf.shape[0]           # query heads a key/value head
-    qspec, qfull, vec, vec_full = _flash_specs(block_q, d, t)
-    dospec, dofull, _, _ = _flash_specs(block_q, dv_w, t)
-    dkspec, _, _, _ = _flash_specs(block_k, d, t)
-    dvspec, _, _, _ = _flash_specs(block_k, dv_w, t)
-    kspec_b, kfull = _flash_kv_specs(block_k, d, t, g)
-    vspec_b, vfull = _flash_kv_specs(block_k, dv_w, t, g)
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, block_k=block_k,
-                          mxu_dtype=mxu_dtype),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d),
-                                       out_dtype or qf.dtype),
-        grid=(bh, t // block_q),
-        in_specs=[qspec, kfull, vfull, dospec, vec, vec],
-        out_specs=qspec,
-        interpret=interpret,
-        name="cos_flash_bwd_dq",
-        **_flash_compiler_params(dq_bytes(t), interpret),
-    )(qf, kf, vf, dof, lse, delta)
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q,
-                          mxu_dtype=mxu_dtype),
-        out_shape=(jax.ShapeDtypeStruct((bh, t, d),
-                                        out_dtype or kf.dtype),
-                   jax.ShapeDtypeStruct((bh, t, dv_w),
-                                        out_dtype or vf.dtype)),
-        grid=(bh, t // block_k),
-        in_specs=[qfull, kspec_b, vspec_b, dofull, vec_full, vec_full],
-        out_specs=(dkspec, dvspec),
-        interpret=interpret,
-        name="cos_flash_bwd_dkv",
-        **_flash_compiler_params(dkv_bytes(t), interpret),
-    )(qf, kf, vf, dof, lse, delta)
-    if g > 1:
-        # one dk, dv a query head: the group's sum is its key/value
-        # head's gradient
-        dk = dk.reshape(bh // g, g, t, d).sum(axis=1)
-        dv = dv.reshape(bh // g, g, t, dv_w).sum(axis=1)
-    return dq, dk, dv
+    floor = (block_q, block_k)
+    c = _flash_chunk(t, max(floor), _dq_block_bytes(d, dv_w, isz, *floor),
+                     _dkv_block_bytes(d, dv_w, isz, *floor))
+    shape = (bh, t, d, dv_w, qf.dtype, bh // kf.shape[0])
+    tiles = {}
+    for kern in ("dq", "dkv"):
+        tiles[kern] = _flash_tiles(kern, c, d, dv_w, isz, floor)
+        _note_plan(kern, shape, causal, c, tiles[kern])
+
+    def one(*pair):
+        dq = _flash_dq_one(*pair, tiles=tiles["dq"], interpret=interpret,
+                           out_dtype=out_dtypes[0])
+        return (dq,) + _flash_dkv_one(
+            *pair, tiles=tiles["dkv"], interpret=interpret,
+            out_dtypes=out_dtypes[1:])
+
+    if c == t:
+        return one(qf, kf, vf, dof, lse, delta, causal)
+    # lse and delta are whole rows' statistics, so the pairs of chunks
+    # add up: dq over a q chunk's pairs, dk and dv over a k / v chunk's
+    n = t // c
+    dqs, dks, dvs = [None] * n, [None] * n, [None] * n
+    for i, j, cz in _chunk_pairs(n, causal):
+        part = one(_rows(qf, i, c), _rows(kf, j, c), _rows(vf, j, c),
+                   _rows(dof, i, c), _rows(lse, i, c), _rows(delta, i, c),
+                   cz)
+        for acc, at, x in zip((dqs, dks, dvs), (i, j, j), part):
+            acc[at] = x if acc[at] is None else acc[at] + x
+    return tuple(jnp.concatenate(a, axis=1) for a in (dqs, dks, dvs))
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, mxu_dtype, res,
@@ -942,9 +1113,11 @@ def _flash_carry_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
         k_pos = (koff_ref[0] + i * block_k
                  + jax.lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 1))
-        return _online_softmax_step(q, kb, vb, m, l, acc,
-                                    sm_scale=sm_scale, causal=causal,
-                                    q_pos=q_pos, k_pos=k_pos)
+        # the offsets are the ring's, known only on the device: every
+        # tile of a causal hop keeps the masked body
+        return _online_softmax_step(
+            q, kb, vb, m, l, acc, sm_scale=sm_scale,
+            visible=q_pos >= k_pos if causal else None)
 
     m, l, acc = jax.lax.fori_loop(0, t_k // block_k, body, (m, l, acc))
     mo_ref[0] = m

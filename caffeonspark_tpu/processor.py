@@ -492,6 +492,8 @@ class CaffeProcessor:
                                                solver.step_rng(it))
                     else:
                         params, st, out = fused_step(params, st, batch)
+                if nd == 0:
+                    self._note_flash_plans()
                 if self.step_observer is not None:
                     self.step_observer(it, n, batch, params, st, out)
                 it += n
@@ -547,6 +549,18 @@ class CaffeProcessor:
                     pool.stop(join_timeout=2.0)
             for q in self.queues:
                 q.stop()
+
+    def _note_flash_plans(self):
+        """The first step is lowered: what its flash attention calls
+        came to (tiles, calls an attention, share of score tiles under
+        the masked body; `pallas_kernels.flash_plans`) goes into the
+        metrics as `info.flash` and into the log, once.  Static facts,
+        nothing a step on the device."""
+        from .ops.pallas_kernels import flash_plans
+        plans = flash_plans()
+        if plans:
+            self.metrics.set_info("flash", plans)
+            _LOG.info("flash attention as lowered: %s", plans)
 
     VALIDATION_STALL_TIMEOUT = 30.0
 

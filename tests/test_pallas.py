@@ -194,63 +194,118 @@ def test_lrn_pallas_bf16_io_f32_normalizer():
     assert gap > 1e-2, "LRN degenerated to identity"
 
 
+def _force_tiles(monkeypatch, tiles):
+    """Run the flash kernels at given (block_q, block_k) instead of the
+    ones `_flash_tiles` picks (which at test lengths is one tile a row):
+    one pair for all three kernels, or one a kernel."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    if tiles is not None:
+        monkeypatch.setattr(
+            pk, "_flash_tiles", lambda kernel, *a, **kw: (
+                tiles[kernel] if isinstance(tiles, dict) else tiles))
+
+
+def _qkv(seed, b, h, g, t, d, dv, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(b, h, t, d), dtype),
+            jnp.asarray(rng.randn(b, h // g, t, d), dtype),
+            jnp.asarray(rng.randn(b, h // g, t, dv), dtype))
+
+
+# the paths the tile-wise kernels added: a q tile taller than a k tile
+# (several diagonal tiles a q tile) and the reverse, tiles chosen from
+# the shape, 192 / 128-wide heads, g = 4 at 64-wide heads, operands
+# cast once to bfloat16, bfloat16 inputs
+_TILED = [
+    # t, block, h, g, d, dv, tiles, mxu, dtype
+    (512, 128, 2, 1, 32, 32, (256, 128), None, jnp.float32),
+    (512, 128, 2, 1, 32, 32, (128, 256), None, jnp.float32),
+    (512, 128, 2, 1, 32, 32, None, None, jnp.float32),
+    (512, 128, 2, 1, 192, 128, (256, 128), None, jnp.float32),
+    (512, 128, 8, 4, 64, 64, (128, 256), None, jnp.float32),
+    (512, 128, 8, 4, 64, 64, (256, 128), jnp.bfloat16, jnp.float32),
+    (512, 128, 2, 1, 192, 128, (128, 256), jnp.bfloat16, jnp.float32),
+    (512, 128, 2, 1, 32, 32, (256, 128), None, jnp.bfloat16),
+    (384, 128, 2, 1, 32, 32, {"fwd": (128, 384), "dq": (384, 128),
+                              "dkv": (128, 384)}, None, jnp.float32),
+]
+
+
+def _tolerance(mxu, dtype):
+    """float32 operands: the exact mode; one bfloat16 pass (operands
+    or inputs) rounds the scores to 2^-9."""
+    exact = mxu is None and dtype == jnp.float32
+    return dict(rtol=2e-5, atol=2e-5) if exact else dict(rtol=3e-2,
+                                                         atol=3e-2)
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("t,block,h,g,d", [
-    (256, 128, 3, 1, 32), (64, 64, 3, 1, 32), (384, 128, 3, 1, 32),
+@pytest.mark.parametrize("t,block,h,g,d,dv,tiles,mxu,dtype", [
+    (256, 128, 3, 1, 32, 32, None, None, jnp.float32),
+    (64, 64, 3, 1, 32, 32, None, None, jnp.float32),
+    (384, 128, 3, 1, 32, 32, None, None, jnp.float32),
     # grouped queries: h heads over h / g key/value heads of 64
-    (256, 128, 8, 4, 64), (1024, 128, 8, 4, 64)])
-def test_flash_attention_matches_reference(causal, t, block, h, g, d):
+    (256, 128, 8, 4, 64, 64, None, None, jnp.float32),
+    (1024, 128, 8, 4, 64, 64, None, None, jnp.float32)] + _TILED)
+def test_flash_attention_matches_reference(monkeypatch, causal, t, block,
+                                           h, g, d, dv, tiles, mxu,
+                                           dtype):
     """Flash fwd parity vs the einsum reference (interpret mode)."""
     from caffeonspark_tpu.ops.pallas_kernels import flash_attention
     from caffeonspark_tpu.parallel.sp import attention
-    rng = np.random.RandomState(0)
-    b = 2
-    q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
-    ref = attention(q, k, v, causal=causal)
+    _force_tiles(monkeypatch, tiles)
+    q, k, v = _qkv(0, 2, h, g, t, d, dv, dtype)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref = attention(*f32, causal=causal)
     if g > 1:       # the einsum path itself against repeated heads
-        rep = attention(q, jnp.repeat(k, g, axis=1),
-                        jnp.repeat(v, g, axis=1), causal=causal)
+        rep = attention(f32[0], jnp.repeat(f32[1], g, axis=1),
+                        jnp.repeat(f32[2], g, axis=1), causal=causal)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(rep),
                                    rtol=2e-5, atol=2e-5)
-    out = flash_attention(q, k, v, causal, block, block, True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    out = flash_attention(q, k, v, causal, block, block, True, mxu)
+    assert out.dtype == dtype and out.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), **_tolerance(mxu, dtype))
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("t,h,g,d", [
-    (256, 2, 1, 16), (256, 8, 4, 64), (1024, 8, 4, 64)])
-def test_flash_attention_grads_match_reference(causal, t, h, g, d):
+@pytest.mark.parametrize("t,block,h,g,d,dv,tiles,mxu,dtype", [
+    (256, 128, 2, 1, 16, 16, None, None, jnp.float32),
+    (256, 128, 8, 4, 64, 64, None, None, jnp.float32),
+    (1024, 128, 8, 4, 64, 64, None, None, jnp.float32)] + _TILED)
+def test_flash_attention_grads_match_reference(monkeypatch, causal, t,
+                                               block, h, g, d, dv, tiles,
+                                               mxu, dtype):
     """Flash bwd kernels (dq/dk/dv) vs jax.grad of the reference; with
     g > 1 dk and dv are the sums over each group of query heads."""
     from caffeonspark_tpu.ops.pallas_kernels import flash_attention
     from caffeonspark_tpu.parallel.sp import attention
-    rng = np.random.RandomState(1)
-    b = 2
-    q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
+    _force_tiles(monkeypatch, tiles)
+    q, k, v = _qkv(1, 2, h, g, t, d, dv, dtype)
 
     def scal(fn):
         return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
 
     gr = jax.grad(scal(lambda q, k, v: attention(
         q, jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1),
-        causal=causal)), argnums=(0, 1, 2))(q, k, v)
+        causal=causal)), argnums=(0, 1, 2))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
     gf = jax.grad(scal(lambda q, k, v: flash_attention(
-        q, k, v, causal, 128, 128, True)), argnums=(0, 1, 2))(q, k, v)
+        q, k, v, causal, block, block, True, mxu).astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)
+    tol = (dict(rtol=2e-4, atol=1e-5 * g)
+           if mxu is None and dtype == jnp.float32
+           else dict(rtol=5e-2, atol=5e-2 * g))
     for name, a, b_ in zip("qkv", gr, gf):
-        assert a.shape == b_.shape
-        np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
-                                   rtol=2e-4, atol=1e-5 * g,
-                                   err_msg=f"d{name}")
+        assert a.shape == b_.shape and b_.dtype == dtype
+        np.testing.assert_allclose(np.asarray(b_, np.float32),
+                                   np.asarray(a), err_msg=f"d{name}",
+                                   **tol)
 
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("h,g,d,dv,room", [
-    (8, 4, 64, 64, 1_100_000), (4, 1, 192, 128, 1_500_000)])
+    (8, 4, 64, 64, 1_500_000), (4, 1, 192, 128, 2_100_000)])
 def test_flash_attention_in_chunks_matches_reference(monkeypatch, causal,
                                                      h, g, d, dv, room):
     """Rows too long for the default VMEM window go as pairs of chunks
@@ -258,20 +313,16 @@ def test_flash_attention_in_chunks_matches_reference(monkeypatch, causal,
     softmax sum, dq / dk / dv added over the pairs.  The window is made
     small here (`room` bytes over Mosaic's own scratch) so that 512
     rows are cut, forward in 2 chunks of 256 and backward in 4 of 128,
-    as 8,192 are on the chip."""
+    as 8,192 rows of float32 operands are on the chip."""
     from caffeonspark_tpu.ops import pallas_kernels as pk
     from caffeonspark_tpu.parallel.sp import attention
-    monkeypatch.setattr(pk, "_ASK_UP_TO_T", 0)
-    monkeypatch.setattr(pk, "_SCOPED_VMEM", (4 << 20) + room)
+    monkeypatch.setattr(pk, "_SCOPED_VMEM", pk._MOSAIC_ROOM + room)
     t, b = 512, 2
     fwd = pk._flash_chunk(t, 128, pk._fwd_block_bytes(d, dv, 4, 128))
     bwd = pk._flash_chunk(t, 128, pk._dq_block_bytes(d, dv, 4, 128),
                           pk._dkv_block_bytes(d, dv, 4, 128))
     assert (fwd, bwd) == (256, 128)
-    rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, h // g, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, h // g, t, dv), jnp.float32)
+    q, k, v = _qkv(3, b, h, g, t, d, dv)
 
     def scal(fn):
         return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
@@ -289,24 +340,34 @@ def test_flash_attention_in_chunks_matches_reference(monkeypatch, causal,
         np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
                                    rtol=2e-4, atol=1e-5 * g,
                                    err_msg=f"d{name}")
+    # the counter: three pairs of chunks under the mask, four without
+    plan = pk.flash_plans()[
+        f"{b * h}x{t}x{d}/{dv} float32 g{g}{' causal' if causal else ''}"]
+    assert plan["fwd"]["calls"] == (3 if causal else 4)
+    assert plan["dq"]["calls"] == plan["dkv"]["calls"] == (
+        10 if causal else 16)
 
 
 def test_flash_chunk_keeps_long_rows_inside_the_default_window():
-    """8,192 rows of 64-wide heads: 4,096 a forward call, 2,048 a
-    backward one; the shapes the tree already ran on the chip (up to
-    4,096 rows) stay one call."""
+    """No flash call asks for a VMEM window: rows whose blocks do not
+    fit the default one are cut.  In bfloat16 operands (what both
+    language-model cells run) 8,192 rows of 64-wide heads and 4,096 of
+    192 / 128-wide ones are one call; float32 operands take twice the
+    room and are cut where bfloat16 ones are not."""
     from caffeonspark_tpu.ops import pallas_kernels as pk
 
-    def chunks(t, d, dv):
-        return (pk._flash_chunk(t, 128, pk._fwd_block_bytes(d, dv, 4, 128)),
-                pk._flash_chunk(t, 128, pk._dq_block_bytes(d, dv, 4, 128),
-                                pk._dkv_block_bytes(d, dv, 4, 128)))
+    def chunks(t, d, dv, isz):
+        return (pk._flash_chunk(t, 128, pk._fwd_block_bytes(d, dv, isz, 128)),
+                pk._flash_chunk(t, 128, pk._dq_block_bytes(d, dv, isz, 128),
+                                pk._dkv_block_bytes(d, dv, isz, 128)))
 
-    assert chunks(8192, 64, 64) == (4096, 2048)
-    assert chunks(4096, 64, 64) == (4096, 4096)
-    assert chunks(4096, 192, 128) == (4096, 4096)      # kanana2's
-    assert chunks(8192, 192, 128) == (4096, 2048)
-    assert chunks(1024, 64, 64) == (1024, 1024)
+    assert chunks(8192, 64, 64, 2) == (8192, 8192)     # lfm2's
+    assert chunks(4096, 64, 64, 2) == (4096, 4096)
+    assert chunks(4096, 192, 128, 2) == (4096, 4096)   # kanana2's
+    assert chunks(1024, 64, 64, 4) == (1024, 1024)
+    assert chunks(8192, 64, 64, 4) == (4096, 4096)
+    assert chunks(8192, 192, 128, 4) == (4096, 4096)
+    assert chunks(16384, 192, 128, 2) == (8192, 8192)
     # a length no halving brings inside is refused, not sent to a
     # window XLA does not keep free
     with pytest.raises(ValueError, match="cannot be halved"):
@@ -315,6 +376,106 @@ def test_flash_chunk_keeps_long_rows_inside_the_default_window():
     assert pk._chunk_pairs(2, True) == [(0, 0, True), (1, 0, False),
                                         (1, 1, True)]
     assert len(pk._chunk_pairs(2, False)) == 4
+
+
+@pytest.mark.parametrize("b,h,hkv,t,d,dv", [
+    (2, 32, 32, 4096, 192, 128),    # kanana2.train_packed4k
+    (2, 32, 8, 4096, 64, 64),
+    (1, 32, 8, 8192, 64, 64),       # lfm2.train_packed8k
+    (2, 4, 4, 256, 32, 32), (1, 2, 2, 384, 64, 64),
+    (1, 2, 2, 3072, 128, 128)])
+@pytest.mark.parametrize("isz", [2, 4])
+def test_flash_tiles_follow_from_the_shape(b, h, hkv, t, d, dv, isz):
+    """`_flash_tiles` is a pure function of (kernel, rows, widths,
+    operand size): its tiles divide the rows, are multiples of the
+    floor and never under it, and the call they make fits the default
+    VMEM window by the same arithmetic `_flash_chunk` cuts rows by."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    floor = (pk.BLOCK_Q, pk.BLOCK_K)
+    for kernel, need in pk._BLOCK_BYTES.items():
+        c = pk._flash_chunk(t, 128, need(d, dv, isz, *floor))
+        tiles = pk._flash_tiles(kernel, c, d, dv, isz)
+        assert tiles == pk._flash_tiles(kernel, c, d, dv, isz, floor)
+        for n, lo in zip(tiles, floor):
+            assert n >= lo and n % lo == 0 and c % n == 0, (kernel, tiles)
+        assert pk._flash_window(
+            need(d, dv, isz, *tiles)(c)) <= pk._SCOPED_VMEM
+        # wider than the floor wherever the rows allow it: the point
+        assert max(tiles) > 128 or c == 128, (kernel, tiles)
+        if isz == 2 and t >= 4096:          # both cells: one call
+            assert c == t
+    # a floor above the rows' own tiles is kept (the ring's whole-shard
+    # blocks), and where not even the floor fits there is no answer
+    assert pk._flash_tiles("fwd", 64, 32, 32, 4, (64, 64)) == (64, 64)
+    assert pk._flash_tiles("dkv", 1 << 16, 128, 128, 4) is None
+
+
+def test_flash_plans_count_the_masked_tiles():
+    """The counter: per call shape lowered, each kernel's tiles, the
+    calls an attention takes, and the share of the score tiles it
+    visits that run the masked body = diagonal tiles / tiles visited.
+    Static, written while the program is traced."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    # 4 q tiles of 128 over 2 k tiles of 256: q tiles 0, 1 see k tile 0
+    # (on the diagonal), 2 and 3 see tile 0 whole and tile 1 on it
+    assert pk._masked_tiles("fwd", 512, 128, 256, True) == (4, 6)
+    assert pk._masked_tiles("dq", 512, 128, 256, True) == (4, 6)
+    # the same tiles seen from the k tiles' programs
+    assert pk._masked_tiles("dkv", 512, 128, 256, True) == (4, 6)
+    # a q tile of 256 over k tiles of 128 lies on two diagonal tiles
+    assert pk._masked_tiles("fwd", 512, 256, 128, True) == (4, 6)
+    assert pk._masked_tiles("dkv", 512, 256, 128, True) == (4, 6)
+    assert pk._masked_tiles("fwd", 512, 128, 128, True) == (4, 10)
+    assert pk._masked_tiles("fwd", 512, 128, 128, False) == (0, 16)
+    # brute force: a tile is masked iff it holds a score above the
+    # diagonal and one at or under it
+    for bq, bk in ((128, 384), (384, 128), (256, 256), (128, 128)):
+        t = 768
+        masked = visited = 0
+        for i in range(t // bq):
+            for j in range(t // bk):
+                lowest_row, top_row = (i + 1) * bq - 1, i * bq
+                if j * bk <= lowest_row:
+                    visited += 1
+                    masked += (j + 1) * bk - 1 > top_row
+        for kernel in ("fwd", "dq", "dkv"):
+            assert pk._masked_tiles(kernel, t, bq, bk, True) == (
+                masked, visited), (kernel, bq, bk)
+    q, k, v = _qkv(7, 1, 2, 1, 512, 32, 32)
+    pk._FLASH_PLANS.clear()
+    jax.grad(lambda q: jnp.sum(pk.flash_attention(
+        q, k, v, True, 128, 128, True)))(q)
+    (shape, plan), = pk.flash_plans().items()
+    assert shape == "2x512x32/32 float32 g1 causal"
+    assert set(plan) == {"fwd", "dq", "dkv"}
+    for kernel, p in plan.items():
+        tiles = pk._flash_tiles(kernel, 512, 32, 32, 4)
+        assert (p["block_q"], p["block_k"]) == tiles and p["calls"] == 1
+        m, n = pk._masked_tiles(kernel, 512, *tiles, True)
+        assert p["masked_tile_share"] == round(m / n, 4)
+
+
+def test_train_job_reports_flash_plans():
+    """What the first step's flash calls were lowered to rides in the
+    metrics the -train job prints at shutdown, as `info.flash`."""
+    from caffeonspark_tpu.metrics import PipelineMetrics
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    from caffeonspark_tpu.processor import CaffeProcessor
+
+    class Job:
+        metrics = PipelineMetrics()
+
+    pk._FLASH_PLANS.clear()
+    CaffeProcessor._note_flash_plans(Job)       # no attention: nothing
+    assert "info" not in Job.metrics.summary()
+    q, k, v = _qkv(8, 1, 4, 2, 256, 64, 64)
+    pk.flash_attention(q, k, v, True, interpret=True,
+                       mxu_dtype=jnp.bfloat16)
+    CaffeProcessor._note_flash_plans(Job)
+    assert Job.metrics.summary()["info"]["flash"] == {
+        "4x256x64/64 bfloat16 g2 causal": {"fwd": {
+            "block_q": 256, "block_k": 256, "calls": 1,
+            "masked_tile_share": 1.0}}}
 
 
 def test_flash_attention_rejects_indivisible_t():

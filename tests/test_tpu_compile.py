@@ -29,18 +29,31 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("b,h,hkv,t,d,dv", [
-    (2, 32, 8, 4096, 64, 64),       # lfm2.train_packed8k: g = 4
-    (1, 32, 8, 8192, 64, 64),       # the same at T = 8,192: VMEM (_lanes)
-    (2, 32, 32, 4096, 192, 128),    # kanana2.train_packed4k: g = 1
+@pytest.mark.parametrize("b,h,hkv,t,d,dv,mxu,calls", [
+    (2, 32, 8, 4096, 64, 64, jnp.bfloat16, 3),      # lfm2 at 2 x 4,096
+    (1, 32, 8, 8192, 64, 64, jnp.bfloat16, 3),      # lfm2.train_packed8k
+    (2, 32, 32, 4096, 192, 128, jnp.bfloat16, 3),   # kanana2.train_packed4k
+    # float32 operands (`_mha`'s exact mode) take twice the room: 8,192
+    # rows go as pairs of chunks of 4,096, three forward and three for
+    # each backward kernel
+    (1, 4, 4, 8192, 64, 64, None, 9),
+    (1, 4, 4, 2048, 128, 128, None, 3),
 ])
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
-                                                    t, d, dv):
-    from caffeonspark_tpu.ops.pallas_kernels import flash_attention
+                                                    t, d, dv, mxu, calls):
+    """The three kernels at the tiles `_flash_tiles` picks lower for the
+    v5e, and NO call carries a VMEM window of its own: XLA lays the
+    buffers it keeps across a Mosaic call as if the call took the
+    default 16 MiB, and a step of lfm2 whose kernels took 20-36 never
+    ended on the chip (PR 33).  Since PR 34 that holds at every length,
+    kanana2's 4,096 rows included (its dk/dv call asked for 22.6 MiB):
+    operands arrive in bfloat16, the statistics of the dk/dv call as
+    rows, and what still does not fit is cut (`_flash_chunk`)."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, 128, 128, False,
-                                       jnp.bfloat16))
+        return jnp.sum(pk.flash_attention(q, k, v, True, interpret=False,
+                                          mxu_dtype=mxu))
 
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
               for s in ((b, h, t, d), (b, hkv, t, d), (b, hkv, t, dv))]
@@ -57,19 +70,19 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
     # dk, dv come back in k's and v's own shapes
     out = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), *shapes)
     assert [o.shape for o in out] == [s.shape for s in shapes]
-    # beyond 4,096 rows no call asks for more VMEM than the default
-    # window: XLA lays the buffers it keeps across a Mosaic call as if
-    # the call took 16 MiB, and a step of lfm2 whose kernels took 20-36
-    # never ended on the chip (PR 33)
-    calls = [line for line in text.splitlines()
+    lines = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    windows = [int(n) for line in calls for n in re.findall(
+    assert len(lines) == calls
+    # a call at the default carries no window
+    windows = [int(n) for line in lines for n in re.findall(
         r'"scoped_memory_configs":\[\{"memory_space":"1",'
         r'"offset":"\d+","size":"(\d+)"', line)]
-    # one call a kernel up to 4,096 rows; at 8,192 three pairs of
-    # 4,096 forward and ten of 2,048 for each backward kernel
-    assert len(calls) == (3 if t <= 4096 else 23)
-    if t > 4096:        # a call at the default carries no window
-        assert max(windows, default=0) <= 16 << 20, windows
-    else:               # and these still ask, as they did
-        assert windows
+    assert not windows, windows
+    # the counter says what was lowered: tiles above the floor, and
+    # the calls an attention takes
+    plan = pk.flash_plans()[
+        f"{b * h}x{t}x{d}/{dv} "
+        f"{jnp.dtype(mxu or jnp.float32).name} g{h // hkv} causal"]
+    assert sum(p["calls"] for p in plan.values()) == calls
+    assert all(max(p["block_q"], p["block_k"]) > 128
+               for p in plan.values())
