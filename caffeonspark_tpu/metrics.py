@@ -134,7 +134,9 @@ exchanges / skipped / adopted / timeouts / max_gap), and after the
 first step what its flash attention calls were lowered to as
 `info.flash` (per call shape and kernel: tiles, calls an attention,
 share of score tiles under the masked body;
-`ops.pallas_kernels.flash_plans`).  The relaxed
+`ops.pallas_kernels.flash_plans`) and, for a net with GatedDeltaNet
+layers, `info.gdn` (per operator shape: the chunk, the chunks a row,
+the heads, the state's bytes; `ops.layers.gdn_plans`).  The relaxed
 sync modes also record a `sync_exchange` stage series (host-side
 round-average / global-merge wall time).  The continuous-deployment
 controller publishes `info.deploy` the same way (incumbent, verdict

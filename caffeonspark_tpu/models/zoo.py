@@ -587,6 +587,118 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
     return parse_net_prototxt(t)
 
 
+# qwen3_next's published operator schedule (Qwen3-Next-80B-A3B, 48
+# layers, full_attention_interval 4): three Gated DeltaNet layers, then
+# one gated full-attention layer, repeating
+QWEN3_NEXT_LAYER_TYPES = tuple(
+    "full_attention" if (i + 1) % 4 == 0 else "linear_attention"
+    for i in range(48))
+
+
+def qwen3_next(vocab: int = 18992, hidden: int = 2048, heads: int = 16,
+               kv_heads: int = 2, head_dim: int = 256,
+               rotary_dim: int = 64, linear_k_heads: int = 16,
+               linear_v_heads: int = 32, linear_k_dim: int = 128,
+               linear_v_dim: int = 128, conv_taps: int = 4,
+               chunk: int = 64, expert_width: int = 512,
+               shared_width: int = 512, experts: int = 512,
+               top_k: int = 10, experts_held: int = 32,
+               first_expert: int = 0, layer_types=QWEN3_NEXT_LAYER_TYPES,
+               first_layer: int = 0, layers: int = 4, seq: int = 8192,
+               batch: int = 1, rope_theta: float = 1e7, eps: float = 1e-6,
+               init_std: float = 0.02, recompute: bool = True
+               ) -> NetParameter:
+    """Qwen/Qwen3-Next-80B-A3B-Instruct (`model_type: qwen3_next`) as
+    one chip's share of an expert-parallel deployment: pre-norm
+    residual blocks whose operator follows `layer_types` — the Gated
+    DeltaNet operator (`linear_attention`) or grouped-query attention
+    with q/k norms, rotary positions on the first `rotary_dim` dims of
+    a head and a sigmoid output gate (`full_attention`) — and whose
+    feed-forward is `experts` softmax-routed experts (the `top_k`
+    largest, renormalised), of which this net holds `experts_held` from
+    `first_expert` on, plus one sigmoid-gated shared expert.  The net is
+    the published layers [`first_layer`, `first_layer` + `layers`),
+    named L0, L1, ... in the order they run.  The defaults are the
+    published widths with the cut of `perfbench/configs/
+    qwen3_next_80b_a3b.json` (32 of 512 experts, an eighth of the
+    vocabulary, published layers 0-3: one whole period);
+    `experts_held=512, vocab=151936, layers=48` is the whole model.
+    Every RMSNorm is stored as one scale filled 1 (the family's 1 + w
+    with w filled 0).  Time-major (T, B) int tops `input_ids` /
+    `target_ids` (one row of 8,192 by default); every block is one
+    `recompute_block`; each expert layer's `moe_stats` / `moe_rows`
+    tops are net outputs."""
+    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    if first_layer + layers > len(layer_types):
+        raise ValueError(f"qwen3_next: layers [{first_layer}, "
+                         f"{first_layer + layers}) of {len(layer_types)}")
+    t = f"""
+name: "Qwen3Next"
+layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
+  cos_data_param {{ batch_size: {batch}
+    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }}
+    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }} }} }}
+layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
+  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
+    {gauss} }} }}
+"""
+    h = "h0"
+    for i in range(layers):
+        p = f"L{i}"
+        tag = f'recompute_block: "{p}"' if recompute else ""
+        t += f"""
+layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
+  {tag} rms_norm_param {{ eps: {eps} }} }}"""
+        kind = layer_types[first_layer + i]
+        if kind == "linear_attention":
+            t += f"""
+layer {{ name: "{p}.gdn" type: "GatedDeltaNet" bottom: "{p}.n1" top: "{p}.a"
+  {tag} gated_delta_net_param {{ num_k_heads: {linear_k_heads}
+    num_v_heads: {linear_v_heads} head_k_dim: {linear_k_dim}
+    head_v_dim: {linear_v_dim} conv_taps: {conv_taps} chunk: {chunk}
+    rms_norm_eps: {eps} {gauss} }} }}"""
+        elif kind == "full_attention":
+            t += f"""
+layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
+  top: "{p}.a" {tag}
+  attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
+    head_dim: {head_dim} causal: true qk_norm: true rotary: true
+    rotary_dim: {rotary_dim} output_gate: true
+    rope_theta: {rope_theta} rms_norm_eps: {eps} {gauss} }} }}"""
+        else:
+            raise ValueError(f"qwen3_next: layer type {kind!r}")
+        # blobs: router, W_gate, W_up, W_down, the shared expert's three
+        # and its gate
+        t += f"""
+layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
+  top: "{p}.h1" {tag} }}
+layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
+  {tag} rms_norm_param {{ eps: {eps} }} }}
+layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
+  top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
+  moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
+    top_k: {top_k} dispatch: "dropless" scoring: "softmax" gated: true
+    shared_hidden_dim: {shared_width} shared_gate: true
+    experts_held: {experts_held} first_expert: {first_expert}
+    {gauss} }} }}
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
+  top: "{p}.out" {tag} }}
+"""
+        h = f"{p}.out"
+    t += f"""
+layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
+  rms_norm_param {{ eps: {eps} }} }}
+layer {{ name: "head.logits" type: "InnerProduct" bottom: "head.n"
+  top: "logits" inner_product_param {{ num_output: {vocab} axis: 2
+    bias_term: false {gauss} }} }}
+layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
+  bottom: "target_ids" top: "loss" softmax_param {{ axis: 2 }} }}
+"""
+    return parse_net_prototxt(t)
+
+
 def lstm_lm(vocab: int = 8801, d_model: int = 1000, seq: int = 20,
             batch_size: int = 32) -> NetParameter:
     """LRCN-shaped recurrent language model: Embed -> cont-gated LSTM

@@ -2,7 +2,8 @@
 referenced by every `weight_filler`/`bias_filler` in data/*.prototxt).
 
 Supported types: constant, uniform, gaussian, xavier, msra, positive_unitball,
-bilinear.  `xavier`/`msra` honor `variance_norm` (FAN_IN default).
+bilinear, and log_uniform (the log of a uniform draw: GatedDeltaNet's
+A_log).  `xavier`/`msra` honor `variance_norm` (FAN_IN default).
 """
 
 from __future__ import annotations
@@ -45,6 +46,9 @@ def fill(key: jax.Array, filler: FillerParameter, shape: Sequence[int],
         return jnp.full(shape, filler.value, dtype)
     if t == "uniform":
         return jax.random.uniform(key, shape, dtype, filler.min, filler.max)
+    if t == "log_uniform":
+        return jnp.log(jax.random.uniform(key, shape, dtype, filler.min,
+                                          filler.max))
     if t == "gaussian":
         return (filler.mean
                 + filler.std * jax.random.normal(key, shape)).astype(dtype)

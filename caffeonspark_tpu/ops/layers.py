@@ -25,6 +25,7 @@ Reference equivalents: caffe-public layer implementations consumed via
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import os
 import zlib
@@ -1333,7 +1334,6 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
                and not os.environ.get("COS_DISABLE_FLASH"))
     with jax.named_scope("attn.core"):
         if enabled and _FLASH_MESH:
-            import functools
             from jax.sharding import PartitionSpec as P
             from ..parallel.sp import shard_map_nocheck
             mesh, b_axes, h_axes, t_axes = _FLASH_MESH[-1]
@@ -1545,11 +1545,13 @@ def _mla(ctx, lp, params, bottoms):
 def _gqa_heads(ap):
     h, hd = int(ap.num_heads), int(ap.head_dim)
     hkv = int(ap.num_kv_heads) or h
-    if not (h and hd) or h % hkv or (ap.rotary and hd % 2):
+    rd = int(ap.rotary_dim) or hd
+    if not (h and hd) or h % hkv or (ap.rotary and (rd % 2 or rd > hd)):
         raise ValueError(
             f"GroupedQueryAttention: {h} heads of {hd} over {hkv} "
             "key/value heads (num_heads must be a multiple of "
-            "num_kv_heads, a rotary head_dim even)")
+            "num_kv_heads, a rotary head_dim or rotary_dim even and "
+            "inside the head)")
     return h, hkv, hd
 
 
@@ -1560,7 +1562,9 @@ def _gqa_params(lp, shapes):
     wf = _filler(ap.weight_filler if ap.has("weight_filler") else None,
                  "xavier")
     one = FillerParameter(type="constant", value=1.0)
-    specs = [("W_q", (h * hd, d), wf), ("W_k", (hkv * hd, d), wf),
+    # with an output gate W_q gives a head's query and gate side by side
+    qw = 2 * hd if ap.output_gate else hd
+    specs = [("W_q", (h * qw, d), wf), ("W_k", (hkv * hd, d), wf),
              ("W_v", (hkv * hd, d), wf), ("W_o", (d, h * hd), wf)]
     if ap.qk_norm:
         specs += [("q_norm", (hd,), one), ("k_norm", (hd,), one)]
@@ -1575,10 +1579,13 @@ def _gqa(ctx, lp, params, bottoms):
         q = x W_q -> H x head_dim;  k = x W_k, v = x W_v -> H/g x head_dim
         qk_norm: q <- RMSNorm(q), k <- RMSNorm(k) over each head, one
                  head_dim-wide scale each, shared by the heads
-        rotary:  adjacent pairs of the WHOLE head turn by position t
-                 with base rope_theta, after the norms
+        rotary:  adjacent pairs of the WHOLE head (rotary_dim > 0: of
+                 its first rotary_dim dims, the rest left as they are)
+                 turn by position t with base rope_theta, after the norms
         o = softmax(q k^T / sqrt(head_dim), causal) v, query head h
             reading key/value head h // g;  y = o W_o
+        output_gate: [q, gate] = x W_q, a head's 2 x head_dim side by
+                 side; y = (o * sigmoid(gate)) W_o (qwen3_next)
 
     The attention itself is `_attention_dispatch`, the one the other
     two attention types take; products are as `LatentAttention`'s (one
@@ -1592,19 +1599,28 @@ def _gqa(ctx, lp, params, bottoms):
     xf = x.reshape(t, b, -1)
     with jax.named_scope("attn"):
         q, k, v = (jnp.einsum("tbd,ed->tbe", xf, w, precision=prec
-                              ).reshape(t, b, n, hd)
+                              ).reshape(t, b, n, -1)
                    for w, n in ((w_q, h), (w_k, hkv), (w_v, hkv)))
+        if ap.output_gate:
+            q, gate = q[..., :hd], q[..., hd:].reshape(t, b, h * hd)
         if ap.qk_norm:
             eps = float(ap.rms_norm_eps)
             q, k = rms_norm(q, params[4], eps), rms_norm(k, params[5], eps)
         if ap.rotary:
-            theta = float(ap.rope_theta)
-            q, k = rope_adjacent(q, theta), rope_adjacent(k, theta)
+            theta, rd = float(ap.rope_theta), int(ap.rotary_dim)
+            if rd and rd < hd:
+                q, k = (jnp.concatenate(
+                    [rope_adjacent(a[..., :rd], theta), a[..., rd:]],
+                    axis=-1) for a in (q, k))
+            else:
+                q, k = rope_adjacent(q, theta), rope_adjacent(k, theta)
         # (T, B, heads, hd) -> (B, heads, T, hd)
         q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
         o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
                                 mxu_dtype=_kernel_operand_dtype(prec, q))
         o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * hd)
+        if ap.output_gate:
+            o = o * jax.nn.sigmoid(gate)
         return [jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)]
 
 
@@ -1624,19 +1640,24 @@ def _short_conv_params(lp, shapes):
     return specs
 
 
-def short_conv_mix(b, c, u, taps, bias=None):
-    """c * conv(b * u): the gates and the depthwise causal convolution
-    over time (axis 0) of the gated short convolution.  taps (D, L);
-    tap j multiplies the input at t - (L - 1) + j, zero before t = 0.
-    Shifted slices of one padded array, so XLA fuses gate, shifts and
-    gate into one pass."""
-    z = b * u
+def causal_taps(z, taps):
+    """Depthwise causal convolution over time (axis 0) of z (T, ..., D):
+    taps (D, L); tap j multiplies the input at t - (L - 1) + j, zero
+    before t = 0.  Shifted slices of one padded array, which XLA fuses
+    with the elementwise passes around them."""
     n_taps, t = taps.shape[1], z.shape[0]
     zp = jnp.pad(z, ((n_taps - 1, 0),) + ((0, 0),) * (z.ndim - 1))
-    conv = sum(zp[j:j + t] * taps[:, j].astype(z.dtype)
+    return sum(zp[j:j + t] * taps[:, j].astype(z.dtype)
                for j in range(n_taps))
+
+
+def short_conv_mix(b, c, u, taps, bias=None):
+    """c * conv(b * u): the gates and the depthwise causal convolution
+    over time (axis 0) of the gated short convolution (`causal_taps`:
+    XLA fuses gate, shifts and gate into one pass)."""
+    conv = causal_taps(b * u, taps)
     if bias is not None:
-        conv = conv + bias.astype(z.dtype)
+        conv = conv + bias.astype(conv.dtype)
     return c * conv
 
 
@@ -1664,6 +1685,261 @@ def _short_conv(ctx, lp, params, bottoms):
                                bcu[..., 2 * d:], taps,
                                params[3] if len(params) > 3 else None)
         return [jnp.einsum("...d,ed->...e", y, w_out, precision=prec)]
+
+
+def _gdn_dims(gp):
+    hk, hv = int(gp.num_k_heads), int(gp.num_v_heads)
+    dk, dv = int(gp.head_k_dim), int(gp.head_v_dim)
+    if not (hk and hv and dk and dv) or hv % hk or int(gp.chunk) < 1 \
+            or int(gp.conv_taps) < 1:
+        raise ValueError(
+            f"GatedDeltaNet: {hv} value heads of {dv} over {hk} key "
+            f"heads of {dk}, chunk {int(gp.chunk)}, taps "
+            f"{int(gp.conv_taps)} (num_v_heads must be a multiple of "
+            "num_k_heads)")
+    return hk, hv, dk, dv
+
+
+def _gdn_params(lp, shapes):
+    gp = lp.gated_delta_net_param
+    d = int(shapes[0][-1])
+    hk, hv, dk, dv = _gdn_dims(gp)
+    wf = _filler(gp.weight_filler if gp.has("weight_filler") else None,
+                 "xavier")
+    one = FillerParameter(type="constant", value=1.0)
+    kw, vw = hk * dk, hv * dv
+    return [("W_qkvz", (2 * kw + 2 * vw, d), wf), ("W_ba", (2 * hv, d), wf),
+            ("taps", (2 * kw + vw, int(gp.conv_taps)), wf),
+            # the family's A = uniform(0, 16), kept off log(0)
+            ("A_log", (hv,), FillerParameter(type="log_uniform", min=1e-3,
+                                             max=16.0)),
+            ("dt_bias", (hv,), one), ("norm", (dv,), one),
+            ("W_out", (d, vw), wf)]
+
+
+def _unit_lower_inverse(m, precision):
+    """Inverse of unit lower triangular matrices m (..., c, c), c a
+    power of two: the inverse of [[A, 0], [C, D]] is X - X [[0, 0],
+    [C, 0]] X with X = diag(A^-1, D^-1), from 1 x 1 blocks (the
+    identity) up, every level past the first two products of whole
+    c x c matrices under a mask.  No entry ever exceeds what the true
+    inverses of the diagonal blocks hold (a power series of the strict
+    part would, for keys that resemble each other)."""
+    c = m.shape[-1]
+    row, col = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+
+    def below(s):
+        """The block C of every 2s x 2s block on the diagonal."""
+        return ((row // (2 * s) == col // (2 * s))
+                & (row % (2 * s) >= s) & (col % (2 * s) < s))
+
+    # 2 x 2 blocks: [[1, 0], [c, 1]]^-1 = [[1, 0], [-c, 1]]
+    x = jnp.eye(c, dtype=m.dtype) - jnp.where(below(1), m, 0)
+    sizes = [s for s in (2 ** j for j in range(1, c.bit_length()))
+             if s < c]
+    if not sizes:
+        return x
+
+    def level(x, off):      # one loop, so that the levels compile once
+        xc = jnp.matmul(x, jnp.where(off, m, 0), precision=precision)
+        return x - jnp.matmul(xc, x, precision=precision), None
+
+    return lax.scan(level, x, jnp.stack([below(s) for s in sizes]))[0]
+
+
+# the state's products keep float32 (HIGHEST: a state carried along
+# 8,192 tokens is not rounded to bfloat16 once a chunk), as the router's
+_GDN_PRECISION = lax.Precision.HIGHEST
+
+# What was lowered, by operator shape: the chunk, the chunks a row, the
+# heads and the state's bytes.  Static, written while a program is
+# traced; the -train job puts it into its metrics as `info.gdn`.
+_GDN_PLANS: dict = {}
+
+
+def gdn_plans() -> dict:
+    return {k: dict(v) for k, v in _GDN_PLANS.items()}
+
+
+# chunks whose triangular systems, products and states are alive
+# together: the rule runs a group of chunks at a time, and the backward
+# pass computes a group again from the state at its edge
+_GDN_GROUP = 32
+
+
+def _delta_group(state, x, *, c: int):
+    """One group of G chunks of `gated_delta_rule`: state (B, Hk, R, dk,
+    dv) before it, x = q, k (B, Hk, 1, G, c, dk), v (B, Hk, R, G, c,
+    dv), g, beta (B, Hk, R, G, c) -> the state after it, o (B, Hk, R,
+    G, c, dv).
+
+    With gam the running sum of g inside a chunk, A = tril(beta k k^T
+    e^(gam_i - gam_j), -1) and T = (I + A)^-1, a chunk's tokens write
+    u - w S where u = T (beta v), w = T (beta e^gam k), and S is the
+    state before the chunk.  So a chunk is a linear map of the state,
+
+        S' = M S + B,    M = e^(gam_c) I - kd^T w,   B = kd^T u
+        o  = Q S + O,    Q = e^gam q - P w,          O = P u
+
+    (kd = e^(gam_c - gam) k, P = tril(q k^T e^(gam_i - gam_j))).  M, B,
+    Q and O of the G chunks are products over all of them at once; what
+    is left to go from chunk to chunk is one (dk, dk) x (dk, dv) product
+    a head, and the outputs follow from the G states together."""
+    prec = _GDN_PRECISION
+    qc, kc, vc, gc, bc = x
+    gam = jnp.cumsum(gc, axis=-1)
+    i = jnp.arange(c)
+    low = i[:, None] >= i[None, :]
+    # e^(gam_i - gam_j) for j <= i, 0 above the diagonal
+    decay = jnp.exp(jnp.where(low, gam[..., :, None] - gam[..., None, :],
+                              -jnp.inf))
+    kk = jnp.einsum("bhxnid,bhxnjd->bhxnij", kc, kc, precision=prec)
+    qk = jnp.einsum("bhxnid,bhxnjd->bhxnij", qc, kc, precision=prec)
+    strict = jnp.where(i[:, None] > i[None, :],
+                       bc[..., None] * kk * decay, 0)
+    tinv = _unit_lower_inverse(strict + jnp.eye(c, dtype=strict.dtype),
+                               prec)
+    egam = jnp.exp(gam)[..., None]
+    u = jnp.matmul(tinv, vc * bc[..., None], precision=prec)
+    w = jnp.matmul(tinv, kc * (bc[..., None] * egam), precision=prec)
+    p = qk * decay
+    last = gam[..., -1:]
+    kd_t = jnp.swapaxes(kc * jnp.exp(last - gam)[..., None], -1, -2)
+    dk = kc.shape[-1]
+    m = (jnp.exp(last)[..., None] * jnp.eye(dk, dtype=w.dtype)
+         - jnp.matmul(kd_t, w, precision=prec))
+    b_in = jnp.matmul(kd_t, u, precision=prec)
+    q_s = qc * egam - jnp.matmul(p, w, precision=prec)
+    o_own = jnp.matmul(p, u, precision=prec)
+
+    def step(state, x):     # -> the state after a chunk; emits the one before
+        m_n, b_n = x
+        return jnp.matmul(m_n, state, precision=prec) + b_n, state
+
+    state, before = lax.scan(
+        step, state, (jnp.moveaxis(m, 3, 0), jnp.moveaxis(b_in, 3, 0)))
+    return state, o_own + jnp.matmul(q_s, jnp.moveaxis(before, 0, 3),
+                                     precision=prec)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: int):
+    """The gated delta rule over the sequence, in chunks.
+
+        S_t = e^(g_t) S_(t-1) + k_t (beta_t (v_t - (e^(g_t) S_(t-1))^T k_t))^T
+        o_t = S_t^T q_t,  S_0 = 0
+
+    q, k (B, Hk, T, dk); v (B, Hk, R, T, dv); g (<= 0), beta (B, Hk, R,
+    T): key head h serves the R value heads (h, .)  -> o (B, Hk, R, T,
+    dv).  Within a chunk of `chunk` tokens (a power of two) the rule is
+    a unit lower triangular system and products (`_unit_lower_inverse`),
+    which make of the chunk a linear map of the (dk, dv) state; across
+    chunks the states are carried, one product a chunk; every decay is
+    the exponential of a difference of running sums that is <= 0.  The
+    chunks go `_GDN_GROUP` at a time (`_delta_group`) under a
+    `lax.scan` whose body is recomputed in the backward pass: what the
+    forward pass keeps is the state at each group's edge, nothing a
+    token and nothing a chunk.  T is padded to whole groups with tokens
+    that neither write (beta 0) nor decay (g 0)."""
+    b, hk, t, dk = q.shape
+    r, dv = v.shape[2], v.shape[-1]
+    c = int(chunk)
+    if c & (c - 1):
+        raise ValueError(f"gated_delta_rule: chunk {c} is not a power "
+                         "of two")
+    n = -(-t // c)
+    grp = min(_GDN_GROUP, n)
+    ng = -(-n // grp)
+    _GDN_PLANS[f"{b}x{t} {hk}/{hk * r} heads {dk}/{dv}"] = {
+        "chunk": c, "chunks_a_row": n, "chunks_a_group": grp,
+        "heads": hk * r, "state_bytes": b * hk * r * dk * dv * 4}
+    full = ng * grp * c
+
+    def groups(a, axis):
+        """Time on `axis` -> (groups, ..., chunks of a group, c, ...)."""
+        if full != t:
+            w = [(0, 0)] * a.ndim
+            w[axis] = (0, full - t)
+            a = jnp.pad(a, w)
+        a = a.reshape(a.shape[:axis] + (ng, grp, c) + a.shape[axis + 1:])
+        return jnp.moveaxis(a, axis, 0)
+
+    xs = (groups(q, 2)[:, :, :, None], groups(k, 2)[:, :, :, None],
+          groups(v, 3), groups(g, 3), groups(beta, 3))
+    _, o = lax.scan(
+        jax.checkpoint(functools.partial(_delta_group, c=c)),
+        jnp.zeros((b, hk, r, dk, dv), v.dtype), xs)
+    # (groups, B, Hk, R, chunks, c, dv) -> (B, Hk, R, T, dv)
+    return jnp.moveaxis(o, 0, 3).reshape(b, hk, r, full, dv)[..., :t, :]
+
+
+@register("GatedDeltaNet", params=_gdn_params)
+def _gdn(ctx, lp, params, bottoms):
+    """The Gated DeltaNet operator (qwen3_next's linear-attention
+    layer) on time-major (T, B, D) input:
+
+        [q, k, v, z] = x W_qkvz;  [b, a] = x W_ba
+        [q, k, v] <- silu(taps over time of concat(q, k, v)), causal
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)
+        q <- q / |q| / sqrt(head_k_dim);  k <- k / |k|
+        o = `gated_delta_rule`(q, k, v, g, beta), key head h // R
+            serving value head h, in float32
+        y = (RMSNorm(o) * norm * silu(z)) W_out
+
+    No state crosses a batch column, and nothing marks a document's
+    start inside a packed row (the state and the taps reach over a
+    boundary).  Scopes: `gdn`, inside it `gdn.conv` (taps + SiLU) and
+    `gdn.scan` (decay, strengths, normalisation, the chunked rule)."""
+    gp = lp.gated_delta_net_param
+    w_qkvz, w_ba, taps, a_log, dt_bias, norm, w_out = params
+    x = bottoms[0]
+    t, b = x.shape[0], x.shape[1]
+    hk, hv, dk, dv = _gdn_dims(gp)
+    r, kw, vw = hv // hk, hk * dk, hv * dv
+    prec = ctx.precision()
+    f32 = jnp.float32
+
+    def conv(a, taps):
+        return jax.nn.silu(causal_taps(a, taps))
+
+    def heads(qkv, ba, a_log, dt_bias):
+        """-> q, k (B, Hk, T, dk), v (B, Hk, R, T, dv), g, beta (B, Hk,
+        R, T), float32."""
+        beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            ba[..., hv:].astype(f32) + dt_bias.astype(f32))
+        q, k = (qkv[..., j * kw:(j + 1) * kw].astype(f32).reshape(
+            t, b, hk, dk) for j in (0, 1))
+        q, k = (a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True)
+                              + 1e-6) for a in (q, k))
+        v = qkv[..., 2 * kw:].astype(f32).reshape(t, b, hk, r, dv)
+        return (jnp.transpose(q * (dk ** -0.5), (1, 2, 0, 3)),
+                jnp.transpose(k, (1, 2, 0, 3)),
+                jnp.transpose(v, (1, 2, 3, 0, 4)),
+                jnp.transpose(g.reshape(t, b, hk, r), (1, 2, 3, 0)),
+                jnp.transpose(beta.reshape(t, b, hk, r), (1, 2, 3, 0)))
+
+    def gate(o, z, norm):
+        # (B, Hk, R, T, dv) -> (T, B, Hv, dv), normed and gated
+        o = jnp.transpose(o, (3, 0, 1, 2, 4)).reshape(t, b, hv, dv)
+        return (rms_norm(o, norm.astype(f32), float(gp.rms_norm_eps))
+                * jax.nn.silu(z.astype(f32))).astype(x.dtype)
+
+    # the elementwise passes between the products are computed again in
+    # the backward pass (`jax.checkpoint`), so that of a layer's 8,192-
+    # channel activations only the products' own outputs are kept
+    with jax.named_scope("gdn"):
+        qkvz = jnp.einsum("tbd,ed->tbe", x, w_qkvz, precision=prec)
+        ba = jnp.einsum("tbd,ed->tbe", x, w_ba, precision=prec)
+        with jax.named_scope("gdn.conv"):
+            qkv = jax.checkpoint(conv)(qkvz[..., :2 * kw + vw], taps)
+        with jax.named_scope("gdn.scan"):
+            o = gated_delta_rule(
+                *jax.checkpoint(heads)(qkv, ba, a_log, dt_bias),
+                int(gp.chunk))
+        o = jax.checkpoint(gate)(
+            o, qkvz[..., 2 * kw + vw:].reshape(t, b, hv, dv), norm)
+        return [jnp.einsum("tbe,de->tbd", o.reshape(t, b, vw), w_out,
+                           precision=prec)]
 
 
 def _moe_held(mp):
@@ -1701,6 +1977,8 @@ def _moe_params(lp, shapes):
         if hs:
             specs += [("S_gate", (d, hs), wf), ("S_up", (d, hs), wf),
                       ("S_down", (hs, d), wf)]
+            if mp.shared_gate:
+                specs.append(("S_sgate", (d, 1), wf))
         return specs
     if mp.dispatch != "capacity":
         raise ValueError(f"moe_param.dispatch {mp.dispatch!r}: "
@@ -1827,7 +2105,8 @@ def _moe_dropless(ctx, lp, params, bottoms):
     backward pass, so the layer keeps no k·N-row activation.
 
     What the absent experts would add is left out: the result is this
-    share's part of the routed sum plus the shared experts."""
+    share's part of the routed sum plus the shared experts (with
+    `shared_gate` times sigmoid(x w_sg), one gate a token)."""
     mp = lp.moe_param
     names = [n for n, _, _ in _moe_params(lp, [bottoms[0].shape])]
     pd = dict(zip(names, params))
@@ -1922,7 +2201,11 @@ def _moe_dropless(ctx, lp, params, bottoms):
         with jax.named_scope("moe.shared"):
             hs = jax.nn.silu(jnp.matmul(xf, pd["S_gate"], precision=prec)) \
                 * jnp.matmul(xf, pd["S_up"], precision=prec)
-            out = out + jnp.matmul(hs, pd["S_down"], precision=prec)
+            shared = jnp.matmul(hs, pd["S_down"], precision=prec)
+            if "S_sgate" in pd:     # one sigmoid gate a token
+                shared = shared * jax.nn.sigmoid(
+                    jnp.matmul(xf, pd["S_sgate"], precision=prec))
+            out = out + shared
     tops = [out.reshape(lead + (d,))]
     if len(lp.top) > 1:
         cf = counts.astype(jnp.float32)

@@ -205,8 +205,9 @@ def tp_param_specs(net, *, min_features: int = TP_MIN_FEATURES
                 # the dropless dispatch's gated experts alike (router,
                 # selection bias and shared experts stay replicated)
                 spec = P("ep", None, None)
-            # the three attention types and ShortConv stay replicated:
-            # their head / channel splits are not written
+            # the three attention types, ShortConv and GatedDeltaNet
+            # stay replicated: their head / channel splits are not
+            # written
             specs[lname][bname] = spec
     return specs
 
@@ -256,6 +257,9 @@ class MeshLayout:
         self.shapes = {ln: {bn: s for bn, s, _ in blobs}
                        for ln, blobs in net.param_layout.items()}
         validate_param_specs(self.param_specs, self.shapes, mesh)
+        if mesh.shape.get("sp", 1) > 1:
+            from .sp import refuse_time_sharding   # lazy: avoids cycle
+            refuse_time_sharding(net)
         # -- pipeline stages (pp axis) ---------------------------------
         # pp > 1 cuts the net into contiguous stages (the roofline-
         # balanced partitioner shared with PipelineSolver) and pins

@@ -38,6 +38,26 @@ def shard_map_nocheck(fn, mesh, in_specs, out_specs):
                      out_specs=out_specs, check_vma=False)
 
 
+# layer types whose state runs along the whole sequence on one device:
+# their time axis cannot be cut over `sp` (no exchange of the state
+# between the shards is written)
+WHOLE_SEQUENCE_TYPES = ("GatedDeltaNet",)
+
+
+def refuse_time_sharding(net) -> None:
+    """A net with a layer of `WHOLE_SEQUENCE_TYPES` is refused on a
+    mesh that shards time, by name, before anything is traced."""
+    bad = [lp.name for lp in net.compute_layers
+           if lp.type in WHOLE_SEQUENCE_TYPES]
+    if bad:
+        raise ValueError(
+            f"sequence parallelism (mesh axis sp > 1) is not written for "
+            f"{'/'.join(WHOLE_SEQUENCE_TYPES)} layers ({bad[0]!r} and "
+            f"{len(bad) - 1} more): the chunked scan carries its state "
+            "along the whole sequence on one device; use dp / ep / pp "
+            "axes for this net")
+
+
 def attention(q: Array, k: Array, v: Array, *, causal: bool = False,
               q_offset: int = 0, k_offset: int = 0) -> Array:
     """Reference softmax attention. q,k,v: (B, H, T, D); k and v may

@@ -551,16 +551,24 @@ class CaffeProcessor:
                 q.stop()
 
     def _note_flash_plans(self):
-        """The first step is lowered: what its flash attention calls
+        """The first step is lowered: what its Gated DeltaNet operators
+        came to (`info.gdn`) and what its flash attention calls
         came to (tiles, calls an attention, share of score tiles under
         the masked body; `pallas_kernels.flash_plans`) goes into the
         metrics as `info.flash` and into the log, once.  Static facts,
         nothing a step on the device."""
+        from .ops.layers import gdn_plans
         from .ops.pallas_kernels import flash_plans
         plans = flash_plans()
         if plans:
             self.metrics.set_info("flash", plans)
             _LOG.info("flash attention as lowered: %s", plans)
+        # beside it `info.gdn`: per GatedDeltaNet shape lowered, the
+        # chunk, the chunks a row, the heads and the state's bytes
+        plans = gdn_plans()
+        if plans:
+            self.metrics.set_info("gdn", plans)
+            _LOG.info("gated delta rule as lowered: %s", plans)
 
     VALIDATION_STALL_TIMEOUT = 30.0
 

@@ -586,6 +586,9 @@ class MoEParameter(Message):
         # added to the sum of the k chosen scores before the division
         # (lfm2_moe: 1e-6); 0 = the bare sum
         Field(14, "norm_epsilon", FLOAT, default=0.0),
+        # the shared experts' sum is multiplied by sigmoid(x w_sg), one
+        # gate a token (qwen3_next); blob `S_sgate` (D, 1)
+        Field(15, "shared_gate", BOOL, default=False),
     ]
 
 
@@ -607,8 +610,12 @@ class AttentionParameter(Message):
     `W_o`; query head h reads key/value head h // (num_heads /
     num_kv_heads); `qk_norm` puts an RMSNorm (`rms_norm_eps`, one
     `head_dim`-wide scale each, shared by the heads) on every q and k
-    head; `rotary` turns adjacent pairs of the whole head by
-    `rope_theta` after the norms.  All
+    head; `rotary` turns adjacent pairs of the whole head (or, with
+    `rotary_dim` > 0, of its first `rotary_dim` dims alone, angle
+    t theta^(-2i/rotary_dim)) by `rope_theta` after the norms;
+    `output_gate` widens `W_q` to `num_heads` x 2 `head_dim` (query
+    and gate of a head side by side) and multiplies the attention's
+    output by sigmoid(gate) before `W_o` (qwen3_next).  All
     types share one attention dispatch (flash kernel on the TPU when
     the shape tiles, XLA einsums otherwise); GSPMD partitions the
     einsums over whatever mesh axes the activations carry."""
@@ -626,6 +633,8 @@ class AttentionParameter(Message):
         Field(11, "num_kv_heads", UINT32, default=0),
         Field(12, "qk_norm", BOOL, default=False),
         Field(13, "rotary", BOOL, default=False),
+        Field(14, "rotary_dim", UINT32, default=0),
+        Field(15, "output_gate", BOOL, default=False),
     ]
 
 
@@ -640,6 +649,34 @@ class ShortConvParameter(Message):
         Field(1, "taps", UINT32, default=3),
         Field(2, "bias_term", BOOL, default=False),
         Field(3, "weight_filler", MESSAGE, message=FillerParameter),
+    ]
+
+
+class GatedDeltaNetParameter(Message):
+    """Extension: the Gated DeltaNet operator (`GatedDeltaNet`,
+    qwen3_next's linear-attention layer) on time-major (T, B, D) input.
+    `[q, k, v, z] = x W_qkvz` (`num_k_heads` x `head_k_dim` each for q
+    and k, `num_v_heads` x `head_v_dim` each for v and z, in whole
+    blocks in this order), `[b, a] = x W_ba` (`num_v_heads` each);
+    a depthwise causal convolution of `conv_taps` taps and a SiLU over
+    concat(q, k, v); per value head beta = sigmoid(b), g = -exp(A_log)
+    softplus(a + dt_bias); q and k L2-normalised, q / sqrt(head_k_dim);
+    key head h // (num_v_heads / num_k_heads) serves value head h; the
+    gated delta rule S_t = e^g S_(t-1) + k (beta (v - (e^g S_(t-1))^T
+    k))^T, o = S_t^T q over the sequence in chunks of `chunk` tokens;
+    RMSNorm(o) (`rms_norm_eps`, one `head_v_dim`-wide scale) x silu(z);
+    `W_out`.  Blobs `W_qkvz`, `W_ba`, `taps` (channels, conv_taps),
+    `A_log` (log of uniform [1e-3, 16)), `dt_bias` (1), `norm` (1),
+    `W_out`."""
+    FIELDS = [
+        Field(1, "num_k_heads", UINT32, default=1),
+        Field(2, "num_v_heads", UINT32, default=1),
+        Field(3, "head_k_dim", UINT32, default=128),
+        Field(4, "head_v_dim", UINT32, default=128),
+        Field(5, "conv_taps", UINT32, default=4),
+        Field(6, "chunk", UINT32, default=64),
+        Field(7, "rms_norm_eps", FLOAT, default=1e-6),
+        Field(8, "weight_filler", MESSAGE, message=FillerParameter),
     ]
 
 
@@ -677,6 +714,8 @@ class LayerParameter(Message):
         Field(150, "moe_param", MESSAGE, message=MoEParameter),
         Field(151, "rms_norm_param", MESSAGE, message=RMSNormParameter),
         Field(153, "short_conv_param", MESSAGE, message=ShortConvParameter),
+        Field(154, "gated_delta_net_param", MESSAGE,
+              message=GatedDeltaNetParameter),
         # consecutive layers that give the same non-empty name form one
         # block whose activations are recomputed in the backward pass
         # (Net.apply: one jax.checkpoint around the block); COS_REMAT

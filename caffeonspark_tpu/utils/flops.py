@@ -2,9 +2,9 @@
 
 Counts the multiply-accumulate work of the parametrised layers
 (Convolution / Deconvolution / InnerProduct / LSTM-style weights,
-the three attention types, ShortConv, MixtureOfExperts) from
-the weight blob shapes and inferred top shapes — the >99% of CaffeNet's
-arithmetic that lands on the MXU.  Elementwise layers (ReLU, LRN,
+the three attention types, ShortConv, GatedDeltaNet, MixtureOfExperts)
+from the weight blob shapes and inferred top shapes — the >99% of
+CaffeNet's arithmetic that lands on the MXU.  Elementwise layers (ReLU, LRN,
 Pooling, Softmax) are ignored; they are HBM-bound, not FLOP-bound.
 
 Used by bench.py for MFU: images/sec alone can't be sanity-checked
@@ -91,6 +91,19 @@ def layer_forward_flops(net) -> dict:
             # are elementwise passes (HBM-bound), not counted
             out[lp.name] = 2 * prod(first_top[:-1]) * sum(
                 prod(ps) for (n, ps, _) in specs if n in ("W_in", "W_out"))
+            continue
+        if lp.type == "GatedDeltaNet":
+            # the three products per position, plus the recurrence as
+            # written: per token and value head the read S^T k, the
+            # rank-one write and the read S^T q, 2 x dk x dv each (the
+            # decay of the state is an elementwise pass; taps, gates
+            # and norms are not counted)
+            gp = lp.gated_delta_net_param
+            n = prod(first_top[:-1])
+            out[lp.name] = 2 * n * sum(
+                prod(ps) for (nm, ps, _) in specs if nm.startswith("W_"))
+            out[lp.name] += (n * int(gp.num_v_heads) * 3 * 2
+                             * int(gp.head_k_dim) * int(gp.head_v_dim))
             continue
         if lp.type == "MixtureOfExperts":
             out[lp.name] = _moe_forward_flops(lp, dict(

@@ -368,6 +368,7 @@ def test_flash_chunk_keeps_long_rows_inside_the_default_window():
     assert chunks(8192, 64, 64, 4) == (4096, 4096)
     assert chunks(8192, 192, 128, 4) == (4096, 4096)
     assert chunks(16384, 192, 128, 2) == (8192, 8192)
+    assert chunks(8192, 256, 256, 2) == (4096, 4096)   # qwen3next's
     # a length no halving brings inside is refused, not sent to a
     # window XLA does not keep free
     with pytest.raises(ValueError, match="cannot be halved"):
@@ -382,6 +383,7 @@ def test_flash_chunk_keeps_long_rows_inside_the_default_window():
     (2, 32, 32, 4096, 192, 128),    # kanana2.train_packed4k
     (2, 32, 8, 4096, 64, 64),
     (1, 32, 8, 8192, 64, 64),       # lfm2.train_packed8k
+    (1, 16, 2, 8192, 256, 256),     # qwen3next.train_packed8k
     (2, 4, 4, 256, 32, 32), (1, 2, 2, 384, 64, 64),
     (1, 2, 2, 3072, 128, 128)])
 @pytest.mark.parametrize("isz", [2, 4])
@@ -402,12 +404,38 @@ def test_flash_tiles_follow_from_the_shape(b, h, hkv, t, d, dv, isz):
             need(d, dv, isz, *tiles)(c)) <= pk._SCOPED_VMEM
         # wider than the floor wherever the rows allow it: the point
         assert max(tiles) > 128 or c == 128, (kernel, tiles)
-        if isz == 2 and t >= 4096:          # both cells: one call
+        if isz == 2 and t >= 4096 and d < 256:  # kanana2, lfm2: one call
             assert c == t
     # a floor above the rows' own tiles is kept (the ring's whole-shard
     # blocks), and where not even the floor fits there is no answer
     assert pk._flash_tiles("fwd", 64, 32, 32, 4, (64, 64)) == (64, 64)
     assert pk._flash_tiles("dkv", 1 << 16, 128, 128, 4) is None
+
+
+@pytest.mark.parametrize("t,d,dv,chunk,fwd,dq,dkv", [
+    # what the parent of PR 36 plans for the two accepted cells: one
+    # call a kernel, 512 x 512 tiles, no window
+    (4096, 192, 128, 4096, (512, 512), (512, 512), (512, 512)),  # kanana2
+    (8192, 64, 64, 8192, (512, 512), (512, 512), (512, 512)),    # lfm2
+    # qwen3next.train_packed8k: k and v of one key/value head are 2 x 4
+    # MiB of bfloat16, so the rows go as pairs of chunks of 4,096
+    (8192, 256, 256, 4096, (512, 512), (256, 512), (512, 256)),
+])
+def test_flash_plans_of_the_language_model_cells(t, d, dv, chunk, fwd, dq,
+                                                 dkv):
+    """The plan of each language-model cell's attention shape, letter
+    for letter: a change to `_flash_tiles`, `_lanes` or `_flash_chunk`
+    for one model's sake shows here before it shows as another cell's
+    rate.  Every call fits the default window (no call asks for one)."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    floor = (pk.BLOCK_Q, pk.BLOCK_K)
+    need = {k: f(d, dv, 2, *floor) for k, f in pk._BLOCK_BYTES.items()}
+    assert pk._flash_chunk(t, 128, need["fwd"]) == chunk
+    assert pk._flash_chunk(t, 128, need["dq"], need["dkv"]) == chunk
+    for kernel, tiles in (("fwd", fwd), ("dq", dq), ("dkv", dkv)):
+        assert pk._flash_tiles(kernel, chunk, d, dv, 2) == tiles, kernel
+        assert pk._flash_window(pk._BLOCK_BYTES[kernel](
+            d, dv, 2, *tiles)(chunk)) <= pk._SCOPED_VMEM
 
 
 def test_flash_plans_count_the_masked_tiles():
@@ -465,7 +493,9 @@ def test_train_job_reports_flash_plans():
     class Job:
         metrics = PipelineMetrics()
 
+    from caffeonspark_tpu.ops import layers as L
     pk._FLASH_PLANS.clear()
+    L._GDN_PLANS.clear()        # `info.gdn` rides the same route
     CaffeProcessor._note_flash_plans(Job)       # no attention: nothing
     assert "info" not in Job.metrics.summary()
     q, k, v = _qkv(8, 1, 4, 2, 256, 64, 64)

@@ -122,6 +122,7 @@ def test_flash_attention_vjp_parity_on_tpu():
 @pytest.mark.parametrize("b,h,hkv,t,d,dv,sub", [
     (2, 32, 32, 4096, 192, 128, 4),     # kanana2.train_packed4k
     (1, 32, 8, 8192, 64, 64, 4),        # lfm2.train_packed8k: g = 4
+    (1, 16, 2, 8192, 256, 256, 4),      # qwen3next.train_packed8k: g = 8
 ])
 def test_flash_attention_real_shapes_parity_on_tpu(b, h, hkv, t, d, dv,
                                                    sub):
@@ -130,7 +131,8 @@ def test_flash_attention_real_shapes_parity_on_tpu(b, h, hkv, t, d, dv,
     blobs, bfloat16 operands, causal, tiles from the shape), against
     the einsum path.  The (T, T) scores of all heads do not fit the
     chip beside their gradients, so the einsum path runs the first
-    `sub` query heads (and the key/value heads they read): heads are
+    `sub` query heads (and the key/value heads they read; a whole group
+    where g > `sub`, `sub` heads a call): heads are
     independent, and the loss is a sum over them.  A new lowering runs
     under a watchdog (PERF.md section 7): a call that never ends
     kills the process instead of holding the machine."""
@@ -155,13 +157,26 @@ def test_flash_attention_real_shapes_parity_on_tpu(b, h, hkv, t, d, dv,
     try:
         out = jax.jit(fl)(q, k, v)
         gf = jax.jit(jax.grad(scal(fl), argnums=(0, 1, 2)))(q, k, v)
-        qs, ks, vs = q[:, :sub], k[:, :sub // g], v[:, :sub // g]
-        want = jax.jit(ref)(qs, ks, vs)
-        gr = jax.jit(jax.grad(scal(ref), argnums=(0, 1, 2)))(qs, ks, vs)
+        n_q = max(sub, g)               # whole groups of query heads
+        n_kv = n_q // g
+        ks, vs = k[:, :n_kv], v[:, :n_kv]
+        want, gr = [], None
+        for i in range(0, n_q, sub):    # `sub` query heads a call
+            kv = slice(i // g, max(i // g + 1, (i + sub) // g))
+            qs = q[:, i:i + sub]
+            want.append(jax.jit(ref)(qs, ks[:, kv], vs[:, kv]))
+            gq, gk, gv = jax.jit(jax.grad(scal(ref), argnums=(0, 1, 2)))(
+                qs, ks[:, kv], vs[:, kv])
+            zk, zv = jnp.zeros_like(ks), jnp.zeros_like(vs)
+            part = [gq, zk.at[:, kv].set(gk), zv.at[:, kv].set(gv)]
+            gr = part if gr is None else [
+                jnp.concatenate([gr[0], part[0]], axis=1),
+                gr[1] + part[1], gr[2] + part[2]]
+        want = jnp.concatenate(want, axis=1)
         got = [np.asarray(jax.device_get(x)) for x in (
-            out[:, :sub], gf[0][:, :sub], gf[1][:, :sub // g],
-            gf[2][:, :sub // g])]
-        want = [np.asarray(jax.device_get(x)) for x in (want,) + gr]
+            out[:, :n_q], gf[0][:, :n_q], gf[1][:, :n_kv],
+            gf[2][:, :n_kv])]
+        want = [np.asarray(jax.device_get(x)) for x in [want] + gr]
     finally:
         faulthandler.cancel_dump_traceback_later()
     assert all(np.isfinite(x).all() for x in got)

@@ -1,4 +1,4 @@
-"""The flash kernels at the real shapes of the benchmark's two language
+"""The flash kernels at the real shapes of the benchmark's three language
 models, compiled for a described (not attached) TPU v5e: what interpret
 mode cannot see — VMEM, tiling, the grouped block index maps.  PR 33
 found here, before any chip time, that a 64-wide head is padded to 128
@@ -33,6 +33,10 @@ def one_chip():
     (2, 32, 8, 4096, 64, 64, jnp.bfloat16, 3),      # lfm2 at 2 x 4,096
     (1, 32, 8, 8192, 64, 64, jnp.bfloat16, 3),      # lfm2.train_packed8k
     (2, 32, 32, 4096, 192, 128, jnp.bfloat16, 3),   # kanana2.train_packed4k
+    # qwen3next.train_packed8k: 256-wide heads, g = 8; k and v of one
+    # key/value head are 2 x 4 MiB resident, so 8,192 rows go as pairs
+    # of chunks of 4,096 (3 calls a kernel), none with a window
+    (1, 16, 2, 8192, 256, 256, jnp.bfloat16, 9),
     # float32 operands (`_mha`'s exact mode) take twice the room: 8,192
     # rows go as pairs of chunks of 4,096, three forward and three for
     # each backward kernel
