@@ -1751,9 +1751,11 @@ def _unit_lower_inverse(m, precision):
 # 8,192 tokens is not rounded to bfloat16 once a chunk), as the router's
 _GDN_PRECISION = lax.Precision.HIGHEST
 
-# What was lowered, by operator shape: the chunk, the chunks a row, the
-# heads and the state's bytes.  Static, written while a program is
-# traced; the -train job puts it into its metrics as `info.gdn`.
+# What was lowered, by operator shape: the form ("kernel" or "xla"), the
+# chunk, the chunks a row, between two kept states and a call (or
+# loop), the heads and the state's bytes.  Static, written while a
+# program is traced; the -train job puts it into its metrics as
+# `info.gdn`.
 _GDN_PLANS: dict = {}
 
 
@@ -1761,17 +1763,25 @@ def gdn_plans() -> dict:
     return {k: dict(v) for k, v in _GDN_PLANS.items()}
 
 
-# chunks whose triangular systems, products and states are alive
-# together: the rule runs a group of chunks at a time, and the backward
-# pass computes a group again from the state at its edge
+# chunks between two states that the forward pass keeps (the residual
+# of either form: (chunks a row / group) states of (dk, dv) a head), the
+# backward pass computing a group again from the state at its edge.  In
+# the XLA form, this number, it also bounds what is alive together: the
+# triangular systems, products and states of a group of chunks.  In the
+# kernel form (`pallas_kernels.GDN_GROUP_CHUNKS`, 16) it bounds the
+# backward pass's transient: the state before every chunk of a group,
+# its T and its vn in HBM between the recomputation and the sweep
+# (4 MB a chunk at 16 key heads of 128 / 2 x 128)
 _GDN_GROUP = 32
 
 
 def _delta_group(state, x, *, c: int):
-    """One group of G chunks of `gated_delta_rule`: state (B, Hk, R, dk,
-    dv) before it, x = q, k (B, Hk, 1, G, c, dk), v (B, Hk, R, G, c,
-    dv), g, beta (B, Hk, R, G, c) -> the state after it, o (B, Hk, R,
-    G, c, dv).
+    """One group of G chunks of the XLA form of `gated_delta_rule`:
+    state (B, Hk, R, dk, dv) before it, x = q, k (B, Hk, 1, G, c, dk),
+    v (B, Hk, R, G, c, dv), g, beta (B, Hk, R, G, c) -> the state after
+    it, o (B, Hk, R, G, c, dv).  (The kernel form computes the same
+    chunk in the three-product order, `pallas_kernels._GdnChunk`; this
+    one is what its tests are held to.)
 
     With gam the running sum of g inside a chunk, A = tril(beta k k^T
     e^(gam_i - gam_j), -1) and T = (I + A)^-1, a chunk's tokens write
@@ -1831,27 +1841,71 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int):
     q, k (B, Hk, T, dk); v (B, Hk, R, T, dv); g (<= 0), beta (B, Hk, R,
     T): key head h serves the R value heads (h, .)  -> o (B, Hk, R, T,
     dv).  Within a chunk of `chunk` tokens (a power of two) the rule is
-    a unit lower triangular system and products (`_unit_lower_inverse`),
-    which make of the chunk a linear map of the (dk, dv) state; across
-    chunks the states are carried, one product a chunk; every decay is
-    the exponential of a difference of running sums that is <= 0.  The
-    chunks go `_GDN_GROUP` at a time (`_delta_group`) under a
-    `lax.scan` whose body is recomputed in the backward pass: what the
-    forward pass keeps is the state at each group's edge, nothing a
-    token and nothing a chunk.  T is padded to whole groups with tokens
-    that neither write (beta 0) nor decay (g 0)."""
+    a unit lower triangular system and products, which make of the
+    chunk a linear map of the (dk, dv) state; across chunks the states
+    are carried; every decay is the exponential of a difference of
+    running sums that is <= 0.  What the forward pass keeps is the
+    state every group of chunks (`_GDN_GROUP`), nothing a token and
+    nothing a chunk; the backward pass computes a group again from
+    there.
+
+    Two forms compute it, chosen by what can be observed here, no
+    option: the Mosaic kernels (`pallas_kernels.gated_delta_rule_
+    kernels`: the states in VMEM from the first chunk to the last) on
+    the TPU (`pallas_enabled()`; in interpret mode under
+    COS_FLASH_INTERPRET=1, the CPU suite's way in) when R chunk and both
+    head sizes fill whole 128-lane tiles, float32 comes in and no mesh
+    of several devices is installed (a bare Mosaic call cannot be
+    partitioned); else the XLA form, `gated_delta_rule_xla`, which is
+    also what the kernels' tests are held to.  `gdn_plans()` says which
+    one a shape was lowered to."""
+    from .pallas_kernels import (gated_delta_rule_kernels, gdn_rule_steps,
+                                 gdn_rule_tiles, pallas_enabled)
     b, hk, t, dk = q.shape
     r, dv = v.shape[2], v.shape[-1]
     c = int(chunk)
     if c & (c - 1):
         raise ValueError(f"gated_delta_rule: chunk {c} is not a power "
                          "of two")
+    interpret = _pallas_interpret()
+    kernel = ((pallas_enabled() or interpret) and not _FLASH_MESH
+              and gdn_rule_tiles(r, c, dk, dv)
+              and all(a.dtype == jnp.float32
+                      for a in (q, k, v, g, beta)))
+    n = -(-t // c)
+    # chunks between two kept states, and chunks the states pass through
+    # in one call: a row, padded to whole grid steps and groups (the
+    # kernels' forward call, the states in VMEM all along), or one
+    # iteration of the scan over groups (XLA)
+    if kernel:
+        steps, group_steps, a_call = gdn_rule_steps(n)
+        group = steps * group_steps
+    else:
+        group = a_call = min(_GDN_GROUP, n)
+    _GDN_PLANS[f"{b}x{t} {hk}/{hk * r} heads {dk}/{dv}"] = {
+        "rule": "kernel" if kernel else "xla",
+        "chunk": c, "chunks_a_row": n, "chunks_a_group": group,
+        "chunks_a_call": a_call, "heads": hk * r,
+        "state_bytes": b * hk * r * dk * dv * 4}
+    if kernel:
+        return gated_delta_rule_kernels(q, k, v, g, beta, c,
+                                        interpret=interpret)
+    return gated_delta_rule_xla(q, k, v, g, beta, c)
+
+
+def gated_delta_rule_xla(q, k, v, g, beta, c: int):
+    """`gated_delta_rule` as XLA products at `_GDN_PRECISION`: the
+    fallback (CPU, shapes that do not tile, a mesh) and the parity
+    reference of the kernels' tests.  The chunks go `_GDN_GROUP` at a
+    time (`_delta_group`: `_unit_lower_inverse`, the chunk as a linear
+    map) under a `lax.scan` whose body is recomputed in the backward
+    pass.  T is padded to whole groups with tokens that neither write
+    (beta 0) nor decay (g 0)."""
+    b, hk, t, dk = q.shape
+    r, dv = v.shape[2], v.shape[-1]
     n = -(-t // c)
     grp = min(_GDN_GROUP, n)
     ng = -(-n // grp)
-    _GDN_PLANS[f"{b}x{t} {hk}/{hk * r} heads {dk}/{dv}"] = {
-        "chunk": c, "chunks_a_row": n, "chunks_a_group": grp,
-        "heads": hk * r, "state_bytes": b * hk * r * dk * dv * 4}
     full = ng * grp * c
 
     def groups(a, axis):
@@ -1888,7 +1942,11 @@ def _gdn(ctx, lp, params, bottoms):
     No state crosses a batch column, and nothing marks a document's
     start inside a packed row (the state and the taps reach over a
     boundary).  Scopes: `gdn`, inside it `gdn.conv` (taps + SiLU) and
-    `gdn.scan` (decay, strengths, normalisation, the chunked rule)."""
+    `gdn.scan` (decay, strengths, normalisation, and the rule in
+    whichever form `gated_delta_rule` lowers here: the Mosaic kernels on
+    the TPU at the family's shapes, forward, recomputation and backward
+    calls alike, else the XLA form, the reference of the kernels'
+    tests)."""
     gp = lp.gated_delta_net_param
     w_qkvz, w_ba, taps, a_log, dt_bias, norm, w_out = params
     x = bottoms[0]

@@ -1165,3 +1165,536 @@ def flash_block_update(q: jax.Array, k_blk: jax.Array,
         interpret=interpret,
     )(*offs, q, k_blk, v_blk, m[:, :, None], l[:, :, None], acc)
     return mo[:, :, 0], lo[:, :, 0], ao
+
+
+# ---------------------------------------------------------------------------
+# The gated delta rule (Gated DeltaNet's scan), fwd + bwd kernels
+# ---------------------------------------------------------------------------
+# `ops.layers.gated_delta_rule` as Mosaic calls: a program is one key
+# head with its R value heads, the grid's second axis walks the chunks
+# in order, and the (dk, dv) states of the R heads stay in a VMEM
+# scratch from the first chunk to the last ((dk, R dv) float32: the
+# heads side by side along the lanes).  The R heads of a chunk are
+# PACKED along the rows, N = R c of them (128 at the family's chunk of
+# 64 and two value heads a key head): their unit lower triangular
+# systems are the diagonal blocks of one (N, N) system, so the blockwise
+# inverse, the decays and every score matrix fill whole 128-lane tiles.
+# A chunk, with gam the running sum of g inside it and S = S_r the state
+# before it (the XLA form's algebra, `layers._delta_group`, in the
+# three-product order):
+#
+#     A  = tril(beta k k^T e^(gam_i - gam_j), -1),  T = (I + A)^-1
+#     vn = T (beta (v - e^gam (k S)))             what the tokens write
+#     o  = e^gam (q S) + tril(q k^T e^(gam_i - gam_j)) vn
+#     S' = e^(gam_c) S + (e^(gam_c - gam) k)^T vn
+#
+# Every product is float32 at HIGHEST, as outside (`_GDN_PRECISION`).
+# The backward pass goes a group of chunks at a time, last group first,
+# in one loop: the forward kernel runs again from the state the forward
+# pass kept at the group's edge and writes the state before every chunk,
+# T and vn (transient, a group's worth), and the backward kernel sweeps
+# the group's chunks in reverse with dS in VMEM, writing its rows of
+# the five gradients into arrays the loop carries.  No call asks for a
+# VMEM window (PR 33's hang: `_SCOPED_VMEM`).  What sets a chunk's time
+# on the v5e is the MXU's weight loads: a (128, 128) float32 product at
+# HIGHEST is six loads of its right-hand tile, 768 cycles on one of the
+# four MXUs, whether 64 or 128 rows stream past it (PR 37: the static
+# schedule, 2,448 / 2,117 / 3,121 bundles a chunk for the forward, the
+# recomputation and the sweep, matched the chip's time to 3%).
+
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+_NN = (((1,), (0,)), ((), ()))
+
+# Chunks a grid step (one block of rows) and between two kept states.
+# On the chip at qwen3next's shape (PR 37, call 1: 16 / 32 heads, 8,192
+# tokens, 128 / 128) 4 and 8 a step take the same forward (5.25 / 5.33
+# ms), 4 the shorter backward; 16 between states keeps the backward
+# pass's transient at 64 MB a layer and the step's memory at the XLA
+# form's; the chunk loop unrolled gains 2-5% for four times the code.
+GDN_STEP_CHUNKS = 4
+GDN_GROUP_CHUNKS = 16
+
+
+def _gdn_dot(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _rowsum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _stack_heads(x, r: int, width: int):
+    """(c, r width), the heads side by side -> (r c, width), the heads
+    one under the other."""
+    return jnp.concatenate(
+        [x[:, i * width:(i + 1) * width] for i in range(r)], axis=0)
+
+
+def _stacked_rows(ref, at, r: int):
+    """Rows `at` of the r heads of a (1, r, rows, width) block, one
+    head under the other."""
+    return jnp.concatenate([ref[0, i, at, :] for i in range(r)], axis=0)
+
+
+def _beside_heads(x, r: int, c: int):
+    """(r c, width), the heads one under the other -> (c, r width)."""
+    return jnp.concatenate(
+        [x[i * c:(i + 1) * c] for i in range(r)], axis=1)
+
+
+class _GdnChunk:
+    """What a chunk's forward and backward passes share: the masks of
+    the packed (N, N) matrices, gam and beta as columns, the decays, the
+    scores, the state's products with k and q."""
+
+    def __init__(self, qc, kc, gam_row, beta_row, s_cat, r: int, c: int,
+                 with_q: bool = True):
+        n = r * c
+        self.r, self.c, self.n = r, c, n
+        self.dv = s_cat.shape[1] // r
+        ri = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+        self.ri, self.ci = ri, ci
+        lg = c.bit_length() - 1
+        self.eye = ri == ci
+        same = (ri >> lg) == (ci >> lg)         # one head's block
+        self.low = same & (ri >= ci)
+        self.strict = same & (ri > ci)
+        self.gam = self.col(gam_row)
+        self.beta = self.col(beta_row)
+        # e^(gam_i - gam_j) at j <= i of one head, 0 elsewhere
+        self.dm = jnp.exp(jnp.where(self.low, self.gam - gam_row,
+                                    _NEG_INF))
+        self.k_big = jnp.concatenate([kc] * r, axis=0)
+        self.q_big = jnp.concatenate([qc] * r, axis=0)
+        self.kq = jnp.concatenate([kc, qc], axis=0)
+        # k k^T and q k^T: the heads share k and q, so c rows each go
+        # through the MXU, against every head's columns at once
+        both = _gdn_dot(self.kq if with_q else kc, self.k_big, _NT)
+        self.kk = jnp.concatenate([both[:c]] * r, axis=0)
+        if with_q:
+            self.qk = jnp.concatenate([both[c:]] * r, axis=0)
+        ss = _gdn_dot(self.kq if with_q else kc, s_cat)     # (2c, r dv)
+        self.ks = _stack_heads(ss[:c], r, self.dv)
+        if with_q:
+            self.qs = _stack_heads(ss[c:], r, self.dv)
+        self.eg = jnp.exp(self.gam)
+        # gam at a head's last token, on every row of the head
+        self.glast = _rowsum(jnp.where(
+            same & ((ci & (c - 1)) == c - 1), gam_row, 0.0))
+        self.ed = jnp.exp(self.glast - self.gam)
+        self.kd = self.ed * self.k_big
+        self.s_cat = s_cat
+
+    def col(self, row):
+        return _rowsum(jnp.where(self.eye, row, 0.0))
+
+    def row(self, col):
+        return jnp.sum(jnp.where(self.eye, col, 0.0), axis=0,
+                       keepdims=True)
+
+    def head_rows(self, x, i):
+        return x[i * self.c:(i + 1) * self.c]
+
+    def head_state(self, s, i):
+        return s[:, i * self.dv:(i + 1) * self.dv]
+
+    def egl(self, i):
+        """e^(gam_c) of head i, (1, 1)."""
+        return jnp.exp(self.glast[i * self.c:i * self.c + 1])
+
+    def scores(self):
+        """P = tril(q k^T e^(gam_i - gam_j)) of every head."""
+        return self.qk * self.dm
+
+    def fold(self, x):
+        """(N, N), nothing outside the heads' own blocks -> (c, N): the
+        heads' blocks side by side (the sum of the heads' rows)."""
+        return sum(self.head_rows(x, i) for i in range(self.r))
+
+    def inverse(self):
+        """(I + A)^-1 by blocks (`layers._unit_lower_inverse`): the
+        inverse of [[X, 0], [C, Y]] from those of X and Y, 1 x 1 blocks
+        up to a head's c x c; the blocks of different heads never
+        meet."""
+        a = jnp.where(self.strict, self.beta * self.kk * self.dm, 0.0)
+        ri, ci = self.ri, self.ci
+
+        def below(s):       # the block C of every 2s x 2s block
+            k = s.bit_length()
+            return (((ri >> k) == (ci >> k)) & ((ri & s) != 0)
+                    & ((ci & s) == 0))
+
+        if self.c >= 8:
+            # the 8 x 8 blocks on the diagonal (a sublane tile each) by
+            # forward substitution on the VPU, which has the room (the
+            # MXU's weight loads set a chunk's time): column j of every
+            # block, spread along the lanes, times row j of the block's
+            # inverse
+            n = self.n
+            in8 = (ri >> 3) == (ci >> 3)
+            a8 = jnp.where(in8, a, 0.0)
+            x = jnp.where(self.eye, 1.0, 0.0)
+            for j in range(7):
+                col = _rowsum(jnp.where((ci & 7) == j, a8, 0.0))
+                row = jnp.sum(
+                    jnp.where((ri & 7) == j, x, 0.0).reshape(n // 8, 8, n),
+                    axis=1, keepdims=True)
+                x = x - (col.reshape(n // 8, 8, 1) * row).reshape(n, n)
+            s = 8
+        else:
+            x = jnp.where(self.eye, 1.0, 0.0) - jnp.where(below(1), a, 0.0)
+            s = 2
+        while s < self.c:
+            off = jnp.where(below(s), a, 0.0)
+            if s % 8:
+                x = x - _gdn_dot(_gdn_dot(x, off), x)
+            else:
+                # X C X has rows in the lower half of each 2s x 2s
+                # block only: those rows alone go through the MXU
+                # (whole sublane tiles from s = 8 on)
+                blocks = range(0, self.n, 2 * s)
+                half = jnp.concatenate(
+                    [x[b + s:b + 2 * s] for b in blocks], axis=0)
+                half = half - _gdn_dot(_gdn_dot(half, off), x)
+                x = jnp.concatenate(
+                    [p for i, b in enumerate(blocks)
+                     for p in (x[b:b + s], half[i * s:(i + 1) * s])],
+                    axis=0)
+            s *= 2
+        return x
+
+
+def _gdn_fwd_kernel(group_ref, q_ref, k_ref, v_ref, gam_ref, beta_ref,
+                    s0_ref, *refs, r: int, c: int, steps: int,
+                    group_steps: int, save: bool):
+    """`steps` chunks of one key head (`group_ref` is for the index
+    maps: which group of the row the grid walks).  save=False: o and the
+    state at each group's edge; save=True (the backward pass's
+    recomputation of a group): the state before each chunk, T and vn."""
+    del group_ref
+    if save:
+        s_all_ref, t_ref, vn_ref, s_ref = refs
+    else:
+        o_ref, edge_ref, s_ref = refs
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        s_ref[...] = s0_ref[0, 0]
+
+    if not save:
+        @pl.when(j % group_steps == 0)
+        def _():
+            edge_ref[0, 0] = s_ref[...]
+
+    def chunk(jj, carry):
+        at = pl.ds(pl.multiple_of(jj * c, c), c)
+        s_cat = s_ref[...]
+        ch = _GdnChunk(q_ref[0, at, :], k_ref[0, at, :], gam_ref[0, jj],
+                       beta_ref[0, jj], s_cat, r, c, with_q=not save)
+        v_big = _stacked_rows(v_ref, at, r)
+        t = ch.inverse()
+        vn = _gdn_dot(t, ch.beta * (v_big - ch.eg * ch.ks))
+        if save:
+            s_all_ref[0, jj] = s_cat
+            t_ref[0, jj] = t
+            vn_ref[0, jj] = vn
+        else:
+            o = ch.eg * ch.qs + _gdn_dot(ch.scores(), vn)
+            for i in range(r):
+                o_ref[0, i, at, :] = ch.head_rows(o, i)
+        s_ref[...] = jnp.concatenate(
+            [ch.egl(i) * ch.head_state(s_cat, i)
+             + _gdn_dot(ch.head_rows(ch.kd, i), ch.head_rows(vn, i), _TN)
+             for i in range(r)], axis=1)
+        return carry
+
+    jax.lax.fori_loop(0, steps, chunk, 0)
+
+
+def _gdn_bwd_kernel(group_ref, q_ref, k_ref, v_ref, gam_ref, beta_ref,
+                    do_ref, s_all_ref, t_ref, vn_ref, ds_in_ref, *refs,
+                    r: int, c: int, steps: int):
+    """The reverse sweep over `steps` chunks of a group (the grid walks
+    the group's blocks last first), dS of the R heads in VMEM.  The
+    gradients' arrays are whole rows': the later groups' calls wrote
+    their blocks, this one is handed the arrays (five operands never
+    read here, aliased to the outputs) and writes its own."""
+    del group_ref
+    (dq_ref, dk_ref, dv_ref, dgam_ref, dbeta_ref, ds_out_ref,
+     ds_ref) = refs[-7:]
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        ds_ref[...] = ds_in_ref[0, 0]
+
+    def chunk(it, carry):
+        jj = steps - 1 - it
+        at = pl.ds(pl.multiple_of(jj * c, c), c)
+        ds_cat = ds_ref[...]
+        ch = _GdnChunk(q_ref[0, at, :], k_ref[0, at, :], gam_ref[0, jj],
+                       beta_ref[0, jj], s_all_ref[0, jj], r, c)
+        n = ch.n
+        v_big, do = _stacked_rows(v_ref, at, r), _stacked_rows(do_ref, at, r)
+        t, vn = t_ref[0, jj], vn_ref[0, jj]
+        p = ch.scores()
+        pre = v_big - ch.eg * ch.ks
+        # what the later chunks' states hand back to this chunk's writes
+        kdds = jnp.concatenate(
+            [_gdn_dot(ch.head_rows(ch.kd, i), ch.head_state(ds_cat, i))
+             for i in range(r)], axis=0)
+        dvn = _gdn_dot(p, do, _TN) + kdds
+        drhs = _gdn_dot(t, dvn, _TN)
+        both = _gdn_dot(jnp.concatenate([drhs, do], axis=0), vn, _NT)
+        da = jnp.where(ch.strict, -both[:n], 0.0)       # d A
+        dp = both[n:]                                   # d P, in dm's mask
+        dpre = ch.beta * drhs
+        dks, dqs = -ch.eg * dpre, ch.eg * do
+        kkd = ch.kk * ch.dm
+        e = da * (ch.beta * kkd) + dp * p               # d(decay) x decay
+        z = _rowsum(vn * kdds)
+        dbeta = _rowsum(drhs * pre) + _rowsum(da * kkd)
+        dgam = (_rowsum(e) - z
+                + ch.eg * (_rowsum(do * ch.qs) - _rowsum(dpre * ch.ks)))
+        dgam_row = ch.row(dgam) - jnp.sum(e, axis=0, keepdims=True)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+        for i in range(r):      # through gam at each head's last token
+            sds = ch.head_state(ch.s_cat, i) * ch.head_state(ds_cat, i)
+            tot = (jnp.sum(ch.head_rows(z, i), axis=0, keepdims=True)
+                   + ch.egl(i) * jnp.sum(_rowsum(sds), axis=0,
+                                         keepdims=True))
+            dgam_row = dgam_row + jnp.where(lane == i * c + c - 1, tot,
+                                            0.0)
+        dgam_ref[0, jj] = dgam_row
+        dbeta_ref[0, jj] = ch.row(dbeta)
+        # through the state: k S and q S
+        st = jnp.concatenate(
+            [jnp.concatenate([ch.head_rows(dks, i), ch.head_rows(dqs, i)],
+                             axis=0) for i in range(r)], axis=1)
+        dkq = _gdn_dot(st, ch.s_cat, _NT)               # (2c, dk)
+        ds_ref[...] = jnp.concatenate(
+            [ch.egl(i) * ch.head_state(ds_cat, i) for i in range(r)],
+            axis=1) + _gdn_dot(ch.kq, st, _TN)
+        dk_kd = _gdn_dot(_beside_heads(ch.ed * vn, r, c), ds_cat, _NT)
+        # through the scores
+        dkk = da * ch.beta * ch.dm
+        dqk = dp * ch.dm
+        on_k = _gdn_dot(jnp.concatenate(
+            [ch.fold(dkk + dkk.T), ch.fold(dqk)], axis=0), ch.k_big)
+        dq_ref[0, at, :] = dkq[c:] + on_k[c:]
+        dk_ref[0, at, :] = (dkq[:c] + dk_kd + on_k[:c]
+                            + _gdn_dot(ch.fold(dqk.T), ch.q_big))
+        for i in range(r):
+            dv_ref[0, i, at, :] = ch.head_rows(dpre, i)
+        return carry
+
+    jax.lax.fori_loop(0, steps, chunk, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        ds_out_ref[0, 0] = ds_ref[...]
+
+
+def _gdn_specs(r, c, steps, dk, dv, at):
+    """Block specs of one grid step's rows: q / k, v-shaped, gam-shaped
+    arrays; `at(j, group)` is the block of grid step j."""
+    rows = steps * c
+    return (pl.BlockSpec((1, rows, dk), lambda b, j, g: (b, at(j, g), 0)),
+            pl.BlockSpec((1, r, rows, dv),
+                         lambda b, j, g: (b, 0, at(j, g), 0)),
+            pl.BlockSpec((1, steps, 1, r * c),
+                         lambda b, j, g: (b, at(j, g), 0, 0)))
+
+
+def _gdn_call(kernel, name, group, operands, *, grid, in_specs, out_specs,
+              out_shape, state_shape, interpret, aliases=None):
+    """One Mosaic call of the rule: `group` ((1,) int32, which group of
+    the row) is prefetched for the index maps; the grid is (key heads,
+    the group's steps), the second in order with the states in a VMEM
+    scratch; no VMEM window is asked."""
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"))}
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM(state_shape, jnp.float32)]),
+        out_shape=out_shape,
+        input_output_aliases=aliases or {},
+        interpret=interpret, name=name, **params,
+    )(group, *operands)
+
+
+def _gdn_fwd_call(group, q, k, v, gam, beta, s0, *, c, steps, count,
+                  group_steps=None, interpret=False):
+    """The forward kernel over the `count` grid steps of group `group`
+    of the rows.  group_steps given (count = the whole row, group 0):
+    -> o and the states at the groups' edges; else (the backward's
+    recomputation) -> the state before every chunk, T and vn.  s0 (BH,
+    groups, dk, R dv): the state before each group."""
+    bh, _, dk = q.shape
+    r, dv = v.shape[1], v.shape[-1]
+    n = r * c
+    qspec, vspec, gspec = _gdn_specs(r, c, steps, dk, dv,
+                                     lambda j, g: g[0] * count + j)
+    state = pl.BlockSpec((1, 1, dk, r * dv),
+                         lambda b, j, g: (b, g[0], 0, 0))
+    f32 = jnp.float32
+    if group_steps is None:
+        chunks = count * steps
+        out_shape = (jax.ShapeDtypeStruct((bh, chunks, dk, r * dv), f32),
+                     jax.ShapeDtypeStruct((bh, chunks, n, n), f32),
+                     jax.ShapeDtypeStruct((bh, chunks, n, dv), f32))
+        out_specs = tuple(
+            pl.BlockSpec((1, steps) + a.shape[2:],
+                         lambda b, j, g: (b, j, 0, 0)) for a in out_shape)
+    else:
+        out_shape = (
+            jax.ShapeDtypeStruct(v.shape, f32),
+            jax.ShapeDtypeStruct((bh, count // group_steps, dk, r * dv),
+                                 f32))
+        out_specs = (
+            vspec,
+            pl.BlockSpec((1, 1, dk, r * dv),
+                         lambda b, j, g: (b, j // group_steps, 0, 0)))
+    return _gdn_call(
+        functools.partial(_gdn_fwd_kernel, r=r, c=c, steps=steps,
+                          group_steps=group_steps or 1,
+                          save=group_steps is None),
+        "cos_gdn_save" if group_steps is None else "cos_gdn_fwd",
+        group, (q, k, v, gam, beta, s0), grid=(bh, count),
+        in_specs=[qspec, qspec, vspec, gspec, gspec, state],
+        out_specs=out_specs, out_shape=out_shape,
+        state_shape=(dk, r * dv), interpret=interpret)
+
+
+def _gdn_bwd_call(group, q, k, v, gam, beta, do, s_all, t_all, vn_all, ds,
+                  grads, *, c, steps, count, interpret=False):
+    """The backward kernel over the `count` grid steps of group `group`
+    of the rows, last first -> (dq, dk, dv, dgam, dbeta) with those rows
+    written, and dS before them; s_all, t_all, vn_all are the group's
+    own, ds (BH, 1, dk, R dv) is dS after the group, `grads` the five
+    arrays as the later groups' calls left them."""
+    bh, _, dk = q.shape
+    r, dv = v.shape[1], v.shape[-1]
+    qspec, vspec, gspec = _gdn_specs(
+        r, c, steps, dk, dv, lambda j, g: g[0] * count + count - 1 - j)
+    saved = [pl.BlockSpec((1, steps) + a.shape[2:],
+                          lambda b, j, g: (b, count - 1 - j, 0, 0))
+             for a in (s_all, t_all, vn_all)]
+    state = pl.BlockSpec((1, 1, dk, r * dv), lambda b, j, g: (b, 0, 0, 0))
+    operands = (q, k, v, gam, beta, do, s_all, t_all, vn_all, ds, *grads)
+    return _gdn_call(
+        functools.partial(_gdn_bwd_kernel, r=r, c=c, steps=steps),
+        "cos_gdn_bwd", group, operands, grid=(bh, count),
+        in_specs=[qspec, qspec, vspec, gspec, gspec, vspec] + saved
+        + [state] + [pl.BlockSpec(memory_space=pl.ANY)] * len(grads),
+        out_specs=(qspec, qspec, vspec, gspec, gspec, state),
+        out_shape=tuple(jax.ShapeDtypeStruct(a.shape, jnp.float32)
+                        for a in (*grads, ds)),
+        # operand 0 is the group's index
+        aliases={len(operands) - len(grads) + 1 + i: i
+                 for i in range(len(grads))},
+        state_shape=(dk, r * dv), interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _gdn_rule(q, k, v, gam, beta, c, steps, group_steps, interpret):
+    return _gdn_rule_fwd(q, k, v, gam, beta, c, steps, group_steps,
+                         interpret)[0]
+
+
+def _gdn_rule_fwd(q, k, v, gam, beta, c, steps, group_steps, interpret):
+    bh, full, dk = q.shape
+    s0 = jnp.zeros((bh, 1, dk, v.shape[1] * v.shape[-1]), jnp.float32)
+    o, edges = _gdn_fwd_call(
+        jnp.zeros((1,), jnp.int32), q, k, v, gam, beta, s0, c=c,
+        steps=steps, count=full // (steps * c), group_steps=group_steps,
+        interpret=interpret)
+    return o, (q, k, v, gam, beta, edges)
+
+
+def _gdn_rule_bwd(c, steps, group_steps, interpret, res, do):
+    """A group at a time, last first, ONE loop whatever the row's
+    length: a call site costs its tracing and lowering at every job's
+    start, cached program or not (PR 37: 48 sites a step for 8 groups
+    unrolled made `setup_s` 17 s longer)."""
+    q, k, v, gam, beta, edges = res
+    groups = edges.shape[1]
+    at = {"c": c, "steps": steps, "count": group_steps,
+          "interpret": interpret}
+
+    def group(i, carry):
+        ds, grads = carry
+        g = jnp.full((1,), groups - 1 - i, jnp.int32)
+        saved = _gdn_fwd_call(g, q, k, v, gam, beta, edges, **at)
+        *grads, ds = _gdn_bwd_call(g, q, k, v, gam, beta, do, *saved, ds,
+                                   grads, **at)
+        return ds, tuple(grads)
+
+    return jax.lax.fori_loop(
+        0, groups, group,
+        (jnp.zeros_like(edges[:, :1]),
+         tuple(jnp.zeros_like(a) for a in (q, k, v, gam, beta))))[1]
+
+
+_gdn_rule.defvjp(_gdn_rule_fwd, _gdn_rule_bwd)
+
+
+def gdn_rule_tiles(r: int, c: int, dk: int, dv: int) -> bool:
+    """Whether the kernels take a rule of R value heads a key head at
+    chunk c: the packed rows fill whole 128-lane tiles, the state's
+    sides too."""
+    return (c & (c - 1) == 0 and (r * c) % 128 == 0 and r * c <= 512
+            and dk % 128 == 0 and dv % 128 == 0)
+
+
+def gdn_rule_steps(chunks: int):
+    """(chunks a grid step, grid steps a group, chunks the row is padded
+    to): whole grid steps, and whole groups once there is more than
+    one."""
+    steps = min(GDN_STEP_CHUNKS, chunks)
+    count = -(-chunks // steps)
+    group_steps = min(max(GDN_GROUP_CHUNKS // steps, 1), count)
+    return steps, group_steps, -(-count // group_steps) * group_steps * steps
+
+
+def gated_delta_rule_kernels(q, k, v, g, beta, chunk: int,
+                             interpret: bool = False):
+    """`ops.layers.gated_delta_rule` through the kernels above: q, k (B,
+    Hk, T, dk), v (B, Hk, R, T, dv), g, beta (B, Hk, R, T) -> o (B, Hk,
+    R, T, dv), differentiable in all five.  The running sum of g inside
+    each chunk is taken here, outside the kernels (one pass over (B, Hv,
+    T)); T is padded to whole grid steps and groups (`gdn_rule_steps`)
+    with tokens that neither write (beta 0) nor decay (g 0)."""
+    b, hk, t, dk = q.shape
+    r, dv = v.shape[2], v.shape[-1]
+    c = int(chunk)
+    steps, group_steps, n = gdn_rule_steps(-(-t // c))
+    full = n * c
+
+    def rows(a, axis):      # time on `axis`, padded to n chunks
+        if full == t:
+            return a
+        w = [(0, 0)] * a.ndim
+        w[axis] = (0, full - t)
+        return jnp.pad(a, w)
+
+    def packed(a):      # (B, Hk, R, T) -> (B Hk, chunks, 1, R c)
+        a = a.reshape(b * hk, r, n, c)
+        return jnp.swapaxes(a, 1, 2).reshape(b * hk, n, 1, r * c)
+
+    g, beta = rows(g, 3), rows(beta, 3)
+    gam = jnp.cumsum(g.reshape(b, hk, r, n, c), axis=-1)
+    o = _gdn_rule(rows(q, 2).reshape(b * hk, full, dk),
+                  rows(k, 2).reshape(b * hk, full, dk),
+                  rows(v, 3).reshape(b * hk, r, full, dv),
+                  packed(gam), packed(beta), c, steps, group_steps,
+                  interpret)
+    return o.reshape(b, hk, r, full, dv)[..., :t, :]

@@ -191,6 +191,52 @@ def test_flash_attention_real_shapes_parity_on_tpu(b, h, hkv, t, d, dv,
         assert err < 2e-2, (name, err)
 
 
+def test_gated_delta_rule_kernels_real_shape_on_tpu():
+    """`qwen3next.train_packed8k`'s rule (1, 16 / 32 heads, 8,192,
+    128 / 128, chunk 64): the Mosaic kernels, forward and all five
+    gradients, against the XLA form, both float32 at HIGHEST (another
+    order of the same products).  Under a watchdog, as every new
+    lowering."""
+    import faulthandler
+    import jax
+    import jax.numpy as jnp
+    from caffeonspark_tpu.ops import layers as L
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    b, hk, r, t, dk, dv, c = 1, 16, 2, 8192, 128, 128, 64
+    rng = np.random.RandomState(7)
+    q, k = (rng.randn(b, hk, t, dk) for _ in range(2))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.randn(b, hk, r, t, dv)
+    # A = uniform(1e-3, 16) in the logarithm, as the layer's filler
+    rate = np.exp(rng.uniform(np.log(1e-3), np.log(16.0), (1, hk, r, 1)))
+    g = -rate * np.log1p(np.exp(rng.randn(b, hk, r, t) + 1.0)) * 0.1
+    beta = 1.0 / (1.0 + np.exp(-rng.randn(b, hk, r, t)))
+    args = [jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta)]
+    w = jnp.asarray(rng.randn(b, hk, r, t, dv), jnp.float32)
+
+    def both(rule):
+        return (jax.jit(lambda *a: rule(*a, c))(*args),
+                jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a, c) * w),
+                                 argnums=(0, 1, 2, 3, 4)))(*args))
+
+    assert pk.gdn_rule_tiles(r, c, dk, dv)
+    faulthandler.dump_traceback_later(300, exit=True)
+    try:
+        got, got_grads = jax.device_get(both(pk.gated_delta_rule_kernels))
+        want, want_grads = jax.device_get(both(L.gated_delta_rule_xla))
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+    for name, a, x in zip(("o", "dq", "dk", "dv", "dg", "dbeta"),
+                          [got] + list(got_grads),
+                          [want] + list(want_grads)):
+        assert a.shape == x.shape and np.isfinite(a).all(), name
+        err = np.abs(a - x).max() / max(np.abs(x).max(), 1e-12)
+        print(f"gated delta rule {b}x{hk}/{hk * r}x{t}x{dk}/{dv} "
+              f"{name}: max gap / max {err:.3e}")
+        assert err < 1e-4, (name, err)
+
+
 # CaffeNet's two LRN inputs at a reduced batch: pool1 -> norm1 and
 # pool2 -> norm2 (zoo.caffenet); hw 729 and 169 both take the pad path
 _NORM_SHAPES = [(32, 96, 27, 27), (32, 256, 13, 13)]

@@ -234,6 +234,149 @@ def test_chunked_rule_is_causal_and_refuses_a_chunk_that_is_no_power_of_two():
         L.gated_delta_rule(q, k, v, g, beta, 24)
 
 
+def _float64_recurrence(args, w):
+    """The recurrence of `gated_delta_rule`'s docstring, a token at a
+    time in float64 on the program's layout -> o and the gradients of
+    sum(o w) with respect to q, k, v, g, beta."""
+    with jax.enable_x64(True):
+        q, k, v, g, beta, w = (jnp.asarray(np.asarray(a, np.float64))
+                               for a in args + (w,))
+
+        def rule(q, k, v, g, beta):
+            def token(s, x):        # s (B, Hk, R, dk, dv)
+                qt, kt, vt, gt, bt = x
+                s = s * jnp.exp(gt)[..., None, None]
+                write = bt[..., None] * (
+                    vt - jnp.einsum("bhd,bhrde->bhre", kt, s))
+                s = s + jnp.einsum("bhd,bhre->bhrde", kt, write)
+                return s, jnp.einsum("bhd,bhrde->bhre", qt, s)
+
+            s0 = jnp.zeros(v.shape[:3] + (q.shape[-1], v.shape[-1]),
+                           jnp.float64)
+            _, o = jax.lax.scan(token, s0, (
+                jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
+                jnp.moveaxis(v, 3, 0), jnp.moveaxis(g, 3, 0),
+                jnp.moveaxis(beta, 3, 0)))
+            return jnp.moveaxis(o, 0, 3)
+
+        grads = jax.grad(lambda *a: jnp.sum(rule(*a) * w),
+                         argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+        return (np.asarray(rule(q, k, v, g, beta)),
+                [np.asarray(a) for a in grads])
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The Mosaic kernels in interpret mode, two chunks a grid step and
+    four between two kept states, so that a few hundred tokens cross
+    grid steps and groups."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(pk, "GDN_STEP_CHUNKS", 2)
+    monkeypatch.setattr(pk, "GDN_GROUP_CHUNKS", 4)
+    L._GDN_PLANS.clear()
+
+
+# (T, R, chunk): 5 chunks = two groups, the second's last grid step and
+# a half padding; one chunk, not full; one value head a key head at a
+# chunk of 128 (one group); two groups and no padding
+@pytest.mark.parametrize("t,r,chunk", [(300, 2, 64), (40, 2, 64),
+                                       (200, 1, 128), (512, 2, 64)])
+@pytest.mark.parametrize("alike", [False, True])
+def test_kernel_rule_equals_the_recurrence_and_the_xla_form(
+        kernel_route, t, r, chunk, alike):
+    """The kernel route (`pallas_kernels.gated_delta_rule_kernels`, in
+    interpret mode) against the token-by-token recurrence in float64
+    and against the XLA form: values and all five gradients, at the
+    family's head sizes (128 / 128) and one key head."""
+    args = _rule_inputs(t, b=1, hk=1, r=r, dk=128, dv=128, alike=alike,
+                        seed=t)
+    w = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def both(rule):
+        return (rule(*args, chunk), jax.grad(
+            lambda *a: jnp.sum(rule(*a, chunk) * w),
+            argnums=(0, 1, 2, 3, 4))(*args))
+
+    got, got_grads = both(L.gated_delta_rule)
+    assert L.gdn_plans()[f"1x{t} 1/{r} heads 128/128"]["rule"] == "kernel"
+    xla, xla_grads = both(L.gated_delta_rule_xla)
+    want, want_grads = _float64_recurrence(args, w)
+    top = np.abs(want).max()
+    # float32 against float64: what the XLA form leaves, and no more
+    assert np.abs(got - want).max() <= max(
+        2.0 * np.abs(xla - want).max(), 2e-6 * top)
+    np.testing.assert_allclose(got, xla, rtol=2e-5, atol=2e-6 * top)
+    for name, a, b, c in zip("q k v g beta".split(), got_grads,
+                             xla_grads, want_grads):
+        assert a.shape == c.shape, name
+        scale = np.abs(c).max()
+        np.testing.assert_allclose(a, c, rtol=2e-4, atol=2e-5 * scale,
+                                   err_msg=name)
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5 * scale,
+                                   err_msg=name)
+
+
+def test_kernel_rule_is_causal(kernel_route):
+    """Changing token 150 (chunk 2 of 5, the second grid step) moves no
+    output before it, through every input."""
+    q, k, v, g, beta = _rule_inputs(300, b=1, hk=1, dk=128, dv=128, seed=4)
+    base = np.asarray(L.gated_delta_rule(q, k, v, g, beta, 64))
+    moved = (q.at[:, :, 150].multiply(1.5), k.at[:, :, 150].multiply(-1.0),
+             v.at[:, :, :, 150].add(1.0), g.at[:, :, :, 150].add(-0.7),
+             beta.at[:, :, :, 150].multiply(0.5))
+    for i, name in enumerate("q k v g beta".split()):
+        args = [q, k, v, g, beta]
+        args[i] = moved[i]
+        got = np.asarray(L.gated_delta_rule(*args, 64))
+        np.testing.assert_array_equal(got[..., :150, :], base[..., :150, :],
+                                      err_msg=name)
+        assert np.abs(got[..., 150, :] - base[..., 150, :]).max() > 0, name
+        if name != "q":
+            assert np.abs(got[..., 151:, :] - base[..., 151:, :]).max() > 0
+    assert L.gdn_plans()["1x300 1/2 heads 128/128"]["rule"] == "kernel"
+
+
+@pytest.mark.parametrize("why", ["dk", "r_chunk", "bfloat16", "disabled",
+                                 "mesh"])
+def test_rule_falls_back_to_the_xla_form(monkeypatch, why):
+    """What sends a rule to the XLA form although kernels could run: a
+    head size or R x chunk that does not fill 128-lane tiles, operands
+    that are not float32, COS_DISABLE_PALLAS on a TPU backend, a mesh
+    of several devices (a bare Mosaic call cannot be partitioned).
+    Each time the values are the XLA form's and `info.gdn` says so."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    dims = dict(b=2, hk=1, dk=128, dv=128)
+    chunk, ctx = 64, None
+    if why == "dk":
+        dims["dk"] = 64
+    elif why == "r_chunk":
+        chunk = 32
+    elif why == "disabled":
+        # no interpret mode: the backend says TPU, the switch says no
+        monkeypatch.delenv("COS_FLASH_INTERPRET")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert pk.pallas_enabled()
+        monkeypatch.setenv("COS_DISABLE_PALLAS", "1")
+    elif why == "mesh":
+        from caffeonspark_tpu.parallel.mesh import build_mesh
+        ctx = L.flash_mesh(build_mesh(dp=2, devices=jax.devices()[:2]))
+    args = _rule_inputs(70, **dims)
+    if why == "bfloat16":
+        args = tuple(a.astype(jnp.bfloat16) for a in args)
+    L._GDN_PLANS.clear()
+    if ctx is None:
+        got = L.gated_delta_rule(*args, chunk)
+    else:
+        with ctx:
+            got = L.gated_delta_rule(*args, chunk)
+    (plan,) = L.gdn_plans().values()
+    assert plan["rule"] == "xla" and plan["chunks_a_call"] == 70 // chunk + 1
+    np.testing.assert_array_equal(
+        got, L.gated_delta_rule_xla(*args, chunk))
+
+
 def test_unit_lower_inverse_inverts():
     c = 64
     m = jnp.tril(jax.random.normal(jax.random.key(1), (3, c, c)), -1) \
@@ -284,12 +427,12 @@ def test_gated_delta_net_layer_equals_the_reference_and_is_causal():
                for ti in (13, 14, 16, 20))
     # the counter says what was lowered
     plan = L.gdn_plans()[f"{b}x{t} 2/4 heads 8/8"]
-    assert plan == {"chunk": 8, "chunks_a_row": 3, "chunks_a_group": 3,
-                    "heads": 4,
+    assert plan == {"rule": "xla", "chunk": 8, "chunks_a_row": 3,
+                    "chunks_a_group": 3, "chunks_a_call": 3, "heads": 4,
                     "state_bytes": b * 4 * 8 * 8 * 4}
 
 
-def test_train_job_reports_the_lowered_scan_as_info_gdn():
+def test_train_job_reports_the_lowered_scan_as_info_gdn(monkeypatch):
     """What the first step's Gated DeltaNet operators were lowered to
     rides in the metrics the -train job prints at shutdown, as
     `info.gdn`, beside `info.flash` and through the same route."""
@@ -303,11 +446,27 @@ def test_train_job_reports_the_lowered_scan_as_info_gdn():
     L.gated_delta_rule(*_rule_inputs(300, b=1), 64)
     CaffeProcessor._note_flash_plans(Job)
     assert Job.metrics.summary()["info"]["gdn"] == {
-        "1x300 2/4 heads 8/4": {"chunk": 64, "chunks_a_row": 5,
-                                "chunks_a_group": 5, "heads": 4,
+        "1x300 2/4 heads 8/4": {"rule": "xla", "chunk": 64,
+                                "chunks_a_row": 5, "chunks_a_group": 5,
+                                "chunks_a_call": 5, "heads": 4,
                                 "state_bytes": 4 * 8 * 4 * 4}}
     L.gated_delta_rule(*_rule_inputs(64 * 40, b=1), 64)
     assert L.gdn_plans()["1x2560 2/4 heads 8/4"]["chunks_a_group"] == 32
+    # the form that was lowered is part of the line: the kernels where
+    # the shape fills the tiles (here in interpret mode), with the
+    # chunks a call walks with the states in VMEM: a row of 5 chunks in
+    # two grid steps of 4 (one group), one of 40 in 12 (three groups of
+    # 16 chunks), one of 128 in 32
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    for t, row, group, call in ((300, 5, 8, 8), (64 * 40, 40, 16, 48),
+                                (8192, 128, 16, 128)):
+        jax.eval_shape(
+            lambda *a: L.gated_delta_rule(*a, 64),
+            *_rule_inputs(t, b=1, hk=1, dk=128, dv=128))
+        assert L.gdn_plans()[f"1x{t} 1/2 heads 128/128"] == {
+            "rule": "kernel", "chunk": 64, "chunks_a_row": row,
+            "chunks_a_group": group, "chunks_a_call": call,
+            "heads": 2, "state_bytes": 2 * 128 * 128 * 4}
 
 
 # ------------------------------------------------------ the gated attention

@@ -1,5 +1,6 @@
 """The flash kernels at the real shapes of the benchmark's three language
-models, compiled for a described (not attached) TPU v5e: what interpret
+models, and the gated delta rule's kernels at qwen3next's, compiled for
+a described (not attached) TPU v5e: what interpret
 mode cannot see — VMEM, tiling, the grouped block index maps.  PR 33
 found here, before any chip time, that a 64-wide head is padded to 128
 lanes in VMEM.  About two seconds a case; nothing runs.
@@ -90,3 +91,38 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
     assert sum(p["calls"] for p in plan.values()) == calls
     assert all(max(p["block_q"], p["block_k"]) > 128
                for p in plan.values())
+
+
+def test_gated_delta_rule_kernels_compile_for_v5e(one_chip):
+    """The rule's forward and backward calls at `qwen3next.train_
+    packed8k`'s shape (1, 16 / 32 heads, 8,192, 128 / 128, chunk 64)
+    lower for the v5e: one forward call over the row, and in ONE loop
+    over the 8 groups of 16 chunks a recomputation and a sweep (three
+    call sites: what a job traces and lowers at every start), none
+    with a VMEM window of its own."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    b, hk, r, t, dk, dv, c = 1, 16, 2, 8192, 128, 128, 64
+    assert pk.gdn_rule_tiles(r, c, dk, dv)
+
+    def loss(*a):
+        return jnp.sum(pk.gated_delta_rule_kernels(*a, c))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+              for s in ((b, hk, t, dk), (b, hk, t, dk), (b, hk, r, t, dv),
+                        (b, hk, r, t), (b, hk, r, t))]
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grad).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert [o.shape for o in jax.eval_shape(grad, *shapes)] == [
+        s.shape for s in shapes]
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("cos_gdn_fwd", "cos_gdn_save", "cos_gdn_bwd"):
+        assert sum(name in line for line in lines) == 1, name
+    assert len(lines) == 3
+    assert all('"scoped_memory_configs":[]' in line for line in lines)
+    assert pk.gdn_rule_steps(t // c) == (4, 4, 128)     # 8 groups
