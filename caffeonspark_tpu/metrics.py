@@ -136,7 +136,12 @@ first step what its flash attention calls were lowered to as
 share of score tiles under the masked body;
 `ops.pallas_kernels.flash_plans`) and, for a net with GatedDeltaNet
 layers, `info.gdn` (per operator shape: the chunk, the chunks a row,
-the heads, the state's bytes; `ops.layers.gdn_plans`).  The relaxed
+the heads, the state's bytes; `ops.layers.gdn_plans`) and, for a net
+with dropless expert layers, `info.moe` (per layer shape: the layers,
+the k N assignments, the rows a pass, the passes and those an even
+router fills, the row tile, the operations a held row costs, the bytes
+of weight gradient the backward scan carries;
+`ops.layers.moe_plans`).  The relaxed
 sync modes also record a `sync_exchange` stage series (host-side
 round-average / global-merge wall time).  The continuous-deployment
 controller publishes `info.deploy` the same way (incumbent, verdict

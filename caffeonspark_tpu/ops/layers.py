@@ -2142,6 +2142,21 @@ def _moe_chunk_rows(n: int, k: int, held: int, e: int) -> int:
     return min(k * n, -(-want // _MOE_ROW_TILE) * _MOE_ROW_TILE)
 
 
+# What the dropless layers were lowered to, by layer shape: the layers
+# that took it, the k N assignments, the rows and the number of passes,
+# the passes an even router fills, the row tile, the forward operations
+# a held row costs and the bytes of expert weight gradient that the
+# backward scan carries through every pass.  Static, written while a
+# program is traced; the -train job puts it into its metrics as
+# `info.moe`.
+_MOE_PLANS: dict = {}
+
+
+def moe_plans() -> dict:
+    return {k: dict(v, layers=list(v["layers"]))
+            for k, v in _MOE_PLANS.items()}
+
+
 def _moe_dropless(ctx, lp, params, bottoms):
     """Routed experts without a capacity, for a layer that may hold
     only some of the experts.
@@ -2160,7 +2175,26 @@ def _moe_dropless(ctx, lp, params, bottoms):
     A pass that holds no held assignment is skipped (`lax.cond`); an
     even router needs one, every token on one held expert needs them
     all, and nothing is ever dropped.  Each pass is recomputed in the
-    backward pass, so the layer keeps no k·N-row activation.
+    backward pass, so the layer keeps no k·N-row activation.  A skipped
+    pass is not nothing: its conditional still hands the running sum
+    through, and in the backward scan its transpose yields a zero
+    cotangent for every operand of a pass (the tokens, the gates, every
+    expert weight), which the scan adds into the gradients it carries
+    as it adds a run pass's.  `moe_plans()` has the passes a shape takes
+    and the bytes so carried.
+
+    Scopes on the device's ops (forward, recomputation and transpose
+    carry the same token): `moe.route` holds the router's product, the
+    scoring, `top_k` and the weights, and inside it `moe.sort` the held
+    mask, the argsort, the counts and their running sums; `moe.experts`
+    holds the scan over the passes, and inside a pass that runs
+    `moe.gather` (the slice of the order, the row gather; transposed, a
+    scatter-add into dx), `moe.products` (the grouped products and the
+    activation between them) and `moe.combine` (the mask, the gate
+    product, the scatter-add; transposed, a gather).  What `moe.experts`
+    holds outside those three is the loop's and the conditionals' own:
+    carries, copies, zero fills, the sums into the carried gradients.
+    `moe.shared` holds the shared experts.
 
     What the absent experts would add is left out: the result is this
     share's part of the routed sum plus the shared experts (with
@@ -2198,15 +2232,18 @@ def _moe_dropless(ctx, lp, params, bottoms):
                 total_s = total_s + float(mp.norm_epsilon)
             topv = topv / total_s
         gates = (topv * float(mp.routed_scaling_factor)).reshape(-1)
-        # token-major flattening: assignment a belongs to token a // k
-        local = topi.reshape(-1) - first
-        on_held = (local >= 0) & (local < held)
-        group = jnp.where(on_held, local, held)              # absent last
-        order = jnp.argsort(group, stable=True)
-        counts = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        ends = jnp.cumsum(counts)
-        starts = ends - counts
-        total = ends[-1]
+        with jax.named_scope("moe.sort"):
+            # token-major flattening: assignment a belongs to token
+            # a // k
+            local = topi.reshape(-1) - first
+            on_held = (local >= 0) & (local < held)
+            group = jnp.where(on_held, local, held)          # absent last
+            order = jnp.argsort(group, stable=True)
+            counts = jnp.zeros((held + 1,),
+                               jnp.int32).at[group].add(1)[:held]
+            ends = jnp.cumsum(counts)
+            starts = ends - counts
+            total = ends[-1]
 
     rows = _moe_chunk_rows(n, k, held, e)
     n_pass = -(-(k * n) // rows)
@@ -2214,32 +2251,50 @@ def _moe_dropless(ctx, lp, params, bottoms):
     prec = ctx.precision()
     w_in = (pd["W_gate"], pd["W_up"]) if gated else (pd["W1"],)
     w_out = pd["W_down"] if gated else pd["W2"]
+    hidden, products = int(w_out.shape[1]), len(w_in) + 1
+    plan = _MOE_PLANS.setdefault(
+        f"{n}x{d} top {k} of {e}, {held} held x {hidden}"
+        f"{' gated' if gated else ''}, "
+        f"shared {int(mp.shared_hidden_dim)}",
+        {"layers": [], "assignments": k * n, "rows": rows,
+         "passes": n_pass,
+         "passes_even_router": -(-(k * n * held) // (e * rows)),
+         "row_tile": _MOE_ROW_TILE,
+         "row_flops": 2 * d * hidden * products,
+         "carry_bytes": held * products * d * hidden
+         * jnp.dtype(w_out.dtype).itemsize})
+    if lp.name not in plan["layers"]:
+        plan["layers"].append(lp.name)
 
     def one_pass(acc, lo, xf, gates, w_in, w_out):
         def run(acc):
-            idx = lax.dynamic_slice(order, (lo,), (rows,))
-            valid = (lo + jnp.arange(rows)) < total
-            idx = jnp.where(valid, idx, 0)
-            tok = idx // k
-            sizes = (jnp.clip(ends - lo, 0, rows)
-                     - jnp.clip(starts - lo, 0, rows))
-            xs = jnp.where(valid[:, None], xf[tok], 0)
-            hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
-                                 precision=prec)
-            if gated:
-                hid = jax.nn.silu(hid) * lax.ragged_dot(
-                    xs, w_in[1].astype(xs.dtype), sizes, precision=prec)
-            else:
-                hid = jax.nn.relu(hid)
-            ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
-                                precision=prec)
-            # rows past the last group hold whatever the kernel left
-            # (NaN bit patterns included): they are cut out BEFORE the
-            # product, so that neither the sum nor the gates' gradient
-            # (d/dg = ys) ever sees them
-            ys = jnp.where(valid[:, None], ys, 0) \
-                * gates[idx][:, None].astype(ys.dtype)
-            return acc.at[tok].add(ys)
+            with jax.named_scope("moe.gather"):
+                idx = lax.dynamic_slice(order, (lo,), (rows,))
+                valid = (lo + jnp.arange(rows)) < total
+                idx = jnp.where(valid, idx, 0)
+                tok = idx // k
+                sizes = (jnp.clip(ends - lo, 0, rows)
+                         - jnp.clip(starts - lo, 0, rows))
+                xs = jnp.where(valid[:, None], xf[tok], 0)
+            with jax.named_scope("moe.products"):
+                hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
+                                     precision=prec)
+                if gated:
+                    hid = jax.nn.silu(hid) * lax.ragged_dot(
+                        xs, w_in[1].astype(xs.dtype), sizes,
+                        precision=prec)
+                else:
+                    hid = jax.nn.relu(hid)
+                ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
+                                    precision=prec)
+            with jax.named_scope("moe.combine"):
+                # rows past the last group hold whatever the kernel left
+                # (NaN bit patterns included): they are cut out BEFORE
+                # the product, so that neither the sum nor the gates'
+                # gradient (d/dg = ys) ever sees them
+                ys = jnp.where(valid[:, None], ys, 0) \
+                    * gates[idx][:, None].astype(ys.dtype)
+                return acc.at[tok].add(ys)
 
         return lax.cond(lo < total, run, lambda a: a, acc)
 

@@ -493,7 +493,7 @@ class CaffeProcessor:
                     else:
                         params, st, out = fused_step(params, st, batch)
                 if nd == 0:
-                    self._note_flash_plans()
+                    self._note_lowering_plans()
                 if self.step_observer is not None:
                     self.step_observer(it, n, batch, params, st, out)
                 it += n
@@ -550,25 +550,27 @@ class CaffeProcessor:
             for q in self.queues:
                 q.stop()
 
-    def _note_flash_plans(self):
-        """The first step is lowered: what its Gated DeltaNet operators
-        came to (`info.gdn`) and what its flash attention calls
-        came to (tiles, calls an attention, share of score tiles under
-        the masked body; `pallas_kernels.flash_plans`) goes into the
-        metrics as `info.flash` and into the log, once.  Static facts,
-        nothing a step on the device."""
-        from .ops.layers import gdn_plans
+    def _note_lowering_plans(self):
+        """The first step is lowered: the plans its operators took go
+        into the metrics and into the log, once.  `info.flash`: what the
+        flash attention calls came to (tiles, calls an attention, share
+        of score tiles under the masked body;
+        `pallas_kernels.flash_plans`); `info.gdn`: per GatedDeltaNet
+        shape the form of the rule, the chunk, the chunks a row, the
+        heads and the state's bytes (`layers.gdn_plans`); `info.moe`:
+        per dropless expert-layer shape the rows a pass, the passes,
+        the row tile, the operations a held row costs and the bytes of
+        weight gradient the backward scan carries (`layers.moe_plans`).
+        Static facts, nothing a step on the device."""
+        from .ops.layers import gdn_plans, moe_plans
         from .ops.pallas_kernels import flash_plans
-        plans = flash_plans()
-        if plans:
-            self.metrics.set_info("flash", plans)
-            _LOG.info("flash attention as lowered: %s", plans)
-        # beside it `info.gdn`: per GatedDeltaNet shape lowered, the
-        # chunk, the chunks a row, the heads and the state's bytes
-        plans = gdn_plans()
-        if plans:
-            self.metrics.set_info("gdn", plans)
-            _LOG.info("gated delta rule as lowered: %s", plans)
+        for key, what, plans in (
+                ("flash", "flash attention", flash_plans()),
+                ("gdn", "gated delta rule", gdn_plans()),
+                ("moe", "expert layers", moe_plans())):
+            if plans:
+                self.metrics.set_info(key, plans)
+                _LOG.info("%s as lowered: %s", what, plans)
 
     VALIDATION_STALL_TIMEOUT = 30.0
 

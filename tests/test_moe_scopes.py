@@ -1,0 +1,350 @@
+"""The dropless expert layer's device scopes and its pass plan.
+
+`moe.sort` inside `moe.route`, and `moe.gather` / `moe.products` /
+`moe.combine` inside a pass of `moe.experts`, are `jax.named_scope`s:
+metadata on the lowered ops, read back by the benchmark from a device
+trace's name stacks (`perfbench/harness/scopes.py`).  What is held here,
+on the CPU: the tokens reach the optimised HLO's `op_name`s through
+`lax.scan`, `jax.checkpoint`, `lax.cond`, the block's recomputation and
+the transpose; the work each names sits under it and under no other;
+the values are the parent's to the last bit; and `moe_plans()` is the
+three language cells' plan, published as `info.moe`."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from caffeonspark_tpu.models import zoo
+from caffeonspark_tpu.net import Net
+from caffeonspark_tpu.ops import layers as L
+from caffeonspark_tpu.proto import LayerParameter
+
+INNER = ("moe.gather", "moe.products", "moe.combine")
+# 48 tokens x top 2 of 8 experts, 2 held, row tile 8: passes of 32 rows,
+# 3 of them, of which an even router fills one
+N, D, H, E, K, HELD = 48, 32, 12, 8, 2, 2
+
+
+@pytest.fixture
+def tile8(monkeypatch):
+    monkeypatch.setattr(L, "_MOE_ROW_TILE", 8)
+    monkeypatch.setattr(L, "_MOE_PLANS", {})
+
+
+def layer_param(scoring="sigmoid", gated=True, shared=2 * H, held=HELD,
+                e=E, k=K, hidden=H):
+    return LayerParameter.from_text(f'''
+      name: "L1.moe" type: "MixtureOfExperts" bottom: "x" top: "y"
+      top: "stats"
+      moe_param {{ num_experts: {e} hidden_dim: {hidden} top_k: {k}
+        dispatch: "dropless" scoring: "{scoring}"
+        routed_scaling_factor: 2.448 gated: {str(gated).lower()}
+        shared_hidden_dim: {shared} experts_held: {held} }}''')
+
+
+def blobs(lp, d=D, seed=7):
+    keys = jax.random.split(jax.random.key(seed), 16)
+    return [0.3 * jax.random.normal(k, shape, jnp.float32)
+            for k, (_, shape, _) in zip(keys, L._moe_params(lp, [(N, d)]))]
+
+
+def apply(lp, params, x):
+    return L.get_op("MixtureOfExperts").apply(L.Ctx(train=True), lp,
+                                              params, [x])
+
+
+# --------------------------------------------------------------- scopes
+
+@pytest.fixture
+def stacks(tile8):
+    """`op_name`s of the optimised HLO of a gradient through one block
+    of the small kanana2 net (its expert layer under `recompute_block`),
+    with the stats of the same step: the assignments held."""
+    small = dict(vocab=64, hidden=D, heads=2, qk_nope=8, qk_rope=4,
+                 v_head=6, kv_lora_rank=16, dense_width=48,
+                 expert_width=H, experts=E, top_k=K, shared_experts=2,
+                 expert_layers=1, seq=N // 2, batch=2, experts_held=HELD)
+    net = Net(zoo.kanana2(**small, recompute=True))
+    assert net.recompute_blocks
+    params = net.init(jax.random.key(0))
+    rng = np.random.default_rng(0)
+    ins = {k: jnp.asarray(rng.integers(0, 64, (N // 2, 2)), jnp.float32)
+           for k in ("input_ids", "target_ids")}
+
+    def loss(p):
+        value, (tops, _) = net.loss(p, ins, train=True,
+                                    rng=jax.random.key(1))
+        return value, tops["L1.moe_stats"]
+
+    fn = jax.jit(jax.grad(loss, has_aux=True))
+    _, stats = fn(params)
+    text = fn.lower(params).compile().as_text()
+    names = sorted(set(re.findall(r'op_name="([^"]*)"', text)))
+    return [n for n in names if "moe." in n], stats
+
+
+def phase(name):
+    if "rematted_computation" in name:
+        return "recomputation"
+    return "backward" if "transpose(" in name else "forward"
+
+
+def under(names, scope, ph):
+    return [n for n in names if f"/{scope}/" in n and phase(n) == ph]
+
+
+def test_a_pass_is_skipped_in_the_traced_step(stacks):
+    _, stats = stacks
+    held = float(stats[1]) * K * N
+    assert 0 < held <= 64       # of 3 passes of 32 rows at least one idles
+    assert float(stats[2]) == 0.0
+    plan, = L.moe_plans().values()
+    assert (plan["rows"], plan["passes"], plan["passes_even_router"]) \
+        == (32, 3, 1)
+
+
+def test_sort_lies_inside_route_in_forward_and_recomputation(stacks):
+    names, _ = stacks
+    for ph in ("forward", "recomputation"):
+        got = under(names, "moe.sort", ph)
+        assert got, ph
+        assert all("/moe.route/moe.sort/" in n for n in got)
+        # the argsort and the counts' scatter-add are the scope's, the
+        # router's product and top_k are not
+        assert any(n.endswith("jit(argsort)/sort") for n in got)
+        assert any(n.endswith("moe.sort/scatter-add") for n in got)
+        route = under(names, "moe.route", ph)
+        for prim in ("dot_general", "top_k"):
+            at = [n for n in route if n.endswith("/" + prim)]
+            assert at and not any("moe.sort" in n for n in at), prim
+    # integers carry no gradient: the sort has no transpose
+    assert not under(names, "moe.sort", "backward")
+
+
+@pytest.mark.parametrize("scope", INNER)
+def test_inner_scope_in_forward_recomputation_and_transpose(stacks, scope):
+    names, _ = stacks
+    for ph in ("forward", "recomputation", "backward"):
+        # (a few stacks are cut short, `checkpoint/cond/branch_1_fun/
+        # moe.gather/add`: ops the compiler moved out of their function)
+        got = [n for n in under(names, scope, ph) if n.startswith("jit(")]
+        assert got, (scope, ph)
+        for n in got:
+            # inside the pass that runs, inside the scan of moe.experts,
+            # and under no second inner scope
+            assert re.search(r"/moe\.experts/.*while/body/.*cond/"
+                             r"branch_1_fun/" + re.escape(scope) + "/",
+                             n), n
+            assert sum(f"/{s}/" in n for s in INNER) == 1, n
+            assert "L1.moe" in n
+
+
+def test_each_kind_of_work_sits_under_its_own_scope(stacks):
+    names, _ = stacks
+    experts = [n for n in names if "/moe.experts/" in n]
+
+    def scopes_of(prim, ph):
+        return {next((s for s in INNER if f"/{s}/" in n), None)
+                for n in experts
+                if n.endswith("/" + prim) and phase(n) == ph}
+
+    for ph in ("forward", "recomputation"):
+        # the slice of the sorted order and the row gather; the gates'
+        # gather is the combine's
+        assert scopes_of("dynamic_slice", ph) >= {"moe.gather"}
+        assert scopes_of("gather", ph) == {"moe.gather", "moe.combine"}
+        # the grouped products (on the CPU, their expansion)
+        assert scopes_of("dot_general", ph) == {"moe.products"}
+    assert scopes_of("scatter-add", "forward") == {"moe.combine"}
+    # transposed: the row gather becomes a scatter-add into dx (and the
+    # gates' into dgates), the scatter-add a gather of d(acc)'s rows,
+    # the products stay products
+    assert scopes_of("scatter-add", "backward") == {"moe.gather",
+                                                    "moe.combine"}
+    assert scopes_of("gather", "backward") == {"moe.combine"}
+    assert scopes_of("dot_general", "backward") == {"moe.products"}
+    # the loop and its conditionals carry `moe.experts` and no inner
+    # scope: what the unscoped metric reads
+    own = [n for n in experts if not any(f"/{s}/" in n for s in INNER)]
+    assert any(n.endswith("/while") for n in own)
+    assert any(n.endswith("/cond") for n in own)
+
+
+# --------------------------------------------------------------- values
+
+# the parent tree's values (commit ed92fd8, this machine's CPU backend,
+# float32 at the default precision), as `float.hex`: a named scope is
+# metadata and may change no bit of them
+PARENT = {
+    "y": "0x1.e5212c0000000p+7", "dx": "-0x1.dc05ee0000000p+6",
+    "dW_gate": "-0x1.4baea00000000p+8", "dW_down": "0x1.b7b4360000000p+6",
+    "drouter": "-0x1.f3e7400000000p+2", "y_abs": "0x1.067fb00000000p+12",
+    "dx_abs": "0x1.f1c5f80000000p+11",
+}
+
+
+def values():
+    lp = layer_param()
+    params = blobs(lp)
+    x = jax.random.normal(jax.random.key(11), (N, D), jnp.float32)
+
+    def f(x, params):
+        y = jax.checkpoint(lambda a, p: apply(lp, p, a)[0])(x, params)
+        return jnp.sum(jnp.sin(y)), y
+
+    (_, y), (dx, dp) = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1), has_aux=True))(x, params)
+    names = [n for n, _, _ in L._moe_params(lp, [(N, D)])]
+    dp = dict(zip(names, dp))
+    out = {"y": y.sum(), "dx": dx.sum(), "dW_gate": dp["W_gate"].sum(),
+           "dW_down": dp["W_down"].sum(), "drouter": dp["router"].sum(),
+           "y_abs": jnp.abs(y).sum(), "dx_abs": jnp.abs(dx).sum()}
+    return {k: float(v).hex() for k, v in out.items()}
+
+
+def test_outputs_and_gradients_are_the_parents_to_the_last_bit(tile8):
+    assert values() == PARENT
+
+
+# ----------------------------------------------------------------- plan
+
+CELLS = [
+    # (zoo net, N, the plan's key, layers, rows, passes, carry bytes)
+    ("kanana2", 8192, "8192x2048 top 6 of 128, 16 held x 768 gated, "
+     "shared 1536", 5, 8192, 6, 16 * 3 * 2048 * 768 * 4),
+    ("lfm2", 8192, "8192x2048 top 4 of 64, 8 held x 1536 gated, shared 0",
+     6, 5632, 6, 8 * 3 * 2048 * 1536 * 4),
+    ("qwen3_next", 8192, "8192x2048 top 10 of 512, 32 held x 512 gated, "
+     "shared 512", 4, 7168, 12, 32 * 3 * 2048 * 512 * 4),
+]
+
+
+@pytest.mark.parametrize("name,n,key,layers,rows,passes,carry", CELLS,
+                         ids=[c[0] for c in CELLS])
+def test_moe_plans_of_the_language_model_cells(monkeypatch, name, n, key,
+                                               layers, rows, passes,
+                                               carry):
+    """Every expert layer of the cell's net as `zoo` writes it, traced
+    at the cell's tokens a step (shapes only: nothing runs)."""
+    monkeypatch.setattr(L, "_MOE_PLANS", {})
+    npm = getattr(zoo, name)()
+    moe = [lp for lp in npm.layer if lp.type == "MixtureOfExperts"]
+    assert len(moe) == layers
+    for lp in moe:
+        mp = lp.moe_param
+        shapes = [jax.ShapeDtypeStruct(s, jnp.float32)
+                  for _, s, _ in L._moe_params(lp, [(n, 2048)])]
+        jax.eval_shape(lambda p, x, lp=lp: apply(lp, p, x), shapes,
+                       jax.ShapeDtypeStruct((n, 2048), jnp.float32))
+    k, e = int(mp.top_k), int(mp.num_experts)
+    held, hidden = int(mp.experts_held), int(mp.hidden_dim)
+    assert L.moe_plans() == {key: {
+        "layers": [lp.name for lp in moe], "assignments": k * n,
+        "rows": rows, "passes": passes,
+        "passes_even_router": 1, "row_tile": 512,
+        "row_flops": 2 * 2048 * hidden * 3, "carry_bytes": carry}}
+    assert passes == -(-k * n // rows)
+    assert rows >= k * n * held / e           # an even router fits one
+
+
+def test_plan_of_a_plain_layer_and_a_copy_that_cannot_be_edited(tile8):
+    """ReLU experts (two products), no shared expert, every expert held:
+    all k N rows are one pass; `moe_plans()` hands out copies."""
+    lp = layer_param(gated=False, shared=0, held=0)
+    x = jnp.ones((N, D), jnp.float32)
+    apply(lp, blobs(lp), x)
+    apply(lp, blobs(lp), x)                     # the same layer again
+    key = f"{N}x{D} top {K} of {E}, {E} held x {H}, shared 0"
+    assert L.moe_plans() == {key: {
+        "layers": ["L1.moe"], "assignments": K * N, "rows": K * N,
+        "passes": 1, "passes_even_router": 1, "row_tile": 8,
+        "row_flops": 2 * D * H * 2, "carry_bytes": E * 2 * D * H * 4}}
+    L.moe_plans()[key]["layers"].append("x")
+    assert L.moe_plans()[key]["layers"] == ["L1.moe"]
+
+
+def test_capacity_dispatch_writes_no_plan(tile8):
+    lp = LayerParameter.from_text(f'''
+      name: "moe" type: "MixtureOfExperts" bottom: "x" top: "y"
+      moe_param {{ num_experts: {E} hidden_dim: {H} top_k: {K} }}''')
+    keys = jax.random.split(jax.random.key(0), 3)
+    params = [jax.random.normal(k, s) for k, (_, s, _) in
+              zip(keys, L._moe_params(lp, [(N, D)]))]
+    apply(lp, params, jnp.ones((N, D), jnp.float32))
+    assert L.moe_plans() == {}
+
+
+# ------------------------------------------------------------- info.moe
+
+EXPERTS = '''
+layer { name: "experts" type: "MixtureOfExperts" bottom: "ip1" top: "ip1"
+  moe_param { num_experts: 4 hidden_dim: 8 top_k: 2 dispatch: "dropless"
+    scoring: "softmax" gated: true experts_held: 2 } }'''
+
+
+@pytest.mark.parametrize("experts", [True, False],
+                         ids=["expert_layer", "no_expert_layer"])
+def test_train_job_reports_the_pass_plan_as_info_moe(tmp_path, monkeypatch,
+                                                     caplog, experts):
+    """A tiny -train job: after its first step the summary that
+    `/metrics`, `metrics.json` and the shutdown line print holds
+    `info.moe` if the net has a dropless expert layer, and no such key
+    if it has none."""
+    import logging
+
+    from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
+    from caffeonspark_tpu.config import Config
+    from caffeonspark_tpu.data import LmdbWriter, get_source
+    from caffeonspark_tpu.data.synthetic import make_images
+    from caffeonspark_tpu.processor import CaffeProcessor
+    from caffeonspark_tpu.proto.caffe import Datum
+
+    monkeypatch.setattr(L, "_MOE_PLANS", {})
+    monkeypatch.setenv("COS_TRANSFORM_THREADS", "0")
+    imgs, labels = make_images(32, seed=6)
+    LmdbWriter(str(tmp_path / "lmdb")).write([
+        (b"%06d" % i,
+         Datum(channels=1, height=28, width=28,
+               data=(imgs[i, 0] * 255).astype(np.uint8).tobytes(),
+               label=int(labels[i])).to_binary()) for i in range(32)])
+    net = tmp_path / "net.prototxt"
+    net.write_text(f'''
+layer {{ name: "data" type: "MemoryData" top: "data" top: "label"
+  source_class: "LMDB"
+  memory_data_param {{ source: "{tmp_path}/lmdb" batch_size: 16
+    channels: 1 height: 28 width: 28 }} }}
+layer {{ name: "ip1" type: "InnerProduct" bottom: "data" top: "ip1"
+  inner_product_param {{ num_output: 32
+    weight_filler {{ type: "xavier" }} }} }}''' + (EXPERTS if experts else "")
+                   + '''
+layer { name: "ip2" type: "InnerProduct" bottom: "ip1" top: "ip2"
+  inner_product_param { num_output: 10
+    weight_filler { type: "xavier" } } }
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip2"
+  bottom: "label" top: "loss" }''')
+    solver = tmp_path / "solver.prototxt"
+    solver.write_text(f'net: "{net}"\nbase_lr: 0.01\n'
+                      'lr_policy: "fixed"\nmax_iter: 2\n'
+                      'snapshot_prefix: "x"\nrandom_seed: 2\n'
+                      'snapshot_after_train: false\n')
+    conf = Config(["-conf", str(solver), "-train", "-output",
+                   str(tmp_path)])
+    with caplog.at_level(logging.INFO, "caffeonspark_tpu"):
+        CaffeOnSpark().train(
+            get_source(conf.train_data_layer(), phase_train=True), conf)
+    proc = CaffeProcessor.instance()
+    info = proc.metrics.summary().get("info", {})
+    proc.stop()
+    said = [r for r in caplog.records
+            if "expert layers as lowered" in r.getMessage()]
+    if not experts:
+        assert "moe" not in info and not said
+        return
+    assert info["moe"] == {"16x32 top 2 of 4, 2 held x 8 gated, shared 0": {
+        "layers": ["experts"], "assignments": 32, "rows": 32, "passes": 1,
+        "passes_even_router": 1, "row_tile": 512,
+        "row_flops": 2 * 32 * 8 * 3, "carry_bytes": 2 * 3 * 32 * 8 * 4}}
+    assert len(said) == 1

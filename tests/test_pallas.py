@@ -495,13 +495,14 @@ def test_train_job_reports_flash_plans():
 
     from caffeonspark_tpu.ops import layers as L
     pk._FLASH_PLANS.clear()
-    L._GDN_PLANS.clear()        # `info.gdn` rides the same route
-    CaffeProcessor._note_flash_plans(Job)       # no attention: nothing
+    L._GDN_PLANS.clear()        # `info.gdn` and `info.moe` ride the
+    L._MOE_PLANS.clear()        # same route
+    CaffeProcessor._note_lowering_plans(Job)       # no attention: nothing
     assert "info" not in Job.metrics.summary()
     q, k, v = _qkv(8, 1, 4, 2, 256, 64, 64)
     pk.flash_attention(q, k, v, True, interpret=True,
                        mxu_dtype=jnp.bfloat16)
-    CaffeProcessor._note_flash_plans(Job)
+    CaffeProcessor._note_lowering_plans(Job)
     assert Job.metrics.summary()["info"]["flash"] == {
         "4x256x64/64 bfloat16 g2 causal": {"fwd": {
             "block_q": 256, "block_k": 256, "calls": 1,

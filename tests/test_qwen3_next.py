@@ -444,7 +444,7 @@ def test_train_job_reports_the_lowered_scan_as_info_gdn(monkeypatch):
 
     L._GDN_PLANS.clear()
     L.gated_delta_rule(*_rule_inputs(300, b=1), 64)
-    CaffeProcessor._note_flash_plans(Job)
+    CaffeProcessor._note_lowering_plans(Job)
     assert Job.metrics.summary()["info"]["gdn"] == {
         "1x300 2/4 heads 8/4": {"rule": "xla", "chunk": 64,
                                 "chunks_a_row": 5, "chunks_a_group": 5,
