@@ -140,8 +140,12 @@ the heads, the state's bytes; `ops.layers.gdn_plans`) and, for a net
 with dropless expert layers, `info.moe` (per layer shape: the layers,
 the k N assignments, the rows a pass, the passes and those an even
 router fills, the row tile, the operations a held row costs, the bytes
-of weight gradient the backward scan carries;
-`ops.layers.moe_plans`).  The relaxed
+of weight gradient the backward loop carries, added into once a pass
+that runs; `ops.layers.moe_plans`).  Where those layers return their
+stats, the summary's `experts` says what they did over the last steps
+(`moe.passes_run`: `experts.passes_run`, a layer's mean and max of the
+passes that ran, beside `held_share`; read from the steps' outputs when
+the summary is read, `processor.ExpertWindow`).  The relaxed
 sync modes also record a `sync_exchange` stage series (host-side
 round-average / global-merge wall time).  The continuous-deployment
 controller publishes `info.deploy` the same way (incumbent, verdict
@@ -176,7 +180,7 @@ import contextlib
 import json
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 _DEFAULT_CAPACITY = 8192
 
@@ -335,6 +339,7 @@ class PipelineMetrics:
         self._gauges: Dict[str, _Gauge] = {}
         self._steps: List[float] = []
         self._info: Dict[str, object] = {}
+        self._sections: Dict[str, Callable[[], object]] = {}
         self._cap = capacity
         self._step_i = 0
         self._created = time.monotonic()
@@ -377,6 +382,14 @@ class PipelineMetrics:
         e.g. the gradient-exchange plan under "comm"."""
         with self._lock:
             self._info[name] = value
+
+    def set_section(self, name: str, read: Callable[[], object]) -> None:
+        """A top-level entry of the summary that is computed when the
+        summary is read (`read()`, JSON-serializable, None = left out)
+        — e.g. "experts", from device values nobody waits for while the
+        job steps."""
+        with self._lock:
+            self._sections[name] = read
 
     def mark_step(self, n: int = 1):
         """Timestamp `n` completed solver steps (throughput series).
@@ -444,6 +457,7 @@ class PipelineMetrics:
             gauges = {k: v.summary() for k, v in self._gauges.items()}
             nsteps = len(self._steps)
             info = dict(self._info)
+            sections = dict(self._sections)
         out = {
             "stages": stages,
             "counters": counters,
@@ -453,6 +467,10 @@ class PipelineMetrics:
         }
         if info:
             out["info"] = info
+        for name, read in sections.items():
+            value = read()
+            if value is not None:
+                out[name] = value
         sps = self.steady_steps_per_sec()
         if sps is not None:
             out["steady_steps_per_sec"] = round(sps, 3)

@@ -2146,15 +2146,100 @@ def _moe_chunk_rows(n: int, k: int, held: int, e: int) -> int:
 # that took it, the k N assignments, the rows and the number of passes,
 # the passes an even router fills, the row tile, the forward operations
 # a held row costs and the bytes of expert weight gradient that the
-# backward scan carries through every pass.  Static, written while a
-# program is traced; the -train job puts it into its metrics as
-# `info.moe`.
+# backward loop carries, added into once a pass that runs.  Static,
+# written while a program is traced; the -train job puts it into its
+# metrics as `info.moe`.
 _MOE_PLANS: dict = {}
 
 
 def moe_plans() -> dict:
     return {k: dict(v, layers=list(v["layers"]))
             for k, v in _MOE_PLANS.items()}
+
+
+def _moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts, ends, total,
+              rows, k, gated, prec):
+    """`acc` plus what the sorted rows [lo, lo + rows) add to it: linear
+    in `acc`, so a pass's gradients need no running sum."""
+    with jax.named_scope("moe.gather"):
+        idx = lax.dynamic_slice(order, (lo,), (rows,))
+        valid = (lo + jnp.arange(rows)) < total
+        idx = jnp.where(valid, idx, 0)
+        tok = idx // k
+        sizes = (jnp.clip(ends - lo, 0, rows)
+                 - jnp.clip(starts - lo, 0, rows))
+        xs = jnp.where(valid[:, None], xf[tok], 0)
+    with jax.named_scope("moe.products"):
+        hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
+                             precision=prec)
+        if gated:
+            hid = jax.nn.silu(hid) * lax.ragged_dot(
+                xs, w_in[1].astype(xs.dtype), sizes, precision=prec)
+        else:
+            hid = jax.nn.relu(hid)
+        ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
+                            precision=prec)
+    with jax.named_scope("moe.combine"):
+        # rows past the last group hold whatever the kernel left (NaN
+        # bit patterns included): they are cut out BEFORE the product,
+        # so that neither the sum nor the gates' gradient (d/dg = ys)
+        # ever sees them
+        ys = jnp.where(valid[:, None], ys, 0) \
+            * gates[idx][:, None].astype(ys.dtype)
+        return acc.at[tok].add(ys)
+
+
+def _moe_passes_run(total, rows, n_pass):
+    """Held rows sort first: the passes that hold one are the first
+    ceil(total / rows)."""
+    return jnp.minimum(n_pass, (total + rows - 1) // rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
+def _moe_passes(xf, gates, w_in, w_out, order, starts, ends, total, rows,
+                n_pass, k, gated, prec):
+    """The routed sum over the passes that run, (N, D).  The trip count
+    is the step's own, forward and backward; the integer operands are
+    arguments (a tracer closed over would leak under the block's
+    `jax.checkpoint`) and take no cotangent."""
+    return _moe_passes_fwd(xf, gates, w_in, w_out, order, starts, ends,
+                           total, rows, n_pass, k, gated, prec)[0]
+
+
+def _moe_passes_fwd(xf, gates, w_in, w_out, order, starts, ends, total,
+                    rows, n_pass, k, gated, prec):
+    res = (xf, gates, w_in, w_out, order, starts, ends, total)
+    routed = lax.fori_loop(
+        0, _moe_passes_run(total, rows, n_pass),
+        lambda i, acc: _moe_pass(acc, i * rows, *res, rows, k, gated, prec),
+        jnp.zeros_like(xf))
+    return routed, res
+
+
+def _moe_passes_bwd(rows, n_pass, k, gated, prec, res, g):
+    """The passes that ran, last to first (the order in which a scan's
+    transpose sums, so the gradients round as its would): a pass is
+    computed again, pulled back against `g` (every pass's output
+    cotangent, the pass being linear in the running sum) and added into
+    one accumulator a differentiable operand."""
+    *diff, order, starts, ends, total = res
+    n_run = _moe_passes_run(total, rows, n_pass)
+
+    def body(i, sums):
+        lo = (n_run - 1 - i) * rows
+        # any running sum will do (here `g`, for its shape): the pass's
+        # value is not used, and the gradients do not depend on it
+        _, pull = jax.vjp(
+            lambda *a: _moe_pass(g, lo, *a, order, starts, ends, total,
+                                 rows, k, gated, prec), *diff)
+        return jax.tree.map(jnp.add, sums, pull(g))
+
+    sums = lax.fori_loop(0, n_run, body,
+                         jax.tree.map(jnp.zeros_like, tuple(diff)))
+    return (*sums, None, None, None, None)
+
+
+_moe_passes.defvjp(_moe_passes_fwd, _moe_passes_bwd)
 
 
 def _moe_dropless(ctx, lp, params, bottoms):
@@ -2172,29 +2257,35 @@ def _moe_dropless(ctx, lp, params, bottoms):
     of `_moe_chunk_rows` rows: gather the tokens, three grouped
     products (`lax.ragged_dot`: on the TPU a Mosaic grouped matmul
     that visits only the tiles its groups cover), weight, scatter-add.
-    A pass that holds no held assignment is skipped (`lax.cond`); an
-    even router needs one, every token on one held expert needs them
-    all, and nothing is ever dropped.  Each pass is recomputed in the
-    backward pass, so the layer keeps no k·N-row activation.  A skipped
-    pass is not nothing: its conditional still hands the running sum
-    through, and in the backward scan its transpose yields a zero
-    cotangent for every operand of a pass (the tokens, the gates, every
-    expert weight), which the scan adds into the gradients it carries
-    as it adds a run pass's.  `moe_plans()` has the passes a shape takes
-    and the bytes so carried.
+    Only the passes that hold a held assignment run: held rows sort
+    first, so they are the first ceil(held / rows) (`_moe_passes_run`), a
+    number known on the device before the loop starts.  An even router
+    needs one, every token on one held expert needs them all, and
+    nothing is ever dropped.  The loop has its own backward
+    (`_moe_passes`, a `jax.custom_vjp`): a second loop of the same trip
+    count that computes each pass again, last first, pulls it back
+    against the output's cotangent and adds the pass's gradients (tokens,
+    gates, every expert weight) into one accumulator each.  So the layer
+    keeps no k·N-row activation and, a pass being linear in the running
+    sum, no running sum either: the backward keeps the loop's inputs and
+    nothing else, and a block's `recompute_block` has no forward loop to
+    run again.  A pass that does not run costs nothing, forward or
+    backward; a step whose router fills one pass fills each accumulator
+    with zeros once and adds into it once.  `moe_plans()` has the passes
+    a shape takes and the accumulators' bytes.
 
     Scopes on the device's ops (forward, recomputation and transpose
     carry the same token): `moe.route` holds the router's product, the
     scoring, `top_k` and the weights, and inside it `moe.sort` the held
     mask, the argsort, the counts and their running sums; `moe.experts`
-    holds the scan over the passes, and inside a pass that runs
-    `moe.gather` (the slice of the order, the row gather; transposed, a
-    scatter-add into dx), `moe.products` (the grouped products and the
-    activation between them) and `moe.combine` (the mask, the gate
-    product, the scatter-add; transposed, a gather).  What `moe.experts`
-    holds outside those three is the loop's and the conditionals' own:
-    carries, copies, zero fills, the sums into the carried gradients.
-    `moe.shared` holds the shared experts.
+    holds both loops over the passes, and inside a pass `moe.gather`
+    (the slice of the order, the row gather; transposed, a scatter-add
+    into dx), `moe.products` (the grouped products and the activation
+    between them) and `moe.combine` (the mask, the gate product, the
+    scatter-add; transposed, a gather).  What `moe.experts` holds
+    outside those three is the loops' own: the trip count, the carries,
+    the accumulators' zero fill and the sums into them.  `moe.shared`
+    holds the shared experts.
 
     What the absent experts would add is left out: the result is this
     share's part of the routed sum plus the shared experts (with
@@ -2266,48 +2357,10 @@ def _moe_dropless(ctx, lp, params, bottoms):
     if lp.name not in plan["layers"]:
         plan["layers"].append(lp.name)
 
-    def one_pass(acc, lo, xf, gates, w_in, w_out):
-        def run(acc):
-            with jax.named_scope("moe.gather"):
-                idx = lax.dynamic_slice(order, (lo,), (rows,))
-                valid = (lo + jnp.arange(rows)) < total
-                idx = jnp.where(valid, idx, 0)
-                tok = idx // k
-                sizes = (jnp.clip(ends - lo, 0, rows)
-                         - jnp.clip(starts - lo, 0, rows))
-                xs = jnp.where(valid[:, None], xf[tok], 0)
-            with jax.named_scope("moe.products"):
-                hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
-                                     precision=prec)
-                if gated:
-                    hid = jax.nn.silu(hid) * lax.ragged_dot(
-                        xs, w_in[1].astype(xs.dtype), sizes,
-                        precision=prec)
-                else:
-                    hid = jax.nn.relu(hid)
-                ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
-                                    precision=prec)
-            with jax.named_scope("moe.combine"):
-                # rows past the last group hold whatever the kernel left
-                # (NaN bit patterns included): they are cut out BEFORE
-                # the product, so that neither the sum nor the gates'
-                # gradient (d/dg = ys) ever sees them
-                ys = jnp.where(valid[:, None], ys, 0) \
-                    * gates[idx][:, None].astype(ys.dtype)
-                return acc.at[tok].add(ys)
-
-        return lax.cond(lo < total, run, lambda a: a, acc)
-
     with jax.named_scope("moe.experts"):
-        # one scanned body: the backward pass sums the experts' weight
-        # gradients in the scan's carry (one buffer a blob, not one a
-        # pass) and keeps of each pass only the running sum it started
-        # from
-        step = jax.checkpoint(one_pass)
-        gates = gates.astype(xf.dtype)
-        routed, _ = lax.scan(
-            lambda acc, lo: (step(acc, lo, xf, gates, w_in, w_out), None),
-            jnp.zeros_like(xf), jnp.arange(n_pass, dtype=jnp.int32) * rows)
+        routed = _moe_passes(xf, gates.astype(xf.dtype), w_in, w_out, order,
+                             starts, ends, total, rows, n_pass, k, gated,
+                             prec)
 
     out = routed
     if "S_gate" in pd:

@@ -16,6 +16,7 @@ barrier.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
 import logging
@@ -67,6 +68,51 @@ class ValidationReport:
             self.rounds.append({n: self._acc[n] / self._n
                                 for n in self.names})
         self._acc, self._n = {}, 0
+
+
+class ExpertWindow:
+    """What the dropless expert layers did over the last `STEPS` steps,
+    from the `moe_stats` the step returns anyway: nothing is fetched
+    while the job steps, the record is computed when the metrics are
+    read (`PipelineMetrics.set_section`).  `passes_run` is how often
+    the layer's loop over the passes ran, forward and backward: held
+    assignments over the rows a pass takes, rounded up
+    (`layers._moe_passes_run`)."""
+
+    STEPS = 64
+
+    def __init__(self, layers: Dict[str, Tuple[str, dict]]):
+        # stats top -> (layer, its entry of `moe_plans()`)
+        self.layers = layers
+        self._steps: collections.deque = collections.deque(
+            maxlen=self.STEPS)
+        self._lock = threading.Lock()
+
+    def add(self, out: Dict[str, Any]):
+        with self._lock:
+            self._steps.append([out[top] for top in self.layers])
+
+    def record(self) -> Optional[dict]:
+        import jax
+        with self._lock:
+            steps = list(self._steps)
+        if not steps:
+            return None
+        # a fused chunk's stats are (K, 3), a step's (3,)
+        per_layer = [np.concatenate([np.reshape(v, (-1, 3)) for v in vs],
+                                    dtype=np.float64)
+                     for vs in zip(*jax.device_get(steps))]
+        passes_run = {}
+        for (layer, plan), vals in zip(self.layers.values(), per_layer):
+            held = np.rint(vals[:, 1] * plan["assignments"])
+            ran = np.minimum(plan["passes"],
+                             np.ceil(held / plan["rows"]))
+            passes_run[layer] = {"mean": float(ran.mean()),
+                                 "max": int(ran.max())}
+        return {"steps": len(per_layer[0]),
+                "held_share": float(np.mean([v[:, 1].mean()
+                                             for v in per_layer])),
+                "passes_run": passes_run}
 
 
 class CaffeProcessor:
@@ -477,6 +523,7 @@ class CaffeProcessor:
                 metrics=self.metrics)
             params, st = self.params, self.opt_state
             m = self.metrics
+            experts = None      # the expert layers' window, if any
             for nd in itertools.count():        # dispatches of this run
                 inj.step_delay()
                 inj.maybe_die(it)
@@ -494,6 +541,9 @@ class CaffeProcessor:
                         params, st, out = fused_step(params, st, batch)
                 if nd == 0:
                     self._note_lowering_plans()
+                    experts = self._expert_window(out)
+                if experts is not None:
+                    experts.add(out)
                 if self.step_observer is not None:
                     self.step_observer(it, n, batch, params, st, out)
                 it += n
@@ -560,7 +610,8 @@ class CaffeProcessor:
         heads and the state's bytes (`layers.gdn_plans`); `info.moe`:
         per dropless expert-layer shape the rows a pass, the passes,
         the row tile, the operations a held row costs and the bytes of
-        weight gradient the backward scan carries (`layers.moe_plans`).
+        weight gradient the backward loop carries, added into once a
+        pass that runs (`layers.moe_plans`).
         Static facts, nothing a step on the device."""
         from .ops.layers import gdn_plans, moe_plans
         from .ops.pallas_kernels import flash_plans
@@ -571,6 +622,23 @@ class CaffeProcessor:
             if plans:
                 self.metrics.set_info(key, plans)
                 _LOG.info("%s as lowered: %s", what, plans)
+
+    def _expert_window(self, out) -> Optional[ExpertWindow]:
+        """The window over the dropless expert layers whose stats the
+        step returns, as the summary's `experts`; None without one."""
+        from .ops.layers import moe_plans
+        plan_of = {layer: plan for plan in moe_plans().values()
+                   for layer in plan["layers"]}
+        layers = {lp.top[1]: (lp.name, plan_of[lp.name])
+                  for lp in self.solver.train_net.layers
+                  if lp.name in plan_of and len(lp.top) > 1
+                  and getattr(out.get(lp.top[1]), "is_fully_addressable",
+                              False)}
+        if not layers:
+            return None
+        window = ExpertWindow(layers)
+        self.metrics.set_section("experts", window.record)
+        return window
 
     VALIDATION_STALL_TIMEOUT = 30.0
 

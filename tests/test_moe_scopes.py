@@ -1,14 +1,19 @@
-"""The dropless expert layer's device scopes and its pass plan.
+"""The dropless expert layer's device scopes, its loops over the passes
+that run, and its pass plan.
 
 `moe.sort` inside `moe.route`, and `moe.gather` / `moe.products` /
 `moe.combine` inside a pass of `moe.experts`, are `jax.named_scope`s:
 metadata on the lowered ops, read back by the benchmark from a device
 trace's name stacks (`perfbench/harness/scopes.py`).  What is held here,
 on the CPU: the tokens reach the optimised HLO's `op_name`s through
-`lax.scan`, `jax.checkpoint`, `lax.cond`, the block's recomputation and
-the transpose; the work each names sits under it and under no other;
-the values are the parent's to the last bit; and `moe_plans()` is the
-three language cells' plan, published as `info.moe`."""
+the loops over the passes that run (`_moe_passes`: a `custom_vjp`, its
+forward loop and its backward loop), the block's recomputation and the
+transpose; the work each names sits under it and under no other; the
+values are PR 38's to the last bit; and `moe_plans()` is the three
+language cells' plan, published as `info.moe`.  And of the loops
+themselves (PR 39): gradients equal to a scan over every pass bit for
+bit whatever the router fills, no work on a weight gradient outside
+the backward loop, and the passes that ran in the job's metrics."""
 
 import re
 
@@ -35,13 +40,14 @@ def tile8(monkeypatch):
 
 
 def layer_param(scoring="sigmoid", gated=True, shared=2 * H, held=HELD,
-                e=E, k=K, hidden=H):
+                e=E, k=K, hidden=H, bias=False):
     return LayerParameter.from_text(f'''
       name: "L1.moe" type: "MixtureOfExperts" bottom: "x" top: "y"
       top: "stats"
       moe_param {{ num_experts: {e} hidden_dim: {hidden} top_k: {k}
         dispatch: "dropless" scoring: "{scoring}"
         routed_scaling_factor: 2.448 gated: {str(gated).lower()}
+        selection_bias: {str(bias).lower()}
         shared_hidden_dim: {shared} experts_held: {held} }}''')
 
 
@@ -86,14 +92,31 @@ def stacks(tile8):
     return [n for n in names if "moe." in n], stats
 
 
-def phase(name):
-    if "rematted_computation" in name:
+def wrapped(name, scope):
+    """How a scope's token stands in a name stack: plain where the
+    forward pass wrote it, `jvp(...)` where the backward loop computes a
+    pass again, `transpose(jvp(...))` where it pulls the pass back; None
+    where the stack has no such token."""
+    for form, ph in ((f"/transpose(jvp({scope}))/", "backward"),
+                     (f"/jvp({scope})/", "recomputation"),
+                     (f"/{scope}/", "forward")):
+        if form in name:
+            return ph
+    return None
+
+
+def phase(name, scope):
+    """The phase of an op of `scope`.  What the block's `jax.checkpoint`
+    runs again (the router and the sort; not the forward loop, whose
+    result no gradient needs) is a recomputation too."""
+    ph = wrapped(name, scope)
+    if ph == "forward" and "rematted_computation" in name:
         return "recomputation"
-    return "backward" if "transpose(" in name else "forward"
+    return ph
 
 
 def under(names, scope, ph):
-    return [n for n in names if f"/{scope}/" in n and phase(n) == ph]
+    return [n for n in names if phase(n, scope) == ph]
 
 
 def test_a_pass_is_skipped_in_the_traced_step(stacks):
@@ -128,18 +151,17 @@ def test_sort_lies_inside_route_in_forward_and_recomputation(stacks):
 def test_inner_scope_in_forward_recomputation_and_transpose(stacks, scope):
     names, _ = stacks
     for ph in ("forward", "recomputation", "backward"):
-        # (a few stacks are cut short, `checkpoint/cond/branch_1_fun/
-        # moe.gather/add`: ops the compiler moved out of their function)
         got = [n for n in under(names, scope, ph) if n.startswith("jit(")]
         assert got, (scope, ph)
         for n in got:
-            # inside the pass that runs, inside the scan of moe.experts,
-            # and under no second inner scope
-            assert re.search(r"/moe\.experts/.*while/body/.*cond/"
-                             r"branch_1_fun/" + re.escape(scope) + "/",
-                             n), n
-            assert sum(f"/{s}/" in n for s in INNER) == 1, n
-            assert "L1.moe" in n
+            # inside a pass of a loop of moe.experts (the forward's, or
+            # the backward's for the pass computed again and pulled
+            # back), under the prototxt layer's name and under no second
+            # inner scope
+            assert re.search(r"[/(]L1\.moe[/)]+moe\.experts/while/body/"
+                             r"[a-z(]*" + re.escape(scope) + r"\)*/", n), n
+            assert sum(wrapped(n, s) is not None for s in INNER) == 1, n
+            assert ("transpose(" in n) == (ph != "forward"), n
 
 
 def test_each_kind_of_work_sits_under_its_own_scope(stacks):
@@ -147,9 +169,8 @@ def test_each_kind_of_work_sits_under_its_own_scope(stacks):
     experts = [n for n in names if "/moe.experts/" in n]
 
     def scopes_of(prim, ph):
-        return {next((s for s in INNER if f"/{s}/" in n), None)
-                for n in experts
-                if n.endswith("/" + prim) and phase(n) == ph}
+        return {s for n in experts if n.endswith("/" + prim)
+                for s in INNER if phase(n, s) == ph}
 
     for ph in ("forward", "recomputation"):
         # the slice of the sorted order and the row gather; the gates'
@@ -159,6 +180,8 @@ def test_each_kind_of_work_sits_under_its_own_scope(stacks):
         # the grouped products (on the CPU, their expansion)
         assert scopes_of("dot_general", ph) == {"moe.products"}
     assert scopes_of("scatter-add", "forward") == {"moe.combine"}
+    # a pass computed again adds into no running sum: none is needed
+    assert scopes_of("scatter-add", "recomputation") == set()
     # transposed: the row gather becomes a scatter-add into dx (and the
     # gates' into dgates), the scatter-add a gather of d(acc)'s rows,
     # the products stay products
@@ -166,18 +189,26 @@ def test_each_kind_of_work_sits_under_its_own_scope(stacks):
                                                     "moe.combine"}
     assert scopes_of("gather", "backward") == {"moe.combine"}
     assert scopes_of("dot_general", "backward") == {"moe.products"}
-    # the loop and its conditionals carry `moe.experts` and no inner
-    # scope: what the unscoped metric reads
-    own = [n for n in experts if not any(f"/{s}/" in n for s in INNER)]
-    assert any(n.endswith("/while") for n in own)
-    assert any(n.endswith("/cond") for n in own)
+    # the loops and the sums into the backward's accumulators carry
+    # `moe.experts` and no inner scope (what the unscoped metric reads);
+    # no conditional is left, a pass that does not run is not visited
+    own = [n for n in experts
+           if all(wrapped(n, s) is None for s in INNER)]
+    for ph, stack in (("forward", "jvp(L1.moe)"),
+                      ("backward", "transpose(")):
+        assert any(stack in n and n.endswith("/moe.experts/while")
+                   for n in own), ph
+    assert any("transpose(" in n and n.endswith("/while/body/add")
+               for n in own)
+    assert not any(n.endswith("/cond") or "/branch_" in n for n in experts)
 
 
 # --------------------------------------------------------------- values
 
-# the parent tree's values (commit ed92fd8, this machine's CPU backend,
-# float32 at the default precision), as `float.hex`: a named scope is
-# metadata and may change no bit of them
+# the values of the tree before PR 38 (commit ed92fd8, this machine's CPU
+# backend, float32 at the default precision), as `float.hex`: a named
+# scope is metadata and may change no bit of them, and PR 39's backward
+# loop leaves out only sums of zeros
 PARENT = {
     "y": "0x1.e5212c0000000p+7", "dx": "-0x1.dc05ee0000000p+6",
     "dW_gate": "-0x1.4baea00000000p+8", "dW_down": "0x1.b7b4360000000p+6",
@@ -207,6 +238,154 @@ def values():
 
 def test_outputs_and_gradients_are_the_parents_to_the_last_bit(tile8):
     assert values() == PARENT
+
+
+# ------------------------------------------------- the passes that run
+
+def every_pass(xf, gates, w_in, w_out, order, starts, ends, total, rows,
+               n_pass, k, gated, prec):
+    """The reference for `_moe_passes`: a scan over all `n_pass` passes,
+    those that hold no held row too, differentiated by JAX (PR 38's
+    loop without its conditional)."""
+    def one(acc, lo):
+        return L._moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts,
+                           ends, total, rows, k, gated, prec), None
+
+    return jax.lax.scan(one, jnp.zeros_like(xf),
+                        jnp.arange(n_pass, dtype=jnp.int32) * rows)[0]
+
+
+# the selection bias that makes every token choose these experts (0 and
+# 1 are held): the passes of 32 rows that the 96 assignments then fill
+FILLS = {0: (6, 7), 1: (), 2: (0,), 3: (0, 1)}
+
+
+def steered(fill, gated):
+    lp = layer_param(gated=gated, bias=True)
+    names = [n for n, _, _ in L._moe_params(lp, [(N, D)])]
+    params = blobs(lp)
+    params[names.index("bias")] = jnp.zeros((E,), jnp.float32).at[
+        jnp.asarray(FILLS[fill], jnp.int32)].set(10.0)
+    return lp, names, params
+
+
+def value_and_gradients(lp, params, x, remat):
+    def f(x, params):
+        def layer(a, p):
+            return tuple(apply(lp, p, a))
+
+        y, stats = (jax.checkpoint(layer) if remat else layer)(x, params)
+        return jnp.sum(jnp.sin(y)), (y, stats)
+
+    return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(
+        x, params)
+
+
+@pytest.mark.parametrize("remat", [False, True],
+                         ids=["plain", "recompute_block"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu"])
+@pytest.mark.parametrize("fill", sorted(FILLS))
+def test_gradients_equal_those_of_every_pass_run(tile8, monkeypatch, fill,
+                                                 gated, remat):
+    """x, the router and every expert weight: the loops over the passes
+    that run give what `n_pass` passes run unconditionally give, bit for
+    bit (a sum of zeros left out may turn a -0.0 into 0.0, no more)."""
+    lp, names, params = steered(fill, gated)
+    x = jax.random.normal(jax.random.key(11), (N, D), jnp.float32)
+    (_, (y, stats)), (dx, dp) = value_and_gradients(lp, params, x, remat)
+    held = round(float(stats[1]) * K * N)
+    assert -(-held // 32) == fill and float(stats[2]) == 0.0
+    monkeypatch.setattr(L, "_moe_passes", every_pass)
+    (_, (y_ref, _)), (dx_ref, dp_ref) = value_and_gradients(lp, params, x,
+                                                            remat)
+    np.testing.assert_array_equal(y, y_ref)
+    np.testing.assert_array_equal(dx, dx_ref)
+    for name, got, ref in zip(names, dp, dp_ref):
+        assert np.isfinite(got).all(), name
+        np.testing.assert_array_equal(got, ref, err_msg=name)
+        if name.startswith("W") or name == "router":
+            # no held assignment, no pass: the accumulators' zeros
+            assert bool(jnp.any(got != 0)) == (fill > 0), name
+
+
+def computations(text):
+    """{name: its instructions' lines} of an HLO module's text, and
+    {name: the computations it calls}."""
+    lines, calls, name = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            lines[name], calls[name] = [], set()
+        elif line.strip() == "}":
+            name = None
+        elif name and line.strip():
+            lines[name].append(line)
+            calls[name].update(re.findall(
+                r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line))
+    return lines, calls
+
+
+def reachable(calls, name):
+    seen, todo = set(), [name]
+    while todo:
+        at = todo.pop()
+        if at not in seen:
+            seen.add(at)
+            todo.extend(calls[at])
+    return seen
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu"])
+def test_weight_gradients_are_touched_in_the_backward_loop_alone(tile8,
+                                                                 gated):
+    """The optimised HLO of a gradient: outside the bodies of the two
+    loops of `moe.experts` the only work on an array of an expert
+    weight's shape is the zero fill of the backward loop's accumulators,
+    one a weight.  (What a skipped pass used to add, a zero cotangent a
+    weight and its sum into the carry, is in no computation: a pass
+    that does not run is an iteration the loop does not make.)"""
+    lp = layer_param(gated=gated)
+    params = blobs(lp)
+    x = jax.random.normal(jax.random.key(11), (N, D), jnp.float32)
+
+    def f(x, params):
+        y = jax.checkpoint(lambda a, p: apply(lp, p, a)[0])(x, params)
+        return jnp.sum(jnp.sin(y))
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(
+        x, params).compile().as_text()
+    lines, calls = computations(text)
+    loops = [(line, re.search(r"body=%([\w.\-]+)", line).group(1))
+             for ls in lines.values() for line in ls
+             if re.search(r" while\(.*moe\.experts\)?/while\"", line)]
+    assert len(loops) == 2          # forward and backward, no third
+    assert sum("transpose(" in line for line, _ in loops) == 1
+    inside = set().union(*(reachable(calls, body) for _, body in loops))
+
+    weight = re.compile(rf"%(\S+) = f32\[{HELD},(?:{D},{H}|{H},{D})\]\S* "
+                        r"([\w\-]+)\((.*?)\)(?:.* calls=%([\w.\-]+))?")
+    fused = {c for ls in lines.values() for line in ls
+             for c in re.findall(r" calls=%([\w.\-]+)", line)}
+    fills, work = {}, []            # a zero fill -> its computation
+    for comp in sorted(set(lines) - inside):
+        for line in lines[comp]:
+            m = weight.search(line)
+            if not m or m.group(2) in ("parameter", "get-tuple-element"):
+                continue
+            name, op, args, called = m.groups()
+            if (op == "broadcast" and "," not in args) \
+                    or (op == "copy" and args.lstrip("%") in fills) \
+                    or (op == "fusion" and all(      # a broadcast, wrapped
+                        re.search(r" (parameter|broadcast|constant)\(", ln)
+                        for ln in lines[called])):
+                fills[name] = comp
+            else:
+                work.append(line.strip()[:160])
+    assert not work, work
+    # at most one fill a weight
+    assert 1 <= sum(c not in fused for c in fills.values()) \
+        <= (3 if gated else 2), fills
 
 
 # ----------------------------------------------------------------- plan
@@ -281,20 +460,15 @@ def test_capacity_dispatch_writes_no_plan(tile8):
 
 EXPERTS = '''
 layer { name: "experts" type: "MixtureOfExperts" bottom: "ip1" top: "ip1"
-  moe_param { num_experts: 4 hidden_dim: 8 top_k: 2 dispatch: "dropless"
-    scoring: "softmax" gated: true experts_held: 2 } }'''
+  %s
+  moe_param { num_experts: %d hidden_dim: 8 top_k: %d dispatch: "dropless"
+    scoring: "softmax" gated: true experts_held: %d } }'''
 
 
-@pytest.mark.parametrize("experts", [True, False],
-                         ids=["expert_layer", "no_expert_layer"])
-def test_train_job_reports_the_pass_plan_as_info_moe(tmp_path, monkeypatch,
-                                                     caplog, experts):
-    """A tiny -train job: after its first step the summary that
-    `/metrics`, `metrics.json` and the shutdown line print holds
-    `info.moe` if the net has a dropless expert layer, and no such key
-    if it has none."""
-    import logging
-
+def train_job(tmp_path, monkeypatch, experts, steps=2, observer=None):
+    """A tiny -train job of `steps` steps over 16 x 32 features, its
+    net with the expert layer `experts` (prototxt, may be empty) between
+    two inner products.  -> the metrics' summary after the last step."""
     from caffeonspark_tpu.caffe_on_spark import CaffeOnSpark
     from caffeonspark_tpu.config import Config
     from caffeonspark_tpu.data import LmdbWriter, get_source
@@ -318,8 +492,7 @@ layer {{ name: "data" type: "MemoryData" top: "data" top: "label"
     channels: 1 height: 28 width: 28 }} }}
 layer {{ name: "ip1" type: "InnerProduct" bottom: "data" top: "ip1"
   inner_product_param {{ num_output: 32
-    weight_filler {{ type: "xavier" }} }} }}''' + (EXPERTS if experts else "")
-                   + '''
+    weight_filler {{ type: "xavier" }} }} }}''' + experts + '''
 layer { name: "ip2" type: "InnerProduct" bottom: "ip1" top: "ip2"
   inner_product_param { num_output: 10
     weight_filler { type: "xavier" } } }
@@ -327,19 +500,38 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip2"
   bottom: "label" top: "loss" }''')
     solver = tmp_path / "solver.prototxt"
     solver.write_text(f'net: "{net}"\nbase_lr: 0.01\n'
-                      'lr_policy: "fixed"\nmax_iter: 2\n'
+                      f'lr_policy: "fixed"\nmax_iter: {steps}\n'
                       'snapshot_prefix: "x"\nrandom_seed: 2\n'
                       'snapshot_after_train: false\n')
     conf = Config(["-conf", str(solver), "-train", "-output",
                    str(tmp_path)])
-    with caplog.at_level(logging.INFO, "caffeonspark_tpu"):
-        CaffeOnSpark().train(
-            get_source(conf.train_data_layer(), phase_train=True), conf)
-    proc = CaffeProcessor.instance()
-    info = proc.metrics.summary().get("info", {})
+    proc = CaffeProcessor.instance(conf)
+    proc.step_observer = observer
+    CaffeOnSpark().train(
+        get_source(conf.train_data_layer(), phase_train=True), conf)
+    assert CaffeProcessor.instance() is proc
+    summary = proc.metrics.summary()
     proc.stop()
+    return summary
+
+
+@pytest.mark.parametrize("experts", [True, False],
+                         ids=["expert_layer", "no_expert_layer"])
+def test_train_job_reports_the_pass_plan_as_info_moe(tmp_path, monkeypatch,
+                                                     caplog, experts):
+    """After its first step the summary that `/metrics`, `metrics.json`
+    and the shutdown line print holds `info.moe` if the net has a
+    dropless expert layer, and no such key if it has none."""
+    import logging
+
+    with caplog.at_level(logging.INFO, "caffeonspark_tpu"):
+        summary = train_job(tmp_path, monkeypatch,
+                            EXPERTS % ("", 4, 2, 2) if experts else "")
+    info = summary.get("info", {})
     said = [r for r in caplog.records
             if "expert layers as lowered" in r.getMessage()]
+    # the layer returns no stats: nothing to say of its passes
+    assert "experts" not in summary
     if not experts:
         assert "moe" not in info and not said
         return
@@ -348,3 +540,26 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip2"
         "passes_even_router": 1, "row_tile": 512,
         "row_flops": 2 * 32 * 8 * 3, "carry_bytes": 2 * 3 * 32 * 8 * 4}}
     assert len(said) == 1
+
+
+def test_train_job_counts_the_passes_that_ran(tmp_path, monkeypatch, tile8):
+    """`experts.passes_run` of a job whose expert layer returns its
+    stats: a layer's mean and max over the steps of ceil(held
+    assignments / the rows a pass takes), from the stats the steps
+    returned (here 64 assignments in 3 passes of 24 rows; the router
+    collapses as it trains, the passes that run change)."""
+    seen = []
+    summary = train_job(
+        tmp_path, monkeypatch, EXPERTS % ('top: "experts_stats"', 16, 4, 4),
+        steps=5,
+        observer=lambda it, n, batch, p, st, out: seen.append(
+            np.asarray(out["experts_stats"])))
+    plan, = summary["info"]["moe"].values()
+    assert (plan["assignments"], plan["rows"], plan["passes"]) == (64, 24, 3)
+    held = [round(float(s[1]) * 64) for s in seen]
+    ran = [-(-h // 24) for h in held]
+    assert len(ran) == 5 and 1 < len(set(ran))
+    assert summary["experts"] == {
+        "steps": 5, "held_share": pytest.approx(np.mean(held) / 64),
+        "passes_run": {"experts": {"mean": pytest.approx(np.mean(ran)),
+                                   "max": max(ran)}}}
