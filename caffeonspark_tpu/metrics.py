@@ -133,7 +133,9 @@ final exchange counts as `info.sync` (COS_SYNC_MODE, K/staleness,
 exchanges / skipped / adopted / timeouts / max_gap), and after the
 first step what its flash attention calls were lowered to as
 `info.flash` (per call shape and kernel: tiles, calls an attention,
-share of score tiles under the masked body;
+share of score tiles under the masked body; under a window also the
+window, the pairs of chunks a causal call would run and the share of
+the causal triangle's tiles visited;
 `ops.pallas_kernels.flash_plans`) and, for a net with GatedDeltaNet
 layers, `info.gdn` (per operator shape: the chunk, the chunks a row,
 the heads, the state's bytes; `ops.layers.gdn_plans`) and, for a net

@@ -587,6 +587,113 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
     return parse_net_prototxt(t)
 
 
+# smallthinker's published layouts (SmallThinker-21BA3B-Instruct, 52
+# layers): layer 0 of every four attends to its whole past and carries
+# no position, the other three turn q and k by position and see a window
+SMALLTHINKER_LAYOUT = tuple(int(i % 4 != 0) for i in range(52))
+
+
+def smallthinker(vocab: int = 18992, hidden: int = 2560, heads: int = 28,
+                 kv_heads: int = 4, head_dim: int = 128,
+                 expert_width: int = 768, experts: int = 64,
+                 top_k: int = 6, experts_held: int = 8,
+                 first_expert: int = 0, window: int = 4096,
+                 sliding_window_layout=SMALLTHINKER_LAYOUT,
+                 rope_layout=SMALLTHINKER_LAYOUT, first_layer: int = 0,
+                 layers: int = 4, seq: int = 16384, batch: int = 1,
+                 rope_theta: float = 1.5e6, eps: float = 1e-6,
+                 init_std: float = 0.02, embed_std: float | None = None,
+                 router_reads: str = "n1",
+                 recompute: bool = True) -> NetParameter:
+    """PowerInfer/SmallThinker-21BA3B-Instruct (`model_name:
+    smallthinker_21b_instruct`) as one chip's share of an
+    expert-parallel deployment: pre-norm residual blocks of
+    grouped-query attention (no q / k norm, no bias) over `experts`
+    ReLU-gated experts of which `top_k` a token (softmax over the
+    chosen logits, none shared), this net holding `experts_held` from
+    `first_expert` on.  Published layer i sees a window of `window`
+    keys where `sliding_window_layout[i]` is 1 and its whole past where
+    it is 0, and turns q and k by position where `rope_layout[i]` is 1
+    (0: the layer carries no position at all).  The router reads the
+    block's normed INPUT (`router_reads: "n1"`, the second bottom of
+    the expert layer), the experts the normed residual after the
+    attention; `"n2"` is a router that reads what the experts read.
+    The net is the published layers [`first_layer`, `first_layer` +
+    `layers`), named L0, L1, ...  The defaults are the published widths
+    with the cut of `perfbench/configs/smallthinker_21b_a3b.json` (8 of
+    64 experts, an eighth of the vocabulary, published layers 0-3: one
+    global layer, three window layers); `experts_held=64, vocab=151936,
+    layers=52` is the whole model.  Time-major (T, B) int tops
+    `input_ids` / `target_ids` (one row of 16,384 by default); every
+    block is one `recompute_block`; each expert layer's `moe_stats` /
+    `moe_rows` tops are net outputs.  Every matrix is filled gaussian
+    `init_std`, the embedding gaussian `embed_std` where one is given."""
+    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    embed = gauss if embed_std is None else \
+        f'weight_filler {{ type: "gaussian" std: {embed_std} }}'
+    if first_layer + layers > min(len(sliding_window_layout),
+                                  len(rope_layout)):
+        raise ValueError(f"smallthinker: layers [{first_layer}, "
+                         f"{first_layer + layers}) of "
+                         f"{len(sliding_window_layout)}")
+    if router_reads not in ("n1", "n2"):
+        raise ValueError(f"smallthinker: router_reads {router_reads!r}")
+    t = f"""
+name: "SmallThinker"
+layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
+  cos_data_param {{ batch_size: {batch}
+    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }}
+    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }} }} }}
+layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
+  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
+    {embed} }} }}
+"""
+    h = "h0"
+    for i in range(layers):
+        p = f"L{i}"
+        published = first_layer + i
+        tag = f'recompute_block: "{p}"' if recompute else ""
+        win = (f" window: {window}" if sliding_window_layout[published]
+               else "")
+        rot = "true" if rope_layout[published] else "false"
+        router = (f' bottom: "{p}.n1"' if router_reads == "n1" else "")
+        t += f"""
+layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
+  {tag} rms_norm_param {{ eps: {eps} }} }}
+layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
+  top: "{p}.a" {tag}
+  attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
+    head_dim: {head_dim} causal: true rotary: {rot}
+    rope_theta: {rope_theta}{win} {gauss} }} }}
+layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
+  top: "{p}.h1" {tag} }}
+layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
+  {tag} rms_norm_param {{ eps: {eps} }} }}
+layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"{router}
+  top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
+  moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
+    top_k: {top_k} dispatch: "dropless" scoring: "softmax" gated: true
+    gate_activation: "relu"
+    experts_held: {experts_held} first_expert: {first_expert}
+    {gauss} }} }}
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
+  top: "{p}.out" {tag} }}
+"""
+        h = f"{p}.out"
+    t += f"""
+layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
+  rms_norm_param {{ eps: {eps} }} }}
+layer {{ name: "head.logits" type: "InnerProduct" bottom: "head.n"
+  top: "logits" inner_product_param {{ num_output: {vocab} axis: 2
+    bias_term: false {gauss} }} }}
+layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
+  bottom: "target_ids" top: "loss" softmax_param {{ axis: 2 }} }}
+"""
+    return parse_net_prototxt(t)
+
+
 # qwen3_next's published operator schedule (Qwen3-Next-80B-A3B, 48
 # layers, full_attention_interval 4): three Gated DeltaNet layers, then
 # one gated full-attention layer, repeating

@@ -1310,7 +1310,8 @@ def _on_batch_shards(kernel, x, *whole):
     return kernel(x, *whole)
 
 
-def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
+def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None,
+                        window: int = 0):
     """Flash (Pallas, O(block·T) VMEM) on TPU when the shape tiles;
     under a multi-device mesh the kernel runs per-device via shard_map
     over (batch, heads); XLA einsum attention otherwise — numerically
@@ -1321,9 +1322,14 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
     repeated, except before the time-sharded ring, which assumes equal
     heads).  `mxu_dtype` is the operand type of the kernel's products
     (None = float32 operands, the kernel's exact mode); the einsum path
-    takes XLA's precision.  Everything here runs under the scope
-    `attn.core`: the kernels' (or the einsums') device time apart from
-    the products, norms and rotary turns of the layer around them."""
+    takes XLA's precision.  `window` > 0: row t sees the keys
+    t - window < s <= t alone, on the kernel route (which skips what
+    the window hides) and on the einsum route alike; a mesh that shards
+    time refuses such a layer before anything is traced
+    (`parallel.sp.refuse_time_sharding`).  Everything here runs under
+    the scope `attn.core`: the kernels' (or the einsums') device time
+    apart from the products, norms and rotary turns of the layer around
+    them; a windowed layer's under `attn.window` around that."""
     from .pallas_kernels import flash_attention, pallas_enabled
     t = q.shape[2]
     interpret = _pallas_interpret()
@@ -1332,7 +1338,9 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
     # cheap anyway
     enabled = ((pallas_enabled() or interpret) and not _FLASH_SUPPRESS
                and not os.environ.get("COS_DISABLE_FLASH"))
-    with jax.named_scope("attn.core"):
+    windowed = (jax.named_scope("attn.window") if window
+                else contextlib.nullcontext())
+    with windowed, jax.named_scope("attn.core"):
         if enabled and _FLASH_MESH:
             from jax.sharding import PartitionSpec as P
             from ..parallel.sp import shard_map_nocheck
@@ -1345,6 +1353,10 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
             nh = math.prod(shape[a] for a in h_axes) if h_axes else 1
             tiles = (q.shape[0] % nb == 0 and q.shape[1] % nh == 0
                      and k.shape[1] % nh == 0)
+            if t_axes and window:
+                raise ValueError(
+                    "an attention layer with a window under a mesh that "
+                    "shards time: the ring masks by the diagonal alone")
             if (t_axes and len(t_axes) == 1 and tiles
                     and t % shape[t_axes[0]] == 0):
                 # TIME sharded: differentiable fused ring per (b, h) block
@@ -1371,15 +1383,15 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None):
                     # shard's shape (`pallas_kernels._flash_tiles`)
                     functools.partial(flash_attention, causal=causal,
                                       interpret=interpret,
-                                      mxu_dtype=mxu_dtype),
+                                      mxu_dtype=mxu_dtype, window=window),
                     mesh, (spec, spec, spec), spec)
                 return fl(q, k, v)
             # shapes don't tile the mesh: einsum path below
         elif enabled and not _FLASH_MESH and t % 128 == 0:
             return flash_attention(q, k, v, causal, interpret=interpret,
-                                   mxu_dtype=mxu_dtype)
+                                   mxu_dtype=mxu_dtype, window=window)
         from ..parallel.sp import attention as _plain_attention
-        return _plain_attention(q, k, v, causal=causal)
+        return _plain_attention(q, k, v, causal=causal, window=window)
 
 
 def _kernel_operand_dtype(prec, q):
@@ -1418,9 +1430,11 @@ def _mha(ctx, lp, params, bottoms):
         # autotune variant: pin the einsum reference path (A/B partner
         # of the flash dispatch; same math, see tests/test_pallas.py)
         with suppress_flash():
-            o = _attention_dispatch(q, k, v, causal=bool(ap.causal))
+            o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
+                                    window=int(ap.window))
     else:
-        o = _attention_dispatch(q, k, v, causal=bool(ap.causal))
+        o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
+                                window=int(ap.window))
     # back to (T, B, H*hd)
     o = jnp.moveaxis(o, (0, 1, 2), (1, 2, 0)).reshape(t_steps, batch,
                                                       h * hd)
@@ -1537,7 +1551,8 @@ def _mla(ctx, lp, params, bottoms):
         # (T, B, H, ·) -> (B, H, T, ·)
         q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
         o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
-                                mxu_dtype=_kernel_operand_dtype(prec, q))
+                                mxu_dtype=_kernel_operand_dtype(prec, q),
+                                window=int(ap.window))
         o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * vd)
         return [jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)]
 
@@ -1617,7 +1632,8 @@ def _gqa(ctx, lp, params, bottoms):
         # (T, B, heads, hd) -> (B, heads, T, hd)
         q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
         o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
-                                mxu_dtype=_kernel_operand_dtype(prec, q))
+                                mxu_dtype=_kernel_operand_dtype(prec, q),
+                                window=int(ap.window))
         o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * hd)
         if ap.output_gate:
             o = o * jax.nn.sigmoid(gate)
@@ -2012,6 +2028,13 @@ def _moe_held(mp):
     return first, held
 
 
+def _moe_gate_activation(mp) -> str:
+    if mp.gate_activation not in ("silu", "relu"):
+        raise ValueError(f"moe_param.gate_activation "
+                         f"{mp.gate_activation!r}: expected silu or relu")
+    return mp.gate_activation
+
+
 def _moe_params(lp, shapes):
     mp = lp.moe_param
     d = int(shapes[0][-1])
@@ -2173,7 +2196,8 @@ def _moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts, ends, total,
         hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
                              precision=prec)
         if gated:
-            hid = jax.nn.silu(hid) * lax.ragged_dot(
+            # `gated` names the gate's activation: silu | relu
+            hid = getattr(jax.nn, gated)(hid) * lax.ragged_dot(
                 xs, w_in[1].astype(xs.dtype), sizes, precision=prec)
         else:
             hid = jax.nn.relu(hid)
@@ -2250,7 +2274,9 @@ def _moe_dropless(ctx, lp, params, bottoms):
     float32 at HIGHEST precision (a routing choice must not turn on a
     bfloat16 rounding); the k experts with the largest s + bias are
     chosen; weights s_i / (sum(s chosen) + norm_epsilon) x
-    routed_scaling_factor.
+    routed_scaling_factor.  With a second bottom the router reads that
+    (a block's normed input, before its attention) and the experts the
+    first; the router's cotangent then flows into the second.
 
     Dispatch: the k·N assignments are sorted by expert, those of
     experts held elsewhere last.  The sorted rows are taken in passes
@@ -2297,12 +2323,13 @@ def _moe_dropless(ctx, lp, params, bottoms):
     lead, d = x.shape[:-1], x.shape[-1]
     e, k = int(mp.num_experts), max(1, int(mp.top_k))
     first, held = _moe_held(mp)
-    gated = bool(mp.gated)
+    gated = bool(mp.gated) and _moe_gate_activation(mp)
     xf = x.reshape(-1, d)
     n = xf.shape[0]
+    routed_from = bottoms[1].reshape(-1, d) if len(bottoms) > 1 else xf
 
     with jax.named_scope("moe.route"):
-        logits = jnp.matmul(xf.astype(jnp.float32),
+        logits = jnp.matmul(routed_from.astype(jnp.float32),
                             pd["router"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         if mp.scoring == "sigmoid":
@@ -2345,7 +2372,8 @@ def _moe_dropless(ctx, lp, params, bottoms):
     hidden, products = int(w_out.shape[1]), len(w_in) + 1
     plan = _MOE_PLANS.setdefault(
         f"{n}x{d} top {k} of {e}, {held} held x {hidden}"
-        f"{' gated' if gated else ''}, "
+        f"{' gated' if gated else ''}"
+        f"{' by relu' if gated == 'relu' else ''}, "
         f"shared {int(mp.shared_hidden_dim)}",
         {"layers": [], "assignments": k * n, "rows": rows,
          "passes": n_pass,

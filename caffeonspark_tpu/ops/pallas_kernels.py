@@ -430,7 +430,10 @@ def pallas_enabled() -> bool:
 # devices).  A score tile does only what it needs: the tiles wholly
 # under the causal diagonal run a body with no mask at all, the tiles
 # on it the masked one, and the tile sizes follow from the shape
-# (`_flash_tiles`).
+# (`_flash_tiles`).  Under a window (`window` keys a row, its own among
+# them) there is a second edge: the tiles before it are skipped like the
+# ones past the diagonal, the ones on it masked by it, pair of chunks by
+# pair of chunks (`_window_span`, `_chunk_pairs`).
 
 BLOCK_Q = 128             # the floor of a tile, and the public default
 BLOCK_K = 128
@@ -492,8 +495,94 @@ def _diagonal_span(i, block, other):
     return (i * block) // other, ((i + 1) * block + other - 1) // other
 
 
+def _clip(x, lo, hi):
+    """x held to [lo, hi]: Python numbers where a plan is counted,
+    traced scalars inside a kernel."""
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _window_span(i, block, other, n_other, offset: int, window: int,
+                 rows: bool):
+    """(lo, e1, e2, hi): the tiles of `other` rows along one axis that
+    tile i of `block` rows along the other axis meets under a causal
+    mask with a window, row t seeing the columns s with
+    t - window < s <= t, the call's rows lying `offset` past its columns
+    (a pair of chunks).  [lo, hi) hold a visible score and are visited;
+    [e1, e2) hold no masked one and take the body without a mask;
+    [lo, e1) straddle the first edge the loop meets and [e2, hi) the
+    second.  `rows` = tile i is one of q rows and the loop walks k
+    tiles (the window's edge first, then the diagonal); else tile i is
+    one of k columns and the loop walks q tiles (the diagonal first).
+    Where window >= block + other - 1 no tile lies on both edges and
+    e1 <= e2 before the clips; below that every masked tile takes both
+    masks, and [e1, max(e1, e2)) is what is whole."""
+    big = (n_other + 1) * other
+
+    def tiles(x):           # x // other, of an x held inside the call
+        return _clip(x, 0, big) // other
+
+    a = i * block + (offset if rows else -offset)
+    b = a + block           # tile i is [a, b) on the loop's axis
+    if rows:
+        lo = tiles(a - window + 1)              # last column > a - window
+        e1 = tiles(b - 1 - window + other)      # first column > b-1-window
+        e2, hi = tiles(a), tiles(b + other - 1)
+    else:
+        lo, e1 = tiles(a), tiles(b + other - 1)
+        e2 = tiles(a + window)                  # last row < a + window
+        hi = tiles(b + window + other - 2)      # first row < b-1+window
+    lo = _clip(lo, 0, n_other)
+    hi = _clip(hi, lo, n_other)
+    e1 = _clip(e1, lo, hi)
+    return lo, e1, _clip(e2, e1, hi), hi
+
+
+def _window_visible(ahead, shift, window: int, edge: str, both: bool):
+    """A masked tile's visible scores: `ahead` is row - column inside
+    the tile, `shift` what the tile's place adds to it; the tile lies
+    on the window's `edge` or on the "diagonal", or, where `both`, maybe
+    on the two."""
+    if edge == "diagonal" and not both:
+        return ahead >= -shift                  # column <= row
+    inside = ahead < window - shift             # column > row - window
+    return inside & (ahead >= -shift) if both else inside
+
+
+def _window_loops(tile, carry, i, block, other, n_other, offset: int,
+                  window: int, rows: bool):
+    """`tile(j, carry[, visible])` folded over the tiles j that tile i
+    meets under the window (`_window_span`): the ones on the first edge
+    masked by it, the ones between the edges without a mask, the ones
+    on the second edge masked by that; the rest are not visited.  The
+    scores of a tile are (block, other), rows x columns where `rows`
+    and columns x rows (the dK/dV kernel's transposed scores) else."""
+    lo, e1, e2, hi = _window_span(i, block, other, n_other, offset, window,
+                                  rows)
+    ahead = _row_minus_col(block, other)
+    both = window < block + other - 1
+    first, second = (("window", "diagonal") if rows
+                     else ("diagonal", "window"))
+
+    def on(edge):
+        # the call's row offset + r sees column c: what tile (i, j)'s
+        # place adds to r - c
+        def masked(j, carry):
+            place = i * block - j * other
+            return tile(j, carry, _window_visible(
+                ahead if rows else -ahead,
+                offset + (place if rows else -place), window, edge, both))
+        return masked
+
+    carry = jax.lax.fori_loop(lo, e1, on(first), carry)
+    carry = jax.lax.fori_loop(e1, e2, tile, carry)
+    return jax.lax.fori_loop(e2, hi, on(second), carry)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      sm_scale: float, causal: bool, block_k: int):
+                      sm_scale: float, causal: bool, block_k: int,
+                      window: int = 0, offset: int = 0):
     # operands of every product arrive in the type the MXU is to see
     # (float32: exact, several passes; bfloat16: one pass, float32
     # accumulation, what XLA's default precision gives the einsum path
@@ -511,7 +600,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     carry = (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
              jnp.zeros((block_q, 1), jnp.float32),
              jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32))
-    if causal:
+    if window:
+        # the k tiles before the window's edge and past the diagonal are
+        # skipped, the ones on either edge masked, each by its own edge
+        carry = _window_loops(tile, carry, qi, block_q, block_k,
+                              k_ref.shape[1] // block_k, offset, window,
+                              True)
+    elif causal:
         # K/V tiles starting past this q tile's last row are fully
         # masked and skipped, the ones ending at or before its first row
         # hold no masked score and take the body without a mask
@@ -527,13 +622,22 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         carry = jax.lax.fori_loop(0, k_ref.shape[1] // block_k, tile,
                                   carry)
     m, l, acc = carry
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)
+    if window and offset:
+        # a row of a later chunk may see no column of this one: its part
+        # is nothing, weighted by nothing when the parts are added up
+        seen = l > 0.0
+        l = jnp.where(seen, l, 1.0)
+        o_ref[0] = jnp.where(seen, acc / l, 0.0).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(seen, m + jnp.log(l), _NEG_INF)
+    else:
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0] = m + jnp.log(l)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, *, sm_scale: float,
-                          causal: bool, block_q: int):
+                          causal: bool, block_q: int, window: int = 0,
+                          offset: int = 0):
     # the scores of this kernel are transposed, (block_k, block_q): both
     # accumulations are then plain products of them (pT dO, dsT q), and
     # the two per-row statistics broadcast as (1, block_q) rows
@@ -565,7 +669,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
     # masked scores and are skipped; the ones up to its last row lie on
     # the diagonal; the rest hold no masked score
     i_whole = 0
-    if causal:
+    if causal and not window:
         i0, i_whole = _diagonal_span(ki, block_k, block_q)
         behind = -_row_minus_col(block_k, block_q)
         # row i*block_q + r sees column ki*block_k + c
@@ -573,7 +677,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
             i0, i_whole,
             lambda i, carry: tile(
                 i, carry, behind >= ki * block_k - i * block_q), carry)
-    dk, dv = jax.lax.fori_loop(i_whole, n_q, tile, carry)
+    if window:
+        # under a window the loop also ends early, past the last q row
+        # that sees this k tile, and the q tiles on that edge are masked
+        dk, dv = _window_loops(tile, carry, ki, block_k, block_q, n_q,
+                               offset, window, False)
+    else:
+        dk, dv = jax.lax.fori_loop(i_whole, n_q, tile, carry)
     # d(scores) = ds * sm_scale: the scale once, on the accumulator
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -581,7 +691,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, *, sm_scale: float,
-                         causal: bool, block_k: int):
+                         causal: bool, block_k: int, window: int = 0,
+                         offset: int = 0):
     qb = q_ref[0]                                # (block_q, D)
     dob = do_ref[0]
     lse = lse_ref[0]                             # (block_q, 1)
@@ -600,7 +711,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                             preferred_element_type=jnp.float32)
 
     dq = jnp.zeros((block_q, qb.shape[-1]), jnp.float32)
-    if causal:
+    if window:
+        dq = _window_loops(tile, dq, qi, block_q, block_k,
+                           k_ref.shape[1] // block_k, offset, window, True)
+    elif causal:
         n_whole, n_k = _diagonal_span(qi, block_q, block_k)
         dq = jax.lax.fori_loop(0, n_whole, tile, dq)
         ahead = _row_minus_col(block_q, block_k)
@@ -765,13 +879,18 @@ def _flash_chunk(t: int, block: int, *block_bytes) -> int:
     return c
 
 
-def _chunk_pairs(n: int, causal: bool):
+def _chunk_pairs(n: int, causal: bool, window: int = 0, chunk: int = 0):
     """(q chunk, k / v chunk, causal) of every pair that holds a score:
     under the causal mask the pairs below the diagonal are whole, the
     diagonal ones masked by their local positions (the chunks are
-    equally long), the ones above it empty."""
+    equally long), the ones above it empty.  Under a window of `window`
+    keys over chunks of `chunk` rows the pairs whose nearest row and
+    column lie a window apart or more are empty too, and the others
+    below the diagonal are masked by the window's edge, (i - j) chunk
+    rows past their columns."""
     return [(i, j, causal and i == j) for i in range(n)
-            for j in range(i + 1 if causal else n)]
+            for j in range(i + 1 if causal else n)
+            if not window or (i - j - 1) * chunk + 1 < window]
 
 
 def _rows(x, i, c):
@@ -779,11 +898,20 @@ def _rows(x, i, c):
 
 
 def _masked_tiles(kernel: str, t: int, block_q: int, block_k: int,
-                  causal: bool):
+                  causal: bool, window: int = 0, offset: int = 0):
     """(score tiles that run the masked body, score tiles visited) of
     one call: what the kernels' loop bounds come to, summed over the
     grid's second axis."""
     n_q, n_k = t // block_q, t // block_k
+    if window:
+        if kernel == "dkv":
+            spans = [_window_span(i, block_k, block_q, n_q, offset, window,
+                                  False) for i in range(n_k)]
+        else:
+            spans = [_window_span(i, block_q, block_k, n_k, offset, window,
+                                  True) for i in range(n_q)]
+        return (sum(e1 - lo + hi - e2 for lo, e1, e2, hi in spans),
+                sum(hi - lo for lo, _, _, hi in spans))
     if not causal:
         return 0, n_q * n_k
     if kernel == "dkv":     # a k tile's program: q tiles from lo on
@@ -802,17 +930,26 @@ def _masked_tiles(kernel: str, t: int, block_q: int, block_k: int,
 _FLASH_PLANS: dict = {}
 
 
-def _note_plan(kernel, shape, causal, chunk, tiles):
+def _note_plan(kernel, shape, causal, chunk, tiles, window=0):
     bh, t, d, dv, dtype, g = shape
-    pairs = _chunk_pairs(t // chunk, causal)
-    counts = [_masked_tiles(kernel, chunk, *tiles, cz)
-              for _, _, cz in pairs]
+    pairs = _chunk_pairs(t // chunk, causal, window, chunk)
+    counts = [_masked_tiles(kernel, chunk, *tiles, cz, window,
+                            (i - j) * chunk) for i, j, cz in pairs]
     masked, visited = (sum(c[i] for c in counts) for i in (0, 1))
     key = (f"{bh}x{t}x{d}/{dv} {jnp.dtype(dtype).name} g{g}"
-           f"{' causal' if causal else ''}")
-    _FLASH_PLANS.setdefault(key, {})[kernel] = {
+           f"{' causal' if causal else ''}"
+           f"{f' window {window}' if window else ''}")
+    plan = _FLASH_PLANS.setdefault(key, {})[kernel] = {
         "block_q": tiles[0], "block_k": tiles[1], "calls": len(pairs),
         "masked_tile_share": round(masked / visited, 4)}
+    if window:
+        # beside what a causal call over the same chunks and tiles runs
+        whole = _chunk_pairs(t // chunk, True)
+        plan.update(
+            window=window, causal_calls=len(whole),
+            visited_tile_share=round(visited / sum(
+                _masked_tiles(kernel, chunk, *tiles, cz)[1]
+                for _, _, cz in whole), 4))
 
 
 def flash_plans() -> dict:
@@ -841,8 +978,8 @@ def _check_blocks(t, block_q, block_k):
             f"t={t} % block_q={block_q}, t={t} % block_k={block_k}")
 
 
-def _flash_fwd_one(q, k, v, causal, *, sm_scale, tiles, interpret,
-                   out_dtype):
+def _flash_fwd_one(q, k, v, causal, offset=0, *, sm_scale, tiles,
+                   interpret, out_dtype, window=0):
     """One forward call: (out, lse) of q over all of k, v."""
     bh, t, d = q.shape
     dv = v.shape[-1]
@@ -854,7 +991,8 @@ def _flash_fwd_one(q, k, v, causal, *, sm_scale, tiles, interpret,
     _, vspec = _flash_kv_specs(block_q, dv, t, g)
     out, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
-                          causal=causal, block_k=block_k),
+                          causal=causal, block_k=block_k, window=window,
+                          offset=offset),
         out_shape=(jax.ShapeDtypeStruct((bh, t, dv), out_dtype),
                    jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)),
         grid=(bh, t // block_q),
@@ -866,11 +1004,21 @@ def _flash_fwd_one(q, k, v, causal, *, sm_scale, tiles, interpret,
     return out, lse[:, :, 0]
 
 
+def _check_window(causal, window, t):
+    """The window a call runs under: 0 where every row sees its whole
+    causal past anyway."""
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True "
+                         "(row t sees the `window` keys up to its own)")
+    return 0 if window >= t else window
+
+
 def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
-                    interpret, mxu_dtype=None):
+                    interpret, mxu_dtype=None, window=0):
     bh, t, d = q.shape
     dv = v.shape[-1]
     _check_blocks(t, block_q, block_k)
+    window = _check_window(causal, window, t)
     out_dtype = q.dtype
     q, k, v = _operands(mxu_dtype, q, k, v)
     isz = q.dtype.itemsize
@@ -878,18 +1026,20 @@ def _flash_fwd_call(q, k, v, sm_scale, causal, block_q, block_k,
     c = _flash_chunk(t, max(floor), _fwd_block_bytes(d, dv, isz, *floor))
     tiles = _flash_tiles("fwd", c, d, dv, isz, floor)
     _note_plan("fwd", (bh, t, d, dv, q.dtype, bh // k.shape[0]), causal,
-               c, tiles)
+               c, tiles, window)
     one = functools.partial(_flash_fwd_one, sm_scale=sm_scale,
                             tiles=tiles, interpret=interpret,
-                            out_dtype=out_dtype)
+                            out_dtype=out_dtype, window=window)
     if c == t:
         return one(q, k, v, causal)
     # each chunk of q against the chunks of k / v it sees, one call a
     # pair; a row's parts are weighted by their share of its softmax sum
     outs, lses = [], []
     for i in range(t // c):
-        parts = [one(_rows(q, i, c), _rows(k, j, c), _rows(v, j, c), cz)
-                 for qi, j, cz in _chunk_pairs(t // c, causal) if qi == i]
+        parts = [one(_rows(q, i, c), _rows(k, j, c), _rows(v, j, c), cz,
+                     (i - j) * c)
+                 for qi, j, cz in _chunk_pairs(t // c, causal, window, c)
+                 if qi == i]
         lse = functools.reduce(jnp.logaddexp, [p[1] for p in parts])
         outs.append(sum(o * jnp.exp(l - lse)[:, :, None].astype(
             o.dtype) for o, l in parts))
@@ -906,12 +1056,12 @@ def _flash_flatten(q, k, v):
     return tuple(x.reshape((-1,) + x.shape[2:]) for x in (q, k, v))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: int = BLOCK_Q,
                     block_k: int = BLOCK_K,
                     interpret: bool = False,
-                    mxu_dtype=None) -> jax.Array:
+                    mxu_dtype=None, window: int = 0) -> jax.Array:
     """Fused blockwise attention, q (B, H, T, D), k (B, H/g, T, D),
     v (B, H/g, T, Dv) → (B, H, T, Dv); Dv may differ from D (latent
     attention: 192-wide q/k, 128-wide v), and with g > 1 query head h
@@ -928,27 +1078,32 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (`_flash_tiles`).  `mxu_dtype` (None = the inputs' own type) is
     the operand type of the products: q, k, v and dO are cast to it
     once, before the kernels; the scores, the softmax statistics and
-    the accumulators stay float32, the outputs the inputs' type."""
+    the accumulators stay float32, the outputs the inputs' type.
+    `window` > 0 (with `causal`): row t sees the `window` columns
+    t - window < s <= t and no others; the kernels visit the score
+    tiles that hold a visible score and mask the ones on either edge
+    (`_window_span`), pair of chunks by pair of chunks; 0, or a window
+    of t rows or more, is the causal mask alone."""
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
     qf, kf, vf = _flash_flatten(q, k, v)
     out, _ = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
-                             block_k, interpret, mxu_dtype)
+                             block_k, interpret, mxu_dtype, window)
     return out.reshape(b, h, t, v.shape[-1])
 
 
 def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
-                   mxu_dtype):
+                   mxu_dtype, window):
     b, h, t, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
     qf, kf, vf = _flash_flatten(q, k, v)
     out, lse = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
-                               block_k, interpret, mxu_dtype)
+                               block_k, interpret, mxu_dtype, window)
     return out.reshape(b, h, t, v.shape[-1]), (qf, kf, vf, out, lse)
 
 
-def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
-                  interpret, out_dtype):
+def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *, tiles,
+                  interpret, out_dtype, window=0):
     """One pair's dq: a program a block of q, all of k and v past it;
     the statistics as (block_q, 1) columns beside the scores' rows."""
     bh, t, d = qf.shape
@@ -962,7 +1117,7 @@ def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
     return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel,
                           sm_scale=1.0 / math.sqrt(d), causal=causal,
-                          block_k=block_k),
+                          block_k=block_k, window=window, offset=offset),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), out_dtype),
         grid=(bh, t // block_q),
         in_specs=[qspec, kfull, vfull, dospec, vec, vec],
@@ -972,8 +1127,8 @@ def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
     )(qf, kf, vf, dof, lse[:, :, None], delta[:, :, None])
 
 
-def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
-                   interpret, out_dtypes):
+def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *,
+                   tiles, interpret, out_dtypes, window=0):
     """One pair's dk, dv: a program a block of k and v, all of q and dO
     past it; the statistics as (1, t) rows beside the transposed
     scores' columns."""
@@ -988,7 +1143,7 @@ def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel,
                           sm_scale=1.0 / math.sqrt(d), causal=causal,
-                          block_q=block_q),
+                          block_q=block_q, window=window, offset=offset),
         out_shape=(jax.ShapeDtypeStruct((bh, t, d), out_dtypes[0]),
                    jax.ShapeDtypeStruct((bh, t, dv_w), out_dtypes[1])),
         grid=(bh, t // block_k),
@@ -1007,7 +1162,7 @@ def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, *, tiles,
 
 def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
                     block_q: int, block_k: int, interpret: bool,
-                    out_dtype=None, mxu_dtype=None):
+                    out_dtype=None, mxu_dtype=None, window: int = 0):
     """dq, dk, dv for one (q-group, kv-block) attention pair from the
     saved stats — the flash backward building block.  All operands
     flattened (B·H, T, D) / (B·H, T), v and dO (B·H, T, Dv); k and v
@@ -1019,11 +1174,12 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
     causal=False for fully-visible ones.  `out_dtype` overrides the
     gradient dtype — accumulating callers pass float32 so bf16 inputs
     don't round each per-hop partial before the sum.  `block_q` /
-    `block_k` are the smallest tiles, `mxu_dtype` the operand type, as
-    in `flash_attention`."""
+    `block_k` are the smallest tiles, `mxu_dtype` the operand type and
+    `window` the window, as in `flash_attention`."""
     bh, t, d = qf.shape
     dv_w = vf.shape[-1]
     _check_blocks(t, block_q, block_k)
+    window = _check_window(causal, window, t)
     out_dtypes = tuple(out_dtype or x.dtype for x in (qf, kf, vf))
     qf, kf, vf, dof = _operands(mxu_dtype, qf, kf, vf, dof)
     isz = qf.dtype.itemsize
@@ -1034,14 +1190,14 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
     tiles = {}
     for kern in ("dq", "dkv"):
         tiles[kern] = _flash_tiles(kern, c, d, dv_w, isz, floor)
-        _note_plan(kern, shape, causal, c, tiles[kern])
+        _note_plan(kern, shape, causal, c, tiles[kern], window)
 
     def one(*pair):
         dq = _flash_dq_one(*pair, tiles=tiles["dq"], interpret=interpret,
-                           out_dtype=out_dtypes[0])
+                           out_dtype=out_dtypes[0], window=window)
         return (dq,) + _flash_dkv_one(
             *pair, tiles=tiles["dkv"], interpret=interpret,
-            out_dtypes=out_dtypes[1:])
+            out_dtypes=out_dtypes[1:], window=window)
 
     if c == t:
         return one(qf, kf, vf, dof, lse, delta, causal)
@@ -1049,17 +1205,17 @@ def flash_bwd_block(qf, kf, vf, dof, lse, delta, *, causal: bool,
     # add up: dq over a q chunk's pairs, dk and dv over a k / v chunk's
     n = t // c
     dqs, dks, dvs = [None] * n, [None] * n, [None] * n
-    for i, j, cz in _chunk_pairs(n, causal):
+    for i, j, cz in _chunk_pairs(n, causal, window, c):
         part = one(_rows(qf, i, c), _rows(kf, j, c), _rows(vf, j, c),
                    _rows(dof, i, c), _rows(lse, i, c), _rows(delta, i, c),
-                   cz)
+                   cz, (i - j) * c)
         for acc, at, x in zip((dqs, dks, dvs), (i, j, j), part):
             acc[at] = x if acc[at] is None else acc[at] + x
     return tuple(jnp.concatenate(a, axis=1) for a in (dqs, dks, dvs))
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, mxu_dtype, res,
-                   do):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, mxu_dtype, window,
+                   res, do):
     qf, kf, vf, out, lse = res
     bh, t, d = qf.shape
     dof = do.reshape(bh, t, vf.shape[-1])
@@ -1069,7 +1225,7 @@ def _flash_vjp_bwd(causal, block_q, block_k, interpret, mxu_dtype, res,
     dq, dk, dv = flash_bwd_block(qf, kf, vf, dof, lse, delta,
                                  causal=causal, block_q=block_q,
                                  block_k=block_k, interpret=interpret,
-                                 mxu_dtype=mxu_dtype)
+                                 mxu_dtype=mxu_dtype, window=window)
     lead = do.shape[:3]
     kv_lead = (lead[0], kf.shape[0] // lead[0], t)
     return (dq.reshape(lead + (d,)), dk.reshape(kv_lead + (d,)),

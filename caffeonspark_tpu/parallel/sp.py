@@ -56,12 +56,26 @@ def refuse_time_sharding(net) -> None:
             f"{len(bad) - 1} more): the chunked scan carries its state "
             "along the whole sequence on one device; use dp / ep / pp "
             "axes for this net")
+    bad = [lp.name for lp in net.compute_layers
+           if lp.has("attention_param") and lp.attention_param.window]
+    if bad:
+        raise ValueError(
+            f"sequence parallelism (mesh axis sp > 1) is not written for "
+            f"an attention layer with a window ({bad[0]!r} and "
+            f"{len(bad) - 1} more): the ring's hops mask by the causal "
+            "diagonal alone, so the layer would attend to its whole "
+            "past; use dp / ep / pp axes for this net")
 
 
 def attention(q: Array, k: Array, v: Array, *, causal: bool = False,
-              q_offset: int = 0, k_offset: int = 0) -> Array:
+              q_offset: int = 0, k_offset: int = 0,
+              window: int = 0) -> Array:
     """Reference softmax attention. q,k,v: (B, H, T, D); k and v may
-    hold H / g heads, query head h then reads key/value head h // g."""
+    hold H / g heads, query head h then reads key/value head h // g.
+    `window` > 0 (with `causal`): row t sees the `window` columns
+    t - window < s <= t alone."""
+    if window and not causal:
+        raise ValueError("attention: a window needs causal=True")
     scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, _ = q.shape
     g = h // k.shape[1]
@@ -72,6 +86,8 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = False,
         qpos = q_offset + jnp.arange(tq)[:, None]
         kpos = k_offset + jnp.arange(k.shape[2])[None, :]
         mask = qpos >= kpos
+        if window:
+            mask &= qpos - kpos < window
         s = jnp.where(jnp.tile(mask, (g, 1)) if g > 1 else mask, s,
                       -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)

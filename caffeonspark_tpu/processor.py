@@ -604,7 +604,8 @@ class CaffeProcessor:
         """The first step is lowered: the plans its operators took go
         into the metrics and into the log, once.  `info.flash`: what the
         flash attention calls came to (tiles, calls an attention, share
-        of score tiles under the masked body;
+        of score tiles under the masked body, and under a window the
+        window, `causal_calls` and `visited_tile_share`;
         `pallas_kernels.flash_plans`); `info.gdn`: per GatedDeltaNet
         shape the form of the rule, the chunk, the chunks a row, the
         heads and the state's bytes (`layers.gdn_plans`); `info.moe`:

@@ -560,12 +560,17 @@ class MoEParameter(Message):
       a bias blob that moves only the top-k choice (give it lr_mult 0),
       the chosen scores are normalised over the k and multiplied by
       `routed_scaling_factor`; `gated` experts are SiLU-gated
-      (`W_gate`/`W_up`/`W_down`), `shared_hidden_dim` > 0 adds shared
+      (`W_gate`/`W_up`/`W_down`; `gate_activation: "relu"` gates them
+      by a ReLU instead), `shared_hidden_dim` > 0 adds shared
       experts as one gated FFN every token passes.  `experts_held` /
       `first_expert` tell the layer which experts live here (0 = all):
       it routes over all `num_experts` and computes the part of the
       sum that experts [first_expert, first_expert + experts_held)
-      give, plus the shared experts.  Tops after the first: a (3,)
+      give, plus the shared experts.  A SECOND BOTTOM, when given, is
+      what the router reads (the experts read the first): a block whose
+      router sits before its attention hands it the block's normed
+      input, and the router's gradient flows back into that.  Tops
+      after the first: a (3,)
       vector [rows per held expert max/mean, share of the k N
       assignments on held experts, dropped assignments], then the
       rows per held expert."""
@@ -589,6 +594,9 @@ class MoEParameter(Message):
         # the shared experts' sum is multiplied by sigmoid(x w_sg), one
         # gate a token (qwen3_next); blob `S_sgate` (D, 1)
         Field(15, "shared_gate", BOOL, default=False),
+        # what a `gated` expert's gate passes through: "silu"
+        # (silu(x W_gate) * (x W_up)) or "relu" (smallthinker's ReGLU)
+        Field(16, "gate_activation", STRING, default="silu"),
     ]
 
 
@@ -615,7 +623,11 @@ class AttentionParameter(Message):
     t theta^(-2i/rotary_dim)) by `rope_theta` after the norms;
     `output_gate` widens `W_q` to `num_heads` x 2 `head_dim` (query
     and gate of a head side by side) and multiplies the attention's
-    output by sigmoid(gate) before `W_o` (qwen3_next).  All
+    output by sigmoid(gate) before `W_o` (qwen3_next); `window` > 0
+    (with `causal`, any of the three types' dispatch, set on
+    `GroupedQueryAttention`) lets row t see the `window` keys up to and
+    with its own (t - window < s <= t): a sliding-window layer; a layer
+    without positions is `rotary: false`.  All
     types share one attention dispatch (flash kernel on the TPU when
     the shape tiles, XLA einsums otherwise); GSPMD partitions the
     einsums over whatever mesh axes the activations carry."""
@@ -635,6 +647,9 @@ class AttentionParameter(Message):
         Field(13, "rotary", BOOL, default=False),
         Field(14, "rotary_dim", UINT32, default=0),
         Field(15, "output_gate", BOOL, default=False),
+        # with `causal`: row t sees the `window` keys t - window < s <= t
+        # (its own among them) and no others; 0 = its whole past
+        Field(16, "window", UINT32, default=0),
     ]
 
 

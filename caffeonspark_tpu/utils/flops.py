@@ -18,6 +18,18 @@ from __future__ import annotations
 from math import prod
 
 
+def visible_scores(t: int, causal: bool, window: int = 0) -> int:
+    """Scores a head computes over t rows: all t x t, the causal half
+    (counted as t x t / 2), or under a window of `window` keys the
+    pairs a row can see and no others: row r sees min(r + 1, window)
+    columns, window (window + 1) / 2 + (t - window) window in all."""
+    if not causal:
+        return t * t
+    if 0 < window < t:
+        return window * (window + 1) // 2 + (t - window) * window
+    return t * t // 2
+
+
 def forward_flops(net) -> int:
     """Estimated forward-pass FLOPs for one batch through `net`.
 
@@ -56,7 +68,11 @@ def layer_forward_flops(net) -> dict:
             for (pname, pshape, _) in specs:
                 total += 2 * t_s * b_s * prod(pshape)
             ap = lp.attention_param
-            total += 4 * b_s * int(ap.num_heads) * t_s * t_s \
+            # every score, as this type always counted them, unless a
+            # window hides some
+            scores = (visible_scores(t_s, True, int(ap.window))
+                      if ap.causal and ap.window else t_s * t_s)
+            total += 4 * b_s * int(ap.num_heads) * scores \
                 * int(ap.head_dim)
             out[lp.name] = total
             continue
@@ -68,7 +84,8 @@ def layer_forward_flops(net) -> dict:
             ap = lp.attention_param
             total = 2 * t_s * b_s * sum(
                 prod(ps) for (_, ps, _) in specs if len(ps) == 2)
-            total += (2 * b_s * int(ap.num_heads) * t_s * t_s // 2
+            total += (2 * b_s * int(ap.num_heads)
+                      * visible_scores(t_s, True, int(ap.window))
                       * (int(ap.qk_nope_head_dim)
                          + int(ap.qk_rope_head_dim)
                          + int(ap.v_head_dim)))
@@ -77,12 +94,14 @@ def layer_forward_flops(net) -> dict:
         if lp.type == "GroupedQueryAttention":
             # the four projections per (t, b) position (W_k and W_v at
             # their own fewer heads), plus causal attention over
-            # head_dim wide q/k and v for every QUERY head
+            # head_dim wide q/k and v for every QUERY head: under a
+            # window the scores a row can see and no others
             t_s, b_s = first_top[0], first_top[1]
             ap = lp.attention_param
             total = 2 * t_s * b_s * sum(
                 prod(ps) for (_, ps, _) in specs if len(ps) == 2)
-            total += (2 * b_s * int(ap.num_heads) * t_s * t_s // 2
+            total += (2 * b_s * int(ap.num_heads)
+                      * visible_scores(t_s, True, int(ap.window))
                       * 2 * int(ap.head_dim))
             out[lp.name] = total
             continue

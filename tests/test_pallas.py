@@ -348,6 +348,156 @@ def test_flash_attention_in_chunks_matches_reference(monkeypatch, causal,
         10 if causal else 16)
 
 
+# windows over one call's tiles: g = 7 (the first group that is no
+# power of two), W smaller than, equal to and no multiple of the tile,
+# a tile on both edges at once, W a row short of T
+_WINDOWED = [
+    # t, w, h, g, d, tiles
+    (512, 64, 7, 7, 32, (128, 128)),
+    (512, 128, 7, 7, 32, (128, 128)),
+    (512, 100, 7, 7, 32, (128, 128)),
+    (512, 300, 7, 7, 32, (128, 256)),
+    (512, 300, 14, 7, 32, (256, 128)),
+    (768, 200, 2, 1, 32, {"fwd": (128, 384), "dq": (384, 128),
+                          "dkv": (128, 384)}),
+    (512, 1, 2, 2, 32, (128, 128)),
+    (512, 511, 2, 2, 32, (128, 128)),
+    (256, 40, 7, 7, 64, None),
+]
+
+
+def _windowed_pair(t, w, h, g, d, seed=11):
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    from caffeonspark_tpu.parallel.sp import attention
+    q, k, v = _qkv(seed, 1, h, g, t, d, d)
+    ref = lambda q, k, v: attention(q, k, v, causal=True,   # noqa: E731
+                                    window=w)
+    fl = lambda q, k, v: pk.flash_attention(                 # noqa: E731
+        q, k, v, True, 128, 128, True, None, w)
+    return (q, k, v), ref, fl
+
+
+def _assert_same_values_and_grads(args, ref, fl, g):
+    def scal(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+    np.testing.assert_allclose(np.asarray(fl(*args)),
+                               np.asarray(ref(*args)),
+                               rtol=2e-5, atol=2e-5)
+    gr = jax.grad(scal(ref), argnums=(0, 1, 2))(*args)
+    gf = jax.grad(scal(fl), argnums=(0, 1, 2))(*args)
+    for name, a, b_ in zip("qkv", gr, gf):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
+                                   rtol=2e-4, atol=1e-5 * g,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("t,w,h,g,d,tiles", _WINDOWED)
+def test_windowed_flash_matches_the_masked_einsum_path(monkeypatch, t, w,
+                                                       h, g, d, tiles):
+    """The three kernels under a window of w keys (row t sees
+    t - w < s <= t) against `parallel/sp.attention(window=)`, values and
+    gradients: the tiles before the window's edge and past the diagonal
+    are skipped, the ones on either edge masked."""
+    _force_tiles(monkeypatch, tiles)
+    args, ref, fl = _windowed_pair(t, w, h, g, d)
+    _assert_same_values_and_grads(args, ref, fl, g)
+
+
+@pytest.mark.parametrize("w,fwd_calls,bwd_calls", [
+    (128, 3, 7),    # half a forward chunk (the cell's case), one backward
+    (256, 3, 9),    # a forward chunk; two backward chunks
+    (384, 3, 10),   # one and a half forward chunks
+    (100, 3, 7), (1, 2, 4), (129, 3, 7), (130, 3, 9)])
+def test_windowed_flash_in_chunks_matches_reference(monkeypatch, w,
+                                                    fwd_calls, bwd_calls):
+    """T cut into chunks (forward 2 of 256, backward 4 of 128, as
+    `test_flash_attention_in_chunks_matches_reference` cuts them) with W
+    half a chunk, a chunk, one and a half: every pair carries its row
+    offset in both bounds, a row of a later chunk that sees no column of
+    an earlier one adds nothing, and the pairs a window apart or more
+    are not run."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_SCOPED_VMEM", pk._MOSAIC_ROOM + 1_500_000)
+    t, h, g, d = 512, 7, 7, 64
+    assert pk._flash_chunk(t, 128, pk._fwd_block_bytes(d, d, 4, 128)) == 256
+    assert pk._flash_chunk(t, 128, pk._dq_block_bytes(d, d, 4, 128),
+                           pk._dkv_block_bytes(d, d, 4, 128)) == 128
+    args, ref, fl = _windowed_pair(t, w, h, g, d, seed=12)
+    pk._FLASH_PLANS.clear()
+    _assert_same_values_and_grads(args, ref, fl, g)
+    plan = pk.flash_plans()[f"{h}x{t}x{d}/{d} float32 g{g} causal "
+                            f"window {w}"]
+    assert plan["fwd"]["calls"] == fwd_calls
+    assert plan["fwd"]["causal_calls"] == 3
+    assert plan["dq"]["calls"] == plan["dkv"]["calls"] == bwd_calls
+    assert plan["dq"]["causal_calls"] == 10
+
+
+@pytest.mark.parametrize("w", [512, 513, 4096])
+def test_a_window_of_all_the_rows_is_plain_causal_to_the_last_bit(w):
+    """W >= T: the call is the causal call, kernels, plan and all."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    q, k, v = _qkv(13, 1, 7, 7, 512, 32, 32)
+
+    def both(window):
+        fn = lambda q, k, v: pk.flash_attention(             # noqa: E731
+            q, k, v, True, 128, 128, True, None, window)
+        return (fn(q, k, v),) + jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2))(q, k, v)
+
+    pk._FLASH_PLANS.clear()
+    for a, b_ in zip(both(w), both(0)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+    assert list(pk.flash_plans()) == ["7x512x32/32 float32 g7 causal"]
+    with pytest.raises(ValueError, match="causal"):
+        pk.flash_attention(q, k, v, False, 128, 128, True, None, 64)
+
+
+def test_window_spans_count_what_a_brute_force_counts():
+    """`_window_span` / `_masked_tiles` / `_chunk_pairs` against a walk
+    over every (row, column): a tile is visited iff it holds a visible
+    score, masked iff it also holds a hidden one; at the cell's shape
+    (16,384 rows as 2 chunks of 8,192, W 4,096, tiles 512 x 512) 3 pairs
+    of 3 and 252 tiles of the causal triangle's 528 a head; at chunks of
+    W the pairs two apart are dropped."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+
+    def brute(t, bq, bk, w, off):
+        r = np.arange(t)[:, None] + off
+        c = np.arange(t)[None, :]
+        seen = (c <= r) & (c > r - w)
+        tiles = seen.reshape(t // bq, bq, t // bk, bk)
+        some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+        return int((some & ~every).sum()), int(some.sum())
+
+    for t, bq, bk in ((768, 128, 128), (768, 128, 384), (768, 384, 128),
+                      (768, 256, 256)):
+        for w in (1, 2, 100, 128, 129, 255, 256, 300, 511, 767):
+            for off in (0, t, 2 * t):
+                want = brute(t, bq, bk, w, off)
+                for kernel in ("fwd", "dq", "dkv"):
+                    assert pk._masked_tiles(kernel, t, bq, bk, off == 0, w,
+                                            off) == want, (
+                        kernel, t, bq, bk, w, off)
+    assert pk._chunk_pairs(2, True, 4096, 8192) == pk._chunk_pairs(2, True)
+    counts = [pk._masked_tiles("fwd", 8192, 512, 512, cz, 4096,
+                               (i - j) * 8192)
+              for i, j, cz in pk._chunk_pairs(2, True, 4096, 8192)]
+    assert counts == [(24, 108), (8, 36), (24, 108)]
+    assert sum(pk._masked_tiles("fwd", 8192, 512, 512, cz)[1]
+               for _, _, cz in pk._chunk_pairs(2, True)) == 528
+    # chunks of W: in the pair next to the diagonal row r sees the
+    # columns above r; the pairs further off hold nothing
+    assert pk._chunk_pairs(4, True, 4096, 4096) == [
+        (0, 0, True), (1, 0, False), (1, 1, True), (2, 1, False),
+        (2, 2, True), (3, 2, False), (3, 3, True)]
+    assert len(pk._chunk_pairs(4, True, 4097, 4096)) == 7
+    assert len(pk._chunk_pairs(4, True, 4098, 4096)) == 9
+    assert pk._chunk_pairs(2, True, 1, 256) == [(0, 0, True), (1, 1, True)]
+
+
 def test_flash_chunk_keeps_long_rows_inside_the_default_window():
     """No flash call asks for a VMEM window: rows whose blocks do not
     fit the default one are cut.  In bfloat16 operands (what both

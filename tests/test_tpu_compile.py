@@ -30,22 +30,32 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("b,h,hkv,t,d,dv,mxu,calls", [
-    (2, 32, 8, 4096, 64, 64, jnp.bfloat16, 3),      # lfm2 at 2 x 4,096
-    (1, 32, 8, 8192, 64, 64, jnp.bfloat16, 3),      # lfm2.train_packed8k
-    (2, 32, 32, 4096, 192, 128, jnp.bfloat16, 3),   # kanana2.train_packed4k
+@pytest.mark.parametrize("b,h,hkv,t,d,dv,mxu,calls,window", [
+    (2, 32, 8, 4096, 64, 64, jnp.bfloat16, 3, 0),   # lfm2 at 2 x 4,096
+    (1, 32, 8, 8192, 64, 64, jnp.bfloat16, 3, 0),   # lfm2.train_packed8k
+    (2, 32, 32, 4096, 192, 128, jnp.bfloat16, 3, 0),    # kanana2...4k
     # qwen3next.train_packed8k: 256-wide heads, g = 8; k and v of one
     # key/value head are 2 x 4 MiB resident, so 8,192 rows go as pairs
     # of chunks of 4,096 (3 calls a kernel), none with a window
-    (1, 16, 2, 8192, 256, 256, jnp.bfloat16, 9),
+    (1, 16, 2, 8192, 256, 256, jnp.bfloat16, 9, 0),
     # float32 operands (`_mha`'s exact mode) take twice the room: 8,192
     # rows go as pairs of chunks of 4,096, three forward and three for
     # each backward kernel
-    (1, 4, 4, 8192, 64, 64, None, 9),
-    (1, 4, 4, 2048, 128, 128, None, 3),
+    (1, 4, 4, 8192, 64, 64, None, 9, 0),
+    (1, 4, 4, 2048, 128, 128, None, 3, 0),
+    # smallthinker.train_packed16k: g = 7, 128-wide heads, 16,384 rows
+    # as two chunks of 8,192: 3 pairs a kernel in the global layer, and
+    # under the window of 4,096 keys (half a chunk) 3 pairs too, the
+    # off-diagonal one visited in an eighth of its tiles
+    (1, 28, 4, 16384, 128, 128, jnp.bfloat16, 9, 0),
+    (1, 28, 4, 16384, 128, 128, jnp.bfloat16, 9, 4096),
+    # a window of one chunk drops the pairs two chunks apart (float32
+    # operands: 4 chunks of 4,096, 7 of the 10 causal pairs a kernel)
+    (1, 28, 4, 16384, 128, 128, None, 21, 4096),
 ])
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
-                                                    t, d, dv, mxu, calls):
+                                                    t, d, dv, mxu, calls,
+                                                    window):
     """The three kernels at the tiles `_flash_tiles` picks lower for the
     v5e, and NO call carries a VMEM window of its own: XLA lays the
     buffers it keeps across a Mosaic call as if the call took the
@@ -58,7 +68,7 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
 
     def loss(q, k, v):
         return jnp.sum(pk.flash_attention(q, k, v, True, interpret=False,
-                                          mxu_dtype=mxu))
+                                          mxu_dtype=mxu, window=window))
 
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
               for s in ((b, h, t, d), (b, hkv, t, d), (b, hkv, t, dv))]
@@ -87,10 +97,53 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
     # the calls an attention takes
     plan = pk.flash_plans()[
         f"{b * h}x{t}x{d}/{dv} "
-        f"{jnp.dtype(mxu or jnp.float32).name} g{h // hkv} causal"]
+        f"{jnp.dtype(mxu or jnp.float32).name} g{h // hkv} causal"
+        f"{f' window {window}' if window else ''}"]
     assert sum(p["calls"] for p in plan.values()) == calls
     assert all(max(p["block_q"], p["block_k"]) > 128
                for p in plan.values())
+    if window and mxu is not None:
+        # the cell's windowed layers: 252 of the causal triangle's 528
+        # tiles of 512 x 512 a head, the first and the last of a q
+        # tile's 9 k tiles masked
+        assert all((p["block_q"], p["block_k"], p["causal_calls"],
+                    p["visited_tile_share"], p["masked_tile_share"])
+                   == (512, 512, 3, 0.4773, 0.2222) for p in plan.values())
+    elif window:
+        assert all(p["causal_calls"] == 10 for p in plan.values())
+
+
+@pytest.mark.parametrize("shape,plan", [
+    ("64x4096x192/128 bfloat16 g1 causal",          # kanana2
+     {"fwd": (512, 512, 1, 0.2222), "dq": (512, 512, 1, 0.2222),
+      "dkv": (512, 512, 1, 0.2222)}),
+    ("32x8192x64/64 bfloat16 g4 causal",            # lfm2
+     {"fwd": (512, 512, 1, 0.1176), "dq": (512, 512, 1, 0.1176),
+      "dkv": (512, 512, 1, 0.1176)}),
+    ("16x8192x256/256 bfloat16 g8 causal",          # qwen3next
+     {"fwd": (512, 512, 3, 0.1176), "dq": (256, 512, 3, 0.1176),
+      "dkv": (512, 256, 3, 0.1176)}),
+])
+def test_accepted_cells_flash_plans_are_what_they_were(shape, plan):
+    """`info.flash` of the three accepted language cells, letter for
+    letter as the parent of the PR that brought windows wrote them (tiles,
+    calls an attention, masked share; no new key): a call without a
+    window is planned, counted and lowered as before."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    head, dtype, g = shape.split()[:3]
+    bh, t, widths = head.split("x")
+    d, dv = (int(x) for x in widths.split("/"))
+    hkv = int(bh) // int(g[1:])
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+        (1, int(bh), int(t), d), (1, hkv, int(t), d), (1, hkv, int(t), dv)))
+    pk._FLASH_PLANS.pop(shape, None)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+        q, k, v, True, interpret=True, mxu_dtype=jnp.bfloat16)),
+        argnums=(0, 1, 2)), q, k, v)
+    assert pk.flash_plans()[shape] == {
+        kern: {"block_q": bq, "block_k": bk, "calls": calls,
+               "masked_tile_share": share}
+        for kern, (bq, bk, calls, share) in plan.items()}
 
 
 def test_gated_delta_rule_kernels_compile_for_v5e(one_chip):
