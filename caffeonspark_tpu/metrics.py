@@ -143,7 +143,12 @@ with dropless expert layers, `info.moe` (per layer shape: the layers,
 the k N assignments, the rows a pass, the passes and those an even
 router fills, the row tile, the operations a held row costs, the bytes
 of weight gradient the backward loop carries, added into once a pass
-that runs; `ops.layers.moe_plans`).  Where those layers return their
+that runs; `ops.layers.moe_plans`) and, for a net with
+`recompute_block`s, `info.recompute` (`blocks`: per block the values it
+keeps between its forward and its backward pass, by name, with their
+bytes; `bytes_a_step`: their sum; `keep_nothing`: the blocks whose
+layers name nothing; `ops.recompute.recompute_plans`).  Where the
+expert layers return their
 stats, the summary's `experts` says what they did over the last steps
 (`moe.passes_run`: `experts.passes_run`, a layer's mean and max of the
 passes that ran, beside `held_share`; read from the steps' outputs when

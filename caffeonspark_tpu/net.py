@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from .ops import layers as L
+from .ops.recompute import BLOCK_POLICY, block_trace
 from .proto.caffe import (LayerParameter, NetParameter, NetState,
                           NetStateRule, Phase, TopBlobType)
 
@@ -299,7 +300,6 @@ class Net:
         # fraction of the recompute tax, since the expensive MXU ops
         # never re-run
         if remat is None:
-            import os
             remat = os.environ.get("COS_REMAT", "")
         if isinstance(remat, str):
             # env values and string args share one mapping; an unknown
@@ -489,9 +489,12 @@ class Net:
     def _recompute_blocks(self) -> Dict[str, dict]:
         """`recompute_block: "<name>"` on consecutive layers makes them
         one block: in a TRAIN pass `apply` runs it under one
-        jax.checkpoint, so only the blobs that enter it are kept for
-        the backward pass and everything inside is computed again
-        there.  -> {first layer's name: {layers, inputs, exports}}."""
+        jax.checkpoint, so the blobs that enter it are kept for the
+        backward pass and what is inside is computed again there, all
+        but the few values its layers name as they make them (a Mosaic
+        forward kernel's outputs, the router's result:
+        `ops/recompute.py`).  -> {first layer's name: {layers, inputs,
+        exports}}."""
         blocks: Dict[str, dict] = {}
         seen: set = set()
         run: List[LayerParameter] = []
@@ -857,13 +860,16 @@ class Net:
 
             def block_fn(bparams, ins, blk=blk):
                 local, merged = dict(ins), {**params, **bparams}
-                for blp in blk["layers"]:
-                    run_layer(blp, local, merged)
+                with block_trace(blk["layers"][0].recompute_block):
+                    for blp in blk["layers"]:
+                        run_layer(blp, local, merged)
                 return {n: local[n] for n in blk["exports"]}
 
             own = {blp.name: params[blp.name] for blp in blk["layers"]
                    if blp.name in self.param_layout}
-            blobs.update(jax.checkpoint(block_fn)(
+            # computed again in the backward pass, all but what the
+            # layers named as they made it (`ops/recompute.py`)
+            blobs.update(jax.checkpoint(block_fn, policy=BLOCK_POLICY)(
                 own, {n: blobs[n] for n in blk["inputs"]}))
             skip.update(blp.name for blp in blk["layers"])
         return blobs, ctx.state_out

@@ -38,6 +38,7 @@ from jax import lax
 
 from ..proto.caffe import (EltwiseOp, FillerParameter, LayerParameter,
                            NormalizationMode, NormRegion, PoolMethod)
+from .recompute import keep
 
 Array = jax.Array
 
@@ -2328,10 +2329,15 @@ def _moe_dropless(ctx, lp, params, bottoms):
     n = xf.shape[0]
     routed_from = bottoms[1].reshape(-1, d) if len(bottoms) > 1 else xf
 
+    # what a recompute_block keeps of the routing (`recompute.KEPT`):
+    # the values the router's own backward and the expert loops read, so
+    # that the recomputation has no reader for the product at HIGHEST,
+    # top_k or the argsort
     with jax.named_scope("moe.route"):
-        logits = jnp.matmul(routed_from.astype(jnp.float32),
-                            pd["router"].astype(jnp.float32),
-                            precision=lax.Precision.HIGHEST)
+        logits = keep(jnp.matmul(routed_from.astype(jnp.float32),
+                                 pd["router"].astype(jnp.float32),
+                                 precision=lax.Precision.HIGHEST),
+                      "moe.logits")
         if mp.scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits)
         elif mp.scoring == "softmax":
@@ -2342,14 +2348,15 @@ def _moe_dropless(ctx, lp, params, bottoms):
         if "bias" in pd:
             sel = scores + lax.stop_gradient(
                 pd["bias"].astype(jnp.float32))[None, :]
-        _, topi = lax.top_k(sel, k)                          # (N, k)
+        topi = keep(lax.top_k(sel, k)[1], "moe.topi")        # (N, k)
         topv = jnp.take_along_axis(scores, topi, axis=1)
         if k > 1:
             total_s = jnp.sum(topv, axis=-1, keepdims=True)
             if float(mp.norm_epsilon):
                 total_s = total_s + float(mp.norm_epsilon)
             topv = topv / total_s
-        gates = (topv * float(mp.routed_scaling_factor)).reshape(-1)
+        gates = keep((topv * float(mp.routed_scaling_factor)).reshape(
+            -1).astype(xf.dtype), "moe.gates")
         with jax.named_scope("moe.sort"):
             # token-major flattening: assignment a belongs to token
             # a // k
@@ -2360,12 +2367,13 @@ def _moe_dropless(ctx, lp, params, bottoms):
             counts = jnp.zeros((held + 1,),
                                jnp.int32).at[group].add(1)[:held]
             ends = jnp.cumsum(counts)
-            starts = ends - counts
-            total = ends[-1]
+            starts = keep(ends - counts, "moe.starts")
+            ends = keep(ends, "moe.ends")
+            total = keep(ends[-1], "moe.total")
 
     rows = _moe_chunk_rows(n, k, held, e)
     n_pass = -(-(k * n) // rows)
-    order = jnp.pad(order, (0, n_pass * rows - k * n))
+    order = keep(jnp.pad(order, (0, n_pass * rows - k * n)), "moe.order")
     prec = ctx.precision()
     w_in = (pd["W_gate"], pd["W_up"]) if gated else (pd["W1"],)
     w_out = pd["W_down"] if gated else pd["W2"]
@@ -2386,9 +2394,8 @@ def _moe_dropless(ctx, lp, params, bottoms):
         plan["layers"].append(lp.name)
 
     with jax.named_scope("moe.experts"):
-        routed = _moe_passes(xf, gates.astype(xf.dtype), w_in, w_out, order,
-                             starts, ends, total, rows, n_pass, k, gated,
-                             prec)
+        routed = _moe_passes(xf, gates, w_in, w_out, order, starts, ends,
+                             total, rows, n_pass, k, gated, prec)
 
     out = routed
     if "S_gate" in pd:

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .recompute import keep
+
 TILE = 512  # spatial lanes per block (4 × 128)
 
 
@@ -1084,12 +1086,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     tiles that hold a visible score and mask the ones on either edge
     (`_window_span`), pair of chunks by pair of chunks; 0, or a window
     of t rows or more, is the causal mask alone."""
-    b, h, t, d = q.shape
-    sm_scale = 1.0 / math.sqrt(d)
-    qf, kf, vf = _flash_flatten(q, k, v)
-    out, _ = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
-                             block_k, interpret, mxu_dtype, window)
-    return out.reshape(b, h, t, v.shape[-1])
+    return _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
+                          mxu_dtype, window)[0]
 
 
 def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
@@ -1099,6 +1097,10 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
     qf, kf, vf = _flash_flatten(q, k, v)
     out, lse = _flash_fwd_call(qf, kf, vf, sm_scale, causal, block_q,
                                block_k, interpret, mxu_dtype, window)
+    # a recompute_block keeps these two (the named values themselves go
+    # to the backward: a sibling would be kept AND the kernel run again);
+    # q, k, v are computed again
+    out, lse = keep(out, "flash.out"), keep(lse, "flash.lse")
     return out.reshape(b, h, t, v.shape[-1]), (qf, kf, vf, out, lse)
 
 
@@ -1773,6 +1775,9 @@ def _gdn_rule_fwd(q, k, v, gam, beta, c, steps, group_steps, interpret):
         jnp.zeros((1,), jnp.int32), q, k, v, gam, beta, s0, c=c,
         steps=steps, count=full // (steps * c), group_steps=group_steps,
         interpret=interpret)
+    # what a recompute_block keeps of the rule: the gated norm's
+    # backward reads o, the groups' recomputation starts from the edges
+    o, edges = keep(o, "gdn.o"), keep(edges, "gdn.edges")
     return o, (q, k, v, gam, beta, edges)
 
 

@@ -612,14 +612,19 @@ class CaffeProcessor:
         per dropless expert-layer shape the rows a pass, the passes,
         the row tile, the operations a held row costs and the bytes of
         weight gradient the backward loop carries, added into once a
-        pass that runs (`layers.moe_plans`).
+        pass that runs (`layers.moe_plans`); `info.recompute`: per
+        `recompute_block` the values it keeps for its backward pass and
+        their bytes, the bytes a step, the blocks that keep nothing
+        (`recompute.recompute_plans`).
         Static facts, nothing a step on the device."""
         from .ops.layers import gdn_plans, moe_plans
         from .ops.pallas_kernels import flash_plans
+        from .ops.recompute import recompute_plans
         for key, what, plans in (
                 ("flash", "flash attention", flash_plans()),
                 ("gdn", "gated delta rule", gdn_plans()),
-                ("moe", "expert layers", moe_plans())):
+                ("moe", "expert layers", moe_plans()),
+                ("recompute", "recompute blocks", recompute_plans())):
             if plans:
                 self.metrics.set_info(key, plans)
                 _LOG.info("%s as lowered: %s", what, plans)

@@ -129,20 +129,27 @@ def test_a_pass_is_skipped_in_the_traced_step(stacks):
         == (32, 3, 1)
 
 
-def test_sort_lies_inside_route_in_forward_and_recomputation(stacks):
+def test_sort_lies_inside_route_and_is_not_computed_again(stacks):
     names, _ = stacks
-    for ph in ("forward", "recomputation"):
-        got = under(names, "moe.sort", ph)
-        assert got, ph
-        assert all("/moe.route/moe.sort/" in n for n in got)
-        # the argsort and the counts' scatter-add are the scope's, the
-        # router's product and top_k are not
-        assert any(n.endswith("jit(argsort)/sort") for n in got)
-        assert any(n.endswith("moe.sort/scatter-add") for n in got)
-        route = under(names, "moe.route", ph)
-        for prim in ("dot_general", "top_k"):
-            at = [n for n in route if n.endswith("/" + prim)]
-            assert at and not any("moe.sort" in n for n in at), prim
+    got = under(names, "moe.sort", "forward")
+    assert got
+    assert all("/moe.route/moe.sort/" in n for n in got)
+    # the argsort and the counts' scatter-add are the scope's, the
+    # router's product and top_k are not
+    assert any(n.endswith("jit(argsort)/sort") for n in got)
+    assert any(n.endswith("moe.sort/scatter-add") for n in got)
+    route = under(names, "moe.route", "forward")
+    for prim in ("dot_general", "top_k"):
+        at = [n for n in route if n.endswith("/" + prim)]
+        assert at and not any("moe.sort" in n for n in at), prim
+    # the block keeps the router's result (`ops/recompute.py`): what it
+    # computes again of the routing is the scoring, from the kept
+    # logits; no sort, no top_k, no product
+    assert not under(names, "moe.sort", "recomputation")
+    again = under(names, "moe.route", "recomputation")
+    assert again
+    assert not [n for n in again
+                if n.endswith(("/dot_general", "/top_k", "/sort"))]
     # integers carry no gradient: the sort has no transpose
     assert not under(names, "moe.sort", "backward")
 
