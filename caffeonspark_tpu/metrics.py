@@ -147,7 +147,13 @@ that runs; `ops.layers.moe_plans`) and, for a net with
 `recompute_block`s, `info.recompute` (`blocks`: per block the values it
 keeps between its forward and its backward pass, by name, with their
 bytes; `bytes_a_step`: their sum; `keep_nothing`: the blocks whose
-layers name nothing; `ops.recompute.recompute_plans`).  Where the
+layers name nothing; `ops.recompute.recompute_plans`) and, for a net
+with Mamba layers, `info.ssm` (per scan shape: the form that ran, the
+chunk, the chunks a row, the channels a program, the VMEM a call takes,
+the kept edges' bytes; `ops.layers.ssm_plans`) and, where a
+recompute_block's blob is read by blocks further on than the next,
+`info.shared` (per blob: the layer that makes it, the layers that read
+it, its bytes; `Net.shared_blobs`).  Where the
 expert layers return their
 stats, the summary's `experts` says what they did over the last steps
 (`moe.passes_run`: `experts.passes_run`, a layer's mean and max of the

@@ -5,6 +5,8 @@ reference prototxts in /root/reference/data parse identically."""
 
 from __future__ import annotations
 
+import math
+
 from ..proto import NetParameter, parse_net_prototxt
 
 LENET = """
@@ -690,6 +692,170 @@ layer {{ name: "head.logits" type: "InnerProduct" bottom: "head.n"
     bias_term: false {gauss} }} }}
 layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
   bottom: "target_ids" top: "loss" softmax_param {{ axis: 2 }} }}
+"""
+    return parse_net_prototxt(t)
+
+
+def phi4flash_kinds(total_layers: int = 32):
+    """Published layer i's kind in a SambaY model of `total_layers`
+    (arXiv:2507.06607; Phi-4-mini-flash-reasoning: 32): the self-decoder
+    alternates Mamba (even) and sliding-window attention (odd) up to
+    layer N/2 - 1; layer N/2 is the Mamba whose scan output is the
+    memory, layer N/2 + 1 the full attention whose keys and values the
+    cross-decoder reads; from N/2 + 2 on, Gated Memory Units (even) and
+    cross-attention (odd)."""
+    half = total_layers // 2
+    return tuple(
+        ("mamba" if i < half else "mamba_memory" if i == half else "gmu")
+        if i % 2 == 0 else
+        ("window" if i < half else "full_kv" if i == half + 1 else "cross")
+        for i in range(total_layers))
+
+
+def phi4flash(vocab: int = 25008, hidden: int = 2560, heads: int = 40,
+              kv_heads: int = 20, head_dim: int = 64,
+              intermediate: int = 10240, d_inner: int = 5120,
+              d_state: int = 16, d_conv: int = 4, dt_rank: int = 160,
+              window: int = 512, total_layers: int = 32,
+              first_layer: int = 14, layers: int = 6, seq: int = 8192,
+              batch: int = 1, eps: float = 1e-5, init_std: float = 0.02,
+              lambda_std: float = 0.1, conv_bound: float = 0.5,
+              chunk: int = 64, tie: bool = True, recompute: bool = True,
+              blocks_a_layer: int = 2) -> NetParameter:
+    """microsoft/Phi-4-mini-flash-reasoning (`model_type: phi4flash`,
+    the SambaY decoder-hybrid-decoder of arXiv:2507.06607) as a pipeline
+    stage: pre-norm residual layers, `x + Mixer_i(LN(x))` then
+    `x + MLP(LN(x))` with a plain LayerNorm (scale and bias) and a dense
+    SiLU-gated feed-forward, whose mixer follows the published index i
+    (`phi4flash_kinds`): a Mamba selective scan, differential attention
+    under a window of `window` keys, the Mamba that also hands on its
+    scan output as the memory, the differential attention over the
+    whole past that also hands on its keys and values, a Gated Memory
+    Unit that gates the memory, or a cross-attention layer that has
+    `W_q` and `W_o` alone and reads those keys and values.  No layer
+    carries a position.  lambda_init of a differential layer is
+    0.8 - 0.6 exp(-0.3 i) by its PUBLISHED index.  The embedding and
+    the head are one blob (`param { name: "E" }`) unless `tie` is off.
+    The net is the published layers [`first_layer`, `first_layer` +
+    `layers`), named L0, L1, ...; a cut that holds a GMU or a cross
+    layer holds the layer that makes what it reads.  The defaults are
+    the published widths with the cut of `perfbench/configs/
+    phi4flash_mini.json` (published layers 14-19: every kind once, Mamba
+    and attention twice; an eighth of the vocabulary); `layers=32,
+    vocab=200064, first_layer=0` is the whole model.  Time-major (T, B)
+    int tops `input_ids` / `target_ids` (one row of 8,192 by default);
+    a layer's mixer half and its feed-forward half are a
+    `recompute_block` each (`blocks_a_layer` 1: one for both).  Every
+    matrix is filled gaussian `init_std`, the lambda vectors gaussian
+    `lambda_std`, the taps and their bias uniform +-`conv_bound`."""
+    kinds = phi4flash_kinds(total_layers)
+    if first_layer + layers > total_layers:
+        raise ValueError(f"phi4flash: layers [{first_layer}, "
+                         f"{first_layer + layers}) of {total_layers}")
+    run = kinds[first_layer:first_layer + layers]
+    if ("gmu" in run and "mamba_memory" not in run) or \
+            ("cross" in run and "full_kv" not in run):
+        raise ValueError(
+            f"phi4flash: layers [{first_layer}, {first_layer + layers}) "
+            "read a memory or keys and values that no layer of the cut "
+            "makes")
+    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    shared = 'param { name: "E" }' if tie else ""
+
+    def ip(name, bottom, top, n, tag, extra=""):
+        return f"""
+layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
+  {tag} {extra} inner_product_param {{ num_output: {n} axis: 2
+    bias_term: false {gauss} }} }}"""
+
+    t = f"""
+name: "Phi4Flash"
+layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
+  cos_data_param {{ batch_size: {batch}
+    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }}
+    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }} }} }}
+layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
+  {shared} embed_param {{ input_dim: {vocab} num_output: {hidden}
+    bias_term: false {gauss} }} }}
+"""
+    h = "h0"
+    memory = kv = None
+    for i, kind in enumerate(run):
+        p = f"L{i}"
+        published = first_layer + i
+        tag = mlp_tag = ""
+        if recompute:
+            tag = f'recompute_block: "{p}{".mix" * (blocks_a_layer > 1)}"'
+            mlp_tag = (f'recompute_block: "{p}.mlp"' if blocks_a_layer > 1
+                       else tag)
+        lam = 0.8 - 0.6 * math.exp(-0.3 * published)
+        attn = f"""num_heads: {heads} num_kv_heads: {kv_heads}
+    head_dim: {head_dim} causal: true differential: true
+    lambda_init: {lam:.8f} rms_norm_eps: {eps}
+    lambda_filler {{ type: "gaussian" std: {lambda_std} }} {gauss}"""
+        t += f"""
+layer {{ name: "{p}.norm1" type: "LayerNorm" bottom: "{h}" top: "{p}.n1"
+  {tag} layer_norm_param {{ eps: {eps} }} }}"""
+        if kind in ("mamba", "mamba_memory"):
+            tops = f'top: "{p}.a"'
+            if kind == "mamba_memory":
+                memory = f"{p}.memory"
+                tops += f' top: "{memory}"'
+            t += f"""
+layer {{ name: "{p}.mamba" type: "Mamba" bottom: "{p}.n1" {tops} {tag}
+  mamba_param {{ d_inner: {d_inner} d_state: {d_state} d_conv: {d_conv}
+    dt_rank: {dt_rank} chunk: {chunk} {gauss}
+    conv_filler {{ type: "uniform" min: {-conv_bound} max: {conv_bound} }}
+  }} }}"""
+        elif kind in ("window", "full_kv"):
+            tops, more = f'top: "{p}.a"', ""
+            if kind == "window":
+                more = f" window: {window}"
+            else:
+                kv = (f"{p}.k", f"{p}.v")
+                tops += f' top: "{kv[0]}" top: "{kv[1]}"'
+                more = " emit_kv: true"
+            t += f"""
+layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
+  {tops} {tag}
+  attention_param {{ {attn}{more} }} }}"""
+        elif kind == "gmu":
+            t += f"""
+layer {{ name: "{p}.gmu" type: "GatedMemoryUnit" bottom: "{p}.n1"
+  bottom: "{memory}" top: "{p}.a" {tag}
+  gated_memory_unit_param {{ {gauss} }} }}"""
+        else:
+            t += f"""
+layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
+  bottom: "{kv[0]}" bottom: "{kv[1]}" top: "{p}.a" {tag}
+  attention_param {{ {attn} shared_kv: true }} }}"""
+        t += f"""
+layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
+  top: "{p}.h1" {tag} }}
+layer {{ name: "{p}.norm2" type: "LayerNorm" bottom: "{p}.h1" top: "{p}.n2"
+  {mlp_tag} layer_norm_param {{ eps: {eps} }} }}"""
+        t += ip(f"{p}.gate", f"{p}.n2", f"{p}.g", intermediate, mlp_tag)
+        t += ip(f"{p}.up", f"{p}.n2", f"{p}.u", intermediate, mlp_tag)
+        t += f"""
+layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g"
+  {mlp_tag} }}
+layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
+  top: "{p}.gu" {mlp_tag} eltwise_param {{ operation: PROD }} }}"""
+        t += ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, mlp_tag)
+        t += f"""
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
+  top: "{p}.out" {mlp_tag} }}
+"""
+        h = f"{p}.out"
+    t += f"""
+layer {{ name: "head.norm" type: "LayerNorm" bottom: "{h}" top: "head.n"
+  layer_norm_param {{ eps: {eps} }} }}"""
+    t += ip("head.logits", "head.n", "logits", vocab, "", shared)
+    t += """
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
+  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
 """
     return parse_net_prototxt(t)
 

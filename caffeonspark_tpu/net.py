@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from .ops import layers as L
-from .ops.recompute import BLOCK_POLICY, block_trace
+from .ops.recompute import BLOCK_POLICY, block_trace, note_shared
 from .proto.caffe import (LayerParameter, NetParameter, NetState,
                           NetStateRule, Phase, TopBlobType)
 
@@ -418,6 +418,17 @@ class Net:
         blob_shapes: Dict[str, Tuple[int, ...]] = {
             name: tuple(shape) for name, shape, _ in self.input_specs}
         self.param_layout: Dict[str, List[Tuple[str, Tuple[int, ...], object]]] = {}
+        # named parameter sharing (`param { name: "E" }`, Caffe's own
+        # mechanism): the first layer that names a blob owns it, and it
+        # is that layer's entry of `param_layout` (one gradient, one
+        # optimizer state, one blob of a snapshot); a later layer that
+        # gives the same name reads the owner's.  `param_sources` is
+        # every parameterized layer's blobs in its op's order as
+        # (its name there, owning layer, blob, shape); `shared_params`
+        # {(reader, blob): (owner, blob)} the ones read from elsewhere
+        self.param_sources: Dict[str, List[Tuple[str, str, str, Tuple[int, ...]]]] = {}
+        self.shared_params: Dict[Tuple[str, str], Tuple[str, str]] = {}
+        named: Dict[str, Tuple[str, str, Tuple[int, ...]]] = {}
         self._top_shapes: Dict[str, Dict[str, Tuple[int, ...]]] = {}
         for lp in self.compute_layers:
             op = L.get_op(lp.type)
@@ -431,7 +442,26 @@ class Net:
             specs = [(n, tuple(int(x) for x in s), f)
                      for (n, s, f) in op.param_specs(lp, bshapes)]
             if specs:
-                self.param_layout[lp.name] = specs
+                sources, owned = [], []
+                for i, (bname, shape, filler) in enumerate(specs):
+                    share = lp.param[i].name if i < len(lp.param) else ""
+                    if share and share in named:
+                        oln, obn, oshape = named[share]
+                        if oshape != shape:
+                            raise ValueError(
+                                f"layer {lp.name!r}: blob {bname!r} "
+                                f"{shape} shares {share!r} with "
+                                f"{oln}/{obn} {oshape}")
+                        self.shared_params[lp.name, bname] = (oln, obn)
+                        sources.append((bname, oln, obn, shape))
+                        continue
+                    if share:
+                        named[share] = (lp.name, bname, shape)
+                    sources.append((bname, lp.name, bname, shape))
+                    owned.append((bname, shape, filler))
+                self.param_sources[lp.name] = sources
+                if owned:
+                    self.param_layout[lp.name] = owned
             # abstract evaluation for top shapes
             dummy_params = [jax.ShapeDtypeStruct(s, dtype)
                             for (_, s, _) in specs]
@@ -796,11 +826,8 @@ class Net:
             # output must cast back up entering its f32 consumer);
             # with no variants this reduces to the pre-autotune gate
             docast = cast or bool(self._variant_dtype)
-            lparams = []
-            if lp.name in self.param_layout:
-                pd = params[lp.name]
-                lparams = [pd[bname]
-                           for bname, _, _ in self.param_layout[lp.name]]
+            lparams = [params[ln][bname] for _, ln, bname, _ in
+                       self.param_sources.get(lp.name, ())]
             if lp.name in self.fused_bias_lrn:
                 # bias-fused stem LRN: the producing conv's bias rides
                 # in as params[0]; its gradient flows back to the conv
@@ -849,6 +876,8 @@ class Net:
 
         blocks = (self.recompute_blocks
                   if train and subset is None and not self.remat else {})
+        if blocks:
+            note_shared(self.shared_blobs())
         skip: set = set()
         for lp in compute:
             if lp.name in skip:
@@ -865,8 +894,8 @@ class Net:
                         run_layer(blp, local, merged)
                 return {n: local[n] for n in blk["exports"]}
 
-            own = {blp.name: params[blp.name] for blp in blk["layers"]
-                   if blp.name in self.param_layout}
+            own = {ln: params[ln] for blp in blk["layers"]
+                   for _, ln, _, _ in self.param_sources.get(blp.name, ())}
             # computed again in the backward pass, all but what the
             # layers named as they made it (`ops/recompute.py`)
             blobs.update(jax.checkpoint(block_fn, policy=BLOCK_POLICY)(
@@ -901,6 +930,39 @@ class Net:
                 continue   # side-channel keys (LSTM hidden, HDF5Output)
             for (bname, _, _), arr in zip(self.param_layout[lname], blobs):
                 out[lname][bname] = arr
+        return out
+
+    def layer_param_specs(self, lname: str
+                          ) -> List[Tuple[str, Tuple[int, ...]]]:
+        """(blob, shape) of every blob layer `lname` computes with, its
+        own and the ones it reads under a shared name, in its op's
+        order; [] for a layer without parameters."""
+        return [(name, shape) for name, _, _, shape in
+                self.param_sources.get(lname, ())]
+
+    def shared_blobs(self) -> Dict[str, dict]:
+        """The blobs one `recompute_block` makes and a block further on
+        than the next reads (another layer's keys and values, a scan's
+        output as memory): {blob: {"from": layer, "to": [layers],
+        "bytes": n}}.  They leave their block as `exports` and enter
+        every reader's as `inputs`: kept, not recomputed."""
+        order = list(self.recompute_blocks)
+        block_of = {lp.name: i for i, first in enumerate(order)
+                    for lp in self.recompute_blocks[first]["layers"]}
+        out: Dict[str, dict] = {}
+        for i, first in enumerate(order):
+            blk = self.recompute_blocks[first]
+            for blob in blk["exports"]:
+                readers = [lp.name for lp in self.compute_layers
+                           if blob in lp.bottom
+                           and block_of.get(lp.name, i + 1) > i + 1]
+                if readers:
+                    maker = next(lp.name for lp in blk["layers"]
+                                 if blob in lp.top)
+                    out[blob] = {
+                        "from": maker, "to": readers,
+                        "bytes": math.prod(self.blob_shapes[blob])
+                        * jnp.dtype(self.compute_dtype).itemsize}
         return out
 
     def stat_param_layers(self) -> List[str]:

@@ -2,8 +2,10 @@
 referenced by every `weight_filler`/`bias_filler` in data/*.prototxt).
 
 Supported types: constant, uniform, gaussian, xavier, msra, positive_unitball,
-bilinear, and log_uniform (the log of a uniform draw: GatedDeltaNet's
-A_log).  `xavier`/`msra` honor `variance_norm` (FAN_IN default).
+bilinear, log_uniform (the log of a uniform draw: GatedDeltaNet's
+A_log), log_arange (log(1..n) along the last axis, the same in every
+row: Mamba's A_log) and inv_softplus_log_uniform (the inverse softplus
+of a log-uniform draw in [min, max]: Mamba's dt bias).  `xavier`/`msra` honor `variance_norm` (FAN_IN default).
 """
 
 from __future__ import annotations
@@ -49,6 +51,15 @@ def fill(key: jax.Array, filler: FillerParameter, shape: Sequence[int],
     if t == "log_uniform":
         return jnp.log(jax.random.uniform(key, shape, dtype, filler.min,
                                           filler.max))
+    if t == "log_arange":
+        return jnp.broadcast_to(
+            jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)),
+            shape).astype(dtype)
+    if t == "inv_softplus_log_uniform":
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                        math.log(filler.min),
+                                        math.log(filler.max)))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
     if t == "gaussian":
         return (filler.mean
                 + filler.std * jax.random.normal(key, shape)).astype(dtype)

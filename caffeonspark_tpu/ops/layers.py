@@ -1568,6 +1568,14 @@ def _gqa_heads(ap):
             "key/value heads (num_heads must be a multiple of "
             "num_kv_heads, a rotary head_dim or rotary_dim even and "
             "inside the head)")
+    if ap.differential and (hkv % 2 or ap.output_gate):
+        raise ValueError(
+            f"GroupedQueryAttention: differential over {hkv} key/value "
+            "heads (they pair, so their number is even; no output gate)")
+    if ap.shared_kv and (ap.emit_kv or ap.qk_norm or ap.rotary):
+        raise ValueError(
+            "GroupedQueryAttention: shared_kv reads another layer's keys "
+            "and values as they are (no emit_kv, qk_norm or rotary)")
     return h, hkv, hd
 
 
@@ -1580,10 +1588,18 @@ def _gqa_params(lp, shapes):
     one = FillerParameter(type="constant", value=1.0)
     # with an output gate W_q gives a head's query and gate side by side
     qw = 2 * hd if ap.output_gate else hd
-    specs = [("W_q", (h * qw, d), wf), ("W_k", (hkv * hd, d), wf),
-             ("W_v", (hkv * hd, d), wf), ("W_o", (d, h * hd), wf)]
+    specs = [("W_q", (h * qw, d), wf)]
+    if not ap.shared_kv:
+        specs += [("W_k", (hkv * hd, d), wf), ("W_v", (hkv * hd, d), wf)]
+    specs.append(("W_o", (d, h * hd), wf))
     if ap.qk_norm:
         specs += [("q_norm", (hd,), one), ("k_norm", (hd,), one)]
+    if ap.differential:
+        lf = _filler(ap.lambda_filler if ap.has("lambda_filler") else None,
+                     "constant")
+        specs += [(n, (hd,), lf) for n in ("lambda_q1", "lambda_k1",
+                                           "lambda_q2", "lambda_k2")]
+        specs.append(("sub_norm", (2 * hd,), one))
     return specs
 
 
@@ -1602,26 +1618,45 @@ def _gqa(ctx, lp, params, bottoms):
             reading key/value head h // g;  y = o W_o
         output_gate: [q, gate] = x W_q, a head's 2 x head_dim side by
                  side; y = (o * sigmoid(gate)) W_o (qwen3_next)
+        differential: heads pair (phi4flash; `_differential`): key pair
+                 j = key heads 2j, 2j + 1, its value the two heads' side
+                 by side; query pairs g j .. g j + g - 1 read it, first
+                 queries at query heads 2 g j + r, second at 2 g j + g
+                 + r; o_p = map1 v - lambda map2 v, RMSNorm over its
+                 2 x head_dim, x (1 - lambda_init)
+        emit_kv: tops 1, 2 = k (B, H/g, T, head_dim) and v as the
+                 dispatch takes them; shared_kv: bottoms 1, 2 = another
+                 layer's, and W_q, W_o are the only matrices
 
     The attention itself is `_attention_dispatch`, the one the other
     two attention types take; products are as `LatentAttention`'s (one
     bfloat16 pass on the TPU at the default precision)."""
     ap = lp.attention_param
-    w_q, w_k, w_v, w_o = params[:4]
+    blobs = dict(zip((n for n, _, _ in _gqa_params(
+        lp, [bt.shape for bt in bottoms])), params))
+    w_q, w_o = blobs["W_q"], blobs["W_o"]
     x = bottoms[0]
     t, b = x.shape[0], x.shape[1]
     h, hkv, hd = _gqa_heads(ap)
     prec = ctx.precision()
     xf = x.reshape(t, b, -1)
+
+    def heads(w, n):
+        return jnp.einsum("tbd,ed->tbe", xf, w, precision=prec
+                          ).reshape(t, b, n, -1)
+
     with jax.named_scope("attn"):
-        q, k, v = (jnp.einsum("tbd,ed->tbe", xf, w, precision=prec
-                              ).reshape(t, b, n, -1)
-                   for w, n in ((w_q, h), (w_k, hkv), (w_v, hkv)))
+        q = heads(w_q, h)
+        if ap.shared_kv:
+            k, v = bottoms[1], bottoms[2]       # (B, Hkv, T, .) as emitted
+        else:
+            k, v = heads(blobs["W_k"], hkv), heads(blobs["W_v"], hkv)
         if ap.output_gate:
             q, gate = q[..., :hd], q[..., hd:].reshape(t, b, h * hd)
         if ap.qk_norm:
             eps = float(ap.rms_norm_eps)
-            q, k = rms_norm(q, params[4], eps), rms_norm(k, params[5], eps)
+            q = rms_norm(q, blobs["q_norm"], eps)
+            k = rms_norm(k, blobs["k_norm"], eps)
         if ap.rotary:
             theta, rd = float(ap.rope_theta), int(ap.rotary_dim)
             if rd and rd < hd:
@@ -1631,14 +1666,49 @@ def _gqa(ctx, lp, params, bottoms):
             else:
                 q, k = rope_adjacent(q, theta), rope_adjacent(k, theta)
         # (T, B, heads, hd) -> (B, heads, T, hd)
-        q, k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (q, k, v))
+        q = jnp.transpose(q, (1, 2, 0, 3))
+        if not ap.shared_kv:
+            k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (k, v))
+            if ap.differential:
+                # the value heads pair: both key heads of a pair read
+                # the pair's two values side by side, 2 x hd wide
+                v = v.reshape(b, hkv // 2, 1, 2, t, hd)
+                v = jnp.moveaxis(v, 3, 4).reshape(b, hkv // 2, 1, t, 2 * hd)
+                v = jnp.broadcast_to(
+                    v, (b, hkv // 2, 2, t, 2 * hd)).reshape(
+                        b, hkv, t, 2 * hd)
         o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
                                 mxu_dtype=_kernel_operand_dtype(prec, q),
                                 window=int(ap.window))
+        if ap.differential:
+            o = _differential(o, blobs, ap, h // hkv)
         o = jnp.transpose(o, (2, 0, 1, 3)).reshape(t, b, h * hd)
         if ap.output_gate:
             o = o * jax.nn.sigmoid(gate)
-        return [jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)]
+        y = jnp.einsum("tbe,de->tbd", o, w_o, precision=prec)
+        return [y, k, v] if ap.emit_kv else [y]
+
+
+def _differential(o, blobs, ap, g: int):
+    """The two maps of every head pair, subtracted and normed: o (B, H,
+    T, 2 hd), the dispatch's output, whose 2g heads a key pair are the
+    g first queries of its pairs and then their g second queries -> (B,
+    H/2, T, 2 hd), pair p's o1 - lambda o2 under an RMSNorm over its
+    2 hd, times 1 - lambda_init.  Scope `attn.diff`."""
+    b, h, t, w = o.shape
+    f32 = jnp.float32
+    with jax.named_scope("attn.diff"):
+        lam_init = float(ap.lambda_init)
+        lam = (jnp.exp(jnp.sum(blobs["lambda_q1"].astype(f32)
+                               * blobs["lambda_k1"].astype(f32)))
+               - jnp.exp(jnp.sum(blobs["lambda_q2"].astype(f32)
+                                 * blobs["lambda_k2"].astype(f32)))
+               + lam_init)
+        o = o.reshape(b, h // (2 * g), 2, g, t, w)
+        o = (o[:, :, 0] - lam.astype(o.dtype) * o[:, :, 1]).reshape(
+            b, h // 2, t, w)
+        return rms_norm(o, blobs["sub_norm"], float(ap.rms_norm_eps)) \
+            * (1.0 - lam_init)
 
 
 def _short_conv_params(lp, shapes):
@@ -2015,6 +2085,240 @@ def _gdn(ctx, lp, params, bottoms):
             o, qkvz[..., 2 * kw + vw:].reshape(t, b, hv, dv), norm)
         return [jnp.einsum("tbe,de->tbd", o.reshape(t, b, vw), w_out,
                            precision=prec)]
+
+
+def layer_norm(x, scale, bias, eps):
+    """(x - mean) / sqrt(var + eps) * scale + bias over the last axis,
+    statistics in float32 whatever the compute dtype."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    xc = x32 - mean
+    inv = lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
+    return (xc * inv).astype(x.dtype) * scale + bias
+
+
+def _layer_norm_params(lp, shapes):
+    np_ = lp.layer_norm_param
+    d = int(shapes[0][-1])
+    return [("scale", (d,), np_.scale_filler if np_.has("scale_filler")
+             else FillerParameter(type="constant", value=1.0)),
+            ("bias", (d,), np_.bias_filler if np_.has("bias_filler")
+             else FillerParameter(type="constant", value=0.0))]
+
+
+@register("LayerNorm", params=_layer_norm_params)
+def _layer_norm(ctx, lp, params, bottoms):
+    return [layer_norm(bottoms[0], params[0], params[1],
+                       float(lp.layer_norm_param.eps))]
+
+
+def _mamba_dims(mp, d):
+    di, n = int(mp.d_inner) or 2 * d, int(mp.d_state)
+    taps, rank = int(mp.d_conv), int(mp.dt_rank) or -(-d // 16)
+    if n < 1 or taps < 1 or int(mp.chunk) < 1:
+        raise ValueError(f"Mamba: state {n}, taps {taps}, chunk "
+                         f"{int(mp.chunk)}")
+    return di, n, taps, rank
+
+
+def _mamba_params(lp, shapes):
+    mp = lp.mamba_param
+    d = int(shapes[0][-1])
+    di, n, taps, rank = _mamba_dims(mp, d)
+    wf = _filler(mp.weight_filler if mp.has("weight_filler") else None,
+                 "xavier")
+    cf = _filler(mp.conv_filler if mp.has("conv_filler") else None,
+                 "xavier")
+    return [("W_in", (2 * di, d), wf), ("taps", (di, taps), cf),
+            ("conv_bias", (di,), cf), ("W_x", (rank + 2 * n, di), wf),
+            ("W_dt", (di, rank), wf),
+            ("dt_bias", (di,), FillerParameter(
+                type="inv_softplus_log_uniform", min=float(mp.dt_min),
+                max=float(mp.dt_max))),
+            ("A_log", (di, n), FillerParameter(type="log_arange")),
+            ("D", (di,), FillerParameter(type="constant", value=1.0)),
+            ("W_out", (d, di), wf)]
+
+
+# What was lowered, by operator shape: the form ("kernel" or "xla"), the
+# chunk, the chunks a row, the channels a program and the VMEM a call
+# takes.  Static, written while a program is traced; the -train job
+# puts it into its metrics as `info.ssm`.
+_SSM_PLANS: dict = {}
+
+
+def ssm_plans() -> dict:
+    return {k: dict(v) for k, v in _SSM_PLANS.items()}
+
+
+def selective_scan(u, dt, a, b, c, chunk: int = 64):
+    """The selective state-space recurrence over the sequence,
+
+        s_t[c, n] = exp(dt_t[c] A[c, n]) s_(t-1)[c, n] + dt_t[c] u_t[c] B_t[n]
+        y_t[c]    = sum_n s_t[c, n] C_t[n],      s_(-1) = 0
+
+    u, dt (B, T, C); a = A (C, N), < 0; b, c (B, T, N) -> y (B, T, C),
+    all float32 (the skip D u is the caller's).  No state crosses a
+    batch row.  What the forward pass keeps is y and the state at every
+    chunk's edge, nothing a token; the backward pass computes a chunk's
+    states again from there.
+
+    Two forms compute it, chosen by what can be observed here, no
+    option: the Mosaic kernels (`pallas_kernels.selective_scan_kernels`:
+    channels on lanes, the (N, channels) state in VMEM from a row's
+    first chunk to its last) on the TPU (`pallas_enabled()`; in
+    interpret mode under COS_FLASH_INTERPRET=1, the CPU suite's way in)
+    when the channels fill whole 128-lane tiles, the states whole
+    sublanes, float32 comes in and no mesh of several devices is
+    installed (a bare Mosaic call cannot be partitioned); else the XLA
+    form, `selective_scan_xla`, which is also what the kernels' tests
+    are held to.  `ssm_plans()` says which one a shape was lowered to."""
+    from .pallas_kernels import (pallas_enabled, selective_scan_kernels,
+                                 ssm_scan_plan)
+    bsz, t, ch = u.shape
+    n = a.shape[1]
+    interpret = _pallas_interpret()
+    plan = ssm_scan_plan(t, ch, n, int(chunk))
+    kernel = ((pallas_enabled() or interpret) and not _FLASH_MESH
+              and plan is not None
+              and all(x.dtype == jnp.float32 for x in (u, dt, a, b, c)))
+    length = plan["chunk"] if kernel else min(int(chunk), t)
+    _SSM_PLANS[f"{bsz}x{t} {ch} channels {n} states"] = dict(
+        {"form": "kernel" if kernel else "xla", "chunk": length,
+         "chunks_a_row": -(-t // length),
+         "edge_bytes": bsz * -(-t // length) * ch * n * 4},
+        **({"channels_a_program": plan["channels"],
+            "vmem_bytes": plan["vmem_bytes"]} if kernel else {}))
+    if kernel:
+        return selective_scan_kernels(u, dt, a, b, c, plan,
+                                      interpret=interpret)
+    return selective_scan_xla(u, dt, a, b, c, length)
+
+
+def _ssm_chunk(state, x, *, a):
+    """One chunk of the XLA form: state (B, C, N) before it, x = u, dt
+    (B, L, C), b, c (B, L, N) -> the state after it, y (B, L, C).  The
+    steps s <- decay s + write compose associatively, so a chunk is one
+    `lax.associative_scan` over its L steps."""
+    u, dt, b, c = x
+    decay = jnp.exp(dt[..., None] * a)                    # (B, L, C, N)
+    write = (dt * u)[..., None] * b[:, :, None, :]
+
+    def then(first, second):
+        return (first[0] * second[0], second[0] * first[1] + second[1])
+
+    decays, states = lax.associative_scan(then, (decay, write), axis=1)
+    states = states + decays * state[:, None]
+    return states[:, -1], jnp.sum(states * c[:, :, None, :], axis=-1)
+
+
+def selective_scan_xla(u, dt, a, b, c, chunk: int):
+    """`selective_scan` as plain XLA in float32: the fallback (CPU,
+    shapes that do not tile, a mesh) and the parity reference of the
+    kernels' tests.  The chunks go one at a time under a `lax.scan`
+    whose body is recomputed in the backward pass; T is padded to whole
+    chunks with steps that neither decay nor write (dt 0)."""
+    bsz, t, ch = u.shape
+    length = min(int(chunk), t)
+    n = -(-t // length)
+
+    def chunks(x):      # (B, T, w) -> (chunks, B, L, w)
+        x = jnp.pad(x, ((0, 0), (0, n * length - t), (0, 0)))
+        return jnp.moveaxis(x.reshape(bsz, n, length, x.shape[-1]), 1, 0)
+
+    _, y = lax.scan(
+        jax.checkpoint(functools.partial(_ssm_chunk, a=a)),
+        jnp.zeros((bsz, ch, a.shape[1]), jnp.float32),
+        tuple(chunks(x) for x in (u, dt, b, c)))
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, n * length, ch)[:, :t]
+
+
+@register("Mamba", params=_mamba_params)
+def _mamba(ctx, lp, params, bottoms):
+    """The selective state-space mixer (Mamba, arXiv:2312.00752) on
+    time-major (T, B, D) input:
+
+        [a, z] = x W_in;  u = silu(taps over time of a + conv_bias)
+        [r, B, C] = u W_x;  dt = softplus(r W_dt + dt_bias)
+        y = `selective_scan`(u, dt, -exp(A_log), B, C) + D u, float32
+        out = (y * silu(z)) W_out
+
+    A second top is y itself, before the gate: the memory that
+    `GatedMemoryUnit` layers further on read.  No state crosses a batch
+    column, and nothing marks a document's start inside a packed row
+    (the state and the taps reach over a boundary).  Scopes: `ssm`,
+    inside it `ssm.proj` (the four products), `ssm.conv` (taps, bias,
+    SiLU) and `ssm.scan` (softplus, the recurrence in whichever form
+    `selective_scan` lowers here, the skip)."""
+    mp = lp.mamba_param
+    (w_in, taps, conv_bias, w_x, w_dt, dt_bias, a_log, d_skip,
+     w_out) = params
+    x = bottoms[0]
+    di, n, _, rank = _mamba_dims(mp, x.shape[-1])
+    prec = ctx.precision()
+    f32 = jnp.float32
+
+    def conv(a, taps, bias):
+        return jax.nn.silu(causal_taps(a, taps) + bias.astype(a.dtype))
+
+    def rows(u, r, bc, dt_bias, a_log):
+        """-> u, dt (B, T, C), A (C, N), B, C (B, T, N), float32."""
+        u, r, bc = (jnp.swapaxes(v.astype(f32), 0, 1) for v in (u, r, bc))
+        return (u, jax.nn.softplus(r + dt_bias.astype(f32)),
+                -jnp.exp(a_log.astype(f32)), bc[..., :n], bc[..., n:])
+
+    def skip(y, u, d_skip):     # (B, T, C) -> (T, B, C), + D u
+        return (jnp.swapaxes(y, 0, 1)
+                + d_skip.astype(f32) * u.astype(f32)).astype(x.dtype)
+
+    # the elementwise passes between the products are computed again in
+    # the backward pass (`jax.checkpoint`), as `GatedDeltaNet`'s are
+    with jax.named_scope("ssm"):
+        with jax.named_scope("ssm.proj"):
+            az = jnp.einsum("tbd,ed->tbe", x, w_in, precision=prec)
+        with jax.named_scope("ssm.conv"):
+            u = jax.checkpoint(conv)(az[..., :di], taps, conv_bias)
+        with jax.named_scope("ssm.proj"):
+            rbc = jnp.einsum("tbe,re->tbr", u, w_x, precision=prec)
+            r = jnp.einsum("tbr,er->tbe", rbc[..., :rank], w_dt,
+                           precision=prec)
+        with jax.named_scope("ssm.scan"):
+            y = selective_scan(
+                *jax.checkpoint(rows)(u, r, rbc[..., rank:], dt_bias,
+                                      a_log), int(mp.chunk))
+            y = jax.checkpoint(skip)(y, u, d_skip)
+        with jax.named_scope("ssm.proj"):
+            out = jnp.einsum(
+                "tbe,de->tbd", y * jax.nn.silu(az[..., di:]), w_out,
+                precision=prec)
+        return [out, y][:max(1, len(lp.top))]
+
+
+def _gmu_params(lp, shapes):
+    gp = lp.gated_memory_unit_param
+    if len(shapes) != 2:
+        raise ValueError(f"GatedMemoryUnit {lp.name!r}: bottoms are the "
+                         "stream and the memory")
+    d, m = int(shapes[0][-1]), int(shapes[1][-1])
+    wf = _filler(gp.weight_filler if gp.has("weight_filler") else None,
+                 "xavier")
+    return [("W_in", (m, d), wf), ("W_out", (d, m), wf)]
+
+
+@register("GatedMemoryUnit", params=_gmu_params)
+def _gmu(ctx, lp, params, bottoms):
+    """The Gated Memory Unit (arXiv:2507.06607) on time-major input:
+    y = (m * silu(x W_in)) W_out, x (T, B, D) the normed stream, m (T,
+    B, M) the memory an earlier layer made (a `Mamba` layer's second
+    top).  Scope `gmu`."""
+    w_in, w_out = params
+    x, mem = bottoms
+    prec = ctx.precision()
+    with jax.named_scope("gmu"):
+        gate = jax.nn.silu(jnp.einsum("tbd,ed->tbe", x, w_in,
+                                      precision=prec))
+        return [jnp.einsum("tbe,de->tbd", mem.astype(gate.dtype) * gate,
+                           w_out, precision=prec)]
 
 
 def _moe_held(mp):

@@ -1859,3 +1859,245 @@ def gated_delta_rule_kernels(q, k, v, g, beta, chunk: int,
                   packed(gam), packed(beta), c, steps, group_steps,
                   interpret)
     return o.reshape(b, hk, r, full, dv)[..., :t, :]
+
+
+# ---------------------------------------------------------------------------
+# The selective state-space scan (Mamba), fwd + bwd kernels
+# ---------------------------------------------------------------------------
+# s_t = exp(dt_t A) s_(t-1) + dt_t u_t B_t,  y_t = sum_n s_t C_t: all
+# elementwise, nothing for the MXU.  Channels lie on lanes and the N
+# states of a channel on sublanes, so the state of `SSM_CHANNELS`
+# channels is N / 8 rows of vregs that stay in registers through a
+# chunk's steps and in a VMEM scratch from a row's first chunk to its
+# last.  The grid is (batch row, chunk, channel block), the channel
+# block innermost: B_t and C_t, which every channel reads, arrive
+# already laid over 128 lanes ((T, N, 128), made once outside) and are
+# fetched once a chunk, their block index unchanged while the channel
+# blocks go by.  The forward pass writes y and the state before every
+# chunk; the backward pass goes over the chunks last first, computes a
+# chunk's states again from its edge into a scratch and sweeps them in
+# reverse, dB and dC summed over the channel blocks in their resident
+# output block (their 128 lanes are summed outside).
+
+SSM_CHANNELS = 512        # lanes a program holds the state of
+SSM_UNROLL = 8            # steps of a chunk unrolled together
+
+
+def ssm_scan_plan(t: int, channels: int, states: int, chunk: int):
+    """{chunk, channels, vmem_bytes} the kernels take a scan of `t`
+    steps at, or None where they do not take it: the channels fill
+    whole 128-lane tiles, the states whole sublanes, the chunk whole
+    unrolled groups."""
+    if channels % 128 or states % 8 or chunk % SSM_UNROLL:
+        return None
+    block = next(c for c in (SSM_CHANNELS, 256, 128) if channels % c == 0)
+    rows = 2 * chunk * block * 4            # a (chunk, block) f32, twice
+    wide = 2 * chunk * states * 128 * 4     # a (chunk, N, 128) f32, twice
+    state = states * channels * 4
+    # the backward call, the larger: u, dt, dy in and du, ddt out; B, C
+    # in and dB, dC out; A and the edge in; the chunk's states; g and dA
+    vmem = (5 * rows + 4 * wide + 4 * states * block * 4
+            + (chunk + 1) * states * block * 4 + 3 * state)
+    if _flash_window(vmem) > _SCOPED_VMEM:
+        return None
+    return {"chunk": chunk, "channels": block, "vmem_bytes": vmem}
+
+
+def _over_lanes(x, width: int):
+    """(N, 128) -> (N, width): the same 128 lanes side by side."""
+    return x if width == 128 else jnp.concatenate([x] * (width // 128),
+                                                  axis=1)
+
+
+def _unrolled(chunk: int, step, carry):
+    """`step(i, carry)` for i in 0..chunk-1, `SSM_UNROLL` steps a loop
+    iteration (Mosaic unrolls a loop whole or not at all)."""
+    def group(k, carry):
+        for j in range(SSM_UNROLL):
+            carry = step(k * SSM_UNROLL + j, carry)
+        return carry
+    return jax.lax.fori_loop(0, chunk // SSM_UNROLL, group, carry)
+
+
+def _fold_lanes(x):
+    """(N, width) -> (N, 128): the 128-lane groups summed."""
+    return sum(x[:, i:i + 128] for i in range(0, x.shape[1], 128))
+
+
+def _ssm_fwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, edge_ref,
+                    s_ref, *, chunk: int):
+    j, cb = pl.program_id(1), pl.program_id(2)
+    width = u_ref.shape[-1]
+
+    @pl.when(j == 0)
+    def _():
+        s_ref[cb] = jnp.zeros(s_ref.shape[1:], jnp.float32)
+
+    a = a_ref[0]
+    s = s_ref[cb]
+    edge_ref[0, 0, 0] = s
+
+    def step(i, s):
+        dt = dt_ref[0, pl.ds(i, 1), :]                      # (1, width)
+        w = dt * u_ref[0, pl.ds(i, 1), :]
+        s = jnp.exp(dt * a) * s + w * _over_lanes(b_ref[0, i], width)
+        y_ref[0, pl.ds(i, 1), :] = jnp.sum(
+            s * _over_lanes(c_ref[0, i], width), axis=0, keepdims=True)
+        return s
+
+    s_ref[cb] = _unrolled(chunk, step, s)
+
+
+def _ssm_bwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, edge_ref, dy_ref,
+                    du_ref, ddt_ref, db_ref, dc_ref, da_ref,
+                    g_ref, st_ref, *, chunk: int):
+    j, cb = pl.program_id(1), pl.program_id(2)
+    width = u_ref.shape[-1]
+
+    @pl.when(j == 0)            # the row's last chunk
+    def _():
+        g_ref[cb] = jnp.zeros(g_ref.shape[1:], jnp.float32)
+        da_ref[0, cb] = jnp.zeros(da_ref.shape[2:], jnp.float32)
+
+    @pl.when(cb == 0)
+    def _():
+        db_ref[...] = jnp.zeros(db_ref.shape, jnp.float32)
+        dc_ref[...] = jnp.zeros(dc_ref.shape, jnp.float32)
+
+    a = a_ref[0]
+
+    def again(i, s):            # st[i] = the state before step i
+        st_ref[i] = s
+        dt = dt_ref[0, pl.ds(i, 1), :]
+        w = dt * u_ref[0, pl.ds(i, 1), :]
+        return jnp.exp(dt * a) * s + w * _over_lanes(b_ref[0, i], width)
+
+    st_ref[chunk] = _unrolled(chunk, again, edge_ref[0, 0, 0])
+
+    def step(k, carry):
+        g, da = carry           # dL/ds_i from the steps after i; dA
+        i = chunk - 1 - k
+        dt = dt_ref[0, pl.ds(i, 1), :]
+        u = u_ref[0, pl.ds(i, 1), :]
+        dy = dy_ref[0, pl.ds(i, 1), :]
+        b = _over_lanes(b_ref[0, i], width)
+        g = g + dy * _over_lanes(c_ref[0, i], width)
+        dc_ref[0, i] += _fold_lanes(dy * st_ref[i + 1])
+        db_ref[0, i] += _fold_lanes(g * (dt * u))
+        decay = jnp.exp(dt * a)
+        gs = g * st_ref[i] * decay          # dL/d(dt A), elementwise
+        gb = jnp.sum(g * b, axis=0, keepdims=True)
+        du_ref[0, pl.ds(i, 1), :] = dt * gb
+        ddt_ref[0, pl.ds(i, 1), :] = u * gb + jnp.sum(
+            gs * a, axis=0, keepdims=True)
+        return g * decay, da + gs * dt
+
+    g, da = _unrolled(chunk, step, (g_ref[cb], da_ref[0, cb]))
+    g_ref[cb] = g
+    da_ref[0, cb] = da
+
+
+def _ssm_call(kernel, name, operands, *, grid, in_specs, out_specs,
+              out_shape, scratch, interpret):
+    """One Mosaic call of the scan: the grid is (batch rows, chunks,
+    channel blocks), the last two in order with the states in a VMEM
+    scratch; no VMEM window is asked."""
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"))}
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
+        interpret=interpret, name=name, **params)(*operands)
+
+
+def _ssm_specs(chunk, block, n, blocks, at):
+    """Block specs of one grid step: a (B, T, C) array, a (B, T, N,
+    128) one, A as (blocks, N, block), the edges (B, chunks, blocks, N,
+    block), dA (B, blocks, N, block); `at(j)` is the chunk of step j."""
+    return (pl.BlockSpec((1, chunk, block), lambda b, j, c: (b, at(j), c)),
+            pl.BlockSpec((1, chunk, n, 128),
+                         lambda b, j, c: (b, at(j), 0, 0)),
+            pl.BlockSpec((1, n, block), lambda b, j, c: (c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, n, block),
+                         lambda b, j, c: (b, at(j), c, 0, 0)),
+            pl.BlockSpec((1, blocks, n, block),
+                         lambda b, j, c: (b, 0, 0, 0)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _ssm_scan(u, dt, bx, cx, a, chunk, block, interpret):
+    return _ssm_scan_fwd(u, dt, bx, cx, a, chunk, block, interpret)[0]
+
+
+def _ssm_scan_fwd(u, dt, bx, cx, a, chunk, block, interpret):
+    bsz, t, ch = u.shape
+    blocks, n = a.shape[0], a.shape[1]
+    chunks = t // chunk
+    rows, wide, aspec, edge, _ = _ssm_specs(chunk, block, n, blocks,
+                                            lambda j: j)
+    f32 = jnp.float32
+    y, edges = _ssm_call(
+        functools.partial(_ssm_fwd_kernel, chunk=chunk), "cos_ssm_fwd",
+        (u, dt, bx, cx, a), grid=(bsz, chunks, blocks),
+        in_specs=[rows, rows, wide, wide, aspec], out_specs=(rows, edge),
+        out_shape=(jax.ShapeDtypeStruct(u.shape, f32),
+                   jax.ShapeDtypeStruct((bsz, chunks, blocks, n, block),
+                                        f32)),
+        scratch=[(blocks, n, block)], interpret=interpret)
+    # what a recompute_block keeps of the scan: the gate's backward
+    # reads y, the chunks' recomputation starts from the edges
+    y, edges = keep(y, "ssm.y"), keep(edges, "ssm.edges")
+    return y, (u, dt, bx, cx, a, edges)
+
+
+def _ssm_scan_bwd(chunk, block, interpret, res, dy):
+    u, dt, bx, cx, a, edges = res
+    bsz, t, ch = u.shape
+    blocks, n = a.shape[0], a.shape[1]
+    chunks = t // chunk
+    rows, wide, aspec, edge, da = _ssm_specs(
+        chunk, block, n, blocks, lambda j: chunks - 1 - j)
+    f32 = jnp.float32
+    du, ddt, dbx, dcx, dax = _ssm_call(
+        functools.partial(_ssm_bwd_kernel, chunk=chunk), "cos_ssm_bwd",
+        (u, dt, bx, cx, a, edges, dy), grid=(bsz, chunks, blocks),
+        in_specs=[rows, rows, wide, wide, aspec, edge, rows],
+        out_specs=(rows, rows, wide, wide, da),
+        out_shape=(jax.ShapeDtypeStruct(u.shape, f32),
+                   jax.ShapeDtypeStruct(u.shape, f32),
+                   jax.ShapeDtypeStruct(bx.shape, f32),
+                   jax.ShapeDtypeStruct(cx.shape, f32),
+                   jax.ShapeDtypeStruct((bsz,) + a.shape, f32)),
+        scratch=[(blocks, n, block), (chunk + 1, n, block)],
+        interpret=interpret)
+    return du, ddt, dbx, dcx, jnp.sum(dax, axis=0)
+
+
+_ssm_scan.defvjp(_ssm_scan_fwd, _ssm_scan_bwd)
+
+
+def selective_scan_kernels(u, dt, a, b, c, plan: dict,
+                           interpret: bool = False):
+    """`ops.layers.selective_scan` through the kernels above: u, dt (B,
+    T, C), a (C, N), b, c (B, T, N) -> y (B, T, C), differentiable in
+    all five.  T is padded to whole chunks with steps that neither
+    decay nor write (dt 0); B and C are laid over 128 lanes and A is
+    turned channel block by channel block here, outside the kernels."""
+    bsz, t, ch = u.shape
+    n = a.shape[1]
+    chunk, block = plan["chunk"], plan["channels"]
+    full = -(-t // chunk) * chunk
+
+    def rows(x):
+        return x if full == t else jnp.pad(
+            x, ((0, 0), (0, full - t), (0, 0)))
+
+    def wide(x):        # (B, T, N) -> (B, T, N, 128)
+        return jnp.broadcast_to(rows(x)[..., None], (bsz, full, n, 128))
+
+    a_blocks = jnp.swapaxes(a.T.reshape(n, ch // block, block), 0, 1)
+    y = _ssm_scan(rows(u), rows(dt), wide(b), wide(c), a_blocks, chunk,
+                  block, interpret)
+    return y[:, :t]

@@ -25,6 +25,10 @@ KEPT = (
     # gated norm's backward) and the states at the groups' edges (where
     # the backward's recomputation of a group starts)
     "gdn.o", "gdn.edges",
+    # `pallas_kernels._ssm_scan`: cos_ssm_fwd's output (read by the
+    # gate's and the skip's backward) and the states at the chunks'
+    # edges (where the backward's recomputation of a chunk starts)
+    "ssm.y", "ssm.edges",
     # `layers._moe_dropless`, scope moe.route: what the router's own
     # backward reads (its product at HIGHEST, top_k's choice) ...
     "moe.logits", "moe.topi",
@@ -43,6 +47,10 @@ BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(*KEPT)
 # `info.recompute`.
 _BLOCKS: dict = {}
 _TRACING: list = []     # the entries of the blocks being traced
+# The blobs one block makes and blocks further on than the next read
+# (`Net.shared_blobs`), of the nets whose blocks this process traced:
+# the job's `info.shared`.
+_SHARED: dict = {}
 
 
 def keep(x: jax.Array, name: str) -> jax.Array:
@@ -66,6 +74,16 @@ def block_trace(tag: str):
         yield
     finally:
         _TRACING.pop()
+
+
+def note_shared(blobs: dict) -> None:
+    """A net whose blocks are being traced says which of their blobs
+    cross blocks."""
+    _SHARED.update(blobs)
+
+
+def shared_plans() -> dict:
+    return {k: dict(v) for k, v in _SHARED.items()}
 
 
 def recompute_plans() -> dict:

@@ -205,9 +205,13 @@ def tp_param_specs(net, *, min_features: int = TP_MIN_FEATURES
                 # the dropless dispatch's gated experts alike (router,
                 # selection bias and shared experts stay replicated)
                 spec = P("ep", None, None)
-            # the three attention types, ShortConv and GatedDeltaNet
-            # stay replicated: their head / channel splits are not
-            # written
+            # the three attention types (differential and shared-KV
+            # layers among them), ShortConv, GatedDeltaNet, Mamba,
+            # GatedMemoryUnit and LayerNorm stay replicated: their head
+            # / channel splits are not written.  A blob shared under a
+            # name (`param { name: .. }`, a tied embedding) is its
+            # owner's entry of `param_layout` and has that one spec;
+            # the layers that read it take it as laid out
             specs[lname][bname] = spec
     return specs
 

@@ -38,10 +38,11 @@ def shard_map_nocheck(fn, mesh, in_specs, out_specs):
                      out_specs=out_specs, check_vma=False)
 
 
-# layer types whose state runs along the whole sequence on one device:
-# their time axis cannot be cut over `sp` (no exchange of the state
-# between the shards is written)
-WHOLE_SEQUENCE_TYPES = ("GatedDeltaNet",)
+# layer types whose state runs along the whole sequence on one device
+# (or, a Gated Memory Unit, that read such a layer's output row for
+# row): their time axis cannot be cut over `sp` (no exchange of the
+# state between the shards is written)
+WHOLE_SEQUENCE_TYPES = ("GatedDeltaNet", "Mamba", "GatedMemoryUnit")
 
 
 def refuse_time_sharding(net) -> None:
@@ -56,6 +57,17 @@ def refuse_time_sharding(net) -> None:
             f"{len(bad) - 1} more): the chunked scan carries its state "
             "along the whole sequence on one device; use dp / ep / pp "
             "axes for this net")
+    bad = [lp.name for lp in net.compute_layers
+           if lp.has("attention_param") and (
+               lp.attention_param.differential
+               or lp.attention_param.shared_kv or lp.attention_param.emit_kv)]
+    if bad:
+        raise ValueError(
+            f"sequence parallelism (mesh axis sp > 1) is not written for "
+            f"a differential attention layer or one that shares its keys "
+            f"and values ({bad[0]!r} and {len(bad) - 1} more): the ring "
+            "rotates equal heads of one width and knows no second layer's "
+            "keys; use dp / ep / pp axes for this net")
     bad = [lp.name for lp in net.compute_layers
            if lp.has("attention_param") and lp.attention_param.window]
     if bad:

@@ -615,16 +615,25 @@ class CaffeProcessor:
         pass that runs (`layers.moe_plans`); `info.recompute`: per
         `recompute_block` the values it keeps for its backward pass and
         their bytes, the bytes a step, the blocks that keep nothing
-        (`recompute.recompute_plans`).
+        (`recompute.recompute_plans`); `info.ssm`: per selective-scan
+        shape the form that ran, the chunk, the chunks a row, the
+        channels a program, the VMEM a call takes and the kept edges'
+        bytes (`layers.ssm_plans`); `info.shared`: the blobs one
+        recompute_block makes and blocks further on than the next read
+        (another layer's keys and values, a scan's output as memory),
+        their readers and bytes (`Net.shared_blobs`, through
+        `recompute.shared_plans`).
         Static facts, nothing a step on the device."""
-        from .ops.layers import gdn_plans, moe_plans
+        from .ops.layers import gdn_plans, moe_plans, ssm_plans
         from .ops.pallas_kernels import flash_plans
-        from .ops.recompute import recompute_plans
+        from .ops.recompute import recompute_plans, shared_plans
         for key, what, plans in (
                 ("flash", "flash attention", flash_plans()),
                 ("gdn", "gated delta rule", gdn_plans()),
                 ("moe", "expert layers", moe_plans()),
-                ("recompute", "recompute blocks", recompute_plans())):
+                ("recompute", "recompute blocks", recompute_plans()),
+                ("ssm", "selective scans", ssm_plans()),
+                ("shared", "blobs shared across blocks", shared_plans())):
             if plans:
                 self.metrics.set_info(key, plans)
                 _LOG.info("%s as lowered: %s", what, plans)

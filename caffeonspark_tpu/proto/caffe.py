@@ -627,7 +627,18 @@ class AttentionParameter(Message):
     (with `causal`, any of the three types' dispatch, set on
     `GroupedQueryAttention`) lets row t see the `window` keys up to and
     with its own (t - window < s <= t): a sliding-window layer; a layer
-    without positions is `rotary: false`.  All
+    without positions is `rotary: false`.  `differential` (on
+    `GroupedQueryAttention`, arXiv:2410.05258) reads the `num_heads`
+    query heads and the `num_kv_heads` key heads as PAIRS (2p, 2p + 1)
+    and the value heads pairwise side by side, 2 x `head_dim` wide:
+    o_p = softmax(q_2p k^T) v - lambda softmax(q_2p+1 k'^T) v with
+    lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + `lambda_init` from four
+    `head_dim`-wide blobs, then an RMSNorm over the pair's 2 x
+    `head_dim` (one `sub_norm` scale, eps `rms_norm_eps`) times (1 -
+    `lambda_init`).  `emit_kv`: two more tops, the layer's keys and
+    values as the dispatch takes them; `shared_kv`: two more BOTTOMS,
+    another layer's keys and values, and then `W_q` and `W_o` are the
+    layer's only matrices.  All
     types share one attention dispatch (flash kernel on the TPU when
     the shape tiles, XLA einsums otherwise); GSPMD partitions the
     einsums over whatever mesh axes the activations carry."""
@@ -650,6 +661,13 @@ class AttentionParameter(Message):
         # with `causal`: row t sees the `window` keys t - window < s <= t
         # (its own among them) and no others; 0 = its whole past
         Field(16, "window", UINT32, default=0),
+        Field(17, "differential", BOOL, default=False),
+        Field(18, "lambda_init", FLOAT, default=0.8),
+        Field(19, "lambda_filler", MESSAGE, message=FillerParameter),
+        # tops 1, 2 = this layer's k (B, Hkv, T, head_dim) and v
+        Field(20, "emit_kv", BOOL, default=False),
+        # bottoms 1, 2 = another layer's k and v; no W_k, no W_v
+        Field(21, "shared_kv", BOOL, default=False),
     ]
 
 
@@ -705,6 +723,56 @@ class RMSNormParameter(Message):
     ]
 
 
+class LayerNormParameter(Message):
+    """Extension: y = (x - mean) / sqrt(var + eps) * scale + bias over
+    the last axis (biased variance), blobs `scale` (constant 1 unless
+    `scale_filler` says otherwise) and `bias` (0 unless `bias_filler`),
+    each the width of the last axis."""
+    FIELDS = [
+        Field(1, "eps", FLOAT, default=1e-5),
+        Field(2, "scale_filler", MESSAGE, message=FillerParameter),
+        Field(3, "bias_filler", MESSAGE, message=FillerParameter),
+    ]
+
+
+class MambaParameter(Message):
+    """Extension: the selective state-space mixer (`Mamba`,
+    arXiv:2312.00752) on time-major (T, B, D) input.  `[a, z] = x W_in`
+    (`d_inner` each); u = silu(taps over time of a + conv_bias), a
+    depthwise causal convolution of `d_conv` taps; `[r, B, C] = u W_x`
+    (`dt_rank`, `d_state`, `d_state`); dt = softplus(r W_dt + dt_bias);
+    A = -exp(A_log); per channel c and state n
+    s_t = exp(dt_t A) s_(t-1) + dt_t u_t B_t, y_t = sum_n s_t C_t + D u_t
+    over the sequence in chunks of `chunk` tokens, float32;
+    out = (y * silu(z)) W_out.  A second top, where the layer has one,
+    is y itself: the memory a `GatedMemoryUnit` further on reads.
+    Blobs `W_in` (2 d_inner, D), `taps` (d_inner, d_conv), `conv_bias`
+    (`conv_filler` fills both), `W_x`, `W_dt`, `dt_bias` (the inverse
+    softplus of a log-uniform draw in [`dt_min`, `dt_max`]), `A_log`
+    (log of 1..d_state in every channel), `D` (1), `W_out`."""
+    FIELDS = [
+        Field(1, "d_inner", UINT32, default=0),
+        Field(2, "d_state", UINT32, default=16),
+        Field(3, "d_conv", UINT32, default=4),
+        Field(4, "dt_rank", UINT32, default=0),
+        Field(5, "chunk", UINT32, default=64),
+        Field(6, "dt_min", FLOAT, default=1e-3),
+        Field(7, "dt_max", FLOAT, default=1e-1),
+        Field(8, "weight_filler", MESSAGE, message=FillerParameter),
+        Field(9, "conv_filler", MESSAGE, message=FillerParameter),
+    ]
+
+
+class GatedMemoryUnitParameter(Message):
+    """Extension: the Gated Memory Unit (`GatedMemoryUnit`,
+    arXiv:2507.06607) on time-major input: bottoms x (T, B, D) and a
+    memory m (T, B, M) that an earlier layer made;
+    y = (m * silu(x W_in)) W_out.  Blobs `W_in` (M, D), `W_out` (D, M)."""
+    FIELDS = [
+        Field(1, "weight_filler", MESSAGE, message=FillerParameter),
+    ]
+
+
 # ---------------------------------------------------------------------------
 # LayerParameter / NetParameter / SolverParameter
 # ---------------------------------------------------------------------------
@@ -731,6 +799,10 @@ class LayerParameter(Message):
         Field(153, "short_conv_param", MESSAGE, message=ShortConvParameter),
         Field(154, "gated_delta_net_param", MESSAGE,
               message=GatedDeltaNetParameter),
+        Field(155, "layer_norm_param", MESSAGE, message=LayerNormParameter),
+        Field(156, "mamba_param", MESSAGE, message=MambaParameter),
+        Field(157, "gated_memory_unit_param", MESSAGE,
+              message=GatedMemoryUnitParameter),
         # consecutive layers that give the same non-empty name form one
         # block whose activations are recomputed in the backward pass
         # (Net.apply: one jax.checkpoint around the block); COS_REMAT

@@ -188,11 +188,16 @@ class Solver:
         dc_m: Dict[str, Dict[str, float]] = {}
         net = self.train_net
         by_name = {lp.name: lp for lp in net.compute_layers}
-        for lname, specs in net.param_layout.items():
+        for lname in net.param_layout:
             lp = by_name[lname]
             lr_m[lname] = {}
             dc_m[lname] = {}
-            for i, (bname, _, _) in enumerate(specs):
+            # `param {}` i is the layer's blob i, one it reads under a
+            # shared name included: the owner's spec is the blob's
+            for i, (bname, owner, _, _) in enumerate(
+                    net.param_sources[lname]):
+                if owner != lname:
+                    continue
                 if i < len(lp.param):
                     ps = lp.param[i]
                     lr_m[lname][bname] = (ps.lr_mult

@@ -49,7 +49,9 @@ def layer_forward_flops(net) -> dict:
     accounting (scripts/roofline.py consumes this too)."""
     out: dict = {}
     for lp in net.compute_layers:
-        specs = net.param_layout.get(lp.name)
+        # every blob the layer computes with, one it reads under a
+        # shared name (a tied head) included
+        specs = [(n, ps, None) for n, ps in net.layer_param_specs(lp.name)]
         if not specs:
             continue
         tops = net._top_shapes[lp.name]
@@ -100,10 +102,27 @@ def layer_forward_flops(net) -> dict:
             ap = lp.attention_param
             total = 2 * t_s * b_s * sum(
                 prod(ps) for (_, ps, _) in specs if len(ps) == 2)
+            # a differential layer's values are two heads wide: the
+            # score head_dim, the weighted value 2 x head_dim a pair
+            wide = 3 if ap.differential else 2
             total += (2 * b_s * int(ap.num_heads)
                       * visible_scores(t_s, True, int(ap.window))
-                      * 2 * int(ap.head_dim))
+                      * wide * int(ap.head_dim))
             out[lp.name] = total
+            continue
+        if lp.type in ("Mamba", "GatedMemoryUnit"):
+            # every product per position; Mamba's recurrence as written
+            # besides: per token, channel and state the decay's product
+            # and its exponential, the write, the update and the read,
+            # 9 elementwise operations (vector-unit work, counted as
+            # operations, not as MXU work); taps, gates and softplus are
+            # not counted
+            n = prod(first_top[:-1])
+            out[lp.name] = 2 * n * sum(
+                prod(ps) for (nm, ps, _) in specs if nm.startswith("W_"))
+            if lp.type == "Mamba":
+                out[lp.name] += 9 * n * prod(dict(
+                    (nm, ps) for nm, ps, _ in specs)["A_log"])
             continue
         if lp.type == "ShortConv":
             # W_in and W_out per position; the taps and the two gates

@@ -645,8 +645,12 @@ def test_train_job_reports_flash_plans():
 
     from caffeonspark_tpu.ops import layers as L
     pk._FLASH_PLANS.clear()
-    L._GDN_PLANS.clear()        # `info.gdn` and `info.moe` ride the
-    L._MOE_PLANS.clear()        # same route
+    L._GDN_PLANS.clear()        # `info.gdn`, `info.moe`, `info.ssm`,
+    L._MOE_PLANS.clear()        # `info.recompute` and `info.shared`
+    L._SSM_PLANS.clear()        # ride the same route
+    from caffeonspark_tpu.ops import recompute
+    recompute._BLOCKS.clear()
+    recompute._SHARED.clear()
     CaffeProcessor._note_lowering_plans(Job)       # no attention: nothing
     assert "info" not in Job.metrics.summary()
     q, k, v = _qkv(8, 1, 4, 2, 256, 64, 64)

@@ -1,5 +1,6 @@
-"""The flash kernels at the real shapes of the benchmark's three language
-models, and the gated delta rule's kernels at qwen3next's, compiled for
+"""The flash kernels at the real shapes of the benchmark's language
+models, the gated delta rule's kernels at qwen3next's and the selective
+scan's at phi4flash's, compiled for
 a described (not attached) TPU v5e: what interpret
 mode cannot see — VMEM, tiling, the grouped block index maps.  PR 33
 found here, before any chip time, that a 64-wide head is padded to 128
@@ -52,6 +53,12 @@ def one_chip():
     # a window of one chunk drops the pairs two chunks apart (float32
     # operands: 4 chunks of 4,096, 7 of the 10 causal pairs a kernel)
     (1, 28, 4, 16384, 128, 128, None, 21, 4096),
+    # phi4flash.train_packed8k: differential attention, 40 query heads of
+    # 64 over 20 key heads of 64 with the values of a pair side by side,
+    # 128 wide; one chunk, whole past and under a window of 512 keys (one
+    # tile: every visited tile is masked)
+    (1, 40, 20, 8192, 64, 128, jnp.bfloat16, 3, 0),
+    (1, 40, 20, 8192, 64, 128, jnp.bfloat16, 3, 512),
 ])
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
                                                     t, d, dv, mxu, calls,
@@ -102,7 +109,11 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
     assert sum(p["calls"] for p in plan.values()) == calls
     assert all(max(p["block_q"], p["block_k"]) > 128
                for p in plan.values())
-    if window and mxu is not None:
+    if window == 512:
+        assert all((p["block_q"], p["block_k"], p["visited_tile_share"],
+                    p["masked_tile_share"]) == (512, 512, 0.2279, 1.0)
+                   for p in plan.values())
+    elif window and mxu is not None:
         # the cell's windowed layers: 252 of the causal triangle's 528
         # tiles of 512 x 512 a head, the first and the last of a q
         # tile's 9 k tiles masked
@@ -179,3 +190,42 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip):
     assert len(lines) == 3
     assert all('"scoped_memory_configs":[]' in line for line in lines)
     assert pk.gdn_rule_steps(t // c) == (4, 4, 128)     # 8 groups
+
+
+def test_selective_scan_kernels_compile_for_v5e(one_chip):
+    """The scan's forward and backward calls at `phi4flash.train_
+    packed8k`'s shape (1, 8,192, 5,120 channels, 16 states, chunk 64)
+    lower for the v5e: one call each over the row's 128 chunks and 10
+    channel blocks, neither with a VMEM window of its own (8.7 MB
+    counted for the backward call, the larger)."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    b, t, ch, n = 1, 8192, 5120, 16
+    plan = pk.ssm_scan_plan(t, ch, n, 64)
+    assert plan == {"chunk": 64, "channels": 512, "vmem_bytes": 8749056}
+    assert pk._flash_window(plan["vmem_bytes"]) <= pk._SCOPED_VMEM
+    # what does not tile falls to the XLA form
+    assert pk.ssm_scan_plan(t, 5000, n, 64) is None
+    assert pk.ssm_scan_plan(t, ch, 4, 64) is None
+    assert pk.ssm_scan_plan(t, ch, n, 1024) is None     # over the window
+
+    def loss(*a):
+        return jnp.sum(pk.selective_scan_kernels(*a, plan))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+              for s in ((b, t, ch), (b, t, ch), (ch, n), (b, t, n),
+                        (b, t, n))]
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grad).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert [o.shape for o in jax.eval_shape(grad, *shapes)] == [
+        s.shape for s in shapes]
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("cos_ssm_fwd", "cos_ssm_bwd"):
+        assert sum(name in line for line in lines) == 1, name
+    assert len(lines) == 2
+    assert all('"scoped_memory_configs":[]' in line for line in lines)

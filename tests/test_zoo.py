@@ -222,3 +222,35 @@ def test_alexnet_params_and_fusion(monkeypatch):
     assert not any(lp.name in ("relu_conv1", "relu_conv2")
                    for lp in fused.compute_layers)
     assert fused.blob_shapes["fc8"] == (8, 1000)
+
+
+def test_phi4flash_counts_kinds_and_round_trip():
+    """`zoo.phi4flash`: the cut of `perfbench/configs/phi4flash_mini.json`
+    and the whole model count what the published model does (3.85 B),
+    the layers' kinds follow the published index, the tied head owns no
+    blob, the net text round-trips, and a cut that reads a memory or
+    keys no layer of it makes is refused."""
+    from caffeonspark_tpu.models import zoo
+    from caffeonspark_tpu.proto import NetParameter
+    kinds = zoo.phi4flash_kinds(32)
+    assert kinds[14:20] == ("mamba", "window", "mamba_memory", "full_kv",
+                            "gmu", "cross")
+    assert [kinds.count(k) for k in ("mamba", "window", "gmu", "cross")] \
+        == [8, 8, 7, 7]
+    cut = zoo.phi4flash()
+    assert NetParameter.from_text(cut.to_text()) == cut
+    assert NetParameter.from_binary(cut.to_binary()) == cut
+    net = Net(cut, NetState(phase=Phase.TRAIN))
+    assert net.num_params() == 697_073_792
+    assert "head.logits" not in net.param_layout
+    assert len(net.recompute_blocks) == 12
+    assert set(net.shared_blobs()) == {"L2.memory", "L3.k", "L3.v"}
+    types = [lp.type for lp in net.compute_layers]
+    assert [types.count(t) for t in ("Mamba", "GatedMemoryUnit",
+                                     "GroupedQueryAttention", "LayerNorm")] \
+        == [2, 1, 3, 13]
+    whole = Net(zoo.phi4flash(vocab=200064, first_layer=0, layers=32,
+                              seq=128), NetState(phase=Phase.TRAIN))
+    assert whole.num_params() == 3_852_457_984
+    with pytest.raises(ValueError, match="no layer of the cut"):
+        zoo.phi4flash(first_layer=18, layers=2)
