@@ -147,7 +147,10 @@ that runs; `ops.layers.moe_plans`) and, for a net with
 `recompute_block`s, `info.recompute` (`blocks`: per block the values it
 keeps between its forward and its backward pass, by name, with their
 bytes; `bytes_a_step`: their sum; `keep_nothing`: the blocks whose
-layers name nothing; `ops.recompute.recompute_plans`) and, for a net
+layers name nothing; `stages_unwrapped`: per block the elementwise
+stages that ran in it without a checkpoint of their own, the block's
+recomputation being their second and last run;
+`ops.recompute.recompute_plans`) and, for a net
 with Mamba layers, `info.ssm` (per scan shape: the form that ran, the
 chunk, the chunks a row, the channels a program, the VMEM a call takes,
 the kept edges' bytes; `ops.layers.ssm_plans`) and, where a

@@ -38,7 +38,7 @@ from jax import lax
 
 from ..proto.caffe import (EltwiseOp, FillerParameter, LayerParameter,
                            NormalizationMode, NormRegion, PoolMethod)
-from .recompute import keep
+from .recompute import keep, stage
 
 Array = jax.Array
 
@@ -2070,16 +2070,21 @@ def _gdn(ctx, lp, params, bottoms):
                 * jax.nn.silu(z.astype(f32))).astype(x.dtype)
 
     # the elementwise passes between the products are computed again in
-    # the backward pass (`jax.checkpoint`), so that of a layer's 8,192-
-    # channel activations only the products' own outputs are kept
+    # the backward pass, so that of a layer's 8,192-channel activations
+    # only the products' own outputs are kept.  What feeds the rule is
+    # a `stage`: inside a recompute_block the block's recomputation is
+    # its second and last run.  The gate, which reads the rule's kept
+    # output, has its own checkpoint there too: bare, the v5e's
+    # compiler fuses it into W_out's backward, which at qwen3_next's
+    # shapes costs 1 ms a step more than the gate's third run
     with jax.named_scope("gdn"):
         qkvz = jnp.einsum("tbd,ed->tbe", x, w_qkvz, precision=prec)
         ba = jnp.einsum("tbd,ed->tbe", x, w_ba, precision=prec)
         with jax.named_scope("gdn.conv"):
-            qkv = jax.checkpoint(conv)(qkvz[..., :2 * kw + vw], taps)
+            qkv = stage(conv)(qkvz[..., :2 * kw + vw], taps)
         with jax.named_scope("gdn.scan"):
             o = gated_delta_rule(
-                *jax.checkpoint(heads)(qkv, ba, a_log, dt_bias),
+                *stage(heads)(qkv, ba, a_log, dt_bias),
                 int(gp.chunk))
         o = jax.checkpoint(gate)(
             o, qkvz[..., 2 * kw + vw:].reshape(t, b, hv, dv), norm)
@@ -2272,12 +2277,15 @@ def _mamba(ctx, lp, params, bottoms):
                 + d_skip.astype(f32) * u.astype(f32)).astype(x.dtype)
 
     # the elementwise passes between the products are computed again in
-    # the backward pass (`jax.checkpoint`), as `GatedDeltaNet`'s are
+    # the backward pass, as `GatedDeltaNet`'s are: the convolution is a
+    # `stage`, `rows` and `skip` have their own checkpoint inside a
+    # recompute_block too (bare, at phi4flash's shapes on the v5e, they
+    # cost 1.3 and 1.8 ms a step more than their third run)
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.proj"):
             az = jnp.einsum("tbd,ed->tbe", x, w_in, precision=prec)
         with jax.named_scope("ssm.conv"):
-            u = jax.checkpoint(conv)(az[..., :di], taps, conv_bias)
+            u = stage(conv)(az[..., :di], taps, conv_bias)
         with jax.named_scope("ssm.proj"):
             rbc = jnp.einsum("tbe,re->tbr", u, w_x, precision=prec)
             r = jnp.einsum("tbr,er->tbe", rbc[..., :rank], w_dt,

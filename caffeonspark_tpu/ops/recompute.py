@@ -8,6 +8,18 @@ kernel that its own backward reads, and the router's result.  A layer
 names such a value where it makes it (`keep`); the block keeps what was
 named.  Everywhere else (a TEST pass, a net without blocks,
 COS_REMAT=1) a name is an identity.
+
+A block computes nothing a third time.  A layer that would recompute an
+elementwise stage between its products by itself (`stage`: the Gated
+DeltaNet's and Mamba's convolution, the preparation of the delta rule's
+operands) does so only outside a block: there `stage` is
+`jax.checkpoint`.  Inside one the block's recomputation is the second
+and last time the stage runs, and its intermediates live from there to
+the stage's transpose; a checkpoint of its own would keep only its
+inputs from that second run and compute the stage once more before it
+could pull back.  (A stage that the compiler fuses worse bare than its
+third run costs, as those that read a kernel's kept output do, stays a
+plain `jax.checkpoint` at its call site.)
 """
 from __future__ import annotations
 
@@ -42,11 +54,13 @@ KEPT = (
 BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 # What the blocks traced by this process keep: {block: {name: bytes}},
-# a name's bytes summed over the block's layers.  Static, written while
-# a program is traced; the -train job puts it into its metrics as
-# `info.recompute`.
+# a name's bytes summed over the block's layers, and the stages that
+# ran in each without a checkpoint of their own: {block: count}.
+# Static, written while a program is traced; the -train job puts both
+# into its metrics as `info.recompute`.
 _BLOCKS: dict = {}
-_TRACING: list = []     # the entries of the blocks being traced
+_STAGES: dict = {}
+_TRACING: list = []     # the blocks being traced, by tag
 # The blobs one block makes and blocks further on than the next read
 # (`Net.shared_blobs`), of the nets whose blocks this process traced:
 # the job's `info.shared`.
@@ -59,17 +73,28 @@ def keep(x: jax.Array, name: str) -> jax.Array:
     if name not in KEPT:
         raise KeyError(f"{name!r} is not a value a recompute_block keeps")
     if _TRACING:
-        entry = _TRACING[-1]
+        entry = _BLOCKS[_TRACING[-1]]
         entry[name] = entry.get(name, 0) + x.size * x.dtype.itemsize
     return checkpoint_name(x, name)
+
+
+def stage(fn):
+    """`fn`, an elementwise stage of a layer whose intermediates are not
+    worth keeping from the forward pass to the backward pass: inside a
+    `recompute_block` itself (the block computes it again, once), under
+    a `jax.checkpoint` of its own anywhere else."""
+    if not _TRACING:
+        return jax.checkpoint(fn)
+    _STAGES[_TRACING[-1]] += 1
+    return fn
 
 
 @contextlib.contextmanager
 def block_trace(tag: str):
     """Around the trace of block `tag`'s layers: what they name is the
     block's entry (a later trace of the block writes it anew)."""
-    entry = _BLOCKS[tag] = {}
-    _TRACING.append(entry)
+    _BLOCKS[tag], _STAGES[tag] = {}, 0
+    _TRACING.append(tag)
     try:
         yield
     finally:
@@ -88,10 +113,13 @@ def shared_plans() -> dict:
 
 def recompute_plans() -> dict:
     """{"blocks": {block: {name: bytes}}, "bytes_a_step": their sum,
-    "keep_nothing": the blocks whose layers named nothing} for the
-    blocks traced by this process; {} without one."""
+    "keep_nothing": the blocks whose layers named nothing,
+    "stages_unwrapped": {block: the stages that ran in it without a
+    checkpoint of their own, where any did}} for the blocks traced by
+    this process; {} without one."""
     if not _BLOCKS:
         return {}
     return {"blocks": {t: dict(e) for t, e in _BLOCKS.items() if e},
             "bytes_a_step": sum(sum(e.values()) for e in _BLOCKS.values()),
-            "keep_nothing": [t for t, e in _BLOCKS.items() if not e]}
+            "keep_nothing": [t for t, e in _BLOCKS.items() if not e],
+            "stages_unwrapped": {t: n for t, n in _STAGES.items() if n}}

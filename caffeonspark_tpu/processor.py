@@ -614,7 +614,8 @@ class CaffeProcessor:
         weight gradient the backward loop carries, added into once a
         pass that runs (`layers.moe_plans`); `info.recompute`: per
         `recompute_block` the values it keeps for its backward pass and
-        their bytes, the bytes a step, the blocks that keep nothing
+        their bytes, the bytes a step, the blocks that keep nothing, the
+        stages a block that ran without a checkpoint of their own
         (`recompute.recompute_plans`); `info.ssm`: per selective-scan
         shape the form that ran, the chunk, the chunks a row, the
         channels a program, the VMEM a call takes and the kept edges'

@@ -4,12 +4,21 @@
 `ops/recompute.py`: the block is computed again in the backward pass,
 all but the values its layers name as they make them: a Mosaic forward
 kernel's outputs that its own backward reads (flash attention, the gated
-delta rule) and the router's result.  Held here, on the CPU with the
-kernels in interpret mode, a layer type a case: the recomputation holds
+delta rule, the selective scan) and the router's result.  Held here, on
+the CPU with the kernels in interpret mode, a layer type a case: the
+recomputation holds
 no forward kernel, `top_k`, sort or router product and the saved
 residuals are the list's names; values and gradients are a plain
 `jax.checkpoint`'s to the last bit; outside a block the names are
-identities; `info.recompute` counts the bytes the shapes give."""
+identities; `info.recompute` counts the bytes the shapes give.
+
+And a block computes nothing a third time: the elementwise stages that
+`GatedDeltaNet` and `Mamba` hand to `recompute.stage` (the convolution
+of both, the preparation of the delta rule's operands) carry no
+checkpoint of their own inside a block, where they run twice a step;
+outside one the layers' programs are what they were; values and
+gradients equal a build with the wrappers on to the last bit;
+`info.recompute` counts the stages a block."""
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +56,11 @@ def gdn_bytes(hk=1, r=2, dk=128, dv=128, c=64):
             "gdn.edges": B * hk * groups * dk * r * dv * 4}
 
 
+def ssm_bytes(ch=128, n=16, c=64):
+    c = pk.ssm_scan_plan(T, ch, n, c)["chunk"]
+    return {"ssm.y": B * T * ch * 4, "ssm.edges": B * -(-T // c) * ch * n * 4}
+
+
 def moe_bytes():
     rows = L._moe_chunk_rows(N, K, HELD, E)
     return {"moe.logits": N * E * 4, "moe.topi": N * K * 4,
@@ -70,6 +84,8 @@ CASES = {
     "gdn": ('type: "GatedDeltaNet" gated_delta_net_param { num_k_heads: 1 '
             'num_v_heads: 2 head_k_dim: 128 head_v_dim: 128 conv_taps: 4 '
             'chunk: 64 }', gdn_bytes, ("cos_gdn_fwd",)),
+    "mamba": ('type: "Mamba" mamba_param { d_inner: 128 d_state: 16 '
+              'd_conv: 4 dt_rank: 4 chunk: 64 }', ssm_bytes, ("cos_ssm_fwd",)),
     "moe_sigmoid": (MOE % ("sigmoid", "selection_bias: true "
                            "routed_scaling_factor: 2.5 "
                            "shared_hidden_dim: 12"),
@@ -82,6 +98,14 @@ CASES = {
               dict, ()),
 }
 KEEPING = [c for c in CASES if c != "dense"]
+# the layers with three stages each -> (the shape of the stages'
+# marker, the SiLU over the convolution's channels; the SiLUs of that
+# shape a gradient holds outside the stages: Mamba's gate on z, in the
+# forward pass and in the block's recomputation; how many of the three
+# run bare in a block: all but the Gated DeltaNet's gate, all but
+# Mamba's `rows` and `skip`, which keep a checkpoint of their own)
+STAGED = {"gdn": ((T, B, 2 * 128 + 2 * 128), 0, 2),
+          "mamba": ((T, B, 128), 2, 1)}
 
 
 def build(cases, tag=True, **net_kw):
@@ -111,6 +135,7 @@ def interpret(monkeypatch):
     """The kernels' route, in interpret mode; fresh counters."""
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
     monkeypatch.setattr(R, "_BLOCKS", {})
+    monkeypatch.setattr(R, "_STAGES", {})
 
 
 def plain(monkeypatch):
@@ -174,10 +199,10 @@ def handed_over(jaxpr):
     """What the one block's checkpoint reads in the backward pass that
     the forward pass made -> ({name: bytes} of the values that are a named value:
     as named, behind the full-precision `reduce_precision` jax puts on
-    a float residual, or handed through a jitted function that returns
-    its argument; the shapes of the others).  This is what
-    `jax.ad_checkpoint.print_saved_residuals` lists, less the arguments,
-    with each value traced to its name."""
+    a float residual, or handed through a jitted function or a layer's
+    own checkpoint that returns its argument; the shapes of the others).
+    This is what `jax.ad_checkpoint.print_saved_residuals` lists, less
+    the arguments, with each value traced to its name."""
     made = {v: e for e in jaxpr.eqns for v in e.outvars}
     remat = recomputations(jaxpr)[-1]
     named, other = {}, []
@@ -190,8 +215,9 @@ def handed_over(jaxpr):
             if e.primitive.name == "reduce_precision":
                 src = e.invars[0]
                 continue
-            if e.primitive.name in ("jit", "pjit"):
-                inner = e.params["jaxpr"].jaxpr
+            if e.primitive.name in ("jit", "pjit", "checkpoint", "remat2"):
+                inner = e.params["jaxpr"]
+                inner = getattr(inner, "jaxpr", inner)
                 out = inner.outvars[e.outvars.index(src)]
                 if out in inner.invars:
                     src = e.invars[inner.invars.index(out)]
@@ -243,6 +269,22 @@ def test_recomputation_holds_no_kept_forward(interpret, monkeypatch, case):
 
 # ----------------------------------------------------- (b) the values
 
+def assert_same_leaves(got, want, ulps=False):
+    """Two runs' losses, tops and gradients, leaf by leaf: to the last
+    bit, or with `ulps` every array within 1e-7 of its largest entry
+    (the scalars still to the last bit); not all zero."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    flat = jax.tree_util.tree_leaves_with_path(got)
+    assert any(np.abs(np.asarray(a)).max() > 0 for _, a in flat)
+    for (path, a), b in zip(flat, jax.tree.leaves(want)):
+        a, b, name = np.asarray(a), np.asarray(b), jax.tree_util.keystr(path)
+        if ulps and a.ndim:
+            np.testing.assert_allclose(a, b, rtol=0, err_msg=name,
+                                       atol=1e-7 * np.abs(b).max())
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
 @pytest.mark.parametrize("case", KEEPING)
 def test_values_equal_a_plain_checkpoint_bit_for_bit(interpret, monkeypatch,
                                                      case):
@@ -261,16 +303,24 @@ def test_values_equal_a_plain_checkpoint_bit_for_bit(interpret, monkeypatch,
     plain(monkeypatch)
     want, parent = run()
     assert program != parent
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    flat = jax.tree_util.tree_leaves_with_path(got)
-    assert any(np.abs(np.asarray(a)).max() > 0 for _, a in flat)
-    for (path, a), b in zip(flat, jax.tree.leaves(want)):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b),
-            err_msg=jax.tree_util.keystr(path))
+    assert_same_leaves(got, want)
 
 
 # ------------------------------------- (c) a name outside a block
+
+def gradient_program(net, train=True):
+    """The gradient of the net's loss in its parameters and its input,
+    traced anew at every call."""
+    kp, kx = jax.random.split(jax.random.key(0))
+    params = net.init(kp)
+    x = jax.random.normal(kx, (T, B, D), jnp.float32)
+
+    def loss(p, x):
+        return net.loss(p, {"x": x, "want": x}, train=train,
+                        rng=jax.random.key(1))[0]
+
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+
 
 def shape_of(jaxpr):
     """The program but for its naming identities: every equation's
@@ -291,19 +341,8 @@ def test_names_are_identities_outside_a_block(interpret, monkeypatch, where):
     net = build(cases, tag=where != "no_block")
     assert net.remat is (where == "COS_REMAT")
     assert bool(net.recompute_blocks) is (where != "no_block")
-    kp, kx = jax.random.split(jax.random.key(0))
-    params = net.init(kp)
-    x = jax.random.normal(kx, (T, B, D), jnp.float32)
-
-    def program():
-        def loss(p, x):
-            return net.loss(p, {"x": x, "want": x},
-                            train=where != "test_pass",
-                            rng=jax.random.key(1))[0]
-        return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params,
-                                                              x).jaxpr
-
-    named = program()
+    train = where != "test_pass"
+    named = gradient_program(net, train)
     names = [e.params["name"] for e in eqns(named)
              if e.primitive.name == "name"]
     assert {"flash.out", "gdn.edges", "moe.order"} <= set(names)
@@ -312,7 +351,7 @@ def test_names_are_identities_outside_a_block(interpret, monkeypatch, where):
     assert R.recompute_plans() == {}
     for mod in (L, pk):
         monkeypatch.setattr(mod, "keep", lambda x, name: x)
-    bare = program()
+    bare = gradient_program(net, train)
     assert not [e for e in eqns(bare) if e.primitive.name == "name"]
     assert shape_of(named) == shape_of(bare)
 
@@ -336,8 +375,10 @@ def test_keep_refuses_a_name_off_the_list():
 @pytest.mark.parametrize("case", list(CASES))
 def test_info_recompute_counts_the_bytes_the_shapes_give(interpret, case):
     """`info.recompute` after a traced gradient: the block's names with
-    the bytes reckoned from the shapes, their sum a step, and the block
-    that keeps nothing by name; a second trace counts nothing twice."""
+    the bytes reckoned from the shapes, their sum a step, the block
+    that keeps nothing by name, and the stages that ran in a block
+    without a checkpoint of their own; a second trace counts nothing
+    twice."""
     from caffeonspark_tpu.metrics import PipelineMetrics
     from caffeonspark_tpu.processor import CaffeProcessor
 
@@ -349,10 +390,119 @@ def test_info_recompute_counts_the_bytes_the_shapes_give(interpret, case):
         assert R.recompute_plans() == {
             "blocks": {case: want} if want else {},
             "bytes_a_step": sum(want.values()),
-            "keep_nothing": ["dense"]}
+            "keep_nothing": ["dense"],
+            "stages_unwrapped": ({case: STAGED[case][2]}
+                                 if case in STAGED else {})}
 
     class Job:
         metrics = PipelineMetrics()
 
     CaffeProcessor._note_lowering_plans(Job)
     assert Job.metrics.summary()["info"]["recompute"] == R.recompute_plans()
+
+
+# ------------------------- (e) a block computes nothing a third time
+
+def wrapped(monkeypatch):
+    """The parent's layers: every stage under its own `jax.checkpoint`,
+    inside a block too."""
+    monkeypatch.setattr(L, "stage", jax.checkpoint)
+
+
+def is_marker(e, case):
+    return (e.primitive.name == "logistic"
+            and e.outvars[0].aval.shape == STAGED[case][0])
+
+
+def times_run(jaxpr, case):
+    """How often the program computes the convolution stage's SiLU: the
+    `logistic` equations over its channels, wherever they stand."""
+    return sum(is_marker(e, case) for e in eqns(jaxpr)) - STAGED[case][1]
+
+
+def nested(jaxpr):
+    """The checkpoints inside the last checkpoint at the top (a
+    block's, in a net that has blocks)."""
+    return [e for e in eqns(recomputations(jaxpr)[-1].params["jaxpr"])
+            if e.primitive.name in ("checkpoint", "remat2")]
+
+
+@pytest.mark.parametrize("case", list(STAGED))
+def test_a_stage_runs_twice_inside_a_block_not_three_times(
+        interpret, monkeypatch, case):
+    """The gradient through one block holds, inside the block's
+    checkpoint, those of the stages that keep one and no other, and the
+    convolution's SiLU twice: the forward pass and the block's
+    recomputation.  With the wrappers on (the control) every stage's
+    checkpoint is nested there and the SiLU stands a third time, in the
+    inner checkpoint's own backward."""
+    bare = STAGED[case][2]
+    net = build([case])
+    jaxpr = gradient_program(net)
+    assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3 - bare
+    assert times_run(jaxpr, case) == 2
+    assert R.recompute_plans()["stages_unwrapped"] == {case: bare}
+
+    wrapped(monkeypatch)
+    jaxpr = gradient_program(net)
+    assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3
+    assert times_run(jaxpr, case) == 3
+    assert R.recompute_plans()["stages_unwrapped"] == {}
+
+
+@pytest.mark.parametrize("where", ["no_block", "test_pass", "COS_REMAT"])
+@pytest.mark.parametrize("case", list(STAGED))
+def test_outside_a_block_a_stage_keeps_its_own_checkpoint(
+        interpret, monkeypatch, case, where):
+    """A net without blocks, a TEST pass and COS_REMAT=1 trace to the
+    program of the layers with `jax.checkpoint` at the call sites: the
+    convolution stage's checkpoint is there, nothing is counted."""
+    if where == "COS_REMAT":
+        monkeypatch.setenv("COS_REMAT", "1")
+    net = build([case], tag=where != "no_block")
+    train = where != "test_pass"
+    jaxpr = gradient_program(net, train)
+    own = [e for e in eqns(jaxpr)
+           if e.primitive.name in ("checkpoint", "remat2")
+           and any(is_marker(s, case) for s in eqns(e.params["jaxpr"]))]
+    assert own and R.recompute_plans() == {}
+    wrapped(monkeypatch)
+    assert shape_of(gradient_program(net, train)) == shape_of(jaxpr)
+
+
+@pytest.mark.parametrize("how", ["op_by_op", "compiled"])
+@pytest.mark.parametrize("case", list(STAGED))
+def test_values_equal_the_wrapped_stages_bit_for_bit(interpret, monkeypatch,
+                                                     case, how):
+    """Loss, every top and every gradient of a block whose stages run
+    bare equal those of the same block with each stage under its own
+    checkpoint: the same arithmetic, once less.  To the last bit where
+    each primitive runs by itself; compiled, XLA fuses what the inner
+    checkpoints' barriers kept apart, and a sum over the rows (the taps'
+    gradient) may come out an ulp away."""
+    net = build([case])
+    loss, params, x = problem(net, seed=5)
+
+    def run():
+        fn = jax.value_and_grad(
+            lambda p, x: loss(p, x), argnums=(0, 1), has_aux=True)
+        if how == "compiled":
+            fn = jax.jit(fn)
+        return fn(params, x), jax.jit(fn).lower(params, x).as_text()
+
+    got, program = run()
+    wrapped(monkeypatch)
+    want, parent = run()
+    assert program != parent
+    assert_same_leaves(got, want, ulps=how == "compiled")
+
+
+def test_stages_are_counted_by_block(interpret):
+    """Two staged layers in blocks of their own beside a block without
+    one: each of the two with its stages, the third block not listed, a
+    second trace counting nothing twice."""
+    net = build(["gdn", "mamba", "dense"])
+    for _ in range(2):
+        gradient_program(net)
+        assert R.recompute_plans()["stages_unwrapped"] == {
+            c: STAGED[c][2] for c in STAGED}
