@@ -156,7 +156,11 @@ chunk, the chunks a row, the channels a program, the VMEM a call takes,
 the kept edges' bytes; `ops.layers.ssm_plans`) and, where a
 recompute_block's blob is read by blocks further on than the next,
 `info.shared` (per blob: the layer that makes it, the layers that read
-it, its bytes; `Net.shared_blobs`).  Where the
+it, its bytes; `Net.shared_blobs`) and, for a net with GatedDeltaNet
+or Mamba layers, `info.taps` (per shape of their convolution + SiLU
+stage: the form that ran, `kernel` or `xla`, the kernels' time and
+channel tiles, the layers that took it; `ops.layers.taps_plans`).
+Where the
 expert layers return their
 stats, the summary's `experts` says what they did over the last steps
 (`moe.passes_run`: `experts.passes_run`, a layer's mean and max of the

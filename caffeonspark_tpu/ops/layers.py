@@ -1730,12 +1730,78 @@ def _short_conv_params(lp, shapes):
 def causal_taps(z, taps):
     """Depthwise causal convolution over time (axis 0) of z (T, ..., D):
     taps (D, L); tap j multiplies the input at t - (L - 1) + j, zero
-    before t = 0.  Shifted slices of one padded array, which XLA fuses
-    with the elementwise passes around them."""
+    before t = 0.  Shifted slices of one padded array.  What XLA makes
+    of them on the v5e depends on the array's size, not on what stands
+    around them (PERF.md, section 7, PR 44): `short_conv_mix`'s 2,048
+    channels (67 MB a copy at T = 8,192) are staged in the chip's second
+    memory space and the shifted reads run at 690 GB/s; the 8,192 or
+    5,120 channels before a Gated DeltaNet's or Mamba's SiLU (268 / 168
+    MB) are copied out to HBM first and read back at 265 GB/s, which is
+    why `causal_taps_silu` has kernels of its own."""
     n_taps, t = taps.shape[1], z.shape[0]
     zp = jnp.pad(z, ((n_taps - 1, 0),) + ((0, 0),) * (z.ndim - 1))
     return sum(zp[j:j + t] * taps[:, j].astype(z.dtype)
                for j in range(n_taps))
+
+
+# What was lowered, by call shape: the form ("kernel" or "xla"), the
+# kernels' tiles, the layers that took it.  Static, written while a
+# program is traced; the -train job puts it into its metrics as
+# `info.taps`.
+_TAPS_PLANS: dict = {}
+
+
+def taps_plans() -> dict:
+    return {k: dict(v, sites=list(v["sites"]))
+            for k, v in _TAPS_PLANS.items()}
+
+
+def causal_taps_silu(z, taps, bias=None, *, site: str = ""):
+    """silu(`causal_taps`(z[..., :C], taps) [+ bias]) for time-major z
+    (T, B, W), taps (C, L), bias (C,): the convolution stage of the
+    Gated DeltaNet and Mamba layers, over the first C channels of the
+    product's W-wide output.
+
+    Two forms compute it, chosen by what can be observed here, no
+    option: the Mosaic kernels (`pallas_kernels.causal_taps_silu_
+    kernels`: one pass forward and one backward, each element read where
+    it lies in z) on the TPU (`pallas_enabled()`; in interpret mode under
+    COS_FLASH_INTERPRET=1, the CPU suite's way in) when float32 comes
+    in, C and W fill whole 128-lane tiles, T whole sublane groups, and no
+    mesh of several devices is installed (a bare Mosaic call cannot be
+    partitioned); else the XLA form, `causal_taps_silu_xla`, which is
+    also what the kernels' tests are held to.  `taps_plans()` says which
+    one a shape was lowered to, and for which layers (`site`)."""
+    from .pallas_kernels import (causal_taps_silu_kernels, pallas_enabled,
+                                 taps_plan)
+    t, b, w = z.shape
+    c, n = taps.shape
+    interpret = _pallas_interpret()
+    plan = taps_plan(t, c, w, n)
+    kernel = ((pallas_enabled() or interpret) and not _FLASH_MESH
+              and plan is not None
+              and all(a.dtype == jnp.float32 for a in (z, taps)
+                      + (() if bias is None else (bias,))))
+    entry = _TAPS_PLANS.setdefault(
+        f"{b}x{t} {c} of {w} channels {n} taps {z.dtype.name}"
+        f"{'' if bias is None else ' bias'}", {"sites": []})
+    entry.update({"form": "kernel", **plan} if kernel else {"form": "xla"})
+    if site and site not in entry["sites"]:
+        entry["sites"].append(site)
+    if kernel:
+        return causal_taps_silu_kernels(z, taps, bias, plan,
+                                        interpret=interpret)
+    return causal_taps_silu_xla(z, taps, bias)
+
+
+def causal_taps_silu_xla(z, taps, bias=None):
+    """`causal_taps_silu` as plain XLA: the fallback (CPU, shapes that
+    do not tile, a mesh) and the parity reference of the kernels'
+    tests."""
+    pre = causal_taps(z[..., :taps.shape[0]], taps)
+    if bias is not None:
+        pre = pre + bias.astype(pre.dtype)
+    return jax.nn.silu(pre)
 
 
 def short_conv_mix(b, c, u, taps, bias=None):
@@ -2028,7 +2094,9 @@ def _gdn(ctx, lp, params, bottoms):
 
     No state crosses a batch column, and nothing marks a document's
     start inside a packed row (the state and the taps reach over a
-    boundary).  Scopes: `gdn`, inside it `gdn.conv` (taps + SiLU) and
+    boundary).  Scopes: `gdn`, inside it `gdn.conv` (taps + SiLU,
+    `causal_taps_silu`: the Mosaic calls `cos_taps_fwd` / `cos_taps_bwd`
+    where the rule's kernels run, else the XLA form) and
     `gdn.scan` (decay, strengths, normalisation, and the rule in
     whichever form `gated_delta_rule` lowers here: the Mosaic kernels on
     the TPU at the family's shapes, forward, recomputation and backward
@@ -2043,8 +2111,7 @@ def _gdn(ctx, lp, params, bottoms):
     prec = ctx.precision()
     f32 = jnp.float32
 
-    def conv(a, taps):
-        return jax.nn.silu(causal_taps(a, taps))
+    conv = functools.partial(causal_taps_silu, site=lp.name)
 
     def heads(qkv, ba, a_log, dt_bias):
         """-> q, k (B, Hk, T, dk), v (B, Hk, R, T, dv), g, beta (B, Hk,
@@ -2081,7 +2148,7 @@ def _gdn(ctx, lp, params, bottoms):
         qkvz = jnp.einsum("tbd,ed->tbe", x, w_qkvz, precision=prec)
         ba = jnp.einsum("tbd,ed->tbe", x, w_ba, precision=prec)
         with jax.named_scope("gdn.conv"):
-            qkv = stage(conv)(qkvz[..., :2 * kw + vw], taps)
+            qkv = stage(conv)(qkvz, taps)
         with jax.named_scope("gdn.scan"):
             o = gated_delta_rule(
                 *stage(heads)(qkv, ba, a_log, dt_bias),
@@ -2253,7 +2320,8 @@ def _mamba(ctx, lp, params, bottoms):
     column, and nothing marks a document's start inside a packed row
     (the state and the taps reach over a boundary).  Scopes: `ssm`,
     inside it `ssm.proj` (the four products), `ssm.conv` (taps, bias,
-    SiLU) and `ssm.scan` (softplus, the recurrence in whichever form
+    SiLU: `causal_taps_silu`, in whichever form it lowers here) and
+    `ssm.scan` (softplus, the recurrence in whichever form
     `selective_scan` lowers here, the skip)."""
     mp = lp.mamba_param
     (w_in, taps, conv_bias, w_x, w_dt, dt_bias, a_log, d_skip,
@@ -2263,8 +2331,7 @@ def _mamba(ctx, lp, params, bottoms):
     prec = ctx.precision()
     f32 = jnp.float32
 
-    def conv(a, taps, bias):
-        return jax.nn.silu(causal_taps(a, taps) + bias.astype(a.dtype))
+    conv = functools.partial(causal_taps_silu, site=lp.name)
 
     def rows(u, r, bc, dt_bias, a_log):
         """-> u, dt (B, T, C), A (C, N), B, C (B, T, N), float32."""
@@ -2285,7 +2352,7 @@ def _mamba(ctx, lp, params, bottoms):
         with jax.named_scope("ssm.proj"):
             az = jnp.einsum("tbd,ed->tbe", x, w_in, precision=prec)
         with jax.named_scope("ssm.conv"):
-            u = stage(conv)(az[..., :di], taps, conv_bias)
+            u = stage(conv)(az, taps, conv_bias)
         with jax.named_scope("ssm.proj"):
             rbc = jnp.einsum("tbe,re->tbr", u, w_x, precision=prec)
             r = jnp.einsum("tbr,er->tbe", rbc[..., :rank], w_dt,

@@ -1997,14 +1997,16 @@ def _ssm_bwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, edge_ref, dy_ref,
     da_ref[0, cb] = da
 
 
-def _ssm_call(kernel, name, operands, *, grid, in_specs, out_specs,
-              out_shape, scratch, interpret):
-    """One Mosaic call of the scan: the grid is (batch rows, chunks,
-    channel blocks), the last two in order with the states in a VMEM
-    scratch; no VMEM window is asked."""
+def _mosaic_call(kernel, name, operands, *, grid, in_specs, out_specs,
+                 out_shape, scratch, interpret,
+                 semantics=("parallel", "arbitrary", "arbitrary")):
+    """One Mosaic call over a grid of three axes with float32 VMEM
+    scratch (the scan's: batch rows, chunks, channel blocks, the last
+    two in order with the states in the scratch); no VMEM window is
+    asked."""
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))}
+            dimension_semantics=semantics)}
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
@@ -2038,7 +2040,7 @@ def _ssm_scan_fwd(u, dt, bx, cx, a, chunk, block, interpret):
     rows, wide, aspec, edge, _ = _ssm_specs(chunk, block, n, blocks,
                                             lambda j: j)
     f32 = jnp.float32
-    y, edges = _ssm_call(
+    y, edges = _mosaic_call(
         functools.partial(_ssm_fwd_kernel, chunk=chunk), "cos_ssm_fwd",
         (u, dt, bx, cx, a), grid=(bsz, chunks, blocks),
         in_specs=[rows, rows, wide, wide, aspec], out_specs=(rows, edge),
@@ -2060,7 +2062,7 @@ def _ssm_scan_bwd(chunk, block, interpret, res, dy):
     rows, wide, aspec, edge, da = _ssm_specs(
         chunk, block, n, blocks, lambda j: chunks - 1 - j)
     f32 = jnp.float32
-    du, ddt, dbx, dcx, dax = _ssm_call(
+    du, ddt, dbx, dcx, dax = _mosaic_call(
         functools.partial(_ssm_bwd_kernel, chunk=chunk), "cos_ssm_bwd",
         (u, dt, bx, cx, a, edges, dy), grid=(bsz, chunks, blocks),
         in_specs=[rows, rows, wide, wide, aspec, edge, rows],
@@ -2101,3 +2103,236 @@ def selective_scan_kernels(u, dt, a, b, c, plan: dict,
     y = _ssm_scan(rows(u), rows(dt), wide(b), wide(c), a_blocks, chunk,
                   block, interpret)
     return y[:, :t]
+
+
+# ---------------------------------------------------------------------------
+# The short causal convolution + SiLU of the Gated DeltaNet and Mamba layers
+# ---------------------------------------------------------------------------
+# silu(sum_j taps[:, j] * a[t - (L - 1) + j] + bias) over time-major (T,
+# B, W) whose first C channels are a: the free view (T, B W) puts time on
+# the sublanes and the channels on the lanes, so a step back in time is
+# one row up and the taps and the bias repeat every W lanes.  The grid is
+# (channel tile, batch column, time tile); a program reads its (time
+# tile, channel tile) block of the wide array where it lies (no slice is
+# copied out first) and, through a second block spec on the same
+# operand, the 8 rows above it (zero at t = 0), and writes the block of
+# the output: every element is read from HBM once, plus the halo, and
+# written once.  The shifted reads are static slices, 0 to L - 1 rows
+# off the tile's rows; only a tile's first rows reach into the halo, and
+# only they go through a small scratch that holds the halo above them.
+# The backward call computes the pre-activation again from a, then
+# du = dy silu'(pre) on the tile and on the 8 rows after it (zero past
+# the last row), da[t] = sum_j taps[:, j] du[t + (L - 1) - j], and the
+# sums over time of du a[t - (L - 1) + j] and of du, eight partial sums a
+# channel in an output block that the batch and time axes revisit.
+
+TAPS_TIME = 512           # rows of a time tile, at most
+TAPS_CHANNELS = 512       # lanes of a channel tile, at most
+_TAPS_HALO = 8            # rows of the blocks above and below a tile
+_TAPS_ROWS = 64           # rows computed together
+
+
+def taps_plan(t: int, channels: int, width: int, n_taps: int):
+    """{time_tile, channel_tile} the kernels take a convolution of `t`
+    steps over the first `channels` of `width` channels at, or None
+    where they do not take it: the taps reach no further back than the
+    halo, T is whole sublane groups and either one tile or whole tiles
+    of at least 128 rows (a grid of shorter ones costs more than the
+    XLA form), and both the channels and the width are whole 128-lane
+    tiles."""
+    tile = t if t <= TAPS_TIME else math.gcd(t, TAPS_TIME)
+    if not 1 <= n_taps <= _TAPS_HALO + 1 or t % _TAPS_HALO \
+            or tile < min(t, 128) or channels % 128 or width % 128 \
+            or channels > width:
+        return None
+    return {"time_tile": tile,
+            "channel_tile": math.gcd(math.gcd(channels, width),
+                                     TAPS_CHANNELS)}
+
+
+def _taps_chunks(tile: int):
+    """(first row, rows) of the row groups a time tile is computed in."""
+    return [(r, min(_TAPS_ROWS, tile - r))
+            for r in range(0, tile, _TAPS_ROWS)]
+
+
+def _taps_edge(a_ref, up_ref, edge_ref):
+    """The halo above the tile (zero at t = 0) and the tile's first row
+    group under it, in `edge_ref`."""
+    first = edge_ref.shape[0] - _TAPS_HALO
+    edge_ref[0:_TAPS_HALO] = jnp.where(pl.program_id(2) == 0, 0.0,
+                                       up_ref[...])
+    edge_ref[_TAPS_HALO:] = a_ref[0:first]
+
+
+def _taps_back(a_ref, edge_ref, r0: int, rows: int, k: int):
+    """The input k steps before rows [r0, r0 + rows) of the tile."""
+    if r0 == 0:
+        return edge_ref[_TAPS_HALO - k:_TAPS_HALO - k + rows]
+    return a_ref[r0 - k:r0 - k + rows]
+
+
+def _taps_pre(xs, taps_ref, bias_ref):
+    """bias + sum_j taps[j] xs[j]: xs[j] the input L - 1 - j steps back."""
+    pre = bias_ref[...] + taps_ref[0:1] * xs[0]
+    for j in range(1, len(xs)):
+        pre = pre + taps_ref[j:j + 1] * xs[j]
+    return pre
+
+
+def _sigmoid(x):
+    return 1.0 / (1.0 + jnp.exp(-x))
+
+
+def _taps_fwd_kernel(a_ref, up_ref, taps_ref, bias_ref, y_ref, edge_ref):
+    n = taps_ref.shape[0]
+    _taps_edge(a_ref, up_ref, edge_ref)
+    for r0, rows in _taps_chunks(a_ref.shape[0]):
+        pre = _taps_pre([_taps_back(a_ref, edge_ref, r0, rows, n - 1 - j)
+                         for j in range(n)], taps_ref, bias_ref)
+        y_ref[r0:r0 + rows] = pre * _sigmoid(pre)
+
+
+def _sum8(x):
+    """(rows, lanes) -> (8, lanes): the sublane groups summed."""
+    return sum(x[g:g + 8] for g in range(0, x.shape[0], 8))
+
+
+def _taps_bwd_kernel(a_ref, up_ref, down_ref, dy_ref, dy_down_ref,
+                     taps_ref, bias_ref, da_ref, sums_ref, edge_ref,
+                     tail_ref, du_ref):
+    n, tile = taps_ref.shape[0], a_ref.shape[0]
+    t = pl.program_id(2)
+    halo = _TAPS_HALO
+
+    @pl.when((pl.program_id(1) == 0) & (t == 0))
+    def _():
+        sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.float32)
+
+    def du_of(pre, dy):         # dy silu'(pre)
+        s = _sigmoid(pre)
+        return dy * (s * (1.0 + pre * (1.0 - s)))
+
+    _taps_edge(a_ref, up_ref, edge_ref)
+    for r0, rows in _taps_chunks(tile):
+        xs = [_taps_back(a_ref, edge_ref, r0, rows, n - 1 - j)
+              for j in range(n)]
+        du = du_of(_taps_pre(xs, taps_ref, bias_ref), dy_ref[r0:r0 + rows])
+        du_ref[r0:r0 + rows] = du
+        for j in range(n):
+            sums_ref[j] += _sum8(du * xs[j])
+        sums_ref[n] += _sum8(du)
+    # du of the 8 rows after the tile, which its last rows' da reads:
+    # zero past the sequence's end
+    tail_ref[0:halo] = a_ref[tile - halo:tile]
+    tail_ref[halo:] = down_ref[...]
+    pre = _taps_pre([tail_ref[halo - (n - 1 - j):2 * halo - (n - 1 - j)]
+                     for j in range(n)], taps_ref, bias_ref)
+    du_ref[tile:] = jnp.where(t == pl.num_programs(2) - 1, 0.0,
+                              du_of(pre, dy_down_ref[...]))
+    for r0, rows in _taps_chunks(tile):
+        da = taps_ref[0:1] * du_ref[r0 + n - 1:r0 + n - 1 + rows]
+        for j in range(1, n):
+            da = da + taps_ref[j:j + 1] * du_ref[r0 + n - 1 - j:
+                                                 r0 + n - 1 - j + rows]
+        da_ref[r0:r0 + rows] = da
+
+
+def _taps_grid(z, taps, cols, tile, block):
+    """The grid (channel tiles, batch columns, time tiles) of a call on
+    z (T, cols W) and taps (L, C), and its block specs: for an array of
+    W-wide and of C-wide columns each (a (tile, block) of it, the 8
+    rows above, the 8 rows below), then the taps (L, C) and the bias
+    (1, C)."""
+    n, channels = taps.shape
+    steps, groups = z.shape[0] // tile, tile // _TAPS_HALO
+
+    def rows(width):
+        per = width // block
+        return (pl.BlockSpec((tile, block),
+                             lambda c, b, t: (t, b * per + c)),
+                pl.BlockSpec((_TAPS_HALO, block), lambda c, b, t: (
+                    jnp.maximum(t * groups - 1, 0), b * per + c)),
+                pl.BlockSpec((_TAPS_HALO, block), lambda c, b, t: (
+                    jnp.minimum((t + 1) * groups, steps * groups - 1),
+                    b * per + c)))
+
+    return ((channels // block, cols, steps), rows(z.shape[1] // cols),
+            rows(channels),
+            (pl.BlockSpec((n, block), lambda c, b, t: (0, c)),
+             pl.BlockSpec((1, block), lambda c, b, t: (0, c))))
+
+
+# (The two calls are plain functions, not a `jax.jit` each: under a jit
+# the unrolled bodies are traced once a shape instead of once a call
+# site, 1.5 s less of tracing for a qwen3_next step, but the -train job
+# then starts 6 s later warm and 16 s later with an empty compile cache:
+# PERF.md, section 7, PR 44.)
+def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret):
+    """z (T, cols W), taps (L, C), bias (1, C) -> y (T, cols C)."""
+    grid, (wide, up, _), (narrow, _, _), consts = _taps_grid(
+        z, taps, cols, tile, block)
+    return _mosaic_call(
+        _taps_fwd_kernel, "cos_taps_fwd", (z, z, taps, bias), grid=grid,
+        in_specs=[wide, up, *consts], out_specs=narrow,
+        out_shape=jax.ShapeDtypeStruct(
+            (z.shape[0], cols * taps.shape[1]), jnp.float32),
+        scratch=[(_TAPS_HALO + min(_TAPS_ROWS, tile), block)],
+        semantics=("parallel",) * 3, interpret=interpret)
+
+
+def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret):
+    """-> da (T, cols C), the sums over time (L + 1, 8, C): the taps'
+    gradient row by row, then the bias's, eight partial sums each."""
+    n, channels = taps.shape
+    f32 = jnp.float32
+    grid, (wide, up, down), (narrow, _, narrow_down), consts = _taps_grid(
+        z, taps, cols, tile, block)
+    return _mosaic_call(
+        _taps_bwd_kernel, "cos_taps_bwd", (z, z, z, dy, dy, taps, bias),
+        grid=grid, in_specs=[wide, up, down, narrow, narrow_down, *consts],
+        out_specs=(narrow, pl.BlockSpec((n + 1, 8, block),
+                                        lambda c, b, t: (0, 0, c))),
+        out_shape=(jax.ShapeDtypeStruct(dy.shape, f32),
+                   jax.ShapeDtypeStruct((n + 1, 8, channels), f32)),
+        scratch=[(_TAPS_HALO + min(_TAPS_ROWS, tile), block),
+                 (2 * _TAPS_HALO, block), (tile + _TAPS_HALO, block)],
+        interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _taps_silu(z, taps, bias, cols, tile, block, interpret):
+    return _taps_fwd_call(z, taps, bias, cols, tile, block, interpret)
+
+
+def _taps_silu_fwd(z, taps, bias, cols, tile, block, interpret):
+    return (_taps_fwd_call(z, taps, bias, cols, tile, block, interpret),
+            (z, taps, bias))
+
+
+def _taps_silu_bwd(cols, tile, block, interpret, res, dy):
+    z, taps, bias = res
+    n, channels = taps.shape
+    da, sums = _taps_bwd_call(z, taps, bias, dy, cols, tile, block,
+                              interpret)
+    sums = jnp.sum(sums, axis=1)
+    dz = jnp.pad(da.reshape(z.shape[0], cols, channels),
+                 ((0, 0), (0, 0), (0, z.shape[1] // cols - channels)))
+    return dz.reshape(z.shape), sums[:n], sums[n:]
+
+
+_taps_silu.defvjp(_taps_silu_fwd, _taps_silu_bwd)
+
+
+def causal_taps_silu_kernels(z, taps, bias, plan: dict,
+                             interpret: bool = False):
+    """`ops.layers.causal_taps_silu` through the kernels above: z (T, B,
+    W) float32 whose first C channels are convolved, taps (C, L), bias
+    (C,) or None -> (T, B, C), differentiable in all three.  What a
+    backward pass keeps is the inputs alone."""
+    t, cols, _ = z.shape
+    channels = taps.shape[0]
+    bias = jnp.zeros((channels,), jnp.float32) if bias is None else bias
+    y = _taps_silu(z.reshape(t, -1), taps.T, bias.reshape(1, channels),
+                   cols, plan["time_tile"], plan["channel_tile"], interpret)
+    return y.reshape(t, cols, channels)

@@ -623,9 +623,11 @@ class CaffeProcessor:
         recompute_block makes and blocks further on than the next read
         (another layer's keys and values, a scan's output as memory),
         their readers and bytes (`Net.shared_blobs`, through
-        `recompute.shared_plans`).
+        `recompute.shared_plans`); `info.taps`: per shape of the Gated
+        DeltaNet's and Mamba's convolution + SiLU the form that ran, the
+        kernels' tiles and the layers that took it (`layers.taps_plans`).
         Static facts, nothing a step on the device."""
-        from .ops.layers import gdn_plans, moe_plans, ssm_plans
+        from .ops.layers import gdn_plans, moe_plans, ssm_plans, taps_plans
         from .ops.pallas_kernels import flash_plans
         from .ops.recompute import recompute_plans, shared_plans
         for key, what, plans in (
@@ -634,7 +636,8 @@ class CaffeProcessor:
                 ("moe", "expert layers", moe_plans()),
                 ("recompute", "recompute blocks", recompute_plans()),
                 ("ssm", "selective scans", ssm_plans()),
-                ("shared", "blobs shared across blocks", shared_plans())):
+                ("shared", "blobs shared across blocks", shared_plans()),
+                ("taps", "convolution + SiLU stages", taps_plans())):
             if plans:
                 self.metrics.set_info(key, plans)
                 _LOG.info("%s as lowered: %s", what, plans)

@@ -647,7 +647,8 @@ def test_train_job_reports_flash_plans():
     pk._FLASH_PLANS.clear()
     L._GDN_PLANS.clear()        # `info.gdn`, `info.moe`, `info.ssm`,
     L._MOE_PLANS.clear()        # `info.recompute` and `info.shared`
-    L._SSM_PLANS.clear()        # ride the same route
+    L._SSM_PLANS.clear()        # and `info.taps` ride the same route
+    L._TAPS_PLANS.clear()
     from caffeonspark_tpu.ops import recompute
     recompute._BLOCKS.clear()
     recompute._SHARED.clear()

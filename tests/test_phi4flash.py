@@ -282,8 +282,9 @@ def test_state_carries_across_a_chunk_edge(form):
 
 def test_the_layer_takes_the_kernels_under_interpret(monkeypatch):
     """COS_FLASH_INTERPRET=1 is the CPU suite's way into the kernel
-    form: `selective_scan` lowers to it, says so in `ssm_plans()`, and
-    the Mamba layer's output and gradients are the XLA form's."""
+    form: `selective_scan` and the convolution stage before it lower to
+    it, say so in `ssm_plans()` and `taps_plans()`, and the Mamba
+    layer's output and gradients are the XLA form's."""
     cfg = small_cfg(**MIDDLE)
     net = Net(small_net(**MIDDLE, batch=1, recompute=False),
               NetState(phase=Phase.TRAIN))
@@ -294,16 +295,53 @@ def test_the_layer_takes_the_kernels_under_interpret(monkeypatch):
         return net.apply(q, {"h0": x}, train=True,
                          layers=["L0.norm1", "L0.mamba"])[0]["L0.a"]
 
+    conv = "1x64 128 of 256 channels 4 taps float32 bias"
+    monkeypatch.setattr(L, "_TAPS_PLANS", {})
     want, gw = jax.value_and_grad(lambda q: jnp.sum(f(q) ** 2))(params)
     assert L.ssm_plans()["1x64 128 channels 16 states"]["form"] == "xla"
+    assert L.taps_plans()[conv] == {"form": "xla", "sites": ["L0.mamba"]}
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
     got, gg = jax.value_and_grad(lambda q: jnp.sum(f(q) ** 2))(params)
     plan = L.ssm_plans()["1x64 128 channels 16 states"]
     assert plan["form"] == "kernel" and plan["chunks_a_row"] == 4
+    # and the convolution before it to `cos_taps_fwd` / `cos_taps_bwd`
+    assert L.taps_plans()[conv] == {
+        "form": "kernel", "time_tile": 64, "channel_tile": 128,
+        "sites": ["L0.mamba"]}
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for k, v in flat(gw).items():
         if k.startswith("L0.mamba"):
             close(flat(gg)[k], v, 1e-4, k)
+
+
+@pytest.mark.parametrize("recompute", [True, False],
+                         ids=["in_blocks", "no_block"])
+def test_the_convolution_kernels_change_no_value_in_or_out_of_a_block(
+        monkeypatch, recompute):
+    """The middle cut's loss and every gradient with the two Mamba
+    layers' convolution on its kernels (interpret mode) against a build
+    whose convolution keeps the XLA form, the scan's kernels on both
+    sides: inside `recompute_block`s, where the stage runs bare, and
+    outside, where it carries a checkpoint of its own."""
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    cfg = small_cfg(**MIDDLE)
+    params = unflat(ref.init_params(cfg, 3))
+    data = batches(1, seed=3)[0]
+    net_param = small_net(**MIDDLE, recompute=recompute)
+    key = "2x64 128 of 256 channels 4 taps float32 bias"
+    monkeypatch.setattr(L, "_TAPS_PLANS", {})
+    loss, grads = _loss_and_grads(net_param, params, data)
+    assert L.taps_plans()[key] == {
+        "form": "kernel", "time_tile": 64, "channel_tile": 128,
+        "sites": ["L0.mamba", "L2.mamba"]}
+    monkeypatch.setattr(pk, "taps_plan", lambda *a: None)
+    want, want_grads = _loss_and_grads(net_param, params, data)
+    assert L.taps_plans()[key]["form"] == "xla"
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    for k in ("L0.mamba/taps", "L2.mamba/conv_bias", "L0.mamba/W_in"):
+        assert np.abs(want_grads[k]).max() > 0, k
+    for k, v in want_grads.items():
+        close(grads[k], v, 1e-4, k)
 
 
 # ------------------------------------------------------------ planted faults

@@ -647,6 +647,86 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
         rtol=2e-4, atol=2e-7)
 
 
+# one key head of 128 serving two value heads of 128: 512 convolved
+# channels of a 768-wide product, whole 128-lane tiles (SMALL's 64 of 96
+# are not: its layers keep the XLA form under interpret mode too)
+TILED = dict(linear_k_heads=1, linear_v_heads=2, linear_k_dim=128,
+             linear_v_dim=128, chunk=64, seq=64, layers=2,
+             layer_types=("linear_attention", "full_attention"))
+
+
+@pytest.mark.parametrize("recompute", [True, False],
+                         ids=["in_a_block", "no_block"])
+def test_the_layer_takes_the_convolution_kernels_under_interpret(
+        monkeypatch, recompute):
+    """COS_FLASH_INTERPRET=1 is the CPU suite's way into the kernel form
+    of the convolution stage: the Gated DeltaNet layer lowers to
+    `cos_taps_fwd` / `cos_taps_bwd`, says so in `taps_plans()` with the
+    layer's name, and the net's loss and every gradient are those of a
+    build whose convolution keeps the XLA form (the rule's kernels in
+    interpret mode on both sides), inside a `recompute_block` and
+    outside one."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    net = Net(small_net(**TILED, recompute=recompute))
+    assert bool(net.recompute_blocks) == recompute
+    params = net.init(jax.random.key(0))
+    ids = jax.random.randint(jax.random.key(1), (64, 2), 0, SMALL["vocab"])
+    ins = {"input_ids": ids.astype(jnp.float32),
+           "target_ids": jnp.roll(ids, 1, 0).astype(jnp.float32)}
+
+    def run():
+        monkeypatch.setattr(L, "_TAPS_PLANS", {})
+        fn = jax.value_and_grad(
+            lambda p: net.loss(p, ins, train=True, rng=jax.random.key(1)),
+            has_aux=True)
+        calls = str(jax.make_jaxpr(fn)(params)).count("cos_taps_")
+        (loss, _), g = fn(params)
+        return float(loss), flat(g), L.taps_plans(), calls
+
+    loss, grads, plans, calls = run()
+    assert plans == {"2x64 512 of 768 channels 4 taps float32": {
+        "form": "kernel", "time_tile": 64, "channel_tile": 256,
+        "sites": ["L0.gdn"]}}
+    assert calls >= 2
+    monkeypatch.setattr(pk, "taps_plan", lambda *a: None)
+    want, want_grads, plans, calls = run()
+    assert plans == {"2x64 512 of 768 channels 4 taps float32": {
+        "form": "xla", "sites": ["L0.gdn"]}}
+    assert calls == 0
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    assert np.abs(want_grads["L0.gdn/taps"]).max() > 0
+    for k, v in want_grads.items():
+        np.testing.assert_allclose(grads[k], v, rtol=2e-4,
+                                   atol=2e-6 * np.abs(v).max(), err_msg=k)
+
+
+def test_under_a_time_sharding_mesh_the_convolution_keeps_the_xla_form(
+        monkeypatch):
+    """A bare Mosaic call cannot be partitioned: while a mesh of several
+    devices is installed (here one that shards time) the layer's
+    convolution is the XLA form, interpret mode or not."""
+    from caffeonspark_tpu.parallel.mesh import build_mesh
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(L, "_TAPS_PLANS", {})
+    monkeypatch.setattr(L, "_GDN_PLANS", {})
+    z = dict(SMALL, **TILED)
+    lp = LayerParameter.from_text(
+        'name: "g" type: "GatedDeltaNet" bottom: "x" top: "y" '
+        'gated_delta_net_param { num_k_heads: 1 num_v_heads: 2 '
+        'head_k_dim: 128 head_v_dim: 128 conv_taps: 4 chunk: 64 }')
+    op = L.get_op("GatedDeltaNet")
+    x = jax.ShapeDtypeStruct((64, 1, z["hidden"]), jnp.float32)
+    blobs = [jax.ShapeDtypeStruct(s[1], jnp.float32)
+             for s in op.param_specs(lp, [x.shape])]
+    with L.flash_mesh(build_mesh(dp=1, sp=2, devices=jax.devices()[:2])):
+        jax.eval_shape(lambda x, *b: op.apply(L.Ctx(train=True), lp,
+                                              list(b), [x])[0], x, *blobs)
+    assert L.taps_plans() == {"1x64 512 of 768 channels 4 taps float32": {
+        "form": "xla", "sites": ["g"]}}
+    assert [p["rule"] for p in L.gdn_plans().values()] == ["xla"]
+
+
 # ------------------------------------------------------------------ the net
 
 def test_recompute_block_changes_no_value():

@@ -18,7 +18,10 @@ of both, the preparation of the delta rule's operands) carry no
 checkpoint of their own inside a block, where they run twice a step;
 outside one the layers' programs are what they were; values and
 gradients equal a build with the wrappers on to the last bit;
-`info.recompute` counts the stages a block."""
+`info.recompute` counts the stages a block.  The convolution stage is
+counted in both its forms (`layers.causal_taps_silu`): the XLA form by
+its SiLU, the kernel form by its calls, `cos_taps_fwd` twice and
+`cos_taps_bwd` once in a block."""
 
 import jax
 import jax.numpy as jnp
@@ -409,15 +412,34 @@ def wrapped(monkeypatch):
     monkeypatch.setattr(L, "stage", jax.checkpoint)
 
 
-def is_marker(e, case):
+@pytest.fixture(params=["xla", "kernel"])
+def form(request, monkeypatch):
+    """The form the convolution stage is lowered to under the
+    `interpret` fixture: the kernels', or (no shape tiles) XLA's."""
+    if request.param == "xla":
+        monkeypatch.setattr(pk, "taps_plan", lambda *a: None)
+    return request.param
+
+
+def is_call(e, name):
+    return e.primitive.name == "pallas_call" and name in e.params["name"]
+
+
+def is_marker(e, case, form):
+    """The convolution stage's forward: the `logistic` over its channels
+    (XLA form) or the forward kernel call."""
+    if form == "kernel":
+        return is_call(e, "cos_taps_fwd")
     return (e.primitive.name == "logistic"
             and e.outvars[0].aval.shape == STAGED[case][0])
 
 
-def times_run(jaxpr, case):
-    """How often the program computes the convolution stage's SiLU: the
-    `logistic` equations over its channels, wherever they stand."""
-    return sum(is_marker(e, case) for e in eqns(jaxpr)) - STAGED[case][1]
+def times_run(jaxpr, case, form):
+    """How often the program computes the convolution stage forward:
+    the `logistic` equations over its channels, wherever they stand,
+    less those outside the stages, or the forward kernel's calls."""
+    return (sum(is_marker(e, case, form) for e in eqns(jaxpr))
+            - (STAGED[case][1] if form == "xla" else 0))
 
 
 def nested(jaxpr):
@@ -429,34 +451,41 @@ def nested(jaxpr):
 
 @pytest.mark.parametrize("case", list(STAGED))
 def test_a_stage_runs_twice_inside_a_block_not_three_times(
-        interpret, monkeypatch, case):
+        interpret, monkeypatch, case, form):
     """The gradient through one block holds, inside the block's
     checkpoint, those of the stages that keep one and no other, and the
-    convolution's SiLU twice: the forward pass and the block's
-    recomputation.  With the wrappers on (the control) every stage's
-    checkpoint is nested there and the SiLU stands a third time, in the
-    inner checkpoint's own backward."""
+    convolution's forward twice: the forward pass and the block's
+    recomputation (the XLA form's SiLU; the kernel form's forward call,
+    beside one backward call).  With the wrappers on (the control) every
+    stage's checkpoint is nested there and the XLA form's SiLU stands a
+    third time, in the inner checkpoint's own backward (the kernel
+    form's backward call computes it inside: what its forward keeps is
+    its inputs, so the inner checkpoint has no call to make again)."""
     bare = STAGED[case][2]
     net = build([case])
     jaxpr = gradient_program(net)
     assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3 - bare
-    assert times_run(jaxpr, case) == 2
+    assert times_run(jaxpr, case, form) == 2
+    assert sum(is_call(e, "cos_taps_bwd") for e in eqns(jaxpr)) == (
+        form == "kernel")
     assert R.recompute_plans()["stages_unwrapped"] == {case: bare}
 
     wrapped(monkeypatch)
     jaxpr = gradient_program(net)
     assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3
-    assert times_run(jaxpr, case) == 3
+    assert times_run(jaxpr, case, form) == (3 if form == "xla" else 2)
     assert R.recompute_plans()["stages_unwrapped"] == {}
 
 
 @pytest.mark.parametrize("where", ["no_block", "test_pass", "COS_REMAT"])
 @pytest.mark.parametrize("case", list(STAGED))
 def test_outside_a_block_a_stage_keeps_its_own_checkpoint(
-        interpret, monkeypatch, case, where):
+        interpret, monkeypatch, case, where, form):
     """A net without blocks, a TEST pass and COS_REMAT=1 trace to the
     program of the layers with `jax.checkpoint` at the call sites: the
-    convolution stage's checkpoint is there, nothing is counted."""
+    convolution stage's checkpoint is there (around the SiLU's second
+    run, or around the backward kernel call, which runs it inside),
+    nothing is counted."""
     if where == "COS_REMAT":
         monkeypatch.setenv("COS_REMAT", "1")
     net = build([case], tag=where != "no_block")
@@ -464,7 +493,9 @@ def test_outside_a_block_a_stage_keeps_its_own_checkpoint(
     jaxpr = gradient_program(net, train)
     own = [e for e in eqns(jaxpr)
            if e.primitive.name in ("checkpoint", "remat2")
-           and any(is_marker(s, case) for s in eqns(e.params["jaxpr"]))]
+           and any(is_call(s, "cos_taps_bwd") if form == "kernel"
+                   else is_marker(s, case, form)
+                   for s in eqns(e.params["jaxpr"]))]
     assert own and R.recompute_plans() == {}
     wrapped(monkeypatch)
     assert shape_of(gradient_program(net, train)) == shape_of(jaxpr)
@@ -473,7 +504,7 @@ def test_outside_a_block_a_stage_keeps_its_own_checkpoint(
 @pytest.mark.parametrize("how", ["op_by_op", "compiled"])
 @pytest.mark.parametrize("case", list(STAGED))
 def test_values_equal_the_wrapped_stages_bit_for_bit(interpret, monkeypatch,
-                                                     case, how):
+                                                     case, how, form):
     """Loss, every top and every gradient of a block whose stages run
     bare equal those of the same block with each stage under its own
     checkpoint: the same arithmetic, once less.  To the last bit where

@@ -1,6 +1,6 @@
 """The flash kernels at the real shapes of the benchmark's language
-models, the gated delta rule's kernels at qwen3next's and the selective
-scan's at phi4flash's, compiled for
+models, the gated delta rule's kernels at qwen3next's, the selective
+scan's at phi4flash's and the convolution stage's at both, compiled for
 a described (not attached) TPU v5e: what interpret
 mode cannot see — VMEM, tiling, the grouped block index maps.  PR 33
 found here, before any chip time, that a 64-wide head is padded to 128
@@ -8,7 +8,11 @@ lanes in VMEM.  About two seconds a case; nothing runs.
 
 The topology is described inside a fixture, never at import (only one
 process may hold the TPU's library: `on-chip-measurement` guide,
-section 2), and the compile is made in the test's own process."""
+section 2), and the compile is made in the test's own process.
+
+One test here runs on the chip and is skipped without one (the `tpu`
+fixture, COS_TPU_TESTS=1): the convolution stage's compiled kernels
+against the XLA form at the two cells' shapes."""
 
 import os
 import re
@@ -229,3 +233,89 @@ def test_selective_scan_kernels_compile_for_v5e(one_chip):
         assert sum(name in line for line in lines) == 1, name
     assert len(lines) == 2
     assert all('"scoped_memory_configs":[]' in line for line in lines)
+
+
+# the convolution stage of `qwen3next.train_packed8k` (the first 8,192
+# of W_qkvz's 12,288 channels) and of `phi4flash.train_packed8k` (the
+# first 5,120 of W_in's 10,240, with a bias)
+TAPS_SHAPES = [(8192, 1, 12288, 8192, False), (8192, 1, 10240, 5120, True)]
+
+
+def _taps_case(t, b, w, c, bias):
+    """The stage's kernel and XLA forms as functions of 2-D arrays (so
+    that a test's own arguments carry the layout the step's product
+    gives them), each -> (y, (dz, dtaps[, dbias]))."""
+    from caffeonspark_tpu.ops import layers as L
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    plan = pk.taps_plan(t, c, w, 4)
+
+    def both(stage):
+        def run(z2, taps, bv, dy2):
+            args = (z2, taps) + ((bv,) if bias else ())
+            y, vjp = jax.vjp(lambda z2, taps, bv=None: stage(
+                z2.reshape(t, b, w), taps, bv).reshape(t, b * c), *args)
+            return y, vjp(dy2)
+        return run
+
+    return plan, both(lambda z, taps, bv: pk.causal_taps_silu_kernels(
+        z, taps, bv, plan)), both(L.causal_taps_silu_xla)
+
+
+@pytest.mark.parametrize("t,b,w,c,bias", TAPS_SHAPES)
+def test_convolution_stage_kernels_compile_for_v5e(one_chip, t, b, w, c,
+                                                   bias):
+    """`cos_taps_fwd` and `cos_taps_bwd` at the two cells' shapes lower
+    for the v5e at the tiles `taps_plan` picks, one call each, neither
+    with a VMEM window of its own, and read the wide array where it
+    lies: no copy of it or of its slice stands before a call."""
+    plan, kernel, _ = _taps_case(t, b, w, c, bias)
+    assert plan == {"time_tile": 512, "channel_tile": 512}
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+              for s in ((t, b * w), (c, 4), (c,), (t, b * c))]
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(kernel).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("cos_taps_fwd", "cos_taps_bwd"):
+        assert sum(name in line for line in lines) == 1, name
+    assert len(lines) == 2
+    assert all('"scoped_memory_configs":[]' in line for line in lines)
+    # the calls' first operand is the test's own argument
+    assert all(re.search(r"custom-call\(f32\[%d,%d\][^ ]* %%z2" % (t, b * w),
+                         line) or re.search(r"custom-call\(%%?z2", line)
+               for line in lines), lines
+
+
+@pytest.mark.parametrize("t,b,w,c,bias", TAPS_SHAPES)
+def test_convolution_stage_kernels_equal_the_xla_form_on_the_chip(
+        tpu, t, b, w, c, bias):
+    """On the chip (skipped elsewhere): the compiled kernels against the
+    XLA form at the two cells' shapes.  The bound is float32 rounding of
+    a sum of four products and a bias under a SiLU: y and dz within 8 ulp
+    of the array's largest entry (8 x 2^-23 = 9.5e-7 of it; measured
+    0 to 2.9e-7, PR 44's lab), the sums over 8,192 rows (the taps' and
+    the bias's gradients) within 2^-17 = 7.6e-6 of theirs (measured
+    4.4e-7)."""
+    _, kernel, xla = _taps_case(t, b, w, c, bias)
+    k = jax.random.split(jax.random.key(t + c), 4)
+    args = (jax.random.normal(k[0], (t, b * w)),
+            0.5 * jax.random.normal(k[1], (c, 4)),
+            0.5 * jax.random.normal(k[2], (c,)),
+            jax.random.normal(k[3], (t, b * c)))
+    got, got_grads = jax.jit(kernel)(*args)
+    want, want_grads = jax.jit(xla)(*args)
+    assert len(got_grads) == 2 + bias
+
+    def gap(a, r):
+        return float(jnp.max(jnp.abs(a - r)) / jnp.max(jnp.abs(r)))
+
+    assert float(jnp.max(jnp.abs(want))) > 1.0
+    assert gap(got, want) <= 8 * 2.0 ** -23
+    assert gap(got_grads[0], want_grads[0]) <= 8 * 2.0 ** -23
+    assert not bool(jnp.any(got_grads[0].reshape(t, b, w)[..., c:]))
+    for a, r in zip(got_grads[1:], want_grads[1:]):
+        assert a.shape == r.shape and gap(a, r) <= 2.0 ** -17
