@@ -131,35 +131,10 @@ false} on clean runs, the exact injectors otherwise, so every drill
 and bench artifact is self-describing), and the sync-mode policy +
 final exchange counts as `info.sync` (COS_SYNC_MODE, K/staleness,
 exchanges / skipped / adopted / timeouts / max_gap), and after the
-first step what its flash attention calls were lowered to as
-`info.flash` (per call shape and kernel: tiles, calls an attention,
-share of score tiles under the masked body; under a window also the
-window, the pairs of chunks a causal call would run and the share of
-the causal triangle's tiles visited;
-`ops.pallas_kernels.flash_plans`) and, for a net with GatedDeltaNet
-layers, `info.gdn` (per operator shape: the chunk, the chunks a row,
-the heads, the state's bytes; `ops.layers.gdn_plans`) and, for a net
-with dropless expert layers, `info.moe` (per layer shape: the layers,
-the k N assignments, the rows a pass, the passes and those an even
-router fills, the row tile, the operations a held row costs, the bytes
-of weight gradient the backward loop carries, added into once a pass
-that runs; `ops.layers.moe_plans`) and, for a net with
-`recompute_block`s, `info.recompute` (`blocks`: per block the values it
-keeps between its forward and its backward pass, by name, with their
-bytes; `bytes_a_step`: their sum; `keep_nothing`: the blocks whose
-layers name nothing; `stages_unwrapped`: per block the elementwise
-stages that ran in it without a checkpoint of their own, the block's
-recomputation being their second and last run;
-`ops.recompute.recompute_plans`) and, for a net
-with Mamba layers, `info.ssm` (per scan shape: the form that ran, the
-chunk, the chunks a row, the channels a program, the VMEM a call takes,
-the kept edges' bytes; `ops.layers.ssm_plans`) and, where a
-recompute_block's blob is read by blocks further on than the next,
-`info.shared` (per blob: the layer that makes it, the layers that read
-it, its bytes; `Net.shared_blobs`) and, for a net with GatedDeltaNet
-or Mamba layers, `info.taps` (per shape of their convolution + SiLU
-stage: the form that ran, `kernel` or `xla`, the kernels' time and
-channel tiles, the layers that took it; `ops.layers.taps_plans`).
+first step what its operators were lowered to, one `info.<kind>` for
+every kind in `ops.route.plans()` (`info.flash`, `info.moe`,
+`info.recompute` and their like: what a kind's facts mean is written
+where `ops.route.lowered` is called for it).
 Where the
 expert layers return their
 stats, the summary's `experts` says what they did over the last steps

@@ -367,6 +367,88 @@ layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
     return parse_net_prototxt(t)
 
 
+# ---------------------------------------------------------------------------
+# the language builders' shared text: inputs and embedding, one pre-norm
+# residual block, the dense gated feed-forward, norm + head + loss
+# ---------------------------------------------------------------------------
+
+def _gauss(std) -> str:
+    return f'weight_filler {{ type: "gaussian" std: {std} }}'
+
+
+def _lm_inputs(name, batch, seq, vocab, hidden, filler, extra="") -> str:
+    """The net's name, its time-major (T, B) int tops `input_ids` /
+    `target_ids` and the embedding of the ids into `h0`."""
+    return f"""
+name: "{name}"
+layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
+  cos_data_param {{ batch_size: {batch}
+    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }}
+    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
+          sample_num_axes: 1 transpose: true }} }} }}
+layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
+  {extra} embed_param {{ input_dim: {vocab} num_output: {hidden}
+    bias_term: false {filler} }} }}
+"""
+
+
+def _norm(kind, name, bottom, top, tag, eps) -> str:
+    param = {"RMSNorm": "rms_norm_param", "LayerNorm": "layer_norm_param"}
+    return f"""
+layer {{ name: "{name}" type: "{kind}" bottom: "{bottom}" top: "{top}"
+  {tag} {param[kind]} {{ eps: {eps} }} }}"""
+
+
+def _ip(name, bottom, top, n, tag, filler, extra="") -> str:
+    return f"""
+layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
+  {tag} {extra} inner_product_param {{ num_output: {n} axis: 2
+    bias_term: false {filler} }} }}"""
+
+
+def _gated_ffn(p, width, hidden, tag, filler) -> str:
+    """Block `p`'s dense SiLU-gated feed-forward, "{p}.n2" -> "{p}.f"."""
+    return (_ip(f"{p}.gate", f"{p}.n2", f"{p}.g", width, tag, filler)
+            + _ip(f"{p}.up", f"{p}.n2", f"{p}.u", width, tag, filler) + f"""
+layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g" {tag} }}
+layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
+  top: "{p}.gu" {tag} eltwise_param {{ operation: PROD }} }}"""
+            + _ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, tag, filler))
+
+
+def _block(p, h, mixer, ffn, tag, eps, *, ffn_tag=None, norm="RMSNorm"):
+    """One pre-norm residual block named `p` over the blob `h`:
+
+        h1 = h + mixer(norm1(h)),    out = h1 + ffn(norm2(h1))
+
+    `mixer` is the text of the layers that read "{p}.n1" and write
+    "{p}.a", `ffn` of those that read "{p}.n2" and write "{p}.f"; `tag`
+    is the `recompute_block` field that the block's own layers carry
+    ("" for none), `ffn_tag` that of the second half where it is a
+    block of its own.  -> (text, the blob that leaves the block)."""
+    ffn_tag = tag if ffn_tag is None else ffn_tag
+    return (_norm(norm, f"{p}.norm1", h, f"{p}.n1", tag, eps) + mixer + f"""
+layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
+  top: "{p}.h1" {tag} }}"""
+            + _norm(norm, f"{p}.norm2", f"{p}.h1", f"{p}.n2", ffn_tag, eps)
+            + ffn + f"""
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
+  top: "{p}.out" {ffn_tag} }}
+""", f"{p}.out")
+
+
+def _lm_head(h, vocab, filler, eps, *, norm="RMSNorm", extra="") -> str:
+    """The last norm, the logits over `vocab` ids and the loss against
+    `target_ids`."""
+    return (_norm(norm, "head.norm", h, "head.n", "", eps)
+            + _ip("head.logits", "head.n", "logits", vocab, "", filler, extra)
+            + """
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
+  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
+""")
+
+
 def kanana2(vocab: int = 16032, hidden: int = 2048, heads: int = 32,
             qk_nope: int = 128, qk_rope: int = 64, v_head: int = 128,
             kv_lora_rank: int = 512, dense_width: int = 6144,
@@ -391,55 +473,25 @@ def kanana2(vocab: int = 16032, hidden: int = 2048, heads: int = 32,
     whole model.  Time-major (T, B) int tops `input_ids` /
     `target_ids`; every block is one `recompute_block`; each expert
     layer's `moe_stats` / `moe_rows` tops are net outputs."""
-    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
-
-    def ip(name, bottom, top, n, tag):
-        return f"""
-layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
-  {tag} inner_product_param {{ num_output: {n} axis: 2 bias_term: false
-    {gauss} }} }}"""
-
-    t = f"""
-name: "Kanana2"
-layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
-  cos_data_param {{ batch_size: {batch}
-    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }}
-    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }} }} }}
-layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
-  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
-    {gauss} }} }}
-"""
+    gauss = _gauss(init_std)
+    t = _lm_inputs("Kanana2", batch, seq, vocab, hidden, gauss)
     h = "h0"
     for i in range(expert_layers + 1):
         p = f"L{i}"
         tag = f'recompute_block: "{p}"' if recompute else ""
-        t += f"""
-layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
-  {tag} rms_norm_param {{ eps: {eps} }} }}
+        attn = f"""
 layer {{ name: "{p}.attn" type: "LatentAttention" bottom: "{p}.n1"
   top: "{p}.a" {tag}
   attention_param {{ num_heads: {heads} causal: true
     qk_nope_head_dim: {qk_nope} qk_rope_head_dim: {qk_rope}
     v_head_dim: {v_head} kv_lora_rank: {kv_lora_rank}
-    rope_theta: {rope_theta} rms_norm_eps: {eps} {gauss} }} }}
-layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
-  top: "{p}.h1" {tag} }}
-layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
-  {tag} rms_norm_param {{ eps: {eps} }} }}"""
+    rope_theta: {rope_theta} rms_norm_eps: {eps} {gauss} }} }}"""
         if i == 0:
-            t += ip(f"{p}.gate", f"{p}.n2", f"{p}.g", dense_width, tag)
-            t += ip(f"{p}.up", f"{p}.n2", f"{p}.u", dense_width, tag)
-            t += f"""
-layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g" {tag} }}
-layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
-  top: "{p}.gu" {tag} eltwise_param {{ operation: PROD }} }}"""
-            t += ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, tag)
+            ffn = _gated_ffn(p, dense_width, hidden, tag, gauss)
         else:
             # blobs: router, bias (moves only the choice: frozen),
             # W_gate, W_up, W_down, S_gate, S_up, S_down
-            t += f"""
+            ffn = f"""
 layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
   top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
   param {{ lr_mult: 1 }} param {{ lr_mult: 0 decay_mult: 0 }}
@@ -449,20 +501,9 @@ layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
     gated: true shared_hidden_dim: {shared_experts * expert_width}
     experts_held: {experts_held} first_expert: {first_expert}
     {gauss} }} }}"""
-        t += f"""
-layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
-  top: "{p}.out" {tag} }}
-"""
-        h = f"{p}.out"
-    t += f"""
-layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
-  rms_norm_param {{ eps: {eps} }} }}"""
-    t += ip("head.logits", "head.n", "logits", vocab, "")
-    t += """
-layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
-  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
-"""
-    return parse_net_prototxt(t)
+        block, h = _block(p, h, attn, ffn, tag, eps)
+        t += block
+    return parse_net_prototxt(t + _lm_head(h, vocab, gauss, eps))
 
 
 # lfm2_moe's published operator schedule (LFM2-24B-A2B, 40 layers):
@@ -501,45 +542,24 @@ def lfm2(vocab: int = 8192, hidden: int = 2048, heads: int = 32,
     Time-major (T, B) int tops `input_ids` / `target_ids` (one row of
     8,192 by default); every block is one `recompute_block`; each
     expert layer's `moe_stats` / `moe_rows` tops are net outputs."""
-    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    gauss = _gauss(init_std)
     if first_layer + layers > len(layer_types):
         raise ValueError(f"lfm2: layers [{first_layer}, "
                          f"{first_layer + layers}) of {len(layer_types)}")
-
-    def ip(name, bottom, top, n, tag):
-        return f"""
-layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
-  {tag} inner_product_param {{ num_output: {n} axis: 2 bias_term: false
-    {gauss} }} }}"""
-
-    t = f"""
-name: "LFM2"
-layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
-  cos_data_param {{ batch_size: {batch}
-    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }}
-    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }} }} }}
-layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
-  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
-    {gauss} }} }}
-"""
+    t = _lm_inputs("LFM2", batch, seq, vocab, hidden, gauss)
     h = "h0"
     for i in range(layers):
         p = f"L{i}"
         published = first_layer + i
         tag = f'recompute_block: "{p}"' if recompute else ""
-        t += f"""
-layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
-  {tag} rms_norm_param {{ eps: {eps} }} }}"""
         kind = layer_types[published]
         if kind == "conv":
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.conv" type: "ShortConv" bottom: "{p}.n1" top: "{p}.a"
   {tag} short_conv_param {{ taps: {conv_taps}
     bias_term: {"true" if conv_bias else "false"} {gauss} }} }}"""
         elif kind == "full_attention":
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
   top: "{p}.a" {tag}
   attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
@@ -547,23 +567,12 @@ layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
     rope_theta: {rope_theta} rms_norm_eps: {eps} {gauss} }} }}"""
         else:
             raise ValueError(f"lfm2: layer type {kind!r}")
-        t += f"""
-layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
-  top: "{p}.h1" {tag} }}
-layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
-  {tag} rms_norm_param {{ eps: {eps} }} }}"""
         if published < num_dense_layers:
-            t += ip(f"{p}.gate", f"{p}.n2", f"{p}.g", dense_width, tag)
-            t += ip(f"{p}.up", f"{p}.n2", f"{p}.u", dense_width, tag)
-            t += f"""
-layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g" {tag} }}
-layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
-  top: "{p}.gu" {tag} eltwise_param {{ operation: PROD }} }}"""
-            t += ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, tag)
+            ffn = _gated_ffn(p, dense_width, hidden, tag, gauss)
         else:
             # blobs: router, bias (moves only the choice: frozen),
             # W_gate, W_up, W_down
-            t += f"""
+            ffn = f"""
 layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
   top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
   param {{ lr_mult: 1 }} param {{ lr_mult: 0 decay_mult: 0 }}
@@ -573,20 +582,9 @@ layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
     norm_epsilon: {norm_epsilon} gated: true
     experts_held: {experts_held} first_expert: {first_expert}
     {gauss} }} }}"""
-        t += f"""
-layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
-  top: "{p}.out" {tag} }}
-"""
-        h = f"{p}.out"
-    t += f"""
-layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
-  rms_norm_param {{ eps: {eps} }} }}"""
-    t += ip("head.logits", "head.n", "logits", vocab, "")
-    t += """
-layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
-  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
-"""
-    return parse_net_prototxt(t)
+        block, h = _block(p, h, mixer, ffn, tag, eps)
+        t += block
+    return parse_net_prototxt(t + _lm_head(h, vocab, gauss, eps))
 
 
 # smallthinker's published layouts (SmallThinker-21BA3B-Instruct, 52
@@ -630,9 +628,7 @@ def smallthinker(vocab: int = 18992, hidden: int = 2560, heads: int = 28,
     block is one `recompute_block`; each expert layer's `moe_stats` /
     `moe_rows` tops are net outputs.  Every matrix is filled gaussian
     `init_std`, the embedding gaussian `embed_std` where one is given."""
-    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
-    embed = gauss if embed_std is None else \
-        f'weight_filler {{ type: "gaussian" std: {embed_std} }}'
+    gauss = _gauss(init_std)
     if first_layer + layers > min(len(sliding_window_layout),
                                   len(rope_layout)):
         raise ValueError(f"smallthinker: layers [{first_layer}, "
@@ -640,18 +636,8 @@ def smallthinker(vocab: int = 18992, hidden: int = 2560, heads: int = 28,
                          f"{len(sliding_window_layout)}")
     if router_reads not in ("n1", "n2"):
         raise ValueError(f"smallthinker: router_reads {router_reads!r}")
-    t = f"""
-name: "SmallThinker"
-layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
-  cos_data_param {{ batch_size: {batch}
-    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }}
-    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }} }} }}
-layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
-  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
-    {embed} }} }}
-"""
+    t = _lm_inputs("SmallThinker", batch, seq, vocab, hidden,
+                   gauss if embed_std is None else _gauss(embed_std))
     h = "h0"
     for i in range(layers):
         p = f"L{i}"
@@ -661,39 +647,23 @@ layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
                else "")
         rot = "true" if rope_layout[published] else "false"
         router = (f' bottom: "{p}.n1"' if router_reads == "n1" else "")
-        t += f"""
-layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
-  {tag} rms_norm_param {{ eps: {eps} }} }}
+        attn = f"""
 layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
   top: "{p}.a" {tag}
   attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
     head_dim: {head_dim} causal: true rotary: {rot}
-    rope_theta: {rope_theta}{win} {gauss} }} }}
-layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
-  top: "{p}.h1" {tag} }}
-layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
-  {tag} rms_norm_param {{ eps: {eps} }} }}
+    rope_theta: {rope_theta}{win} {gauss} }} }}"""
+        moe = f"""
 layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"{router}
   top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
   moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
     top_k: {top_k} dispatch: "dropless" scoring: "softmax" gated: true
     gate_activation: "relu"
     experts_held: {experts_held} first_expert: {first_expert}
-    {gauss} }} }}
-layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
-  top: "{p}.out" {tag} }}
-"""
-        h = f"{p}.out"
-    t += f"""
-layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
-  rms_norm_param {{ eps: {eps} }} }}
-layer {{ name: "head.logits" type: "InnerProduct" bottom: "head.n"
-  top: "logits" inner_product_param {{ num_output: {vocab} axis: 2
-    bias_term: false {gauss} }} }}
-layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
-  bottom: "target_ids" top: "loss" softmax_param {{ axis: 2 }} }}
-"""
-    return parse_net_prototxt(t)
+    {gauss} }} }}"""
+        block, h = _block(p, h, attn, moe, tag, eps)
+        t += block
+    return parse_net_prototxt(t + _lm_head(h, vocab, gauss, eps))
 
 
 def phi4flash_kinds(total_layers: int = 32):
@@ -759,27 +729,9 @@ def phi4flash(vocab: int = 25008, hidden: int = 2560, heads: int = 40,
             f"phi4flash: layers [{first_layer}, {first_layer + layers}) "
             "read a memory or keys and values that no layer of the cut "
             "makes")
-    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    gauss = _gauss(init_std)
     shared = 'param { name: "E" }' if tie else ""
-
-    def ip(name, bottom, top, n, tag, extra=""):
-        return f"""
-layer {{ name: "{name}" type: "InnerProduct" bottom: "{bottom}" top: "{top}"
-  {tag} {extra} inner_product_param {{ num_output: {n} axis: 2
-    bias_term: false {gauss} }} }}"""
-
-    t = f"""
-name: "Phi4Flash"
-layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
-  cos_data_param {{ batch_size: {batch}
-    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }}
-    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }} }} }}
-layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
-  {shared} embed_param {{ input_dim: {vocab} num_output: {hidden}
-    bias_term: false {gauss} }} }}
-"""
+    t = _lm_inputs("Phi4Flash", batch, seq, vocab, hidden, gauss, shared)
     h = "h0"
     memory = kv = None
     for i, kind in enumerate(run):
@@ -795,15 +747,12 @@ layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
     head_dim: {head_dim} causal: true differential: true
     lambda_init: {lam:.8f} rms_norm_eps: {eps}
     lambda_filler {{ type: "gaussian" std: {lambda_std} }} {gauss}"""
-        t += f"""
-layer {{ name: "{p}.norm1" type: "LayerNorm" bottom: "{h}" top: "{p}.n1"
-  {tag} layer_norm_param {{ eps: {eps} }} }}"""
         if kind in ("mamba", "mamba_memory"):
             tops = f'top: "{p}.a"'
             if kind == "mamba_memory":
                 memory = f"{p}.memory"
                 tops += f' top: "{memory}"'
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.mamba" type: "Mamba" bottom: "{p}.n1" {tops} {tag}
   mamba_param {{ d_inner: {d_inner} d_state: {d_state} d_conv: {d_conv}
     dt_rank: {dt_rank} chunk: {chunk} {gauss}
@@ -817,47 +766,26 @@ layer {{ name: "{p}.mamba" type: "Mamba" bottom: "{p}.n1" {tops} {tag}
                 kv = (f"{p}.k", f"{p}.v")
                 tops += f' top: "{kv[0]}" top: "{kv[1]}"'
                 more = " emit_kv: true"
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
   {tops} {tag}
   attention_param {{ {attn}{more} }} }}"""
         elif kind == "gmu":
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.gmu" type: "GatedMemoryUnit" bottom: "{p}.n1"
   bottom: "{memory}" top: "{p}.a" {tag}
   gated_memory_unit_param {{ {gauss} }} }}"""
         else:
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
   bottom: "{kv[0]}" bottom: "{kv[1]}" top: "{p}.a" {tag}
   attention_param {{ {attn} shared_kv: true }} }}"""
-        t += f"""
-layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
-  top: "{p}.h1" {tag} }}
-layer {{ name: "{p}.norm2" type: "LayerNorm" bottom: "{p}.h1" top: "{p}.n2"
-  {mlp_tag} layer_norm_param {{ eps: {eps} }} }}"""
-        t += ip(f"{p}.gate", f"{p}.n2", f"{p}.g", intermediate, mlp_tag)
-        t += ip(f"{p}.up", f"{p}.n2", f"{p}.u", intermediate, mlp_tag)
-        t += f"""
-layer {{ name: "{p}.act" type: "SiLU" bottom: "{p}.g" top: "{p}.g"
-  {mlp_tag} }}
-layer {{ name: "{p}.prod" type: "Eltwise" bottom: "{p}.g" bottom: "{p}.u"
-  top: "{p}.gu" {mlp_tag} eltwise_param {{ operation: PROD }} }}"""
-        t += ip(f"{p}.down", f"{p}.gu", f"{p}.f", hidden, mlp_tag)
-        t += f"""
-layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
-  top: "{p}.out" {mlp_tag} }}
-"""
-        h = f"{p}.out"
-    t += f"""
-layer {{ name: "head.norm" type: "LayerNorm" bottom: "{h}" top: "head.n"
-  layer_norm_param {{ eps: {eps} }} }}"""
-    t += ip("head.logits", "head.n", "logits", vocab, "", shared)
-    t += """
-layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
-  bottom: "target_ids" top: "loss" softmax_param { axis: 2 } }
-"""
-    return parse_net_prototxt(t)
+        block, h = _block(
+            p, h, mixer, _gated_ffn(p, intermediate, hidden, mlp_tag, gauss),
+            tag, eps, ffn_tag=mlp_tag, norm="LayerNorm")
+        t += block
+    return parse_net_prototxt(t + _lm_head(
+        h, vocab, gauss, eps, norm="LayerNorm", extra=shared))
 
 
 # qwen3_next's published operator schedule (Qwen3-Next-80B-A3B, 48
@@ -901,39 +829,25 @@ def qwen3_next(vocab: int = 18992, hidden: int = 2048, heads: int = 16,
     `target_ids` (one row of 8,192 by default); every block is one
     `recompute_block`; each expert layer's `moe_stats` / `moe_rows`
     tops are net outputs."""
-    gauss = f'weight_filler {{ type: "gaussian" std: {init_std} }}'
+    gauss = _gauss(init_std)
     if first_layer + layers > len(layer_types):
         raise ValueError(f"qwen3_next: layers [{first_layer}, "
                          f"{first_layer + layers}) of {len(layer_types)}")
-    t = f"""
-name: "Qwen3Next"
-layer {{ name: "data" type: "CoSData" top: "input_ids" top: "target_ids"
-  cos_data_param {{ batch_size: {batch}
-    top {{ name: "input_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }}
-    top {{ name: "target_ids" type: INT_ARRAY channels: {seq}
-          sample_num_axes: 1 transpose: true }} }} }}
-layer {{ name: "embed" type: "Embed" bottom: "input_ids" top: "h0"
-  embed_param {{ input_dim: {vocab} num_output: {hidden} bias_term: false
-    {gauss} }} }}
-"""
+    t = _lm_inputs("Qwen3Next", batch, seq, vocab, hidden, gauss)
     h = "h0"
     for i in range(layers):
         p = f"L{i}"
         tag = f'recompute_block: "{p}"' if recompute else ""
-        t += f"""
-layer {{ name: "{p}.norm1" type: "RMSNorm" bottom: "{h}" top: "{p}.n1"
-  {tag} rms_norm_param {{ eps: {eps} }} }}"""
         kind = layer_types[first_layer + i]
         if kind == "linear_attention":
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.gdn" type: "GatedDeltaNet" bottom: "{p}.n1" top: "{p}.a"
   {tag} gated_delta_net_param {{ num_k_heads: {linear_k_heads}
     num_v_heads: {linear_v_heads} head_k_dim: {linear_k_dim}
     head_v_dim: {linear_v_dim} conv_taps: {conv_taps} chunk: {chunk}
     rms_norm_eps: {eps} {gauss} }} }}"""
         elif kind == "full_attention":
-            t += f"""
+            mixer = f"""
 layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
   top: "{p}.a" {tag}
   attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
@@ -944,32 +858,17 @@ layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
             raise ValueError(f"qwen3_next: layer type {kind!r}")
         # blobs: router, W_gate, W_up, W_down, the shared expert's three
         # and its gate
-        t += f"""
-layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
-  top: "{p}.h1" {tag} }}
-layer {{ name: "{p}.norm2" type: "RMSNorm" bottom: "{p}.h1" top: "{p}.n2"
-  {tag} rms_norm_param {{ eps: {eps} }} }}
+        moe = f"""
 layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
   top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
   moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
     top_k: {top_k} dispatch: "dropless" scoring: "softmax" gated: true
     shared_hidden_dim: {shared_width} shared_gate: true
     experts_held: {experts_held} first_expert: {first_expert}
-    {gauss} }} }}
-layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
-  top: "{p}.out" {tag} }}
-"""
-        h = f"{p}.out"
-    t += f"""
-layer {{ name: "head.norm" type: "RMSNorm" bottom: "{h}" top: "head.n"
-  rms_norm_param {{ eps: {eps} }} }}
-layer {{ name: "head.logits" type: "InnerProduct" bottom: "head.n"
-  top: "logits" inner_product_param {{ num_output: {vocab} axis: 2
-    bias_term: false {gauss} }} }}
-layer {{ name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
-  bottom: "target_ids" top: "loss" softmax_param {{ axis: 2 }} }}
-"""
-    return parse_net_prototxt(t)
+    {gauss} }} }}"""
+        block, h = _block(p, h, mixer, moe, tag, eps)
+        t += block
+    return parse_net_prototxt(t + _lm_head(h, vocab, gauss, eps))
 
 
 def lstm_lm(vocab: int = 8801, d_model: int = 1000, seq: int = 20,

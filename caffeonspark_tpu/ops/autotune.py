@@ -262,8 +262,8 @@ def _conv_variants(net, lp, *, dtype_flip: Optional[str]) -> List[dict]:
         if env_s2d is not None:
             s2d_on = env_s2d == "1"
         else:
-            from .pallas_kernels import pallas_enabled
-            s2d_on = pallas_enabled()
+            from .route import on_tpu
+            s2d_on = on_tpu()
         amb = "s2d" if (s2d_on and s2d_ok) else "nchw"
     candidates = ["nchw", "nhwc"] + (["s2d"] if s2d_ok else [])
     out: List[dict] = [{"layout": lo} for lo in candidates if lo != amb]

@@ -38,6 +38,8 @@ from jax import lax
 
 from ..proto.caffe import (EltwiseOp, FillerParameter, LayerParameter,
                            NormalizationMode, NormRegion, PoolMethod)
+from ..utils.flops import visible_scores
+from . import route
 from .recompute import keep, stage
 
 Array = jax.Array
@@ -98,11 +100,15 @@ def stable_hash(name: str) -> int:
 _REGISTRY: Dict[str, "LayerOp"] = {}
 
 
+def _no_params(lp, shapes):
+    return []
+
+
 @dataclass
 class LayerOp:
     name: str
     apply: Callable
-    param_specs: Callable = lambda lp, shapes: []
+    param_specs: Callable = _no_params
     is_loss: bool = False
     is_data: bool = False
     # layer updates running statistics in the forward pass and must run
@@ -113,17 +119,58 @@ class LayerOp:
     # significant bits, so an id above 256 would be rounded to another
     # id (and 999 to 1000, out of range: a NaN loss on the chip, PR 21)
     index_bottoms: Tuple[int, ...] = ()
+    # (lp, specs, tops) -> the layer's forward FLOPs, from the blobs it
+    # computes with [(name, shape, filler)] and its tops {name: shape};
+    # None: the count of `utils.flops` holds (every weight of two or
+    # more axes once a position of the first top)
+    flops: Optional[Callable] = None
+    # (lp) -> None where the layer's time axis may be cut over the `sp`
+    # mesh axis, else (what kind of layer this is, why it may not): the
+    # two halves of `parallel.sp.refuse_time_sharding`'s message; None:
+    # the type has no time axis of its own, any cut is the bottoms'
+    time_sharding: Optional[Callable] = None
 
 
-def register(name: str, *, params=None, is_loss=False, is_data=False,
-             f32_stats=False, index_bottoms=()):
+def register(name: str, *, params=_no_params, is_loss=False, is_data=False,
+             f32_stats=False, index_bottoms=(), flops=None,
+             time_sharding=None):
+    """A layer type is declared here and nowhere else: what other
+    modules have to know of it (`utils.flops`, `parallel.sp`) they ask
+    its `LayerOp`.  A type with blobs of its own that is not one of
+    Caffe's states both `flops` and `time_sharding`
+    (`tests/test_layer_facts.py` holds every registered type to it)."""
     def deco(fn):
-        _REGISTRY[name] = LayerOp(name, fn, params or (lambda lp, s: []),
+        _REGISTRY[name] = LayerOp(name, fn, params,
                                   is_loss=is_loss, is_data=is_data,
                                   f32_stats=f32_stats,
-                                  index_bottoms=tuple(index_bottoms))
+                                  index_bottoms=tuple(index_bottoms),
+                                  flops=flops, time_sharding=time_sharding)
         return fn
     return deco
+
+
+def _rows(tops) -> int:
+    """Positions of a time-major layer: the first top's (T, B, ...)
+    without its feature axis."""
+    return math.prod(next(iter(tops.values()))[:-1])
+
+
+def _products_flops(lp, specs, tops) -> int:
+    """Every product per position: the blobs called W_..., each applied
+    whole to every row; gates, taps and norms are elementwise passes
+    (HBM-bound), not counted."""
+    return 2 * _rows(tops) * sum(math.prod(ps) for nm, ps, _ in specs
+                                 if nm.startswith("W_"))
+
+
+def _no_flops(lp, specs, tops) -> int:
+    """A gather or an elementwise pass: nothing for the MXU."""
+    return 0
+
+
+def _cut_anywhere(lp):
+    """Every row is computed by itself: the time axis may be cut."""
+    return None
 
 
 def get_op(type_name: str) -> LayerOp:
@@ -146,39 +193,13 @@ def _filler(msg, default_type="constant") -> FillerParameter:
 # data layers — net inputs; shapes resolved by the net compiler
 # ---------------------------------------------------------------------------
 
-@register("MemoryData", is_data=True)
-def _memory_data(ctx, lp, params, bottoms):
+def _net_input(ctx, lp, params, bottoms):
     raise RuntimeError("data layers are net inputs; never applied")
 
 
-@register("CoSData", is_data=True)
-def _cos_data(ctx, lp, params, bottoms):
-    raise RuntimeError("data layers are net inputs; never applied")
-
-
-@register("Input", is_data=True)
-def _input(ctx, lp, params, bottoms):
-    raise RuntimeError("data layers are net inputs; never applied")
-
-
-@register("Data", is_data=True)
-def _db_data(ctx, lp, params, bottoms):
-    raise RuntimeError("data layers are net inputs; never applied")
-
-
-@register("HDF5Data", is_data=True)
-def _hdf5_data(ctx, lp, params, bottoms):
-    raise RuntimeError("data layers are net inputs; never applied")
-
-
-@register("DummyData", is_data=True)
-def _dummy_data(ctx, lp, params, bottoms):
-    raise RuntimeError("data layers are net inputs; never applied")
-
-
-@register("ImageData", is_data=True)
-def _image_data(ctx, lp, params, bottoms):
-    raise RuntimeError("data layers are net inputs; never applied")
+for _type in ("MemoryData", "CoSData", "Input", "Data", "HDF5Data",
+              "DummyData", "ImageData"):
+    register(_type, is_data=True)(_net_input)
 
 
 @register("HDF5Output")
@@ -255,13 +276,11 @@ def _s2d_eligible(x, cp, kh, kw, sh, sw, dh, dw) -> bool:
     summation order, so results match the direct conv to float-rounding
     tolerance, not bitwise (like any XLA layout change).  On by default
     on TPU; COS_CONV_S2D=0 forces the direct conv everywhere."""
-    import os
     env = os.environ.get("COS_CONV_S2D")
     if env is not None:
         enabled = env == "1"
     else:
-        from .pallas_kernels import pallas_enabled
-        enabled = pallas_enabled()
+        enabled = route.on_tpu()
     return enabled and _s2d_geometry_ok(x.shape[1], cp, kh, kw, sh, sw,
                                         dh, dw)
 
@@ -274,7 +293,6 @@ def _conv_layout() -> str:
     minormost (lane) dimension without a layout-assignment round trip.
     A/B lever for the roofline experiments (docs/benchmarks.md); numerics
     are identical to float rounding.  Default NCHW."""
-    import os
     return os.environ.get("COS_CONV_LAYOUT", "NCHW").upper()
 
 
@@ -462,7 +480,8 @@ def _embed_params(lp, shapes):
     return specs
 
 
-@register("Embed", params=_embed_params, index_bottoms=(0,))
+@register("Embed", params=_embed_params, index_bottoms=(0,),
+          flops=_no_flops)
 def _embed(ctx, lp, params, bottoms):
     ep = lp.embed_param
     idx = bottoms[0].astype(jnp.int32)
@@ -692,9 +711,7 @@ def _lrn(ctx, lp, params, bottoms):
     # relu in-kernel (pallas) or inline (XLA fallback) — identical
     # semantics on every backend
     fuse_relu = lp.name in ctx.fused_relu_lrn
-    from .pallas_kernels import pallas_enabled
-    interpret = _pallas_interpret()
-    use_kernel = (pallas_enabled() or interpret) and x.ndim == 4
+    kernel = route.kernel(x.ndim == 4, mesh="shard_map")
     if lp.name in ctx.bias_lrn:
         # generalized stem epilogue (net.py bias peephole): the
         # producing conv's bias arrives as params[0] and bias-add +
@@ -703,19 +720,19 @@ def _lrn(ctx, lp, params, bottoms):
         from .pallas_kernels import (bias_relu_lrn_across_channels,
                                      xla_bias_relu_lrn)
         bias = params[0]
-        if use_kernel:
+        if kernel:
             return [_on_batch_shards(
-                lambda a, b: bias_relu_lrn_across_channels(
-                    a, b, n, alpha, beta, k, interpret), x, bias)]
+                kernel.mesh, lambda a, b: bias_relu_lrn_across_channels(
+                    a, b, n, alpha, beta, k, kernel.interpret), x, bias)]
         return [xla_bias_relu_lrn(x, bias, n, alpha, beta, k)]
     if p.norm_region == NormRegion.ACROSS_CHANNELS:
         from .pallas_kernels import lrn_across_channels
-        if use_kernel:
+        if kernel:
             # fused VMEM-resident kernel on TPU, with a matching fused
             # VJP kernel so the training path stays on Pallas
             return [_on_batch_shards(
-                lambda a: lrn_across_channels(
-                    a, n, alpha, beta, k, interpret, fuse_relu), x)]
+                kernel.mesh, lambda a: lrn_across_channels(
+                    a, n, alpha, beta, k, kernel.interpret, fuse_relu), x)]
         if fuse_relu:
             x = jnp.maximum(x, 0)
         # one shared XLA fallback chain (pallas_kernels owns it so the
@@ -1243,63 +1260,19 @@ def _mha_params(lp, shapes):
             ("W_o", (d_model, h * hd), wf)]
 
 
-_FLASH_SUPPRESS = 0      # >0 while tracing a multi-device SPMD step
-_FLASH_MESH: list = []   # (mesh, batch_axes, head_axes, time_axes)
-
-
-@contextlib.contextmanager
-def suppress_flash():
-    """Disable the flash-attention dispatch for the duration — an
-    explicit opt-out for callers (and tests) that need the einsum
-    path regardless of backend; ParallelSolver itself now always
-    installs the flash_mesh route on multi-device meshes."""
-    global _FLASH_SUPPRESS
-    _FLASH_SUPPRESS += 1
-    try:
-        yield
-    finally:
-        _FLASH_SUPPRESS -= 1
-
-
-@contextlib.contextmanager
-def flash_mesh(mesh, batch_axes=("dp",), head_axes=("tp",),
-               time_axes=("sp",)):
-    """Route the flash dispatch through shard_map over `mesh` for the
-    duration of a trace.  Attention is embarrassingly parallel over
-    batch x heads, so each device runs the kernel on its (B/dp, H/tp)
-    local block; when the mesh also shards TIME (sp axis), the body is
-    the differentiable fused RING (parallel.sp._ring_attention_local)
-    — K/V shards rotate on ppermute while flash kernels accumulate —
-    so prototxt-driven sequence-parallel training gets ring+flash
-    without hand-rolled steps."""
-    _FLASH_MESH.append((mesh, tuple(batch_axes), tuple(head_axes),
-                        tuple(time_axes)))
-    try:
-        yield
-    finally:
-        _FLASH_MESH.pop()
-
-
-def _pallas_interpret() -> bool:
-    """COS_FLASH_INTERPRET=1 forces the Pallas kernels (flash and LRN)
-    in interpret mode on any backend — how the CPU suite exercises the
-    shard_map kernel routes on virtual meshes."""
-    return os.environ.get("COS_FLASH_INTERPRET") == "1"
-
-
-def _on_batch_shards(kernel, x, *whole):
+def _on_batch_shards(installed, kernel, x, *whole):
     """Run a batch-major Pallas kernel on each device's batch shard.
 
     A bare pallas_call cannot be partitioned: inside a dp-sharded
     program JAX refuses to lower it ("Mosaic kernels cannot be
     automatically partitioned", the seed's four-chip train step,
-    PR 21).  While a mesh is installed (flash_mesh, the route
+    PR 21).  While a mesh is `installed` (`route.Route.mesh`, the route
     attention already takes) the call goes through shard_map over the
     batch axes instead; `whole` operands (a bias) reach every shard
     unsplit.  Without a mesh, or with batch axes of extent 1, the
     kernel is called directly."""
-    if _FLASH_MESH:
-        mesh, b_axes, _, _ = _FLASH_MESH[-1]
+    if installed:
+        mesh, b_axes, _, _ = installed
         b_axes = tuple(a for a in b_axes if mesh.shape.get(a, 1) > 1)
         if b_axes:
             from jax.sharding import PartitionSpec as P
@@ -1331,21 +1304,20 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None,
     the scope `attn.core`: the kernels' (or the einsums') device time
     apart from the products, norms and rotary turns of the layer around
     them; a windowed layer's under `attn.window` around that."""
-    from .pallas_kernels import flash_attention, pallas_enabled
+    from .pallas_kernels import flash_attention
     t = q.shape[2]
-    interpret = _pallas_interpret()
-    # only 128-aligned sequence lengths take the kernel: Mosaic block
-    # shapes must tile (8, 128), and at small T the O(T²) XLA path is
-    # cheap anyway
-    enabled = ((pallas_enabled() or interpret) and not _FLASH_SUPPRESS
-               and not os.environ.get("COS_DISABLE_FLASH"))
+    # whether the shape tiles depends on the branch below: only
+    # 128-aligned sequence lengths take the kernel (Mosaic block shapes
+    # must tile (8, 128), and at small T the O(T²) XLA path is cheap
+    # anyway), and under a mesh batch and heads have to divide as well
+    kernel = route.kernel(True, mesh="shard_map", attention=True)
     windowed = (jax.named_scope("attn.window") if window
                 else contextlib.nullcontext())
     with windowed, jax.named_scope("attn.core"):
-        if enabled and _FLASH_MESH:
+        if kernel and kernel.mesh:
             from jax.sharding import PartitionSpec as P
             from ..parallel.sp import shard_map_nocheck
-            mesh, b_axes, h_axes, t_axes = _FLASH_MESH[-1]
+            mesh, b_axes, h_axes, t_axes = kernel.mesh
             shape = dict(mesh.shape)
             b_axes = tuple(a for a in b_axes if shape.get(a, 1) > 1)
             h_axes = tuple(a for a in h_axes if shape.get(a, 1) > 1)
@@ -1373,7 +1345,8 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None,
                         functools.partial(
                             _ring_attention_local, axis_name=t_axes[0],
                             causal=causal,
-                            flash="interpret" if interpret else True),
+                            flash=("interpret" if kernel.interpret
+                                   else True)),
                         mesh, (spec, spec, spec), spec)
                     return fl(q, k, v)
                 # local T unsuited to the kernel: einsum path below
@@ -1383,13 +1356,14 @@ def _attention_dispatch(q, k, v, *, causal: bool, mxu_dtype=None,
                     # the kernels size their own tiles from the
                     # shard's shape (`pallas_kernels._flash_tiles`)
                     functools.partial(flash_attention, causal=causal,
-                                      interpret=interpret,
+                                      interpret=kernel.interpret,
                                       mxu_dtype=mxu_dtype, window=window),
                     mesh, (spec, spec, spec), spec)
                 return fl(q, k, v)
             # shapes don't tile the mesh: einsum path below
-        elif enabled and not _FLASH_MESH and t % 128 == 0:
-            return flash_attention(q, k, v, causal, interpret=interpret,
+        elif kernel and t % 128 == 0:
+            return flash_attention(q, k, v, causal,
+                                   interpret=kernel.interpret,
                                    mxu_dtype=mxu_dtype, window=window)
         from ..parallel.sp import attention as _plain_attention
         return _plain_attention(q, k, v, causal=causal, window=window)
@@ -1404,7 +1378,48 @@ def _kernel_operand_dtype(prec, q):
             and jax.default_backend() == "tpu" else None)
 
 
-@register("MultiHeadAttention", params=_mha_params)
+def _attention_time_sharding(lp):
+    ap = lp.attention_param
+    if ap.differential or ap.shared_kv or ap.emit_kv:
+        return ("a differential attention layer or one that shares its "
+                "keys and values",
+                "the ring rotates equal heads of one width and knows no "
+                "second layer's keys")
+    if ap.window:
+        return ("an attention layer with a window",
+                "the ring's hops mask by the causal diagonal alone, so "
+                "the layer would attend to its whole past")
+    return None
+
+
+def _mha_flops(lp, specs, tops):
+    """The projections apply the FULL weight per (t, b) position (top
+    is (T, B, D), not (T, B, 3D)), plus the two attention einsums (QK^T
+    and PV: 2 * 2*B*H*T^2*hd): every score, as this type always counted
+    them, unless a window hides some."""
+    t_s, b_s = next(iter(tops.values()))[:2]
+    ap = lp.attention_param
+    scores = (visible_scores(t_s, True, int(ap.window))
+              if ap.causal and ap.window else t_s * t_s)
+    return (2 * t_s * b_s * sum(math.prod(ps) for _, ps, _ in specs)
+            + 4 * b_s * int(ap.num_heads) * scores * int(ap.head_dim))
+
+
+def _causal_attention_flops(lp, specs, tops, wide):
+    """Every two-axis blob per (t, b) position, plus causal attention
+    over `wide` lanes a query head (the score product's and the
+    weighted value's): the masked half of QK^T and PV is not work, and
+    under a window the scores a row can see and no others."""
+    t_s, b_s = next(iter(tops.values()))[:2]
+    ap = lp.attention_param
+    return (2 * t_s * b_s * sum(math.prod(ps) for _, ps, _ in specs
+                                if len(ps) == 2)
+            + 2 * b_s * int(ap.num_heads)
+            * visible_scores(t_s, True, int(ap.window)) * wide)
+
+
+@register("MultiHeadAttention", params=_mha_params, flops=_mha_flops,
+          time_sharding=_attention_time_sharding)
 def _mha(ctx, lp, params, bottoms):
     """Multi-head self-attention on time-major (T, B, D) input: one
     fused `W_qkv` of equal heads, no positions, no norm (the latent
@@ -1426,14 +1441,10 @@ def _mha(ctx, lp, params, bottoms):
     # (B, H, T, hd)
     q, k, v = (jnp.moveaxis(qkv[:, :, i], (0, 1, 2), (2, 0, 1))
                for i in range(3))
-    var = ctx.variant or {}
-    if var.get("attention") == "reference":
-        # autotune variant: pin the einsum reference path (A/B partner
-        # of the flash dispatch; same math, see tests/test_pallas.py)
-        with suppress_flash():
-            o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
-                                    window=int(ap.window))
-    else:
+    # autotune variant "reference": pin the einsum reference path (A/B
+    # partner of the flash dispatch; same math, see tests/test_pallas.py)
+    pinned = (ctx.variant or {}).get("attention") == "reference"
+    with route.suppress_flash() if pinned else contextlib.nullcontext():
         o = _attention_dispatch(q, k, v, causal=bool(ap.causal),
                                 window=int(ap.window))
     # back to (T, B, H*hd)
@@ -1457,7 +1468,8 @@ def _rms_norm_params(lp, shapes):
     return [("scale", (int(shapes[0][-1]),), f)]
 
 
-@register("RMSNorm", params=_rms_norm_params)
+@register("RMSNorm", params=_rms_norm_params, flops=_no_flops,
+          time_sharding=_cut_anywhere)
 def _rms_norm(ctx, lp, params, bottoms):
     return [rms_norm(bottoms[0], params[0], float(lp.rms_norm_param.eps))]
 
@@ -1504,7 +1516,17 @@ def _mla_params(lp, shapes):
             ("W_o", (d, h * vd), wf)]
 
 
-@register("LatentAttention", params=_mla_params)
+def _mla_flops(lp, specs, tops):
+    """The five projections, and nope + rope wide q/k with v_head_dim
+    wide v."""
+    ap = lp.attention_param
+    return _causal_attention_flops(
+        lp, specs, tops, int(ap.qk_nope_head_dim)
+        + int(ap.qk_rope_head_dim) + int(ap.v_head_dim))
+
+
+@register("LatentAttention", params=_mla_params, flops=_mla_flops,
+          time_sharding=_attention_time_sharding)
 def _mla(ctx, lp, params, bottoms):
     """Multi-head latent attention without q compression (deepseek_v3
     with `q_lora_rank: null`) on time-major (T, B, D) input:
@@ -1603,7 +1625,18 @@ def _gqa_params(lp, shapes):
     return specs
 
 
-@register("GroupedQueryAttention", params=_gqa_params)
+def _gqa_flops(lp, specs, tops):
+    """The four projections (W_k and W_v at their own fewer heads),
+    and head_dim wide q/k and v for every QUERY head; a differential
+    layer's values are two heads wide: the score head_dim, the weighted
+    value 2 x head_dim a pair."""
+    ap = lp.attention_param
+    return _causal_attention_flops(
+        lp, specs, tops, (3 if ap.differential else 2) * int(ap.head_dim))
+
+
+@register("GroupedQueryAttention", params=_gqa_params, flops=_gqa_flops,
+          time_sharding=_attention_time_sharding)
 def _gqa(ctx, lp, params, bottoms):
     """Self-attention with fewer key/value heads than query heads
     (lfm2, and most dense decoders since) on time-major (T, B, D) input:
@@ -1744,53 +1777,34 @@ def causal_taps(z, taps):
                for j in range(n_taps))
 
 
-# What was lowered, by call shape: the form ("kernel" or "xla"), the
-# kernels' tiles, the layers that took it.  Static, written while a
-# program is traced; the -train job puts it into its metrics as
-# `info.taps`.
-_TAPS_PLANS: dict = {}
-
-
-def taps_plans() -> dict:
-    return {k: dict(v, sites=list(v["sites"]))
-            for k, v in _TAPS_PLANS.items()}
-
-
 def causal_taps_silu(z, taps, bias=None, *, site: str = ""):
     """silu(`causal_taps`(z[..., :C], taps) [+ bias]) for time-major z
     (T, B, W), taps (C, L), bias (C,): the convolution stage of the
     Gated DeltaNet and Mamba layers, over the first C channels of the
     product's W-wide output.
 
-    Two forms compute it, chosen by what can be observed here, no
-    option: the Mosaic kernels (`pallas_kernels.causal_taps_silu_
-    kernels`: one pass forward and one backward, each element read where
-    it lies in z) on the TPU (`pallas_enabled()`; in interpret mode under
-    COS_FLASH_INTERPRET=1, the CPU suite's way in) when float32 comes
-    in, C and W fill whole 128-lane tiles, T whole sublane groups, and no
-    mesh of several devices is installed (a bare Mosaic call cannot be
-    partitioned); else the XLA form, `causal_taps_silu_xla`, which is
-    also what the kernels' tests are held to.  `taps_plans()` says which
-    one a shape was lowered to, and for which layers (`site`)."""
-    from .pallas_kernels import (causal_taps_silu_kernels, pallas_enabled,
-                                 taps_plan)
+    The Mosaic kernels (`pallas_kernels.causal_taps_silu_kernels`: one
+    pass forward and one backward, each element read where it lies in
+    z) where `route.kernel` says so: C and W fill whole 128-lane tiles,
+    T whole sublane groups (`taps_plan`); else `causal_taps_silu_xla`.
+    `route.plans()["taps"]` (the job's `info.taps`) says by call shape
+    which form it was lowered to (`form`: "kernel" with the kernels'
+    tiles, or "xla") and for which layers (`sites`)."""
+    from .pallas_kernels import causal_taps_silu_kernels, taps_plan
     t, b, w = z.shape
     c, n = taps.shape
-    interpret = _pallas_interpret()
     plan = taps_plan(t, c, w, n)
-    kernel = ((pallas_enabled() or interpret) and not _FLASH_MESH
-              and plan is not None
-              and all(a.dtype == jnp.float32 for a in (z, taps)
-                      + (() if bias is None else (bias,))))
-    entry = _TAPS_PLANS.setdefault(
-        f"{b}x{t} {c} of {w} channels {n} taps {z.dtype.name}"
-        f"{'' if bias is None else ' bias'}", {"sites": []})
+    kernel = route.kernel(plan, z, taps, *(() if bias is None else (bias,)))
+    entry = route.lowered(
+        "taps", f"{b}x{t} {c} of {w} channels {n} taps {z.dtype.name}"
+        f"{'' if bias is None else ' bias'}")
+    entry.setdefault("sites", [])
     entry.update({"form": "kernel", **plan} if kernel else {"form": "xla"})
     if site and site not in entry["sites"]:
         entry["sites"].append(site)
     if kernel:
         return causal_taps_silu_kernels(z, taps, bias, plan,
-                                        interpret=interpret)
+                                        interpret=kernel.interpret)
     return causal_taps_silu_xla(z, taps, bias)
 
 
@@ -1814,7 +1828,8 @@ def short_conv_mix(b, c, u, taps, bias=None):
     return c * conv
 
 
-@register("ShortConv", params=_short_conv_params)
+@register("ShortConv", params=_short_conv_params, flops=_products_flops,
+          time_sharding=_cut_anywhere)
 def _short_conv(ctx, lp, params, bottoms):
     """The gated short convolution (lfm2's `conv` operator) on
     time-major (T, B, D) input:
@@ -1904,18 +1919,6 @@ def _unit_lower_inverse(m, precision):
 # 8,192 tokens is not rounded to bfloat16 once a chunk), as the router's
 _GDN_PRECISION = lax.Precision.HIGHEST
 
-# What was lowered, by operator shape: the form ("kernel" or "xla"), the
-# chunk, the chunks a row, between two kept states and a call (or
-# loop), the heads and the state's bytes.  Static, written while a
-# program is traced; the -train job puts it into its metrics as
-# `info.gdn`.
-_GDN_PLANS: dict = {}
-
-
-def gdn_plans() -> dict:
-    return {k: dict(v) for k, v in _GDN_PLANS.items()}
-
-
 # chunks between two states that the forward pass keeps (the residual
 # of either form: (chunks a row / group) states of (dk, dv) a head), the
 # backward pass computing a group again from the state at its edge.  In
@@ -2002,29 +2005,23 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int):
     nothing a chunk; the backward pass computes a group again from
     there.
 
-    Two forms compute it, chosen by what can be observed here, no
-    option: the Mosaic kernels (`pallas_kernels.gated_delta_rule_
-    kernels`: the states in VMEM from the first chunk to the last) on
-    the TPU (`pallas_enabled()`; in interpret mode under
-    COS_FLASH_INTERPRET=1, the CPU suite's way in) when R chunk and both
-    head sizes fill whole 128-lane tiles, float32 comes in and no mesh
-    of several devices is installed (a bare Mosaic call cannot be
-    partitioned); else the XLA form, `gated_delta_rule_xla`, which is
-    also what the kernels' tests are held to.  `gdn_plans()` says which
-    one a shape was lowered to."""
+    The Mosaic kernels (`pallas_kernels.gated_delta_rule_kernels`: the
+    states in VMEM from the first chunk to the last) where
+    `route.kernel` says so: R chunk and both head sizes fill whole
+    128-lane tiles (`gdn_rule_tiles`); else `gated_delta_rule_xla`.
+    `route.plans()["gdn"]` (the job's `info.gdn`) says by operator
+    shape which form it was lowered to (`rule`: "kernel" or "xla"), the
+    chunk, the chunks a row, between two kept states and a call (or
+    loop), the heads and the state's bytes."""
     from .pallas_kernels import (gated_delta_rule_kernels, gdn_rule_steps,
-                                 gdn_rule_tiles, pallas_enabled)
+                                 gdn_rule_tiles)
     b, hk, t, dk = q.shape
     r, dv = v.shape[2], v.shape[-1]
     c = int(chunk)
     if c & (c - 1):
         raise ValueError(f"gated_delta_rule: chunk {c} is not a power "
                          "of two")
-    interpret = _pallas_interpret()
-    kernel = ((pallas_enabled() or interpret) and not _FLASH_MESH
-              and gdn_rule_tiles(r, c, dk, dv)
-              and all(a.dtype == jnp.float32
-                      for a in (q, k, v, g, beta)))
+    kernel = route.kernel(gdn_rule_tiles(r, c, dk, dv), q, k, v, g, beta)
     n = -(-t // c)
     # chunks between two kept states, and chunks the states pass through
     # in one call: a row, padded to whole grid steps and groups (the
@@ -2035,14 +2032,15 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int):
         group = steps * group_steps
     else:
         group = a_call = min(_GDN_GROUP, n)
-    _GDN_PLANS[f"{b}x{t} {hk}/{hk * r} heads {dk}/{dv}"] = {
-        "rule": "kernel" if kernel else "xla",
-        "chunk": c, "chunks_a_row": n, "chunks_a_group": group,
-        "chunks_a_call": a_call, "heads": hk * r,
-        "state_bytes": b * hk * r * dk * dv * 4}
+    route.lowered(
+        "gdn", f"{b}x{t} {hk}/{hk * r} heads {dk}/{dv}",
+        rule="kernel" if kernel else "xla",
+        chunk=c, chunks_a_row=n, chunks_a_group=group,
+        chunks_a_call=a_call, heads=hk * r,
+        state_bytes=b * hk * r * dk * dv * 4)
     if kernel:
         return gated_delta_rule_kernels(q, k, v, g, beta, c,
-                                        interpret=interpret)
+                                        interpret=kernel.interpret)
     return gated_delta_rule_xla(q, k, v, g, beta, c)
 
 
@@ -2079,7 +2077,28 @@ def gated_delta_rule_xla(q, k, v, g, beta, c: int):
     return jnp.moveaxis(o, 0, 3).reshape(b, hk, r, full, dv)[..., :t, :]
 
 
-@register("GatedDeltaNet", params=_gdn_params)
+def _whole_sequence(lp):
+    """A layer whose state runs along the whole sequence on one device
+    (or, a Gated Memory Unit, that reads such a layer's output row for
+    row): no exchange of the state between time shards is written."""
+    return ("GatedDeltaNet/Mamba/GatedMemoryUnit layers",
+            "the chunked scan carries its state along the whole sequence "
+            "on one device")
+
+
+def _gdn_flops(lp, specs, tops):
+    """The three products per position, plus the recurrence as
+    written: per token and value head the read S^T k, the rank-one
+    write and the read S^T q, 2 x dk x dv each (the decay of the state
+    is an elementwise pass; taps, gates and norms are not counted)."""
+    gp = lp.gated_delta_net_param
+    return _products_flops(lp, specs, tops) + _rows(tops) * (
+        int(gp.num_v_heads) * 3 * 2 * int(gp.head_k_dim)
+        * int(gp.head_v_dim))
+
+
+@register("GatedDeltaNet", params=_gdn_params, flops=_gdn_flops,
+          time_sharding=_whole_sequence)
 def _gdn(ctx, lp, params, bottoms):
     """The Gated DeltaNet operator (qwen3_next's linear-attention
     layer) on time-major (T, B, D) input:
@@ -2178,7 +2197,8 @@ def _layer_norm_params(lp, shapes):
              else FillerParameter(type="constant", value=0.0))]
 
 
-@register("LayerNorm", params=_layer_norm_params)
+@register("LayerNorm", params=_layer_norm_params, flops=_no_flops,
+          time_sharding=_cut_anywhere)
 def _layer_norm(ctx, lp, params, bottoms):
     return [layer_norm(bottoms[0], params[0], params[1],
                        float(lp.layer_norm_param.eps))]
@@ -2212,17 +2232,6 @@ def _mamba_params(lp, shapes):
             ("W_out", (d, di), wf)]
 
 
-# What was lowered, by operator shape: the form ("kernel" or "xla"), the
-# chunk, the chunks a row, the channels a program and the VMEM a call
-# takes.  Static, written while a program is traced; the -train job
-# puts it into its metrics as `info.ssm`.
-_SSM_PLANS: dict = {}
-
-
-def ssm_plans() -> dict:
-    return {k: dict(v) for k, v in _SSM_PLANS.items()}
-
-
 def selective_scan(u, dt, a, b, c, chunk: int = 64):
     """The selective state-space recurrence over the sequence,
 
@@ -2235,35 +2244,31 @@ def selective_scan(u, dt, a, b, c, chunk: int = 64):
     chunk's edge, nothing a token; the backward pass computes a chunk's
     states again from there.
 
-    Two forms compute it, chosen by what can be observed here, no
-    option: the Mosaic kernels (`pallas_kernels.selective_scan_kernels`:
+    The Mosaic kernels (`pallas_kernels.selective_scan_kernels`:
     channels on lanes, the (N, channels) state in VMEM from a row's
-    first chunk to its last) on the TPU (`pallas_enabled()`; in
-    interpret mode under COS_FLASH_INTERPRET=1, the CPU suite's way in)
-    when the channels fill whole 128-lane tiles, the states whole
-    sublanes, float32 comes in and no mesh of several devices is
-    installed (a bare Mosaic call cannot be partitioned); else the XLA
-    form, `selective_scan_xla`, which is also what the kernels' tests
-    are held to.  `ssm_plans()` says which one a shape was lowered to."""
-    from .pallas_kernels import (pallas_enabled, selective_scan_kernels,
-                                 ssm_scan_plan)
+    first chunk to its last) where `route.kernel` says so: the channels
+    fill whole 128-lane tiles, the states whole sublanes
+    (`ssm_scan_plan`); else `selective_scan_xla`.
+    `route.plans()["ssm"]` (the job's `info.ssm`) says by operator shape
+    which form it was lowered to (`form`: "kernel" or "xla"), the chunk,
+    the chunks a row, the kept edges' bytes and, of the kernels, the
+    channels a program and the VMEM a call takes."""
+    from .pallas_kernels import selective_scan_kernels, ssm_scan_plan
     bsz, t, ch = u.shape
     n = a.shape[1]
-    interpret = _pallas_interpret()
     plan = ssm_scan_plan(t, ch, n, int(chunk))
-    kernel = ((pallas_enabled() or interpret) and not _FLASH_MESH
-              and plan is not None
-              and all(x.dtype == jnp.float32 for x in (u, dt, a, b, c)))
+    kernel = route.kernel(plan, u, dt, a, b, c)
     length = plan["chunk"] if kernel else min(int(chunk), t)
-    _SSM_PLANS[f"{bsz}x{t} {ch} channels {n} states"] = dict(
-        {"form": "kernel" if kernel else "xla", "chunk": length,
-         "chunks_a_row": -(-t // length),
-         "edge_bytes": bsz * -(-t // length) * ch * n * 4},
+    route.lowered(
+        "ssm", f"{bsz}x{t} {ch} channels {n} states",
+        form="kernel" if kernel else "xla", chunk=length,
+        chunks_a_row=-(-t // length),
+        edge_bytes=bsz * -(-t // length) * ch * n * 4,
         **({"channels_a_program": plan["channels"],
             "vmem_bytes": plan["vmem_bytes"]} if kernel else {}))
     if kernel:
         return selective_scan_kernels(u, dt, a, b, c, plan,
-                                      interpret=interpret)
+                                      interpret=kernel.interpret)
     return selective_scan_xla(u, dt, a, b, c, length)
 
 
@@ -2305,7 +2310,19 @@ def selective_scan_xla(u, dt, a, b, c, chunk: int):
     return jnp.moveaxis(y, 0, 1).reshape(bsz, n * length, ch)[:, :t]
 
 
-@register("Mamba", params=_mamba_params)
+def _mamba_flops(lp, specs, tops):
+    """Every product per position, and the recurrence as written
+    besides: per token, channel and state the decay's product and its
+    exponential, the write, the update and the read, 9 elementwise
+    operations (vector-unit work, counted as operations, not as MXU
+    work); taps, gates and softplus are not counted."""
+    a_log = dict((nm, ps) for nm, ps, _ in specs)["A_log"]
+    return (_products_flops(lp, specs, tops)
+            + 9 * _rows(tops) * math.prod(a_log))
+
+
+@register("Mamba", params=_mamba_params, flops=_mamba_flops,
+          time_sharding=_whole_sequence)
 def _mamba(ctx, lp, params, bottoms):
     """The selective state-space mixer (Mamba, arXiv:2312.00752) on
     time-major (T, B, D) input:
@@ -2380,7 +2397,8 @@ def _gmu_params(lp, shapes):
     return [("W_in", (m, d), wf), ("W_out", (d, m), wf)]
 
 
-@register("GatedMemoryUnit", params=_gmu_params)
+@register("GatedMemoryUnit", params=_gmu_params, flops=_products_flops,
+          time_sharding=_whole_sequence)
 def _gmu(ctx, lp, params, bottoms):
     """The Gated Memory Unit (arXiv:2507.06607) on time-major input:
     y = (m * silu(x W_in)) W_out, x (T, B, D) the normed stream, m (T,
@@ -2459,7 +2477,33 @@ def _moe_params(lp, shapes):
             ("W2", (e, h, d), unif(h))]
 
 
-@register("MixtureOfExperts", params=_moe_params)
+def _moe_flops(lp, specs, tops):
+    """Router over all experts for every token, plus the expert
+    products a token's k assignments touch.  `capacity` dispatch runs
+    every expert on its full (C, D) buffer; `dropless` runs, for an
+    even router, the k x held / experts of the assignments that fall on
+    the experts this layer holds, plus the shared experts on every
+    token."""
+    shapes = dict((nm, ps) for nm, ps, _ in specs)
+    n = _rows(tops)
+    mp = lp.moe_param
+    e, k = int(mp.num_experts), max(1, int(mp.top_k))
+    total = 2 * n * math.prod(shapes["router"])
+    if mp.dispatch == "dropless":
+        held = int(mp.experts_held) or e
+        per_expert = sum(math.prod(ps[1:]) for nm, ps in shapes.items()
+                         if nm.startswith("W"))
+        total += int(2 * n * k * held / e * per_expert)
+        total += 2 * n * sum(math.prod(ps) for nm, ps in shapes.items()
+                             if nm.startswith("S_"))
+        return total
+    cap = max(1, int(math.ceil(k * n / e * float(mp.capacity_factor))))
+    return total + 2 * cap * (math.prod(shapes["W1"])
+                              + math.prod(shapes["W2"]))
+
+
+@register("MixtureOfExperts", params=_moe_params, flops=_moe_flops,
+          time_sharding=_cut_anywhere)
 def _moe(ctx, lp, params, bottoms):
     """Top-k routed expert FFN on (..., D) input — extension beyond the
     reference.  `moe_param.dispatch: "dropless"` is `_moe_dropless`
@@ -2545,14 +2589,9 @@ def _moe_chunk_rows(n: int, k: int, held: int, e: int) -> int:
     return min(k * n, -(-want // _MOE_ROW_TILE) * _MOE_ROW_TILE)
 
 
-# What the dropless layers were lowered to, by layer shape: the layers
-# that took it, the k N assignments, the rows and the number of passes,
-# the passes an even router fills, the row tile, the forward operations
-# a held row costs and the bytes of expert weight gradient that the
-# backward loop carries, added into once a pass that runs.  Static,
-# written while a program is traced; the -train job puts it into its
-# metrics as `info.moe`.
-_MOE_PLANS: dict = {}
+# `route.plans()["moe"]`, under the two names that the benchmark's
+# reader of `moe.products_mfu_pct.train` and its tests know
+_MOE_PLANS = route.entries("moe")
 
 
 def moe_plans() -> dict:
@@ -2757,18 +2796,23 @@ def _moe_dropless(ctx, lp, params, bottoms):
     w_in = (pd["W_gate"], pd["W_up"]) if gated else (pd["W1"],)
     w_out = pd["W_down"] if gated else pd["W2"]
     hidden, products = int(w_out.shape[1]), len(w_in) + 1
-    plan = _MOE_PLANS.setdefault(
-        f"{n}x{d} top {k} of {e}, {held} held x {hidden}"
+    # `info.moe`, by layer shape: the layers that took it, the k N
+    # assignments, the rows and the number of passes, the passes an even
+    # router fills, the row tile, the forward operations a held row
+    # costs and the bytes of expert weight gradient that the backward
+    # loop carries, added into once a pass that runs
+    plan = route.lowered(
+        "moe", f"{n}x{d} top {k} of {e}, {held} held x {hidden}"
         f"{' gated' if gated else ''}"
         f"{' by relu' if gated == 'relu' else ''}, "
-        f"shared {int(mp.shared_hidden_dim)}",
-        {"layers": [], "assignments": k * n, "rows": rows,
-         "passes": n_pass,
-         "passes_even_router": -(-(k * n * held) // (e * rows)),
-         "row_tile": _MOE_ROW_TILE,
-         "row_flops": 2 * d * hidden * products,
-         "carry_bytes": held * products * d * hidden
-         * jnp.dtype(w_out.dtype).itemsize})
+        f"shared {int(mp.shared_hidden_dim)}")
+    plan.setdefault("layers", [])
+    plan.update(
+        assignments=k * n, rows=rows, passes=n_pass,
+        passes_even_router=-(-(k * n * held) // (e * rows)),
+        row_tile=_MOE_ROW_TILE, row_flops=2 * d * hidden * products,
+        carry_bytes=(held * products * d * hidden
+                     * jnp.dtype(w_out.dtype).itemsize))
     if lp.name not in plan["layers"]:
         plan["layers"].append(lp.name)
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import route
 from .recompute import keep
 
 TILE = 512  # spatial lanes per block (4 × 128)
@@ -333,17 +334,17 @@ def _int8_matmul_kernel(x_ref, w_ref, o_ref):
         preferred_element_type=jnp.int32)
 
 
-def int8_matmul(xq: jax.Array, wq: jax.Array, *,
-                interpret: bool = False) -> jax.Array:
-    """(M, K) int8 @ (N, K) int8ᵀ → (M, N) int32.  Pallas-tiled when
-    the shapes tile (grid over M/N blocks, K resident per block — the
-    flash kernels' layout); XLA int8 dot_general otherwise (same
-    int32-accumulated math on every backend, incl. CPU)."""
+def int8_matmul(xq: jax.Array, wq: jax.Array) -> jax.Array:
+    """(M, K) int8 @ (N, K) int8ᵀ → (M, N) int32.  Pallas-tiled where
+    `route.kernel` says so and the shapes tile (grid over M/N blocks, K
+    resident per block — the flash kernels' layout); XLA int8
+    dot_general otherwise (same int32-accumulated math on every
+    backend, incl. CPU)."""
     m, kk = xq.shape
     n = wq.shape[0]
-    tiles = (m % INT8_BLOCK_M == 0 and n % INT8_BLOCK_N == 0
-             and kk % INT8_BLOCK_LANE == 0)
-    if tiles and (interpret or pallas_enabled()):
+    kernel = route.kernel(m % INT8_BLOCK_M == 0 and n % INT8_BLOCK_N == 0
+                          and kk % INT8_BLOCK_LANE == 0)
+    if kernel:
         xspec = pl.BlockSpec((INT8_BLOCK_M, kk), lambda i, j: (i, 0),
                              memory_space=pltpu.VMEM)
         wspec = pl.BlockSpec((INT8_BLOCK_N, kk), lambda i, j: (j, 0),
@@ -357,7 +358,7 @@ def int8_matmul(xq: jax.Array, wq: jax.Array, *,
             grid=(m // INT8_BLOCK_M, n // INT8_BLOCK_N),
             in_specs=[xspec, wspec],
             out_specs=ospec,
-            interpret=interpret,
+            interpret=kernel.interpret,
         )(xq, wq)
     return jax.lax.dot_general(xq, wq, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.int32)
@@ -365,7 +366,6 @@ def int8_matmul(xq: jax.Array, wq: jax.Array, *,
 
 def int8_inner_product(x: jax.Array, w: jax.Array, *,
                        transpose: bool = False,
-                       interpret: bool = False,
                        w_scale: Optional[jax.Array] = None
                        ) -> jax.Array:
     """Quantized InnerProduct forward: y ≈ x @ wᵀ (Caffe layout; or
@@ -397,18 +397,8 @@ def int8_inner_product(x: jax.Array, w: jax.Array, *,
         wqn, sw = wn, w_scale
     else:
         wqn, sw = quantize_int8(wn, None)
-    acc = int8_matmul(xq, wqn, interpret=interpret)
+    acc = int8_matmul(xq, wqn)
     return (acc.astype(jnp.float32) * (sx * sw)).astype(x.dtype)
-
-
-def pallas_enabled() -> bool:
-    """Pallas kernels activate on the TPU backend only (CPU tests use
-    interpret=True explicitly).  A backend that fails to initialise
-    raises here — it must not quietly become "no Pallas"."""
-    import os
-    if os.environ.get("COS_DISABLE_PALLAS"):
-        return False
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -925,14 +915,12 @@ def _masked_tiles(kernel: str, t: int, block_q: int, block_k: int,
     return sum(hi - lo for lo, hi in spans), visited
 
 
-# What was lowered, by call shape: the tiles chosen, the calls an
-# attention takes and the share of score tiles under the masked body.
-# Static, written while a program is traced; `flash_plans()` is what
-# the -train job puts into its metrics after the first step.
-_FLASH_PLANS: dict = {}
-
-
 def _note_plan(kernel, shape, causal, chunk, tiles, window=0):
+    """`route.plans()["flash"]` (the job's `info.flash`), by call shape
+    and kernel: the tiles chosen, the calls an attention takes and the
+    share of score tiles under the masked body; under a window also the
+    window, the calls a causal attention over the same chunks takes and
+    the share of its tiles that are visited."""
     bh, t, d, dv, dtype, g = shape
     pairs = _chunk_pairs(t // chunk, causal, window, chunk)
     counts = [_masked_tiles(kernel, chunk, *tiles, cz, window,
@@ -941,7 +929,7 @@ def _note_plan(kernel, shape, causal, chunk, tiles, window=0):
     key = (f"{bh}x{t}x{d}/{dv} {jnp.dtype(dtype).name} g{g}"
            f"{' causal' if causal else ''}"
            f"{f' window {window}' if window else ''}")
-    plan = _FLASH_PLANS.setdefault(key, {})[kernel] = {
+    plan = route.lowered("flash", key)[kernel] = {
         "block_q": tiles[0], "block_k": tiles[1], "calls": len(pairs),
         "masked_tile_share": round(masked / visited, 4)}
     if window:
@@ -952,12 +940,6 @@ def _note_plan(kernel, shape, causal, chunk, tiles, window=0):
             visited_tile_share=round(visited / sum(
                 _masked_tiles(kernel, chunk, *tiles, cz)[1]
                 for _, _, cz in whole), 4))
-
-
-def flash_plans() -> dict:
-    """{call shape: {kernel: tiles, calls an attention, masked share}}
-    of every flash attention lowered by this process."""
-    return {k: dict(v) for k, v in _FLASH_PLANS.items()}
 
 
 def _operands(mxu_dtype, *xs):

@@ -28,6 +28,8 @@ import contextlib
 import jax
 from jax.ad_checkpoint import checkpoint_name
 
+from . import route
+
 KEPT = (
     # `pallas_kernels.flash_attention`: cos_flash_fwd's output (read by
     # delta and by W_o's backward) and its log-sum-exp a row (read by
@@ -53,18 +55,10 @@ KEPT = (
 # the `jax.checkpoint` policy of every `recompute_block`
 BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
-# What the blocks traced by this process keep: {block: {name: bytes}},
-# a name's bytes summed over the block's layers, and the stages that
-# ran in each without a checkpoint of their own: {block: count}.
-# Static, written while a program is traced; the -train job puts both
-# into its metrics as `info.recompute`.
-_BLOCKS: dict = {}
-_STAGES: dict = {}
-_TRACING: list = []     # the blocks being traced, by tag
-# The blobs one block makes and blocks further on than the next read
-# (`Net.shared_blobs`), of the nets whose blocks this process traced:
-# the job's `info.shared`.
-_SHARED: dict = {}
+# the entries of the blocks being traced, innermost last: what a block
+# keeps ({name: bytes}, a name's bytes summed over the block's layers)
+# and the stages that ran in it without a checkpoint of their own
+_TRACING: list = []
 
 
 def keep(x: jax.Array, name: str) -> jax.Array:
@@ -73,8 +67,8 @@ def keep(x: jax.Array, name: str) -> jax.Array:
     if name not in KEPT:
         raise KeyError(f"{name!r} is not a value a recompute_block keeps")
     if _TRACING:
-        entry = _BLOCKS[_TRACING[-1]]
-        entry[name] = entry.get(name, 0) + x.size * x.dtype.itemsize
+        kept = _TRACING[-1]["kept"]
+        kept[name] = kept.get(name, 0) + x.size * x.dtype.itemsize
     return checkpoint_name(x, name)
 
 
@@ -85,7 +79,7 @@ def stage(fn):
     a `jax.checkpoint` of its own anywhere else."""
     if not _TRACING:
         return jax.checkpoint(fn)
-    _STAGES[_TRACING[-1]] += 1
+    _TRACING[-1]["stages"] += 1
     return fn
 
 
@@ -93,8 +87,7 @@ def stage(fn):
 def block_trace(tag: str):
     """Around the trace of block `tag`'s layers: what they name is the
     block's entry (a later trace of the block writes it anew)."""
-    _BLOCKS[tag], _STAGES[tag] = {}, 0
-    _TRACING.append(tag)
+    _TRACING.append(route.lowered("recompute", tag, kept={}, stages=0))
     try:
         yield
     finally:
@@ -103,23 +96,28 @@ def block_trace(tag: str):
 
 def note_shared(blobs: dict) -> None:
     """A net whose blocks are being traced says which of their blobs
-    cross blocks."""
-    _SHARED.update(blobs)
+    blocks further on than the next read (`Net.shared_blobs`: per blob
+    the layer that makes it, its readers and bytes): the job's
+    `info.shared`."""
+    for blob, facts in blobs.items():
+        route.lowered("shared", blob, **facts)
 
 
-def shared_plans() -> dict:
-    return {k: dict(v) for k, v in _SHARED.items()}
-
-
-def recompute_plans() -> dict:
-    """{"blocks": {block: {name: bytes}}, "bytes_a_step": their sum,
+def _summary(blocks: dict) -> dict:
+    """`route.plans()["recompute"]`, the job's `info.recompute`:
+    {"blocks": {block: {name: bytes}}, "bytes_a_step": their sum,
     "keep_nothing": the blocks whose layers named nothing,
     "stages_unwrapped": {block: the stages that ran in it without a
     checkpoint of their own, where any did}} for the blocks traced by
     this process; {} without one."""
-    if not _BLOCKS:
+    if not blocks:
         return {}
-    return {"blocks": {t: dict(e) for t, e in _BLOCKS.items() if e},
-            "bytes_a_step": sum(sum(e.values()) for e in _BLOCKS.values()),
-            "keep_nothing": [t for t, e in _BLOCKS.items() if not e],
-            "stages_unwrapped": {t: n for t, n in _STAGES.items() if n}}
+    kept = {t: e["kept"] for t, e in blocks.items()}
+    return {"blocks": {t: dict(e) for t, e in kept.items() if e},
+            "bytes_a_step": sum(sum(e.values()) for e in kept.values()),
+            "keep_nothing": [t for t, e in kept.items() if not e],
+            "stages_unwrapped": {t: e["stages"] for t, e in blocks.items()
+                                 if e["stages"]}}
+
+
+route.summarized("recompute", _summary)

@@ -205,10 +205,9 @@ def tp_param_specs(net, *, min_features: int = TP_MIN_FEATURES
                 # the dropless dispatch's gated experts alike (router,
                 # selection bias and shared experts stay replicated)
                 spec = P("ep", None, None)
-            # the three attention types (differential and shared-KV
-            # layers among them), ShortConv, GatedDeltaNet, Mamba,
-            # GatedMemoryUnit and LayerNorm stay replicated: their head
-            # / channel splits are not written.  A blob shared under a
+            # every other type's blobs stay replicated: a head or
+            # channel split is written for none of them, and a new type
+            # needs no entry here to be right.  A blob shared under a
             # name (`param { name: .. }`, a tied embedding) is its
             # owner's entry of `param_layout` and has that one spec;
             # the layers that read it take it as laid out
@@ -335,14 +334,14 @@ class MeshLayout:
         """A bare pallas_call cannot be GSPMD-partitioned, but attention
         is embarrassingly parallel over batch x heads and LRN over
         batch — on meshes the Pallas dispatches are routed through
-        shard_map (ops.layers.flash_mesh) and each device runs the
+        shard_map (ops.route.flash_mesh) and each device runs the
         kernel on its local block.  Single-device meshes call the
         kernel directly."""
         if self.mesh.devices.size <= 1:
             return fn
 
         def wrapped(*args, _f=fn):
-            from ..ops.layers import flash_mesh
+            from ..ops.route import flash_mesh
             with flash_mesh(self.mesh):  # active during TRACING
                 return _f(*args)
         return wrapped

@@ -38,45 +38,25 @@ def shard_map_nocheck(fn, mesh, in_specs, out_specs):
                      out_specs=out_specs, check_vma=False)
 
 
-# layer types whose state runs along the whole sequence on one device
-# (or, a Gated Memory Unit, that read such a layer's output row for
-# row): their time axis cannot be cut over `sp` (no exchange of the
-# state between the shards is written)
-WHOLE_SEQUENCE_TYPES = ("GatedDeltaNet", "Mamba", "GatedMemoryUnit")
-
-
 def refuse_time_sharding(net) -> None:
-    """A net with a layer of `WHOLE_SEQUENCE_TYPES` is refused on a
-    mesh that shards time, by name, before anything is traced."""
-    bad = [lp.name for lp in net.compute_layers
-           if lp.type in WHOLE_SEQUENCE_TYPES]
+    """A net with a layer whose type says its time axis cannot be cut
+    (`ops.layers.LayerOp.time_sharding`) is refused on a mesh that
+    shards time, by the first such layer's name and reason, before
+    anything is traced."""
+    from ..ops.layers import get_op    # lazy: layers imports this module
+    bad = []
+    for lp in net.compute_layers:
+        ask = get_op(lp.type).time_sharding
+        reason = ask(lp) if ask is not None else None
+        if reason:
+            bad.append((lp.name, reason))
     if bad:
+        name, (what, why) = bad[0]
+        more = sum(reason == bad[0][1] for _, reason in bad) - 1
         raise ValueError(
             f"sequence parallelism (mesh axis sp > 1) is not written for "
-            f"{'/'.join(WHOLE_SEQUENCE_TYPES)} layers ({bad[0]!r} and "
-            f"{len(bad) - 1} more): the chunked scan carries its state "
-            "along the whole sequence on one device; use dp / ep / pp "
+            f"{what} ({name!r} and {more} more): {why}; use dp / ep / pp "
             "axes for this net")
-    bad = [lp.name for lp in net.compute_layers
-           if lp.has("attention_param") and (
-               lp.attention_param.differential
-               or lp.attention_param.shared_kv or lp.attention_param.emit_kv)]
-    if bad:
-        raise ValueError(
-            f"sequence parallelism (mesh axis sp > 1) is not written for "
-            f"a differential attention layer or one that shares its keys "
-            f"and values ({bad[0]!r} and {len(bad) - 1} more): the ring "
-            "rotates equal heads of one width and knows no second layer's "
-            "keys; use dp / ep / pp axes for this net")
-    bad = [lp.name for lp in net.compute_layers
-           if lp.has("attention_param") and lp.attention_param.window]
-    if bad:
-        raise ValueError(
-            f"sequence parallelism (mesh axis sp > 1) is not written for "
-            f"an attention layer with a window ({bad[0]!r} and "
-            f"{len(bad) - 1} more): the ring's hops mask by the causal "
-            "diagonal alone, so the layer would attend to its whole "
-            "past; use dp / ep / pp axes for this net")
 
 
 def attention(q: Array, k: Array, v: Array, *, causal: bool = False,

@@ -601,46 +601,15 @@ class CaffeProcessor:
                 q.stop()
 
     def _note_lowering_plans(self):
-        """The first step is lowered: the plans its operators took go
-        into the metrics and into the log, once.  `info.flash`: what the
-        flash attention calls came to (tiles, calls an attention, share
-        of score tiles under the masked body, and under a window the
-        window, `causal_calls` and `visited_tile_share`;
-        `pallas_kernels.flash_plans`); `info.gdn`: per GatedDeltaNet
-        shape the form of the rule, the chunk, the chunks a row, the
-        heads and the state's bytes (`layers.gdn_plans`); `info.moe`:
-        per dropless expert-layer shape the rows a pass, the passes,
-        the row tile, the operations a held row costs and the bytes of
-        weight gradient the backward loop carries, added into once a
-        pass that runs (`layers.moe_plans`); `info.recompute`: per
-        `recompute_block` the values it keeps for its backward pass and
-        their bytes, the bytes a step, the blocks that keep nothing, the
-        stages a block that ran without a checkpoint of their own
-        (`recompute.recompute_plans`); `info.ssm`: per selective-scan
-        shape the form that ran, the chunk, the chunks a row, the
-        channels a program, the VMEM a call takes and the kept edges'
-        bytes (`layers.ssm_plans`); `info.shared`: the blobs one
-        recompute_block makes and blocks further on than the next read
-        (another layer's keys and values, a scan's output as memory),
-        their readers and bytes (`Net.shared_blobs`, through
-        `recompute.shared_plans`); `info.taps`: per shape of the Gated
-        DeltaNet's and Mamba's convolution + SiLU the form that ran, the
-        kernels' tiles and the layers that took it (`layers.taps_plans`).
-        Static facts, nothing a step on the device."""
-        from .ops.layers import gdn_plans, moe_plans, ssm_plans, taps_plans
-        from .ops.pallas_kernels import flash_plans
-        from .ops.recompute import recompute_plans, shared_plans
-        for key, what, plans in (
-                ("flash", "flash attention", flash_plans()),
-                ("gdn", "gated delta rule", gdn_plans()),
-                ("moe", "expert layers", moe_plans()),
-                ("recompute", "recompute blocks", recompute_plans()),
-                ("ssm", "selective scans", ssm_plans()),
-                ("shared", "blobs shared across blocks", shared_plans()),
-                ("taps", "convolution + SiLU stages", taps_plans())):
-            if plans:
-                self.metrics.set_info(key, plans)
-                _LOG.info("%s as lowered: %s", what, plans)
+        """The first step is lowered: what its operators were lowered to
+        goes into the metrics (`info.<kind>`) and into the log, once.
+        Static facts, nothing a step on the device; each kind's are
+        documented where they are written (`ops.route.lowered`'s
+        callers)."""
+        from .ops import route
+        for kind, plans in sorted(route.plans().items()):
+            self.metrics.set_info(kind, plans)
+            _LOG.info("%s as lowered: %s", kind, plans)
 
     def _expert_window(self, out) -> Optional[ExpertWindow]:
         """The window over the dropless expert layers whose stats the

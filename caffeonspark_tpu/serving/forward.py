@@ -168,7 +168,7 @@ class StagedForward:
                     return {b: blobs[b] for b in _outs}
             if sm.devices.size > 1:
                 def sfwd(*args, _f=sfwd, _m=sm):
-                    from ..ops.layers import flash_mesh
+                    from ..ops.route import flash_mesh
                     with flash_mesh(_m):   # active during TRACING
                         return _f(*args)
             param_sh = {ln: lay.param_sharding[ln]

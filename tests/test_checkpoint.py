@@ -515,11 +515,12 @@ def test_mini_cluster_cli(tmp_path):
 
     solver_txt = tmp_path / "solver.prototxt"
     net_txt = tmp_path / "net.prototxt"
-    net_txt.write_text(open(
-        "/root/reference/data/lenet_memory_train_test.prototxt").read()
-        if os.path.exists(
-            "/root/reference/data/lenet_memory_train_test.prototxt")
-        else NET)
+    # the reference's lenet_memory_train_test.prototxt: LeNet fed from
+    # an LMDB whose path the command line gives
+    from caffeonspark_tpu.models import zoo
+    lenet = zoo.lenet(batch_size=8)
+    lenet.layer[0].source_class = "com.yahoo.ml.caffe.LMDB"
+    net_txt.write_text(lenet.to_text())
     solver_txt.write_text(f"""
 net: "{net_txt}"
 base_lr: 0.01

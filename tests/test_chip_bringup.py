@@ -1,5 +1,5 @@
 """What PR 21 (chip bring-up) added: the chip_smoke.py rehearsal and its
-armed platform check, the one compile-cache helper, pallas_enabled()
+armed platform check, the one compile-cache helper, route.on_tpu()
 without a fallback, and the fleet's refusal to outnumber the chips."""
 
 import os
@@ -130,21 +130,21 @@ def test_aot_namespace_kept_when_env_unset(monkeypatch, tmp_path,
     assert os.path.isdir(ns)
 
 
-# --------------------------------------------------------- pallas_enabled
+# ------------------------------------------------------------ route.on_tpu
 
 def test_pallas_enabled_only_on_tpu_and_never_swallows(monkeypatch):
     import jax
-    from caffeonspark_tpu.ops.pallas_kernels import pallas_enabled
+    from caffeonspark_tpu.ops.route import on_tpu
     monkeypatch.delenv("COS_DISABLE_PALLAS", raising=False)
     for backend, want in (("tpu", True), ("cpu", False), ("gpu", False)):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
-        assert pallas_enabled() is want, backend
+        assert on_tpu() is want, backend
 
     def broken():
         raise RuntimeError("Unable to initialize backend 'tpu'")
     monkeypatch.setattr(jax, "default_backend", broken)
     with pytest.raises(RuntimeError, match="Unable to initialize"):
-        pallas_enabled()
+        on_tpu()
 
 
 # ------------------------------------------------------- one process/chip

@@ -1,5 +1,5 @@
 """kanana-2-30b-a3b through the system against the plain reference
-(`caffeonspark_tpu/models/reference/kanana2.py`, float32, "highest"),
+(`perfbench/reference/kanana2_30b_a3b.py`, float32, "highest"),
 at a small size with the model's structure: 1 dense + 2 expert layers,
 8 sigmoid-routed experts, top-2, 2 shared, latent attention with q/k
 wider than v.
@@ -19,11 +19,11 @@ import numpy as np
 import pytest
 
 from caffeonspark_tpu.models import zoo
-from caffeonspark_tpu.models.reference import kanana2 as ref
 from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
 from caffeonspark_tpu.proto import SolverParameter
 from caffeonspark_tpu.solver import Solver
+from perfbench.reference import kanana2_30b_a3b as ref
 
 SMALL = dict(vocab=64, hidden=32, heads=2, qk_nope=8, qk_rope=4, v_head=6,
              kv_lora_rank=16, dense_width=48, expert_width=12, experts=8,
@@ -294,13 +294,3 @@ def test_full_width_net_text_parses_and_counts_687_5_million():
     assert net.blob_shapes["logits"] == (4096, 2, 16032)
     assert len(net.recompute_blocks) == 6
 
-
-def test_the_benchmark_reference_is_this_reference():
-    """perfbench keeps its own copy (it imports nothing from the
-    program); the two must not drift."""
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    a = open(os.path.join(root, "perfbench", "reference",
-                          "kanana2_30b_a3b.py")).read()
-    b = open(ref.__file__).read()
-    assert a.split('"""', 2)[2] == b.split('"""', 2)[2]

@@ -1,5 +1,5 @@
 """LFM2-24B-A2B through the system against the plain reference
-(`caffeonspark_tpu/models/reference/lfm2.py`, float32, "highest"), at a
+(`perfbench/reference/lfm2_24b_a2b.py`, float32, "highest"), at a
 small size with the model's structure: the published layers 1-5 (a
 dense conv layer, then attention, conv, conv, conv over experts), 8
 sigmoid-routed experts, top-2, no shared one, 4 query heads over 2
@@ -14,11 +14,11 @@ import numpy as np
 import pytest
 
 from caffeonspark_tpu.models import zoo
-from caffeonspark_tpu.models.reference import lfm2 as ref
 from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
 from caffeonspark_tpu.proto import LayerParameter, SolverParameter
 from caffeonspark_tpu.solver import Solver
+from perfbench.reference import lfm2_24b_a2b as ref
 
 SMALL = dict(vocab=64, hidden=32, heads=4, kv_heads=2, head_dim=8,
              dense_width=48, expert_width=12, experts=8, top_k=2,
@@ -415,13 +415,3 @@ def test_flops_and_param_specs_know_the_new_operators():
     assert all(tuple(s) == () for s in specs["L1.attn"].values())
     assert tuple(specs["L1.moe"]["W_up"]) == ("ep", None, None)
 
-
-def test_the_benchmark_reference_is_this_reference():
-    """perfbench keeps its own copy (it imports nothing from the
-    program); the two must not drift."""
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    a = open(os.path.join(root, "perfbench", "reference",
-                          "lfm2_24b_a2b.py")).read()
-    b = open(ref.__file__).read()
-    assert a.split('"""', 2)[2] == b.split('"""', 2)[2]

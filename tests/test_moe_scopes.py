@@ -25,6 +25,7 @@ import pytest
 from caffeonspark_tpu.models import zoo
 from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
+from caffeonspark_tpu.ops import route
 from caffeonspark_tpu.proto import LayerParameter
 
 INNER = ("moe.gather", "moe.products", "moe.combine")
@@ -36,7 +37,7 @@ N, D, H, E, K, HELD = 48, 32, 12, 8, 2, 2
 @pytest.fixture
 def tile8(monkeypatch):
     monkeypatch.setattr(L, "_MOE_ROW_TILE", 8)
-    monkeypatch.setattr(L, "_MOE_PLANS", {})
+    route.forget("moe")
 
 
 def layer_param(scoring="sigmoid", gated=True, shared=2 * H, held=HELD,
@@ -415,7 +416,7 @@ def test_moe_plans_of_the_language_model_cells(monkeypatch, name, n, key,
                                                carry):
     """Every expert layer of the cell's net as `zoo` writes it, traced
     at the cell's tokens a step (shapes only: nothing runs)."""
-    monkeypatch.setattr(L, "_MOE_PLANS", {})
+    route.forget("moe")
     npm = getattr(zoo, name)()
     moe = [lp for lp in npm.layer if lp.type == "MixtureOfExperts"]
     assert len(moe) == layers
@@ -483,7 +484,7 @@ def train_job(tmp_path, monkeypatch, experts, steps=2, observer=None):
     from caffeonspark_tpu.processor import CaffeProcessor
     from caffeonspark_tpu.proto.caffe import Datum
 
-    monkeypatch.setattr(L, "_MOE_PLANS", {})
+    route.forget("moe")
     monkeypatch.setenv("COS_TRANSFORM_THREADS", "0")
     imgs, labels = make_images(32, seed=6)
     LmdbWriter(str(tmp_path / "lmdb")).write([
@@ -536,7 +537,7 @@ def test_train_job_reports_the_pass_plan_as_info_moe(tmp_path, monkeypatch,
                             EXPERTS % ("", 4, 2, 2) if experts else "")
     info = summary.get("info", {})
     said = [r for r in caplog.records
-            if "expert layers as lowered" in r.getMessage()]
+            if "moe as lowered" in r.getMessage()]
     # the layer returns no stats: nothing to say of its passes
     assert "experts" not in summary
     if not experts:

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from caffeonspark_tpu.ops import route
 from caffeonspark_tpu.ops.pallas_kernels import lrn_across_channels
 
 
@@ -136,20 +137,21 @@ def test_bias_relu_lrn_xla_fallback_matches_kernel():
         rtol=2e-5, atol=2e-6)
 
 
-def test_int8_matmul_pallas_matches_xla():
+def test_int8_matmul_pallas_matches_xla(monkeypatch):
     """The tiled int8 kernel is EXACT vs the XLA int8 dot_general
     (int32 accumulation both ways)."""
     from caffeonspark_tpu.ops.pallas_kernels import int8_matmul
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
     rng = np.random.RandomState(9)
     xq = jnp.asarray(rng.randint(-127, 128, (64, 256)).astype(np.int8))
     wq = jnp.asarray(rng.randint(-127, 128, (128, 256)).astype(np.int8))
-    got = int8_matmul(xq, wq, interpret=True)
+    got = int8_matmul(xq, wq)
     ref = jax.lax.dot_general(xq, wq, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.int32)
     assert got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     # non-tiling shapes take the XLA fallback — same result contract
-    got2 = int8_matmul(xq[:50], wq[:100], interpret=True)
+    got2 = int8_matmul(xq[:50], wq[:100])
     np.testing.assert_array_equal(np.asarray(got2),
                                   np.asarray(ref[:50, :100]))
 
@@ -341,7 +343,7 @@ def test_flash_attention_in_chunks_matches_reference(monkeypatch, causal,
                                    rtol=2e-4, atol=1e-5 * g,
                                    err_msg=f"d{name}")
     # the counter: three pairs of chunks under the mask, four without
-    plan = pk.flash_plans()[
+    plan = route.plans()["flash"][
         f"{b * h}x{t}x{d}/{dv} float32 g{g}{' causal' if causal else ''}"]
     assert plan["fwd"]["calls"] == (3 if causal else 4)
     assert plan["dq"]["calls"] == plan["dkv"]["calls"] == (
@@ -425,9 +427,9 @@ def test_windowed_flash_in_chunks_matches_reference(monkeypatch, w,
     assert pk._flash_chunk(t, 128, pk._dq_block_bytes(d, d, 4, 128),
                            pk._dkv_block_bytes(d, d, 4, 128)) == 128
     args, ref, fl = _windowed_pair(t, w, h, g, d, seed=12)
-    pk._FLASH_PLANS.clear()
+    route.forget("flash")
     _assert_same_values_and_grads(args, ref, fl, g)
-    plan = pk.flash_plans()[f"{h}x{t}x{d}/{d} float32 g{g} causal "
+    plan = route.plans()["flash"][f"{h}x{t}x{d}/{d} float32 g{g} causal "
                             f"window {w}"]
     assert plan["fwd"]["calls"] == fwd_calls
     assert plan["fwd"]["causal_calls"] == 3
@@ -447,10 +449,10 @@ def test_a_window_of_all_the_rows_is_plain_causal_to_the_last_bit(w):
         return (fn(q, k, v),) + jax.grad(
             lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2))(q, k, v)
 
-    pk._FLASH_PLANS.clear()
+    route.forget("flash")
     for a, b_ in zip(both(w), both(0)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
-    assert list(pk.flash_plans()) == ["7x512x32/32 float32 g7 causal"]
+    assert list(route.plans()["flash"]) == ["7x512x32/32 float32 g7 causal"]
     with pytest.raises(ValueError, match="causal"):
         pk.flash_attention(q, k, v, False, 128, 128, True, None, 64)
 
@@ -620,10 +622,10 @@ def test_flash_plans_count_the_masked_tiles():
             assert pk._masked_tiles(kernel, t, bq, bk, True) == (
                 masked, visited), (kernel, bq, bk)
     q, k, v = _qkv(7, 1, 2, 1, 512, 32, 32)
-    pk._FLASH_PLANS.clear()
+    route.forget("flash")
     jax.grad(lambda q: jnp.sum(pk.flash_attention(
         q, k, v, True, 128, 128, True)))(q)
-    (shape, plan), = pk.flash_plans().items()
+    (shape, plan), = route.plans()["flash"].items()
     assert shape == "2x512x32/32 float32 g1 causal"
     assert set(plan) == {"fwd", "dq", "dkv"}
     for kernel, p in plan.items():
@@ -643,15 +645,7 @@ def test_train_job_reports_flash_plans():
     class Job:
         metrics = PipelineMetrics()
 
-    from caffeonspark_tpu.ops import layers as L
-    pk._FLASH_PLANS.clear()
-    L._GDN_PLANS.clear()        # `info.gdn`, `info.moe`, `info.ssm`,
-    L._MOE_PLANS.clear()        # `info.recompute` and `info.shared`
-    L._SSM_PLANS.clear()        # and `info.taps` ride the same route
-    L._TAPS_PLANS.clear()
-    from caffeonspark_tpu.ops import recompute
-    recompute._BLOCKS.clear()
-    recompute._SHARED.clear()
+    route.forget()      # every `info.<kind>` rides the same route
     CaffeProcessor._note_lowering_plans(Job)       # no attention: nothing
     assert "info" not in Job.metrics.summary()
     q, k, v = _qkv(8, 1, 4, 2, 256, 64, 64)
@@ -704,7 +698,7 @@ def test_flash_suppressed_under_multi_device_mesh(monkeypatch):
     from caffeonspark_tpu.ops import layers as L
     import caffeonspark_tpu.ops.pallas_kernels as pk
     calls = []
-    monkeypatch.setattr(pk, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(route, "on_tpu", lambda: True)
     monkeypatch.setattr(pk, "flash_attention",
                         lambda q, *a, **k: calls.append(1) or q)
     monkeypatch.delenv("COS_DISABLE_FLASH", raising=False)
@@ -712,7 +706,7 @@ def test_flash_suppressed_under_multi_device_mesh(monkeypatch):
     L._attention_dispatch(q, q, q, causal=True)
     assert calls, "flash must engage when allowed"
     calls.clear()
-    with L.suppress_flash():
+    with route.suppress_flash():
         L._attention_dispatch(q, q, q, causal=True)
     assert not calls, "flash must be suppressed inside the guard"
 
@@ -733,10 +727,10 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip"
             "base_lr: 0.01 random_seed: 1"), npm)
         ps = ParallelSolver(s, build_mesh(**mesh_kw))
         probe = ps._install_flash_mesh(
-            lambda: (L._FLASH_SUPPRESS, len(L._FLASH_MESH)))
+            lambda: (route._SUPPRESS, len(route._MESH)))
         assert probe() == (0, 1), (
             f"{mesh_kw}: mesh must install the shard_map route")
-    assert L._FLASH_SUPPRESS == 0 and not L._FLASH_MESH
+    assert route._SUPPRESS == 0 and not route._MESH
 
 
 def test_flash_mesh_dispatch_fallbacks(monkeypatch):
@@ -751,7 +745,7 @@ def test_flash_mesh_dispatch_fallbacks(monkeypatch):
     import caffeonspark_tpu.parallel.sp as sp_mod
 
     kernel_calls = []
-    monkeypatch.setattr(pk, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(route, "on_tpu", lambda: True)
     monkeypatch.setattr(pk, "flash_attention",
                         lambda *a, **k: kernel_calls.append(1) or a[0])
     monkeypatch.setattr(sp_mod, "_ring_attention_local",
@@ -768,7 +762,7 @@ def test_flash_mesh_dispatch_fallbacks(monkeypatch):
     ]
     for mesh, shape in cases:
         q = jnp.asarray(rng.randn(*shape), jnp.float32)
-        with L.flash_mesh(mesh):
+        with route.flash_mesh(mesh):
             out = L._attention_dispatch(q, q, q, causal=True)
         assert not kernel_calls, (mesh.shape, shape)
         np.testing.assert_allclose(

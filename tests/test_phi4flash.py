@@ -1,5 +1,5 @@
 """Phi-4-mini-flash-reasoning through the system against the plain
-reference (`caffeonspark_tpu/models/reference/phi4flash.py`, float32,
+reference (`perfbench/reference/phi4flash_mini.py`, float32,
 "highest"), at a small size with the model's structure: hidden 64, 4
 query heads of 16 over 2 key/value heads (read as pairs), d_inner 128,
 16 states, 4 taps, a window of 8 keys, a whole model of 8 layers
@@ -16,12 +16,13 @@ import numpy as np
 import pytest
 
 from caffeonspark_tpu.models import zoo
-from caffeonspark_tpu.models.reference import phi4flash as ref
 from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
 from caffeonspark_tpu.ops import pallas_kernels as pk
+from caffeonspark_tpu.ops import route
 from caffeonspark_tpu.proto import NetState, Phase, SolverParameter
 from caffeonspark_tpu.solver import Solver
+from perfbench.reference import phi4flash_mini as ref
 
 SMALL = dict(vocab=96, hidden=64, heads=4, kv_heads=2, head_dim=16,
              intermediate=96, d_inner=128, d_state=16, d_conv=4, dt_rank=4,
@@ -83,6 +84,17 @@ def inputs(ids, tgt):
 def highest():
     with jax.default_matmul_precision("highest"):
         yield
+
+
+@pytest.fixture(autouse=True)
+def one_plain_scan(monkeypatch):
+    """The benchmark's reference puts 256 steps of its scan under one
+    `jax.checkpoint` (`SCAN_BLOCK`: a row of 8,192 has to fit the chip
+    beside the system's step).  A checkpoint boundary changes no value,
+    only what the backward pass holds; this suite compares values, on
+    rows shorter than a block, so it runs the one plain scan (as the
+    copy of the reference that the package kept until PR 45 did)."""
+    monkeypatch.setattr(ref, "SCAN_BLOCK", 0)
 
 
 def close(got, want, rel, what=""):
@@ -283,7 +295,7 @@ def test_state_carries_across_a_chunk_edge(form):
 def test_the_layer_takes_the_kernels_under_interpret(monkeypatch):
     """COS_FLASH_INTERPRET=1 is the CPU suite's way into the kernel
     form: `selective_scan` and the convolution stage before it lower to
-    it, say so in `ssm_plans()` and `taps_plans()`, and the Mamba
+    it, say so in `info.ssm` and `info.taps`, and the Mamba
     layer's output and gradients are the XLA form's."""
     cfg = small_cfg(**MIDDLE)
     net = Net(small_net(**MIDDLE, batch=1, recompute=False),
@@ -296,16 +308,16 @@ def test_the_layer_takes_the_kernels_under_interpret(monkeypatch):
                          layers=["L0.norm1", "L0.mamba"])[0]["L0.a"]
 
     conv = "1x64 128 of 256 channels 4 taps float32 bias"
-    monkeypatch.setattr(L, "_TAPS_PLANS", {})
+    route.forget("taps")
     want, gw = jax.value_and_grad(lambda q: jnp.sum(f(q) ** 2))(params)
-    assert L.ssm_plans()["1x64 128 channels 16 states"]["form"] == "xla"
-    assert L.taps_plans()[conv] == {"form": "xla", "sites": ["L0.mamba"]}
+    assert route.plans()["ssm"]["1x64 128 channels 16 states"]["form"] == "xla"
+    assert route.plans()["taps"][conv] == {"form": "xla", "sites": ["L0.mamba"]}
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
     got, gg = jax.value_and_grad(lambda q: jnp.sum(f(q) ** 2))(params)
-    plan = L.ssm_plans()["1x64 128 channels 16 states"]
+    plan = route.plans()["ssm"]["1x64 128 channels 16 states"]
     assert plan["form"] == "kernel" and plan["chunks_a_row"] == 4
     # and the convolution before it to `cos_taps_fwd` / `cos_taps_bwd`
-    assert L.taps_plans()[conv] == {
+    assert route.plans()["taps"][conv] == {
         "form": "kernel", "time_tile": 64, "channel_tile": 128,
         "sites": ["L0.mamba"]}
     np.testing.assert_allclose(got, want, rtol=1e-5)
@@ -329,14 +341,14 @@ def test_the_convolution_kernels_change_no_value_in_or_out_of_a_block(
     data = batches(1, seed=3)[0]
     net_param = small_net(**MIDDLE, recompute=recompute)
     key = "2x64 128 of 256 channels 4 taps float32 bias"
-    monkeypatch.setattr(L, "_TAPS_PLANS", {})
+    route.forget("taps")
     loss, grads = _loss_and_grads(net_param, params, data)
-    assert L.taps_plans()[key] == {
+    assert route.plans()["taps"][key] == {
         "form": "kernel", "time_tile": 64, "channel_tile": 128,
         "sites": ["L0.mamba", "L2.mamba"]}
     monkeypatch.setattr(pk, "taps_plan", lambda *a: None)
     want, want_grads = _loss_and_grads(net_param, params, data)
-    assert L.taps_plans()[key]["form"] == "xla"
+    assert route.plans()["taps"][key]["form"] == "xla"
     np.testing.assert_allclose(loss, want, rtol=1e-6)
     for k in ("L0.mamba/taps", "L2.mamba/conv_bias", "L0.mamba/W_in"):
         assert np.abs(want_grads[k]).max() > 0, k
@@ -586,18 +598,16 @@ def test_train_job_reports_info_ssm_and_info_shared(monkeypatch):
     from caffeonspark_tpu.metrics import PipelineMetrics
     from caffeonspark_tpu.processor import CaffeProcessor
 
-    from caffeonspark_tpu.ops import recompute
-
     class Job:
         metrics = PipelineMetrics()
 
     # tracing a TRAIN pass of a net with blocks notes what crosses them
-    recompute._SHARED.clear()
+    route.forget("shared")
     net = Net(small_net(**MIDDLE), NetState(phase=Phase.TRAIN))
     ids, tgt = batches(1)[0]
     jax.eval_shape(lambda q: net.loss(q, inputs(ids, tgt), train=True)[0],
                    unflat(ref.init_params(small_cfg(**MIDDLE), 5)))
-    L._SSM_PLANS.clear()
+    route.forget("ssm")
     x, _ = _scan_inputs(bsz=1, t=72, ch=128)
     L.selective_scan(*x, 16)
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")

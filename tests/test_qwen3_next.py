@@ -1,5 +1,5 @@
 """Qwen3-Next-80B-A3B-Instruct through the system against the plain
-reference (`caffeonspark_tpu/models/reference/qwen3_next.py`, float32,
+reference (`perfbench/reference/qwen3_next_80b_a3b.py`, float32,
 "highest"), at a small size with the model's structure: one period of
 the published schedule (Gated DeltaNet, Gated DeltaNet, Gated DeltaNet,
 gated full attention), 2 key heads serving 4 value heads in the linear
@@ -19,11 +19,12 @@ import numpy as np
 import pytest
 
 from caffeonspark_tpu.models import zoo
-from caffeonspark_tpu.models.reference import qwen3_next as ref
 from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
+from caffeonspark_tpu.ops import route
 from caffeonspark_tpu.proto import LayerParameter, SolverParameter
 from caffeonspark_tpu.solver import Solver
+from perfbench.reference import qwen3_next_80b_a3b as ref
 
 SMALL = dict(vocab=64, hidden=32, heads=4, kv_heads=2, head_dim=16,
              rotary_dim=4, linear_k_heads=2, linear_v_heads=4,
@@ -274,7 +275,7 @@ def kernel_route(monkeypatch):
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
     monkeypatch.setattr(pk, "GDN_STEP_CHUNKS", 2)
     monkeypatch.setattr(pk, "GDN_GROUP_CHUNKS", 4)
-    L._GDN_PLANS.clear()
+    route.forget("gdn")
 
 
 # (T, R, chunk): 5 chunks = two groups, the second's last grid step and
@@ -299,7 +300,7 @@ def test_kernel_rule_equals_the_recurrence_and_the_xla_form(
             argnums=(0, 1, 2, 3, 4))(*args))
 
     got, got_grads = both(L.gated_delta_rule)
-    assert L.gdn_plans()[f"1x{t} 1/{r} heads 128/128"]["rule"] == "kernel"
+    assert route.plans()["gdn"][f"1x{t} 1/{r} heads 128/128"]["rule"] == "kernel"
     xla, xla_grads = both(L.gated_delta_rule_xla)
     want, want_grads = _float64_recurrence(args, w)
     top = np.abs(want).max()
@@ -334,7 +335,7 @@ def test_kernel_rule_is_causal(kernel_route):
         assert np.abs(got[..., 150, :] - base[..., 150, :]).max() > 0, name
         if name != "q":
             assert np.abs(got[..., 151:, :] - base[..., 151:, :]).max() > 0
-    assert L.gdn_plans()["1x300 1/2 heads 128/128"]["rule"] == "kernel"
+    assert route.plans()["gdn"]["1x300 1/2 heads 128/128"]["rule"] == "kernel"
 
 
 @pytest.mark.parametrize("why", ["dk", "r_chunk", "bfloat16", "disabled",
@@ -345,7 +346,6 @@ def test_rule_falls_back_to_the_xla_form(monkeypatch, why):
     that are not float32, COS_DISABLE_PALLAS on a TPU backend, a mesh
     of several devices (a bare Mosaic call cannot be partitioned).
     Each time the values are the XLA form's and `info.gdn` says so."""
-    from caffeonspark_tpu.ops import pallas_kernels as pk
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
     dims = dict(b=2, hk=1, dk=128, dv=128)
     chunk, ctx = 64, None
@@ -357,21 +357,21 @@ def test_rule_falls_back_to_the_xla_form(monkeypatch, why):
         # no interpret mode: the backend says TPU, the switch says no
         monkeypatch.delenv("COS_FLASH_INTERPRET")
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        assert pk.pallas_enabled()
+        assert route.on_tpu()
         monkeypatch.setenv("COS_DISABLE_PALLAS", "1")
     elif why == "mesh":
         from caffeonspark_tpu.parallel.mesh import build_mesh
-        ctx = L.flash_mesh(build_mesh(dp=2, devices=jax.devices()[:2]))
+        ctx = route.flash_mesh(build_mesh(dp=2, devices=jax.devices()[:2]))
     args = _rule_inputs(70, **dims)
     if why == "bfloat16":
         args = tuple(a.astype(jnp.bfloat16) for a in args)
-    L._GDN_PLANS.clear()
+    route.forget("gdn")
     if ctx is None:
         got = L.gated_delta_rule(*args, chunk)
     else:
         with ctx:
             got = L.gated_delta_rule(*args, chunk)
-    (plan,) = L.gdn_plans().values()
+    (plan,) = route.plans()["gdn"].values()
     assert plan["rule"] == "xla" and plan["chunks_a_call"] == 70 // chunk + 1
     np.testing.assert_array_equal(
         got, L.gated_delta_rule_xla(*args, chunk))
@@ -426,7 +426,7 @@ def test_gated_delta_net_layer_equals_the_reference_and_is_causal():
     assert all(np.abs(got2[ti, 0] - got[ti, 0]).max() > 0
                for ti in (13, 14, 16, 20))
     # the counter says what was lowered
-    plan = L.gdn_plans()[f"{b}x{t} 2/4 heads 8/8"]
+    plan = route.plans()["gdn"][f"{b}x{t} 2/4 heads 8/8"]
     assert plan == {"rule": "xla", "chunk": 8, "chunks_a_row": 3,
                     "chunks_a_group": 3, "chunks_a_call": 3, "heads": 4,
                     "state_bytes": b * 4 * 8 * 8 * 4}
@@ -442,7 +442,7 @@ def test_train_job_reports_the_lowered_scan_as_info_gdn(monkeypatch):
     class Job:
         metrics = PipelineMetrics()
 
-    L._GDN_PLANS.clear()
+    route.forget("gdn")
     L.gated_delta_rule(*_rule_inputs(300, b=1), 64)
     CaffeProcessor._note_lowering_plans(Job)
     assert Job.metrics.summary()["info"]["gdn"] == {
@@ -451,7 +451,7 @@ def test_train_job_reports_the_lowered_scan_as_info_gdn(monkeypatch):
                                 "chunks_a_call": 5, "heads": 4,
                                 "state_bytes": 4 * 8 * 4 * 4}}
     L.gated_delta_rule(*_rule_inputs(64 * 40, b=1), 64)
-    assert L.gdn_plans()["1x2560 2/4 heads 8/4"]["chunks_a_group"] == 32
+    assert route.plans()["gdn"]["1x2560 2/4 heads 8/4"]["chunks_a_group"] == 32
     # the form that was lowered is part of the line: the kernels where
     # the shape fills the tiles (here in interpret mode), with the
     # chunks a call walks with the states in VMEM: a row of 5 chunks in
@@ -463,7 +463,7 @@ def test_train_job_reports_the_lowered_scan_as_info_gdn(monkeypatch):
         jax.eval_shape(
             lambda *a: L.gated_delta_rule(*a, 64),
             *_rule_inputs(t, b=1, hk=1, dk=128, dv=128))
-        assert L.gdn_plans()[f"1x{t} 1/2 heads 128/128"] == {
+        assert route.plans()["gdn"][f"1x{t} 1/2 heads 128/128"] == {
             "rule": "kernel", "chunk": 64, "chunks_a_row": row,
             "chunks_a_group": group, "chunks_a_call": call,
             "heads": 2, "state_bytes": 2 * 128 * 128 * 4}
@@ -661,7 +661,7 @@ def test_the_layer_takes_the_convolution_kernels_under_interpret(
         monkeypatch, recompute):
     """COS_FLASH_INTERPRET=1 is the CPU suite's way into the kernel form
     of the convolution stage: the Gated DeltaNet layer lowers to
-    `cos_taps_fwd` / `cos_taps_bwd`, says so in `taps_plans()` with the
+    `cos_taps_fwd` / `cos_taps_bwd`, says so in `info.taps` with the
     layer's name, and the net's loss and every gradient are those of a
     build whose convolution keeps the XLA form (the rule's kernels in
     interpret mode on both sides), inside a `recompute_block` and
@@ -676,13 +676,13 @@ def test_the_layer_takes_the_convolution_kernels_under_interpret(
            "target_ids": jnp.roll(ids, 1, 0).astype(jnp.float32)}
 
     def run():
-        monkeypatch.setattr(L, "_TAPS_PLANS", {})
+        route.forget("taps")
         fn = jax.value_and_grad(
             lambda p: net.loss(p, ins, train=True, rng=jax.random.key(1)),
             has_aux=True)
         calls = str(jax.make_jaxpr(fn)(params)).count("cos_taps_")
         (loss, _), g = fn(params)
-        return float(loss), flat(g), L.taps_plans(), calls
+        return float(loss), flat(g), route.plans()["taps"], calls
 
     loss, grads, plans, calls = run()
     assert plans == {"2x64 512 of 768 channels 4 taps float32": {
@@ -708,8 +708,8 @@ def test_under_a_time_sharding_mesh_the_convolution_keeps_the_xla_form(
     convolution is the XLA form, interpret mode or not."""
     from caffeonspark_tpu.parallel.mesh import build_mesh
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(L, "_TAPS_PLANS", {})
-    monkeypatch.setattr(L, "_GDN_PLANS", {})
+    route.forget("taps")
+    route.forget("gdn")
     z = dict(SMALL, **TILED)
     lp = LayerParameter.from_text(
         'name: "g" type: "GatedDeltaNet" bottom: "x" top: "y" '
@@ -719,12 +719,12 @@ def test_under_a_time_sharding_mesh_the_convolution_keeps_the_xla_form(
     x = jax.ShapeDtypeStruct((64, 1, z["hidden"]), jnp.float32)
     blobs = [jax.ShapeDtypeStruct(s[1], jnp.float32)
              for s in op.param_specs(lp, [x.shape])]
-    with L.flash_mesh(build_mesh(dp=1, sp=2, devices=jax.devices()[:2])):
+    with route.flash_mesh(build_mesh(dp=1, sp=2, devices=jax.devices()[:2])):
         jax.eval_shape(lambda x, *b: op.apply(L.Ctx(train=True), lp,
                                               list(b), [x])[0], x, *blobs)
-    assert L.taps_plans() == {"1x64 512 of 768 channels 4 taps float32": {
+    assert route.plans()["taps"] == {"1x64 512 of 768 channels 4 taps float32": {
         "form": "xla", "sites": ["g"]}}
-    assert [p["rule"] for p in L.gdn_plans().values()] == ["xla"]
+    assert [p["rule"] for p in route.plans()["gdn"].values()] == ["xla"]
 
 
 # ------------------------------------------------------------------ the net
@@ -853,12 +853,3 @@ def test_full_width_counts_at_the_cell_shape():
     from caffeonspark_tpu.utils.flops import forward_flops
     assert forward_flops(Net(zoo.qwen3_next())) == fwd
 
-
-def test_the_benchmark_reference_is_this_reference():
-    """perfbench keeps its own copy (it imports nothing from the
-    program); the two must not drift."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    a = open(os.path.join(root, "perfbench", "reference",
-                          "qwen3_next_80b_a3b.py")).read()
-    b = open(ref.__file__).read()
-    assert a.split('"""', 2)[2] == b.split('"""', 2)[2]

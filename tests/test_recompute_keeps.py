@@ -34,6 +34,7 @@ from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
 from caffeonspark_tpu.ops import pallas_kernels as pk
 from caffeonspark_tpu.ops import recompute as R
+from caffeonspark_tpu.ops import route
 
 T, B, D = 128, 1, 32
 N = T * B
@@ -137,8 +138,7 @@ layer {{ name: "{c}.res" type: "Eltwise" bottom: "{h}" bottom: "{c}.a"
 def interpret(monkeypatch):
     """The kernels' route, in interpret mode; fresh counters."""
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(R, "_BLOCKS", {})
-    monkeypatch.setattr(R, "_STAGES", {})
+    route.forget("recompute")
 
 
 def plain(monkeypatch):
@@ -263,7 +263,7 @@ def test_recomputation_holds_no_kept_forward(interpret, monkeypatch, case):
     assert named == kept() and set(named) <= set(R.KEPT)
     # and nothing else of the block's making: the cotangent alone
     assert other == [(T, B, D)]
-    assert R.recompute_plans()["blocks"] == {case: named}
+    assert route.plans()["recompute"]["blocks"] == {case: named}
 
     plain(monkeypatch)
     body = recomputations(program())[-1].params["jaxpr"]
@@ -351,7 +351,7 @@ def test_names_are_identities_outside_a_block(interpret, monkeypatch, where):
     assert {"flash.out", "gdn.edges", "moe.order"} <= set(names)
     assert all(e.params["policy"] is None for e in eqns(named)
                if e.primitive.name in ("checkpoint", "remat2"))
-    assert R.recompute_plans() == {}
+    assert route.plans().get("recompute", {}) == {}
     for mod in (L, pk):
         monkeypatch.setattr(mod, "keep", lambda x, name: x)
     bare = gradient_program(net, train)
@@ -390,7 +390,7 @@ def test_info_recompute_counts_the_bytes_the_shapes_give(interpret, case):
     want = CASES[case][1]()
     for _ in range(2):
         jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x)[0]))(params, x)
-        assert R.recompute_plans() == {
+        assert route.plans().get("recompute", {}) == {
             "blocks": {case: want} if want else {},
             "bytes_a_step": sum(want.values()),
             "keep_nothing": ["dense"],
@@ -401,7 +401,7 @@ def test_info_recompute_counts_the_bytes_the_shapes_give(interpret, case):
         metrics = PipelineMetrics()
 
     CaffeProcessor._note_lowering_plans(Job)
-    assert Job.metrics.summary()["info"]["recompute"] == R.recompute_plans()
+    assert Job.metrics.summary()["info"]["recompute"] == route.plans().get("recompute", {})
 
 
 # ------------------------- (e) a block computes nothing a third time
@@ -468,13 +468,13 @@ def test_a_stage_runs_twice_inside_a_block_not_three_times(
     assert times_run(jaxpr, case, form) == 2
     assert sum(is_call(e, "cos_taps_bwd") for e in eqns(jaxpr)) == (
         form == "kernel")
-    assert R.recompute_plans()["stages_unwrapped"] == {case: bare}
+    assert route.plans()["recompute"]["stages_unwrapped"] == {case: bare}
 
     wrapped(monkeypatch)
     jaxpr = gradient_program(net)
     assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3
     assert times_run(jaxpr, case, form) == (3 if form == "xla" else 2)
-    assert R.recompute_plans()["stages_unwrapped"] == {}
+    assert route.plans()["recompute"]["stages_unwrapped"] == {}
 
 
 @pytest.mark.parametrize("where", ["no_block", "test_pass", "COS_REMAT"])
@@ -496,7 +496,7 @@ def test_outside_a_block_a_stage_keeps_its_own_checkpoint(
            and any(is_call(s, "cos_taps_bwd") if form == "kernel"
                    else is_marker(s, case, form)
                    for s in eqns(e.params["jaxpr"]))]
-    assert own and R.recompute_plans() == {}
+    assert own and route.plans().get("recompute", {}) == {}
     wrapped(monkeypatch)
     assert shape_of(gradient_program(net, train)) == shape_of(jaxpr)
 
@@ -535,5 +535,5 @@ def test_stages_are_counted_by_block(interpret):
     net = build(["gdn", "mamba", "dense"])
     for _ in range(2):
         gradient_program(net)
-        assert R.recompute_plans()["stages_unwrapped"] == {
+        assert route.plans()["recompute"]["stages_unwrapped"] == {
             c: STAGED[c][2] for c in STAGED}

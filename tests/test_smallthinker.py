@@ -1,5 +1,5 @@
 """SmallThinker-21BA3B-Instruct through the system against the plain
-reference (`caffeonspark_tpu/models/reference/smallthinker.py`, float32,
+reference (`perfbench/reference/smallthinker_21b_a3b.py`, float32,
 "highest"), at a small size with the model's structure: the published
 layers 0-3 (one global layer without positions, three rotary layers
 under a window), 6 query heads over 2 key/value heads, 8 softmax-routed
@@ -9,19 +9,17 @@ normed input.
 Tolerances as `tests/test_kanana2.py` gives them: both sides are float32
 with exact products, what differs is the order of sums."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from caffeonspark_tpu.models import zoo
-from caffeonspark_tpu.models.reference import smallthinker as ref
 from caffeonspark_tpu.net import Net
 from caffeonspark_tpu.ops import layers as L
 from caffeonspark_tpu.proto import LayerParameter, SolverParameter
 from caffeonspark_tpu.solver import Solver
+from perfbench.reference import smallthinker_21b_a3b as ref
 
 SMALL = dict(vocab=64, hidden=32, heads=6, kv_heads=2, head_dim=8,
              expert_width=12, experts=8, top_k=2, window=8, layers=4,
@@ -496,12 +494,3 @@ def test_a_windowed_layer_is_refused_under_a_mesh_that_shards_time():
     refuse_time_sharding(Net(small_net(
         sliding_window_layout=(0,) * 52)))      # all global: nothing
 
-
-def test_the_benchmark_reference_is_this_reference():
-    """perfbench keeps its own copy (it imports nothing from the
-    program); the two must not drift."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    a = open(os.path.join(root, "perfbench", "reference",
-                          "smallthinker_21b_a3b.py")).read()
-    b = open(ref.__file__).read()
-    assert a.split('"""', 2)[2] == b.split('"""', 2)[2]

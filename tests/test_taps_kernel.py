@@ -3,7 +3,7 @@
 in its two forms: the Mosaic kernels `cos_taps_fwd` / `cos_taps_bwd`
 (here in interpret mode) against the XLA form, value and every
 gradient; the halo between two time tiles, forward and backward; zero
-before t = 0; what sends a shape to the XLA form; what `taps_plans()`
+before t = 0; what sends a shape to the XLA form; what `info.taps`
 and the job's `info.taps` say."""
 
 import jax
@@ -13,6 +13,7 @@ import pytest
 
 from caffeonspark_tpu.ops import layers as L
 from caffeonspark_tpu.ops import pallas_kernels as pk
+from caffeonspark_tpu.ops import route
 
 TAPS = 4
 
@@ -172,7 +173,7 @@ def test_the_form_follows_what_can_be_observed(monkeypatch, why, form):
     are not whole 128-lane tiles, T is not whole sublane groups or only
     in tiles of a few rows, the input is not float32, the taps reach further back than the halo, a
     mesh of several devices is installed, or neither a TPU nor interpret
-    mode is there.  `taps_plans()` says which, with the tiles and the
+    mode is there.  `info.taps` says which, with the tiles and the
     call site, and the value is the XLA form's either way."""
     t, w, c, n, dtype, ctx = 32, 256, 128, TAPS, jnp.float32, None
     if why != "cpu":
@@ -191,17 +192,17 @@ def test_the_form_follows_what_can_be_observed(monkeypatch, why, form):
         n = 10
     elif why == "mesh":
         from caffeonspark_tpu.parallel.mesh import build_mesh
-        ctx = L.flash_mesh(build_mesh(dp=1, sp=2,
+        ctx = route.flash_mesh(build_mesh(dp=1, sp=2,
                                       devices=jax.devices()[:2]))
     z, taps, bias, _ = inputs(t, 1, w, c, True, taps=n)
     z = z.astype(dtype)
-    monkeypatch.setattr(L, "_TAPS_PLANS", {})
+    route.forget("taps")
     if ctx is None:
         got = L.causal_taps_silu(z, taps, bias, site="L0.op")
     else:
         with ctx:
             got = L.causal_taps_silu(z, taps, bias, site="L0.op")
-    (key, plan), = L.taps_plans().items()
+    (key, plan), = route.plans()["taps"].items()
     assert key == (f"1x{t} {c} of {w} channels {n} taps "
                    f"{jnp.dtype(dtype).name} bias")
     tiles = {"time_tile": 32, "channel_tile": 128} if form == "kernel" \
@@ -217,7 +218,7 @@ def test_the_form_follows_what_can_be_observed(monkeypatch, why, form):
     # the first adds nothing
     L.causal_taps_silu(z, taps, bias, site="L1.op")
     L.causal_taps_silu(z, taps, bias, site="L0.op")
-    assert L.taps_plans()[key]["sites"] == ["L0.op", "L1.op"]
+    assert route.plans()["taps"][key]["sites"] == ["L0.op", "L1.op"]
 
 
 def test_train_job_reports_info_taps(monkeypatch):
@@ -230,7 +231,7 @@ def test_train_job_reports_info_taps(monkeypatch):
     class Job:
         metrics = PipelineMetrics()
 
-    monkeypatch.setattr(L, "_TAPS_PLANS", {})
+    route.forget("taps")
     CaffeProcessor._note_lowering_plans(Job)
     assert "taps" not in Job.metrics.summary().get("info", {})
     monkeypatch.setenv("COS_FLASH_INTERPRET", "1")

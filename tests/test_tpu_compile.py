@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from caffeonspark_tpu.ops import route
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -106,7 +108,7 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
     assert not windows, windows
     # the counter says what was lowered: tiles above the floor, and
     # the calls an attention takes
-    plan = pk.flash_plans()[
+    plan = route.plans()["flash"][
         f"{b * h}x{t}x{d}/{dv} "
         f"{jnp.dtype(mxu or jnp.float32).name} g{h // hkv} causal"
         f"{f' window {window}' if window else ''}"]
@@ -151,11 +153,11 @@ def test_accepted_cells_flash_plans_are_what_they_were(shape, plan):
     hkv = int(bh) // int(g[1:])
     q, k, v = (jax.ShapeDtypeStruct(s, jnp.float32) for s in (
         (1, int(bh), int(t), d), (1, hkv, int(t), d), (1, hkv, int(t), dv)))
-    pk._FLASH_PLANS.pop(shape, None)
+    route.entries("flash").pop(shape, None)
     jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
         q, k, v, True, interpret=True, mxu_dtype=jnp.bfloat16)),
         argnums=(0, 1, 2)), q, k, v)
-    assert pk.flash_plans()[shape] == {
+    assert route.plans()["flash"][shape] == {
         kern: {"block_q": bq, "block_k": bk, "calls": calls,
                "masked_tile_share": share}
         for kern, (bq, bk, calls, share) in plan.items()}
