@@ -426,16 +426,20 @@ def _block(p, h, mixer, ffn, tag, eps, *, ffn_tag=None, norm="RMSNorm"):
     "{p}.a", `ffn` of those that read "{p}.n2" and write "{p}.f"; `tag`
     is the `recompute_block` field that the block's own layers carry
     ("" for none), `ffn_tag` that of the second half where it is a
-    block of its own.  -> (text, the blob that leaves the block)."""
+    block of its own.  A block of ONE operator has one half: `ffn` None
+    leaves  out = h + mixer(norm1(h)),  `mixer` None  out = h +
+    ffn(norm2(h)).  -> (text, the blob that leaves the block)."""
     ffn_tag = tag if ffn_tag is None else ffn_tag
-    return (_norm(norm, f"{p}.norm1", h, f"{p}.n1", tag, eps) + mixer + f"""
+    h1 = f"{p}.h1" if mixer and ffn else f"{p}.out" if mixer else h
+    first = "" if not mixer else (
+        _norm(norm, f"{p}.norm1", h, f"{p}.n1", tag, eps) + mixer + f"""
 layer {{ name: "{p}.res1" type: "Eltwise" bottom: "{h}" bottom: "{p}.a"
-  top: "{p}.h1" {tag} }}"""
-            + _norm(norm, f"{p}.norm2", f"{p}.h1", f"{p}.n2", ffn_tag, eps)
-            + ffn + f"""
-layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{p}.h1" bottom: "{p}.f"
-  top: "{p}.out" {ffn_tag} }}
-""", f"{p}.out")
+  top: "{h1}" {tag} }}""")
+    second = "" if not ffn else (
+        _norm(norm, f"{p}.norm2", h1, f"{p}.n2", ffn_tag, eps) + ffn + f"""
+layer {{ name: "{p}.res2" type: "Eltwise" bottom: "{h1}" bottom: "{p}.f"
+  top: "{p}.out" {ffn_tag} }}""")
+    return first + second + "\n", f"{p}.out"
 
 
 def _lm_head(h, vocab, filler, eps, *, norm="RMSNorm", extra="") -> str:
@@ -786,6 +790,96 @@ layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
         t += block
     return parse_net_prototxt(t + _lm_head(
         h, vocab, gauss, eps, norm="LayerNorm", extra=shared))
+
+
+# nemotron_h's published operator schedule (NVIDIA-Nemotron-3-Nano-30B-
+# A3B, 52 blocks of ONE operator each): M a Mamba-2 mixer, E an expert
+# layer, * an attention layer
+NEMOTRON_H_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def nemotron_h(vocab: int = 16384, hidden: int = 2688, heads: int = 32,
+               kv_heads: int = 2, head_dim: int = 128,
+               mamba_heads: int = 64, mamba_head_dim: int = 64,
+               n_groups: int = 8, d_state: int = 128, d_conv: int = 4,
+               chunk: int = 128, expert_width: int = 1856,
+               shared_width: int = 3712, experts: int = 128,
+               top_k: int = 6, experts_held: int = 8,
+               first_expert: int = 0, routed_scaling_factor: float = 2.5,
+               norm_epsilon: float = 1e-20,
+               pattern: str = NEMOTRON_H_PATTERN, first_layer: int = 34,
+               layers: int = 9, seq: int = 8192, batch: int = 1,
+               eps: float = 1e-5, init_std: float = 0.02,
+               conv_bound: float = 0.5, recompute: bool = True
+               ) -> NetParameter:
+    """nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B (`model_type: nemotron_h`)
+    as one chip's share of an expert-parallel pipeline stage: pre-norm
+    residual blocks of ONE operator each, `x + Op(RMSNorm(x))`, Op by
+    the published `pattern` letter: `M` the Mamba-2 mixer
+    (`mamba_heads` heads of `mamba_head_dim`, `n_groups` groups of
+    `d_state` states, `d_conv` taps with a bias, chunks of `chunk`,
+    a gated grouped RMSNorm), `E` `experts` sigmoid-routed ungated
+    squared-ReLU experts (the `top_k` largest scores plus a frozen
+    selection bias, renormalised over the chosen plus `norm_epsilon`,
+    times `routed_scaling_factor`) of which this net holds
+    `experts_held` from `first_expert` on, plus one ungated shared
+    expert of `shared_width`, `*` grouped-query attention without bias
+    and without positions.  The net is the published blocks
+    [`first_layer`, `first_layer` + `layers`), named L0, L1, ... in
+    the order they run.  The defaults are the published widths with
+    the cut of `perfbench/configs/nemotron3_nano_30b_a3b.json` (8 of
+    128 experts, an eighth of the vocabulary, published blocks 34-42,
+    `EMEMEMEM*`: the one whole run of nine between two attention
+    layers); `experts_held=128, vocab=131072, first_layer=0,
+    layers=52` is the whole model.  Time-major (T, B) int tops
+    `input_ids` / `target_ids` (one row of 8,192 by default); every
+    block is one `recompute_block`; each expert layer's `moe_stats` /
+    `moe_rows` tops are net outputs.  Every matrix is filled gaussian
+    `init_std`, the taps and their bias uniform +-`conv_bound`; the
+    head is untied."""
+    gauss = _gauss(init_std)
+    if first_layer + layers > len(pattern):
+        raise ValueError(f"nemotron_h: blocks [{first_layer}, "
+                         f"{first_layer + layers}) of {len(pattern)}")
+    t = _lm_inputs("NemotronH", batch, seq, vocab, hidden, gauss)
+    h = "h0"
+    for i, kind in enumerate(pattern[first_layer:first_layer + layers]):
+        p = f"L{i}"
+        tag = f'recompute_block: "{p}"' if recompute else ""
+        mixer = ffn = None
+        if kind == "M":
+            mixer = f"""
+layer {{ name: "{p}.mamba2" type: "Mamba2" bottom: "{p}.n1" top: "{p}.a"
+  {tag} mamba2_param {{ num_heads: {mamba_heads}
+    head_dim: {mamba_head_dim} n_groups: {n_groups} d_state: {d_state}
+    d_conv: {d_conv} chunk: {chunk} rms_norm_eps: {eps} {gauss}
+    conv_filler {{ type: "uniform" min: {-conv_bound} max: {conv_bound} }}
+  }} }}"""
+        elif kind == "*":
+            mixer = f"""
+layer {{ name: "{p}.attn" type: "GroupedQueryAttention" bottom: "{p}.n1"
+  top: "{p}.a" {tag}
+  attention_param {{ num_heads: {heads} num_kv_heads: {kv_heads}
+    head_dim: {head_dim} causal: true rotary: false {gauss} }} }}"""
+        elif kind == "E":
+            # blobs: router, bias (moves only the choice: frozen), W1,
+            # W2, S_up, S_down
+            ffn = f"""
+layer {{ name: "{p}.moe" type: "MixtureOfExperts" bottom: "{p}.n2"
+  top: "{p}.f" top: "{p}.moe_stats" top: "{p}.moe_rows" {tag}
+  param {{ lr_mult: 1 }} param {{ lr_mult: 0 decay_mult: 0 }}
+  moe_param {{ num_experts: {experts} hidden_dim: {expert_width}
+    top_k: {top_k} dispatch: "dropless" scoring: "sigmoid"
+    selection_bias: true routed_scaling_factor: {routed_scaling_factor}
+    norm_epsilon: {norm_epsilon} gated: false activation: "relu2"
+    shared_hidden_dim: {shared_width}
+    experts_held: {experts_held} first_expert: {first_expert}
+    {gauss} }} }}"""
+        else:
+            raise ValueError(f"nemotron_h: pattern letter {kind!r}")
+        block, h = _block(p, h, mixer, ffn, tag, eps)
+        t += block
+    return parse_net_prototxt(t + _lm_head(h, vocab, gauss, eps))
 
 
 # qwen3_next's published operator schedule (Qwen3-Next-80B-A3B, 48

@@ -1777,11 +1777,13 @@ def causal_taps(z, taps):
                for j in range(n_taps))
 
 
-def causal_taps_silu(z, taps, bias=None, *, site: str = ""):
-    """silu(`causal_taps`(z[..., :C], taps) [+ bias]) for time-major z
-    (T, B, W), taps (C, L), bias (C,): the convolution stage of the
-    Gated DeltaNet and Mamba layers, over the first C channels of the
-    product's W-wide output.
+def causal_taps_silu(z, taps, bias=None, *, site: str = "",
+                     first: int = 0):
+    """silu(`causal_taps`(z[..., first:first + C], taps) [+ bias]) for
+    time-major z (T, B, W), taps (C, L), bias (C,): the convolution
+    stage of the Gated DeltaNet and Mamba layers, over C channels of
+    the product's W-wide output, read where they lie (from channel
+    `first` on: the first C unless the caller says otherwise).
 
     The Mosaic kernels (`pallas_kernels.causal_taps_silu_kernels`: one
     pass forward and one backward, each element read where it lies in
@@ -1793,10 +1795,11 @@ def causal_taps_silu(z, taps, bias=None, *, site: str = ""):
     from .pallas_kernels import causal_taps_silu_kernels, taps_plan
     t, b, w = z.shape
     c, n = taps.shape
-    plan = taps_plan(t, c, w, n)
+    plan = taps_plan(t, c, w, n, first)
     kernel = route.kernel(plan, z, taps, *(() if bias is None else (bias,)))
     entry = route.lowered(
-        "taps", f"{b}x{t} {c} of {w} channels {n} taps {z.dtype.name}"
+        "taps", f"{b}x{t} {c} of {w} channels"
+        f"{f' from {first}' if first else ''} {n} taps {z.dtype.name}"
         f"{'' if bias is None else ' bias'}")
     entry.setdefault("sites", [])
     entry.update({"form": "kernel", **plan} if kernel else {"form": "xla"})
@@ -1804,15 +1807,16 @@ def causal_taps_silu(z, taps, bias=None, *, site: str = ""):
         entry["sites"].append(site)
     if kernel:
         return causal_taps_silu_kernels(z, taps, bias, plan,
-                                        interpret=kernel.interpret)
-    return causal_taps_silu_xla(z, taps, bias)
+                                        interpret=kernel.interpret,
+                                        first=first)
+    return causal_taps_silu_xla(z, taps, bias, first)
 
 
-def causal_taps_silu_xla(z, taps, bias=None):
+def causal_taps_silu_xla(z, taps, bias=None, first: int = 0):
     """`causal_taps_silu` as plain XLA: the fallback (CPU, shapes that
     do not tile, a mesh) and the parity reference of the kernels'
     tests."""
-    pre = causal_taps(z[..., :taps.shape[0]], taps)
+    pre = causal_taps(z[..., first:first + taps.shape[0]], taps)
     if bias is not None:
         pre = pre + bias.astype(pre.dtype)
     return jax.nn.silu(pre)
@@ -2081,7 +2085,7 @@ def _whole_sequence(lp):
     """A layer whose state runs along the whole sequence on one device
     (or, a Gated Memory Unit, that reads such a layer's output row for
     row): no exchange of the state between time shards is written."""
-    return ("GatedDeltaNet/Mamba/GatedMemoryUnit layers",
+    return ("GatedDeltaNet/Mamba/Mamba2/GatedMemoryUnit layers",
             "the chunked scan carries its state along the whole sequence "
             "on one device")
 
@@ -2386,6 +2390,270 @@ def _mamba(ctx, lp, params, bottoms):
         return [out, y][:max(1, len(lp.top))]
 
 
+def _mamba2_dims(mp):
+    h, p, g = int(mp.num_heads), int(mp.head_dim), int(mp.n_groups)
+    n, taps, chunk = int(mp.d_state), int(mp.d_conv), int(mp.chunk)
+    if not (h and p and g and n) or h % g or taps < 1 or chunk < 1:
+        raise ValueError(
+            f"Mamba2: {h} heads of {p} over {g} groups of {n} states, "
+            f"taps {taps}, chunk {chunk} (num_heads must be a multiple "
+            "of n_groups)")
+    return h, p, g, n
+
+
+def _mamba2_params(lp, shapes):
+    mp = lp.mamba2_param
+    d = int(shapes[0][-1])
+    h, p, g, n = _mamba2_dims(mp)
+    di, conv = h * p, h * p + 2 * g * n
+    wf = _filler(mp.weight_filler if mp.has("weight_filler") else None,
+                 "xavier")
+    cf = _filler(mp.conv_filler if mp.has("conv_filler") else None,
+                 "xavier")
+    one = FillerParameter(type="constant", value=1.0)
+    return [("W_in", (di + conv + h, d), wf),
+            ("taps", (conv, int(mp.d_conv)), cf), ("conv_bias", (conv,), cf),
+            ("dt_bias", (h,), FillerParameter(
+                type="inv_softplus_log_uniform", min=float(mp.dt_min),
+                max=float(mp.dt_max))),
+            ("A_log", (h,), FillerParameter(type="log_arange")),
+            ("D", (h,), one), ("norm", (di,), one), ("W_out", (d, di), wf)]
+
+
+# the scan's products keep float32 (HIGHEST), as the gated delta rule's:
+# a state carried along 8,192 tokens is not rounded to bfloat16 once a
+# chunk, and neither is what is read from it
+_SSD_PRECISION = lax.Precision.HIGHEST
+
+# chunks between two states that the forward pass keeps; the backward
+# pass computes a group again from the state at its edge.  It also
+# bounds what is alive together: a group's (heads, chunk, chunk) decay
+# matrices (4 MB a chunk at 64 heads and chunks of 128) and products
+_SSD_GROUP = 16
+
+
+def _ssd_group(state, x, a):
+    """One group of n chunks of `ssd_scan`: state (B, G, R, P, N) before
+    it, x = u (B, n, L, G, R, P), dt (B, n, L, G, R), b, c (B, n, L, G,
+    N), a (G, R) -> the state after it, y (B, n, L, G, R, P).
+
+    With cum the running sum of dt A inside a chunk (<= 0), a chunk's
+    own tokens give  y_t = sum_(s <= t) e^(cum_t - cum_s) (C_t . B_s)
+    dt_s u_s  and leave  sum_s e^(cum_L - cum_s) dt_s u_s B_s^T  in the
+    state; the state before the chunk decays into it by e^(cum_L) and
+    is read by  e^(cum_t) C_t S.  All four are products over the n
+    chunks at once; what goes from chunk to chunk is one elementwise
+    step a chunk.  Every decay is the exponential of a difference of
+    running sums, never a ratio of exponentials."""
+    prec = _SSD_PRECISION
+    u, dt, b, c = x
+    cum = jnp.cumsum(dt * a, axis=2)                # (B, n, L, G, R)
+    i = jnp.arange(u.shape[2])
+    # e^(cum_t - cum_s) for s <= t, 0 above the diagonal: (B, n, G, R,
+    # t, s), the (L, L) matrices innermost
+    by_head = jnp.moveaxis(cum, 2, -1)
+    decay = jnp.exp(jnp.where(
+        i[:, None] >= i[None, :],
+        by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    cb = jnp.einsum("bntgk,bnsgk->bngts", c, b, precision=prec)
+    du = u * dt[..., None]
+    y = jnp.einsum("bngrts,bnsgrp->bntgrp", decay * cb[:, :, :, None], du,
+                   precision=prec)
+    last = cum[:, :, -1:]
+    own = jnp.einsum("bnsgrp,bnsgk->bngrpk",
+                     du * jnp.exp(last - cum)[..., None], b, precision=prec)
+
+    def step(state, x):     # -> the state after a chunk; emits the one before
+        through, own = x
+        return through[..., None, None] * state + own, state
+
+    state, before = lax.scan(
+        step, state, (jnp.moveaxis(jnp.exp(last[:, :, 0]), 1, 0),
+                      jnp.moveaxis(own, 1, 0)))
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "bntgk,bngrpk->bntgrp", c, jnp.moveaxis(before, 0, 1),
+        precision=prec)
+    return state, y
+
+
+@jax.custom_vjp
+def _ssd_groups(xs, a):
+    """The groups of `ssd_scan` in order: xs = u, dt, b, c with the
+    groups on axis 0 (`_ssd_group`'s operands behind it) -> y likewise.
+    Its own backward: what is kept is y and the state before every
+    group, nothing a token and nothing a chunk."""
+    return _ssd_groups_fwd(xs, a)[0]
+
+
+def _ssd_groups_fwd(xs, a):
+    u, b = xs[0], xs[2]
+    zero = jnp.zeros(u.shape[1:2] + u.shape[4:] + b.shape[-1:], u.dtype)
+
+    def group(state, x):
+        after, y = _ssd_group(state, x, a)
+        return after, (y, state)
+
+    _, (y, edges) = lax.scan(group, zero, xs)
+    # what a recompute_block keeps of the scan: the gated norm's and the
+    # skip's backward read y, a group's recomputation starts from its
+    # edge
+    y, edges = keep(y, "ssd.y"), keep(edges, "ssd.edges")
+    return y, (xs, a, edges)
+
+
+def _ssd_groups_bwd(res, dy):
+    """The groups last to first: a group is computed again from the
+    state kept at its edge and pulled back against its outputs'
+    cotangent and the cotangent of the state it leaves."""
+    xs, a, edges = res
+
+    def group(carry, x):
+        dstate, da = carry
+        edge, x, dy = x
+        _, pull = jax.vjp(_ssd_group, edge, x, a)
+        dstate, dx, da_own = pull((dstate, dy))
+        return (dstate, da + da_own), dx
+
+    (_, da), dxs = lax.scan(
+        group, (jnp.zeros_like(edges[0]), jnp.zeros_like(a)),
+        (edges, xs, dy), reverse=True)
+    return dxs, da
+
+
+_ssd_groups.defvjp(_ssd_groups_fwd, _ssd_groups_bwd)
+
+
+def ssd_scan(u, dt, a, b, c, chunk: int = 128):
+    """The Mamba-2 recurrence (state-space duality, arXiv:2405.21060)
+    over the sequence, in chunks: a (P, N) matrix state a head under one
+    scalar decay a head and token,
+
+        S_t[h] = exp(dt_t[h] A[h]) S_(t-1)[h] + dt_t[h] u_t[h] B_t[g]^T
+        y_t[h] = S_t[h] C_t[g],      S_(-1) = 0,  g = h // (H / G)
+
+    u (B, T, H, P); dt (B, T, H); a = A (H,), < 0; b, c (B, T, G, N) ->
+    y (B, T, H, P), all float32 (the skip D u is the caller's).  No
+    state crosses a batch row.  Inside a chunk of `chunk` tokens the
+    rule is products of (chunk, chunk) and (chunk, N) matrices at
+    `_SSD_PRECISION` (`_ssd_group`); the states are carried from chunk
+    to chunk.  What the forward pass keeps is y and the state every
+    group of chunks (`_SSD_GROUP`), nothing a token; the backward pass
+    computes a group again from there.  T is padded to whole groups
+    with steps that neither decay nor write (dt 0).
+
+    There is one form, XLA's; `route.plans()["ssd"]` (the job's
+    `info.ssd`) says by operator shape what was lowered (`form`: "xla"),
+    the chunk, the chunks a row and between two kept states, and the
+    kept states' bytes."""
+    bsz, t, h, p = u.shape
+    g, n = b.shape[2], b.shape[3]
+    length = min(int(chunk), t)
+    chunks = -(-t // length)
+    grp = min(_SSD_GROUP, chunks)
+    groups = -(-chunks // grp)
+    full = groups * grp * length
+    route.lowered(
+        "ssd", f"{bsz}x{t} {h} heads of {p} over {g} groups of {n} states",
+        form="xla", chunk=length, chunks=chunks, chunks_a_group=grp,
+        edges_bytes=bsz * groups * h * p * n * 4)
+
+    def grouped(x, *tail):
+        """(B, T, ...) -> (groups, B, chunks of a group, L, *tail)."""
+        x = jnp.pad(x, ((0, 0), (0, full - t)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape(bsz, groups, grp, length, *tail), 1, 0)
+
+    r = h // g
+    y = _ssd_groups((grouped(u, g, r, p), grouped(dt, g, r),
+                     grouped(b, g, n), grouped(c, g, n)), a.reshape(g, r))
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, full, h, p)[:, :t]
+
+
+def _mamba2_flops(lp, specs, tops):
+    """The two products per position, and the recurrence as written
+    besides: per token and head the decay of the state, the rank-one
+    write and the read, P x N multiply-adds each (not the chunked
+    form's products); taps, gates, softplus and the norm are not
+    counted."""
+    h, p, _, n = _mamba2_dims(lp.mamba2_param)
+    return _products_flops(lp, specs, tops) + _rows(tops) * h * 3 * 2 * p * n
+
+
+@register("Mamba2", params=_mamba2_params, flops=_mamba2_flops,
+          time_sharding=_whole_sequence)
+def _mamba2(ctx, lp, params, bottoms):
+    """The Mamba-2 mixer (nemotron_h's `M` operator) on time-major (T,
+    B, D) input, H heads of P channels, G groups of N states:
+
+        [z | xBC | dt] = x W_in
+        xBC = silu(taps over time of xBC + conv_bias), causal
+        [u | B | C] = xBC;  dt = softplus(dt + dt_bias)
+        y = `ssd_scan`(u, dt, -exp(A_log), B, C) + D u, float32
+        y = RMSNorm(y * silu(z)) over each of the G groups of channels,
+            times the H P-wide `norm`
+        out = y W_out
+
+    No state crosses a batch column, and nothing marks a document's
+    start inside a packed row (the state and the taps reach over a
+    boundary).  W_in's product is made in two parts, [z | xBC] and dt,
+    so that the convolution reads its channels where they lie in a
+    wide array of whole 128-lane tiles (`causal_taps_silu(first=)`).
+    Scopes: `ssd`, inside it `ssd.proj` (the products), `ssd.conv`
+    (taps, bias, SiLU: `causal_taps_silu`, in whichever form it lowers
+    here), `ssd.scan` (softplus, the decays, the chunked scan, the
+    skip) and `ssd.norm` (the gate and the grouped norm)."""
+    mp = lp.mamba2_param
+    w_in, taps, conv_bias, dt_bias, a_log, d_skip, norm, w_out = params
+    x = bottoms[0]
+    t, bsz = x.shape[0], x.shape[1]
+    h, p, g, n = _mamba2_dims(mp)
+    di, bc = h * p, g * n
+    wide = 2 * di + 2 * bc
+    prec = ctx.precision()
+    f32 = jnp.float32
+    eps = float(mp.rms_norm_eps)
+
+    conv = functools.partial(causal_taps_silu, site=lp.name, first=di)
+
+    def rows(xbc, dt, dt_bias, a_log):
+        """-> u (B, T, H, P), dt (B, T, H), A (H,), B, C (B, T, G, N),
+        float32."""
+        xbc, dt = (jnp.swapaxes(v.astype(f32), 0, 1) for v in (xbc, dt))
+        return (xbc[..., :di].reshape(bsz, t, h, p),
+                jax.nn.softplus(dt + dt_bias.astype(f32)),
+                -jnp.exp(a_log.astype(f32)),
+                xbc[..., di:di + bc].reshape(bsz, t, g, n),
+                xbc[..., di + bc:].reshape(bsz, t, g, n))
+
+    def skip(y, xbc, d_skip):   # (B, T, H, P) -> (T, B, H, P), + D u
+        u = xbc[..., :di].astype(f32).reshape(t, bsz, h, p)
+        return jnp.swapaxes(y, 0, 1) + d_skip.astype(f32)[:, None] * u
+
+    def gate(y, z, norm):       # -> (T, B, H P), gated, normed by group
+        y = (y.reshape(t, bsz, di) * jax.nn.silu(z.astype(f32))).reshape(
+            t, bsz, g, di // g)
+        y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+        return (y.reshape(t, bsz, di) * norm.astype(f32)).astype(x.dtype)
+
+    # the elementwise passes between the products are computed again in
+    # the backward pass, as `Mamba`'s are: the convolution is a `stage`;
+    # `rows`, `skip` and `gate`, which feed or read the scan's kept
+    # output, have their own checkpoint inside a recompute_block too
+    with jax.named_scope("ssd"):
+        with jax.named_scope("ssd.proj"):
+            zx = jnp.einsum("tbd,ed->tbe", x, w_in[:wide], precision=prec)
+            dt = jnp.einsum("tbd,ed->tbe", x, w_in[wide:], precision=prec)
+        with jax.named_scope("ssd.conv"):
+            xbc = stage(conv)(zx, taps, conv_bias)
+        with jax.named_scope("ssd.scan"):
+            y = ssd_scan(*jax.checkpoint(rows)(xbc, dt, dt_bias, a_log),
+                         int(mp.chunk))
+            y = jax.checkpoint(skip)(y, xbc, d_skip)
+        with jax.named_scope("ssd.norm"):
+            y = jax.checkpoint(gate)(y, zx[..., :di], norm)
+        with jax.named_scope("ssd.proj"):
+            return [jnp.einsum("tbe,de->tbd", y, w_out, precision=prec)]
+
+
 def _gmu_params(lp, shapes):
     gp = lp.gated_memory_unit_param
     if len(shapes) != 2:
@@ -2433,6 +2701,30 @@ def _moe_gate_activation(mp) -> str:
     return mp.gate_activation
 
 
+def _moe_activation(mp):
+    """What `_moe_pass` and the shared expert are handed as `gated`:
+    the gate's activation for gated experts (a `jax.nn` name: "silu" |
+    "relu"), else False for today's ungated ReLU experts and "relu2"
+    for ungated experts with a squared ReLU (`_moe_hidden`)."""
+    if mp.gated:
+        return _moe_gate_activation(mp)
+    if mp.activation not in ("relu", "relu2"):
+        raise ValueError(f"moe_param.activation {mp.activation!r}: "
+                         "expected relu or relu2")
+    return "relu2" if mp.activation == "relu2" else False
+
+
+def _moe_hidden(gated, first, second):
+    """An expert's hidden activation from its first product and (gated
+    experts: a thunk of) its second."""
+    if gated == "relu2":
+        return jnp.square(jax.nn.relu(first))
+    if gated:
+        # `gated` names the gate's activation: silu | relu
+        return getattr(jax.nn, gated)(first) * second()
+    return jax.nn.relu(first)
+
+
 def _moe_params(lp, shapes):
     mp = lp.moe_param
     d = int(shapes[0][-1])
@@ -2454,8 +2746,9 @@ def _moe_params(lp, shapes):
             specs += [("W1", (held, d, h), wf), ("W2", (held, h, d), wf)]
         hs = int(mp.shared_hidden_dim)
         if hs:
-            specs += [("S_gate", (d, hs), wf), ("S_up", (d, hs), wf),
-                      ("S_down", (hs, d), wf)]
+            if mp.gated:
+                specs.append(("S_gate", (d, hs), wf))
+            specs += [("S_up", (d, hs), wf), ("S_down", (hs, d), wf)]
             if mp.shared_gate:
                 specs.append(("S_sgate", (d, 1), wf))
         return specs
@@ -2612,14 +2905,12 @@ def _moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts, ends, total,
                  - jnp.clip(starts - lo, 0, rows))
         xs = jnp.where(valid[:, None], xf[tok], 0)
     with jax.named_scope("moe.products"):
-        hid = lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
-                             precision=prec)
-        if gated:
-            # `gated` names the gate's activation: silu | relu
-            hid = getattr(jax.nn, gated)(hid) * lax.ragged_dot(
-                xs, w_in[1].astype(xs.dtype), sizes, precision=prec)
-        else:
-            hid = jax.nn.relu(hid)
+        hid = _moe_hidden(
+            gated,
+            lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
+                           precision=prec),
+            lambda: lax.ragged_dot(xs, w_in[1].astype(xs.dtype), sizes,
+                                   precision=prec))
         ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
                             precision=prec)
     with jax.named_scope("moe.combine"):
@@ -2732,6 +3023,11 @@ def _moe_dropless(ctx, lp, params, bottoms):
     the accumulators' zero fill and the sums into them.  `moe.shared`
     holds the shared experts.
 
+    Experts: gated (`W_gate`, `W_up`, `W_down`; the gate through SiLU
+    or `gate_activation`) or, `gated: false`, two matrices around
+    `activation` (ReLU, or "relu2" its square): `_moe_hidden`, the
+    routed and the shared experts alike.
+
     What the absent experts would add is left out: the result is this
     share's part of the routed sum plus the shared experts (with
     `shared_gate` times sigmoid(x w_sg), one gate a token)."""
@@ -2742,7 +3038,7 @@ def _moe_dropless(ctx, lp, params, bottoms):
     lead, d = x.shape[:-1], x.shape[-1]
     e, k = int(mp.num_experts), max(1, int(mp.top_k))
     first, held = _moe_held(mp)
-    gated = bool(mp.gated) and _moe_gate_activation(mp)
+    gated = _moe_activation(mp)
     xf = x.reshape(-1, d)
     n = xf.shape[0]
     routed_from = bottoms[1].reshape(-1, d) if len(bottoms) > 1 else xf
@@ -2793,8 +3089,8 @@ def _moe_dropless(ctx, lp, params, bottoms):
     n_pass = -(-(k * n) // rows)
     order = keep(jnp.pad(order, (0, n_pass * rows - k * n)), "moe.order")
     prec = ctx.precision()
-    w_in = (pd["W_gate"], pd["W_up"]) if gated else (pd["W1"],)
-    w_out = pd["W_down"] if gated else pd["W2"]
+    w_in = (pd["W_gate"], pd["W_up"]) if mp.gated else (pd["W1"],)
+    w_out = pd["W_down"] if mp.gated else pd["W2"]
     hidden, products = int(w_out.shape[1]), len(w_in) + 1
     # `info.moe`, by layer shape: the layers that took it, the k N
     # assignments, the rows and the number of passes, the passes an even
@@ -2803,8 +3099,9 @@ def _moe_dropless(ctx, lp, params, bottoms):
     # loop carries, added into once a pass that runs
     plan = route.lowered(
         "moe", f"{n}x{d} top {k} of {e}, {held} held x {hidden}"
-        f"{' gated' if gated else ''}"
-        f"{' by relu' if gated == 'relu' else ''}, "
+        f"{' gated' if mp.gated else ''}"
+        f"{' by relu' if gated == 'relu' else ''}"
+        f"{' relu2' if gated == 'relu2' else ''}, "
         f"shared {int(mp.shared_hidden_dim)}")
     plan.setdefault("layers", [])
     plan.update(
@@ -2821,10 +3118,15 @@ def _moe_dropless(ctx, lp, params, bottoms):
                              total, rows, n_pass, k, gated, prec)
 
     out = routed
-    if "S_gate" in pd:
+    if "S_up" in pd:
         with jax.named_scope("moe.shared"):
-            hs = jax.nn.silu(jnp.matmul(xf, pd["S_gate"], precision=prec)) \
-                * jnp.matmul(xf, pd["S_up"], precision=prec)
+            if mp.gated:        # SiLU-gated whatever gates the routed ones
+                hs = jax.nn.silu(jnp.matmul(xf, pd["S_gate"],
+                                            precision=prec)) \
+                    * jnp.matmul(xf, pd["S_up"], precision=prec)
+            else:
+                hs = _moe_hidden(gated, jnp.matmul(xf, pd["S_up"],
+                                                   precision=prec), None)
             shared = jnp.matmul(hs, pd["S_down"], precision=prec)
             if "S_sgate" in pd:     # one sigmoid gate a token
                 shared = shared * jax.nn.sigmoid(

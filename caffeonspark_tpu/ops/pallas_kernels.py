@@ -2114,22 +2114,24 @@ _TAPS_HALO = 8            # rows of the blocks above and below a tile
 _TAPS_ROWS = 64           # rows computed together
 
 
-def taps_plan(t: int, channels: int, width: int, n_taps: int):
+def taps_plan(t: int, channels: int, width: int, n_taps: int,
+              first: int = 0):
     """{time_tile, channel_tile} the kernels take a convolution of `t`
-    steps over the first `channels` of `width` channels at, or None
-    where they do not take it: the taps reach no further back than the
-    halo, T is whole sublane groups and either one tile or whole tiles
-    of at least 128 rows (a grid of shorter ones costs more than the
-    XLA form), and both the channels and the width are whole 128-lane
-    tiles."""
+    steps over `channels` of `width` channels (from channel `first` on)
+    at, or None where they do not take it: the taps reach no further
+    back than the halo, T is whole sublane groups and either one tile
+    or whole tiles of at least 128 rows (a grid of shorter ones costs
+    more than the XLA form), and the channels, where they start and the
+    width are whole 128-lane tiles."""
     tile = t if t <= TAPS_TIME else math.gcd(t, TAPS_TIME)
     if not 1 <= n_taps <= _TAPS_HALO + 1 or t % _TAPS_HALO \
             or tile < min(t, 128) or channels % 128 or width % 128 \
-            or channels > width:
+            or first % 128 or first + channels > width:
         return None
     return {"time_tile": tile,
-            "channel_tile": math.gcd(math.gcd(channels, width),
-                                     TAPS_CHANNELS)}
+            "channel_tile": math.gcd(
+                math.gcd(math.gcd(channels, width), first),
+                TAPS_CHANNELS)}
 
 
 def _taps_chunks(tile: int):
@@ -2220,27 +2222,30 @@ def _taps_bwd_kernel(a_ref, up_ref, down_ref, dy_ref, dy_down_ref,
         da_ref[r0:r0 + rows] = da
 
 
-def _taps_grid(z, taps, cols, tile, block):
+def _taps_grid(z, taps, cols, tile, block, first=0):
     """The grid (channel tiles, batch columns, time tiles) of a call on
     z (T, cols W) and taps (L, C), and its block specs: for an array of
-    W-wide and of C-wide columns each (a (tile, block) of it, the 8
-    rows above, the 8 rows below), then the taps (L, C) and the bias
-    (1, C)."""
+    W-wide (the C channels from `first` on) and of C-wide columns each
+    (a (tile, block) of it, the 8 rows above, the 8 rows below), then
+    the taps (L, C) and the bias (1, C)."""
     n, channels = taps.shape
     steps, groups = z.shape[0] // tile, tile // _TAPS_HALO
 
-    def rows(width):
+    def rows(width, off=0):
         per = width // block
+        # (a call on the first channels keeps the index maps it had)
+        lane = ((lambda c, b: b * per + c + off) if off
+                else (lambda c, b: b * per + c))
         return (pl.BlockSpec((tile, block),
-                             lambda c, b, t: (t, b * per + c)),
+                             lambda c, b, t: (t, lane(c, b))),
                 pl.BlockSpec((_TAPS_HALO, block), lambda c, b, t: (
-                    jnp.maximum(t * groups - 1, 0), b * per + c)),
+                    jnp.maximum(t * groups - 1, 0), lane(c, b))),
                 pl.BlockSpec((_TAPS_HALO, block), lambda c, b, t: (
                     jnp.minimum((t + 1) * groups, steps * groups - 1),
-                    b * per + c)))
+                    lane(c, b))))
 
-    return ((channels // block, cols, steps), rows(z.shape[1] // cols),
-            rows(channels),
+    return ((channels // block, cols, steps),
+            rows(z.shape[1] // cols, first // block), rows(channels),
             (pl.BlockSpec((n, block), lambda c, b, t: (0, c)),
              pl.BlockSpec((1, block), lambda c, b, t: (0, c))))
 
@@ -2250,10 +2255,10 @@ def _taps_grid(z, taps, cols, tile, block):
 # site, 1.5 s less of tracing for a qwen3_next step, but the -train job
 # then starts 6 s later warm and 16 s later with an empty compile cache:
 # PERF.md, section 7, PR 44.)
-def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret):
+def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret, first=0):
     """z (T, cols W), taps (L, C), bias (1, C) -> y (T, cols C)."""
     grid, (wide, up, _), (narrow, _, _), consts = _taps_grid(
-        z, taps, cols, tile, block)
+        z, taps, cols, tile, block, first)
     return _mosaic_call(
         _taps_fwd_kernel, "cos_taps_fwd", (z, z, taps, bias), grid=grid,
         in_specs=[wide, up, *consts], out_specs=narrow,
@@ -2263,13 +2268,14 @@ def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret):
         semantics=("parallel",) * 3, interpret=interpret)
 
 
-def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret):
+def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret,
+                   first=0):
     """-> da (T, cols C), the sums over time (L + 1, 8, C): the taps'
     gradient row by row, then the bias's, eight partial sums each."""
     n, channels = taps.shape
     f32 = jnp.float32
     grid, (wide, up, down), (narrow, _, narrow_down), consts = _taps_grid(
-        z, taps, cols, tile, block)
+        z, taps, cols, tile, block, first)
     return _mosaic_call(
         _taps_bwd_kernel, "cos_taps_bwd", (z, z, z, dy, dy, taps, bias),
         grid=grid, in_specs=[wide, up, down, narrow, narrow_down, *consts],
@@ -2282,24 +2288,26 @@ def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret):
         interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _taps_silu(z, taps, bias, cols, tile, block, interpret):
-    return _taps_fwd_call(z, taps, bias, cols, tile, block, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _taps_silu(z, taps, bias, cols, tile, block, interpret, first=0):
+    return _taps_fwd_call(z, taps, bias, cols, tile, block, interpret,
+                          first)
 
 
-def _taps_silu_fwd(z, taps, bias, cols, tile, block, interpret):
-    return (_taps_fwd_call(z, taps, bias, cols, tile, block, interpret),
-            (z, taps, bias))
+def _taps_silu_fwd(z, taps, bias, cols, tile, block, interpret, first):
+    return (_taps_fwd_call(z, taps, bias, cols, tile, block, interpret,
+                           first), (z, taps, bias))
 
 
-def _taps_silu_bwd(cols, tile, block, interpret, res, dy):
+def _taps_silu_bwd(cols, tile, block, interpret, first, res, dy):
     z, taps, bias = res
     n, channels = taps.shape
     da, sums = _taps_bwd_call(z, taps, bias, dy, cols, tile, block,
-                              interpret)
+                              interpret, first)
     sums = jnp.sum(sums, axis=1)
     dz = jnp.pad(da.reshape(z.shape[0], cols, channels),
-                 ((0, 0), (0, 0), (0, z.shape[1] // cols - channels)))
+                 ((0, 0), (0, 0),
+                  (first, z.shape[1] // cols - first - channels)))
     return dz.reshape(z.shape), sums[:n], sums[n:]
 
 
@@ -2307,14 +2315,15 @@ _taps_silu.defvjp(_taps_silu_fwd, _taps_silu_bwd)
 
 
 def causal_taps_silu_kernels(z, taps, bias, plan: dict,
-                             interpret: bool = False):
+                             interpret: bool = False, first: int = 0):
     """`ops.layers.causal_taps_silu` through the kernels above: z (T, B,
-    W) float32 whose first C channels are convolved, taps (C, L), bias
-    (C,) or None -> (T, B, C), differentiable in all three.  What a
-    backward pass keeps is the inputs alone."""
+    W) float32 whose C channels from `first` on are convolved, taps (C,
+    L), bias (C,) or None -> (T, B, C), differentiable in all three.
+    What a backward pass keeps is the inputs alone."""
     t, cols, _ = z.shape
     channels = taps.shape[0]
     bias = jnp.zeros((channels,), jnp.float32) if bias is None else bias
     y = _taps_silu(z.reshape(t, -1), taps.T, bias.reshape(1, channels),
-                   cols, plan["time_tile"], plan["channel_tile"], interpret)
+                   cols, plan["time_tile"], plan["channel_tile"], interpret,
+                   first)
     return y.reshape(t, cols, channels)

@@ -43,6 +43,10 @@ KEPT = (
     # gate's and the skip's backward) and the states at the chunks'
     # edges (where the backward's recomputation of a chunk starts)
     "ssm.y", "ssm.edges",
+    # `layers._ssd_groups`: the chunked scan's output (read by the gated
+    # norm's and the skip's backward) and the states at the groups'
+    # edges (where the backward's recomputation of a group starts)
+    "ssd.y", "ssd.edges",
     # `layers._moe_dropless`, scope moe.route: what the router's own
     # backward reads (its product at HIGHEST, top_k's choice) ...
     "moe.logits", "moe.topi",

@@ -561,8 +561,11 @@ class MoEParameter(Message):
       the chosen scores are normalised over the k and multiplied by
       `routed_scaling_factor`; `gated` experts are SiLU-gated
       (`W_gate`/`W_up`/`W_down`; `gate_activation: "relu"` gates them
-      by a ReLU instead), `shared_hidden_dim` > 0 adds shared
-      experts as one gated FFN every token passes.  `experts_held` /
+      by a ReLU instead; `gated: false` experts are two
+      matrices `W1`/`W2` around `activation`: "relu" or "relu2", the
+      squared ReLU), `shared_hidden_dim` > 0 adds shared
+      experts as one FFN every token passes, SiLU-gated for gated
+      experts, else `S_up`/`S_down` around `activation`.  `experts_held` /
       `first_expert` tell the layer which experts live here (0 = all):
       it routes over all `num_experts` and computes the part of the
       sum that experts [first_expert, first_expert + experts_held)
@@ -597,6 +600,10 @@ class MoEParameter(Message):
         # what a `gated` expert's gate passes through: "silu"
         # (silu(x W_gate) * (x W_up)) or "relu" (smallthinker's ReGLU)
         Field(16, "gate_activation", STRING, default="silu"),
+        # what an expert WITHOUT a gate (`gated: false`: `W1`/`W2`, and
+        # the shared expert as `S_up`/`S_down`) passes through: "relu"
+        # or "relu2" (relu(x)^2: nemotron_h's squared ReLU)
+        Field(17, "activation", STRING, default="relu"),
     ]
 
 
@@ -763,6 +770,40 @@ class MambaParameter(Message):
     ]
 
 
+class Mamba2Parameter(Message):
+    """Extension: the Mamba-2 mixer (`Mamba2`, the state-space duality
+    form of arXiv:2405.21060) on time-major (T, B, D) input: `num_heads`
+    heads of `head_dim` channels (d_inner = their product), `n_groups`
+    groups that share B and C (head h reads group h // (num_heads /
+    n_groups)), a (`head_dim`, `d_state`) matrix state a head under ONE
+    scalar decay a head and token.  `[z | xBC | dt] = x W_in` (d_inner,
+    d_inner + 2 n_groups d_state, num_heads); xBC = silu(taps over time
+    of xBC + conv_bias), a depthwise causal convolution of `d_conv`
+    taps; `[u | B | C] = xBC`; dt = softplus(dt + dt_bias); A =
+    -exp(A_log); S_t = exp(dt_t A) S_(t-1) + dt_t u_t B_t^T, y_t =
+    S_t C_t + D u_t over the sequence in chunks of `chunk` tokens,
+    float32; y = RMSNorm(y * silu(z)) over each of the `n_groups`
+    groups of channels (eps `rms_norm_eps`, one d_inner-wide scale);
+    out = y W_out.  Blobs `W_in` (d_inner + conv channels + num_heads,
+    D), `taps` (conv channels, d_conv), `conv_bias` (`conv_filler`
+    fills both), `dt_bias` (the inverse softplus of a log-uniform draw
+    in [`dt_min`, `dt_max`]), `A_log` (log of 1..num_heads), `D` (1),
+    `norm` (1), `W_out` (D, d_inner)."""
+    FIELDS = [
+        Field(1, "num_heads", UINT32, default=0),
+        Field(2, "head_dim", UINT32, default=64),
+        Field(3, "n_groups", UINT32, default=1),
+        Field(4, "d_state", UINT32, default=128),
+        Field(5, "d_conv", UINT32, default=4),
+        Field(6, "chunk", UINT32, default=128),
+        Field(7, "dt_min", FLOAT, default=1e-3),
+        Field(8, "dt_max", FLOAT, default=1e-1),
+        Field(9, "rms_norm_eps", FLOAT, default=1e-5),
+        Field(10, "weight_filler", MESSAGE, message=FillerParameter),
+        Field(11, "conv_filler", MESSAGE, message=FillerParameter),
+    ]
+
+
 class GatedMemoryUnitParameter(Message):
     """Extension: the Gated Memory Unit (`GatedMemoryUnit`,
     arXiv:2507.06607) on time-major input: bottoms x (T, B, D) and a
@@ -803,6 +844,7 @@ class LayerParameter(Message):
         Field(156, "mamba_param", MESSAGE, message=MambaParameter),
         Field(157, "gated_memory_unit_param", MESSAGE,
               message=GatedMemoryUnitParameter),
+        Field(158, "mamba2_param", MESSAGE, message=Mamba2Parameter),
         # consecutive layers that give the same non-empty name form one
         # block whose activations are recomputed in the backward pass
         # (Net.apply: one jax.checkpoint around the block); COS_REMAT

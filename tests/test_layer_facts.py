@@ -3,7 +3,7 @@
 its own that is not one of Caffe's answers both, so that the next type
 cannot be counted as zero FLOPs or cut over `sp` by omission.  Beside it,
 what the deleted copies of the benchmark's references protected (a
-reference imports nothing from the program), and the five language
+reference imports nothing from the program), and the six language
 builders against the net texts the benchmark runs."""
 
 import ast
@@ -27,7 +27,8 @@ CAFFE = {"BatchNorm", "Bias", "Convolution", "Deconvolution", "Embed",
 LANGUAGE = {"kanana2": "kanana2_30b_a3b", "lfm2": "lfm2_24b_a2b",
             "qwen3_next": "qwen3_next_80b_a3b",
             "smallthinker": "smallthinker_21b_a3b",
-            "phi4flash": "phi4flash_mini"}
+            "phi4flash": "phi4flash_mini",
+            "nemotron_h": "nemotron3_nano_30b_a3b"}
 
 
 @pytest.mark.parametrize("type_name", [

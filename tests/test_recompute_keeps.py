@@ -65,6 +65,13 @@ def ssm_bytes(ch=128, n=16, c=64):
     return {"ssm.y": B * T * ch * 4, "ssm.edges": B * -(-T // c) * ch * n * 4}
 
 
+def ssd_bytes(h=2, p=64, n=128, c=64):
+    groups = -(-(-(-T // c)) // L._SSD_GROUP)
+    full = groups * min(L._SSD_GROUP, -(-T // c)) * c
+    return {"ssd.y": B * full * h * p * 4,
+            "ssd.edges": groups * B * h * p * n * 4}
+
+
 def moe_bytes():
     rows = L._moe_chunk_rows(N, K, HELD, E)
     return {"moe.logits": N * E * 4, "moe.topi": N * K * 4,
@@ -90,6 +97,11 @@ CASES = {
             'chunk: 64 }', gdn_bytes, ("cos_gdn_fwd",)),
     "mamba": ('type: "Mamba" mamba_param { d_inner: 128 d_state: 16 '
               'd_conv: 4 dt_rank: 4 chunk: 64 }', ssm_bytes, ("cos_ssm_fwd",)),
+    # one form, XLA's: what must not run again is the forward scan over
+    # the groups of chunks (`held`: "ssd_forward")
+    "mamba2": ('type: "Mamba2" mamba2_param { num_heads: 2 head_dim: 64 '
+               'n_groups: 1 d_state: 128 d_conv: 4 chunk: 64 }', ssd_bytes,
+               ("ssd_forward",)),
     "moe_sigmoid": (MOE % ("sigmoid", "selection_bias: true "
                            "routed_scaling_factor: 2.5 "
                            "shared_hidden_dim: 12"),
@@ -107,9 +119,15 @@ KEEPING = [c for c in CASES if c != "dense"]
 # shape a gradient holds outside the stages: Mamba's gate on z, in the
 # forward pass and in the block's recomputation; how many of the three
 # run bare in a block: all but the Gated DeltaNet's gate, all but
-# Mamba's `rows` and `skip`, which keep a checkpoint of their own)
-STAGED = {"gdn": ((T, B, 2 * 128 + 2 * 128), 0, 2),
-          "mamba": ((T, B, 128), 2, 1)}
+# Mamba's `rows` and `skip`, which keep a checkpoint of their own; how
+# many stages the layer has)
+STAGED = {"gdn": ((T, B, 2 * 128 + 2 * 128), 0, 2, 3),
+          "mamba": ((T, B, 128), 2, 1, 3),
+          # Mamba-2's gate is on z, 128 wide: no SiLU of the
+          # convolution's 384 channels outside its stage; `rows`, `skip`
+          # and `gate` keep a checkpoint of their own
+          # (four stages, not three)
+          "mamba2": ((T, B, 128 + 2 * 128), 0, 1, 4)}
 
 
 def build(cases, tag=True, **net_kw):
@@ -192,6 +210,14 @@ def held(body, what):
         if what == "router":
             if name == "dot_general" and e.outvars[0].aval.shape == (N, E):
                 return True
+        elif what == "ssd_forward":
+            # `_ssd_groups_fwd`'s scan: forward in time, a group's
+            # running sums in its body (the backward's scan runs in
+            # reverse; the chunk-to-chunk carries hold no running sum)
+            if name == "scan" and not e.params["reverse"] and any(
+                    i.primitive.name == "cumsum"
+                    for i in eqns(e.params["jaxpr"].jaxpr)):
+                return True
         elif name == what or (name == "pallas_call"
                               and what in e.params["name"]):
             return True
@@ -256,7 +282,7 @@ def test_recomputation_holds_no_kept_forward(interpret, monkeypatch, case):
     body = recomputations(jaxpr)[-1].params["jaxpr"]
     assert not [w for w in gone if held(body, w)]
     # the backward's own work is there: this is the right body
-    assert any(e.primitive.name in ("pallas_call", "while")
+    assert any(e.primitive.name in ("pallas_call", "while", "scan")
                for e in eqns(body))
 
     named, other = handed_over(jaxpr)
@@ -464,7 +490,8 @@ def test_a_stage_runs_twice_inside_a_block_not_three_times(
     bare = STAGED[case][2]
     net = build([case])
     jaxpr = gradient_program(net)
-    assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3 - bare
+    assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) \
+        == STAGED[case][3] - bare
     assert times_run(jaxpr, case, form) == 2
     assert sum(is_call(e, "cos_taps_bwd") for e in eqns(jaxpr)) == (
         form == "kernel")
@@ -472,7 +499,8 @@ def test_a_stage_runs_twice_inside_a_block_not_three_times(
 
     wrapped(monkeypatch)
     jaxpr = gradient_program(net)
-    assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) == 3
+    assert len(recomputations(jaxpr)) - 1 == len(nested(jaxpr)) \
+        == STAGED[case][3]
     assert times_run(jaxpr, case, form) == (3 if form == "xla" else 2)
     assert route.plans()["recompute"]["stages_unwrapped"] == {}
 
@@ -529,10 +557,10 @@ def test_values_equal_the_wrapped_stages_bit_for_bit(interpret, monkeypatch,
 
 
 def test_stages_are_counted_by_block(interpret):
-    """Two staged layers in blocks of their own beside a block without
-    one: each of the two with its stages, the third block not listed, a
+    """The staged layers in blocks of their own beside a block without
+    one: each of them with its stages, the last block not listed, a
     second trace counting nothing twice."""
-    net = build(["gdn", "mamba", "dense"])
+    net = build(list(STAGED) + ["dense"])
     for _ in range(2):
         gradient_program(net)
         assert route.plans()["recompute"]["stages_unwrapped"] == {

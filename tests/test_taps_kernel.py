@@ -93,6 +93,36 @@ def test_channel_tiles_follow_the_batch_column():
         close(a, r, name, rtol=2e-5)
 
 
+@pytest.mark.parametrize("t,b", [(48, 1), (32, 2)])
+def test_channels_read_from_an_offset_where_they_lie(t, b):
+    """`first`: the C channels start at a whole tile inside the wider
+    array (a Mamba-2 layer's [z | xBC]: xBC behind z): every (column,
+    tile) reads its own lanes, value and gradients equal the XLA form's,
+    and nothing flows into the channels before or after."""
+    w, c, first = 640, 256, 128
+    z, taps, bv, dy = inputs(t, b, w, c, True, seed=t)
+    plan = pk.taps_plan(t, c, w, TAPS, first)
+    assert plan == {"time_tile": t, "channel_tile": 128}
+    got, gg = value_and_grads(
+        lambda *a: pk.causal_taps_silu_kernels(
+            *a, dict(plan, time_tile=16), interpret=True, first=first),
+        z, taps, bv, dy)
+    want, gw = value_and_grads(
+        lambda *a: L.causal_taps_silu_xla(*a, first), z, taps, bv, dy)
+    close(got, want, "y")
+    close(want, L.causal_taps_silu_xla(z[..., first:], taps, bv), "slice")
+    for name, a, r in zip(("dz", "dtaps", "dbias"), gg, gw):
+        close(a, r, name, rtol=2e-5)
+    dz = np.asarray(gg[0])
+    assert not dz[..., :first].any() and not dz[..., first + c:].any()
+    assert np.abs(dz[..., first:first + c]).min() > 0
+    # where the channels start has to be a whole tile, and they have to fit
+    assert pk.taps_plan(t, c, w, TAPS, 64) is None
+    assert pk.taps_plan(t, c, w, TAPS, 512) is None
+    assert pk.taps_plan(8192, 6144, 10240, 4, 4096) == {
+        "time_tile": 512, "channel_tile": 512}
+
+
 @pytest.mark.parametrize("tile", [8, 16, 64])
 def test_an_impulse_crosses_the_tile_edge_both_ways(tile):
     """A unit impulse in the last row of a time tile shows in that row

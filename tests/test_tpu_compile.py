@@ -65,6 +65,9 @@ def one_chip():
     # tile: every visited tile is masked)
     (1, 40, 20, 8192, 64, 128, jnp.bfloat16, 3, 0),
     (1, 40, 20, 8192, 64, 128, jnp.bfloat16, 3, 512),
+    # nemotron3nano.train_packed8k: g = 16, the most query heads a
+    # key/value head of any cell (32 over 2 heads of 128); one chunk
+    (1, 32, 2, 8192, 128, 128, jnp.bfloat16, 3, 0),
 ])
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, b, h, hkv,
                                                     t, d, dv, mxu, calls,
@@ -239,17 +242,21 @@ def test_selective_scan_kernels_compile_for_v5e(one_chip):
 
 # the convolution stage of `qwen3next.train_packed8k` (the first 8,192
 # of W_qkvz's 12,288 channels) and of `phi4flash.train_packed8k` (the
-# first 5,120 of W_in's 10,240, with a bias)
-TAPS_SHAPES = [(8192, 1, 12288, 8192, False), (8192, 1, 10240, 5120, True)]
+# first 5,120 of W_in's 10,240, with a bias), and of `nemotron3nano.
+# train_packed8k` (the 6,144 channels of xBC behind z's 4,096 in the
+# 10,240-wide [z | xBC], with a bias)
+TAPS_SHAPES = [(8192, 1, 12288, 8192, False, 0),
+               (8192, 1, 10240, 5120, True, 0),
+               (8192, 1, 10240, 6144, True, 4096)]
 
 
-def _taps_case(t, b, w, c, bias):
+def _taps_case(t, b, w, c, bias, first=0):
     """The stage's kernel and XLA forms as functions of 2-D arrays (so
     that a test's own arguments carry the layout the step's product
     gives them), each -> (y, (dz, dtaps[, dbias]))."""
     from caffeonspark_tpu.ops import layers as L
     from caffeonspark_tpu.ops import pallas_kernels as pk
-    plan = pk.taps_plan(t, c, w, 4)
+    plan = pk.taps_plan(t, c, w, 4, first)
 
     def both(stage):
         def run(z2, taps, bv, dy2):
@@ -260,17 +267,18 @@ def _taps_case(t, b, w, c, bias):
         return run
 
     return plan, both(lambda z, taps, bv: pk.causal_taps_silu_kernels(
-        z, taps, bv, plan)), both(L.causal_taps_silu_xla)
+        z, taps, bv, plan, first=first)), both(
+            lambda z, taps, bv: L.causal_taps_silu_xla(z, taps, bv, first))
 
 
-@pytest.mark.parametrize("t,b,w,c,bias", TAPS_SHAPES)
+@pytest.mark.parametrize("t,b,w,c,bias,first", TAPS_SHAPES)
 def test_convolution_stage_kernels_compile_for_v5e(one_chip, t, b, w, c,
-                                                   bias):
+                                                   bias, first):
     """`cos_taps_fwd` and `cos_taps_bwd` at the two cells' shapes lower
     for the v5e at the tiles `taps_plan` picks, one call each, neither
     with a VMEM window of its own, and read the wide array where it
     lies: no copy of it or of its slice stands before a call."""
-    plan, kernel, _ = _taps_case(t, b, w, c, bias)
+    plan, kernel, _ = _taps_case(t, b, w, c, bias, first)
     assert plan == {"time_tile": 512, "channel_tile": 512}
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
               for s in ((t, b * w), (c, 4), (c,), (t, b * c))]
@@ -292,9 +300,9 @@ def test_convolution_stage_kernels_compile_for_v5e(one_chip, t, b, w, c,
                for line in lines), lines
 
 
-@pytest.mark.parametrize("t,b,w,c,bias", TAPS_SHAPES)
+@pytest.mark.parametrize("t,b,w,c,bias,first", TAPS_SHAPES)
 def test_convolution_stage_kernels_equal_the_xla_form_on_the_chip(
-        tpu, t, b, w, c, bias):
+        tpu, t, b, w, c, bias, first):
     """On the chip (skipped elsewhere): the compiled kernels against the
     XLA form at the two cells' shapes.  The bound is float32 rounding of
     a sum of four products and a bias under a SiLU: y and dz within 8 ulp
@@ -302,7 +310,7 @@ def test_convolution_stage_kernels_equal_the_xla_form_on_the_chip(
     0 to 2.9e-7, PR 44's lab), the sums over 8,192 rows (the taps' and
     the bias's gradients) within 2^-17 = 7.6e-6 of theirs (measured
     4.4e-7)."""
-    _, kernel, xla = _taps_case(t, b, w, c, bias)
+    _, kernel, xla = _taps_case(t, b, w, c, bias, first)
     k = jax.random.split(jax.random.key(t + c), 4)
     args = (jax.random.normal(k[0], (t, b * w)),
             0.5 * jax.random.normal(k[1], (c, 4)),
@@ -318,6 +326,72 @@ def test_convolution_stage_kernels_equal_the_xla_form_on_the_chip(
     assert float(jnp.max(jnp.abs(want))) > 1.0
     assert gap(got, want) <= 8 * 2.0 ** -23
     assert gap(got_grads[0], want_grads[0]) <= 8 * 2.0 ** -23
-    assert not bool(jnp.any(got_grads[0].reshape(t, b, w)[..., c:]))
+    dz = got_grads[0].reshape(t, b, w)
+    assert not bool(jnp.any(dz[..., :first]))
+    assert not bool(jnp.any(dz[..., first + c:]))
     for a, r in zip(got_grads[1:], want_grads[1:]):
         assert a.shape == r.shape and gap(a, r) <= 2.0 ** -17
+
+
+def test_nemotron3nano_step_compiles_for_v5e(one_chip, monkeypatch):
+    """`nemotron3nano.train_packed8k`'s whole train step (the net of
+    `zoo.nemotron_h()`, Adam, one row of 8,192) compiles for the v5e
+    with the operators on the forms the cell is to run: the g = 16
+    attention on the three flash kernels, the four convolution stages
+    on the taps kernels reading xBC from lane 4,096 of [z | xBC], the
+    Mamba-2 scan in XLA's form; no Mosaic call asks for a VMEM window;
+    arguments + temporaries stand under the chip's 15.75 GB."""
+    from caffeonspark_tpu.models import zoo
+    from caffeonspark_tpu.proto import SolverParameter
+    from caffeonspark_tpu.solver import Solver
+    monkeypatch.setattr(route, "on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    route.forget()
+    solver = Solver(SolverParameter.from_text(
+        'type: "Adam" base_lr: 1e-6 lr_policy: "fixed" momentum: 0.9 '
+        'momentum2: 0.95 delta: 1e-8 clip_gradients: 1.0 random_seed: 1'),
+        zoo.nemotron_h())
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params, state = jax.eval_shape(solver.init)
+    inputs = {n: jax.ShapeDtypeStruct(tuple(s), jnp.float32)
+              for n, s, _ in solver.train_net.input_specs}
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(solver.train_step_fn(),
+                           donate_argnums=(0, 1)).lower(
+            shaped(params), shaped(state), shaped(inputs),
+            shaped(jax.eval_shape(lambda: jax.random.key(0)))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 8.1e9       # 3 x 4 B x 667 M
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75e9
+    lines = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name, calls in (("cos_flash_fwd", 1), ("cos_flash_bwd_dq", 1),
+                        ("cos_flash_bwd_dkv", 1), ("cos_taps_fwd", 8),
+                        ("cos_taps_bwd", 4)):
+        assert sum(name in line for line in lines) == calls, name
+    windows = [int(n) for line in lines for n in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"\d+","size":"(\d+)"', line)]
+    assert not windows, windows
+    plans = route.plans()
+    assert list(plans["flash"]) == ["32x8192x128/128 bfloat16 g16 causal"]
+    taps = plans["taps"][
+        "1x8192 6144 of 10240 channels from 4096 4 taps float32 bias"]
+    assert taps["form"] == "kernel" and taps["sites"] == [
+        f"L{i}.mamba2" for i in (1, 3, 5, 7)]
+    assert plans["ssd"] == {
+        "1x8192 64 heads of 64 over 8 groups of 128 states": {
+            "form": "xla", "chunk": 128, "chunks": 64, "chunks_a_group": 16,
+            "edges_bytes": 4 * 64 * 64 * 128 * 4}}
+    assert sorted(plans["recompute"]["blocks"]) == [
+        f"L{i}" for i in range(9)]
+    assert "relu2" in next(iter(plans["moe"]))

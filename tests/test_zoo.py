@@ -254,3 +254,40 @@ def test_phi4flash_counts_kinds_and_round_trip():
     assert whole.num_params() == 3_852_457_984
     with pytest.raises(ValueError, match="no layer of the cut"):
         zoo.phi4flash(first_layer=18, layers=2)
+
+
+def test_nemotron_h_counts_pattern_and_round_trip():
+    """`zoo.nemotron_h`: the cut of `perfbench/configs/
+    nemotron3_nano_30b_a3b.json` and the whole model count what the
+    published model does (31.6 B), a block is ONE operator by the
+    published pattern's letter (one norm, one residual a block), the
+    net text round-trips, and a cut past the pattern's end is refused."""
+    from caffeonspark_tpu.models import zoo
+    from caffeonspark_tpu.proto import NetParameter
+    pattern = zoo.NEMOTRON_H_PATTERN
+    assert len(pattern) == 52 and pattern[34:43] == "EMEMEMEM*"
+    assert [pattern.count(k) for k in "ME*"] == [23, 23, 6]
+    cut = zoo.nemotron_h()
+    assert NetParameter.from_text(cut.to_text()) == cut
+    assert NetParameter.from_binary(cut.to_binary()) == cut
+    net = Net(cut, NetState(phase=Phase.TRAIN))
+    assert net.num_params() == 666_963_456
+    assert len(net.recompute_blocks) == 9
+    types = [lp.type for lp in net.compute_layers]
+    assert [types.count(t) for t in (
+        "Mamba2", "MixtureOfExperts", "GroupedQueryAttention", "RMSNorm",
+        "Eltwise")] == [4, 4, 1, 10, 9]
+    names = [lp.name for lp in net.compute_layers]
+    assert "L0.norm1" not in names and "L0.norm2" in names      # E
+    assert "L1.norm1" in names and "L1.norm2" not in names      # M
+    moe = next(lp for lp in net.compute_layers if lp.name == "L0.moe")
+    assert (moe.moe_param.activation, moe.moe_param.gated) == ("relu2",
+                                                               False)
+    assert [n for n, _ in net.layer_param_specs("L0.moe")] == [
+        "router", "bias", "W1", "W2", "S_up", "S_down"]
+    whole = Net(zoo.nemotron_h(vocab=131072, first_layer=0, layers=52,
+                               experts_held=128, seq=128),
+                NetState(phase=Phase.TRAIN))
+    assert whole.num_params() == 31_577_940_288
+    with pytest.raises(ValueError, match=r"blocks \[50, 59\) of 52"):
+        zoo.nemotron_h(first_layer=50)
