@@ -2523,49 +2523,105 @@ def _ssd_groups_bwd(res, dy):
 _ssd_groups.defvjp(_ssd_groups_fwd, _ssd_groups_bwd)
 
 
-def ssd_scan(u, dt, a, b, c, chunk: int = 128):
+def ssd_scan(x, dt, a, d, groups: int, states: int, chunk: int = 128):
     """The Mamba-2 recurrence (state-space duality, arXiv:2405.21060)
-    over the sequence, in chunks: a (P, N) matrix state a head under one
-    scalar decay a head and token,
+    over the sequence, in chunks, with the skip: a (P, N) matrix state a
+    head under one scalar decay a head and token,
 
         S_t[h] = exp(dt_t[h] A[h]) S_(t-1)[h] + dt_t[h] u_t[h] B_t[g]^T
-        y_t[h] = S_t[h] C_t[g],      S_(-1) = 0,  g = h // (H / G)
+        y_t[h] = S_t[h] C_t[g] + D[h] u_t[h],  S_(-1) = 0,  g = h // (H / G)
 
-    u (B, T, H, P); dt (B, T, H); a = A (H,), < 0; b, c (B, T, G, N) ->
-    y (B, T, H, P), all float32 (the skip D u is the caller's).  No
-    state crosses a batch row.  Inside a chunk of `chunk` tokens the
-    rule is products of (chunk, chunk) and (chunk, N) matrices at
-    `_SSD_PRECISION` (`_ssd_group`); the states are carried from chunk
-    to chunk.  What the forward pass keeps is y and the state every
-    group of chunks (`_SSD_GROUP`), nothing a token; the backward pass
-    computes a group again from there.  T is padded to whole groups
-    with steps that neither decay nor write (dt 0).
+    on time-major operands where the convolution stage leaves them: x
+    (T, B, W) = [u (H P) | B (G N) | C (G N)] along W; dt (T, B, H), > 0;
+    a = A (H,), < 0; d = D (H,) -> y (T, B, H P), all float32.  No state
+    crosses a batch column.  Inside a chunk of `chunk` tokens the rule
+    is products of (chunk, chunk) and (chunk, N) matrices at
+    `_SSD_PRECISION`; the states are carried from chunk to chunk; every
+    decay is the exponential of a difference of running sums.
 
-    There is one form, XLA's; `route.plans()["ssd"]` (the job's
-    `info.ssd`) says by operator shape what was lowered (`form`: "xla"),
-    the chunk, the chunks a row and between two kept states, and the
-    kept states' bytes."""
-    bsz, t, h, p = u.shape
-    g, n = b.shape[2], b.shape[3]
-    length = min(int(chunk), t)
+    Two forms, one algorithm.  The Mosaic kernels
+    (`pallas_kernels.ssd_scan_kernels`: a group's u, B and C read from x
+    in place, its (R P, N) state in VMEM from a row's first chunk to its
+    last, y written time-major with the skip added, the state before
+    every chunk kept for the backward kernel) where `route.kernel` says
+    so: the chunk, N and a group's R P channels fill whole 128-lane
+    tiles and the calls fit the default VMEM window (`ssd_scan_plan`);
+    else `ssd_scan_xla` (the CPU, a mesh, a shape that does not tile),
+    which keeps the state every `_SSD_GROUP` chunks and computes a group
+    again in its backward pass.  `route.plans()["ssd"]` (the job's
+    `info.ssd`) says by operator shape which form was lowered (`form`:
+    "kernel" or "xla"), the chunk, the chunks a row and between two
+    kept states, the kept states' bytes and, of the kernels, the chunks
+    a grid step and the VMEM the larger call takes."""
+    from .pallas_kernels import ssd_scan_kernels, ssd_scan_plan
+    t, bsz, w = x.shape
+    h, g, n = dt.shape[-1], int(groups), int(states)
+    p = (w - 2 * g * n) // h
+    plan = ssd_scan_plan(t, bsz, h, p, g, n, int(chunk))
+    kernel = route.kernel(plan, x, dt, a, d)
+    if kernel:
+        length, chunks, grp = plan["chunk"], plan["chunks"], 1
+    else:
+        length = min(int(chunk), t)
+        chunks = -(-t // length)
+        grp = min(_SSD_GROUP, chunks)
+    route.lowered(
+        "ssd", f"{bsz}x{t} {h} heads of {p} over {g} groups of {n} states",
+        form="kernel" if kernel else "xla", chunk=length, chunks=chunks,
+        chunks_a_group=grp,
+        edges_bytes=bsz * -(-chunks // grp) * h * p * n * 4,
+        **({"chunks_a_step": plan["steps"],
+            "vmem_bytes": plan["vmem_bytes"]} if kernel else {}))
+    if kernel:
+        return ssd_scan_kernels(x, dt, a, d, plan, groups=g, states=n,
+                                interpret=kernel.interpret)
+    return ssd_scan_xla(x, dt, a, d, g, n, length)
+
+
+def ssd_scan_xla(x, dt, a, d, g: int, n: int, length: int):
+    """`ssd_scan` as XLA products at `_SSD_PRECISION`: the fallback (CPU,
+    a shape that does not tile, a mesh) and the parity reference of the
+    kernels' tests.  u, B and C are sliced out of x and turned
+    batch-major, the chunks go `_SSD_GROUP` at a time (`_ssd_group`)
+    under `_ssd_groups`' own backward, which keeps y and the state every
+    group, nothing a token; T is padded to whole groups with steps that
+    neither decay nor write (dt 0); the skip and the swap back are a
+    pass of their own.  The passes around the scan are computed again
+    in the backward pass (`jax.checkpoint`, inside a recompute_block
+    too: bare they were slower on the chip, PR 43)."""
+    t, bsz, w = x.shape
+    h = dt.shape[-1]
+    di, bc = w - 2 * g * n, g * n
+    p, r = di // h, h // g
+    f32 = jnp.float32
     chunks = -(-t // length)
     grp = min(_SSD_GROUP, chunks)
     groups = -(-chunks // grp)
     full = groups * grp * length
-    route.lowered(
-        "ssd", f"{bsz}x{t} {h} heads of {p} over {g} groups of {n} states",
-        form="xla", chunk=length, chunks=chunks, chunks_a_group=grp,
-        edges_bytes=bsz * groups * h * p * n * 4)
+
+    def rows(x, dt):
+        """-> u (B, T, H, P), dt (B, T, H), B, C (B, T, G, N), float32."""
+        x, dt = (jnp.swapaxes(v.astype(f32), 0, 1) for v in (x, dt))
+        return (x[..., :di].reshape(bsz, t, h, p), dt,
+                x[..., di:di + bc].reshape(bsz, t, g, n),
+                x[..., di + bc:].reshape(bsz, t, g, n))
+
+    def skip(y, x, d):      # (B, T, H, P) -> (T, B, H P), + D u
+        u = x[..., :di].astype(f32).reshape(t, bsz, h, p)
+        return (jnp.swapaxes(y, 0, 1)
+                + d.astype(f32)[:, None] * u).reshape(t, bsz, di)
 
     def grouped(x, *tail):
         """(B, T, ...) -> (groups, B, chunks of a group, L, *tail)."""
         x = jnp.pad(x, ((0, 0), (0, full - t)) + ((0, 0),) * (x.ndim - 2))
         return jnp.moveaxis(x.reshape(bsz, groups, grp, length, *tail), 1, 0)
 
-    r = h // g
+    u, dt, b, c = jax.checkpoint(rows)(x, dt)
     y = _ssd_groups((grouped(u, g, r, p), grouped(dt, g, r),
-                     grouped(b, g, n), grouped(c, g, n)), a.reshape(g, r))
-    return jnp.moveaxis(y, 0, 1).reshape(bsz, full, h, p)[:, :t]
+                     grouped(b, g, n), grouped(c, g, n)),
+                    a.astype(f32).reshape(g, r))
+    y = jnp.moveaxis(y, 0, 1).reshape(bsz, full, h, p)[:, :t]
+    return jax.checkpoint(skip)(y, x, d)
 
 
 def _mamba2_flops(lp, specs, tops):
@@ -2587,7 +2643,8 @@ def _mamba2(ctx, lp, params, bottoms):
         [z | xBC | dt] = x W_in
         xBC = silu(taps over time of xBC + conv_bias), causal
         [u | B | C] = xBC;  dt = softplus(dt + dt_bias)
-        y = `ssd_scan`(u, dt, -exp(A_log), B, C) + D u, float32
+        y = `ssd_scan`([u | B | C], dt, -exp(A_log), D), float32: the
+            recurrence and the skip D u
         y = RMSNorm(y * silu(z)) over each of the G groups of channels,
             times the H P-wide `norm`
         out = y W_out
@@ -2599,8 +2656,10 @@ def _mamba2(ctx, lp, params, bottoms):
     wide array of whole 128-lane tiles (`causal_taps_silu(first=)`).
     Scopes: `ssd`, inside it `ssd.proj` (the products), `ssd.conv`
     (taps, bias, SiLU: `causal_taps_silu`, in whichever form it lowers
-    here), `ssd.scan` (softplus, the decays, the chunked scan, the
-    skip) and `ssd.norm` (the gate and the grouped norm)."""
+    here), `ssd.scan` (softplus, the decays, the chunked scan and the
+    skip: `ssd_scan`, in whichever form it lowers here, reads xBC where
+    the convolution wrote it) and `ssd.norm` (the gate and the grouped
+    norm)."""
     mp = lp.mamba2_param
     w_in, taps, conv_bias, dt_bias, a_log, d_skip, norm, w_out = params
     x = bottoms[0]
@@ -2614,20 +2673,6 @@ def _mamba2(ctx, lp, params, bottoms):
 
     conv = functools.partial(causal_taps_silu, site=lp.name, first=di)
 
-    def rows(xbc, dt, dt_bias, a_log):
-        """-> u (B, T, H, P), dt (B, T, H), A (H,), B, C (B, T, G, N),
-        float32."""
-        xbc, dt = (jnp.swapaxes(v.astype(f32), 0, 1) for v in (xbc, dt))
-        return (xbc[..., :di].reshape(bsz, t, h, p),
-                jax.nn.softplus(dt + dt_bias.astype(f32)),
-                -jnp.exp(a_log.astype(f32)),
-                xbc[..., di:di + bc].reshape(bsz, t, g, n),
-                xbc[..., di + bc:].reshape(bsz, t, g, n))
-
-    def skip(y, xbc, d_skip):   # (B, T, H, P) -> (T, B, H, P), + D u
-        u = xbc[..., :di].astype(f32).reshape(t, bsz, h, p)
-        return jnp.swapaxes(y, 0, 1) + d_skip.astype(f32)[:, None] * u
-
     def gate(y, z, norm):       # -> (T, B, H P), gated, normed by group
         y = (y.reshape(t, bsz, di) * jax.nn.silu(z.astype(f32))).reshape(
             t, bsz, g, di // g)
@@ -2636,8 +2681,9 @@ def _mamba2(ctx, lp, params, bottoms):
 
     # the elementwise passes between the products are computed again in
     # the backward pass, as `Mamba`'s are: the convolution is a `stage`;
-    # `rows`, `skip` and `gate`, which feed or read the scan's kept
-    # output, have their own checkpoint inside a recompute_block too
+    # `gate`, which reads the scan's kept output, has its own checkpoint
+    # inside a recompute_block too (and so have the passes around the
+    # scan's XLA form: `ssd_scan_xla`)
     with jax.named_scope("ssd"):
         with jax.named_scope("ssd.proj"):
             zx = jnp.einsum("tbd,ed->tbe", x, w_in[:wide], precision=prec)
@@ -2645,9 +2691,10 @@ def _mamba2(ctx, lp, params, bottoms):
         with jax.named_scope("ssd.conv"):
             xbc = stage(conv)(zx, taps, conv_bias)
         with jax.named_scope("ssd.scan"):
-            y = ssd_scan(*jax.checkpoint(rows)(xbc, dt, dt_bias, a_log),
-                         int(mp.chunk))
-            y = jax.checkpoint(skip)(y, xbc, d_skip)
+            y = ssd_scan(
+                xbc, jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)),
+                -jnp.exp(a_log.astype(f32)), d_skip.astype(f32), g, n,
+                int(mp.chunk))
         with jax.named_scope("ssd.norm"):
             y = jax.checkpoint(gate)(y, zx[..., :di], norm)
         with jax.named_scope("ssd.proj"):

@@ -2327,3 +2327,458 @@ def causal_taps_silu_kernels(z, taps, bias, plan: dict,
                    cols, plan["time_tile"], plan["channel_tile"], interpret,
                    first)
     return y.reshape(t, cols, channels)
+
+
+# ---------------------------------------------------------------------------
+# The Mamba-2 scan (state-space duality), fwd + bwd kernels
+# ---------------------------------------------------------------------------
+# `ops.layers.ssd_scan` as Mosaic calls.  A program is one batch column's
+# group of R heads (the heads that share B and C); the grid's second axis
+# walks the row's chunks in order, and the group's state (R P, N)
+# float32, the heads' channels one under the other, stays in a VMEM
+# scratch from the row's first chunk to its last.  The kernels read u, B
+# and C WHERE THEY LIE, in the time-major array the convolution stage
+# wrote ((T, B W) with [u | B | C] along W: three block specs on one
+# operand, a group's R P lanes of u and its N lanes of B and of C), and
+# write y time-major with the skip D u added: no slice, no swap and no
+# pass of its own between the two stages.  Time lies on the sublanes and
+# the channels on the lanes, so what a head has a token (dt, the running
+# sum `cum` of dt A inside the chunk) arrives as columns, the heads side
+# by side ((L, 2 R), made outside on arrays of T x H floats), and the
+# running sum a second time as rows ((R, L)): a head's (L, L) decay
+# matrix is  exp(cum as a column - cum as a row)  masked BEFORE the
+# exponential, every decay the exponential of a difference of running
+# sums (`layers._ssd_group`'s contract).  A chunk, with S the state
+# before it (`layers._ssd_group`'s algebra):
+#
+#     y  = (decay o (C B^T)) (dt u)  +  e^cum o (C S^T)  +  D u
+#     S' = e^(cum_L) S + ((dt u) o e^(cum_L - cum))^T B
+#
+# C B^T is made once a group; the read C S^T and the update are one
+# product each over the group's R P channels; the R products with the
+# decay matrices take a head's lanes of dt u, turned where a head is
+# narrower than a 128-lane tile (`_SsdChunk.own`: the tile transposed
+# once, a head's P rows streamed past its matrix).  Every product is
+# float32 at HIGHEST, as outside (`_SSD_PRECISION`).  The forward pass
+# writes y and the state BEFORE every chunk (`ssd.edges`: R P x N floats
+# a chunk and group, 134 MB a layer at nemotron3nano's shape); the
+# backward pass goes over the chunks last to first with dS in the
+# scratch, reads a chunk's state instead of computing a group again, and
+# writes du (dt's and the skip's part included), dB, dC, the sum over
+# time of dy u a lane (D's gradient), and what reaches dt and cum a
+# token and head, as columns (`_ssd_bwd_kernel` says how cum's is had
+# from y and d(dt u) with no (L, L) matrix summed).  The running sum's,
+# the softplus's and A's backward are XLA's, outside, on those T x H
+# arrays, and so is the pass that lays du, dB, dC side by side as x
+# lies (three outputs cannot share an array).  No call asks for a VMEM
+# window.
+#
+# What sets a chunk's time is the MXU's rows: a float32 product at
+# HIGHEST is six passes, each streams its left operand's rows eight at a
+# time (`vmatmul`) past a (128, 128) tile, and the forward pass of a
+# chunk and group streams 208 vregs of rows (C B^T 16, the read 64, the
+# heads' own 64, the update 64), the backward 496.  The compiler's
+# static schedule of the chunk loop (`--xla_jf_dump_llo_text`, offline)
+# counts 3,003 bundles forward and 6,521 backward at the cell's shape
+# (R = 8 heads of 64, N = 128, L = 128), and ranked every variant as
+# the chip then did; on the chip (PR 48, call 1, 512 chunks and groups
+# a layer) `cos_ssd_fwd` takes 1.11 ms and `cos_ssd_bwd` 2.45 ms, one
+# and two chunks a grid step alike (4.69 | 4.47 ms forward + backward
+# with the passes around them; four do not fit the window).  What cut
+# the schedule from 3,520 + 8,027: the turned products (128 -> 64 rows a
+# head), and cum's gradient from y (no C S^T in the backward pass, no
+# row and column sums of R (L, L) matrices).
+
+# chunks a grid step (one block of rows)
+SSD_STEP_CHUNKS = 2
+
+
+def ssd_scan_plan(t: int, bsz: int, heads: int, head_dim: int, groups: int,
+                  states: int, chunk: int):
+    """{chunk, steps, chunks, vmem_bytes} the kernels take a scan at (a
+    row of `t` tokens as `chunks` chunks, whole grid steps of `steps`),
+    or None where they do not take it.  They take: a chunk that is a
+    power of two and whole 128-lane tiles; N and a group's R P channels
+    in whole tiles; heads of whole sublanes that either share a tile
+    evenly or fill tiles; [u | B | C] a batch column laid so that a
+    group's u, B and C are whole blocks of the (T, B W) view; the
+    backward call, the larger, inside the default VMEM window."""
+    h, p, g, n, c = heads, head_dim, groups, states, int(chunk)
+    if min(h, p, g, n, c, t, bsz) < 1 or h % g:
+        return None
+    r = h // g
+    rp, di, w = r * p, h * p, h * p + 2 * g * n
+    if (c & (c - 1) or c % 128 or n % 128 or rp % 128 or p % 8
+            or (128 % p and p % 128) or 2 * r > 128):
+        return None
+    if di % n or (bsz > 1 and w % rp):
+        return None
+    chunks = -(-t // c)
+    steps = min(SSD_STEP_CHUNKS, chunks)
+    rows = steps * c
+    # the backward call: u, y, dy in and du out; B, C in and dB, dC out;
+    # the columns in and out, 128 lanes in VMEM; the rows; the states;
+    # D and its gradient; each twice (the pipeline's two buffers); dS;
+    # and what a chunk's body keeps alive beside them
+    blocks = 2 * (4 * rows * rp + 4 * rows * n + 2 * rows * 128
+                  + steps * max(r, 8) * c + steps * rp * n + 2 * rp)
+    live = 8 * c * rp + 6 * c * c + 4 * rp * n
+    vmem = 4 * (blocks + rp * n + live)
+    if _flash_window(vmem) > _SCOPED_VMEM:
+        return None
+    return {"chunk": c, "steps": steps,
+            "chunks": -(-chunks // steps) * steps, "vmem_bytes": vmem}
+
+
+def _ssd_head_lanes(r: int, p: int):
+    """[(head, first lane, last lane + 1, the head's place in that span
+    or None)]: the lanes of (L, R P) that a head's products take.  A
+    head of whole tiles takes its own; narrower heads take the tile they
+    share, each with the others' lanes zeroed."""
+    if p % 128 == 0:
+        return [(i, i * p, (i + 1) * p, None) for i in range(r)]
+    per = 128 // p
+    return [(i, i // per * 128, (i // per + 1) * 128, i % per)
+            for i in range(r)]
+
+
+def _ssd_only(x, j, p: int):
+    """A 128-lane tile with every lane but those of its head j zeroed
+    (x itself where a head has its lanes to itself)."""
+    if j is None:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= j * p) & (lane < (j + 1) * p), x, 0.0)
+
+
+class _SsdChunk:
+    """What a chunk's forward and backward passes share: C B^T, a
+    head's columns and row and its decay matrix, the heads' columns laid
+    over their lanes."""
+
+    def __init__(self, bm, cm, cols, rows, r: int, p: int):
+        n = cols.shape[0]
+        self.r, self.p, self.n = r, p, n
+        self.low = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+                    >= jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+        self.cb = _gdn_dot(cm, bm, _NT)                 # C B^T, (L, L)
+        self.dt = [cols[:, i:i + 1] for i in range(r)]
+        self.cum = [cols[:, r + i:r + i + 1] for i in range(r)]
+        self.cum_row = [rows[i:i + 1, :] for i in range(r)]
+        # cum_L, (1, 1): from the row, whose lanes a sum spreads (a
+        # corner of the columns would have to be spread both ways)
+        end = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) == n - 1
+        self.last = [_rowsum(jnp.where(end, x, 0.0)) for x in self.cum_row]
+        self.heads = _ssd_head_lanes(r, p)
+
+    def decay(self, i):
+        """e^(cum_t - cum_s) at s <= t of head i, 0 elsewhere."""
+        return jnp.exp(jnp.where(self.low, self.cum[i] - self.cum_row[i],
+                                 _NEG_INF))
+
+    def lanes(self, cols):
+        """The heads' columns [(L, 1)] -> (L, R P): each over the lanes
+        of its head."""
+        p, n = self.p, self.n
+        if p % 128 == 0:
+            return jnp.concatenate(
+                [jnp.broadcast_to(c, (n, p)) for c in cols], axis=1)
+        per = 128 // p
+        lane = jax.lax.broadcasted_iota(jnp.int32, (n, 128), 1)
+        tiles = []
+        for k in range(0, self.r, per):
+            x = jnp.broadcast_to(cols[k], (n, 128))
+            for j in range(1, per):
+                x = jnp.where(lane >= j * p, cols[k + j], x)
+            tiles.append(x)
+        return jnp.concatenate(tiles, axis=1)
+
+    def own(self, mats, x, dims=_NN):
+        """(L, R P) whose lanes of head i are mats(i) (L, L) times (dims
+        `_NN`; its transpose times, `_TN`) x's lanes of head i.  Heads
+        narrower than a tile go through the MXU turned: a tile of x as
+        (128, L), each head's P rows of it against its matrix, so that P
+        rows stream past a matrix and not L with the other heads' lanes
+        zeroed."""
+        p = self.p
+        if p % 128 == 0:
+            return jnp.concatenate(
+                [_gdn_dot(mats(i), x[:, i * p:(i + 1) * p], dims)
+                 for i in range(self.r)], axis=1)
+        per = 128 // p
+        turned = _NT if dims == _NN else _NN
+        tiles = []
+        for k in range(0, self.r, per):
+            xt = x[:, k * p:k * p + 128].T
+            tiles.append(jnp.concatenate(
+                [_gdn_dot(xt[j * p:(j + 1) * p], mats(k + j), turned)
+                 for j in range(per)], axis=0).T)
+        return jnp.concatenate(tiles, axis=1)
+
+    def by_head(self, scale, s):
+        """(R P, N), the rows of head i times scale[i], a (1, 1)."""
+        p = self.p
+        return jnp.concatenate(
+            [scale[i] * s[i * p:(i + 1) * p] for i in range(self.r)],
+            axis=0)
+
+    def head_sum(self, x, i):
+        """The sum over head i's lanes of (L, R P) -> (L, 1)."""
+        _, a, b, j = self.heads[i]
+        return _rowsum(_ssd_only(x[:, a:b], j, self.p))
+
+
+# (A chunk's arithmetic is a jitted function of values that the kernel
+# bodies call: a body is traced at every call site and again by every
+# transformation that meets it, 16 + 4 times at a nemotron3nano job's
+# start, and the jit's cache makes of all but the first a lookup; Mosaic
+# lowers the call in line, so the kernels are what they would be
+# without it.  PERF.md section 6, PR 48: the bodies written out cost the
+# job 5 s of `setup_s`.)
+@functools.partial(jax.jit, static_argnames=("r", "p"))
+def _ssd_chunk_fwd(u, bm, cm, cols, rows, d, s, *, r: int, p: int):
+    """A chunk of a group forward: u (L, R P), B, C (L, N), the heads'
+    columns (L, 2 R) and rows (R, L), D over the lanes (1, R P), the
+    state before the chunk (R P, N) -> y (L, R P), the state after."""
+    c = u.shape[0]
+    ch = _SsdChunk(bm, cm, cols, rows, r, p)
+    cum = ch.lanes(ch.cum)
+    du = ch.lanes(ch.dt) * u
+    y = (ch.own(lambda i: ch.decay(i) * ch.cb, du)
+         + jnp.exp(cum) * _gdn_dot(cm, s, _NT) + d * u)
+    return y, (ch.by_head([jnp.exp(x) for x in ch.last], s)
+               + _gdn_dot(du * jnp.exp(cum[c - 1:c, :] - cum), bm, _TN))
+
+
+@functools.partial(jax.jit, static_argnames=("r", "p"))
+def _ssd_chunk_bwd(u, bm, cm, cols, rows, d, y, dy, s, ds, *, r: int,
+                   p: int):
+    """A chunk of a group backward: the forward's operands, its y, dy,
+    the state before the chunk and dS after it -> du, dB, dC, the heads'
+    columns [d dt | d cum] (L, 128), the sum over the chunk of dy u (1,
+    R P), dS before the chunk.  With ddu the gradient of dt u, what
+    reaches a head's cum at token t is
+
+        dy_t . (y_t - D u_t)  -  dt_t (u_t . ddu_t)
+
+    (cum_t as the row of its decay matrix and in the read of the state:
+    every term of y_t but the skip carries e^(cum_t); as the column, and
+    in e^(cum_L - cum_t): every term that dt_t u_t enters carries
+    e^(-cum_t)), and at the chunk's last token e^(cum_L) S . dS and every
+    token's e^(cum_L - cum) besides: no (L, L) matrix is summed, and y
+    is the forward kernel's own output."""
+    c = u.shape[0]
+    ch = _SsdChunk(bm, cm, cols, rows, r, p)
+    dt, cum = ch.lanes(ch.dt), ch.lanes(ch.cum)
+    du = dt * u
+    dye = jnp.exp(cum) * dy
+    decay_out = jnp.exp(cum[c - 1:c, :] - cum)          # e^(cum_L - cum)
+    # d(dt u) from the state the chunk leaves, (L, R P)
+    back = decay_out * _gdn_dot(bm, ds, _NT)
+    # the heads' own products: d(decay o C B^T) = dy (dt u)^T
+    dcb = jnp.zeros((c, c), jnp.float32)
+    for i, a, b, j in ch.heads:
+        dcb = dcb + ch.decay(i) * _gdn_dot(
+            _ssd_only(dy[:, a:b], j, p), du[:, a:b], _NT)
+    ddu = ch.own(lambda i: ch.decay(i) * ch.cb, dy, _TN) + back
+    # what reaches dt and cum a token and head, as columns
+    through_dt = ddu * u
+    through_cum = dy * (y - d * u)
+    through_end = back * du
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, 128), 1)
+    dcols = jnp.zeros((c, 128), jnp.float32)
+    for i in range(r):
+        ddt = ch.head_sum(through_dt, i)
+        end = (jnp.exp(ch.last[i]) * jnp.sum(_rowsum(
+            s[i * p:(i + 1) * p] * ds[i * p:(i + 1) * p]), axis=0,
+            keepdims=True) + jnp.sum(ch.head_sum(through_end, i), axis=0,
+                                     keepdims=True))
+        dcum = (ch.head_sum(through_cum, i) - ch.dt[i] * ddt
+                + jnp.where(row == c - 1, end, 0.0))
+        dcols = jnp.where(lane == i, ddt, dcols)
+        dcols = jnp.where(lane == r + i, dcum, dcols)
+    return (dt * ddu + d * dy,
+            _gdn_dot(dcb, cm, _TN) + _gdn_dot(decay_out * du, ds),
+            _gdn_dot(dcb, bm) + _gdn_dot(dye, s), dcols,
+            jnp.sum(dy * u, axis=0, keepdims=True),
+            ch.by_head([jnp.exp(x) for x in ch.last], ds)
+            + _gdn_dot(dye, cm, _TN))
+
+
+def _ssd_fwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref,
+                    y_ref, edge_ref, s_ref, *, r: int, p: int, c: int,
+                    steps: int):
+    """`steps` chunks of one batch column's group: y and the state
+    before every chunk."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, jnp.float32)
+
+    def chunk(jj, carry):
+        at = pl.ds(pl.multiple_of(jj * c, c), c)
+        edge_ref[0, jj] = s_ref[...]
+        y_ref[at, :], s_ref[...] = _ssd_chunk_fwd(
+            u_ref[at, :], b_ref[at, :], c_ref[at, :], cols_ref[0, at, :],
+            rows_ref[0, jj], d_ref[0], s_ref[...], r=r, p=p)
+        return carry
+
+    jax.lax.fori_loop(0, steps, chunk, 0)
+
+
+def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref,
+                    y_ref, dy_ref, edge_ref, du_ref, db_ref, dc_ref,
+                    dcols_ref, dd_ref, ds_ref, *, r: int, p: int, c: int,
+                    steps: int):
+    """The reverse sweep over `steps` chunks (the grid walks the row's
+    blocks last first), dS of the group in VMEM."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros(ds_ref.shape, jnp.float32)
+        dd_ref[...] = jnp.zeros(dd_ref.shape, jnp.float32)
+
+    def chunk(it, carry):
+        jj = steps - 1 - it
+        at = pl.ds(pl.multiple_of(jj * c, c), c)
+        (du_ref[at, :], db_ref[at, :], dc_ref[at, :], dcols_ref[0, at, :],
+         dd, ds_ref[...]) = _ssd_chunk_bwd(
+            u_ref[at, :], b_ref[at, :], c_ref[at, :], cols_ref[0, at, :],
+            rows_ref[0, jj], d_ref[0], y_ref[at, :], dy_ref[at, :],
+            edge_ref[0, jj], ds_ref[...], r=r, p=p)
+        dd_ref[0] += dd
+        return carry
+
+    jax.lax.fori_loop(0, steps, chunk, 0)
+
+
+def _ssd_specs(dims, at):
+    """Block specs of one grid step of program i = (batch column, group)
+    for: u, B and C in the (T, B W) view of [u | B | C]; an array of R P
+    lanes a group ((T, B G R P): y, dy, du) and one of N lanes ((T, B G
+    N): dB, dC); the heads' columns (B G, T, lanes) and rows (B G,
+    chunks, R, L); a row of R P lanes a group or a program; the states
+    (B G, chunks, R P, N).  `at(j)` is the row block of grid step j."""
+    _, r, p, g, n, c, steps, _ = dims
+    rows, rp = steps * c, r * p
+    w = g * rp + 2 * g * n
+
+    def lanes(width, first):
+        per, off = w // width, first // width
+        return pl.BlockSpec((rows, width),
+                            lambda i, j: (at(j), i // g * per + off + i % g))
+
+    def cols(width):
+        return pl.BlockSpec((1, rows, width), lambda i, j: (i, at(j), 0))
+
+    return {
+        "u": lanes(rp, 0), "b": lanes(n, g * rp),
+        "c": lanes(n, g * rp + g * n),
+        "wide": pl.BlockSpec((rows, rp), lambda i, j: (at(j), i)),
+        "narrow": pl.BlockSpec((rows, n), lambda i, j: (at(j), i)),
+        "cols": cols(2 * r), "dcols": cols(128),
+        "rows": pl.BlockSpec((1, steps, r, c),
+                             lambda i, j: (i, at(j), 0, 0)),
+        "d": pl.BlockSpec((1, 1, rp), lambda i, j: (i % g, 0, 0)),
+        "dd": pl.BlockSpec((1, 1, rp), lambda i, j: (i, 0, 0)),
+        "edges": pl.BlockSpec((1, steps, rp, n),
+                              lambda i, j: (i, at(j), 0, 0))}
+
+
+def _ssd_rows(cols, dims):
+    """The running sums once more, as rows: the cum half of the columns
+    (B G, T, 2 R) -> (B G, chunks, R, L)."""
+    _, r, _, _, _, c, _, chunks = dims
+    return jnp.swapaxes(cols[..., r:].reshape(-1, chunks, c, r), 2, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _ssd_rule(x, cols, d, dims, interpret):
+    """x (T, B W), the heads' columns (B G, T, 2 R) = [dt | cum], D over
+    the lanes of a group (G, 1, R P) -> y (T, B H P); dims = (B, R, P,
+    G, N, chunk, chunks a grid step, chunks a row)."""
+    return _ssd_rule_fwd(x, cols, d, dims, interpret)[0]
+
+
+def _ssd_rule_fwd(x, cols, d, dims, interpret):
+    bsz, r, p, g, n, c, steps, chunks = dims
+    full = x.shape[0]
+    spec = _ssd_specs(dims, lambda j: j)
+    f32 = jnp.float32
+    y, edges = _mosaic_call(
+        functools.partial(_ssd_fwd_kernel, r=r, p=p, c=c, steps=steps),
+        "cos_ssd_fwd", (x, x, x, cols, _ssd_rows(cols, dims), d),
+        grid=(bsz * g, chunks // steps),
+        in_specs=[spec[k] for k in ("u", "b", "c", "cols", "rows", "d")],
+        out_specs=(spec["wide"], spec["edges"]),
+        out_shape=(jax.ShapeDtypeStruct((full, bsz * g * r * p), f32),
+                   jax.ShapeDtypeStruct((bsz * g, chunks, r * p, n), f32)),
+        scratch=[(r * p, n)], semantics=("parallel", "arbitrary"),
+        interpret=interpret)
+    # what a recompute_block keeps of the scan: the gated norm's
+    # backward and the backward kernel read y, the kernel a chunk's
+    # state
+    y, edges = keep(y, "ssd.y"), keep(edges, "ssd.edges")
+    return y, (x, cols, d, y, edges)
+
+
+def _ssd_rule_bwd(dims, interpret, res, dy):
+    x, cols, d, y, edges = res
+    bsz, r, p, g, n, c, steps, chunks = dims
+    full, count = x.shape[0], chunks // steps
+    spec = _ssd_specs(dims, lambda j: count - 1 - j)
+    f32 = jnp.float32
+    du, db, dc, dcols, dd = _mosaic_call(
+        functools.partial(_ssd_bwd_kernel, r=r, p=p, c=c, steps=steps),
+        "cos_ssd_bwd",
+        (x, x, x, cols, _ssd_rows(cols, dims), d, y, dy, edges),
+        grid=(bsz * g, count),
+        in_specs=[spec[k] for k in ("u", "b", "c", "cols", "rows", "d",
+                                    "wide", "wide", "edges")],
+        out_specs=tuple(spec[k] for k in ("wide", "narrow", "narrow",
+                                          "dcols", "dd")),
+        out_shape=(jax.ShapeDtypeStruct(dy.shape, f32),
+                   jax.ShapeDtypeStruct((full, bsz * g * n), f32),
+                   jax.ShapeDtypeStruct((full, bsz * g * n), f32),
+                   jax.ShapeDtypeStruct((bsz * g, full, 128), f32),
+                   jax.ShapeDtypeStruct((bsz * g, 1, r * p), f32)),
+        scratch=[(r * p, n)], semantics=("parallel", "arbitrary"),
+        interpret=interpret)
+    # [du | dB | dC] a batch column, as x lies
+    dx = jnp.concatenate(
+        [a.reshape(full, bsz, -1) for a in (du, db, dc)], axis=-1)
+    return (dx.reshape(x.shape), dcols[..., :2 * r],
+            jnp.sum(dd.reshape(bsz, g, 1, r * p), axis=0))
+
+
+_ssd_rule.defvjp(_ssd_rule_fwd, _ssd_rule_bwd)
+
+
+def ssd_scan_kernels(x, dt, a, d, plan: dict, *, groups: int, states: int,
+                     interpret: bool = False):
+    """`ops.layers.ssd_scan` through the kernels above: x (T, B, W) =
+    [u (H P) | B (G N) | C (G N)] time-major, dt (T, B, H), a = A and d
+    = D (H,) -> y (T, B, H P), differentiable in all four.  Made here,
+    outside the kernels, on arrays of T x H floats: dt A and its running
+    sum inside each chunk, and the two as the columns and rows a
+    program reads.  T is padded to whole grid steps with steps that
+    neither decay nor write (dt 0)."""
+    t, bsz, w = x.shape
+    h, g, n = dt.shape[-1], groups, states
+    r, p = h // g, (w - 2 * g * n) // h
+    c, chunks = plan["chunk"], plan["chunks"]
+    full = chunks * c
+    if full != t:
+        x, dt = (jnp.pad(v, ((0, full - t), (0, 0), (0, 0)))
+                 for v in (x, dt))
+    cum = jnp.cumsum((dt * a).reshape(chunks, c, bsz, g, r), axis=1)
+
+    def by_group(v):        # (T, B, G, R) -> (B G, T, R)
+        return jnp.moveaxis(v, 0, 2).reshape(bsz * g, full, r)
+
+    cols = jnp.concatenate(
+        [by_group(dt.reshape(full, bsz, g, r)),
+         by_group(cum.reshape(full, bsz, g, r))], axis=-1)
+    y = _ssd_rule(x.reshape(full, bsz * w), cols,
+                  jnp.repeat(d, p).reshape(g, 1, r * p),
+                  (bsz, r, p, g, n, c, plan["steps"], chunks), interpret)
+    return y.reshape(full, bsz, h * p)[:t]

@@ -43,9 +43,11 @@ KEPT = (
     # gate's and the skip's backward) and the states at the chunks'
     # edges (where the backward's recomputation of a chunk starts)
     "ssm.y", "ssm.edges",
-    # `layers._ssd_groups`: the chunked scan's output (read by the gated
-    # norm's and the skip's backward) and the states at the groups'
-    # edges (where the backward's recomputation of a group starts)
+    # `pallas_kernels._ssd_rule` / `layers._ssd_groups`: the Mamba-2
+    # scan's output (read by the gated norm's backward) and the states
+    # its backward starts from: before every chunk (cos_ssd_fwd's, read
+    # by cos_ssd_bwd) or at the groups' edges (the XLA form's, where the
+    # recomputation of a group starts)
     "ssd.y", "ssd.edges",
     # `layers._moe_dropless`, scope moe.route: what the router's own
     # backward reads (its product at HIGHEST, top_k's choice) ...

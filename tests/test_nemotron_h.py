@@ -101,63 +101,124 @@ def highest():
 # ------------------------------------------------------------- the scan
 
 def _scan_inputs(bsz, t, h, p, g, n, dt_scale=1.0, seed=0):
-    ks = jax.random.split(jax.random.key(seed), 6)
+    """u, dt, A, B, C batch-major as the recurrence reads them, D, and a
+    weight for y."""
+    ks = jax.random.split(jax.random.key(seed), 7)
     return ((jax.random.normal(ks[0], (bsz, t, h, p)),
              dt_scale * jax.nn.softplus(
                  jax.random.normal(ks[1], (bsz, t, h)) - 2),
              -jnp.exp(jax.random.normal(ks[2], (h,))),
              jax.random.normal(ks[3], (bsz, t, g, n)),
-             jax.random.normal(ks[4], (bsz, t, g, n))),
+             jax.random.normal(ks[4], (bsz, t, g, n)),
+             jax.random.normal(ks[6], (h,))),
             jax.random.normal(ks[5], (bsz, t, h, p)))
 
 
-def _recurrence(u, dt, a, b, c):
-    """The reference's token-by-token scan, a row at a time."""
+def _recurrence(u, dt, a, b, c, d):
+    """The reference's token-by-token scan, a row at a time, and the
+    skip."""
     return jnp.stack([ref.ssd_recurrence(u[i], dt[i], a, b[i], c[i])
-                      for i in range(u.shape[0])])
+                      for i in range(u.shape[0])]) + d[:, None] * u
 
 
-@pytest.mark.parametrize("bsz,t,h,g,dt_scale", [
-    (1, 8, 4, 4, 1.0),          # one chunk
-    (2, 40, 4, 2, 1.0),         # several groups of chunks, B > 1, G < H
-    (2, 37, 4, 1, 1.0),         # a T that is no multiple of the chunk
-    (1, 40, 4, 2, 1e-4),        # decays near 1: the state hardly fades
-    (1, 40, 4, 2, 60.0),        # decays near 0: a chunk forgets its start
-])
-def test_chunked_scan_against_the_token_recurrence(monkeypatch, bsz, t, h,
-                                                   g, dt_scale):
-    """Value and every gradient (u, dt, A, B, C) of `ssd_scan` equal
-    the time-step recurrence's, groups of 2 chunks of 8 so that the
-    kept edges, the padding and the carry are all exercised."""
+def _scan(u, dt, a, b, c, d, chunk):
+    """`ssd_scan` on the time-major [u | B | C] that the layer hands it,
+    back as the recurrence's (B, T, H, P)."""
+    bsz, t, h, p = u.shape
+    g, n = b.shape[2:]
+    x = jnp.concatenate([v.reshape(bsz, t, -1) for v in (u, b, c)], axis=-1)
+    y = L.ssd_scan(jnp.swapaxes(x, 0, 1), jnp.swapaxes(dt, 0, 1), a, d,
+                   g, n, chunk)
+    return jnp.swapaxes(y, 0, 1).reshape(bsz, t, h, p)
+
+
+# the XLA form at the small net's sizes (chunks of 8, groups of 2
+# chunks), and the kernels in interpret mode at sizes that tile (chunks
+# of 128, 128 states, a group's heads 128 channels wide).  The last
+# column is the gap allowed against the recurrence: where a chunk of
+# 128 tokens forgets its start the running sum of dt A reaches -1,000
+# inside it, an ulp of which is 6e-5 of a decay (either form reads
+# 3e-5 on dt's gradient there).  A's gradient is a sum over all tokens
+# of terms of both signs, and a token's own term (decay 1, no
+# derivative) cancels in it only as far as float32 goes: the kernels,
+# which take what reaches cum from y and d(dt u) instead of summing
+# (L, L) matrices, are allowed five times the gap there (they read
+# 3e-4 where nothing but the token itself survives, the XLA form 5e-5;
+# 2e-6 on both at decays of common size)
+SCANS = [
+    ("xla", 1, 8, 4, 4, 1.0, 2e-5),     # one chunk
+    ("xla", 2, 40, 4, 2, 1.0, 2e-5),    # groups of chunks, B > 1, G < H
+    ("xla", 2, 37, 4, 1, 1.0, 2e-5),    # a T that is no multiple of the chunk
+    ("xla", 1, 40, 4, 2, 1e-4, 2e-5),   # decays near 1: the state hardly fades
+    ("xla", 1, 40, 4, 2, 60.0, 2e-5),   # near 0: a chunk forgets its start
+    ("kernel", 1, 128, 8, 1, 1.0, 2e-5),    # one chunk, R = 8
+    ("kernel", 2, 600, 16, 2, 1.0, 2e-5),   # grid steps, ragged, B = 2, G = 2
+    ("kernel", 1, 300, 2, 2, 1.0, 2e-5),    # R = 1: a head fills a tile
+    ("kernel", 1, 400, 8, 1, 1e-4, 2e-5),   # decays near 1
+    ("kernel", 1, 400, 8, 1, 60.0, 1e-4),   # decays near 0
+]
+
+
+@pytest.mark.parametrize("form,bsz,t,h,g,dt_scale,rel", SCANS)
+def test_chunked_scan_against_the_token_recurrence(monkeypatch, form, bsz,
+                                                   t, h, g, dt_scale, rel):
+    """Value and every gradient (u, dt, A, B, C, D) of `ssd_scan` equal
+    the time-step recurrence's, in both forms: XLA's with groups of 2
+    chunks of 8 so that the kept edges, the padding and the carry are
+    all exercised, and the kernels' (interpret mode), which are held to
+    the XLA form at the same operands besides, closer (the two share
+    the chunked algebra; A's gradient is a sum over all tokens of terms
+    of both signs)."""
     monkeypatch.setattr(L, "_SSD_GROUP", 2)
-    x, w = _scan_inputs(bsz, t, h, 8, g, 16, dt_scale)
-    got = L.ssd_scan(*x, 8)
-    want = _recurrence(*x)
+    route.forget("ssd")
+    if form == "kernel":
+        monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+        r = h // g
+        p, n, chunk = 128 // r, 128, 128
+    else:
+        p, n, chunk = 8, 16, 8
+    x, w = _scan_inputs(bsz, t, h, p, g, n, dt_scale)
+    names = "u dt A B C D".split()
+
+    def both(fn):
+        return fn(*x), jax.grad(lambda *a: jnp.sum(fn(*a) * w),
+                                argnums=tuple(range(6)))(*x)
+
+    got, grads = both(lambda *a: _scan(*a, chunk))
+    assert [e["form"] for e in route.plans()["ssd"].values()] == [form]
+    want, wants = both(_recurrence)
     np.testing.assert_allclose(got, want, rtol=2e-5,
                                atol=2e-5 * float(jnp.abs(want).max()))
-    grads = jax.grad(lambda *a: jnp.sum(L.ssd_scan(*a, 8) * w),
-                     argnums=(0, 1, 2, 3, 4))(*x)
-    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w),
-                     argnums=(0, 1, 2, 3, 4))(*x)
-    for name, a, b in zip("u dt A B C".split(), grads, wants):
-        close(a, b, 2e-5, name)
+    loose = {"A": 5 * rel} if form == "kernel" else {}
+    for name, a, b in zip(names, grads, wants):
+        close(a, b, loose.get(name, rel), name)
+    if form == "kernel":
+        monkeypatch.delenv("COS_FLASH_INTERPRET")
+        xla, xlas = both(lambda *a: _scan(*a, chunk))
+        assert route.plans()["ssd"].popitem()[1]["form"] == "xla"
+        close(got, xla, 2e-6, "y")
+        loose["dt"] = 5e-5
+        for name, a, b in zip(names, grads, xlas):
+            close(a, b, loose.get(name, 5e-6), name)
 
 
-def test_scan_records_what_was_lowered_and_keeps_no_token_state():
-    """`info.ssd`: the XLA form, the chunk, the chunks a row and a
-    group, the kept states' bytes; the backward's residuals hold the
-    five operands, y's sibling is not among them, and the only state
-    kept is one a group of chunks."""
+def test_scan_records_what_was_lowered_and_keeps_no_token_state(monkeypatch):
+    """`info.ssd` at the cell's shape.  On the CPU: the XLA form, the
+    chunk, the chunks a row and a group, the kept states' bytes; the
+    backward's residuals hold the operands, y's sibling is not among
+    them, and the only state kept is one a group of chunks.  Where the
+    kernels are lowered: the state before every chunk (134 MB), two
+    chunks a grid step, the backward call's VMEM inside the default
+    window."""
     route.forget("ssd")
-    x, _ = _scan_inputs(1, 8192, 64, 64, 8, 128)
-    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in x]
-    del x
-    jax.eval_shape(lambda *a: L.ssd_scan(*a, 128), *shapes)
-    assert route.plans()["ssd"] == {
-        "1x8192 64 heads of 64 over 8 groups of 128 states": {
-            "form": "xla", "chunk": 128, "chunks": 64,
-            "chunks_a_group": L._SSD_GROUP,
-            "edges_bytes": 64 // L._SSD_GROUP * 64 * 64 * 128 * 4}}
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+        (8192, 1, 6144), (8192, 1, 64), (64,), (64,))]
+    key = "1x8192 64 heads of 64 over 8 groups of 128 states"
+    jax.eval_shape(lambda *a: L.ssd_scan(*a, 8, 128, 128), *shapes)
+    assert route.plans()["ssd"] == {key: {
+        "form": "xla", "chunk": 128, "chunks": 64,
+        "chunks_a_group": L._SSD_GROUP,
+        "edges_bytes": 64 // L._SSD_GROUP * 64 * 64 * 128 * 4}}
     _, res = jax.eval_shape(
         lambda *a: L._ssd_groups_fwd(a[:4], a[4]),
         *(jax.ShapeDtypeStruct(s, jnp.float32) for s in (
@@ -165,6 +226,14 @@ def test_scan_records_what_was_lowered_and_keeps_no_token_state():
             (4, 1, 16, 128, 8, 128), (4, 1, 16, 128, 8, 128), (8, 8))))
     assert [r.shape for r in jax.tree.leaves(res)][-1] == (4, 1, 8, 8, 64,
                                                             128)
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    jax.eval_shape(lambda *a: L.ssd_scan(*a, 8, 128, 128), *shapes)
+    entry = route.plans()["ssd"][key]
+    assert entry == {
+        "form": "kernel", "chunk": 128, "chunks": 64, "chunks_a_group": 1,
+        "edges_bytes": 64 * 64 * 64 * 128 * 4, "chunks_a_step": 2,
+        "vmem_bytes": entry["vmem_bytes"]}
+    assert entry["vmem_bytes"] + (1 << 19) <= 16 << 20
 
 
 # ------------------------------------------------------- layer by layer
@@ -189,6 +258,12 @@ def _cfg_params(cfg, lname, seed=3, scale=None):
 
 MAMBA2 = ('mamba2_param { num_heads: 4 head_dim: 8 n_groups: 2 d_state: 16 '
           'd_conv: 4 chunk: 8 rms_norm_eps: 1e-5 }')
+# a Mamba-2 layer that the scan's kernels take (interpret mode): two
+# heads of 64 over one group of 128 states, chunks of 128, two chunks
+TILED = dict(mamba_heads=2, mamba_head_dim=64, n_groups=1, d_state=128,
+             chunk=128, seq=256)
+MAMBA2_TILED = ('mamba2_param { num_heads: 2 head_dim: 64 n_groups: 1 '
+                'd_state: 128 d_conv: 4 chunk: 128 rms_norm_eps: 1e-5 }')
 MOE = ('moe_param { num_experts: 16 hidden_dim: 12 top_k: 3 '
        'dispatch: "dropless" scoring: "sigmoid" selection_bias: true '
        'routed_scaling_factor: 2.5 norm_epsilon: 1e-20 gated: false '
@@ -197,19 +272,25 @@ GQA = ('attention_param { num_heads: 16 num_kv_heads: 1 head_dim: 8 '
        'causal: true rotary: false }')
 
 
-@pytest.mark.parametrize("layer", ["mamba2", "moe", "attn", "attn_flash"])
+@pytest.mark.parametrize("layer", ["mamba2", "mamba2_kernels", "moe",
+                                   "attn", "attn_flash"])
 def test_every_operator_output_and_parameter_gradients(monkeypatch, layer):
     """Each of the three operators alone against the reference's, value
     and the gradient of every blob and of the input; the g = 16
-    attention on the einsum route and through the flash kernels."""
-    cfg = small_cfg()
+    attention on the einsum route and through the flash kernels, the
+    Mamba-2 mixer with its scan in XLA's form and (two batch columns of
+    two chunks, at sizes that tile) on the scan's and the convolution's
+    kernels, which read [u | B | C] where the stage before wrote it."""
+    cfg = small_cfg(**(TILED if layer == "mamba2_kernels" else {}))
     m = ref.dims(cfg)
-    t, bsz = {"attn_flash": (128, 1), "attn": (20, 2)}.get(layer, (20, 1))
-    if layer == "attn_flash":
+    t, bsz = {"attn_flash": (128, 1), "attn": (20, 2),
+              "mamba2_kernels": (256, 2)}.get(layer, (20, 1))
+    if layer in ("attn_flash", "mamba2_kernels"):
         monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
-        route.forget("flash")
+        route.forget("flash", "ssd")
     kind, text, lname, fn = {
         "mamba2": ("Mamba2", MAMBA2, "L1.mamba2", ref.mamba2),
+        "mamba2_kernels": ("Mamba2", MAMBA2_TILED, "L1.mamba2", ref.mamba2),
         "moe": ("MixtureOfExperts", MOE, "L0.moe",
                 lambda *a: ref.moe(*a)[0]),
         "attn": ("GroupedQueryAttention", GQA, "L8.attn", ref.attention),
@@ -243,6 +324,9 @@ def test_every_operator_output_and_parameter_gradients(monkeypatch, layer):
     if layer == "attn_flash":
         plan = route.plans()["flash"]
         assert list(plan) == ["16x128x8/8 float32 g16 causal"]
+    if layer == "mamba2_kernels":
+        assert [e["form"] for e in route.plans()["ssd"].values()] == [
+            "kernel"]
 
 
 def test_relu2_is_not_relu_and_the_default_is_todays_layer():
@@ -328,23 +412,35 @@ def _loss_and_grads(net_param, p, data):
     return float(loss), flat(grads)
 
 
-def test_recompute_blocks_on_and_off_give_equal_gradients():
-    """The published blocks 40-42 (`EM*`: every operator once)."""
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_recompute_blocks_on_and_off_give_equal_gradients(monkeypatch, form):
+    """The published blocks 40-42 (`EM*`: every operator once), with the
+    scan in XLA's form and on its kernels (where the attention and the
+    convolution stage are on theirs too)."""
     cut = dict(first_layer=40, layers=3)
+    if form == "kernel":
+        monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+        cut.update(TILED)
+    seq = cut.get("seq", SMALL["seq"])
     p = unflat(ref.init_params(small_cfg(**cut), 5))
-    data = batches(1)[0]
+    data = batches(1, seq=seq)[0]
     on = small_net(**cut)
     assert len(Net(on, NetState(phase=Phase.TRAIN)).recompute_blocks) == 3
-    route.forget("recompute")
+    route.forget("recompute", "ssd")
     l_on, g_on = _loss_and_grads(on, p, data)
     l_off, g_off = _loss_and_grads(small_net(recompute=False, **cut), p,
                                    data)
     assert l_on == pytest.approx(l_off, rel=1e-6)
     for k, v in g_off.items():
         close(g_on[k], v, 1e-5, k)
+    assert [e["form"] for e in route.plans()["ssd"].values()] == [form]
     kept = route.plans()["recompute"]["blocks"]
     assert set(kept["L1"]) == {"ssd.y", "ssd.edges"}
-    assert kept["L1"]["ssd.y"] == 2 * 24 * 32 * 4       # padded to 3 chunks
+    if form == "kernel":        # y time-major, a state a chunk
+        assert kept["L1"] == {"ssd.y": 256 * 2 * 128 * 4,
+                              "ssd.edges": 2 * 2 * 128 * 128 * 4}
+    else:
+        assert kept["L1"]["ssd.y"] == 2 * 24 * 32 * 4   # padded to 3 chunks
 
 
 def test_refused_by_name_under_a_time_sharding_mesh():

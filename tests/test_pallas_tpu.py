@@ -263,6 +263,59 @@ def test_gated_delta_rule_kernels_real_shape_on_tpu():
 _NORM_SHAPES = [(32, 96, 27, 27), (32, 256, 13, 13)]
 
 
+def test_mamba2_scan_kernels_real_shape_on_tpu():
+    """`nemotron3nano.train_packed8k`'s scan (1 x 8,192, 64 heads of 64
+    over 8 groups of 128 states, chunk 128, [u | B | C] 6,144 wide with
+    the skip): the Mosaic kernels, forward and the four gradients,
+    against the XLA form, both float32 at HIGHEST (another order of the
+    same products; A's and dt's gradients sum terms of both signs over
+    the row).  dt and A as the layer's fillers draw them.  Under a
+    watchdog, as every new lowering."""
+    import faulthandler
+    import jax
+    import jax.numpy as jnp
+    from caffeonspark_tpu.ops import layers as L
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    t, b, h, p, g, n, c = 8192, 1, 64, 64, 8, 128, 128
+    rng = np.random.RandomState(11)
+    x = rng.randn(t, h * p + 2 * g * n)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (1, h))) \
+        * np.exp(0.5 * rng.randn(t, h))
+    a = -np.arange(1.0, h + 1)
+    d = rng.randn(h)
+    args = [jnp.asarray(v, jnp.float32) for v in (x, dt, a, d)]
+    w = jnp.asarray(rng.randn(t, h * p), jnp.float32)
+    plan = pk.ssd_scan_plan(t, b, h, p, g, n, c)
+    assert plan
+
+    def kernels(x, dt, a, d):      # 2-D arguments: no relayout at a call
+        return pk.ssd_scan_kernels(x[:, None], dt[:, None], a, d, plan,
+                                   groups=g, states=n)[:, 0]
+
+    def xla(x, dt, a, d):
+        return L.ssd_scan_xla(x[:, None], dt[:, None], a, d, g, n, c)[:, 0]
+
+    def both(scan):
+        return (jax.jit(scan)(*args),
+                jax.jit(jax.grad(lambda *v: jnp.sum(scan(*v) * w),
+                                 argnums=(0, 1, 2, 3)))(*args))
+
+    faulthandler.dump_traceback_later(300, exit=True)
+    try:
+        got, got_grads = jax.device_get(both(kernels))
+        want, want_grads = jax.device_get(both(xla))
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+    for name, v, r in zip(("y", "dx", "ddt", "dA", "dD"),
+                          [got] + list(got_grads),
+                          [want] + list(want_grads)):
+        assert v.shape == r.shape and np.isfinite(v).all(), name
+        err = np.abs(v - r).max() / max(np.abs(r).max(), 1e-12)
+        print(f"mamba-2 scan {b}x{t} {h} heads of {p} over {g} groups of "
+              f"{n} {name}: max gap / max {err:.3e}")
+        assert err < 1e-4, (name, err)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("shape", _NORM_SHAPES)
 def test_lrn_fuse_relu_parity_on_tpu(shape, dtype):

@@ -4,9 +4,9 @@
 `ops/recompute.py`: the block is computed again in the backward pass,
 all but the values its layers name as they make them: a Mosaic forward
 kernel's outputs that its own backward reads (flash attention, the gated
-delta rule, the selective scan) and the router's result.  Held here, on
-the CPU with the kernels in interpret mode, a layer type a case: the
-recomputation holds
+delta rule, the selective scan, the Mamba-2 scan) and the router's
+result.  Held here, on the CPU with the kernels in interpret mode, a
+layer type a case: the recomputation holds
 no forward kernel, `top_k`, sort or router product and the saved
 residuals are the list's names; values and gradients are a plain
 `jax.checkpoint`'s to the last bit; outside a block the names are
@@ -66,10 +66,20 @@ def ssm_bytes(ch=128, n=16, c=64):
 
 
 def ssd_bytes(h=2, p=64, n=128, c=64):
+    """The XLA form (a chunk of 64 does not tile): y padded to whole
+    groups of chunks, the state before every group."""
     groups = -(-(-(-T // c)) // L._SSD_GROUP)
     full = groups * min(L._SSD_GROUP, -(-T // c)) * c
     return {"ssd.y": B * full * h * p * 4,
             "ssd.edges": groups * B * h * p * n * 4}
+
+
+def ssd_kernel_bytes(h=2, p=64, g=1, n=128, c=128):
+    """The kernels: y time-major, padded to whole grid steps, and the
+    state before every chunk."""
+    chunks = pk.ssd_scan_plan(T, B, h, p, g, n, c)["chunks"]
+    return {"ssd.y": chunks * c * B * h * p * 4,
+            "ssd.edges": B * chunks * h * p * n * 4}
 
 
 def moe_bytes():
@@ -97,11 +107,15 @@ CASES = {
             'chunk: 64 }', gdn_bytes, ("cos_gdn_fwd",)),
     "mamba": ('type: "Mamba" mamba_param { d_inner: 128 d_state: 16 '
               'd_conv: 4 dt_rank: 4 chunk: 64 }', ssm_bytes, ("cos_ssm_fwd",)),
-    # one form, XLA's: what must not run again is the forward scan over
-    # the groups of chunks (`held`: "ssd_forward")
+    # the XLA form (a chunk of 64 does not tile): what must not run
+    # again is the forward scan over the groups of chunks (`held`:
+    # "ssd_forward"); and the kernels at a chunk of 128
     "mamba2": ('type: "Mamba2" mamba2_param { num_heads: 2 head_dim: 64 '
                'n_groups: 1 d_state: 128 d_conv: 4 chunk: 64 }', ssd_bytes,
                ("ssd_forward",)),
+    "mamba2_kernel": ('type: "Mamba2" mamba2_param { num_heads: 2 '
+                      'head_dim: 64 n_groups: 1 d_state: 128 d_conv: 4 '
+                      'chunk: 128 }', ssd_kernel_bytes, ("cos_ssd_fwd",)),
     "moe_sigmoid": (MOE % ("sigmoid", "selection_bias: true "
                            "routed_scaling_factor: 2.5 "
                            "shared_hidden_dim: 12"),
@@ -124,10 +138,11 @@ KEEPING = [c for c in CASES if c != "dense"]
 STAGED = {"gdn": ((T, B, 2 * 128 + 2 * 128), 0, 2, 3),
           "mamba": ((T, B, 128), 2, 1, 3),
           # Mamba-2's gate is on z, 128 wide: no SiLU of the
-          # convolution's 384 channels outside its stage; `rows`, `skip`
-          # and `gate` keep a checkpoint of their own
-          # (four stages, not three)
-          "mamba2": ((T, B, 128 + 2 * 128), 0, 1, 4)}
+          # convolution's 384 channels outside its stage; `gate` keeps
+          # a checkpoint of its own (two stages), and so do the scan's
+          # XLA form's `rows` and `skip` (four)
+          "mamba2": ((T, B, 128 + 2 * 128), 0, 1, 4),
+          "mamba2_kernel": ((T, B, 128 + 2 * 128), 0, 1, 2)}
 
 
 def build(cases, tag=True, **net_kw):

@@ -1,7 +1,7 @@
 """`ops/route.py`: the one rule that says which form of an operator is
 lowered, held against every operator that asks it (LRN, flash attention,
 the convolution + SiLU stage, the gated delta rule, the selective scan,
-the Mamba-2 scan, which has one form so far and records it):
+the Mamba-2 scan):
 what a traced program holds (a `pallas_call`, a `shard_map` around it, or
 neither) and, where the kind keeps one, what `route.plans()` says.  And
 the two properties the module exists for: nothing else under `ops/`
@@ -64,19 +64,15 @@ def _ssm(tiles):
 
 def _ssd(tiles):
     p = 128 if tiles else 96
-    return (lambda *a: L.ssd_scan(*a, 16),
-            [f32(1, 64, 2, p), f32(1, 64, 2), f32(2), f32(1, 64, 1, 128),
-             f32(1, 64, 1, 128)],
-            ("ssd", f"1x64 2 heads of {p} over 1 groups of 128 states"))
+    return (lambda *a: L.ssd_scan(*a, 1, 128, 128),
+            [f32(140, 1, 2 * p + 256), f32(140, 1, 2), f32(2), f32(2)],
+            ("ssd", f"1x140 2 heads of {p} over 1 groups of 128 states"))
 
 
 KINDS = {"lrn": _lrn, "flash": _flash, "taps": _taps, "gdn": _gdn,
          "ssm": _ssm, "ssd": _ssd}
 # the word a kind's plan holds the form under
 FORM = {"taps": "form", "gdn": "rule", "ssm": "form", "ssd": "form"}
-# one form so far, XLA's: it asks `route.kernel` nothing and records
-# itself all the same (its kernel, when it comes, takes mesh="refuse")
-XLA_ONLY = ("ssd",)
 # parallel over the batch (and heads): under a mesh the kernel stays, on
 # each device's block
 OVER_SHARDS = ("lrn", "flash")
@@ -119,8 +115,8 @@ def test_which_form_is_lowered(monkeypatch, kind, where):
     else:
         prims = primitives(jax.make_jaxpr(fn)(*args).jaxpr)
     calls = [inside for name, inside in prims if name == "pallas_call"]
-    kernel = kind not in XLA_ONLY and (
-        where == "interpret" or (where == "mesh" and kind in OVER_SHARDS))
+    kernel = where == "interpret" or (where == "mesh"
+                                      and kind in OVER_SHARDS)
     assert bool(calls) is kernel
     if where == "mesh" and kernel:
         assert all("shard_map" in inside for inside in calls)

@@ -1,6 +1,7 @@
 """The flash kernels at the real shapes of the benchmark's language
 models, the gated delta rule's kernels at qwen3next's, the selective
-scan's at phi4flash's and the convolution stage's at both, compiled for
+scan's at phi4flash's, the Mamba-2 scan's at nemotron3nano's and the
+convolution stage's at all three, compiled for
 a described (not attached) TPU v5e: what interpret
 mode cannot see — VMEM, tiling, the grouped block index maps.  PR 33
 found here, before any chip time, that a 64-wide head is padded to 128
@@ -240,6 +241,50 @@ def test_selective_scan_kernels_compile_for_v5e(one_chip):
     assert all('"scoped_memory_configs":[]' in line for line in lines)
 
 
+def test_mamba2_scan_kernels_compile_for_v5e(one_chip):
+    """The scan's forward and backward calls at `nemotron3nano.train_
+    packed8k`'s shape (1 x 8,192, 64 heads of 64 over 8 groups of 128
+    states, chunk 128, [u | B | C] 6,144 wide) lower for the v5e: one
+    call each over the 8 groups' 32 grid steps of two chunks, neither
+    with a VMEM window of its own (10.6 MB counted for the backward call,
+    the larger).  What does not tile falls to the XLA form."""
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    t, b, h, p, g, n = 8192, 1, 64, 64, 8, 128
+    plan = pk.ssd_scan_plan(t, b, h, p, g, n, 128)
+    assert plan == {"chunk": 128, "steps": 2, "chunks": 64,
+                    "vmem_bytes": 10641408}
+    assert pk._flash_window(plan["vmem_bytes"]) <= pk._SCOPED_VMEM
+    assert pk.ssd_scan_plan(t, b, h, p, g, n, 64) is None    # the chunk
+    assert pk.ssd_scan_plan(t, b, h, p, g, 64, 128) is None     # N
+    assert pk.ssd_scan_plan(t, b, h, 8, g, n, 128) is None      # R P = 64
+    assert pk.ssd_scan_plan(t, b, h, 96, g, n, 128) is None     # heads of 96
+    assert pk.ssd_scan_plan(t, b, 60, p, g, n, 128) is None     # H / G
+    assert pk.ssd_scan_plan(t, b, 256, 256, g, 256, 256) is None    # VMEM
+    # two batch columns: 6,144 = 12 blocks of R P = 512 a column
+    assert pk.ssd_scan_plan(t, 2, h, p, g, n, 128) == plan
+
+    def loss(*a):
+        return jnp.sum(pk.ssd_scan_kernels(*a, plan, groups=g, states=n))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+              for s in ((t, b, h * p + 2 * g * n), (t, b, h), (h,), (h,))]
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grad).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert [o.shape for o in jax.eval_shape(grad, *shapes)] == [
+        s.shape for s in shapes]
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("cos_ssd_fwd", "cos_ssd_bwd"):
+        assert sum(name in line for line in lines) == 1, name
+    assert len(lines) == 2
+    assert all('"scoped_memory_configs":[]' in line for line in lines)
+
+
 # the convolution stage of `qwen3next.train_packed8k` (the first 8,192
 # of W_qkvz's 12,288 channels) and of `phi4flash.train_packed8k` (the
 # first 5,120 of W_in's 10,240, with a bias), and of `nemotron3nano.
@@ -339,8 +384,11 @@ def test_nemotron3nano_step_compiles_for_v5e(one_chip, monkeypatch):
     with the operators on the forms the cell is to run: the g = 16
     attention on the three flash kernels, the four convolution stages
     on the taps kernels reading xBC from lane 4,096 of [z | xBC], the
-    Mamba-2 scan in XLA's form; no Mosaic call asks for a VMEM window;
-    arguments + temporaries stand under the chip's 15.75 GB."""
+    four Mamba-2 scans on their kernels reading u, B and C from the
+    taps kernels' output in place (67 Mosaic calls); no Mosaic call asks
+    for a VMEM window; arguments + temporaries stand under the chip's
+    15.75 GB (8,003,682,816 + 5,131,206,144 = 13.13 GB as compiled
+    here: the states before every chunk are kept, 134 MB a layer)."""
     from caffeonspark_tpu.models import zoo
     from caffeonspark_tpu.proto import SolverParameter
     from caffeonspark_tpu.solver import Solver
@@ -374,10 +422,15 @@ def test_nemotron3nano_step_compiles_for_v5e(one_chip, monkeypatch):
         < 15.75e9
     lines = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(lines) == 67
+    # (by the call's own name: a scan's line names the taps call whose
+    # output it reads)
     for name, calls in (("cos_flash_fwd", 1), ("cos_flash_bwd_dq", 1),
                         ("cos_flash_bwd_dkv", 1), ("cos_taps_fwd", 8),
-                        ("cos_taps_bwd", 4)):
-        assert sum(name in line for line in lines) == calls, name
+                        ("cos_taps_bwd", 4), ("cos_ssd_fwd", 4),
+                        ("cos_ssd_bwd", 4)):
+        assert sum(f'/{name}/pallas_call"' in line
+                   for line in lines) == calls, name
     windows = [int(n) for line in lines for n in re.findall(
         r'"scoped_memory_configs":\[\{"memory_space":"1",'
         r'"offset":"\d+","size":"(\d+)"', line)]
@@ -390,8 +443,11 @@ def test_nemotron3nano_step_compiles_for_v5e(one_chip, monkeypatch):
         f"L{i}.mamba2" for i in (1, 3, 5, 7)]
     assert plans["ssd"] == {
         "1x8192 64 heads of 64 over 8 groups of 128 states": {
-            "form": "xla", "chunk": 128, "chunks": 64, "chunks_a_group": 16,
-            "edges_bytes": 4 * 64 * 64 * 128 * 4}}
+            "form": "kernel", "chunk": 128, "chunks": 64,
+            "chunks_a_group": 1, "edges_bytes": 64 * 64 * 64 * 128 * 4,
+            "chunks_a_step": 2, "vmem_bytes": 10641408}}
+    assert plans["recompute"]["blocks"]["L1"] == {
+        "ssd.y": 8192 * 4096 * 4, "ssd.edges": 64 * 64 * 64 * 128 * 4}
     assert sorted(plans["recompute"]["blocks"]) == [
         f"L{i}" for i in range(9)]
     assert "relu2" in next(iter(plans["moe"]))
