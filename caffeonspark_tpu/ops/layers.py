@@ -25,12 +25,13 @@ Reference equivalents: caffe-public layer implementations consumed via
 from __future__ import annotations
 
 import contextlib
+import copy
 import functools
 import math
 import os
 import zlib
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -2917,7 +2918,11 @@ def _moe(ctx, lp, params, bottoms):
     return tops
 
 
-_MOE_ROW_TILE = 512     # the grouped product's row tile on the TPU
+# A pass's rows come in whole multiples of this (`_moe_chunk_rows`): four
+# row tiles of the grouped-product kernels (`pallas_kernels.GMM_ROW_TILE`,
+# which divides it), and what `lax.ragged_dot`'s calls tile their rows by
+# where the XLA form runs.
+_MOE_ROW_TILE = 512
 
 
 def _moe_chunk_rows(n: int, k: int, held: int, e: int) -> int:
@@ -2935,12 +2940,38 @@ _MOE_PLANS = route.entries("moe")
 
 
 def moe_plans() -> dict:
-    return {k: dict(v, layers=list(v["layers"]))
-            for k, v in _MOE_PLANS.items()}
+    return copy.deepcopy(_MOE_PLANS)
+
+
+class _MoeKernels(NamedTuple):
+    """The kernel form of a layer shape's grouped products: the tiles
+    against the first weights (G, D, hidden) and against the last (G,
+    hidden, D), and whether the calls run in interpret mode."""
+    into: "GmmTiles"
+    out: "GmmTiles"
+    interpret: bool
+
+
+def _moe_products(kernels, sizes, rows, prec):
+    """The grouped product of a pass whose group g owns `sizes[g]` of
+    its `rows` sorted rows: `product(a, w, out=False)`, a (rows, K)
+    against w (G, K, N), `out` for the experts' last weights.  The
+    Mosaic kernels (`pallas_kernels.grouped_product`: float32 tiles of a
+    and of w rounded to bfloat16 in VMEM, accumulated in float32, w and,
+    in the backward pass, its transpose read where they lie) where the
+    layer took that form, else `lax.ragged_dot` at `prec`."""
+    if not kernels:
+        return lambda a, w, out=False: lax.ragged_dot(
+            a, w.astype(a.dtype), sizes, precision=prec)
+    from .pallas_kernels import gmm_visits, grouped_product
+    visits = gmm_visits(sizes, rows)
+    return lambda a, w, out=False: grouped_product(
+        a, w, visits, kernels.out if out else kernels.into,
+        kernels.interpret)
 
 
 def _moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts, ends, total,
-              rows, k, gated, prec):
+              rows, k, gated, prec, kernels=None):
     """`acc` plus what the sorted rows [lo, lo + rows) add to it: linear
     in `acc`, so a pass's gradients need no running sum."""
     with jax.named_scope("moe.gather"):
@@ -2952,19 +2983,15 @@ def _moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts, ends, total,
                  - jnp.clip(starts - lo, 0, rows))
         xs = jnp.where(valid[:, None], xf[tok], 0)
     with jax.named_scope("moe.products"):
-        hid = _moe_hidden(
-            gated,
-            lax.ragged_dot(xs, w_in[0].astype(xs.dtype), sizes,
-                           precision=prec),
-            lambda: lax.ragged_dot(xs, w_in[1].astype(xs.dtype), sizes,
-                                   precision=prec))
-        ys = lax.ragged_dot(hid, w_out.astype(xs.dtype), sizes,
-                            precision=prec)
+        product = _moe_products(kernels, sizes, rows, prec)
+        hid = _moe_hidden(gated, product(xs, w_in[0]),
+                          lambda: product(xs, w_in[1]))
+        ys = product(hid, w_out, out=True)
     with jax.named_scope("moe.combine"):
-        # rows past the last group hold whatever the kernel left (NaN
-        # bit patterns included): they are cut out BEFORE the product,
-        # so that neither the sum nor the gates' gradient (d/dg = ys)
-        # ever sees them
+        # rows past the last group hold whatever the kernels left (NaN
+        # bit patterns included, in either form): they are cut out
+        # BEFORE the product, so that neither the sum nor the gates'
+        # gradient (d/dg = ys) ever sees them
         ys = jnp.where(valid[:, None], ys, 0) \
             * gates[idx][:, None].astype(ys.dtype)
         return acc.at[tok].add(ys)
@@ -2976,28 +3003,30 @@ def _moe_passes_run(total, rows, n_pass):
     return jnp.minimum(n_pass, (total + rows - 1) // rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(8, 9, 10, 11, 12, 13))
 def _moe_passes(xf, gates, w_in, w_out, order, starts, ends, total, rows,
-                n_pass, k, gated, prec):
+                n_pass, k, gated, prec, kernels=None):
     """The routed sum over the passes that run, (N, D).  The trip count
     is the step's own, forward and backward; the integer operands are
     arguments (a tracer closed over would leak under the block's
     `jax.checkpoint`) and take no cotangent."""
     return _moe_passes_fwd(xf, gates, w_in, w_out, order, starts, ends,
-                           total, rows, n_pass, k, gated, prec)[0]
+                           total, rows, n_pass, k, gated, prec, kernels)[0]
 
 
 def _moe_passes_fwd(xf, gates, w_in, w_out, order, starts, ends, total,
-                    rows, n_pass, k, gated, prec):
+                    rows, n_pass, k, gated, prec, kernels):
     res = (xf, gates, w_in, w_out, order, starts, ends, total)
     routed = lax.fori_loop(
         0, _moe_passes_run(total, rows, n_pass),
-        lambda i, acc: _moe_pass(acc, i * rows, *res, rows, k, gated, prec),
+        lambda i, acc: _moe_pass(acc, i * rows, *res, rows, k, gated, prec,
+                                 kernels),
         jnp.zeros_like(xf))
     return routed, res
 
 
-def _moe_passes_bwd(rows, n_pass, k, gated, prec, res, g):
+def _moe_passes_bwd(rows, n_pass, k, gated, prec, kernels, res, g):
     """The passes that ran, last to first (the order in which a scan's
     transpose sums, so the gradients round as its would): a pass is
     computed again, pulled back against `g` (every pass's output
@@ -3012,7 +3041,7 @@ def _moe_passes_bwd(rows, n_pass, k, gated, prec, res, g):
         # value is not used, and the gradients do not depend on it
         _, pull = jax.vjp(
             lambda *a: _moe_pass(g, lo, *a, order, starts, ends, total,
-                                 rows, k, gated, prec), *diff)
+                                 rows, k, gated, prec, kernels), *diff)
         return jax.tree.map(jnp.add, sums, pull(g))
 
     sums = lax.fori_loop(0, n_run, body,
@@ -3038,8 +3067,13 @@ def _moe_dropless(ctx, lp, params, bottoms):
     Dispatch: the k·N assignments are sorted by expert, those of
     experts held elsewhere last.  The sorted rows are taken in passes
     of `_moe_chunk_rows` rows: gather the tokens, three grouped
-    products (`lax.ragged_dot`: on the TPU a Mosaic grouped matmul
-    that visits only the tiles its groups cover), weight, scatter-add.
+    products (two for ungated experts; `_moe_products`: on the TPU the
+    repo's own Mosaic kernels, `pallas_kernels.grouped_product`, one
+    bfloat16 pass of float32 tiles with float32 accumulation, in row
+    tiles of 128 that visit only the tiles a group covers; under a mesh,
+    at HIGHEST, off the TPU and for shapes that do not tile
+    `lax.ragged_dot`, the compiler's grouped matmul), weight,
+    scatter-add.
     Only the passes that hold a held assignment run: held rows sort
     first, so they are the first ceil(held / rows) (`_moe_passes_run`), a
     number known on the device before the loop starts.  An even router
@@ -3139,11 +3173,24 @@ def _moe_dropless(ctx, lp, params, bottoms):
     w_in = (pd["W_gate"], pd["W_up"]) if mp.gated else (pd["W1"],)
     w_out = pd["W_down"] if mp.gated else pd["W2"]
     hidden, products = int(w_out.shape[1]), len(w_in) + 1
+    # the form of the grouped products: the kernels where the shape
+    # tiles, float32 comes in, no mesh is installed and the layer is not
+    # pinned to float32 products (an autotune plan's HIGHEST)
+    from .pallas_kernels import GMM_ROW_TILE, gmm_plan
+    tiles = (gmm_plan(rows, d, hidden, held), gmm_plan(rows, hidden, d, held))
+    kernel = route.kernel(
+        all(tiles) and prec != lax.Precision.HIGHEST, xf, gates, *w_in,
+        w_out)
+    kernels = _MoeKernels(*tiles, kernel.interpret) if kernel else None
     # `info.moe`, by layer shape: the layers that took it, the k N
     # assignments, the rows and the number of passes, the passes an even
-    # router fills, the row tile, the forward operations a held row
-    # costs and the bytes of expert weight gradient that the backward
-    # loop carries, added into once a pass that runs
+    # router fills, the rows' quantum, the forward operations a held row
+    # costs, the bytes of expert weight gradient that the backward
+    # loop carries, added into once a pass that runs, the form of the
+    # grouped products ("kernel" | "xla") and, of the kernels, the tiles
+    # (`pallas_kernels.GmmTiles` against the first and the last weights)
+    # and the call sites a layer's step holds: a product's forward call,
+    # and in the backward loop the call again, its rows^T and its weights
     plan = route.lowered(
         "moe", f"{n}x{d} top {k} of {e}, {held} held x {hidden}"
         f"{' gated' if mp.gated else ''}"
@@ -3156,13 +3203,18 @@ def _moe_dropless(ctx, lp, params, bottoms):
         passes_even_router=-(-(k * n * held) // (e * rows)),
         row_tile=_MOE_ROW_TILE, row_flops=2 * d * hidden * products,
         carry_bytes=(held * products * d * hidden
-                     * jnp.dtype(w_out.dtype).itemsize))
+                     * jnp.dtype(w_out.dtype).itemsize),
+        form="kernel" if kernels else "xla",
+        calls=4 * products if kernels else 0,
+        **({"tiles": {side: {"rows": GMM_ROW_TILE, **t._asdict()}
+                      for side, t in zip(("into", "out"), tiles)}}
+           if kernels else {}))
     if lp.name not in plan["layers"]:
         plan["layers"].append(lp.name)
 
     with jax.named_scope("moe.experts"):
         routed = _moe_passes(xf, gates, w_in, w_out, order, starts, ends,
-                             total, rows, n_pass, k, gated, prec)
+                             total, rows, n_pass, k, gated, prec, kernels)
 
     out = routed
     if "S_up" in pd:

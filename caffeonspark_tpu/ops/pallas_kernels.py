@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,46 @@ from . import route
 from .recompute import keep
 
 TILE = 512  # spatial lanes per block (4 × 128)
+
+
+# `pl.pallas_call` hands back a function under an inlined `jax.jit`.
+# Made anew at every call site, as a kernel with many sites in a step
+# is (an attention's chunk pairs in every layer, the convolution stage
+# and the scans once a layer forward, again in a block's recomputation
+# and backward, the expert layers' 8 to 12 products a layer), the body,
+# the index maps and the grid are traced again each time, in Python, at
+# every job start, compile cache or none: half of a smallthinker job's
+# 14 s of building and tracing here, 1.9 s of an lfm2 step's for the 72
+# sites of the grouped products alone.  Built once an argument list,
+# every site after a shape's first is that jit's cache lookup and binds
+# the same equation under its own name stack; what is lowered is what it
+# was.  PERF.md section 7, "what a Mosaic call site costs a job's start".
+_BUILDERS: list = []
+
+
+def _built_once(build):
+    """`build(*shapes, **statics)` -> what `pl.pallas_call` returns, as
+    a function of the operands: called with arrays (and the statics by
+    name) it hands `build` their `jax.ShapeDtypeStruct`s, runs it once
+    an argument list (`functools.lru_cache`: a static that cannot be
+    hashed is an error) and applies the call to the arrays.  All that
+    `build` can read of the site is its arguments, so they are the
+    whole key."""
+    cached = functools.lru_cache(maxsize=256)(build)
+
+    @functools.wraps(build)
+    def call(*operands, **statics):
+        return cached(*(jax.ShapeDtypeStruct(x.shape, x.dtype)
+                        for x in operands), **statics)(*operands)
+
+    _BUILDERS.append(cached)
+    return call
+
+
+def forget_calls() -> None:
+    """Drop every call `_built_once` holds (tests that count traces)."""
+    for cached in _BUILDERS:
+        cached.cache_clear()
 
 
 def _window_sum(v: jax.Array, pad: int) -> jax.Array:
@@ -962,9 +1002,9 @@ def _check_blocks(t, block_q, block_k):
             f"t={t} % block_q={block_q}, t={t} % block_k={block_k}")
 
 
-def _flash_fwd_one(q, k, v, causal, offset=0, *, sm_scale, tiles,
-                   interpret, out_dtype, window=0):
-    """One forward call: (out, lse) of q over all of k, v."""
+@_built_once
+def _flash_fwd_built(q, k, v, *, causal, offset, sm_scale, tiles,
+                     interpret, out_dtype, window):
     bh, t, d = q.shape
     dv = v.shape[-1]
     block_q, block_k = tiles
@@ -973,7 +1013,7 @@ def _flash_fwd_one(q, k, v, causal, offset=0, *, sm_scale, tiles,
     ospec, _, _, _ = _flash_specs(block_q, dv, t)
     _, kspec = _flash_kv_specs(block_q, d, t, g)
     _, vspec = _flash_kv_specs(block_q, dv, t, g)
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                           causal=causal, block_k=block_k, window=window,
                           offset=offset),
@@ -984,7 +1024,16 @@ def _flash_fwd_one(q, k, v, causal, offset=0, *, sm_scale, tiles,
         out_specs=(ospec, vec),
         interpret=interpret,
         name="cos_flash_fwd",
-    )(q, k, v)
+    )
+
+
+def _flash_fwd_one(q, k, v, causal, offset=0, *, sm_scale, tiles,
+                   interpret, out_dtype, window=0):
+    """One forward call: (out, lse) of q over all of k, v."""
+    out, lse = _flash_fwd_built(
+        q, k, v, causal=causal, offset=offset, sm_scale=sm_scale,
+        tiles=tiles, interpret=interpret, out_dtype=jnp.dtype(out_dtype),
+        window=window)
     return out, lse[:, :, 0]
 
 
@@ -1086,10 +1135,9 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret,
     return out.reshape(b, h, t, v.shape[-1]), (qf, kf, vf, out, lse)
 
 
-def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *, tiles,
-                  interpret, out_dtype, window=0):
-    """One pair's dq: a program a block of q, all of k and v past it;
-    the statistics as (block_q, 1) columns beside the scores' rows."""
+@_built_once
+def _flash_dq_built(qf, kf, vf, dof, lse, delta, *, causal, offset, tiles,
+                    interpret, out_dtype, window):
     bh, t, d = qf.shape
     dv_w = vf.shape[-1]
     block_q, block_k = tiles
@@ -1108,14 +1156,22 @@ def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *, tiles,
         out_specs=qspec,
         interpret=interpret,
         name="cos_flash_bwd_dq",
-    )(qf, kf, vf, dof, lse[:, :, None], delta[:, :, None])
+    )
 
 
-def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *,
-                   tiles, interpret, out_dtypes, window=0):
-    """One pair's dk, dv: a program a block of k and v, all of q and dO
-    past it; the statistics as (1, t) rows beside the transposed
-    scores' columns."""
+def _flash_dq_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *, tiles,
+                  interpret, out_dtype, window=0):
+    """One pair's dq: a program a block of q, all of k and v past it;
+    the statistics as (block_q, 1) columns beside the scores' rows."""
+    return _flash_dq_built(
+        qf, kf, vf, dof, lse[:, :, None], delta[:, :, None], causal=causal,
+        offset=offset, tiles=tiles, interpret=interpret,
+        out_dtype=jnp.dtype(out_dtype), window=window)
+
+
+@_built_once
+def _flash_dkv_built(qf, kf, vf, dof, lse, delta, *, causal, offset, tiles,
+                     interpret, out_dtypes, window):
     bh, t, d = qf.shape
     dv_w = vf.shape[-1]
     block_q, block_k = tiles
@@ -1124,7 +1180,7 @@ def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *,
     dvspec, dofull, _, _ = _flash_specs(block_k, dv_w, t)
     kspec, _ = _flash_kv_specs(block_k, d, t, g)
     vspec, _ = _flash_kv_specs(block_k, dv_w, t, g)
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel,
                           sm_scale=1.0 / math.sqrt(d), causal=causal,
                           block_q=block_q, window=window, offset=offset),
@@ -1135,7 +1191,21 @@ def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *,
         out_specs=(dkspec, dvspec),
         interpret=interpret,
         name="cos_flash_bwd_dkv",
-    )(qf, kf, vf, dof, lse[:, None, :], delta[:, None, :])
+    )
+
+
+def _flash_dkv_one(qf, kf, vf, dof, lse, delta, causal, offset=0, *,
+                   tiles, interpret, out_dtypes, window=0):
+    """One pair's dk, dv: a program a block of k and v, all of q and dO
+    past it; the statistics as (1, t) rows beside the transposed
+    scores' columns."""
+    bh, t, d = qf.shape
+    dv_w = vf.shape[-1]
+    g = bh // kf.shape[0]
+    dk, dv = _flash_dkv_built(
+        qf, kf, vf, dof, lse[:, None, :], delta[:, None, :], causal=causal,
+        offset=offset, tiles=tiles, interpret=interpret,
+        out_dtypes=tuple(jnp.dtype(x) for x in out_dtypes), window=window)
     if g > 1:
         # one dk, dv a query head: the group's sum is its key/value
         # head's gradient
@@ -1979,13 +2049,13 @@ def _ssm_bwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, edge_ref, dy_ref,
     da_ref[0, cb] = da
 
 
-def _mosaic_call(kernel, name, operands, *, grid, in_specs, out_specs,
-                 out_shape, scratch, interpret,
+def _mosaic_call(kernel, name, *, grid, in_specs, out_specs, out_shape,
+                 scratch, interpret,
                  semantics=("parallel", "arbitrary", "arbitrary")):
     """One Mosaic call over a grid of three axes with float32 VMEM
     scratch (the scan's: batch rows, chunks, channel blocks, the last
     two in order with the states in the scratch); no VMEM window is
-    asked."""
+    asked.  The call, for a `_built_once` builder to hand back."""
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=semantics)}
@@ -1993,7 +2063,7 @@ def _mosaic_call(kernel, name, operands, *, grid, in_specs, out_specs,
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
-        interpret=interpret, name=name, **params)(*operands)
+        interpret=interpret, name=name, **params)
 
 
 def _ssm_specs(chunk, block, n, blocks, at):
@@ -2015,38 +2085,44 @@ def _ssm_scan(u, dt, bx, cx, a, chunk, block, interpret):
     return _ssm_scan_fwd(u, dt, bx, cx, a, chunk, block, interpret)[0]
 
 
-def _ssm_scan_fwd(u, dt, bx, cx, a, chunk, block, interpret):
+@_built_once
+def _ssm_fwd_call(u, dt, bx, cx, a, *, chunk, block, interpret):
     bsz, t, ch = u.shape
     blocks, n = a.shape[0], a.shape[1]
     chunks = t // chunk
     rows, wide, aspec, edge, _ = _ssm_specs(chunk, block, n, blocks,
                                             lambda j: j)
     f32 = jnp.float32
-    y, edges = _mosaic_call(
+    return _mosaic_call(
         functools.partial(_ssm_fwd_kernel, chunk=chunk), "cos_ssm_fwd",
-        (u, dt, bx, cx, a), grid=(bsz, chunks, blocks),
+        grid=(bsz, chunks, blocks),
         in_specs=[rows, rows, wide, wide, aspec], out_specs=(rows, edge),
         out_shape=(jax.ShapeDtypeStruct(u.shape, f32),
                    jax.ShapeDtypeStruct((bsz, chunks, blocks, n, block),
                                         f32)),
         scratch=[(blocks, n, block)], interpret=interpret)
+
+
+def _ssm_scan_fwd(u, dt, bx, cx, a, chunk, block, interpret):
+    y, edges = _ssm_fwd_call(u, dt, bx, cx, a, chunk=chunk, block=block,
+                             interpret=interpret)
     # what a recompute_block keeps of the scan: the gate's backward
     # reads y, the chunks' recomputation starts from the edges
     y, edges = keep(y, "ssm.y"), keep(edges, "ssm.edges")
     return y, (u, dt, bx, cx, a, edges)
 
 
-def _ssm_scan_bwd(chunk, block, interpret, res, dy):
-    u, dt, bx, cx, a, edges = res
+@_built_once
+def _ssm_bwd_call(u, dt, bx, cx, a, edges, dy, *, chunk, block, interpret):
     bsz, t, ch = u.shape
     blocks, n = a.shape[0], a.shape[1]
     chunks = t // chunk
     rows, wide, aspec, edge, da = _ssm_specs(
         chunk, block, n, blocks, lambda j: chunks - 1 - j)
     f32 = jnp.float32
-    du, ddt, dbx, dcx, dax = _mosaic_call(
+    return _mosaic_call(
         functools.partial(_ssm_bwd_kernel, chunk=chunk), "cos_ssm_bwd",
-        (u, dt, bx, cx, a, edges, dy), grid=(bsz, chunks, blocks),
+        grid=(bsz, chunks, blocks),
         in_specs=[rows, rows, wide, wide, aspec, edge, rows],
         out_specs=(rows, rows, wide, wide, da),
         out_shape=(jax.ShapeDtypeStruct(u.shape, f32),
@@ -2056,6 +2132,11 @@ def _ssm_scan_bwd(chunk, block, interpret, res, dy):
                    jax.ShapeDtypeStruct((bsz,) + a.shape, f32)),
         scratch=[(blocks, n, block), (chunk + 1, n, block)],
         interpret=interpret)
+
+
+def _ssm_scan_bwd(chunk, block, interpret, res, dy):
+    du, ddt, dbx, dcx, dax = _ssm_bwd_call(
+        *res, dy, chunk=chunk, block=block, interpret=interpret)
     return du, ddt, dbx, dcx, jnp.sum(dax, axis=0)
 
 
@@ -2251,16 +2332,16 @@ def _taps_grid(z, taps, cols, tile, block, first=0):
 
 
 # (The two calls are plain functions, not a `jax.jit` each: under a jit
-# the unrolled bodies are traced once a shape instead of once a call
-# site, 1.5 s less of tracing for a qwen3_next step, but the -train job
-# then starts 6 s later warm and 16 s later with an empty compile cache:
-# PERF.md, section 7, PR 44.)
-def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret, first=0):
-    """z (T, cols W), taps (L, C), bias (1, C) -> y (T, cols C)."""
+# of their own the -train job started 6 s later warm and 16 s later with
+# an empty compile cache, PERF.md, section 7, PR 44.  Since PR 50 the
+# unrolled bodies are traced once a shape all the same: `_built_once`.)
+@_built_once
+def _taps_fwd_built(z, above, taps, bias, *, cols, tile, block, interpret,
+                    first):
     grid, (wide, up, _), (narrow, _, _), consts = _taps_grid(
         z, taps, cols, tile, block, first)
     return _mosaic_call(
-        _taps_fwd_kernel, "cos_taps_fwd", (z, z, taps, bias), grid=grid,
+        _taps_fwd_kernel, "cos_taps_fwd", grid=grid,
         in_specs=[wide, up, *consts], out_specs=narrow,
         out_shape=jax.ShapeDtypeStruct(
             (z.shape[0], cols * taps.shape[1]), jnp.float32),
@@ -2268,17 +2349,22 @@ def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret, first=0):
         semantics=("parallel",) * 3, interpret=interpret)
 
 
-def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret,
-                   first=0):
-    """-> da (T, cols C), the sums over time (L + 1, 8, C): the taps'
-    gradient row by row, then the bias's, eight partial sums each."""
+def _taps_fwd_call(z, taps, bias, cols, tile, block, interpret, first=0):
+    """z (T, cols W), taps (L, C), bias (1, C) -> y (T, cols C)."""
+    return _taps_fwd_built(z, z, taps, bias, cols=cols, tile=tile,
+                           block=block, interpret=interpret, first=first)
+
+
+@_built_once
+def _taps_bwd_built(z, above, below, dy, dy_below, taps, bias, *, cols,
+                    tile, block, interpret, first):
     n, channels = taps.shape
     f32 = jnp.float32
     grid, (wide, up, down), (narrow, _, narrow_down), consts = _taps_grid(
         z, taps, cols, tile, block, first)
     return _mosaic_call(
-        _taps_bwd_kernel, "cos_taps_bwd", (z, z, z, dy, dy, taps, bias),
-        grid=grid, in_specs=[wide, up, down, narrow, narrow_down, *consts],
+        _taps_bwd_kernel, "cos_taps_bwd", grid=grid,
+        in_specs=[wide, up, down, narrow, narrow_down, *consts],
         out_specs=(narrow, pl.BlockSpec((n + 1, 8, block),
                                         lambda c, b, t: (0, 0, c))),
         out_shape=(jax.ShapeDtypeStruct(dy.shape, f32),
@@ -2286,6 +2372,15 @@ def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret,
         scratch=[(_TAPS_HALO + min(_TAPS_ROWS, tile), block),
                  (2 * _TAPS_HALO, block), (tile + _TAPS_HALO, block)],
         interpret=interpret)
+
+
+def _taps_bwd_call(z, taps, bias, dy, cols, tile, block, interpret,
+                   first=0):
+    """-> da (T, cols C), the sums over time (L + 1, 8, C): the taps'
+    gradient row by row, then the bias's, eight partial sums each."""
+    return _taps_bwd_built(z, z, z, dy, dy, taps, bias, cols=cols,
+                           tile=tile, block=block, interpret=interpret,
+                           first=first)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -2699,21 +2794,26 @@ def _ssd_rule(x, cols, d, dims, interpret):
     return _ssd_rule_fwd(x, cols, d, dims, interpret)[0]
 
 
-def _ssd_rule_fwd(x, cols, d, dims, interpret):
-    bsz, r, p, g, n, c, steps, chunks = dims
-    full = x.shape[0]
+@_built_once
+def _ssd_fwd_call(u, b, c, cols, rows, d, *, dims, interpret):
+    bsz, r, p, g, n, lc, steps, chunks = dims
     spec = _ssd_specs(dims, lambda j: j)
     f32 = jnp.float32
-    y, edges = _mosaic_call(
-        functools.partial(_ssd_fwd_kernel, r=r, p=p, c=c, steps=steps),
-        "cos_ssd_fwd", (x, x, x, cols, _ssd_rows(cols, dims), d),
-        grid=(bsz * g, chunks // steps),
+    return _mosaic_call(
+        functools.partial(_ssd_fwd_kernel, r=r, p=p, c=lc, steps=steps),
+        "cos_ssd_fwd", grid=(bsz * g, chunks // steps),
         in_specs=[spec[k] for k in ("u", "b", "c", "cols", "rows", "d")],
         out_specs=(spec["wide"], spec["edges"]),
-        out_shape=(jax.ShapeDtypeStruct((full, bsz * g * r * p), f32),
+        out_shape=(jax.ShapeDtypeStruct((u.shape[0], bsz * g * r * p),
+                                        f32),
                    jax.ShapeDtypeStruct((bsz * g, chunks, r * p, n), f32)),
         scratch=[(r * p, n)], semantics=("parallel", "arbitrary"),
         interpret=interpret)
+
+
+def _ssd_rule_fwd(x, cols, d, dims, interpret):
+    y, edges = _ssd_fwd_call(x, x, x, cols, _ssd_rows(cols, dims), d,
+                             dims=dims, interpret=interpret)
     # what a recompute_block keeps of the scan: the gated norm's
     # backward and the backward kernel read y, the kernel a chunk's
     # state
@@ -2721,17 +2821,16 @@ def _ssd_rule_fwd(x, cols, d, dims, interpret):
     return y, (x, cols, d, y, edges)
 
 
-def _ssd_rule_bwd(dims, interpret, res, dy):
-    x, cols, d, y, edges = res
-    bsz, r, p, g, n, c, steps, chunks = dims
-    full, count = x.shape[0], chunks // steps
+@_built_once
+def _ssd_bwd_call(u, b, c, cols, rows, d, y, dy, edges, *, dims,
+                  interpret):
+    bsz, r, p, g, n, lc, steps, chunks = dims
+    full, count = u.shape[0], chunks // steps
     spec = _ssd_specs(dims, lambda j: count - 1 - j)
     f32 = jnp.float32
-    du, db, dc, dcols, dd = _mosaic_call(
-        functools.partial(_ssd_bwd_kernel, r=r, p=p, c=c, steps=steps),
-        "cos_ssd_bwd",
-        (x, x, x, cols, _ssd_rows(cols, dims), d, y, dy, edges),
-        grid=(bsz * g, count),
+    return _mosaic_call(
+        functools.partial(_ssd_bwd_kernel, r=r, p=p, c=lc, steps=steps),
+        "cos_ssd_bwd", grid=(bsz * g, count),
         in_specs=[spec[k] for k in ("u", "b", "c", "cols", "rows", "d",
                                     "wide", "wide", "edges")],
         out_specs=tuple(spec[k] for k in ("wide", "narrow", "narrow",
@@ -2742,6 +2841,15 @@ def _ssd_rule_bwd(dims, interpret, res, dy):
                    jax.ShapeDtypeStruct((bsz * g, full, 128), f32),
                    jax.ShapeDtypeStruct((bsz * g, 1, r * p), f32)),
         scratch=[(r * p, n)], semantics=("parallel", "arbitrary"),
+        interpret=interpret)
+
+
+def _ssd_rule_bwd(dims, interpret, res, dy):
+    x, cols, d, y, edges = res
+    bsz, r, p, g = dims[:4]
+    full = x.shape[0]
+    du, db, dc, dcols, dd = _ssd_bwd_call(
+        x, x, x, cols, _ssd_rows(cols, dims), d, y, dy, edges, dims=dims,
         interpret=interpret)
     # [du | dB | dC] a batch column, as x lies
     dx = jnp.concatenate(
@@ -2782,3 +2890,298 @@ def ssd_scan_kernels(x, dt, a, d, plan: dict, *, groups: int, states: int,
                   jnp.repeat(d, p).reshape(g, 1, r * p),
                   (bsz, r, p, g, n, c, plan["steps"], chunks), interpret)
     return y.reshape(full, bsz, h * p)[:t]
+
+
+# ---------------------------------------------------------------------------
+# The expert layers' grouped products
+# ---------------------------------------------------------------------------
+# `ops.layers._moe_pass`'s products as Mosaic calls.  The rows of a pass
+# are sorted by expert: group g of the G experts held owns `sizes[g]`
+# consecutive rows, the rows past the last group belong to nobody.  Three
+# kinds of product, one call each:
+#
+#     rows      out[r] = a[r] W[g(r)]          a (M, K), W (G, K, N) -> (M, N)
+#     rows^T    out[r] = a[r] W[g(r)]^T        a (M, N)              -> (M, K)
+#     weights   dW[g]  = a[rows of g]^T b[rows of g]   (M, K), (M, N) -> (G, K, N)
+#
+# The stated precision and no more: every kernel reads float32 tiles
+# where they lie (W and W^T through the block specs' index maps, no
+# copy, cast or transpose of a weight outside), rounds them to bfloat16
+# in VMEM and accumulates in float32, one MXU pass, as `jnp.matmul` of the
+# same operands runs at JAX's default precision.
+#
+# The rows go in tiles of `GMM_ROW_TILE`; a VISIT is a (row tile, group)
+# pair whose rows intersect, in the order of the groups (`gmm_visits`,
+# made once a pass on G integers; at most tiles + G - 1 of them, the
+# grid's last axis, the unused ones at the end standing still on the
+# last visit's blocks).  The grid is (block of output lanes, visit) for
+# the two row products: the group's (K, lanes) block of W stays in VMEM
+# while the group's row tiles go past it, is fetched once a group (the
+# pipeline skips a block whose index does not change) and rounded to
+# bfloat16 once, into a scratch, when the group changes; a tile that two
+# groups share is visited by each, which writes its own rows.  For the
+# weights' gradient the grid is (block of K, block of N, visit): the
+# (K block, N block) of dW[g] stays in VMEM and sums the group's row
+# tiles, every other group's rows in a shared tile masked out; an empty
+# group is visited once, to be zeroed.  A hidden width that is no
+# multiple of 128 (nemotron3nano's 1856) is a whole block where it is
+# contracted and a masked last block where it is the output's lanes;
+# nothing is padded outside.
+#
+# The rows that no group owns (those past the last group) are left
+# undefined in every output: zeros or whatever the buffer held;
+# `_moe_pass` cuts those rows out before it sums.
+
+_BF16 = jnp.bfloat16
+
+
+class GmmTiles(NamedTuple):
+    """Tiles of the three products against one weight (G, K, N), beside
+    the row tile `GMM_ROW_TILE`: the lanes of N a `rows` call holds of
+    W, the rows of K a `rows^T` call holds, the (K, N) block of dW."""
+    lanes: int
+    lanes_t: int
+    grad: Tuple[int, int]
+
+
+# What the blocks of one call may take of the default 16 MiB window by
+# the counts below, which are generous (the calls compile for the v5e at
+# 15 MiB so counted; `tests/test_tpu_compile.py` holds the cells'
+# shapes): at 14 the five cells' passes read 2-5% shorter than at 12 (my
+# chip run, PR 50, call 1).  The row tile is 128 whatever the rows an
+# even router sends an expert (160 to 1,536 in the five cells): 256 read
+# level to 3% slower in all five and 512 17-49% slower (a tile that two
+# groups share is multiplied once for each), and at 128 the blocks of W
+# can be widest.
+_GMM_VMEM = 14 << 20
+GMM_ROW_TILE = 128
+
+
+def _gmm_rows_bytes(k: int, lanes: int, transposed: bool) -> int:
+    """VMEM of a `rows` call: the (K, lanes) block of W twice (the
+    pipeline's buffers) and once in bfloat16, the row tile twice and its
+    bfloat16 copy, the output tile twice and the product."""
+    w = _lanes(k) * lanes if transposed else k * _lanes(lanes)
+    tm = GMM_ROW_TILE
+    return 10 * w + 10 * tm * _lanes(k) + 12 * tm * _lanes(lanes)
+
+
+def _gmm_grad_bytes(bk: int, bn: int) -> int:
+    """VMEM of a `weights` call: the block of dW twice and the product,
+    the two row tiles twice and in bfloat16."""
+    return (12 * bk * _lanes(bn)
+            + 10 * GMM_ROW_TILE * (_lanes(bk) + _lanes(bn)))
+
+
+def _gmm_fit(width: int, most: int, fits) -> int:
+    """The block of `width` lanes to take: the fewest blocks that `fits`
+    allows (blocks are multiples of 128, at most `most`), and of those
+    the narrowest, so that the masked last block wastes least; `width`
+    itself where it fits whole; 0 where nothing does."""
+    if width <= most and fits(width):
+        return width
+    best = 0
+    for lanes in range(128, min(most, _lanes(width)) + 1, 128):
+        if fits(lanes):
+            best = lanes
+    if not best:
+        return 0
+    count = -(-width // best)
+    return _lanes(-(-width // count))
+
+
+@functools.lru_cache(maxsize=None)
+def gmm_plan(m: int, k: int, n: int, groups: int) -> Optional[GmmTiles]:
+    """The tiles of the products of (M, K) rows against (G, K, N)
+    weights, or None where the kernels do not take the shape: M in whole
+    row tiles, K and N in whole sublane tiles of bfloat16.  A function
+    of the shape alone: the widest blocks that leave the calls inside
+    the default VMEM window, so that the rows are read again (once a
+    block of W) as few times as can be."""
+    if min(m, k, n, groups) < 1 or m % GMM_ROW_TILE or k % 16 or n % 16:
+        return None
+    lanes = _gmm_fit(n, 1024, lambda b: _gmm_rows_bytes(
+        k, b, False) <= _GMM_VMEM)
+    lanes_t = _gmm_fit(k, 1024, lambda b: _gmm_rows_bytes(
+        n, b, True) <= _GMM_VMEM)
+    # dW's block: the widest N first (its rows are the lanes of both
+    # the block and b's tile), then the most of K beside it
+    bn = _gmm_fit(n, 1024, lambda b: _gmm_grad_bytes(256, b) <= _GMM_VMEM)
+    bk = bn and _gmm_fit(k, 1024, lambda b: _gmm_grad_bytes(
+        b, bn) <= _GMM_VMEM)
+    if not (lanes and lanes_t and bn and bk):
+        return None
+    return GmmTiles(lanes, lanes_t, (bk, bn))
+
+
+@functools.partial(jax.jit, static_argnames=("m",), inline=True)
+def gmm_visits(sizes, m: int):
+    """The visits of a pass of `m` rows whose group g owns `sizes[g]`
+    consecutive rows from the first row on: int32 (4 V,), V = m / tm + G
+    - 1 (tm = `GMM_ROW_TILE`), laid [row tile | group | first row | end
+    row] with the rows counted from the tile's first.  Every (tile,
+    group) whose rows intersect, the groups in order and a group's tiles
+    in order; an empty group once, with no rows, at the tile its first
+    row would lie in; what is left of V repeats the last visit with no
+    rows."""
+    g, tm = sizes.shape[0], GMM_ROW_TILE
+    tiles = m // tm
+    ends = jnp.cumsum(sizes.astype(jnp.int32))
+    starts = ends - sizes
+    first = jnp.minimum(starts // tm, tiles - 1)
+    last = jnp.where(sizes > 0, (ends - 1) // tm, first)
+    count = last - first + 1
+    upto = jnp.cumsum(count)
+    v = jnp.arange(tiles + g - 1, dtype=jnp.int32)
+    live = v < upto[-1]
+    grp = jnp.minimum(jnp.sum(v[:, None] >= upto[None, :], axis=1),
+                      g - 1).astype(jnp.int32)
+    tile = jnp.where(live, first[grp] + v - (upto - count)[grp],
+                     last[g - 1])
+    lo = jnp.where(live, jnp.maximum(starts[grp] - tile * tm, 0), 0)
+    hi = jnp.where(live, jnp.minimum(ends[grp] - tile * tm, tm), 0)
+    return jnp.concatenate([tile, grp, lo, hi]).astype(jnp.int32)
+
+
+def _gmm_mine(s_ref, v, visits: int, tm: int):
+    """(tm, 1) bool: the rows of visit v's tile that its group owns."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return (row >= s_ref[2 * visits + v]) & (row < s_ref[3 * visits + v])
+
+
+def _gmm_rows_kernel(s_ref, a_ref, w_ref, o_ref, wb_ref, *, visits: int,
+                     transposed: bool):
+    """A visit of `rows` / `rows^T`: the group's block of W to bfloat16
+    when the group changes, the tile's product with it, and of that the
+    group's rows into the output tile.  The tile's other rows keep what
+    the earlier visits of the tile wrote, so every row that a group owns
+    holds that group's product at the end; the rows nobody owns are
+    undefined (zeros where the tile's first visit had rows; a visit with
+    none, an empty group's, writes nothing, and the next visit of its
+    tile then keeps what the buffer held)."""
+    v = pl.program_id(1)
+    prev = jnp.maximum(v - 1, 0)
+
+    @pl.when((v == 0) | (s_ref[visits + v] != s_ref[visits + prev]))
+    def _():
+        wb_ref[...] = w_ref[0].astype(_BF16)
+
+    @pl.when(s_ref[3 * visits + v] > s_ref[2 * visits + v])
+    def _():
+        acc = jax.lax.dot_general(
+            a_ref[...].astype(_BF16), wb_ref[...],
+            _NT if transposed else _NN, preferred_element_type=jnp.float32)
+        fresh = (v == 0) | (s_ref[v] != s_ref[prev])
+        o_ref[...] = jnp.where(
+            _gmm_mine(s_ref, v, visits, o_ref.shape[0]), acc,
+            jnp.where(fresh, 0.0, o_ref[...]))
+
+
+def _gmm_weights_kernel(s_ref, a_ref, b_ref, o_ref, *, visits: int):
+    """A visit of `weights`: a^T b over the group's rows of the tile,
+    added to the group's block, which starts from zeros."""
+    v = pl.program_id(2)
+    prev = jnp.maximum(v - 1, 0)
+
+    @pl.when((v == 0) | (s_ref[visits + v] != s_ref[visits + prev]))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    @pl.when(s_ref[3 * visits + v] > s_ref[2 * visits + v])
+    def _():
+        mine = _gmm_mine(s_ref, v, visits, a_ref.shape[0])
+        o_ref[0] += jax.lax.dot_general(
+            jnp.where(mine, a_ref[...], 0.0).astype(_BF16),
+            jnp.where(mine, b_ref[...], 0.0).astype(_BF16), _TN,
+            preferred_element_type=jnp.float32)
+
+
+def _gmm_call(kernel, name, *, grid, in_specs, out_specs, out_shape,
+              scratch, interpret):
+    """One grouped-product call on (visits, operands): the visits
+    prefetched into SMEM for the index maps, and the grid's last axis,
+    in order.  The call, for a `_built_once` builder to hand back (a
+    job's step holds 8 to 12 sites a layer)."""
+    semantics = ("parallel",) * (len(grid) - 1) + ("arbitrary",)
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=semantics)}
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, interpret=interpret, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        **params)
+
+
+@_built_once
+def _gmm_rows_call(visits, a, w, *, tiles: GmmTiles, transposed: bool,
+                   interpret: bool):
+    """rows (a (M, K) -> (M, N)) or rows^T (a (M, N) -> (M, K)) against
+    w (G, K, N)."""
+    m, (_, k, n) = a.shape[0], w.shape
+    tm, count = GMM_ROW_TILE, visits.shape[0] // 4
+    if transposed:
+        block, width = tiles.lanes_t, k
+        w_spec = pl.BlockSpec((1, block, n),
+                              lambda j, v, s: (s[count + v], j, 0))
+        held = (block, n)
+    else:
+        block, width = tiles.lanes, n
+        w_spec = pl.BlockSpec((1, k, block),
+                              lambda j, v, s: (s[count + v], 0, j))
+        held = (k, block)
+    return _gmm_call(
+        functools.partial(_gmm_rows_kernel, visits=count,
+                          transposed=transposed),
+        "cos_gmm_rows_t" if transposed else "cos_gmm_rows",
+        grid=(-(-width // block), count),
+        in_specs=[pl.BlockSpec((tm, a.shape[1]),
+                               lambda j, v, s: (s[v], 0)), w_spec],
+        out_specs=pl.BlockSpec((tm, block), lambda j, v, s: (s[v], j)),
+        out_shape=jax.ShapeDtypeStruct((m, width), jnp.float32),
+        scratch=[pltpu.VMEM(held, _BF16)], interpret=interpret)
+
+
+@_built_once
+def _gmm_weights_call(visits, a, b, *, groups: int, tiles: GmmTiles,
+                      interpret: bool):
+    """dW (G, K, N) of a (M, K) and b (M, N)."""
+    k, n = a.shape[1], b.shape[1]
+    tm, (bk, bn), count = GMM_ROW_TILE, tiles.grad, visits.shape[0] // 4
+    return _gmm_call(
+        functools.partial(_gmm_weights_kernel, visits=count),
+        "cos_gmm_weights", grid=(-(-k // bk), -(-n // bn), count),
+        in_specs=[pl.BlockSpec((tm, bk), lambda i, j, v, s: (s[v], i)),
+                  pl.BlockSpec((tm, bn), lambda i, j, v, s: (s[v], j))],
+        out_specs=pl.BlockSpec((1, bk, bn),
+                               lambda i, j, v, s: (s[count + v], i, j)),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), jnp.float32),
+        scratch=[], interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def grouped_product(a, w, visits, tiles: GmmTiles, interpret: bool = False):
+    """a (M, K) float32 rows sorted by group times their group's weight
+    of w (G, K, N) float32 -> (M, N), one bfloat16 pass accumulated in
+    float32; `visits` = `gmm_visits(sizes, M)`.  Differentiable in a
+    and w: da is the rows^T product of the cotangent, dw the weights
+    product of a and the cotangent."""
+    return _gmm_rows_call(visits, a, w, tiles=tiles, transposed=False,
+                          interpret=interpret)
+
+
+def _grouped_product_fwd(a, w, visits, tiles, interpret):
+    return grouped_product(a, w, visits, tiles, interpret), (a, w, visits)
+
+
+def _grouped_product_bwd(tiles, interpret, res, dy):
+    a, w, visits = res
+    return (_gmm_rows_call(visits, dy, w, tiles=tiles, transposed=True,
+                           interpret=interpret),
+            _gmm_weights_call(visits, a, dy, groups=w.shape[0], tiles=tiles,
+                              interpret=interpret),
+            None)
+
+
+grouped_product.defvjp(_grouped_product_fwd, _grouped_product_bwd)

@@ -251,13 +251,13 @@ def test_outputs_and_gradients_are_the_parents_to_the_last_bit(tile8):
 # ------------------------------------------------- the passes that run
 
 def every_pass(xf, gates, w_in, w_out, order, starts, ends, total, rows,
-               n_pass, k, gated, prec):
+               n_pass, k, gated, prec, kernels=None):
     """The reference for `_moe_passes`: a scan over all `n_pass` passes,
     those that hold no held row too, differentiated by JAX (PR 38's
     loop without its conditional)."""
     def one(acc, lo):
         return L._moe_pass(acc, lo, xf, gates, w_in, w_out, order, starts,
-                           ends, total, rows, k, gated, prec), None
+                           ends, total, rows, k, gated, prec, kernels), None
 
     return jax.lax.scan(one, jnp.zeros_like(xf),
                         jnp.arange(n_pass, dtype=jnp.int32) * rows)[0]
@@ -432,7 +432,9 @@ def test_moe_plans_of_the_language_model_cells(monkeypatch, name, n, key,
         "layers": [lp.name for lp in moe], "assignments": k * n,
         "rows": rows, "passes": passes,
         "passes_even_router": 1, "row_tile": 512,
-        "row_flops": 2 * 2048 * hidden * 3, "carry_bytes": carry}}
+        "row_flops": 2 * 2048 * hidden * 3, "carry_bytes": carry,
+        # off the TPU and out of interpret mode: `lax.ragged_dot`
+        "form": "xla", "calls": 0}}
     assert passes == -(-k * n // rows)
     assert rows >= k * n * held / e           # an even router fits one
 
@@ -448,9 +450,66 @@ def test_plan_of_a_plain_layer_and_a_copy_that_cannot_be_edited(tile8):
     assert L.moe_plans() == {key: {
         "layers": ["L1.moe"], "assignments": K * N, "rows": K * N,
         "passes": 1, "passes_even_router": 1, "row_tile": 8,
-        "row_flops": 2 * D * H * 2, "carry_bytes": E * 2 * D * H * 4}}
+        "row_flops": 2 * D * H * 2, "carry_bytes": E * 2 * D * H * 4,
+        "form": "xla", "calls": 0}}
     L.moe_plans()[key]["layers"].append("x")
     assert L.moe_plans()[key]["layers"] == ["L1.moe"]
+
+
+def test_kernel_form_is_in_the_plan_and_under_the_scopes(monkeypatch):
+    """Two expert layers of one shape on the grouped-product kernels
+    (interpret mode): `route.plans()["moe"]` holds the form, the tiles
+    and the call sites a layer's step holds beside the keys the
+    benchmark's reader of `moe.products_mfu_pct.train` uses; and every
+    `pallas_call` of the traced gradient, the second layer's too (whose
+    calls are the first's, kept a shape), carries its own layer's name,
+    `moe.experts` and inside it `moe.products`: what
+    `moe.products_device_ms.train` and `moe.experts_unscoped_device_ms.
+    train` read the kernels' time by."""
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    route.forget("moe")
+    net = Net(zoo.kanana2(
+        vocab=64, hidden=D, heads=2, qk_nope=8, qk_rope=4, v_head=6,
+        kv_lora_rank=16, dense_width=48, expert_width=48, experts=E,
+        top_k=K, shared_experts=0, expert_layers=2, seq=32, batch=2,
+        experts_held=4, recompute=True))
+    params = jax.eval_shape(net.init, jax.random.key(0))
+    ins = {k: jax.ShapeDtypeStruct((32, 2), jnp.float32)
+           for k in ("input_ids", "target_ids")}
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, i: net.loss(
+        p, i, train=True, rng=jax.random.key(1))[0]))(params, ins)
+
+    def calls(jaxpr, outer=""):
+        # (an equation's name stack starts at the jaxpr that holds it)
+        for eqn in jaxpr.eqns:
+            stack = f"{outer}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call" \
+                    and eqn.params["name"].startswith("cos_gmm_"):
+                yield eqn.params["name"], stack
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from calls(sub, stack)
+
+    found = list(calls(jaxpr.jaxpr))
+    plan, = route.plans()["moe"].values()
+    assert plan["form"] == "kernel" and plan["calls"] == 12
+    assert set(plan["tiles"]) == {"into", "out"}
+    assert plan["tiles"]["into"] == {"rows": 128, "lanes": 48,
+                                     "lanes_t": D, "grad": (D, 48)}
+    assert {"layers", "assignments", "row_flops"} <= set(
+        L.moe_plans()[next(iter(L.moe_plans()))])
+    assert plan["layers"] == ["L1.moe", "L2.moe"]
+    for layer in plan["layers"]:
+        mine = [(name, stack) for name, stack in found if layer in stack]
+        # the forward loop is not run again in the block's backward
+        assert len(mine) == plan["calls"], (layer, mine)
+        for name, stack in mine:
+            assert re.search(r"moe\.experts.*moe\.products", stack), stack
+            for scope in ("moe.gather", "moe.combine", "moe.route"):
+                assert scope not in stack, stack
+    assert len(found) == 2 * plan["calls"]
 
 
 def test_capacity_dispatch_writes_no_plan(tile8):
@@ -546,7 +605,8 @@ def test_train_job_reports_the_pass_plan_as_info_moe(tmp_path, monkeypatch,
     assert info["moe"] == {"16x32 top 2 of 4, 2 held x 8 gated, shared 0": {
         "layers": ["experts"], "assignments": 32, "rows": 32, "passes": 1,
         "passes_even_router": 1, "row_tile": 512,
-        "row_flops": 2 * 32 * 8 * 3, "carry_bytes": 2 * 3 * 32 * 8 * 4}}
+        "row_flops": 2 * 32 * 8 * 3, "carry_bytes": 2 * 3 * 32 * 8 * 4,
+        "form": "xla", "calls": 0}}
     assert len(said) == 1
 
 

@@ -768,3 +768,141 @@ def test_flash_mesh_dispatch_fallbacks(monkeypatch):
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(attention(q, q, q, causal=True)),
             rtol=2e-4, atol=2e-5, err_msg=str((dict(mesh.shape), shape)))
+
+
+# ------------------------------------------------ the grouped products
+
+def _rounded(x):
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _rounded_straight_through(x):
+    return _rounded(x)
+
+
+_rounded_straight_through.defvjp(lambda x: (_rounded(x), None),
+                                 lambda _, g: (g,))
+
+
+@jax.custom_vjp
+def _cotangent_rounded(y):
+    return y
+
+
+_cotangent_rounded.defvjp(lambda y: (y, None), lambda _, g: (_rounded(g),))
+
+
+def _ragged_dot_of_rounded_operands(kernels, sizes, rows, prec):
+    """`layers._moe_products`' contract in XLA: every product one
+    bfloat16 pass of its operands (the cotangent is an operand of the
+    two transposed products) accumulated in float32."""
+    from jax import lax
+    return lambda a, w, out=False: _cotangent_rounded(lax.ragged_dot(
+        _rounded_straight_through(a), _rounded_straight_through(w), sizes))
+
+
+# scaled-down expert passes of the five language cells: (tokens, top k,
+# D, hidden, gated, assignments a held group, assignments held elsewhere,
+# rows a pass, the pass's first row, the tiles forced on the kernels or
+# None for `gmm_plan`'s)
+_GROUPED = {
+    # a hidden width that is no multiple of 128 under blocks of 128 lanes
+    # (the masked last block, both as W's lanes and as dW's), ungated
+    # relu2, groups that end inside a tile, rows past the last group
+    "nemotron3nano": (128, 3, 256, 208, "relu2", (70, 45, 100, 61), 108,
+                      384, 0, (128, 128, (128, 128))),
+    # gated SiLU, every group inside one tile or across two
+    "lfm2": (128, 2, 128, 96, "silu", (30, 34, 29, 35, 31, 33, 28, 36), 0,
+             256, 0, None),
+    # an empty group among 16, its neighbours sharing a tile
+    "kanana2": (128, 3, 128, 48, "silu",
+                (20, 0, 25, 18, 22, 0, 0, 30, 17, 23, 19, 21, 24, 16, 26, 15),
+                108, 384, 0, None),
+    # 32 small groups, many to a tile, the last ones empty
+    "qwen3next": (64, 4, 128, 32, "silu", (7,) * 28 + (0,) * 4, 60, 256, 0,
+                  (32, 128, (128, 32))),
+    # gated by ReLU, groups longer than a tile; the second pass starts
+    # inside group 1 (`lo` 256 > its first row 200) and ends past the
+    # last held row
+    "smallthinker": (256, 2, 160, 64, "relu", (200, 150, 100), 62, 256, 256,
+                     None),
+    # a pass that holds no held row at all: nothing is added, every
+    # gradient is zero, and what the kernels left is in no sum
+    "no held row": (64, 2, 128, 32, "silu", (40, 30), 58, 128, 128, None),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_GROUPED))
+def test_grouped_products_match_ragged_dot_of_rounded_operands(monkeypatch,
+                                                               cell):
+    """The kernel form (`cos_gmm_rows`, `cos_gmm_rows_t`,
+    `cos_gmm_weights`, interpret mode) against `lax.ragged_dot` on
+    operands rounded to bfloat16: a product alone, value and both
+    cotangents, to float32 summation order; and a pass of `_moe_pass`,
+    value and the cotangents of the rows, the gates and every weight."""
+    from jax import lax
+    from caffeonspark_tpu.ops import layers as L
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    n, k, d, h, gated, held, absent, rows, lo, forced = _GROUPED[cell]
+    groups = len(held)
+    assert sum(held) + absent == k * n
+    keys = jax.random.split(jax.random.key(len(cell)), 8)
+    group = jax.random.permutation(keys[0], jnp.repeat(
+        jnp.arange(groups + 1), jnp.asarray(held + (absent,)),
+        total_repeat_length=k * n))
+    order = jnp.argsort(group, stable=True)
+    ends = jnp.cumsum(jnp.asarray(held, jnp.int32))
+    starts, total = ends - jnp.asarray(held, jnp.int32), ends[-1]
+    n_pass = -(-k * n // rows)
+    order = jnp.pad(order, (0, n_pass * rows - k * n))
+    xf = jax.random.normal(keys[1], (n, d), jnp.float32)
+    gates = jax.random.uniform(keys[2], (k * n,), jnp.float32, 0.1, 1.0)
+    w_in = tuple(jax.random.normal(kk, (groups, d, h)) * d ** -0.5
+                 for kk in keys[3:3 + (1 if gated == "relu2" else 2)])
+    w_out = jax.random.normal(keys[5], (groups, h, d)) * h ** -0.5
+    acc = jax.random.normal(keys[6], (n, d), jnp.float32)
+    tiles = (pk.GmmTiles(*forced), pk.GmmTiles(*forced)) if forced else (
+        pk.gmm_plan(rows, d, h, groups), pk.gmm_plan(rows, h, d, groups))
+    assert all(tiles) and pk.GMM_ROW_TILE == 128
+
+    # a product alone, rows past the last group left out of the cotangent
+    sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(starts - lo, 0, rows)
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    a = jnp.where(live, _rounded(jax.random.normal(keys[7], (rows, d))), 0)
+    dy = jnp.where(live, _rounded(jax.random.normal(keys[6], (rows, h))), 0)
+    w = _rounded(w_in[0])
+    visits = pk.gmm_visits(sizes, rows)
+    y, pull = jax.vjp(lambda a, w: pk.grouped_product(
+        a, w, visits, tiles[0], True), a, w)
+    y_ref, pull_ref = jax.vjp(lambda a, w: lax.ragged_dot(a, w, sizes), a, w)
+    for name, got, ref in zip(("y", "da", "dw"),
+                              (jnp.where(live, y, 0),) + tuple(
+                                  jnp.where(live, g, 0) if g.ndim == 2 else g
+                                  for g in pull(dy)),
+                              (y_ref,) + pull_ref(dy)):
+        np.testing.assert_allclose(got, ref, rtol=0, err_msg=name,
+                                   atol=1e-5 * float(jnp.abs(ref).max() + 1))
+
+    # a pass
+    def a_pass(kernels):
+        def f(acc, xf, gates, w_in, w_out):
+            return L._moe_pass(acc, lo, xf, gates, w_in, w_out, order,
+                               starts, ends, total, rows, k, gated, None,
+                               kernels)
+        out, pull = jax.vjp(f, acc, xf, gates, w_in, w_out)
+        return (out,) + tuple(jax.tree.leaves(pull(jnp.cos(out))))
+
+    got = a_pass(L._MoeKernels(*tiles, True))
+    monkeypatch.setattr(L, "_moe_products", _ragged_dot_of_rounded_operands)
+    ref = a_pass(None)
+    names = ["y", "dacc", "dx", "dgates"] + [f"dw{i}" for i in range(3)]
+    moved = False
+    for name, g, r in zip(names, got, ref):
+        assert np.isfinite(g).all(), name
+        # a bfloat16 rounding of the hidden activation may fall the
+        # other way: 2^-9 of an element, far under one wrong row
+        np.testing.assert_allclose(g, r, rtol=0, err_msg=name,
+                                   atol=4e-3 * float(jnp.abs(r).max() + 1))
+        moved |= name.startswith("dw") and bool(jnp.any(g != 0))
+    assert moved == (cell != "no held row")

@@ -1,7 +1,7 @@
 """`ops/route.py`: the one rule that says which form of an operator is
 lowered, held against every operator that asks it (LRN, flash attention,
 the convolution + SiLU stage, the gated delta rule, the selective scan,
-the Mamba-2 scan):
+the Mamba-2 scan, the expert layers' grouped products):
 what a traced program holds (a `pallas_call`, a `shard_map` around it, or
 neither) and, where the kind keeps one, what `route.plans()` says.  And
 the two properties the module exists for: nothing else under `ops/`
@@ -69,10 +69,29 @@ def _ssd(tiles):
             ("ssd", f"1x140 2 heads of {p} over 1 groups of 128 states"))
 
 
+def _moe_layer(hidden, ctx=None):
+    """A dropless expert layer over (128, 32): passes of 256 rows (two
+    row tiles of the kernels), 4 of 8 experts held."""
+    lp = LayerParameter.from_text(f'''
+      name: "L0.moe" type: "MixtureOfExperts" bottom: "x" top: "y"
+      moe_param {{ num_experts: 8 hidden_dim: {hidden} top_k: 2
+        dispatch: "dropless" scoring: "sigmoid" experts_held: 4 }}''')
+    return (lambda x, *p: L.get_op("MixtureOfExperts").apply(
+                ctx or L.Ctx(train=True), lp, list(p), [x])[0],
+            [f32(128, 32)] + [f32(*s) for _, s, _ in
+                              L._moe_params(lp, [(128, 32)])],
+            ("moe", f"128x32 top 2 of 8, 4 held x {hidden}, shared 0"))
+
+
+def _moe(tiles):
+    return _moe_layer(48 if tiles else 40)     # whole bfloat16 sublanes
+
+
 KINDS = {"lrn": _lrn, "flash": _flash, "taps": _taps, "gdn": _gdn,
-         "ssm": _ssm, "ssd": _ssd}
+         "ssm": _ssm, "ssd": _ssd, "moe": _moe}
 # the word a kind's plan holds the form under
-FORM = {"taps": "form", "gdn": "rule", "ssm": "form", "ssd": "form"}
+FORM = {"taps": "form", "gdn": "rule", "ssm": "form", "ssd": "form",
+        "moe": "form"}
 # parallel over the batch (and heads): under a mesh the kernel stays, on
 # each device's block
 OVER_SHARDS = ("lrn", "flash")
@@ -131,6 +150,31 @@ def test_which_form_is_lowered(monkeypatch, kind, where):
              else plan[1]] if kernel else [])
     else:                       # LRN keeps no record: no `info.lrn`
         assert route.plans() == {}
+
+
+def test_expert_products_pinned_to_float32_keep_the_xla_form(monkeypatch):
+    """An autotune plan that holds a layer at float32 (`ctx.precision()`
+    HIGHEST) keeps `lax.ragged_dot`: the kernels are one bfloat16 pass
+    and no more; the plan says which form, the tiles and the call sites
+    a layer's step holds."""
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    for variant, form, calls in (({"dtype": "float32"}, "xla", 0),
+                                 (None, "kernel", 8)):
+        fn, args, (kind, key) = _moe_layer(
+            48, L.Ctx(train=True, variant=variant))
+        route.forget()
+        prims = primitives(jax.make_jaxpr(fn)(*args).jaxpr)
+        assert ("pallas_call" in [n for n, _ in prims]) is (form == "kernel")
+        assert ("ragged_dot_general" in [n for n, _ in prims]) is (
+            form == "xla")
+        plan = route.plans()[kind][key]
+        assert (plan["form"], plan["calls"]) == (form, calls)
+        assert ("tiles" in plan) is (form == "kernel")
+        if form == "kernel":
+            assert plan["tiles"]["into"] == {
+                "rows": 128, "lanes": 48, "lanes_t": 32, "grad": (32, 48)}
+            assert plan["tiles"]["out"] == {
+                "rows": 128, "lanes": 32, "lanes_t": 48, "grad": (48, 32)}
 
 
 def test_the_attention_vetoes_are_attentions_alone(monkeypatch):

@@ -202,6 +202,139 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip):
     assert pk.gdn_rule_steps(t // c) == (4, 4, 128)     # 8 groups
 
 
+def _expert_layer(net: str):
+    """The first expert layer of a zoo net as `zoo` writes it, and the
+    float32 shapes it is applied to: its blobs, then its bottoms."""
+    from caffeonspark_tpu.models import zoo
+    from caffeonspark_tpu.ops import layers as L
+    npm = getattr(zoo, net)()
+    lp = next(lp for lp in npm.layer if lp.type == "MixtureOfExperts")
+    n, d = 8192, {"nemotron_h": 2688}.get(net, 2048)
+    blobs = [s for _, s, _ in L._moe_params(lp, [(n, d)])]
+    return lp, blobs, [(n, d)] * len(lp.bottom)
+
+
+def _expert_layers_loss(lps):
+    """sum(sin(layer(x))) over `lps`, each with its own blobs."""
+    from caffeonspark_tpu.ops import layers as L
+
+    def loss(blobs, *bottoms):
+        return sum(jnp.sum(jnp.sin(L.get_op("MixtureOfExperts").apply(
+            L.Ctx(train=True), lp, p, list(bottoms))[0]))
+            for lp, p in zip(lps, blobs))
+    return loss
+
+
+@pytest.mark.parametrize("net,key,tiles", [
+    ("lfm2", "8192x2048 top 4 of 64, 8 held x 1536 gated, shared 0",
+     {"into": {"rows": 128, "lanes": 512, "lanes_t": 512,
+               "grad": (1024, 768)},
+      "out": {"rows": 128, "lanes": 512, "lanes_t": 512,
+              "grad": (768, 1024)}}),
+    # a hidden width of 14.5 x 128: whole where it is contracted, a
+    # masked last block where it is the lanes (5 x 384, 2 x 1024)
+    ("nemotron_h", "8192x2688 top 6 of 128, 8 held x 1856 relu2, "
+     "shared 3712",
+     {"into": {"rows": 128, "lanes": 384, "lanes_t": 512,
+               "grad": (896, 1024)},
+      "out": {"rows": 128, "lanes": 512, "lanes_t": 384,
+              "grad": (1024, 896)}}),
+])
+def test_expert_pass_kernels_compile_for_v5e(one_chip, monkeypatch, net,
+                                             key, tiles):
+    """An expert layer of `lfm2.train_packed8k` and of `nemotron3nano.
+    train_packed8k`, value and gradients, lowers for the v5e with exactly
+    the `calls` its plan records: a product's forward call in the
+    forward loop, and in the backward loop the call again, its rows^T
+    and its weights; none with a VMEM window of its own."""
+    monkeypatch.setattr(route, "on_tpu", lambda: True)
+    route.forget("moe")
+    lp, blobs, bottoms = _expert_layer(net)
+    shapes = [[jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+               for s in blobs]] + [jax.ShapeDtypeStruct(
+                   s, jnp.float32, sharding=one_chip) for s in bottoms]
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(jax.value_and_grad(
+            lambda p, *xs: _expert_layers_loss([lp])([p], *xs),
+            argnums=(0, 1))).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    plan = route.plans()["moe"][key]
+    assert plan["form"] == "kernel" and plan["tiles"] == tiles
+    products = plan["calls"] // 4
+    assert plan["calls"] == (8 if net == "nemotron_h" else 12)
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(lines) == plan["calls"]
+    for name, calls in (("cos_gmm_rows", 2 * products),
+                        ("cos_gmm_rows_t", products),
+                        ("cos_gmm_weights", products)):
+        assert sum(f'/{name}/pallas_call"' in line
+                   for line in lines) == calls, name
+    # under `moe.products` inside a loop of `moe.experts`, whatever
+    # transformation wrapped the two tokens
+    assert all(re.search(r"moe\.experts\)*/while/body/[a-z(]*moe\.products"
+                         r"\)*/cos_gmm_", line) for line in lines)
+    assert all('"scoped_memory_configs":[]' in line for line in lines)
+
+
+def test_expert_layers_of_one_shape_trace_each_kernel_once(monkeypatch):
+    """What a job's start pays for the expert layers' kernels (CPU,
+    interpret mode): one layer's forward + backward holds the 12
+    `pallas_call` equations its plan's `calls` says (8 ungated), and
+    two layers of one shape trace each kernel body no more often than
+    one does: once (`rows`: twice) a kind of product and weight shape,
+    whatever the number of layers and call sites (a `pallas_call` is
+    built once an argument list, `pallas_kernels._built_once`).  PRs 37,
+    44, 48 and 49 each found a kernel body's Python in `setup_s` only on
+    the chip."""
+    import collections
+    from caffeonspark_tpu.ops import pallas_kernels as pk
+    from caffeonspark_tpu.proto import LayerParameter
+    monkeypatch.setenv("COS_FLASH_INTERPRET", "1")
+    seen = collections.Counter()
+    for name in ("_gmm_rows_kernel", "_gmm_weights_kernel"):
+        def counted(*refs, _fn=getattr(pk, name), _name=name, **static):
+            seen[_name, static.get("transposed"), refs[1].shape] += 1
+            return _fn(*refs, **static)
+        monkeypatch.setattr(pk, name, counted)
+    n, d, h = 128, 32, 48
+    x = jax.ShapeDtypeStruct((n, d + 16), jnp.float32)
+
+    def layers(count, gated):
+        lps = [LayerParameter.from_text(f"""
+          name: "L{i}.moe" type: "MixtureOfExperts" bottom: "x" top: "y"
+          moe_param {{ num_experts: 8 hidden_dim: {h + 16 + 16 * gated}
+            top_k: 2 dispatch: "dropless" scoring: "sigmoid"
+            gated: {str(gated).lower()} experts_held: 4 }}""")
+               for i in range(count)]
+        from caffeonspark_tpu.ops import layers as L
+        blobs = [[jax.ShapeDtypeStruct(s, jnp.float32) for _, s, _ in
+                  L._moe_params(lp, [x.shape])] for lp in lps]
+        return jax.make_jaxpr(jax.value_and_grad(
+            _expert_layers_loss(lps), argnums=(0, 1)))(blobs, x)
+
+    def calls(jaxpr):       # nested equations are printed too
+        return str(jaxpr).count(" pallas_call[")
+
+    for gated, sites in ((True, 12), (False, 8)):
+        route.forget("moe")
+        pk.forget_calls()
+        seen.clear()
+        assert calls(layers(1, gated)) == sites
+        plan, = route.plans()["moe"].values()
+        assert plan["form"] == "kernel" and plan["calls"] == sites
+        one = dict(seen)
+        # rows and rows^T against the first and the last weights, and
+        # the weights' product of each; `rows` once more where it is
+        # differentiated (the inlined jit keeps a trace a context)
+        assert len(one) == 6 and max(one.values()) <= 2
+        assert calls(layers(2, gated)) == 2 * sites
+        assert dict(seen) == one
+
+
 def test_selective_scan_kernels_compile_for_v5e(one_chip):
     """The scan's forward and backward calls at `phi4flash.train_
     packed8k`'s shape (1, 8,192, 5,120 channels, 16 states, chunk 64)
@@ -422,13 +555,14 @@ def test_nemotron3nano_step_compiles_for_v5e(one_chip, monkeypatch):
         < 15.75e9
     lines = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(lines) == 67
+    assert len(lines) == 23 + 4 * 8
     # (by the call's own name: a scan's line names the taps call whose
     # output it reads)
     for name, calls in (("cos_flash_fwd", 1), ("cos_flash_bwd_dq", 1),
                         ("cos_flash_bwd_dkv", 1), ("cos_taps_fwd", 8),
                         ("cos_taps_bwd", 4), ("cos_ssd_fwd", 4),
-                        ("cos_ssd_bwd", 4)):
+                        ("cos_ssd_bwd", 4), ("cos_gmm_rows", 16),
+                        ("cos_gmm_rows_t", 8), ("cos_gmm_weights", 8)):
         assert sum(f'/{name}/pallas_call"' in line
                    for line in lines) == calls, name
     windows = [int(n) for line in lines for n in re.findall(
@@ -450,4 +584,6 @@ def test_nemotron3nano_step_compiles_for_v5e(one_chip, monkeypatch):
         "ssd.y": 8192 * 4096 * 4, "ssd.edges": 64 * 64 * 64 * 128 * 4}
     assert sorted(plans["recompute"]["blocks"]) == [
         f"L{i}" for i in range(9)]
-    assert "relu2" in next(iter(plans["moe"]))
+    moe, = plans["moe"].items()
+    assert "relu2" in moe[0] and moe[1]["form"] == "kernel" \
+        and moe[1]["calls"] == 8 and len(moe[1]["layers"]) == 4
